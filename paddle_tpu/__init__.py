@@ -17,8 +17,8 @@ Typical use mirrors Fluid:
     loss_val, = exe.run(feed={"x": xb, "y": yb}, fetch_list=[loss])
 """
 
-# Wire the persistent XLA compile cache BEFORE anything can trigger a
-# compile — PADDLE_TPU_COMPILE_CACHE=<dir> makes restarts skip re-compiles.
+# Place the persistent XLA compile cache BEFORE anything can trigger a
+# compile: JAX_COMPILATION_CACHE_DIR where set, else <checkout>/.jax_cache.
 from . import compile_cache as _compile_cache  # noqa: F401
 
 _compile_cache.setup_compile_cache()

@@ -1,13 +1,23 @@
-"""Persistent XLA compile cache (``PADDLE_TPU_COMPILE_CACHE=<dir>``).
+"""Persistent XLA compile cache: always on, placed from outside or at one
+fixed path.
 
 The TVM argument (PAPERS.md) applied to this stack: the traced step is an
-ahead-of-time compilation artifact, yet by default every process restart
-re-pays the full XLA compile — minutes for the big train steps. JAX ships a
-persistent on-disk compilation cache; this module wires it up at import
-when ``PADDLE_TPU_COMPILE_CACHE`` names a directory, with the cache
-thresholds zeroed so *every* executable is cached (JAX's defaults skip
-fast-compiling programs, which would make CPU tests and small models look
-like the cache doesn't work).
+ahead-of-time compilation artifact, yet without a cache every process
+restart re-pays the full XLA compile — minutes for the big train steps. JAX
+ships a persistent on-disk compilation cache; this module decides where it
+lives, once, at ``paddle_tpu`` import:
+
+* ``JAX_COMPILATION_CACHE_DIR`` set — JAX reads it itself and the package
+  sets no directory in code. That is how a scheduler, a test run or the
+  chip tool places the cache.
+* unset — ``<checkout>/.jax_cache``, derived from the package's own
+  location and listed in ``.gitignore``. The path is part of the cache's
+  key, so it is never a temporary, per-process or dated name: a directory
+  that moves never hits.
+
+JAX's own thresholds decide what is worth writing (compiles of a second or
+more): the chip's compiles are far above them, and the small CPU
+executables of the tests stay out of the directory.
 
 Observability: a ``compile_cache/hit`` / ``compile_cache/miss`` counter
 pair in :mod:`paddle_tpu.monitor`, fed by JAX's own monitoring events — so
@@ -19,17 +29,16 @@ named model) to prime the cache before the real job.
 from __future__ import annotations
 
 import os
-from typing import Optional
 
 from .monitor import metrics as _mx
 
-__all__ = ["setup_compile_cache", "compile_cache_dir", "is_configured"]
+__all__ = ["setup_compile_cache", "compile_cache_dir"]
 
-# Registered at import so the counters exist (value 0) even when the cache
-# is off — tools/dump_metrics --selftest asserts their presence.
+# Registered at import so the counters exist (value 0) before the first
+# compile — tools/dump_metrics --selftest asserts their presence.
 _m_hit = _mx.counter("compile_cache/hit",
                      help="XLA executables loaded from the persistent "
-                          "compile cache (PADDLE_TPU_COMPILE_CACHE)")
+                          "compile cache")
 _m_miss = _mx.counter("compile_cache/miss",
                       help="XLA compiles that went to the compiler and were "
                            "written to the persistent cache")
@@ -39,14 +48,16 @@ _configured = False
 _HIT_EVENT = "/jax/compilation_cache/cache_hits"
 _MISS_EVENT = "/jax/compilation_cache/cache_misses"
 
-
-def compile_cache_dir() -> Optional[str]:
-    """The configured cache directory, or None when the env var is unset."""
-    return os.environ.get("PADDLE_TPU_COMPILE_CACHE") or None
+_DEFAULT_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.abspath(__file__))), ".jax_cache")
 
 
-def is_configured() -> bool:
-    return _configured
+def compile_cache_dir() -> str:
+    """Where the cache lives: ``JAX_COMPILATION_CACHE_DIR``, else the fixed
+    ``.jax_cache`` beside the package. The tuned-kernel and calibration
+    tables sit in the same directory (tune.table_path,
+    monitor.numerics.table_path)."""
+    return os.environ.get("JAX_COMPILATION_CACHE_DIR") or _DEFAULT_DIR
 
 
 def _on_event(event: str, **kwargs) -> None:
@@ -56,41 +67,17 @@ def _on_event(event: str, **kwargs) -> None:
         _m_miss.inc()
 
 
-def setup_compile_cache(path: Optional[str] = None) -> bool:
-    """Point JAX's persistent compilation cache at ``path`` (default: the
-    ``PADDLE_TPU_COMPILE_CACHE`` env var) and hook the hit/miss counters.
-
-    Idempotent; returns True when the cache is (now) configured. Called at
-    ``paddle_tpu`` import, so setting the env var is all a job needs — but
-    it can also be called explicitly before any compile to enable the cache
-    programmatically.
-    """
+def setup_compile_cache() -> None:
+    """Place the cache (see the module docstring) and hook the hit/miss
+    counters. Idempotent; called at ``paddle_tpu`` import, before anything
+    can compile."""
     global _configured
     if _configured:
-        return True
-    path = path or compile_cache_dir()
-    if not path:
-        return False
+        return
     import jax
+    from jax import monitoring
 
-    jax.config.update("jax_compilation_cache_dir", os.path.abspath(path))
-    # Cache EVERYTHING: the default min-size/min-compile-time thresholds
-    # exist to keep the cache small, but they also make warm-start silently
-    # not happen for small models — the worst failure mode for a knob whose
-    # whole point is predictable restart latency.
-    for knob, val in (("jax_persistent_cache_min_entry_size_bytes", 0),
-                      ("jax_persistent_cache_min_compile_time_secs", 0)):
-        try:
-            jax.config.update(knob, val)
-        except AttributeError:  # older jax without the knob
-            pass
-    try:
-        from jax._src import monitoring as _jmon
-
-        # register once; _configured guards re-registration
-        _jmon.register_event_listener(_on_event)
-    except Exception:
-        # counters stay at 0 but the on-disk cache still works
-        pass
+    if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        jax.config.update("jax_compilation_cache_dir", _DEFAULT_DIR)
+    monitoring.register_event_listener(_on_event)
     _configured = True
-    return True

@@ -43,16 +43,24 @@ class CPUPlace(Place):
 
 
 class TPUPlace(Place):
-    """The TPU device (north-star equivalent of CUDAPlace place.h:37)."""
+    """The TPU device (north-star equivalent of CUDAPlace place.h:37).
+
+    Resolves to a TPU or fails: constructing it where JAX finds no
+    accelerator raises, naming what was found. A CPU device stands in only
+    when the process was told to run there (``JAX_PLATFORMS=cpu``, as the
+    tests, ``__graft_entry__.py`` and the CPU smokes do), so a trainer
+    never lands on the host without a word."""
 
     def __init__(self, device_id: int = 0):
         self.device_id = device_id
+        self.jax_device()
 
     def jax_device(self):
         devs = _accelerator_devices()
-        if devs and self.device_id < len(devs):
-            return devs[self.device_id]
-        return None
+        if not 0 <= self.device_id < len(devs):
+            raise ValueError("%r: JAX has %d such device(s): %s"
+                             % (self, len(devs), devs))
+        return devs[self.device_id]
 
 
 class CUDAPinnedPlace(Place):
@@ -61,8 +69,15 @@ class CUDAPinnedPlace(Place):
 
 def _accelerator_devices():
     devs = jax.devices()
-    accel = [d for d in devs if d.platform not in ("cpu",)]
-    return accel or devs
+    accel = [d for d in devs if d.platform != "cpu"]
+    if accel:
+        return accel
+    if (jax.config.jax_platforms or "").strip().lower() == "cpu":
+        return devs
+    raise RuntimeError(
+        "TPUPlace: JAX found no accelerator, only %s. Run where the TPU is "
+        "attached, or set JAX_PLATFORMS=cpu to run on the CPU on purpose."
+        % (devs,))
 
 
 def get_device(place: Optional[Place]) -> Optional[jax.Device]:

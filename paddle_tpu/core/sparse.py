@@ -107,7 +107,6 @@ def sharded_rows_update(tables, ids, rows, update, mesh, axis,
     """
     from jax.sharding import PartitionSpec as P
 
-    from ..parallel._compat import shard_map
 
     vocab = tables[0].shape[0]
     n = mesh.shape[axis]
@@ -132,10 +131,10 @@ def sharded_rows_update(tables, ids, rows, update, mesh, axis,
 
     spec_in = (P(axis) if alltoall else P(),
                P(axis, None) if alltoall else P(None, None))
-    fn = shard_map(body, mesh=mesh,
-                   in_specs=spec_in + (P(),) * len(scalars)
-                   + (t_spec,) * len(tables),
-                   out_specs=(t_spec,) * len(tables))
+    fn = jax.shard_map(body, mesh=mesh,
+                       in_specs=spec_in + (P(),) * len(scalars)
+                       + (t_spec,) * len(tables),
+                       out_specs=(t_spec,) * len(tables), check_vma=False)
     return fn(ids, rows, *scalars, *tables)
 
 

@@ -348,8 +348,8 @@ def aot_compile(fn, abstract_args, donate_argnums=(), static_argnums=()):
 
     ``abstract_args`` is a tuple of pytrees of arrays or
     ``ShapeDtypeStruct``\\ s (only shapes/dtypes are read). Compile time
-    lands in ``executor/compile_time_ms``; with ``PADDLE_TPU_COMPILE_CACHE``
-    set the executable persists across processes, so a serving restart
+    lands in ``executor/compile_time_ms``; the executable persists across
+    processes in the compile cache (compile_cache.py), so a serving restart
     skips every prefill/decode compile. Returns the compiled executable
     (call it with concrete arrays; ``donate_argnums`` buffers are consumed).
     """
@@ -1319,6 +1319,7 @@ class Executor:
             feeds = {k: jax.device_put(v, plan.batch_sh)
                      for k, v in feeds.items()}
         else:
+            dev = self._device()
             if state:
                 # State rides a donate_argnums=(0,) jit. Host (numpy)
                 # entries — the scope right after a checkpoint load — MUST
@@ -1327,11 +1328,23 @@ class Executor:
                 # donating an aliased buffer lets the async execution keep
                 # using memory Python frees the moment the scope swaps in
                 # the step's outputs (observed as rare corrupted/NaN state
-                # in the first chunk after a restore). jax.Arrays pass
-                # through untouched — the steady-state carry costs nothing.
-                state = {k: v if isinstance(v, jax.Array) else jnp.array(v)
-                         for k, v in state.items()}
-            dev = self._device()
+                # in the first chunk after a restore).
+                # Then commit the state where the feeds go. The startup
+                # program's outputs are uncommitted, the step's own outputs
+                # are committed (its feeds are), and jit specializes on
+                # that: without this the SECOND step compiles the whole
+                # program again (chip_smoke.py's train phase counts compiles
+                # after the first step). Same device: the buffer is shared,
+                # not copied. Committed jax.Arrays pass through untouched —
+                # the steady-state carry costs nothing.
+                def owned(v):
+                    if not isinstance(v, jax.Array):
+                        v = jnp.array(v)
+                    if dev is not None and not v.committed:
+                        v = jax.device_put(v, dev)
+                    return v
+
+                state = {k: owned(v) for k, v in state.items()}
             if dev is not None and feeds:
                 # jax.Arrays already on the right device skip the device_put —
                 # re-placing them every step costs real host time. Arrays
@@ -1680,9 +1693,9 @@ class Executor:
         ``feed`` WITHOUT executing it (the TVM-style AOT artifact path).
 
         ``feed`` values may be real arrays, ``jax.ShapeDtypeStruct``\\ s, or
-        ``(shape, dtype)`` tuples — only shapes/dtypes matter. With
-        ``PADDLE_TPU_COMPILE_CACHE`` set, the XLA executable lands in the
-        persistent cache, so a later process (``tools/warmup.py`` then the
+        ``(shape, dtype)`` tuples — only shapes/dtypes matter. The XLA
+        executable lands in the persistent cache (compile_cache.py), so a
+        later process (``tools/warmup.py`` then the
         real job) skips the compile entirely. Accepts a ``CompiledProgram``
         like ``run()`` (its mesh specialization is what gets AOT-compiled).
         Returns the cached ``_CompiledStep``.
@@ -1721,7 +1734,24 @@ class Executor:
         compiled = plan.compiled
         if not compiled.jitted:
             return compiled
+        # run() lays state and feeds out before the step sees them (_place)
+        # and the compile key follows the layout: lower for the same one, or
+        # the warmed executable is one run() never asks the cache for
+        def laid_out(tree, sharding_of):
+            return {k: jax.ShapeDtypeStruct(v.shape, v.dtype,
+                                            sharding=sharding_of(k))
+                    for k, v in tree.items()}
+
         abstract_state = _abstractify(state)
+        if mesh is not None:
+            abstract_state = laid_out(
+                abstract_state,
+                lambda k: plan.put_specs.get(k, plan.mesh_repl))
+            abstract = laid_out(abstract, lambda k: plan.batch_sh)
+        elif self._device() is not None:
+            at_dev = jax.sharding.SingleDeviceSharding(self._device())
+            abstract_state = laid_out(abstract_state, lambda k: at_dev)
+            abstract = laid_out(abstract, lambda k: at_dev)
         lowered, aot = _timed_lower_compile(
             compiled.fn, (abstract_state, abstract,
                           jax.ShapeDtypeStruct((), np.dtype("uint32"))))
@@ -1741,8 +1771,8 @@ class Executor:
     def _publish_device_profile(compiled, state, feeds):
         """``PADDLE_TPU_DEVICE_PROFILE=1`` compile-miss hook: AOT-lower this
         specialization at abstract shapes and publish the device_profile/*
-        gauges. Costs an extra trace (+ an XLA compile served from the
-        persistent cache when ``PADDLE_TPU_COMPILE_CACHE`` is set) — a
+        gauges. Costs an extra trace (+ an XLA compile, which the
+        persistent cache serves where JAX's thresholds admitted it) — a
         debug opt-in, never on the default path, never raising into the
         step."""
         try:

@@ -590,8 +590,11 @@ class ProcessReplica:
         self.trace_file = trace_file
         self.clock_offset_us = 0   # worker span clock − router span clock
         self.clock_rtt_us = 0      # min handshake round trip (error bound)
+        # the worker inherits the parent's platform: a CPU fleet is one
+        # whose router was started with JAX_PLATFORMS=cpu. On a TPU host a
+        # chip belongs to one process, and workers cannot yet be handed a
+        # device each (ROADMAP R6), so process fleets are a CPU path today
         env = dict(os.environ)
-        env.setdefault("JAX_PLATFORMS", "cpu")
         env["PYTHONPATH"] = _REPO + os.pathsep + env.get("PYTHONPATH", "")
         if telemetry_dir:
             os.makedirs(telemetry_dir, exist_ok=True)

@@ -26,7 +26,8 @@ import numpy as np
 from ..ops import attention_ops
 
 __all__ = ["DecoderConfig", "DecoderLM", "init_params", "prefill_forward",
-           "decode_forward", "verify_forward", "reference_decode"]
+           "decode_forward", "verify_forward", "reference_decode",
+           "reference_tokens"]
 
 
 class DecoderConfig:
@@ -237,3 +238,33 @@ def reference_decode(params: Dict, cfg: DecoderConfig, prompt,
         if len(seq) >= cfg.max_seq:
             break
     return out_tokens, out_logits
+
+
+_jit_prefill_forward = jax.jit(prefill_forward, static_argnums=1)
+
+
+def reference_tokens(params: Dict, cfg: DecoderConfig, prompt, output,
+                     pad_multiple: int = 128) -> List[int]:
+    """:func:`reference_decode`'s verdict on an ``output`` some other
+    decoder produced for ``prompt``, in ONE forward instead of one per
+    token (on an accelerator each of reference_decode's steps has a new
+    shape, so every op of it compiles again: 32 tokens of a 12-layer model
+    cost minutes of compile).
+
+    The same full-recompute forward runs once, teacher-forced, over
+    ``prompt + output[:-1]``. A causal model's logits at position i depend
+    on tokens <= i only, so row ``len(prompt) - 1 + i`` is the row
+    reference_decode computes for its i-th token as long as the first i
+    tokens agree. Returns those rows' greedy tokens: equal to ``output``
+    iff ``reference_decode(prompt, len(output))`` is, and first different
+    at the same index if not. The sequence is padded (masked through
+    ``lengths``) to a multiple of ``pad_multiple`` so that requests share
+    one compiled forward."""
+    seq = [int(t) for t in prompt] + [int(t) for t in output[:-1]]
+    padded = min(-(-len(seq) // pad_multiple) * pad_multiple, cfg.max_seq)
+    toks = np.zeros((1, padded), np.int32)
+    toks[0, :len(seq)] = seq
+    logits, _ = _jit_prefill_forward(params, cfg, jnp.asarray(toks),
+                                     jnp.asarray([len(seq)], jnp.int32))
+    rows = logits[0, len(prompt) - 1:len(seq)]
+    return [int(t) for t in np.asarray(jnp.argmax(rows, axis=-1))]

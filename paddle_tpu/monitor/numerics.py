@@ -472,18 +472,17 @@ def reset() -> None:
 # -- calibration tables (tune-table discipline, parameterized format) ---------
 
 
-def table_path() -> Optional[str]:
+def table_path() -> str:
     """Where the calibration table lives: ``PADDLE_TPU_NUMERICS_TABLE``
-    wins; else ``numerics_calib.json`` next to the persistent compile
-    cache; None when neither is configured (calibration then accumulates
-    in-process only)."""
+    wins; else ``numerics_calib.json`` in the persistent compile cache's
+    directory (``JAX_COMPILATION_CACHE_DIR``, else the checkout's fixed
+    ``.jax_cache``)."""
     p = os.environ.get("PADDLE_TPU_NUMERICS_TABLE", "").strip()
     if p:
         return p
     from ..compile_cache import compile_cache_dir
 
-    d = compile_cache_dir()
-    return os.path.join(d, "numerics_calib.json") if d else None
+    return os.path.join(compile_cache_dir(), "numerics_calib.json")
 
 
 def read_calibration(path: Optional[str] = None) -> Optional[Dict[str, dict]]:
@@ -497,17 +496,14 @@ def read_calibration(path: Optional[str] = None) -> Optional[Dict[str, dict]]:
 
 def record_calibration(fingerprint: str, slot: str, typ: str, amax: float,
                        *, bits: int = 8,
-                       path: Optional[str] = None) -> Optional[str]:
+                       path: Optional[str] = None) -> str:
     """Merge one per-tensor amax into the table (running max against any
     existing entry; read-modify-write, atomic publish). The stored
     ``scale`` is the symmetric int-``bits`` quantization step
-    ``amax / (2**(bits-1) - 1)``. Returns the table path or None when no
-    location is configured."""
+    ``amax / (2**(bits-1) - 1)``. Returns the table path."""
     from ..tune import table as _tbl
 
     path = path or table_path()
-    if not path:
-        return None
     qmax = float(2 ** (bits - 1) - 1)
     with _lock:
         entries = dict(read_calibration(path) or {})
@@ -531,11 +527,8 @@ def record_calibration(fingerprint: str, slot: str, typ: str, amax: float,
 
 def _flush_calibration() -> None:
     """Publish pending in-memory amax maxima (called under _lock from
-    ``accumulate`` at level 2). Best-effort: no table location configured
-    means calibration stays in-process."""
+    ``accumulate`` at level 2)."""
     path = table_path()
-    if not path:
-        return
     for fp, pend in _calib.items():
         for (slot, typ), amax in pend.items():
             record_calibration(fp, slot, typ, amax, path=path)

@@ -33,8 +33,9 @@ from . import metrics as _mx
 
 __all__ = ["collect_terms", "attribute", "decompose", "render", "PEAKS"]
 
-# per-chip peaks by device-kind fragment: bf16 FLOP/s (bench.py's
-# _PEAK_BF16 table), HBM GB/s and ICI GB/s per direction (public specs)
+# THE per-chip peak table, by device-kind fragment: bf16 FLOP/s, HBM GB/s
+# and ICI GB/s per direction (public spec sheets; v5e: Google Cloud
+# documentation "TPU v5e"). bench.py and chip_smoke.py read it too.
 PEAKS: Dict[str, Dict[str, float]] = {
     "TPU v3": {"flops": 123e12, "hbm_gbps": 900.0, "ici_gbps": 70.0},
     "TPU v4": {"flops": 275e12, "hbm_gbps": 1200.0, "ici_gbps": 100.0},
@@ -66,16 +67,22 @@ _TERM_BOUND = {"compute_ms": "compute", "memory_ms": "compute",
 
 
 def device_peaks(device_kind: Optional[str] = None) -> Dict[str, float]:
-    """Peak table entry matched by device-kind fragment ({} when unknown
-    — CPU dry runs have no meaningful peak)."""
+    """Peak table entry matched by device-kind fragment. The host CPU has
+    no peak worth the name ({}: a dry run's terms stay None); an
+    accelerator the table does not know is an error, not a default — a
+    utilization against a guessed peak is worse than none."""
     if device_kind is None:
         from .device import raw_device_kind
 
         device_kind = raw_device_kind()
+    kind = (device_kind or "").lower()
     for frag, peaks in PEAKS.items():
-        if frag.lower() in (device_kind or "").lower():
+        if frag.lower() in kind:
             return dict(peaks)
-    return {}
+    if kind in ("", "cpu", "unknown"):
+        return {}
+    raise KeyError("device kind %r is not in monitor.stepstats.PEAKS — add "
+                   "its published peaks there" % device_kind)
 
 
 def _hist_mean(snap: Dict[str, dict], name: str) -> Optional[float]:

@@ -22,17 +22,12 @@ from ..core.registry import OpContext, register_op
 
 @functools.lru_cache(maxsize=1)
 def _flash_fn():
-    try:
-        # project-owned vendored kernels (ops/pallas_kernels/flash_attention
-        # .py) — a JAX upgrade can no longer change the kernels under us
-        from .pallas_kernels.flash_attention import (
-            SegmentIds,
-            flash_attention,
-        )
+    # project-owned vendored kernels (ops/pallas_kernels/flash_attention.py)
+    # — a JAX upgrade can no longer change the kernels under us. A function
+    # so that tests can put a fake in the kernel's place.
+    from .pallas_kernels.flash_attention import SegmentIds, flash_attention
 
-        return flash_attention, SegmentIds
-    except Exception:  # pragma: no cover - pallas unavailable
-        return None, None
+    return flash_attention, SegmentIds
 
 
 def _on_tpu() -> bool:
@@ -159,8 +154,7 @@ def _flash_ok(q, k, causal) -> bool:
     single fused HLO beats the kernel's fixed grid overhead, above it the
     O(S) memory AND the tiling win compound. (The composed path OOMs around
     S~24k single-chip, so flash is also the only viable path there.)"""
-    flash, _ = _flash_fn()
-    if flash is None or not _on_tpu():
+    if not _on_tpu():
         return False
     b, h, sq, d = q.shape
     sk = k.shape[2]

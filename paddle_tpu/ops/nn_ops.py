@@ -54,18 +54,32 @@ def cross_entropy_op(ctx: OpContext):
     )
 
 
-def _fused_xent_ok(logits) -> bool:
-    """Use the Pallas kernel on TPU for 2D+ float logits with a wide vocab
-    (small vocabs gain nothing over the XLA fusion)."""
+def fused_xent_gate(shape, dtype, smooth: float = 0.0,
+                    soft_label: bool = False, ignore_index: int = -100):
+    """None when ``softmax_with_cross_entropy`` takes the Pallas kernel
+    (pallas_kernels/softmax_xent.py) for these logits, else the rule that
+    keeps it on the XLA path — the one decision the op and chip_smoke.py's
+    train phase read. On TPU: hard labels, no ignore_index, 2D+ float
+    logits with a wide vocab (small vocabs gain nothing over the XLA
+    fusion), and no label smoothing over a ragged vocab: measured on v5e
+    (16384×30000 bf16 fwd+bwd) the pad copy makes pallas 92.8ms vs XLA
+    82.7ms — XLA fuses the single-pass smoothing formula just as well."""
     if jax.default_backend() in ("cpu", "gpu"):
-        return False
+        return "the %s backend" % jax.default_backend()
+    if soft_label or ignore_index != -100:
+        return "soft labels or an ignore_index"
+    v = int(shape[-1])
+    if smooth and v % 128:
+        return "label smoothing over a vocab (%d) not a multiple of 128" % v
     from .pallas_kernels import softmax_xent_supported
 
     n = 1
-    for d in logits.shape[:-1]:
+    for d in shape[:-1]:
         n *= int(d)
-    return (logits.ndim >= 2 and logits.shape[-1] >= 4096
-            and softmax_xent_supported(n, logits.shape[-1], logits.dtype))
+    if len(shape) < 2 or v < 4096 or not softmax_xent_supported(n, v, dtype):
+        return "logits %s %s below the kernel's shapes" % (
+            tuple(shape), jnp.dtype(dtype).name)
+    return None
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3))
@@ -125,16 +139,11 @@ def softmax_with_cross_entropy_op(ctx: OpContext):
     soft_label = ctx.attr("soft_label", False)
     smooth = float(ctx.attr("label_smoothing", 0.0) or 0.0)
     out_dtype = logits.dtype
-    if (not soft_label
-            and (not smooth or logits.shape[-1] % 128 == 0)
-            and ctx.attr("ignore_index", -100) == -100
-            and _fused_xent_ok(logits)):
+    if fused_xent_gate(logits.shape, logits.dtype, smooth, soft_label,
+                       ctx.attr("ignore_index", -100)) is None:
         # Pallas fused path (pallas_kernels/softmax_xent.py): forward writes
         # only O(N) outputs; backward computes softmax-onehot (with the
-        # closed-form label-smoothing term) on the fly. Smoothed + ragged
-        # vocab stays on the composed path: measured on v5e (16384×30000
-        # bf16 fwd+bwd) the pad copy makes pallas 92.8ms vs XLA 82.7ms —
-        # XLA fuses the single-pass smoothing formula just as well.
+        # closed-form label-smoothing term) on the fly.
         from .pallas_kernels import fused_softmax_xent
 
         v = logits.shape[-1]

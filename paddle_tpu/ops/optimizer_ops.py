@@ -96,18 +96,36 @@ def _use_alltoall(n_ids, n_shards):
 
 def _kernel_for(param, *moments):
     """(kmode, interpret) when the row-DMA kernel should carry this update
-    — FLAGS gate resolved AND sparse_rows_supported (pltpu importable, f32
-    tables); (None, False) means the scatter formulation."""
-    from .pallas_kernels.sparse_adam import sparse_rows_supported
+    — FLAGS gate resolved AND the kernel's static gate admits the table
+    (f32 tables and moments; compiled, a row width of whole 128-lane
+    tiles); (None, False) means the scatter formulation."""
+    return sparse_update_path(param.shape, param.dtype,
+                              *(t.dtype for t in moments))[:2]
+
+
+def sparse_update_path(shape, dtype, *moment_dtypes):
+    """``(kmode, interpret, why)`` of the sparse-row update for a ``shape``
+    table as ``FLAGS_sparse_update_kernel`` and the kernel's static gate
+    (pallas_kernels.sparse_adam.sparse_rows_gate) resolve it: ``kmode``
+    "compiled"/"interpret" with ``why`` None, or None (the XLA scatter
+    path) with ``why`` the flag's value or the gate's rule."""
+    from .pallas_kernels.sparse_adam import sparse_rows_gate
 
     kmode = _sparse_kernel_mode()
     if kmode is None:
-        return None, False
-    if not sparse_rows_supported(param.shape[0], param.shape[1], param.dtype):
-        return None, False
-    if any(t.dtype != jnp.float32 for t in moments):
-        return None, False
-    return kmode, kmode == "interpret"
+        from ..flags import flags
+
+        return None, False, ("FLAGS_sparse_update_kernel=%s on the %s backend"
+                             % (flags.sparse_update_kernel,
+                                jax.default_backend()))
+    interp = kmode == "interpret"
+    why = sparse_rows_gate(shape[0], shape[1], dtype, interpret=interp)
+    if why is None and any(jnp.dtype(d) != jnp.float32
+                           for d in moment_dtypes):
+        why = "moments are not float32"
+    if why is not None:
+        return None, False, "gate: " + why
+    return kmode, interp, None
 
 
 @register_op("sgd")

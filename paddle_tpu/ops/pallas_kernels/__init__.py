@@ -17,6 +17,7 @@ tested against, and ``benchmarks/bench_softmax_xent.py`` /
 from .softmax_xent import fused_softmax_xent, softmax_xent_supported  # noqa: F401
 from .sparse_adam import (  # noqa: F401
     sparse_adam_rows,
+    sparse_rows_gate,
     sparse_rows_supported,
     sparse_sgd_rows,
 )

@@ -35,30 +35,6 @@ import jax.numpy as jnp
 
 DEFAULT_MASK_VALUE = -0.7 * float(jnp.finfo(jnp.dtype("float32")).max)
 
-# paddle_tpu: JAX renamed TPUCompilerParams <-> CompilerParams across
-# releases; alias whichever this install lacks so the vendored kernels run
-# on both (the container's JAX only has TPUCompilerParams, which broke every
-# pallas_call below at import-version skew — found wiring the autotuner's
-# flash candidate sweep through the interpreter).
-if not hasattr(pltpu, "CompilerParams"):  # pragma: no cover - version skew
-  pltpu.CompilerParams = pltpu.TPUCompilerParams
-
-# paddle_tpu: same skew for pl.loop (absent in this install). The kernels
-# below only use it with STATIC python-int bounds and unroll=True, for which
-# an unrolled python loop over the traced body is semantically identical.
-if not hasattr(pl, "loop"):  # pragma: no cover - version skew
-
-  def _compat_loop(lower, upper, *, step=1, unroll=None):
-    del unroll  # static bounds; python unrolling IS the unrolled form
-
-    def deco(body):
-      for i in range(int(lower), int(upper), int(step)):
-        body(jnp.asarray(i, jnp.int32))
-
-    return deco
-
-  pl.loop = _compat_loop
-
 # paddle_tpu: when True, every pallas_call runs in interpret mode so the
 # REAL kernel bodies execute on CPU — used by tests/test_ring_flash_parity
 # .py to assert flash-vs-composed block parity without TPU hardware.

@@ -8,33 +8,45 @@ context ``[B, max_ctx, H, D]`` in HBM (serving.kv_cache.PagedKVCache
 traffic is ``B * max_ctx * H * D`` elements per layer per step regardless
 of how short the ragged sequences actually are. This kernel fuses the page
 gather into the attention inner loop: K/V pages stream from the flat page
-pool ``[num_pages*page_size, H, D]`` straight into VMEM scratch via
-per-page DMAs driven by the device-resident page table, and an
-online-softmax accumulator reduces them wave by wave — HBM traffic becomes
-``sum_b ctx_len[b] * H * D`` (only the LIVE rows move) and the ``[B,
-max_ctx, H, D]`` intermediate never exists.
+pool straight into VMEM scratch via per-page DMAs driven by the
+device-resident page table, and an online-softmax accumulator reduces them
+wave by wave — HBM traffic becomes ``sum_b ctx_len[b] * H * D`` (only the
+LIVE rows move) and the ``[B, max_ctx, H, D]`` intermediate never exists.
 
-Design (the sparse_adam batched-DMA pattern applied to attention):
+Design:
 
+- the pool is seen as ``[num_pages*page_size, H*D]``: one lane-dense row
+  per context position. The chip's compiler tiles the last two dims of
+  every buffer to (8, 128) and refuses a DMA slice that is not a whole
+  number of tiles, so a ``[rows, H, D]`` view only moves for ``D % 128 ==
+  0`` and ``H % 8 == 0``; the flat view moves for any ``H*D % 128 == 0``
+  (GPT-2 small's 12 heads of 64 included). The static gate
+  :func:`paged_attention_gate` states the rule;
+- per-head reductions over the flat row ride the MXU: ``(k * q) @ seg``
+  sums each head's D lanes (``seg`` is the 0/1 head-membership matrix
+  ``[H*D, 128]``), and ``p @ seg.T`` spreads each head's probability back
+  over its lanes. Both at full f32 precision, so the kernel agrees with
+  the gather path to float round-off;
 - grid is ``(slots,)``; the page table (flattened) and per-slot ``ctx_len``
   ride in SMEM via ``PrefetchScalarGridSpec`` scalar prefetch, so page
   addresses are known before the body runs;
 - per slot, pages stream in waves of ``block_pages`` (the autotunable
   knob, table kernel key ``paged_attention``): each wave starts
   ``2 * block_pages`` row-range DMAs back-to-back (K and V per page), waits
-  once, then folds the wave into the online-softmax state ``(m, l, acc)``
-  carried through the wave loop in registers;
-- the ragged bound: waves whose pages lie entirely at/after ``ctx_len``
-  skip their DMAs (``@pl.when``), and the position mask uses
-  attention_ops.neg_inf — the SAME masking constant as the gather path —
-  so stale rows beyond ``ctx_len`` (retired requests, unreserved pages)
-  contribute exactly 0.0, bit-for-bit like the gather path's mask;
+  once, then folds the wave into the online-softmax state ``(m, l, acc)``;
+- the ragged bound: only the waves that hold a position below ``ctx_len``
+  run, a page entirely at/after ``ctx_len`` skips its DMA, and the position
+  mask uses attention_ops.neg_inf — the SAME masking constant as the gather
+  path — with K and V rows beyond ``ctx_len`` zeroed before use, so stale
+  rows (retired requests, unreserved pages, whatever the scratch last held)
+  contribute exactly 0.0;
 - page ids from the table are clamped to the pool, so a corrupt table row
   degrades to wrong-but-safe reads, never an OOB DMA.
 
 ``interpret=True`` runs the same kernel through the Pallas interpreter on
-CPU — what tier-1 parity tests and the ``--selftest`` CLI use; the
-compiled path needs a real TPU. The engine arms the kernel via
+CPU — what tier-1 parity tests and the ``--selftest`` CLI use (the
+interpreter has no tiling, so it takes any shape); tests/test_chip_compile
+.py compiles it for a described v5e. The engine arms the kernel via
 ``FLAGS_paged_attention_kernel`` (auto = compiled on TPU only; on =
 everywhere, interpreted off-TPU; interpret = force the interpreter; off =
 gather), resolved by attention_ops.paged_kernel_mode and dispatched from
@@ -44,31 +56,54 @@ serving.kv_cache.PagedKVCache.decode_attention.
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
-
-try:  # pallas TPU backend (absent on some CPU-only installs)
-    from jax.experimental.pallas import tpu as pltpu
-except Exception:  # pragma: no cover
-    pltpu = None
+from jax.experimental.pallas import tpu as pltpu
 
 __all__ = [
     "paged_decode_attention",
     "gather_reference",
+    "paged_attention_gate",
     "paged_attention_supported",
 ]
 
+_LANES = 128
+_MAX_ROW_WIDTH = 4096  # H*D: the two [H*D, 128] f32 head maps stay in VMEM
 _VMEM_WAVE_BUDGET = 2 * 1024 * 1024  # K+V scratch bytes one wave may hold
+_HIGHEST = jax.lax.Precision.HIGHEST
 
 
-def paged_attention_supported(dtype) -> bool:
-    """Gate: pallas-TPU importable and a float cache dtype."""
-    if pltpu is None:
-        return False
-    return jnp.issubdtype(jnp.dtype(dtype), jnp.floating)
+def paged_attention_gate(dtype, n_head: int, d_head: int, page_size: int,
+                         interpret: bool = False) -> Optional[str]:
+    """None when the kernel takes this cache geometry, else the rule that
+    excludes it (what ``ServingEngine.decode_kernel_info`` reports when
+    ``auto`` keeps the XLA gather path). The dtype rule always holds; the
+    shape rules are the chip compiler's tiling and do not bind the
+    interpreter."""
+    dt = jnp.dtype(dtype)
+    if dt not in (jnp.dtype(jnp.float32), jnp.dtype(jnp.bfloat16)):
+        return "kv dtype %s is not float32/bfloat16" % dt.name
+    if interpret:
+        return None
+    hd = int(n_head) * int(d_head)
+    if hd % _LANES or hd > _MAX_ROW_WIDTH:
+        return ("n_head*d_head=%d is not a multiple of %d up to %d"
+                % (hd, _LANES, _MAX_ROW_WIDTH))
+    sublanes = 32 // dt.itemsize  # rows per tile: 8 for f32, 16 for bf16
+    if page_size % sublanes:
+        return ("page_size=%d is not a multiple of the %s tile's %d rows"
+                % (page_size, dt.name, sublanes))
+    return None
+
+
+def paged_attention_supported(dtype, n_head: int, d_head: int,
+                              page_size: int, interpret: bool = False) -> bool:
+    return paged_attention_gate(dtype, n_head, d_head, page_size,
+                                interpret) is None
 
 
 def _default_block_pages(page_size: int, pages_per_slot: int, hd: int,
@@ -90,10 +125,14 @@ def _block_pages(block, page_size: int, pages_per_slot: int, max_ctx: int,
     consults the tuned config table (paddle_tpu.tune: kernel
     ``paged_attention``, bucketed by (max_ctx, H*D) + device_kind, with the
     shipped v5e seed) and falls back to the analytic VMEM-budget default —
-    an explicit integer is honored verbatim (clamped to the slot's page
-    count), which keeps the autotuner's own sweep from looping through the
-    table it is writing. The lookup never raises; a corrupt table logs once
-    inside tune.table and lands here as the default."""
+    an explicit integer skips the table, which keeps the autotuner's own
+    sweep from looping through the table it is writing. Either way the
+    result is clamped to the slot's page count and to the widest wave whose
+    f32 working tile ``[block*page_size, H*D]`` stays within the wave
+    budget: a table row is bucketed coarsely and must not hand a wide
+    model more fast memory than the chip compiler grants. The lookup never
+    raises; a corrupt table logs once inside tune.table and lands here as
+    the default."""
     if block is None:
         block = _default_block_pages(page_size, pages_per_slot, hd, itemsize)
         try:
@@ -105,11 +144,12 @@ def _block_pages(block, page_size: int, pages_per_slot: int, max_ctx: int,
                 block = int(cfg["block_pages"])
         except Exception:
             pass
-    return max(1, min(int(block), pages_per_slot))
+    fits = _VMEM_WAVE_BUDGET // (page_size * hd * 4)
+    return max(1, min(int(block), pages_per_slot, fits))
 
 
 def _page_dma(table_ref, scr_ref, sem, row, slot_row, ps):
-    """Async copy of one page (``ps`` contiguous [H, D] rows) between the
+    """Async copy of one page (``ps`` contiguous [H*D] rows) between the
     HBM pool and VMEM scratch."""
     return pltpu.make_async_copy(
         table_ref.at[pl.ds(row, ps)],
@@ -118,16 +158,32 @@ def _page_dma(table_ref, scr_ref, sem, row, slot_row, ps):
     )
 
 
-def _paged_attn_kernel(pt_ref, len_ref, q_ref, k_hbm, v_hbm, o_ref,
-                       k_scr, v_scr, sems, *, block_pages, page_size,
-                       pages_per_slot, num_pages, n_waves, sm_scale,
+def _paged_attn_kernel(pt_ref, len_ref, q_ref, seg_ref, segt_ref, k_hbm,
+                       v_hbm, o_ref, k_scr, v_scr, sems, *, block_pages,
+                       page_size, pages_per_slot, num_pages, sm_scale,
                        mask_value):
     b = pl.program_id(0)
     ps = page_size
     ctx = len_ref[b]
-    q = q_ref[0].astype(jnp.float32) * sm_scale  # [H, D]
-    h, d = q.shape
+    q = q_ref[0].astype(jnp.float32) * sm_scale  # [1, HD]
+    seg = seg_ref[...]    # [HD, HP]: lane j belongs to head seg[j].argmax()
+    segt = segt_ref[...]  # [HP, HD]
+    hd, hp = seg.shape
     rows = block_pages * ps
+
+    def per_head(x):
+        """[R, HD] -> [R, HP]: sum each head's lanes."""
+        return jnp.dot(x, seg, precision=_HIGHEST,
+                       preferred_element_type=jnp.float32)
+
+    def over_lanes(x):
+        """[R, HP] -> [R, HD]: each head's value on all of its lanes."""
+        return jnp.dot(x, segt, precision=_HIGHEST,
+                       preferred_element_type=jnp.float32)
+
+    def row_over_lanes(x):
+        """:func:`over_lanes` of one row, as a full-sublane matmul."""
+        return over_lanes(jnp.broadcast_to(x, (8, hp)))[0:1]
 
     def page_row(i, wave):
         """Pool row offset of wave-local page ``i`` (clamped: a corrupt
@@ -169,29 +225,30 @@ def _paged_attn_kernel(pt_ref, len_ref, q_ref, k_hbm, v_hbm, o_ref,
         # ragged validity mask (also covers never-DMA'd pages: their
         # positions are >= ctx by construction)
         pos = (w * rows
-               + jax.lax.broadcasted_iota(jnp.int32, (1, rows), 1))  # [1,R]
+               + jax.lax.broadcasted_iota(jnp.int32, (rows, 1), 0))  # [R,1]
         valid = pos < ctx
-        kb = k_scr[...].astype(jnp.float32)  # [R, H, D]
-        # invalid rows hold whatever the scratch last held — zero V so the
-        # exactly-0 probabilities below cannot meet an Inf/NaN residue
-        vb = jnp.where(valid.reshape(-1, 1, 1),
-                       v_scr[...].astype(jnp.float32), 0.0)
-        s = jnp.sum(q[None, :, :] * kb, axis=-1).T  # [H, R]
-        s = jnp.where(valid, s, mask_value)
-        m_new = jnp.maximum(m, jnp.max(s, axis=1, keepdims=True))  # [H,1]
+        # invalid rows hold whatever the scratch last held — zero them so
+        # the exactly-0 probabilities below cannot meet an Inf/NaN residue
+        kb = jnp.where(valid, k_scr[...].astype(jnp.float32), 0.0)  # [R,HD]
+        vb = jnp.where(valid, v_scr[...].astype(jnp.float32), 0.0)
+        s = jnp.where(valid, per_head(kb * q), mask_value)  # [R,HP]
+        m_new = jnp.maximum(m, jnp.max(s, axis=0, keepdims=True))  # [1,HP]
         alpha = jnp.exp(m - m_new)
-        p = jnp.exp(s - m_new)  # masked lanes underflow to exactly 0.0
-        l_new = l * alpha + jnp.sum(p, axis=1, keepdims=True)
-        acc_new = acc * alpha + jnp.sum(p.T[:, :, None] * vb, axis=0)
+        p = jnp.exp(s - m_new)  # masked rows underflow to exactly 0.0
+        l_new = l * alpha + jnp.sum(p, axis=0, keepdims=True)
+        acc_new = (acc * row_over_lanes(alpha)
+                   + jnp.sum(over_lanes(p) * vb, axis=0, keepdims=True))
         return m_new, l_new, acc_new
 
-    m0 = jnp.full((h, 1), mask_value, jnp.float32)
-    l0 = jnp.zeros((h, 1), jnp.float32)
-    acc0 = jnp.zeros((h, d), jnp.float32)
-    m, l, acc = jax.lax.fori_loop(0, n_waves, wave_body, (m0, l0, acc0))
+    m0 = jnp.full((1, hp), mask_value, jnp.float32)
+    l0 = jnp.zeros((1, hp), jnp.float32)
+    acc0 = jnp.zeros((1, hd), jnp.float32)
+    n_waves = -(-pages_per_slot // block_pages)
+    live_waves = jnp.minimum((ctx + rows - 1) // rows, n_waves)
+    m, l, acc = jax.lax.fori_loop(0, live_waves, wave_body, (m0, l0, acc0))
     # ctx_len >= 1 in the engine (position of the current token + 1); the
     # clamp only guards a degenerate ctx_len <= 0 call from dividing 0/0
-    out = acc / jnp.maximum(l, jnp.asarray(1e-30, jnp.float32))
+    out = acc / jnp.maximum(row_over_lanes(l), jnp.asarray(1e-30, jnp.float32))
     o_ref[0] = out.astype(o_ref.dtype)
 
 
@@ -209,15 +266,11 @@ def paged_decode_attention(q, k_pages, v_pages, page_table, ctx_len, *,
     analytic VMEM-budget fallback (see ``_block_pages``). Returns [B,H,D],
     matching ``gather_reference`` (the XLA gather + decode_attention path)
     to float32 round-off on live rows and EXACTLY ignoring garbage beyond
-    ``ctx_len``.
+    ``ctx_len``. Compiled (``interpret=False``) it takes the shapes
+    :func:`paged_attention_gate` admits; callers gate on it.
     """
-    if pltpu is None:
-        raise RuntimeError(
-            "paged_decode_attention: jax.experimental.pallas.tpu unavailable "
-            "on this install — gate with paged_attention_supported() (the "
-            "XLA gather path is the fallback, "
-            "FLAGS_paged_attention_kernel=off)")
     b, h, d = q.shape
+    hd = h * d
     slots, pages_per_slot = page_table.shape
     if slots != b:
         raise ValueError("page_table slots %d != q batch %d" % (slots, b))
@@ -227,38 +280,46 @@ def paged_decode_attention(q, k_pages, v_pages, page_table, ctx_len, *,
         raise ValueError("pool rows %d not a multiple of page_size %d"
                          % (num_rows, ps))
     max_ctx = pages_per_slot * ps
-    bp = _block_pages(block_pages, ps, pages_per_slot, max_ctx, h * d,
+    bp = _block_pages(block_pages, ps, pages_per_slot, max_ctx, hd,
                       jnp.dtype(k_pages.dtype).itemsize)
-    n_waves = -(-pages_per_slot // bp)
     from ..attention_ops import neg_inf_value
 
+    # head-membership of the flat row's lanes, padded to a full lane tile
+    # of heads: the padded heads own no lane, so they never reach the output
+    hp = -(-h // _LANES) * _LANES
+    seg = (np.arange(hd)[:, None] // d
+           == np.arange(hp)[None, :]).astype(np.float32)
     kernel = functools.partial(
         _paged_attn_kernel, block_pages=bp, page_size=ps,
         pages_per_slot=pages_per_slot, num_pages=num_rows // ps,
-        n_waves=n_waves, sm_scale=float(sm_scale),
-        mask_value=neg_inf_value(jnp.float32))
+        sm_scale=float(sm_scale), mask_value=neg_inf_value(jnp.float32))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(b,),
         in_specs=[
-            pl.BlockSpec((1, h, d), lambda i, *_: (i, 0, 0)),  # q
-            pl.BlockSpec(memory_space=pltpu.ANY),              # K pool
-            pl.BlockSpec(memory_space=pltpu.ANY),              # V pool
+            pl.BlockSpec((1, 1, hd), lambda i, *_: (i, 0, 0)),  # q
+            pl.BlockSpec((hd, hp), lambda i, *_: (0, 0)),       # seg
+            pl.BlockSpec((hp, hd), lambda i, *_: (0, 0)),       # seg.T
+            pl.BlockSpec(memory_space=pl.ANY),                  # K pool
+            pl.BlockSpec(memory_space=pl.ANY),                  # V pool
         ],
-        out_specs=pl.BlockSpec((1, h, d), lambda i, *_: (i, 0, 0)),
+        out_specs=pl.BlockSpec((1, 1, hd), lambda i, *_: (i, 0, 0)),
         scratch_shapes=[
-            pltpu.VMEM((bp * ps, h, d), k_pages.dtype),
-            pltpu.VMEM((bp * ps, h, d), v_pages.dtype),
+            pltpu.VMEM((bp * ps, hd), k_pages.dtype),
+            pltpu.VMEM((bp * ps, hd), v_pages.dtype),
             pltpu.SemaphoreType.DMA((2, bp)),
         ],
     )
-    return pl.pallas_call(
+    out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, h, d), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((b, 1, hd), q.dtype),
         interpret=interpret,
     )(page_table.reshape(-1).astype(jnp.int32),
-      ctx_len.astype(jnp.int32), q, k_pages, v_pages)
+      ctx_len.astype(jnp.int32), q.reshape(b, 1, hd), jnp.asarray(seg),
+      jnp.asarray(seg.T), k_pages.reshape(num_rows, hd),
+      v_pages.reshape(num_rows, hd))
+    return out.reshape(b, h, d)
 
 
 def gather_reference(q, k_pages, v_pages, page_table, ctx_len, page_size,

@@ -24,11 +24,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-try:  # pallas TPU backend (absent on some CPU-only installs)
-    from jax.experimental.pallas import tpu as pltpu
-except Exception:  # pragma: no cover
-    pltpu = None
+from jax.experimental.pallas import tpu as pltpu
 
 _BN = 256   # batch-tile rows (multiple of 8 for f32 sublanes)
 _BV = 2048  # vocab-tile lanes (multiple of 128)
@@ -94,9 +90,7 @@ def _bwd_kernel(labels_ref, logits_ref, lse_ref, g_ref, dlogits_ref,
 
 
 def softmax_xent_supported(n: int, v: int, dtype) -> bool:
-    """Gate: shapes the kernel tiles cleanly and pallas-TPU is importable."""
-    if pltpu is None:
-        return False
+    """Gate: shapes and dtypes the kernel tiles cleanly."""
     if jnp.dtype(dtype) not in (jnp.dtype(jnp.float32), jnp.dtype(jnp.bfloat16)):
         return False
     return n >= 8 and v >= 128
@@ -164,7 +158,7 @@ def fused_softmax_xent(logits, labels, interpret: bool = False,
 def _call_fwd(logits, labels, bn, bv, interpret, smooth, v_true):
     n, v = logits.shape
     grid = (n // bn, v // bv)
-    acc = lambda: pltpu.VMEM((bn, 1), jnp.float32) if pltpu else None
+    acc = lambda: pltpu.VMEM((bn, 1), jnp.float32)
     return pl.pallas_call(
         functools.partial(_fwd_kernel, smooth=smooth, v_true=v_true),
         grid=grid,
@@ -186,11 +180,6 @@ def _call_fwd(logits, labels, bn, bv, interpret, smooth, v_true):
 
 
 def _fwd(logits, labels, interpret, smooth=0.0):
-    if pltpu is None and not interpret:
-        raise RuntimeError(
-            "fused_softmax_xent: pallas TPU backend unavailable on this "
-            "build — gate calls with softmax_xent_supported() or pass "
-            "interpret=True")
     n, v = logits.shape
     labels = labels.reshape(n, 1)
     plog, plab, bn, bv, n_pad, v_pad = _pad(logits, labels)

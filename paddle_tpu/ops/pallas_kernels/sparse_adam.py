@@ -24,48 +24,65 @@ called for):
 - per step, 3·BLOCK row gathers start back-to-back (one DMA semaphore per
   table×row), so the DMA engines pipeline the tiny 4·D-byte transfers
   instead of serializing on a wait per row;
-- the tables stay unblocked in ``ANY``/HBM memory space and are
+- the tables stay unblocked in ``pl.ANY``/HBM memory space and are
   input/output aliased — untouched rows are never copied;
 - merge padding ids (``core/sparse.merge_rows`` pads with ``id == V``)
   gather row 0 (clamped, read-only harmless) but their writeback is
   predicated off, reproducing XLA's OOB-scatter drop semantics.
 
+Row width: the chip's compiler lays a ``[V, D]`` table out in 128-lane
+tiles and refuses a row DMA that is not a whole number of them (``Slice
+shape along dimension 1 must be aligned to tiling (128)``), so the compiled
+kernel takes ``D % 128 == 0`` only. :func:`sparse_rows_gate` states the
+rule and ``FLAGS_sparse_update_kernel=auto`` keeps narrower tables (DeepFM's
+width 10 and 1) on the XLA scatter path.
+
 ``interpret=True`` runs the same kernel through the Pallas interpreter on
-CPU — that is what tier-1 parity tests and the ``--selftest`` CLI use; the
-compiled path needs a real TPU.
+CPU — that is what tier-1 parity tests and the ``--selftest`` CLI use (the
+interpreter has no tiling, so it takes any width);
+tests/test_chip_compile.py compiles it for a described v5e.
 """
 
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
-
-try:  # pallas TPU backend (absent on some CPU-only installs)
-    from jax.experimental.pallas import tpu as pltpu
-except Exception:  # pragma: no cover
-    pltpu = None
+from jax.experimental.pallas import tpu as pltpu
 
 __all__ = [
     "sparse_adam_rows",
     "sparse_sgd_rows",
+    "sparse_rows_gate",
     "sparse_rows_supported",
 ]
 
 _BLOCK = 128  # ids per grid step = DMAs in flight per gather wave
+_LANES = 128
 
 
-def sparse_rows_supported(vocab: int, dim: int, dtype) -> bool:
-    """Gate: pallas-TPU importable, f32 tables (the CTR workload), and a
-    row shape the DMA path handles."""
-    if pltpu is None:
-        return False
+def sparse_rows_gate(vocab: int, dim: int, dtype,
+                     interpret: bool = False) -> Optional[str]:
+    """None when the kernel takes this table, else the rule that excludes
+    it. f32 tables (the CTR workload) always; compiled, the row width must
+    fill whole 128-lane tiles (see the module docstring) — the interpreter
+    has no tiling and takes any width."""
     if jnp.dtype(dtype) != jnp.dtype(jnp.float32):
-        return False
-    return vocab >= 1 and dim >= 1
+        return "table dtype %s is not float32" % jnp.dtype(dtype).name
+    if vocab < 1 or dim < 1:
+        return "empty table [%d, %d]" % (vocab, dim)
+    if not interpret and dim % _LANES:
+        return "row width %d is not a multiple of %d lanes" % (dim, _LANES)
+    return None
+
+
+def sparse_rows_supported(vocab: int, dim: int, dtype,
+                          interpret: bool = False) -> bool:
+    return sparse_rows_gate(vocab, dim, dtype, interpret) is None
 
 
 def _row_dma(table_ref, scr_ref, sem, row, slot):
@@ -245,12 +262,6 @@ def sparse_adam_rows(param, moment1, moment2, ids, rows, lr_t,
     with the hardcoded 128 fallback (see ``_block_size``). Returns
     (param, m, v) updated.
     """
-    if pltpu is None:
-        # the interpreter still needs the TPU grid-spec/memory-space objects
-        raise RuntimeError(
-            "sparse_adam_rows: jax.experimental.pallas.tpu unavailable on "
-            "this install — gate with sparse_rows_supported() (the scatter "
-            "path is the fallback, FLAGS_sparse_update_kernel=off)")
     vocab, dim = param.shape
     ids = ids.astype(jnp.int32)
     rows = rows.astype(jnp.float32)
@@ -263,15 +274,15 @@ def sparse_adam_rows(param, moment1, moment2, ids, rows, lr_t,
         num_scalar_prefetch=2,
         grid=(n // block,),
         in_specs=[
-            pl.BlockSpec(memory_space=pltpu.ANY),   # param
-            pl.BlockSpec(memory_space=pltpu.ANY),   # moment1
-            pl.BlockSpec(memory_space=pltpu.ANY),   # moment2
+            pl.BlockSpec(memory_space=pl.ANY),   # param
+            pl.BlockSpec(memory_space=pl.ANY),   # moment1
+            pl.BlockSpec(memory_space=pl.ANY),   # moment2
             pl.BlockSpec((block, dim), lambda i, *_: (i, 0)),  # grad rows
         ],
         out_specs=[
-            pl.BlockSpec(memory_space=pltpu.ANY),
-            pl.BlockSpec(memory_space=pltpu.ANY),
-            pl.BlockSpec(memory_space=pltpu.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
         ],
         scratch_shapes=[
             pltpu.VMEM((block, dim), jnp.float32),
@@ -303,11 +314,6 @@ def sparse_sgd_rows(param, ids, rows, lr, interpret: bool = False,
     """One-kernel SGD over merged sparse rows: rows of ``param`` at ``ids``
     get ``-lr·rows``; padded ids (== V) are dropped. ``block=None`` =
     tuned-table lookup (see ``_block_size``). Returns param."""
-    if pltpu is None:
-        raise RuntimeError(
-            "sparse_sgd_rows: jax.experimental.pallas.tpu unavailable on "
-            "this install — gate with sparse_rows_supported() (the scatter "
-            "path is the fallback, FLAGS_sparse_update_kernel=off)")
     vocab, dim = param.shape
     ids = ids.astype(jnp.int32)
     rows = rows.astype(jnp.float32)
@@ -320,10 +326,10 @@ def sparse_sgd_rows(param, ids, rows, lr, interpret: bool = False,
         num_scalar_prefetch=2,
         grid=(n // block,),
         in_specs=[
-            pl.BlockSpec(memory_space=pltpu.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
             pl.BlockSpec((block, dim), lambda i, *_: (i, 0)),
         ],
-        out_specs=[pl.BlockSpec(memory_space=pltpu.ANY)],
+        out_specs=[pl.BlockSpec(memory_space=pl.ANY)],
         scratch_shapes=[
             pltpu.VMEM((block, dim), jnp.float32),
             pltpu.SemaphoreType.DMA((1, block)),

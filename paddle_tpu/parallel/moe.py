@@ -19,7 +19,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from ._compat import shard_map as _shard_map
 
 __all__ = ["switch_moe", "make_switch_ffn"]
 
@@ -70,10 +69,10 @@ def switch_moe(x, gate_w, expert_params, expert_fn: Callable, mesh: Mesh,
         # buf_ arrives [E/n_shards, C, D] for THIS shard's experts
         return jax.vmap(expert_fn)(params, buf_)
 
-    expert_out = _shard_map(
+    expert_out = jax.shard_map(
         shard_body, mesh=mesh,
         in_specs=(jax.tree.map(lambda _: P(axis), expert_params), P(axis)),
-        out_specs=P(axis),
+        out_specs=P(axis), check_vma=False,
     )(expert_params, expert_in)
 
     # combine: gather each token's expert output, weight by its gate prob

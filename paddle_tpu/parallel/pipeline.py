@@ -32,7 +32,6 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
 from ..monitor.device import record_collective as _record_collective
-from ._compat import shard_map as _shard_map
 
 __all__ = ["gpipe", "pipeline_step", "stack_stage_params"]
 
@@ -129,8 +128,9 @@ def gpipe(stage_fn: Callable, mesh: Mesh, axis: str = "pipe"):
             jax.tree.map(lambda _: P(axis), stacked_params),
             P(axis),  # microbatch slabs live with their owner stage
         )
-        out = _shard_map(
+        out = jax.shard_map(
             shard_body, mesh=mesh, in_specs=in_specs, out_specs=P(axis),
+            check_vma=False,
         )(stacked_params, microbatches)
         return out[:m] if mpad != m else out
 

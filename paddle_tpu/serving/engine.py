@@ -560,27 +560,27 @@ class ServingEngine:
         """``(kernel, source)`` of the decode-attention inner loop as THIS
         engine resolves it: ``("paged", <tuned|shipped|default>)`` when the
         ragged paged-attention Pallas kernel is armed
-        (``FLAGS_paged_attention_kernel``, paged layout) — source is the
-        tune-table layer answering its ``block_pages`` lookup, i.e. the
-        provenance the compiled trace saw — else ``("gather", "n/a")``."""
-        from ..ops import attention_ops
+        (``FLAGS_paged_attention_kernel``, paged layout, geometry inside
+        the kernel's static gate) — source is the tune-table layer
+        answering its ``block_pages`` lookup, i.e. the provenance the
+        compiled trace saw — else ``("gather", why)``: "n/a" with the flag
+        off or a dense layout, ``"gate: <rule>"`` for a cache geometry or
+        dtype the kernel's gate excludes."""
+        if not self.cfg.paged:
+            return "gather", "n/a"
+        mode, why_not = self.cache_ops.kernel_mode()
+        if mode is None:
+            return "gather", why_not
+        mcfg = self.model.cfg
+        try:
+            from .. import tune
 
-        if self.cfg.paged and attention_ops.paged_kernel_mode() is not None:
-            from ..ops.pallas_kernels import paged_attention as _pa
-
-            if _pa.paged_attention_supported(self.cache_ops.dtype):
-                mcfg = self.model.cfg
-                try:
-                    from .. import tune
-
-                    _c, src = tune.lookup(
-                        "paged_attention",
-                        tune.bucket_ctx(self.cfg.max_seq,
-                                        mcfg.n_head * mcfg.d_head))
-                except Exception:
-                    src = "default"
-                return "paged", src
-        return "gather", "n/a"
+            _c, src = tune.lookup(
+                "paged_attention",
+                tune.bucket_ctx(self.cfg.max_seq, mcfg.n_head * mcfg.d_head))
+        except Exception:
+            src = "default"
+        return "paged", src
 
     def speculation_info(self) -> tuple:
         """``(k, drafter_kind, source)`` of the speculative fast path as
@@ -1467,8 +1467,8 @@ class ServingEngine:
 
     def warmup(self, buckets: Optional[Sequence[int]] = None) -> None:
         """Pre-compile the decode chunk + the given (default: all) prefill
-        buckets — with PADDLE_TPU_COMPILE_CACHE set this both warms and
-        persists the executables before traffic arrives."""
+        buckets — this both warms the process and persists the
+        executables in the compile cache before traffic arrives."""
         for b in (buckets or self.cfg.prompt_buckets):
             self._get_prefill_exe(self._bucket_for(b))
         self._get_decode_exe(self.cfg.decode_fuse)

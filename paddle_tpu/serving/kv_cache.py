@@ -129,27 +129,48 @@ class PagedKVCache(_KVCacheBase):
         rows = rows.reshape(pt.shape[0], self.max_ctx)
         return state["k"][layer][rows], state["v"][layer][rows]
 
+    def kernel_mode(self):
+        """``(mode, why_not)``: ``mode`` is "compiled"/"interpret" when the
+        ragged paged-attention Pallas kernel carries this cache's decode
+        attention — ``FLAGS_paged_attention_kernel`` armed (see
+        ops.attention_ops.paged_kernel_mode) AND the geometry inside the
+        kernel's static gate — else None, with ``why_not`` "n/a" for a flag
+        that is off and the gate's rule for an excluded shape. The one
+        decision both decode paths and ``ServingEngine.decode_kernel_info``
+        read."""
+        from ..ops import attention_ops
+        from ..ops.pallas_kernels.paged_attention import paged_attention_gate
+
+        mode = attention_ops.paged_kernel_mode()
+        if mode is None:
+            return None, "n/a"
+        why_not = paged_attention_gate(
+            self.dtype, self.n_head, self.d_head, self.page_size,
+            interpret=(mode == "interpret"))
+        if why_not is not None:
+            return None, "gate: " + why_not
+        return mode, None
+
     def decode_attention(self, state: Cache, layer: int, q, ctx_len,
                          sm_scale: float = 1.0) -> jnp.ndarray:
         """One decode-attention step [B,H,D] over this layer's ragged
-        contexts. With ``FLAGS_paged_attention_kernel`` armed (see
-        ops.attention_ops.paged_kernel_mode) the Pallas kernel reads K/V
-        pages straight from the pool via the device-resident page table —
-        the ``[B, max_ctx, H, D]`` gather never materializes; otherwise the
-        XLA gather + ops.attention_ops.decode_attention fallback runs.
-        Both mask positions >= ctx_len with the SAME neg_inf constant, so
-        the paths agree to float round-off (tier-1 parity tests pin it)."""
+        contexts. Where :meth:`kernel_mode` arms it, the Pallas kernel
+        reads K/V pages straight from the pool via the device-resident
+        page table — the ``[B, max_ctx, H, D]`` gather never materializes;
+        otherwise the XLA gather + ops.attention_ops.decode_attention
+        path runs. Both mask positions >= ctx_len with the SAME neg_inf
+        constant, so the paths agree to float round-off (tier-1 parity
+        tests pin it)."""
         from ..ops import attention_ops
 
-        mode = attention_ops.paged_kernel_mode()
+        mode, _ = self.kernel_mode()
         if mode is not None:
             from ..ops.pallas_kernels import paged_attention as _pa
 
-            if _pa.paged_attention_supported(self.dtype):
-                return _pa.paged_decode_attention(
-                    q, state["k"][layer], state["v"][layer], state["pt"],
-                    ctx_len, page_size=self.page_size, sm_scale=sm_scale,
-                    interpret=(mode == "interpret"))
+            return _pa.paged_decode_attention(
+                q, state["k"][layer], state["v"][layer], state["pt"],
+                ctx_len, page_size=self.page_size, sm_scale=sm_scale,
+                interpret=(mode == "interpret"))
         ctx_k, ctx_v = self.context(state, layer)
         return attention_ops.decode_attention(q, ctx_k, ctx_v, ctx_len,
                                               sm_scale=sm_scale)
@@ -168,20 +189,19 @@ class PagedKVCache(_KVCacheBase):
         from ..ops import attention_ops
 
         b, w = q.shape[0], q.shape[1]
-        mode = attention_ops.paged_kernel_mode()
+        mode, _ = self.kernel_mode()
         if mode is not None:
             from ..ops.pallas_kernels import paged_attention as _pa
 
-            if _pa.paged_attention_supported(self.dtype):
-                lens = ctx_len[:, None] + jnp.arange(w)[None, :]
-                lens = jnp.clip(lens.reshape(b * w), 0, self.max_ctx)
-                out = _pa.paged_decode_attention(
-                    q.reshape(b * w, self.n_head, self.d_head),
-                    state["k"][layer], state["v"][layer],
-                    jnp.repeat(state["pt"], w, axis=0), lens,
-                    page_size=self.page_size, sm_scale=sm_scale,
-                    interpret=(mode == "interpret"))
-                return out.reshape(b, w, self.n_head, self.d_head)
+            lens = ctx_len[:, None] + jnp.arange(w)[None, :]
+            lens = jnp.clip(lens.reshape(b * w), 0, self.max_ctx)
+            out = _pa.paged_decode_attention(
+                q.reshape(b * w, self.n_head, self.d_head),
+                state["k"][layer], state["v"][layer],
+                jnp.repeat(state["pt"], w, axis=0), lens,
+                page_size=self.page_size, sm_scale=sm_scale,
+                interpret=(mode == "interpret"))
+            return out.reshape(b, w, self.n_head, self.d_head)
         ctx_k, ctx_v = self.context(state, layer)
         return attention_ops.verify_attention(q, ctx_k, ctx_v, ctx_len,
                                               sm_scale=sm_scale)
@@ -291,11 +311,11 @@ class Int8PagedKVCache(PagedKVCache):
     (resp. quadruples) the page capacity of the same byte budget
     (tools/serve_bench.py asserts the capacity and decode-parity claims).
 
-    ``decode_attention`` always takes the gather path: the ragged Pallas
-    kernel reads raw pool rows and has no dequant stage, so the kernel
-    dispatch is bypassed rather than fed garbage — both decode paths
-    (fused decode scan and prefill-side attention) dequantize through
-    ``context``.
+    ``decode_attention``/``decode_verify`` always take the gather path
+    (``kernel_mode`` says so): the ragged Pallas kernel reads raw pool rows
+    and has no dequant stage, so the kernel dispatch is bypassed rather
+    than fed garbage — both decode paths (fused decode scan and
+    prefill-side attention) dequantize through ``context``.
     """
 
     layout = "paged-int8"
@@ -355,23 +375,8 @@ class Int8PagedKVCache(PagedKVCache):
         return (state["k"][layer][rows].astype(self.dtype) * ks,
                 state["v"][layer][rows].astype(self.dtype) * vs)
 
-    def decode_attention(self, state: Cache, layer: int, q, ctx_len,
-                         sm_scale: float = 1.0) -> jnp.ndarray:
-        from ..ops import attention_ops
-
-        ctx_k, ctx_v = self.context(state, layer)
-        return attention_ops.decode_attention(q, ctx_k, ctx_v, ctx_len,
-                                              sm_scale=sm_scale)
-
-    def decode_verify(self, state: Cache, layer: int, q, ctx_len,
-                      sm_scale: float = 1.0) -> jnp.ndarray:
-        """Gather-only, like ``decode_attention``: the ragged kernel has no
-        dequant stage, so int8 pools always dequantize through ``context``."""
-        from ..ops import attention_ops
-
-        ctx_k, ctx_v = self.context(state, layer)
-        return attention_ops.verify_attention(q, ctx_k, ctx_v, ctx_len,
-                                              sm_scale=sm_scale)
+    def kernel_mode(self):
+        return None, "gate: int8 pool (the kernel has no dequant stage)"
 
     def cache_bytes(self, state: Cache) -> int:
         return int(state["k"].nbytes + state["v"].nbytes

@@ -7,9 +7,10 @@ served to a v4 chip or to a 384-long sequence as if it were universal. Three
 layers answer every lookup, best first:
 
 1. **tuned** — the runtime JSON table written by ``tools/autotune.py`` /
-   :func:`paddle_tpu.tune.search`. Lives next to the persistent XLA compile
-   cache (``<PADDLE_TPU_COMPILE_CACHE>/autotune_table.json``) so tuned
-   configs survive restarts exactly like compiled executables do;
+   :func:`paddle_tpu.tune.search`. Lives in the persistent XLA compile
+   cache's directory (``compile_cache.compile_cache_dir()``, file
+   ``autotune_table.json``) so tuned configs survive restarts exactly like
+   compiled executables do;
    ``PADDLE_TPU_TUNE_TABLE=<file>`` overrides the location.
 2. **shipped** — ``paddle_tpu/tune/shipped.json``, checked into the repo and
    seeded with today's hand-tuned entries (the v5e 512x512 flash BlockSizes
@@ -158,18 +159,18 @@ def bucket_ctx(max_ctx: int, hd: int) -> str:
 # -- file locations -----------------------------------------------------------
 
 
-def table_path() -> Optional[str]:
+def table_path() -> str:
     """Where the runtime (tuned) table lives: ``PADDLE_TPU_TUNE_TABLE``
-    wins; else ``autotune_table.json`` next to the persistent compile cache
-    (``PADDLE_TPU_COMPILE_CACHE``); None when neither is configured —
-    lookups then see only shipped + default."""
+    wins; else ``autotune_table.json`` in the persistent compile cache's
+    directory (``JAX_COMPILATION_CACHE_DIR``, else the checkout's fixed
+    ``.jax_cache``). Until something is recorded there the file does not
+    exist and lookups see only shipped + default."""
     p = os.environ.get("PADDLE_TPU_TUNE_TABLE", "").strip()
     if p:
         return p
     from ..compile_cache import compile_cache_dir
 
-    d = compile_cache_dir()
-    return os.path.join(d, "autotune_table.json") if d else None
+    return os.path.join(compile_cache_dir(), "autotune_table.json")
 
 
 def shipped_path() -> str:
@@ -266,13 +267,10 @@ def write_entries(path: str, entries: Dict[str, dict],
 def record(kernel: str, bucket: str, config: dict, *,
            device: Optional[str] = None, median_ms: Optional[float] = None,
            note: Optional[str] = None,
-           path: Optional[str] = None) -> Optional[str]:
+           path: Optional[str] = None) -> str:
     """Merge one tuned entry into the runtime table (read-modify-write,
-    atomic publish). Returns the table path, or None when no table location
-    is configured (no env var, no compile cache — nothing to persist to)."""
+    atomic publish). Returns the table path."""
     path = path or table_path()
-    if not path:
-        return None
     dev = device or device_kind()
     ent: Dict[str, Any] = {"config": dict(config), "source": "tuned"}
     if median_ms is not None:

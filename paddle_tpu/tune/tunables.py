@@ -220,8 +220,10 @@ class SparseAdamTunable(Tunable):
 
     def default_shapes(self):
         if _on_tpu():
-            return [dict(vocab=1_000_000, dim=64, n=4096),
-                    dict(vocab=1_000_000, dim=64, n=16384)]
+            # the compiled kernel takes whole 128-lane rows only
+            # (sparse_adam.sparse_rows_gate)
+            return [dict(vocab=1_000_000, dim=128, n=4096),
+                    dict(vocab=1_000_000, dim=128, n=16384)]
         return [dict(vocab=512, dim=16, n=256),
                 dict(vocab=2048, dim=16, n=1024)]
 

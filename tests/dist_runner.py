@@ -38,13 +38,6 @@ os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=2")
 
 import jax
-
-jax.config.update("jax_platforms", "cpu")
-from jax._src import xla_bridge as _xb
-
-if _xb.backends_are_initialized():
-    _xb._clear_backends()
-
 import numpy as np
 
 

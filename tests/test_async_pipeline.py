@@ -394,3 +394,59 @@ def test_compile_cache_counters_registered():
     snap = mx.snapshot()
     assert "compile_cache/hit" in snap
     assert "compile_cache/miss" in snap
+
+
+@pytest.mark.parametrize("placed", ["from_outside", "unset"])
+def test_compile_cache_location(placed, monkeypatch, tmp_path):
+    """JAX_COMPILATION_CACHE_DIR set: JAX reads it and the package sets no
+    directory in code. Unset: the one fixed path beside the package, which
+    .gitignore lists."""
+    import os
+
+    import jax
+    from jax import monitoring
+
+    from paddle_tpu import compile_cache as cc
+
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(cc.__file__)))
+    updates = []
+    monkeypatch.setattr(jax.config, "update",
+                        lambda k, v: updates.append((k, v)))
+    monkeypatch.setattr(monitoring, "register_event_listener",
+                        lambda fn: None)  # the real one is already hooked
+    monkeypatch.setattr(cc, "_configured", False)
+    if placed == "from_outside":
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+        cc.setup_compile_cache()
+        assert cc.compile_cache_dir() == str(tmp_path)
+        assert updates == []
+    else:
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        cc.setup_compile_cache()
+        fixed = os.path.join(repo, ".jax_cache")
+        assert cc.compile_cache_dir() == fixed
+        assert updates == [("jax_compilation_cache_dir", fixed)]
+        with open(os.path.join(repo, ".gitignore")) as f:
+            assert ".jax_cache/" in f.read().split()
+
+
+def test_second_step_does_not_compile_again(rng):
+    """The startup program's outputs are uncommitted and the step's own
+    outputs committed; jit specializes on that, so without _place
+    committing the state the SECOND run compiled the whole step again."""
+    from jax import monitoring
+
+    main, startup = fluid.Program(), fluid.Program()
+    with fluid.program_guard(main, startup):
+        loss = _mlp_program()
+    exe = fluid.Executor(fluid.TPUPlace(0))
+    exe.run(startup)
+    feed = _feeds(rng, 1)[0]
+    exe.run(main, feed=feed, fetch_list=[loss])
+    compiles = []
+    monitoring.register_event_duration_secs_listener(
+        lambda event, secs, **kw: compiles.append(event)
+        if event == "/jax/core/compile/backend_compile_duration" else None)
+    for _ in range(3):
+        exe.run(main, feed=feed, fetch_list=[loss])
+    assert compiles == []

@@ -84,11 +84,12 @@ def test_default_on_unknown_device(tuned_table):
 
 
 def test_table_round_trip_cold_cache_dir(tmp_path, monkeypatch):
-    """With only PADDLE_TPU_COMPILE_CACHE set (no explicit table env), the
-    table lands next to the compile cache and survives a 'restart'
-    (fresh read through the mtime-invalidated cache)."""
+    """With no explicit table env, the table lands in the compile cache's
+    directory (JAX_COMPILATION_CACHE_DIR, as the caller placed it) and
+    survives a 'restart' (fresh read through the mtime-invalidated
+    cache)."""
     monkeypatch.delenv("PADDLE_TPU_TUNE_TABLE", raising=False)
-    monkeypatch.setenv("PADDLE_TPU_COMPILE_CACHE", str(tmp_path / "cc"))
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / "cc"))
     path = tune.table_path()
     assert path == os.path.join(str(tmp_path / "cc"), "autotune_table.json")
     assert not os.path.exists(path)  # cold

@@ -69,10 +69,10 @@ def test_fused_softmax_xent_bf16(rng):
 def test_fused_gate_is_tpu_only():
     """On CPU the op must keep the composed XLA path (interpret-mode pallas
     would crawl); the gate also rejects tiny vocabs."""
-    from paddle_tpu.ops.nn_ops import _fused_xent_ok
+    from paddle_tpu.ops.nn_ops import fused_xent_gate
 
     assert jax.default_backend() == "cpu"
-    assert not _fused_xent_ok(jnp.zeros((32, 32768)))
+    assert "cpu" in fused_xent_gate((32, 32768), jnp.float32)
 
 
 # -- flash-attention fallback contract ---------------------------------------

@@ -159,6 +159,24 @@ def test_ragged_vs_padded_full_recompute_logit_parity(rng):
             np.testing.assert_allclose(got, want, rtol=2e-5, atol=2e-5)
 
 
+def test_reference_tokens_is_reference_decode_in_one_pass(rng):
+    """The teacher-forced one-pass check gives reference_decode's verdict:
+    the same tokens for its own output, and a first difference at the same
+    index for an output that strays."""
+    model = get_model()
+    for prompt, n_new in make_stream(1, rng):
+        want, _ = decoder_lm.reference_decode(model.params, model.cfg,
+                                              prompt, n_new)
+        assert decoder_lm.reference_tokens(model.params, model.cfg, prompt,
+                                           want) == want
+        strayed = list(want)
+        strayed[n_new // 2] = (strayed[n_new // 2] + 1) % model.cfg.vocab_size
+        got = decoder_lm.reference_tokens(model.params, model.cfg, prompt,
+                                          strayed)
+        first = next(i for i, (a, b) in enumerate(zip(got, strayed)) if a != b)
+        assert first == n_new // 2 and got[:first] == want[:first]
+
+
 def test_decode_fuse_token_parity(rng):
     """Fusing k decode steps into one dispatched scan (the run_steps
     analog) must not change any emitted token."""

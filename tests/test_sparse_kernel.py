@@ -136,8 +136,13 @@ def test_end_to_end_kernel_vs_scatter(rng, opt):
     l_k, p_k = results["interpret"]
     np.testing.assert_allclose(l_ref, l_k, rtol=1e-4)
     assert set(p_ref) == set(p_k)
+    # Adam's step is lr * m / (sqrt(v) + eps): where a merged gradient row
+    # nearly cancels, the two paths' summation orders move m / sqrt(v) in
+    # its fourth digit, i.e. a parameter by lr * 1e-4 = 5e-6 at Adam's
+    # lr = 0.05 (seen: one element in 2000 off by 1.04e-6). SGD is linear
+    # in the gradient and sits far inside the same bound.
     for n in p_ref:
-        np.testing.assert_allclose(p_ref[n], p_k[n], rtol=1e-4, atol=1e-6,
+        np.testing.assert_allclose(p_ref[n], p_k[n], rtol=1e-4, atol=5e-6,
                                    err_msg=n)
 
 

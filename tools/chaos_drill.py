@@ -501,12 +501,6 @@ def drill_fleet(tmp) -> None:
 def selftest() -> int:
     t0 = time.perf_counter()
     with tempfile.TemporaryDirectory() as tmp:
-        # the drills deliberately rebuild programs/engines ("restarted
-        # process" twins) — identical HLO each time, so the persistent
-        # compile cache collapses the repeat compiles and keeps the gate
-        # under budget (and exercises the restart-skips-compile story)
-        os.environ.setdefault("PADDLE_TPU_COMPILE_CACHE",
-                              os.path.join(tmp, "xla_cache"))
         # self-heal first: its hex-identity assert is the tightest
         # determinism gate in the suite (it caught the donated-alias
         # state-buffer corruption fixed in executor._place — keep it the

@@ -54,10 +54,12 @@ BUDGETS = {
     "profile_report": 15.0,
     "serve_bench": 75.0,   # speculative leg + its repetitive-stream drill
     "fleet_bench": 75.0,  # + disagg QPS, remote-hit, and kill-migration legs
-    "chaos_drill": 30.0,
+    # its restarted-process twins compile cold: JAX's own thresholds keep
+    # sub-second CPU executables out of the persistent cache
+    "chaos_drill": 75.0,
     "fleet_trace": 10.0,
     "fleet_autopsy": 10.0,
-    "autotune": 15.0,
+    "autotune": 20.0,  # two interpret-mode kernel micro-sweeps dominate
     "check_budgets": 10.0,
     "perf_gate": 10.0,
     "numerics_report": 15.0,

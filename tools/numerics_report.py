@@ -74,9 +74,6 @@ def render_table(path=None) -> str:
     from paddle_tpu.monitor import numerics
 
     path = path or numerics.table_path()
-    if not path:
-        return "(no calibration table configured: set " \
-               "PADDLE_TPU_NUMERICS_TABLE or PADDLE_TPU_COMPILE_CACHE)"
     entries = numerics.read_calibration(path)
     if not entries:
         return "%s: absent, corrupt or empty" % path

@@ -2,14 +2,13 @@
 
 Builds a named model's train program and ahead-of-time compiles its step
 via ``Executor.prepare`` — ``jax.jit(...).lower().compile()`` — WITHOUT
-running a single step. With ``PADDLE_TPU_COMPILE_CACHE=<dir>`` set (see
-``paddle_tpu/compile_cache.py``), the XLA executable lands in the
-persistent on-disk cache, so the real training/bench job that follows (same
-program, same shapes, same jaxlib) starts with a cache hit instead of a
-multi-minute compile.
+running a single step. The XLA executable lands in the persistent on-disk
+cache (``paddle_tpu/compile_cache.py``: ``JAX_COMPILATION_CACHE_DIR``, else
+``<checkout>/.jax_cache``), so the real training/bench job that follows
+(same program, same shapes, same jaxlib, same cache directory) starts with
+a cache hit instead of a multi-minute compile.
 
-    PADDLE_TPU_COMPILE_CACHE=/var/cache/xla \\
-        python -m tools.warmup --model transformer --batch 64 --seq 256
+    python -m tools.warmup --model transformer --batch 64 --seq 256
 
     python -m tools.warmup --model mlp          # CPU smoke (<5s)
 
@@ -132,7 +131,7 @@ def main(argv=None) -> int:
     p = argparse.ArgumentParser(
         prog="tools.warmup",
         description="AOT-compile a model's train step into the persistent "
-                    "XLA compile cache (PADDLE_TPU_COMPILE_CACHE).")
+                    "XLA compile cache (paddle_tpu/compile_cache.py).")
     p.add_argument("--model", choices=sorted(BUILDERS), default="mlp")
     p.add_argument("--batch", type=int, default=64)
     p.add_argument("--seq", type=int, default=256)
@@ -146,11 +145,6 @@ def main(argv=None) -> int:
 
     import paddle_tpu as fluid
     from paddle_tpu import compile_cache, monitor
-
-    if not compile_cache.is_configured():
-        print("warning: PADDLE_TPU_COMPILE_CACHE is not set — compiling "
-              "without a persistent cache (warmup is then pointless)",
-              file=sys.stderr)
 
     with fluid.unique_name.guard():
         with fluid.scope_guard(fluid.Scope()):
@@ -166,9 +160,8 @@ def main(argv=None) -> int:
     snap = monitor.snapshot()
     hits = int(snap["compile_cache/hit"]["value"])
     misses = int(snap["compile_cache/miss"]["value"])
-    print("warmup[%s]: AOT compile %.2fs  compile_cache hit=%d miss=%d%s"
-          % (args.model, dt, hits, misses,
-             "" if compile_cache.is_configured() else "  (cache OFF)"))
+    print("warmup[%s]: AOT compile %.2fs  compile_cache hit=%d miss=%d  (%s)"
+          % (args.model, dt, hits, misses, compile_cache.compile_cache_dir()))
     return 0
 
 
