@@ -16,7 +16,6 @@ requirement), and scope handling mirror ``python/paddle/fluid/executor.py``.
 
 from __future__ import annotations
 
-import contextlib
 import functools
 import time
 from typing import Any, Dict, List, Optional, Sequence, Tuple
@@ -128,9 +127,6 @@ def _update_hbm_gauges() -> None:
             _m_hbm_limit.set(limit)
     except Exception:
         _mem_stats_ok = False
-
-
-_NULL_CTX = contextlib.nullcontext()
 
 
 def _nbytes(arrays) -> int:
@@ -959,6 +955,17 @@ class Executor:
     ):
         if program is None:
             program = default_main_program()
+        # the profiler's own step view: one marker a run, numbered by the
+        # program's step index; executor/run and its children lie inside it
+        with jax.profiler.StepTraceAnnotation(
+                "train", step_num=getattr(program, "_tpu_step_counter", 0)), \
+                _tr.span("executor/run", cat="executor"):
+            return self._run_step(program, feed, fetch_list, scope,
+                                  return_numpy, use_program_cache, mesh,
+                                  accumulation_steps)
+
+    def _run_step(self, program, feed, fetch_list, scope, return_numpy,
+                  use_program_cache, mesh, accumulation_steps):
         if scope is None:
             scope = global_scope()
         feed = dict(feed or {})
@@ -986,19 +993,21 @@ class Executor:
         src_program = program
         program = self._maybe_optimize(program, fetch_names, scope)
 
-        # hot-path guards read the module flags directly: with metrics and
-        # tracing both off, the whole observability layer costs these two
-        # attribute loads + branches per run — no lock, no allocation
+        # the hot-path guard reads the module flag directly: with metrics
+        # off the registry costs this attribute load + branch per run; the
+        # spans cost their annotation (well under a microsecond each)
         mx_on = _mx._enabled
-        tr_on = _tr._active
 
-        plan, feeds, state, was_miss = self._resolve_plan(
-            program, feed, fetch_names, scope, mesh, accumulation_steps,
-            mx_on, tr_on, use_program_cache)
+        with _tr.span("executor/plan", cat="executor"):
+            plan, feeds, state, was_miss = self._resolve_plan(
+                program, feed, fetch_names, scope, mesh, accumulation_steps,
+                mx_on, use_program_cache)
         compiled = plan.compiled
 
         rng_key = self._next_step_index(src_program)
-        state, feeds = self._place(plan, state, feeds, mesh)
+        # the hand-over of the feeds to the device(s)
+        with _tr.span("executor/place", cat="executor"):
+            state, feeds = self._place(plan, state, feeds, mesh)
         fr = _dev.flight_recorder()  # None unless PADDLE_TPU_FLIGHT_DIR set
         if fr is not None:
             # fingerprint the SOURCE program (the one the user can inspect;
@@ -1012,11 +1021,8 @@ class Executor:
             spec = _faults.fire("executor.dispatch")
             if spec is not None and spec.kind == "nan":
                 feeds = _faults.poison_feeds(feeds)
-            if tr_on:
-                with _tr.span("executor/compile_and_step" if was_miss
-                              else "executor/step", cat="executor"):
-                    new_state, fetches = compiled(state, feeds, rng_key)
-            else:
+            with _tr.span("executor/compile_and_step" if was_miss
+                          else "executor/step", cat="executor"):
                 new_state, fetches = compiled(state, feeds, rng_key)
             if mx_on:
                 # A cache-miss first call pays jit trace + XLA compile;
@@ -1063,12 +1069,13 @@ class Executor:
             # would leave the scope pointing at deleted arrays — writing the
             # (possibly non-finite) state keeps a watchdog failure
             # recoverable/inspectable, mirroring run_steps' finally-flush
-            for n, v in new_state.items():
-                if v is not None:
-                    scope.set_var(n, v)
-            if mask is not None:
-                _dev.check_numerics_mask(mask, compiled.watch_layout)
-            _enforce_step_flags(fetch_names, fetches, new_state)
+            with _tr.span("executor/writeback", cat="executor"):
+                for n, v in new_state.items():
+                    if v is not None:
+                        scope.set_var(n, v)
+                if mask is not None:
+                    _dev.check_numerics_mask(mask, compiled.watch_layout)
+                _enforce_step_flags(fetch_names, fetches, new_state)
         except Exception as e:
             if fr is None:
                 fr = _dev.flight_recorder()
@@ -1088,7 +1095,7 @@ class Executor:
 
     # -- dispatch-plan machinery ----------------------------------------------
     def _resolve_plan(self, program, feed, fetch_names, scope, mesh,
-                      accumulation_steps, mx_on, tr_on, use_program_cache,
+                      accumulation_steps, mx_on, use_program_cache,
                       sample_stats=True):
         """(plan, canonical feeds, state, was_compile_miss) for this run.
 
@@ -1221,8 +1228,7 @@ class Executor:
             t_build = time.perf_counter() if mx_on else 0.0
             with _tr.span("executor/trace_setup", cat="executor",
                           args={"program_version": program._version,
-                                "n_feeds": len(feed_sig)}) if tr_on \
-                    else _NULL_CTX:
+                                "n_feeds": len(feed_sig)}):
                 compiled = _CompiledStep(
                     program,
                     feed_names,
@@ -1454,7 +1460,6 @@ class Executor:
             return tuple(sig), (conv if conv is not None else f)
 
         mx_on = _mx._enabled
-        tr_on = _tr._active
         fr = _dev.flight_recorder()  # None unless PADDLE_TPU_FLIGHT_DIR set
         rows: List[Any] = []      # return_numpy=True: one row per step
         handles: List[FetchHandle] = []  # else: one handle per fused chunk
@@ -1531,7 +1536,7 @@ class Executor:
                     # observed (one fused chunk is one EMA tick already)
                     plan, feeds0, state, chunk_was_miss = self._resolve_plan(
                         program, chunk[0], fetch_names, scope, mesh,
-                        accumulation_steps, mx_on, tr_on, True,
+                        accumulation_steps, mx_on, True,
                         sample_stats=False)
                     chunk_feeds = [feeds0]
                     chunk_feeds += [self._canon_chunk_feed(plan, f)
@@ -1563,11 +1568,10 @@ class Executor:
                 if spec is not None and spec.kind == "nan":
                     stacked = _faults.poison_feeds(stacked)
                 t0 = time.perf_counter() if mx_on else 0.0
-                if tr_on:
-                    with _tr.span("executor/run_steps_chunk", cat="executor",
-                                  args={"steps": n}):
-                        state, fetches = compiled(state, stacked, step_idx0)
-                else:
+                with jax.profiler.StepTraceAnnotation(
+                        "train", step_num=int(step_idx0)), \
+                        _tr.span("executor/run_steps_chunk", cat="executor",
+                                 args={"steps": n}):
                     state, fetches = compiled(state, stacked, step_idx0)
                 if mx_on:
                     # a fresh specialization/chain pays its jit trace + XLA
@@ -1730,7 +1734,7 @@ class Executor:
         # run() at the same shapes share one plan + specialization entry
         plan, _, state, _ = self._resolve_plan(
             program, abstract, fetch_names, scope, mesh, accumulation_steps,
-            _mx._enabled, _tr._active, True)
+            _mx._enabled, True)
         compiled = plan.compiled
         if not compiled.jitted:
             return compiled

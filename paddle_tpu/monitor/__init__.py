@@ -11,9 +11,9 @@ TPU-native in three pieces:
   everything as a dict, ``monitor.to_text()`` as a table.
 * :mod:`~paddle_tpu.monitor.tracer` — nested host wall-clock spans with
   Chrome-trace/Perfetto export. ``PADDLE_TPU_TRACE_FILE=/tmp/t.json``
-  records for the whole process and writes the trace at exit; it composes
-  with the ``jax.profiler`` device trace via ``profiler.record_event`` /
-  ``span(..., device=True)``.
+  records for the whole process and writes the trace at exit; every
+  ``span`` (and ``profiler.record_event``) is also a
+  ``jax.profiler.TraceAnnotation``, so a device trace shows the same names.
 * :mod:`~paddle_tpu.monitor.step_logger` — ``StepLogger``, the periodic
   throughput/step-time/loss line emitter used by ``bench.py`` and
   ``train/``; its ``summary()`` is the ``metrics`` section of bench JSON.

@@ -4,15 +4,16 @@ The role the reference splits between ``platform/profiler.cc`` RecordEvent
 and ``tools/timeline.py`` (CUPTI → chrome://tracing converter): record
 named, nested host spans with microsecond timestamps and export them as a
 ``chrome://tracing`` / Perfetto-loadable JSON — no TensorBoard required.
-It composes with the existing ``jax.profiler`` device trace: spans opened
-with ``device=True`` (and ``profiler.record_event``) also enter a
-``jax.profiler.TraceAnnotation`` so the same name shows up in the XLA
-device timeline when one is being captured.
+There is one kind of span: every :class:`span` (and so
+``profiler.record_event``) also enters a ``jax.profiler.TraceAnnotation``,
+so the same name shows up in the host plane of any ``jax.profiler`` capture,
+on the device trace's clock, whether or not this tracer is recording.
 
 Activation: ``start_tracing()`` explicitly, or set ``PADDLE_TPU_TRACE_FILE``
 — tracing then starts at import and the Chrome trace is written to that
-path at interpreter exit. Hot paths guard on ``active()`` (a single module
-bool read) so an idle tracer costs one branch.
+path at interpreter exit. An idle tracer costs a span its annotation and two
+clock reads; callers that build records of their own (``serving/trace.py``)
+guard on ``active()``, a single module bool read.
 
 Two file formats:
 
@@ -26,12 +27,13 @@ Two file formats:
 from __future__ import annotations
 
 import atexit
-import contextlib
 import json
 import os
 import threading
 import time
 from typing import Any, Dict, List, Optional
+
+from jax.profiler import TraceAnnotation
 
 __all__ = [
     "span", "start_tracing", "stop_tracing", "active", "get_spans",
@@ -58,7 +60,7 @@ except ValueError:
 _active: bool = False
 _spans: List[Dict[str, Any]] = []
 _spans_lock = threading.Lock()
-_tls = threading.local()  # per-thread nesting depth
+_tls = threading.local()  # per-thread stack of open span names
 _trace_file: Optional[str] = None
 
 # Virtual tracks: named synthetic (pid, tid) rows for spans whose natural
@@ -119,7 +121,8 @@ def clear_spans() -> None:
 
 
 def _record(name: str, cat: str, t0_us: int, dur_us: int,
-            args: Optional[dict], depth: int = 0) -> None:
+            args: Optional[dict], depth: int = 0,
+            parent: Optional[str] = None) -> None:
     rec = {
         "name": name,
         "cat": cat,
@@ -129,6 +132,8 @@ def _record(name: str, cat: str, t0_us: int, dur_us: int,
         "tid": threading.get_ident(),
         "depth": depth,
     }
+    if parent is not None:
+        rec["parent"] = parent
     if args:
         rec["args"] = args
     global _dropped
@@ -148,41 +153,54 @@ def _record(name: str, cat: str, t0_us: int, dur_us: int,
             "with start_tracing()/stop_tracing()", _max_spans)
 
 
-@contextlib.contextmanager
-def span(name: str, cat: str = "host", args: Optional[dict] = None,
-         device: bool = False):
-    """Record a nested wall-clock span.
+class span:
+    """One named span, two destinations.
 
-    ``device=True`` additionally enters ``jax.profiler.TraceAnnotation`` so
-    the span lands in an active XLA device trace too (the record_event
-    composition). Nesting is implicit — Chrome's trace viewer stacks
-    overlapping complete events per (pid, tid) by time containment.
+    It always enters ``jax.profiler.TraceAnnotation(name, **args)``, so any
+    ``jax.profiler`` capture shows it in the host plane under its plain
+    name, on the device trace's clock (the annotation costs well under a
+    microsecond when no profile runs). While the host tracer is active it
+    is also recorded in memory: name, start, duration, nesting depth and
+    ``parent`` (the span that contains it on the same thread); ``args``
+    ride along (spans of one request share its ``trace_id``).
+
+    ``t0`` and ``t1`` are the span's own two ``time.perf_counter`` reads,
+    for a caller that feeds a histogram from the same interval.
     """
-    if not _active and not device:
-        yield
-        return
-    ann = None
-    if device:
-        try:
-            import jax
 
-            ann = jax.profiler.TraceAnnotation(name)
-            ann.__enter__()
-        except Exception:
-            ann = None
-    depth = getattr(_tls, "depth", 0)
-    _tls.depth = depth + 1
-    t0 = time.perf_counter_ns()
-    try:
-        yield
-    finally:
-        dur = time.perf_counter_ns() - t0
-        _tls.depth = depth
-        if ann is not None:
-            ann.__exit__(None, None, None)
+    __slots__ = ("name", "cat", "args", "t0", "t1", "_ann", "_stack")
+
+    def __init__(self, name: str, cat: str = "host",
+                 args: Optional[dict] = None):
+        self.name = name
+        self.cat = cat
+        self.args = args
+        self.t0 = self.t1 = 0.0
+
+    def __enter__(self) -> "span":
+        self._ann = TraceAnnotation(self.name, **(self.args or {}))
+        self._ann.__enter__()
+        self._stack = None
         if _active:
-            _record(name, cat, t0 // 1000 + _skew_us, max(1, dur // 1000),
-                    args, depth)
+            stack = getattr(_tls, "stack", None)
+            if stack is None:
+                stack = _tls.stack = []
+            stack.append(self.name)
+            self._stack = stack
+        self.t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc) -> bool:
+        self.t1 = time.perf_counter()
+        self._ann.__exit__(*exc)
+        stack = self._stack
+        if stack is not None:
+            stack.pop()
+            if _active:
+                _record(self.name, self.cat, int(self.t0 * 1e6) + _skew_us,
+                        max(1, int((self.t1 - self.t0) * 1e6)), self.args,
+                        len(stack), stack[-1] if stack else None)
+        return False
 
 
 def instant(name: str, cat: str = "host", args: Optional[dict] = None) -> None:

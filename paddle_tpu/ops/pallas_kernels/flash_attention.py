@@ -848,6 +848,7 @@ def _flash_attention_impl(
       out_shape=out_shape,
       debug=debug,
       interpret=INTERPRET,  # paddle_tpu
+      name="flash_attention_fwd",  # paddle_tpu
       compiler_params=pltpu.CompilerParams(
           dimension_semantics=(
               "parallel",
@@ -1238,6 +1239,7 @@ def _flash_attention_bwd_dkv(
         out_shape=out_shapes,
         debug=debug,
         interpret=INTERPRET,  # paddle_tpu
+        name="flash_attention_bwd_dkv",  # paddle_tpu
         compiler_params=pltpu.CompilerParams(
                 dimension_semantics=(
                     "parallel",
@@ -1592,6 +1594,7 @@ def _flash_attention_bwd_dq(
         out_shape=out_shapes,
         debug=debug,
         interpret=INTERPRET,  # paddle_tpu
+        name="flash_attention_bwd_dq",  # paddle_tpu
         compiler_params=pltpu.CompilerParams(
                 dimension_semantics=(
                     "parallel",

@@ -315,6 +315,7 @@ def paged_decode_attention(q, k_pages, v_pages, page_table, ctx_len, *,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, 1, hd), q.dtype),
         interpret=interpret,
+        name="paged_attention",
     )(page_table.reshape(-1).astype(jnp.int32),
       ctx_len.astype(jnp.int32), q.reshape(b, 1, hd), jnp.asarray(seg),
       jnp.asarray(seg.T), k_pages.reshape(num_rows, hd),

@@ -176,6 +176,7 @@ def _call_fwd(logits, labels, bn, bv, interpret, smooth, v_true):
         ],
         scratch_shapes=[acc(), acc(), acc()],
         interpret=interpret,
+        name="softmax_xent_fwd",
     )(labels, logits)
 
 
@@ -217,6 +218,7 @@ def _fused_bwd(interpret, smooth, res, g):
         out_specs=pl.BlockSpec((bn, bv), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((pn, pv), logits.dtype),
         interpret=interpret,
+        name="softmax_xent_bwd",
     )(plab, plog, lse, g)
     if n_pad or v_pad:
         dlogits = dlogits[:n, :v]

@@ -306,6 +306,7 @@ def sparse_adam_rows(param, moment1, moment2, ids, rows, lr_t,
         # m(3) v(4) rows(5)
         input_output_aliases={2: 0, 3: 1, 4: 2},
         interpret=interpret,
+        name="sparse_adam_rows",
     )(ids, scal, param, moment1, moment2, rows)
 
 
@@ -342,6 +343,7 @@ def sparse_sgd_rows(param, ids, rows, lr, interpret: bool = False,
         out_shape=[jax.ShapeDtypeStruct(param.shape, param.dtype)],
         input_output_aliases={2: 0},  # ids(0) scal(1) p(2) rows(3)
         interpret=interpret,
+        name="sparse_sgd_rows",
     )(ids, scal, param, rows)
     return out
 

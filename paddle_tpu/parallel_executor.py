@@ -53,13 +53,10 @@ class ParallelExecutor:
         if feed is None:
             feed = feed_dict
         _m_runs.inc()
-        if _tr._active:
-            with _tr.span("parallel_executor/run", cat="executor"):
-                return self._exe.run(self._compiled, feed=feed,
-                                     fetch_list=fetch_list, scope=self._scope,
-                                     return_numpy=return_numpy)
-        return self._exe.run(self._compiled, feed=feed, fetch_list=fetch_list,
-                             scope=self._scope, return_numpy=return_numpy)
+        with _tr.span("parallel_executor/run", cat="executor"):
+            return self._exe.run(self._compiled, feed=feed,
+                                 fetch_list=fetch_list, scope=self._scope,
+                                 return_numpy=return_numpy)
 
     @property
     def device_count(self) -> int:

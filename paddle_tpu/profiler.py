@@ -71,7 +71,7 @@ def record_event(name: str):
     export."""
     from .monitor import tracer as _tr
 
-    with _tr.span(name, cat="user", device=True):
+    with _tr.span(name, cat="user"):
         yield
 
 

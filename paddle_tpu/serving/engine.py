@@ -32,7 +32,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..executor import _safe_flight_dump, aot_compile
-from ..monitor import device as _dev, slo as _slo, telemetry as _telemetry
+from ..monitor import (device as _dev, slo as _slo, telemetry as _telemetry,
+                       tracer as _tr)
 from ..reliability import faults as _faults
 from . import metrics as _sm
 from . import speculative as _speculative
@@ -42,6 +43,13 @@ from .page_pool import PagePool, PagePoolExhausted
 from .request import (FAILED, FINISHED, REJECTED, TIMEOUT, DrainingError,
                       Request)
 from .scheduler import Scheduler
+
+
+def _span(name: str, **args) -> _tr.span:
+    """A span on the engine's thread (README: the program's spans). The
+    category keeps it apart from the per-request tracks of serving/trace.py,
+    which read the same list by ``cat``."""
+    return _tr.span(name, cat="engine", args=args or None)
 
 __all__ = ["ServingConfig", "ServingEngine"]
 
@@ -326,6 +334,7 @@ class ServingEngine:
         # process-wide; a fleet replica's health doc needs its own)
         self._prefills = 0
         self._resumes = 0
+        self._cycles = 0
         self._last_error: Optional[str] = None
         self._closed = False
         self._draining = False
@@ -459,20 +468,24 @@ class ServingEngine:
         slots, prefill the admissions, then one fused decode dispatch.
         Returns requests that reached a terminal state during the cycle
         (FINISHED, TIMEOUT or FAILED — check ``req.state``)."""
-        finished = self._expire_deadlines()
-        finished.extend(self._admit())
-        if self.scheduler.occupancy:
-            finished.extend(self._decode_dispatch())
+        self._cycles += 1
+        _sm.CYCLES.inc()
+        sched = self.scheduler
+        with _span("serving/step", cycle=self._cycles,
+                   occupancy=sched.occupancy, queue=sched.queue_depth):
+            with _span("serving/expire"):
+                finished = self._expire_deadlines()
+            with _span("serving/admit"):
+                finished.extend(self._admit())
+            if sched.occupancy:
+                finished.extend(self._decode_dispatch())
         return finished
 
     def run(self, max_steps: Optional[int] = None) -> List[Request]:
         """Drive :meth:`step` until queue and slots drain (or ``max_steps``).
-        Updates the ``serving/tokens_per_sec`` gauge over the drive. A
-        :meth:`request_drain` arriving mid-drive (a SIGTERM handler) flips
+        A :meth:`request_drain` arriving mid-drive (a SIGTERM handler) flips
         the loop into :meth:`drain`: in-flight requests finish, queued
         ones are shed, the engine closes."""
-        t0 = time.perf_counter()
-        tok0 = _sm.TOKENS_GENERATED.value
         done: List[Request] = []
         steps = 0
         while not self.scheduler.idle():
@@ -483,9 +496,6 @@ class ServingEngine:
                 break
             done.extend(self.step())
             steps += 1
-        dt = time.perf_counter() - t0
-        if dt > 0:
-            _sm.TOKENS_PER_SEC.set((_sm.TOKENS_GENERATED.value - tok0) / dt)
         return done
 
     def request_drain(self) -> None:
@@ -805,33 +815,40 @@ class ServingEngine:
         skips the full prefill: its pages are row-copied and only the
         remainder runs (the resume executable)."""
         cfg = self.cfg
+        entry = None
         if self.prefix_cache is not None:
             entry = self.prefix_cache.lookup(req.prompt)
+        with _span("serving/prefill", trace_id=req.trace_id, slot=slot,
+                   bucket=bucket, cause="local" if entry is None else "resume"):
             if entry is not None:
                 return self._prefill_from_prefix(req, slot, entry)
-        prompt = np.full((bucket,), cfg.pad_id, np.int32)
-        prompt[:req.prompt_len] = req.prompt
-        if cfg.paged:
-            dest_np = self.cache_ops.prompt_dest(req.pages)
-            dest = jnp.asarray(dest_np)
-            self._cache["pt"] = self._cache["pt"].at[slot].set(dest)
-        else:
-            dest = jnp.asarray(self.cache_ops.prompt_dest(slot))
-        exe = self._get_prefill_exe(bucket)
-        t0 = time.perf_counter()
-        self._cache, first_tok, last_logits = exe(
-            self.params, self._cache, dest, jnp.asarray(prompt),
-            jnp.asarray(req.prompt_len, jnp.int32),
-            jnp.asarray(req.temperature, jnp.float32),
-            jnp.asarray(req.top_k, jnp.int32),
-            jnp.asarray(req.seed, jnp.int32))
-        tok = int(np.asarray(first_tok))
-        t1 = time.perf_counter()
-        _trace.on_prefill(req, slot, bucket, t0, t1, cause="local")
-        _sm.PREFILL_MS.observe((t1 - t0) * 1e3)
-        _sm.PREFILL_COUNT.inc()
-        self._prefills += 1
-        return self._finish_prefill(req, slot, tok, last_logits)
+            with _span("serving/prefill.launch"):
+                prompt = np.full((bucket,), cfg.pad_id, np.int32)
+                prompt[:req.prompt_len] = req.prompt
+                if cfg.paged:
+                    dest_np = self.cache_ops.prompt_dest(req.pages)
+                    dest = jnp.asarray(dest_np)
+                    self._cache["pt"] = self._cache["pt"].at[slot].set(dest)
+                else:
+                    dest = jnp.asarray(self.cache_ops.prompt_dest(slot))
+                exe = self._get_prefill_exe(bucket)
+                # serving/prefill_ms starts here, as it always has: at the
+                # transfers of the executable's own arguments
+                t0 = time.perf_counter()
+                self._cache, first_tok, last_logits = exe(
+                    self.params, self._cache, dest, jnp.asarray(prompt),
+                    jnp.asarray(req.prompt_len, jnp.int32),
+                    jnp.asarray(req.temperature, jnp.float32),
+                    jnp.asarray(req.top_k, jnp.int32),
+                    jnp.asarray(req.seed, jnp.int32))
+            with _span("serving/prefill.sync") as sync:
+                tok = int(np.asarray(first_tok))
+            t1 = sync.t1
+            _trace.on_prefill(req, slot, bucket, t0, t1, cause="local")
+            _sm.PREFILL_MS.observe((t1 - t0) * 1e3)
+            _sm.PREFILL_COUNT.inc()
+            self._prefills += 1
+            return self._finish_prefill(req, slot, tok, last_logits)
 
     def _prefill_from_prefix(self, req: Request, slot: int, entry
                              ) -> Optional[Request]:
@@ -845,32 +862,35 @@ class ServingEngine:
         ps = self.cfg.page_size
         n = entry.n_tokens
         npages = len(entry.pages)
-        dest_np = self.cache_ops.prompt_dest(req.pages)
-        self._cache["pt"] = self._cache["pt"].at[slot].set(
-            jnp.asarray(dest_np))
-        rows = np.arange(ps, dtype=np.int32)
-        src = np.concatenate([p * ps + rows for p in entry.pages])
-        dst = np.concatenate([p * ps + rows for p in req.pages[:npages]])
-        t0 = time.perf_counter()
-        self._cache["k"] = self._cache["k"].at[:, dst].set(
-            self._cache["k"][:, src])
-        self._cache["v"] = self._cache["v"].at[:, dst].set(
-            self._cache["v"][:, src])
-        # (int8 layout: per-page scales are fixed constants — rows copy 1:1)
-        rbucket = self._bucket_for(req.prompt_len - n)
-        remainder = np.full((rbucket,), self.cfg.pad_id, np.int32)
-        remainder[:req.prompt_len - n] = req.prompt[n:]
-        exe = self._get_resume_exe(rbucket)
-        self._cache, first_tok, last_logits = exe(
-            self.params, self._cache, jnp.asarray(remainder),
-            jnp.asarray(n, jnp.int32),
-            jnp.asarray(req.prompt_len, jnp.int32),
-            jnp.asarray(slot, jnp.int32),
-            jnp.asarray(req.temperature, jnp.float32),
-            jnp.asarray(req.top_k, jnp.int32),
-            jnp.asarray(req.seed, jnp.int32))
-        tok = int(np.asarray(first_tok))
-        t1 = time.perf_counter()
+        with _span("serving/prefill.launch"):
+            dest_np = self.cache_ops.prompt_dest(req.pages)
+            self._cache["pt"] = self._cache["pt"].at[slot].set(
+                jnp.asarray(dest_np))
+            rows = np.arange(ps, dtype=np.int32)
+            src = np.concatenate([p * ps + rows for p in entry.pages])
+            dst = np.concatenate([p * ps + rows for p in req.pages[:npages]])
+            t0 = time.perf_counter()
+            self._cache["k"] = self._cache["k"].at[:, dst].set(
+                self._cache["k"][:, src])
+            self._cache["v"] = self._cache["v"].at[:, dst].set(
+                self._cache["v"][:, src])
+            # (int8 layout: per-page scales are fixed constants — rows copy
+            # 1:1)
+            rbucket = self._bucket_for(req.prompt_len - n)
+            remainder = np.full((rbucket,), self.cfg.pad_id, np.int32)
+            remainder[:req.prompt_len - n] = req.prompt[n:]
+            exe = self._get_resume_exe(rbucket)
+            self._cache, first_tok, last_logits = exe(
+                self.params, self._cache, jnp.asarray(remainder),
+                jnp.asarray(n, jnp.int32),
+                jnp.asarray(req.prompt_len, jnp.int32),
+                jnp.asarray(slot, jnp.int32),
+                jnp.asarray(req.temperature, jnp.float32),
+                jnp.asarray(req.top_k, jnp.int32),
+                jnp.asarray(req.seed, jnp.int32))
+        with _span("serving/prefill.sync") as sync:
+            tok = int(np.asarray(first_tok))
+        t1 = sync.t1
         _trace.on_prefill(req, slot, rbucket, t0, t1, cause="resume")
         _sm.PREFILL_MS.observe((t1 - t0) * 1e3)
         # deliberately NOT PREFILL_COUNT: the bench's "reduced prefill
@@ -1008,7 +1028,6 @@ class ServingEngine:
             steps = self.cfg.decode_fuse
             exe = self._get_decode_exe(steps)
             extra = ()
-        t0 = time.perf_counter()
         attempt = 0
         # Pre-dispatch snapshot: on an async backend a failed dispatch often
         # surfaces at host materialization (np.asarray below), AFTER the
@@ -1018,47 +1037,55 @@ class ServingEngine:
         # snapshot first (the donated cache may be gone; _cache_lost() on
         # the restored ref detects that and downgrades retry to recovery).
         snap = (self._cache, self._len, self._tok, self._active, self._gen)
-        while True:
-            try:
-                spec = _faults.fire("serving.decode")  # chaos drills
-                if spec is not None and spec.kind == "exhausted":
-                    raise PagePoolExhausted(
-                        "injected pool exhaustion at serving.decode")
-                out = exe(self.params, self._cache, self._len, self._tok,
-                          self._active, self._gen, self._maxnew,
-                          self._temp, self._topk, self._seed, *extra)
-                if self.cfg.collect_logits:
+        # serving/decode is the interval serving/decode_step_ms observes:
+        # every launch (one more for each retry) up to the end of the sync
+        with _span("serving/decode", steps=steps,
+                   kind="plain" if dlen_np is None else "verify") as dispatch:
+            while True:
+                try:
+                    with _span("serving/decode.launch"):
+                        spec = _faults.fire("serving.decode")  # chaos drills
+                        if spec is not None and spec.kind == "exhausted":
+                            raise PagePoolExhausted(
+                                "injected pool exhaustion at serving.decode")
+                        out = exe(self.params, self._cache, self._len,
+                                  self._tok, self._active, self._gen,
+                                  self._maxnew, self._temp, self._topk,
+                                  self._seed, *extra)
+                    if self.cfg.collect_logits:
+                        (self._cache, self._len, self._tok, self._active,
+                         self._gen, toks, emitted, fin, logseq) = out
+                    else:
+                        (self._cache, self._len, self._tok, self._active,
+                         self._gen, toks, emitted, fin) = out
+                        logseq = None
+                    # one host sync per dispatch: the retire/admit decision
+                    # needs the emitted tokens (the serving analog of
+                    # run_steps' fetch)
+                    with _span("serving/decode.sync"):
+                        toks = np.asarray(toks)
+                        emitted = np.asarray(emitted)
+                        fin = np.asarray(fin)
+                    break
+                except Exception as e:
                     (self._cache, self._len, self._tok, self._active,
-                     self._gen, toks, emitted, fin, logseq) = out
-                else:
-                    (self._cache, self._len, self._tok, self._active,
-                     self._gen, toks, emitted, fin) = out
-                    logseq = None
-                # one host sync per dispatch: the retire/admit decision needs
-                # the emitted tokens (the serving analog of run_steps' fetch)
-                toks = np.asarray(toks)
-                emitted = np.asarray(emitted)
-                fin = np.asarray(fin)
-                break
-            except Exception as e:
-                (self._cache, self._len, self._tok, self._active,
-                 self._gen) = snap
-                if (_faults.classify(e) == "transient"
-                        and attempt < self.cfg.decode_retries
-                        and not self._cache_lost()):
-                    attempt += 1
-                    _sm.RETRIES.inc()
-                    continue
-                fr = _dev.flight_recorder()
-                if fr is not None:
-                    fr.record_event("serving_inflight_batch",
-                                    **self._batch_spec())
-                _safe_flight_dump(fr, "serving.decode", e)
-                if self.cfg.fail_fast:
-                    raise
-                return self._fail_inflight_batch(e)
+                     self._gen) = snap
+                    if (_faults.classify(e) == "transient"
+                            and attempt < self.cfg.decode_retries
+                            and not self._cache_lost()):
+                        attempt += 1
+                        _sm.RETRIES.inc()
+                        continue
+                    fr = _dev.flight_recorder()
+                    if fr is not None:
+                        fr.record_event("serving_inflight_batch",
+                                        **self._batch_spec())
+                    _safe_flight_dump(fr, "serving.decode", e)
+                    if self.cfg.fail_fast:
+                        raise
+                    return self._fail_inflight_batch(e)
         self._consecutive_failures = 0
-        t1 = time.perf_counter()
+        t0, t1 = dispatch.t0, dispatch.t1
         spec_args = None
         if dlen_np is not None:
             # accepted drafts per slot = its run-steps beyond the first
@@ -1086,19 +1113,21 @@ class ServingEngine:
             _sm.SPEC_VERIFY_DISPATCHES.inc()
             _sm.SPEC_ACCEPT_RATE.observe(accepted / max(1, proposed))
         finished: List[Request] = []
-        for slot in range(self.cfg.slots):
-            req = self.scheduler.slot_request(slot)
-            if req is None:
-                continue
-            for f in range(steps):
-                if emitted[f, slot]:
-                    req.tokens_out.append(int(toks[f, slot]))
-                    if logseq is not None:
-                        self._captured_logits.setdefault(req.id, []).append(
-                            np.asarray(logseq[f, slot]))
-                if fin[f, slot]:
-                    finished.append(self._retire(slot))
-                    break
+        with _span("serving/retire"):
+            for slot in range(self.cfg.slots):
+                req = self.scheduler.slot_request(slot)
+                if req is None:
+                    continue
+                for f in range(steps):
+                    if emitted[f, slot]:
+                        req.tokens_out.append(int(toks[f, slot]))
+                        if logseq is not None:
+                            self._captured_logits.setdefault(
+                                req.id, []).append(
+                                    np.asarray(logseq[f, slot]))
+                    if fin[f, slot]:
+                        finished.append(self._retire(slot))
+                        break
         return finished
 
     def _retire(self, slot: int, state: str = FINISHED,

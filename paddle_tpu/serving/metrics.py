@@ -16,7 +16,7 @@ __all__ = [
     "REQUESTS_REJECTED", "QUEUE_DEPTH", "SLOT_OCCUPANCY",
     "PAGES_IN_USE", "PAGE_POOL_UTILIZATION", "ADMISSION_BLOCKED",
     "PREFILL_COUNT", "DECODE_STEPS", "DECODE_DISPATCHES",
-    "TOKENS_GENERATED", "TOKENS_PER_SEC",
+    "TOKENS_GENERATED", "CYCLES",
     "REQUEST_LATENCY_MS", "TTFT_MS", "DECODE_STEP_MS", "PREFILL_MS",
     "FAULTS", "RETRIES", "TIMEOUTS", "REQUESTS_FAILED",
     "DRAINS", "DRAINED_REQUESTS", "DRAIN_REJECTED",
@@ -54,9 +54,10 @@ DECODE_DISPATCHES = _mx.counter(
     help="decode dispatches issued (each fuses >=1 decode steps)")
 TOKENS_GENERATED = _mx.counter(
     "serving/tokens_generated", help="tokens emitted to finished+running requests")
-TOKENS_PER_SEC = _mx.gauge(
-    "serving/tokens_per_sec",
-    help="sustained generation rate over the last engine.run() drive")
+CYCLES = _mx.counter(
+    "serving/cycles",
+    help="engine.step() calls: the base of every per-cycle ratio "
+         "(prefills a cycle, tokens a cycle)")
 REQUEST_LATENCY_MS = _mx.histogram(
     "serving/request_latency_ms",
     help="submit -> finish wall time per retired request")
@@ -64,7 +65,8 @@ TTFT_MS = _mx.histogram(
     "serving/ttft_ms", help="submit -> first token wall time per request")
 DECODE_STEP_MS = _mx.histogram(
     "serving/decode_step_ms",
-    help="host wall time of one decode dispatch / fused steps")
+    help="host wall time of one decode dispatch, launch to the end of "
+         "the host sync (all its fused steps, retries included)")
 PREFILL_MS = _mx.histogram(
     "serving/prefill_ms", help="host wall time of one compiled prefill call")
 FAULTS = _mx.counter(
