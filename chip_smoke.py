@@ -466,8 +466,8 @@ def _kernel_paged(seed, interpret):
     ctx = np.resize(np.array([1, ps, ps + 1, cfg["max_seq"] // 2 + 3,
                               cfg["max_seq"]], np.int32), slots)
     ctx[-1] = cfg["max_seq"]
-    k_pool = jnp.asarray(rng.randn(num_pages * ps, h, d), jnp.float32)
-    v_pool = jnp.asarray(rng.randn(num_pages * ps, h, d), jnp.float32)
+    k_pool = jnp.asarray(rng.randn(num_pages * ps, h * d), jnp.float32)
+    v_pool = jnp.asarray(rng.randn(num_pages * ps, h * d), jnp.float32)
     q = jnp.asarray(rng.randn(slots, h, d), jnp.float32)
     sm = 1.0 / float(d) ** 0.5
     got = jax.jit(lambda *a: pa.paged_decode_attention(

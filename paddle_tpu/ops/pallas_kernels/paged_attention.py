@@ -15,13 +15,20 @@ LIVE rows move) and the ``[B, max_ctx, H, D]`` intermediate never exists.
 
 Design:
 
-- the pool is seen as ``[num_pages*page_size, H*D]``: one lane-dense row
-  per context position. The chip's compiler tiles the last two dims of
-  every buffer to (8, 128) and refuses a DMA slice that is not a whole
-  number of tiles, so a ``[rows, H, D]`` view only moves for ``D % 128 ==
-  0`` and ``H % 8 == 0``; the flat view moves for any ``H*D % 128 == 0``
-  (GPT-2 small's 12 heads of 64 included). The static gate
+- the pool is STORED as ``[n_layer, num_pages*page_size, H*D]``: one
+  lane-dense row per context position. The chip's compiler tiles the last
+  two dims of every buffer to (8, 128) and refuses a DMA slice that is not
+  a whole number of tiles, so a ``[rows, H, D]`` pool only moves for ``D %
+  128 == 0`` and ``H % 8 == 0`` (and XLA gives GPT-2 small's ``12 x 64``
+  a rows-minor layout that every scatter and every slice converts, a copy
+  of the whole pool each); the flat row moves for any ``H*D % 128 == 0``
+  (GPT-2 small's 12 heads of 64 are six whole lane tiles) and is the
+  default layout, so nothing converts. The static gate
   :func:`paged_attention_gate` states the rule;
+- the kernel takes the WHOLE pool and the layer, and indexes the layer in
+  its page DMA (``k_hbm.at[layer, pl.ds(row, ps)]``): nothing outside the
+  kernel slices or reshapes a pool-sized array. The layer rides in SMEM
+  beside the page table, so every layer's call shares one kernel body;
 - per-head reductions over the flat row ride the MXU: ``(k * q) @ seg``
   sums each head's D lanes (``seg`` is the 0/1 head-membership matrix
   ``[H*D, 128]``), and ``p @ seg.T`` spreads each head's probability back
@@ -148,23 +155,24 @@ def _block_pages(block, page_size: int, pages_per_slot: int, max_ctx: int,
     return max(1, min(int(block), pages_per_slot, fits))
 
 
-def _page_dma(table_ref, scr_ref, sem, row, slot_row, ps):
-    """Async copy of one page (``ps`` contiguous [H*D] rows) between the
-    HBM pool and VMEM scratch."""
+def _page_dma(pool_ref, scr_ref, sem, layer, row, slot_row, ps):
+    """Async copy of one page (``ps`` contiguous [H*D] rows of ``layer``)
+    from the HBM pool to VMEM scratch."""
     return pltpu.make_async_copy(
-        table_ref.at[pl.ds(row, ps)],
+        pool_ref.at[layer, pl.ds(row, ps)],
         scr_ref.at[pl.ds(slot_row, ps)],
         sem,
     )
 
 
-def _paged_attn_kernel(pt_ref, len_ref, q_ref, seg_ref, segt_ref, k_hbm,
-                       v_hbm, o_ref, k_scr, v_scr, sems, *, block_pages,
-                       page_size, pages_per_slot, num_pages, sm_scale,
-                       mask_value):
+def _paged_attn_kernel(pt_ref, len_ref, layer_ref, q_ref, seg_ref, segt_ref,
+                       k_hbm, v_hbm, o_ref, k_scr, v_scr, sems, *,
+                       block_pages, page_size, pages_per_slot, num_pages,
+                       sm_scale, mask_value):
     b = pl.program_id(0)
     ps = page_size
     ctx = len_ref[b]
+    layer = layer_ref[0]
     q = q_ref[0].astype(jnp.float32) * sm_scale  # [1, HD]
     seg = seg_ref[...]    # [HD, HP]: lane j belongs to head seg[j].argmax()
     segt = segt_ref[...]  # [HP, HD]
@@ -203,8 +211,10 @@ def _paged_attn_kernel(pt_ref, len_ref, q_ref, seg_ref, segt_ref, k_hbm,
             @pl.when(page_valid(i, w))
             def _():
                 row = page_row(i, w)
-                _page_dma(k_hbm, k_scr, sems.at[0, i], row, i * ps, ps).start()
-                _page_dma(v_hbm, v_scr, sems.at[1, i], row, i * ps, ps).start()
+                _page_dma(k_hbm, k_scr, sems.at[0, i], layer, row, i * ps,
+                          ps).start()
+                _page_dma(v_hbm, v_scr, sems.at[1, i], layer, row, i * ps,
+                          ps).start()
 
             return 0
 
@@ -214,8 +224,10 @@ def _paged_attn_kernel(pt_ref, len_ref, q_ref, seg_ref, segt_ref, k_hbm,
             @pl.when(page_valid(i, w))
             def _():
                 row = page_row(i, w)
-                _page_dma(k_hbm, k_scr, sems.at[0, i], row, i * ps, ps).wait()
-                _page_dma(v_hbm, v_scr, sems.at[1, i], row, i * ps, ps).wait()
+                _page_dma(k_hbm, k_scr, sems.at[0, i], layer, row, i * ps,
+                          ps).wait()
+                _page_dma(v_hbm, v_scr, sems.at[1, i], layer, row, i * ps,
+                          ps).wait()
 
             return 0
 
@@ -253,13 +265,15 @@ def _paged_attn_kernel(pt_ref, len_ref, q_ref, seg_ref, segt_ref, k_hbm,
 
 
 def paged_decode_attention(q, k_pages, v_pages, page_table, ctx_len, *,
-                           page_size, sm_scale=1.0, block_pages=None,
-                           interpret: bool = False):
+                           page_size, layer=None, sm_scale=1.0,
+                           block_pages=None, interpret: bool = False):
     """Fused ragged paged decode attention.
 
     ``q`` [B,H,D] — current position's query per slot. ``k_pages``/
-    ``v_pages`` [num_pages*page_size, H, D] — ONE layer of the paged KV
-    pool (serving.kv_cache.PagedKVCache state). ``page_table`` [B,
+    ``v_pages`` [n_layer, num_pages*page_size, H*D] — the WHOLE paged KV
+    pool (serving.kv_cache.PagedKVCache state), of which the kernel reads
+    layer ``layer`` (an int or an int32 scalar); or ONE layer as
+    [num_pages*page_size, H*D] with ``layer`` left None. ``page_table`` [B,
     pages_per_slot] int32 — each slot's ordered page ids. ``ctx_len`` [B] —
     valid leading positions per slot (must be >= 1 for slots whose output
     is consumed). ``block_pages=None`` = tuned-table lookup with the
@@ -274,8 +288,19 @@ def paged_decode_attention(q, k_pages, v_pages, page_table, ctx_len, *,
     slots, pages_per_slot = page_table.shape
     if slots != b:
         raise ValueError("page_table slots %d != q batch %d" % (slots, b))
+    if k_pages.ndim == 2 and layer is None:
+        # one layer is a pool of one: a leading 1 is free on tiled memory
+        k_pages, v_pages, layer = k_pages[None], v_pages[None], 0
+    if k_pages.ndim != 3 or layer is None or k_pages.shape[2] != hd \
+            or v_pages.shape != k_pages.shape:
+        raise ValueError(
+            "pool must be [n_layer, rows, %d] with a layer, or one layer "
+            "[rows, %d] without: got k %s v %s layer=%r"
+            % (hd, hd, k_pages.shape, v_pages.shape, layer))
+    n_layer, num_rows = k_pages.shape[:2]
+    if isinstance(layer, (int, np.integer)) and not 0 <= layer < n_layer:
+        raise ValueError("layer %d outside a pool of %d" % (layer, n_layer))
     ps = int(page_size)
-    num_rows = k_pages.shape[0]
     if num_rows % ps != 0:
         raise ValueError("pool rows %d not a multiple of page_size %d"
                          % (num_rows, ps))
@@ -294,7 +319,7 @@ def paged_decode_attention(q, k_pages, v_pages, page_table, ctx_len, *,
         pages_per_slot=pages_per_slot, num_pages=num_rows // ps,
         sm_scale=float(sm_scale), mask_value=neg_inf_value(jnp.float32))
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
+        num_scalar_prefetch=3,
         grid=(b,),
         in_specs=[
             pl.BlockSpec((1, 1, hd), lambda i, *_: (i, 0, 0)),  # q
@@ -317,24 +342,27 @@ def paged_decode_attention(q, k_pages, v_pages, page_table, ctx_len, *,
         interpret=interpret,
         name="paged_attention",
     )(page_table.reshape(-1).astype(jnp.int32),
-      ctx_len.astype(jnp.int32), q.reshape(b, 1, hd), jnp.asarray(seg),
-      jnp.asarray(seg.T), k_pages.reshape(num_rows, hd),
-      v_pages.reshape(num_rows, hd))
+      ctx_len.astype(jnp.int32), jnp.asarray(layer, jnp.int32).reshape(1),
+      q.reshape(b, 1, hd), jnp.asarray(seg), jnp.asarray(seg.T), k_pages,
+      v_pages)
     return out.reshape(b, h, d)
 
 
 def gather_reference(q, k_pages, v_pages, page_table, ctx_len, page_size,
                      sm_scale=1.0):
-    """The XLA path the kernel replaces, as a standalone reference: the
-    PagedKVCache.context gather composed with attention_ops
-    .decode_attention (which supplies the SHARED neg_inf masking constant
-    — the parity contract the selftest asserts)."""
+    """The XLA path the kernel replaces, as a standalone reference over ONE
+    layer ``[rows, H*D]``: the PagedKVCache.context gather (rows first,
+    heads split after) composed with attention_ops.decode_attention (which
+    supplies the SHARED neg_inf masking constant — the parity contract the
+    selftest asserts)."""
     ps = int(page_size)
+    b, h, d = q.shape
     rows = (page_table * ps)[:, :, None] + jnp.arange(ps)[None, None, :]
-    rows = rows.reshape(page_table.shape[0], -1)
+    rows = rows.reshape(b, -1)
     from ..attention_ops import decode_attention
 
-    return decode_attention(q, k_pages[rows], v_pages[rows], ctx_len,
+    return decode_attention(q, k_pages[rows].reshape(b, -1, h, d),
+                            v_pages[rows].reshape(b, -1, h, d), ctx_len,
                             sm_scale=sm_scale)
 
 
@@ -363,8 +391,8 @@ def _selftest() -> int:
     # ragged mixed lengths: 1 token, mid-page, page-exact, multi-page, full
     ctx_len = np.array([1, 7, 8, 33, max_ctx], np.int32)
 
-    k_pool = rng.randn(num_pages * ps, h, d).astype(np.float32)
-    v_pool = rng.randn(num_pages * ps, h, d).astype(np.float32)
+    k_pool = rng.randn(num_pages * ps, h * d).astype(np.float32)
+    v_pool = rng.randn(num_pages * ps, h * d).astype(np.float32)
     q = rng.randn(slots, h, d).astype(np.float32)
 
     def run(kp, vp, block):
@@ -398,8 +426,8 @@ def _selftest() -> int:
         live[flat] = True
     k_poison = k_pool.copy()
     v_poison = v_pool.copy()
-    k_poison[~live] = 1e4 * rng.randn((~live).sum(), h, d)
-    v_poison[~live] = -1e4 * np.ones(((~live).sum(), h, d), np.float32)
+    k_poison[~live] = 1e4 * rng.randn((~live).sum(), h * d)
+    v_poison[~live] = -1e4
     got_p, want_p = run(k_poison, v_poison, 2)
     np.testing.assert_allclose(got_p, want_p, rtol=1e-6, atol=1e-6,
                                err_msg="poisoned kernel vs gather mismatch")
@@ -408,8 +436,24 @@ def _selftest() -> int:
         got_p, clean,
         err_msg="garbage beyond ctx_len leaked into the kernel output")
 
+    # the engine's entry: the whole pool and a layer. The layer sits in the
+    # middle of three with poisoned neighbours, and must come out bit-equal
+    # to the single-layer call
+    def pooled(x, fill):
+        return jnp.asarray(np.stack([np.full_like(x, fill), x,
+                                     np.full_like(x, -fill)]))
+
+    got_l = np.asarray(paged_decode_attention(
+        jnp.asarray(q), pooled(k_pool, 1e4), pooled(v_pool, -1e4),
+        jnp.asarray(pt), jnp.asarray(ctx_len), page_size=ps, layer=1,
+        sm_scale=sm, block_pages=2, interpret=True))
+    np.testing.assert_array_equal(
+        got_l, clean,
+        err_msg="layer 1 of a pool differs from the same layer alone")
+
     print("paged_attention selftest OK (%.2fs): kernel == gather on %d "
-          "ragged slots (ctx %s), garbage pages contribute exactly zero"
+          "ragged slots (ctx %s), garbage pages and neighbouring layers "
+          "contribute exactly zero"
           % (time.time() - t0, slots, list(map(int, ctx_len))))
     return 0
 

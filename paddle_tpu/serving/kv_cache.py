@@ -5,14 +5,23 @@ Two layouts behind ONE functional interface (`init_state` / `write_token` /
 loop is layout-blind and the two paths are bit-comparable:
 
 * :class:`PagedKVCache` — the "Ragged Paged Attention" layout (PAPERS.md):
-  KV rows live in a flat page pool ``[n_layer, num_pages*page_size, H, D]``
-  and each slot owns an ordered page table ``[slots, pages_per_slot]``.
-  Ragged sequence lengths cost only their pages; ``context`` gathers a
-  slot's pages back into logical order (the XLA-gather path), and
-  ``decode_attention`` dispatches between that gather and the fused
-  ragged paged-attention Pallas kernel
+  KV rows live in a flat page pool ``[n_layer, num_pages*page_size, H*D]``
+  (one lane-dense row per context position) and each slot owns an ordered
+  page table ``[slots, pages_per_slot]``. Ragged sequence lengths cost only
+  their pages; ``context`` gathers a slot's pages back into logical order
+  (the XLA-gather path), and ``decode_attention`` dispatches between that
+  gather and the fused ragged paged-attention Pallas kernel
   (ops/pallas_kernels/paged_attention.py) per
-  ``FLAGS_paged_attention_kernel``.
+  ``FLAGS_paged_attention_kernel``. Why the heads are not a dimension of
+  the pool: the chip tiles the last two dims of a buffer to (8, 128), a
+  ``[rows, H, D]`` pool with GPT-2 small's ``12 x 64`` pads badly, so the
+  compiler stored it rows-minor and converted the WHOLE pool before the
+  first row scatter of a step and back after the last, and sliced and
+  copied a layer of it for every kernel call (four fifths of a decode
+  step). ``[rows, H*D]`` is whole lane tiles for every ``H*D % 128 == 0``,
+  so writes scatter in place and the kernel indexes the layer in its own
+  page DMA; the small ``[B, H, D]`` updates and the gathered contexts are
+  reshaped, the pool never is.
 * :class:`ContiguousKVCache` — the dense reference ``[n_layer, slots,
   max_ctx, H, D]`` every slot pays ``max_ctx`` for. The parity yardstick
   (tests/test_serving.py asserts bit-identical tokens/logits) and the
@@ -91,12 +100,18 @@ class PagedKVCache(_KVCacheBase):
         self.num_pages = int(num_pages)
         self.pages_per_slot = self.max_ctx // self.page_size
         self.num_rows = self.num_pages * self.page_size  # flat KV rows
+        self.row_width = self.n_head * self.d_head  # lanes of one KV row
+
+    def _storage_dtype(self):
+        """What a pool row is stored as (``self.dtype`` is what ``context``
+        returns)."""
+        return self.dtype
 
     def init_state(self) -> Cache:
-        shp = (self.n_layer, self.num_rows, self.n_head, self.d_head)
+        shp = (self.n_layer, self.num_rows, self.row_width)
         return {
-            "k": jnp.zeros(shp, self.dtype),
-            "v": jnp.zeros(shp, self.dtype),
+            "k": jnp.zeros(shp, self._storage_dtype()),
+            "v": jnp.zeros(shp, self._storage_dtype()),
             # page table: slot -> ordered page ids; rows beyond a slot's
             # reservation are whatever the allocator last left (reads are
             # masked by length, writes by the drop scatter)
@@ -114,20 +129,38 @@ class PagedKVCache(_KVCacheBase):
         page = pt[b_idx, pos // ps]
         dest = page * ps + pos % ps
         dest = jnp.where(active, dest, self.num_rows)
+        return self._write_rows(state, layer, dest, k_new, v_new)
+
+    def _write_rows(self, state: Cache, layer: int, dest, k_new, v_new
+                    ) -> Cache:
+        """Scatter ``[N, H, D]`` updates into pool rows ``dest`` [N] of
+        ``layer``; rows at ``num_rows`` are dropped."""
         return {
             **state,
-            "k": state["k"].at[layer, dest].set(k_new, mode="drop"),
-            "v": state["v"].at[layer, dest].set(v_new, mode="drop"),
+            "k": state["k"].at[layer, dest].set(
+                k_new.reshape(-1, self.row_width), mode="drop"),
+            "v": state["v"].at[layer, dest].set(
+                v_new.reshape(-1, self.row_width), mode="drop"),
         }
 
     def context(self, state: Cache, layer: int) -> Tuple[jnp.ndarray, jnp.ndarray]:
         """Gather every slot's pages back into logical order:
         ``[slots, max_ctx, H, D]`` — the XLA-gather paged-attention path."""
+        rows = self._context_rows(state["pt"])
+        return (self._gather(state["k"], layer, rows),
+                self._gather(state["v"], layer, rows))
+
+    def _context_rows(self, pt) -> jnp.ndarray:
+        """Pool row of every logical position: ``[slots, max_ctx]``."""
         ps = self.page_size
-        pt = state["pt"]
         rows = (pt * ps)[:, :, None] + jnp.arange(ps)[None, None, :]
-        rows = rows.reshape(pt.shape[0], self.max_ctx)
-        return state["k"][layer][rows], state["v"][layer][rows]
+        return rows.reshape(pt.shape[0], self.max_ctx)
+
+    def _gather(self, pool, layer: int, rows) -> jnp.ndarray:
+        """``pool[layer, rows]`` with the heads split AFTER the gather:
+        ``[slots, max_ctx, H, D]``."""
+        return pool[layer, rows].reshape(rows.shape + (self.n_head,
+                                                       self.d_head))
 
     def kernel_mode(self):
         """``(mode, why_not)``: ``mode`` is "compiled"/"interpret" when the
@@ -155,10 +188,10 @@ class PagedKVCache(_KVCacheBase):
                          sm_scale: float = 1.0) -> jnp.ndarray:
         """One decode-attention step [B,H,D] over this layer's ragged
         contexts. Where :meth:`kernel_mode` arms it, the Pallas kernel
-        reads K/V pages straight from the pool via the device-resident
-        page table — the ``[B, max_ctx, H, D]`` gather never materializes;
-        otherwise the XLA gather + ops.attention_ops.decode_attention
-        path runs. Both mask positions >= ctx_len with the SAME neg_inf
+        takes the WHOLE pool and reads this layer's K/V pages straight from
+        it via the device-resident page table — neither a layer slice nor
+        the ``[B, max_ctx, H, D]`` gather ever materializes; otherwise the
+        XLA gather + ops.attention_ops.decode_attention path runs. Both mask positions >= ctx_len with the SAME neg_inf
         constant, so the paths agree to float round-off (tier-1 parity
         tests pin it)."""
         from ..ops import attention_ops
@@ -168,8 +201,8 @@ class PagedKVCache(_KVCacheBase):
             from ..ops.pallas_kernels import paged_attention as _pa
 
             return _pa.paged_decode_attention(
-                q, state["k"][layer], state["v"][layer], state["pt"],
-                ctx_len, page_size=self.page_size, sm_scale=sm_scale,
+                q, state["k"], state["v"], state["pt"], ctx_len,
+                page_size=self.page_size, layer=layer, sm_scale=sm_scale,
                 interpret=(mode == "interpret"))
         ctx_k, ctx_v = self.context(state, layer)
         return attention_ops.decode_attention(q, ctx_k, ctx_v, ctx_len,
@@ -197,9 +230,9 @@ class PagedKVCache(_KVCacheBase):
             lens = jnp.clip(lens.reshape(b * w), 0, self.max_ctx)
             out = _pa.paged_decode_attention(
                 q.reshape(b * w, self.n_head, self.d_head),
-                state["k"][layer], state["v"][layer],
+                state["k"], state["v"],
                 jnp.repeat(state["pt"], w, axis=0), lens,
-                page_size=self.page_size, sm_scale=sm_scale,
+                page_size=self.page_size, layer=layer, sm_scale=sm_scale,
                 interpret=(mode == "interpret"))
             return out.reshape(b, w, self.n_head, self.d_head)
         ctx_k, ctx_v = self.context(state, layer)
@@ -224,11 +257,7 @@ class PagedKVCache(_KVCacheBase):
         j = jnp.arange(s)
         flat = dest[j // ps] * ps + j % ps
         flat = jnp.where(j < length, flat, self.num_rows)
-        return {
-            **state,
-            "k": state["k"].at[layer, flat].set(k_new, mode="drop"),
-            "v": state["v"].at[layer, flat].set(v_new, mode="drop"),
-        }
+        return self._write_rows(state, layer, flat, k_new, v_new)
 
     # -- page migration ------------------------------------------------------
     def _page_rows(self, pages) -> np.ndarray:
@@ -244,9 +273,6 @@ class PagedKVCache(_KVCacheBase):
                 "page_size": self.page_size,
                 "kv_dtype": jnp.dtype(self._storage_dtype()).name}
 
-    def _storage_dtype(self):
-        return self.dtype
-
     def _check_meta(self, meta: dict, n_blobs: int, blobs) -> None:
         want = self.page_meta()
         got = {k: meta.get(k) for k in want}
@@ -260,7 +286,8 @@ class PagedKVCache(_KVCacheBase):
     def export_pages(self, state: Cache, pages):
         """Serialize ``pages`` (pool page ids) to ``(meta, blobs)``: raw
         C-order bytes of the K rows then the V rows, ``[n_layer,
-        n_pages*page_size, H, D]`` each — bit-exact, no float formatting."""
+        n_pages*page_size, H, D]`` each (which the ``[.., H*D]`` pool's
+        rows are, byte for byte) — bit-exact, no float formatting."""
         rows = self._page_rows(pages)
         k = np.ascontiguousarray(np.asarray(state["k"][:, rows]))
         v = np.ascontiguousarray(np.asarray(state["v"][:, rows]))
@@ -280,7 +307,7 @@ class PagedKVCache(_KVCacheBase):
                              % (n, len(pages)))
         rows = self._page_rows(pages)
         dt = _dtype_by_name(meta["kv_dtype"])
-        shp = (self.n_layer, len(rows), self.n_head, self.d_head)
+        shp = (self.n_layer, len(rows), self.row_width)
         want = int(np.prod(shp)) * dt.itemsize
         if len(blobs[0]) != want or len(blobs[1]) != want:
             raise ValueError("page payload blob bytes %d/%d != %d"
@@ -334,12 +361,12 @@ class Int8PagedKVCache(PagedKVCache):
         self.k_scale = float(k_scale)
         self.v_scale = float(v_scale)
 
+    def _storage_dtype(self):
+        return jnp.int8
+
     def init_state(self) -> Cache:
-        shp = (self.n_layer, self.num_rows, self.n_head, self.d_head)
         return {
-            "k": jnp.zeros(shp, jnp.int8),
-            "v": jnp.zeros(shp, jnp.int8),
-            "pt": jnp.zeros((self.slots, self.pages_per_slot), jnp.int32),
+            **super().init_state(),
             "ks": jnp.full((self.n_layer, self.num_pages), self.k_scale,
                            jnp.float32),
             "vs": jnp.full((self.n_layer, self.num_pages), self.v_scale,
@@ -365,15 +392,12 @@ class Int8PagedKVCache(PagedKVCache):
                                     dest, length)
 
     def context(self, state: Cache, layer: int) -> Tuple[jnp.ndarray, jnp.ndarray]:
-        ps = self.page_size
-        pt = state["pt"]
-        rows = (pt * ps)[:, :, None] + jnp.arange(ps)[None, None, :]
-        rows = rows.reshape(pt.shape[0], self.max_ctx)
-        pages = rows // ps  # page id per logical position [slots, max_ctx]
+        rows = self._context_rows(state["pt"])
+        pages = rows // self.page_size  # page id per logical position
         ks = state["ks"][layer][pages][:, :, None, None].astype(self.dtype)
         vs = state["vs"][layer][pages][:, :, None, None].astype(self.dtype)
-        return (state["k"][layer][rows].astype(self.dtype) * ks,
-                state["v"][layer][rows].astype(self.dtype) * vs)
+        return (self._gather(state["k"], layer, rows).astype(self.dtype) * ks,
+                self._gather(state["v"], layer, rows).astype(self.dtype) * vs)
 
     def kernel_mode(self):
         return None, "gate: int8 pool (the kernel has no dequant stage)"
@@ -383,9 +407,6 @@ class Int8PagedKVCache(PagedKVCache):
                    + state["ks"].nbytes + state["vs"].nbytes)
 
     # -- page migration ------------------------------------------------------
-    def _storage_dtype(self):
-        return jnp.int8
-
     def export_pages(self, state: Cache, pages):
         """int8 pages travel WITH their per-page fp32 scale columns
         (``ks``/``vs`` ``[n_layer]`` per page) — the payload is

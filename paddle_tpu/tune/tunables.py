@@ -381,8 +381,10 @@ class PagedAttentionTunable(Tunable):
         pps = max_ctx // ps
         num_pages = slots * pps  # full-occupancy pool, like the engine's
         rng = np.random.RandomState(0)
-        k_pool = jnp.asarray(rng.randn(num_pages * ps, h, d), jnp.float32)
-        v_pool = jnp.asarray(rng.randn(num_pages * ps, h, d), jnp.float32)
+        # one layer as the pool stores it, [rows, H*D]: the tuner times the
+        # kernel, not a reshape
+        k_pool = jnp.asarray(rng.randn(num_pages * ps, h * d), jnp.float32)
+        v_pool = jnp.asarray(rng.randn(num_pages * ps, h * d), jnp.float32)
         q = jnp.asarray(rng.randn(slots, h, d), jnp.float32)
         pt = jnp.asarray(rng.permutation(num_pages)[:slots * pps]
                          .reshape(slots, pps).astype(np.int32))

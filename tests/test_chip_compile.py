@@ -12,6 +12,7 @@ here says a kernel computes the right thing or how fast.
 
 import functools
 import os
+import re
 
 os.environ.setdefault("TPU_LOG_DIR", "disabled")  # or the compiler logs to /tmp
 
@@ -118,9 +119,42 @@ PAGED_SHAPES = {
 }
 
 
+def _instructions(text):
+    """``(name, result type, opcode, operand names)`` of every instruction
+    in an HLO module's text. Operands are printed by name only, so a
+    caller that wants their shapes looks the names up."""
+    def balanced(s):  # length of the parenthesised group s starts with
+        depth = 0
+        for i, ch in enumerate(s):
+            depth += (ch == "(") - (ch == ")")
+            if depth == 0:
+                return i + 1
+        return len(s)
+
+    for line in text.split("\n"):
+        m = re.match(r"\s*(?:ROOT )?%(\S+) = (.*)", line)
+        if not m:
+            continue
+        name, rest = m.groups()
+        cut = balanced(rest) if rest.startswith("(") else rest.index(" ")
+        rtype, rest = rest[:cut], rest[cut + 1:]
+        opcode, paren, rest = rest.partition("(")
+        if not paren:
+            continue
+        operands = re.findall(r"%([\w.\-]+)", rest[:balanced("(" + rest) - 1])
+        yield name, rtype, opcode, operands
+
+
+def _has_dim(type_str, n):
+    return any(str(n) in dims.split(",")
+               for dims in re.findall(r"\[([\d,]*)\]", type_str))
+
+
 @pytest.mark.parametrize("name", sorted(PAGED_SHAPES))
 def test_paged_decode_attention(chip, name):
-    """``block_pages`` comes from the tune table, as in the engine."""
+    """The engine's entry: the whole ``[n_layer, rows, H*D]`` pool and a
+    layer in the middle of it. ``block_pages`` comes from the tune table,
+    as in the engine."""
     b, h, d, ps, pps, dtype = PAGED_SHAPES[name]
     why = pa.paged_attention_gate(dtype, h, d, ps)
     if name == "tiny_test_model":
@@ -130,13 +164,151 @@ def test_paged_decode_attention(chip, name):
         return
     assert why is None
     rows = b * pps * ps
+    pool = ((3, rows, h * d), dtype)
     text = compiled_text(
         chip,
-        functools.partial(pa.paged_decode_attention, page_size=ps,
+        functools.partial(pa.paged_decode_attention, page_size=ps, layer=1,
                           sm_scale=0.125),
-        ((b, h, d), dtype), ((rows, h, d), dtype), ((rows, h, d), dtype),
-        ((b, pps), jnp.int32), ((b,), jnp.int32))
+        ((b, h, d), dtype), pool, pool, ((b, pps), jnp.int32),
+        ((b,), jnp.int32))
     # the kernel runs under its own name: what a profile's XLA Ops line and
     # the grid's device_ops show in place of closed_call
     kernel, = [ln for ln in text.split("\n") if "tpu_custom_call" in ln]
     assert kernel.strip().startswith("%paged_attention")
+    # and it is handed the pool itself: nothing slices or copies a layer
+    assert [op for _, rtype, op, _ in _instructions(text)
+            if _has_dim(rtype, rows) and op != "parameter"] == []
+
+
+def test_paged_decode_attention_single_layer(chip):
+    """One layer as ``[rows, H*D]`` (what the tuner and ``chip_smoke.py``
+    time) becomes a pool of one without a copy."""
+    b, h, d, ps, pps, dtype = PAGED_SHAPES["gpt2_small_bf16"]
+    rows = b * pps * ps
+    text = compiled_text(
+        chip, functools.partial(pa.paged_decode_attention, page_size=ps),
+        ((b, h, d), dtype), ((rows, h * d), dtype), ((rows, h * d), dtype),
+        ((b, pps), jnp.int32), ((b,), jnp.int32))
+    assert "tpu_custom_call" in text
+    assert {op for _, rtype, op, _ in _instructions(text)
+            if _has_dim(rtype, rows)} <= {"parameter", "bitcast"}
+
+
+# -- the engine's executables at gpt2-small-serve's geometry -------------------
+
+SERVE = dict(slots=32, page_size=16, num_pages=2048, max_seq=1024,
+             n_layer=12, n_head=12, d_model=768, vocab=50257, bucket=256,
+             window=5)
+
+
+def _serve_case(name, chip):
+    """``(fn, abstract args)`` of one of ``ServingEngine``'s executables,
+    composed as ``engine._get_*_exe`` composes it: the model's forward with
+    the cache's own ``write_*``/``decode_*`` calls, the cache first in the
+    result. Greedy ``argmax`` stands where the engine calls its sampler (the
+    vocabulary sort alone compiles for 22 s and touches no pool)."""
+    from paddle_tpu.models import decoder_lm
+    from paddle_tpu.serving.kv_cache import PagedKVCache
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
+
+    g = SERVE
+    cfg = decoder_lm.DecoderConfig(
+        vocab_size=g["vocab"], n_layer=g["n_layer"], d_model=g["d_model"],
+        n_head=g["n_head"], max_seq=g["max_seq"], dtype="bfloat16")
+    model = decoder_lm.DecoderLM(cfg, params={})
+    ops = PagedKVCache(cfg.n_layer, cfg.n_head, cfg.d_head, g["slots"],
+                       g["max_seq"], g["page_size"], g["num_pages"],
+                       dtype=cfg.dtype)
+
+    def abstract(fn):
+        return jax.tree_util.tree_map(lambda x: sds(x.shape, x.dtype),
+                                      jax.eval_shape(fn))
+
+    params = abstract(lambda: decoder_lm.init_params(cfg, 0))
+    cache = abstract(ops.init_state)
+    b, w = g["slots"], g["window"]
+    ints, flags = sds((b,), jnp.int32), sds((b,), jnp.bool_)
+
+    def chunk(params, cache, lengths, tokens, active):
+        def body(carry, _):
+            cache, ln, tk, ac = carry
+            logits, cache = model.decode(params, cache, ops, tk, ln, ac)
+            nxt = jnp.where(ac, jnp.argmax(logits, -1).astype(jnp.int32), tk)
+            return (cache, ln + ac, nxt, ac), nxt
+
+        return jax.lax.scan(body, (cache, lengths, tokens, active), None,
+                            length=1)
+
+    def verify(params, cache, lengths, window, active, write_mask):
+        logits, cache = model.verify(params, cache, ops, window, lengths,
+                                     active, write_mask)
+        return cache, jnp.argmax(logits, -1)
+
+    def prefill(params, cache, dest, prompt, length):
+        logits, kvs = model.prefill(params, prompt[None], length[None])
+        for i, (k, v) in enumerate(kvs):
+            cache = ops.write_prompt(cache, i, k[0], v[0], dest, length)
+        return cache, jnp.argmax(logits[0, length - 1])
+
+    def resume(params, cache, toks, start, length, slot):
+        mask = jnp.arange(b, dtype=jnp.int32) == slot
+
+        def body(cache, i):
+            pos = start + i
+            logits, cache = model.decode(
+                params, cache, ops, jnp.where(mask, toks[i], 0),
+                jnp.full((b,), pos, jnp.int32), mask & (pos < length))
+            return cache, jnp.argmax(logits, -1)
+
+        return jax.lax.scan(body, cache, jnp.arange(g["page_size"]))
+
+    scalar = sds((), jnp.int32)
+    return {
+        "chunk": (chunk, (params, cache, ints, ints, flags)),
+        "verify": (verify, (params, cache, ints, sds((b, w), jnp.int32),
+                            flags, sds((b, w), jnp.bool_))),
+        "prefill": (prefill, (params, cache,
+                              sds((ops.pages_per_slot,), jnp.int32),
+                              sds((g["bucket"],), jnp.int32), scalar)),
+        "resume": (resume, (params, cache, sds((g["page_size"],), jnp.int32),
+                            scalar, scalar, scalar)),
+    }[name], ops.num_rows
+
+
+@pytest.mark.parametrize("exe", ["chunk", "verify", "prefill", "resume"])
+def test_serving_executables_leave_the_pool_in_place(chip, monkeypatch, exe):
+    """The decode chunk, the verify window, a prefill bucket and the resume
+    scan write the 1.2 GB pool where it lies and hand it to the kernel
+    whole: no ``copy``, ``slice``, ``dynamic-slice`` or ``transpose`` with
+    the pool's row count in its result or an operand, both pools aliased
+    from input to output, and temporaries far under one layer of the pool.
+    (A ``[n_layer, rows, H, D]`` pool failed all three: the chip's compiler
+    stored it rows-minor, converted it whole around the row scatters, and
+    sliced and copied a layer for every kernel call: 2.5 GB of temporaries
+    a decode step.) Lowered over abstract shapes: nothing is allocated."""
+    # `auto` compiles the kernel where the default backend is the TPU; here
+    # the backend is the CPU and only the compile's target is the chip
+    monkeypatch.setattr(attention_ops, "paged_kernel_mode",
+                        lambda: "compiled")
+    (fn, args), rows = _serve_case(exe, chip)
+    compiled = jax.jit(fn, donate_argnums=(1,)).lower(*args).compile()
+    text = compiled.as_text()
+    if exe != "prefill":  # the prefill attends over its own K and V
+        assert text.count("tpu_custom_call") == SERVE["n_layer"]
+    instructions = list(_instructions(text))
+    types = {name: rtype for name, rtype, _, _ in instructions}
+    moved = [(op, rtype) for _, rtype, op, operands in instructions
+             if op in ("copy", "copy-start", "slice", "dynamic-slice",
+                       "transpose")
+             and any(_has_dim(t, rows) for t in
+                     [rtype] + [types.get(o, "") for o in operands])]
+    assert moved == []
+    # cache is argument 1 of every executable: its leaves follow the
+    # params' in the flattened parameter list, "k" "pt" "v" in key order
+    n_params = len(jax.tree_util.tree_leaves(args[0]))
+    aliased = {int(p) for p in re.findall(
+        r"\((\d+), \{\}, (?:may|must)-alias\)", text.split("\n", 1)[0])}
+    assert {n_params, n_params + 2} <= aliased
+    assert compiled.memory_analysis().temp_size_in_bytes < 64 * 2 ** 20

@@ -878,6 +878,41 @@ class TestPagePayloadLayouts:
         assert np.array_equal(got_ks, want_ks), \
             "imported pages dequantize with the wrong scales"
 
+    @pytest.mark.parametrize("kind", ["bf16", "int8"])
+    def test_flat_pool_ships_the_head_split_wire_format(self, kind):
+        """The pool is stored ``[n_layer, rows, H*D]``; the wire format is
+        C-order bytes of ``[n_layer, n_pages*page_size, H, D]``. The two
+        are the same bytes: an export equals ``np.ascontiguousarray`` of
+        the head-split view's rows, and a payload built from that view
+        alone imports into the flat pool and re-exports bit for bit."""
+        import jax.numpy as jnp
+        import numpy as np
+
+        from paddle_tpu.serving.kv_cache import (Int8PagedKVCache,
+                                                 PagedKVCache)
+
+        geom = dict(n_layer=3, n_head=2, d_head=4, slots=2, max_ctx=32,
+                    page_size=8, num_pages=6)
+        cache = (PagedKVCache(dtype=jnp.bfloat16, **geom) if kind == "bf16"
+                 else Int8PagedKVCache(k_scale=0.5, v_scale=0.25, **geom))
+        state = self._fill(cache, cache.init_state(), seed=7)
+        assert state["k"].shape == (3, 6 * 8, 2 * 4)
+        pages = [4, 1]
+        rows = np.concatenate([np.arange(p * 8, p * 8 + 8) for p in pages])
+        meta, blobs = cache.export_pages(state, pages)
+        for name, blob in zip("kv", blobs):
+            old = np.asarray(state[name]).reshape(3, 6 * 8, 2, 4)
+            assert blob == np.ascontiguousarray(old[:, rows]).tobytes(), name
+        assert (meta["n_head"], meta["d_head"]) == (2, 4)
+        assert meta["kv_dtype"] == ("bfloat16" if kind == "bf16" else "int8")
+        dst = cache.import_pages(cache.init_state(), [0, 5], meta, blobs)
+        assert cache.export_pages(dst, [0, 5])[1] == blobs
+        got = np.asarray(dst["k"]).reshape(3, 6 * 8, 2, 4)
+        want = np.asarray(state["k"]).reshape(3, 6 * 8, 2, 4)[:, rows]
+        dst_rows = np.concatenate([np.arange(p * 8, p * 8 + 8)
+                                   for p in [0, 5]])
+        assert np.array_equal(got[:, dst_rows], want)
+
     def test_contiguous_layout_refuses_typed(self):
         """The dense layout has no addressable page unit: both directions
         refuse with ValueError — callers surface 'migration unsupported',

@@ -40,10 +40,10 @@ def _restore_flag():
 
 
 def make_pool(rng, slots, pages_per_slot, num_pages, page_size, h, d):
-    """Synthetic one-layer paged KV pool + a permuted page table, the
-    layout PagedKVCache hands the kernel."""
-    k = rng.randn(num_pages * page_size, h, d).astype(np.float32)
-    v = rng.randn(num_pages * page_size, h, d).astype(np.float32)
+    """Synthetic one-layer paged KV pool ``[rows, H*D]`` + a permuted page
+    table: one layer of the layout PagedKVCache hands the kernel."""
+    k = rng.randn(num_pages * page_size, h * d).astype(np.float32)
+    v = rng.randn(num_pages * page_size, h * d).astype(np.float32)
     pt = np.stack([rng.permutation(num_pages)[:pages_per_slot]
                    for _ in range(slots)]).astype(np.int32)
     q = rng.randn(slots, h, d).astype(np.float32)
@@ -87,6 +87,53 @@ def test_garbage_pages_move_no_output_bit(rng):
                                     ctx, page_size=ps, block_pages=2,
                                     interpret=True)
     np.testing.assert_array_equal(np.asarray(got), np.asarray(clean))
+
+
+@pytest.mark.parametrize("layer", [0, 1, 2])
+def test_layer_of_a_pool_is_bit_equal_to_the_layer_alone(rng, layer):
+    """The engine's entry: the whole ``[n_layer, rows, H*D]`` pool and a
+    layer, indexed inside the kernel's page DMA. Neighbouring layers are
+    poisoned; the output must be the single-layer call's, bit for bit —
+    for a Python int and for a traced scalar alike."""
+    import jax
+
+    slots, h, d, ps, pps = 4, 2, 16, 8, 4
+    q, k, v, pt = make_pool(rng, slots, pps, 16, ps, h, d)
+    ctx = jnp.asarray([1, 8, 19, 32], jnp.int32)
+    alone = pa.paged_decode_attention(q, k, v, pt, ctx, page_size=ps,
+                                      block_pages=2, interpret=True)
+
+    def pooled(x, fill):
+        layers = [jnp.full_like(x, fill * (i + 1)) for i in range(3)]
+        layers[layer] = x
+        return jnp.stack(layers)
+
+    kp, vp = pooled(k, 1e4), pooled(v, -1e4)
+    got = pa.paged_decode_attention(q, kp, vp, pt, ctx, page_size=ps,
+                                    layer=layer, block_pages=2,
+                                    interpret=True)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(alone))
+    traced = jax.jit(lambda l: pa.paged_decode_attention(
+        q, kp, vp, pt, ctx, page_size=ps, layer=l, block_pages=2,
+        interpret=True))(jnp.int32(layer))
+    np.testing.assert_array_equal(np.asarray(traced), np.asarray(alone))
+
+
+def test_pool_shape_errors_are_typed(rng):
+    """A pool without a layer, a layer outside the pool, and a ``[rows, H,
+    D]`` layer (the layout this kernel no longer takes) all raise before
+    anything is traced."""
+    q, k, v, pt = make_pool(rng, 2, 2, 4, 8, 2, 8)
+    ctx = jnp.asarray([3, 9], jnp.int32)
+    kw = dict(page_size=8, interpret=True)
+    with pytest.raises(ValueError, match="with a layer"):
+        pa.paged_decode_attention(q, k[None], v[None], pt, ctx, **kw)
+    with pytest.raises(ValueError, match="outside a pool of 1"):
+        pa.paged_decode_attention(q, k[None], v[None], pt, ctx, layer=1,
+                                  **kw)
+    with pytest.raises(ValueError, match="with a layer"):
+        pa.paged_decode_attention(q, k.reshape(-1, 2, 8),
+                                  v.reshape(-1, 2, 8), pt, ctx, **kw)
 
 
 # -- engine-level parity + sampling ------------------------------------------
