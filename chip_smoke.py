@@ -1,6 +1,6 @@
 """The standing proof that the trainer and the server start on the TPU.
 
-    python chip_smoke.py            one chip: train, ctr, kernels, serve
+    python chip_smoke.py            one chip: train, ctr, kernels, serve, serve_moe
     python chip_smoke.py --chips 4  four chips: the data-parallel phase only
 
 One process, no children, no JAX_PLATFORMS set here: a chip belongs to the
@@ -47,6 +47,14 @@ SIZES = {
                   max_seq=1024, page_size=16, slots=8, requests=8,
                   prompt_min=16, prompt_max=512, new_tokens=32,
                   buckets=(128, 512), reference_requests=2),
+    # SmallThinker's block (models/smallthinker.py) small, but at the
+    # geometry the chip's compiler judges: heads of 128, 7 query heads a KV
+    # head, a window that the longer request's context passes
+    "serve_moe": dict(vocab=1024, n_layer=4, d_model=256, n_head=14,
+                      n_kv_head=2, d_head=128, n_expert=8, top_k=3,
+                      d_expert=128, window=256, max_seq=1024, page_size=16,
+                      slots=4, prompts=(40, 200), new_tokens=120,
+                      buckets=(64, 256)),
     "dp": dict(steps=4),
 }
 
@@ -568,6 +576,91 @@ def phase_serve(seed, meter):
             "tune": tune_layers()}
 
 
+def phase_serve_moe(seed, meter):
+    """The sparse decoder through the same engine: two cache groups, the
+    grouped-query kernel, the dropless expert layer; greedy tokens against
+    its own float32 reference, one context past the window."""
+    import jax
+    import numpy as np
+
+    from paddle_tpu.models import smallthinker_reference as reference
+    from paddle_tpu.models.smallthinker import (
+        SmallThinkerConfig, SmallThinkerLM)
+    from paddle_tpu.serving import ServingConfig, ServingEngine
+
+    cfg = SIZES["serve_moe"]
+    pattern = ([0, 1, 1, 1] * cfg["n_layer"])[:cfg["n_layer"]]
+    mcfg = SmallThinkerConfig(
+        vocab_size=cfg["vocab"], n_layer=cfg["n_layer"],
+        d_model=cfg["d_model"], n_head=cfg["n_head"],
+        n_kv_head=cfg["n_kv_head"], d_head=cfg["d_head"],
+        n_expert=cfg["n_expert"], top_k=cfg["top_k"],
+        d_expert=cfg["d_expert"], window=cfg["window"], rope_layout=pattern,
+        window_layout=pattern, max_seq=cfg["max_seq"], dtype="float32")
+    published = {
+        "num_attention_heads": cfg["n_head"],
+        "num_key_value_heads": cfg["n_kv_head"],
+        "moe_num_active_primary_experts": cfg["top_k"],
+        "rope_layout": pattern, "sliding_window_layout": pattern,
+        "sliding_window_size": cfg["window"], "rope_theta": mcfg.rope_theta,
+        "rms_norm_eps": mcfg.rms_eps}
+    rng = np.random.RandomState(seed)
+    prompts = [rng.randint(0, cfg["vocab"], (n,)).tolist()
+               for n in cfg["prompts"]]
+    # one stated precision on both sides, as in phase_serve
+    with jax.default_matmul_precision("highest"):
+        model = SmallThinkerLM(mcfg, seed=seed)
+        engine = ServingEngine(model, ServingConfig(
+            slots=cfg["slots"], page_size=cfg["page_size"],
+            max_seq=cfg["max_seq"], prompt_buckets=cfg["buckets"]))
+        with engine:
+            kernel_info = engine.decode_kernel_info()
+            engine.warmup()
+            reqs = [engine.submit(p, cfg["new_tokens"]) for p in prompts]
+            peak = 0
+            while not engine.scheduler.idle():
+                engine.step()
+                peak = max(peak, engine.pools[1].num_used)
+            accounting = engine.page_accounting_ok()
+        check(all(r.state == "finished" for r in reqs),
+              "not every request finished: %s" % [r.state for r in reqs])
+        check(accounting, "page accounting does not balance in every group")
+        ring = cfg["window"] // cfg["page_size"]
+        check(peak <= ring * len(reqs),
+              "the window group held %d pages, over %d a slot" % (peak, ring))
+        longest = max(cfg["prompts"]) + cfg["new_tokens"]
+        check(longest > cfg["window"], "no context passed the window")
+        for prompt, req in zip(prompts, reqs):
+            got = list(req.tokens_out)
+            seq = prompt + got[:-1]
+            first = len(prompt) - 1
+            rows = reference.forward(
+                model.params, published, np.asarray(seq, np.int32),
+                rows=np.arange(first, first + len(got)))
+            want = [int(t) for t in np.asarray(rows).argmax(-1)]
+            check(got == want,
+                  "prompt %d: engine %s != reference %s"
+                  % (len(prompt), got, want))
+    if on_tpu():
+        check(kernel_info[0] == "paged",
+              "default flags did not arm the paged kernel at 7 query heads "
+              "a KV head: %s" % (kernel_info,))
+    return {"checked": "%d requests (prompts %s, %d tokens each, the longest "
+                       "context %d past the window of %d) through two cache "
+                       "groups: greedy tokens == the float32 reference's "
+                       "(models/smallthinker_reference.py); the window group "
+                       "never over %d pages a slot; both pools balance"
+                       % (len(reqs), list(cfg["prompts"]), cfg["new_tokens"],
+                          longest, cfg["window"], ring),
+            "matmul_precision": "highest",
+            "kernel_path": {"decode_attention": kernel_info[0],
+                            "prefill_attention": "composed, banded past "
+                                                 "the window",
+                            "experts": "ragged_dot"},
+            "decode_kernel_info": list(kernel_info),
+            "tune": tune_layers()}
+
+
 # -- data parallel (--chips 4) ------------------------------------------------
 
 
@@ -633,7 +726,8 @@ def phase_data_parallel(seed, meter):
 # -- driver --------------------------------------------------------------------
 
 PHASES = {1: (("train", phase_train), ("ctr", phase_ctr),
-              ("kernels", phase_kernels), ("serve", phase_serve)),
+              ("kernels", phase_kernels), ("serve", phase_serve),
+              ("serve_moe", phase_serve_moe)),
           4: (("data_parallel", phase_data_parallel),)}
 
 
@@ -675,7 +769,7 @@ def run_phase(name, fn, seed, meter):
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--chips", type=int, choices=sorted(PHASES), default=1,
-                    help="1: train, ctr, kernels, serve. 4: the "
+                    help="1: train, ctr, kernels, serve, serve_moe. 4: the "
                          "data-parallel phase and what it is compared with, "
                          "and no other")
     ap.add_argument("--seed", type=int, default=0)

@@ -332,6 +332,17 @@ def decode_attention(q, ctx_k, ctx_v, ctx_len, sm_scale=1.0):
     ``FLAGS_paged_attention_kernel``, see :func:`paged_kernel_mode` and
     ``serving.kv_cache.PagedKVCache.decode_attention``).
     """
+    if q.shape[1] != ctx_k.shape[2]:
+        # grouped queries: q [B, H*G, D], query head n on KV head n // G
+        b, hq, d = q.shape
+        h = ctx_k.shape[2]
+        scores = jnp.einsum("bhgd,blhd->bhgl", q.reshape(b, h, hq // h, d),
+                            ctx_k) * sm_scale
+        mask = (jnp.arange(ctx_k.shape[1])[None, None, None, :]
+                < ctx_len[:, None, None, None])
+        scores = jnp.where(mask, scores, neg_inf(scores.dtype))
+        probs = jax.nn.softmax(scores, axis=-1)
+        return jnp.einsum("bhgl,blhd->bhgd", probs, ctx_v).reshape(b, hq, d)
     scores = jnp.einsum("bhd,blhd->bhl", q, ctx_k) * sm_scale
     mask = jnp.arange(ctx_k.shape[1])[None, None, :] < ctx_len[:, None, None]
     scores = jnp.where(mask, scores, neg_inf(scores.dtype))
@@ -378,3 +389,76 @@ def sdpa_op(ctx: OpContext):
     p = 0.0 if ctx.is_test else ctx.attr("dropout_rate", 0.0)
     rng = ctx.rng() if p > 0.0 else None
     ctx.set_output("Out", sdpa(q, k, v, bias, seg_q, seg_kv, causal, sm_scale, p, rng))
+
+
+def gqa_causal_attention(q, k, v, sm_scale=1.0):
+    """Causal attention of ONE sequence with grouped queries: ``q`` [S, Hq,
+    D], ``k``/``v`` [S, Hkv, D], query head n reading KV head ``n // (Hq //
+    Hkv)``. Where the flash kernel's gate admits the shape (the chip, S >=
+    ``FLAGS_flash_attention_min_seq``) K and V are repeated over their
+    query heads and handed to :func:`sdpa`, so no S x S tensor exists at
+    long S; below it the scores are composed here, grouped, with the
+    softmax in float32. Returns [S, Hq, D]."""
+    s, hq, d = q.shape
+    hkv = k.shape[1]
+    g = hq // hkv
+    with jax.named_scope("attn/global"):
+        qh = q.transpose(1, 0, 2)[None]
+        if _flash_ok(qh, qh, True):
+            kr = jnp.repeat(k, g, axis=1).transpose(1, 0, 2)[None]
+            vr = jnp.repeat(v, g, axis=1).transpose(1, 0, 2)[None]
+            o = sdpa(qh, kr, vr, causal=True, sm_scale=sm_scale)
+            return o[0].transpose(1, 0, 2)
+        sc = jnp.einsum("qhgd,khd->hgqk", q.reshape(s, hkv, g, d), k,
+                        preferred_element_type=jnp.float32) * sm_scale
+        sc = jnp.where(jnp.tril(jnp.ones((s, s), bool))[None, None], sc,
+                       neg_inf(jnp.float32))
+        p = jax.nn.softmax(sc, axis=-1)
+        o = jnp.einsum("hgqk,khd->qhgd", p.astype(v.dtype), v,
+                       preferred_element_type=jnp.float32)
+        return o.astype(q.dtype).reshape(s, hq, d)
+
+
+def windowed_causal_attention(q, k, v, window: int, sm_scale=1.0,
+                              block_q: int = 512):
+    """Causal attention of ONE sequence in which position i sees ``j <= i``
+    with ``i - j < window``, with grouped queries as in
+    :func:`gqa_causal_attention`. At ``S <= window`` the window hides
+    nothing and this IS the causal attention. Beyond it the queries go in
+    blocks of ``block_q`` rows, each against the ``window + block_q`` keys
+    that can reach it: O(S x window) work, and the largest score tensor is
+    [Hkv, G * block_q, window + block_q], never S x S. Softmax in
+    float32. Returns [S, Hq, D]."""
+    s, hq, d = q.shape
+    hkv = k.shape[1]
+    g = hq // hkv
+    if s <= window:
+        return gqa_causal_attention(q, k, v, sm_scale)
+    with jax.named_scope("attn/window"):
+        bq = block_q
+        while s % bq:
+            bq //= 2
+        span = window + bq
+        # keys of block b: absolute positions [b*bq - window, b*bq + bq)
+        kp = jnp.pad(k, ((window, 0), (0, 0), (0, 0))).transpose(1, 0, 2)
+        vp = jnp.pad(v, ((window, 0), (0, 0), (0, 0))).transpose(1, 0, 2)
+        qb = q.reshape(s // bq, bq, hkv, g, d)
+        rows = jnp.arange(bq)[:, None]
+        cols = jnp.arange(span)[None, :] - window   # relative to b*bq
+
+        def block(args):
+            b, qi = args                     # qi [bq, Hkv, G, D]
+            kb = jax.lax.dynamic_slice_in_dim(kp, b * bq, span, axis=1)
+            vb = jax.lax.dynamic_slice_in_dim(vp, b * bq, span, axis=1)
+            sc = jnp.einsum("qhgd,hkd->hgqk", qi, kb,
+                            preferred_element_type=jnp.float32) * sm_scale
+            ok = (cols <= rows) & (rows - cols < window) \
+                & (cols + b * bq >= 0)
+            sc = jnp.where(ok[None, None], sc, neg_inf(jnp.float32))
+            p = jax.nn.softmax(sc, axis=-1)
+            return jnp.einsum("hgqk,hkd->qhgd", p.astype(vb.dtype), vb,
+                              preferred_element_type=jnp.float32
+                              ).astype(q.dtype)
+
+        out = jax.lax.map(block, (jnp.arange(s // bq), qb))
+        return out.reshape(s, hq, d)

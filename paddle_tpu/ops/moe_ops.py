@@ -1,0 +1,97 @@
+"""Dropless top-k expert layer for the serving path.
+
+``parallel/moe.py`` is top-1 Switch with a capacity factor, for training:
+a token over capacity is dropped. A served token cannot be: every routed
+(token, expert) pair is computed here, with no capacity and no drops, by
+sorting the pairs by expert and running ONE grouped matmul over the experts
+that received rows (``jax.lax.ragged_dot``; on the TPU the compiler lowers
+it to its own grouped-matmul kernel, which reads an expert's weights only
+where its group has rows). The same function serves the decode step (16
+rows x 6) and the prefill (8,192 rows x 6).
+
+The layer is told which experts it HOLDS and routes over all of them: a
+pair routed to an expert that lives on another chip contributes nothing
+here (that chip adds its part), so the one-chip share of a wider
+deployment is this function with a shorter ``held``. On one chip ``held``
+is every expert.
+
+Precision: the router's logits accumulate in float32 and its softmax is
+float32; the experts' outputs are combined in float32.
+"""
+
+from __future__ import annotations
+
+from typing import Dict, Optional, Sequence, Tuple
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+__all__ = ["route_topk", "expert_layer"]
+
+
+def route_topk(h, wr, top_k: int) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """``h`` [N, d] through the router ``wr`` [d, E]: the ``top_k`` largest
+    logits a row and the softmax over THOSE (equal to the softmax over all
+    E kept at the chosen ones and renormalised). Returns ``(idx [N, k]
+    int32, w [N, k] float32)``."""
+    with jax.named_scope("moe/router"):
+        logits = jnp.dot(h, wr, preferred_element_type=jnp.float32)
+        top, idx = jax.lax.top_k(logits, top_k)
+        return idx.astype(jnp.int32), jax.nn.softmax(top, axis=-1)
+
+
+def expert_layer(u, idx, w, wg, wu, wd, n_expert: Optional[int] = None,
+                 held: Optional[Sequence[int]] = None, row_valid=None
+                 ) -> Tuple[jnp.ndarray, Dict[str, jnp.ndarray]]:
+    """``sum_k w[n, k] * (relu(u Wg_e) * (u Wu_e)) Wd_e`` with ``e = idx[n,
+    k]``, for the experts in ``held``.
+
+    ``u`` [N, d]; ``idx``/``w`` [N, k] from :func:`route_topk`; ``wg``/
+    ``wu`` [E_held, d, f] and ``wd`` [E_held, f, d] are the held experts'
+    weights in the order of ``held`` (global expert ids; default: all
+    ``n_expert`` = ``wg.shape[0]`` of them). ``row_valid`` [N] bool marks
+    the rows whose result is used (live decode slots, prompt positions
+    below the length): the others are sorted past the last group and never
+    computed. Returns ``(y [N, d] float32, stats)``; ``stats`` holds
+    ``experts_touched`` (held experts with at least one row) and
+    ``max_expert_rows`` (the largest group), int32 scalars.
+    """
+    n, _ = u.shape
+    k = idx.shape[1]
+    e_held = wg.shape[0]
+    n_expert = e_held if n_expert is None else int(n_expert)
+    if held is None:
+        if n_expert != e_held:
+            raise ValueError("%d experts held of %d: say which (held=)"
+                             % (e_held, n_expert))
+        local = idx
+    else:
+        held = [int(e) for e in held]
+        if len(held) != e_held:
+            raise ValueError("held names %d experts, the weights hold %d"
+                             % (len(held), e_held))
+        table = np.full((n_expert,), e_held, np.int32)
+        table[held] = np.arange(e_held, dtype=np.int32)
+        local = jnp.asarray(table)[idx]
+    with jax.named_scope("moe/experts"):
+        flat = local.reshape(n * k)
+        if row_valid is not None:
+            flat = jnp.where(jnp.repeat(row_valid, k), flat, e_held)
+        # pairs sorted by expert; the absent ones (another chip's experts,
+        # unused rows) carry the sentinel e_held and sort past every group
+        order = jnp.argsort(flat, stable=True)
+        sizes = jnp.zeros((e_held + 1,), jnp.int32).at[flat].add(1)[:e_held]
+        xs = u[order // k]
+        gate = jax.lax.ragged_dot(xs, wg, sizes)
+        up = jax.lax.ragged_dot(xs, wu, sizes)
+        out = jax.lax.ragged_dot(jax.nn.relu(gate) * up, wd, sizes)
+        # rows past the last group are whatever the grouped matmul left
+        out = jnp.where((flat[order] < e_held)[:, None], out, 0)
+        back = jnp.zeros((n * k,), jnp.int32).at[order].set(
+            jnp.arange(n * k, dtype=jnp.int32))
+        y = jnp.sum(out[back].reshape(n, k, -1).astype(jnp.float32)
+                    * w.astype(jnp.float32)[:, :, None], axis=1)
+        stats = {"experts_touched": jnp.sum(sizes > 0).astype(jnp.int32),
+                 "max_expert_rows": jnp.max(sizes).astype(jnp.int32)}
+    return y, stats

@@ -34,6 +34,17 @@ Design:
   ``[H*D, 128]``), and ``p @ seg.T`` spreads each head's probability back
   over its lanes. Both at full f32 precision, so the kernel agrees with
   the gather path to float round-off;
+- GROUPED QUERIES: ``q`` may carry ``G`` query heads for each KV head
+  (query head n reads KV head ``n // G``). It arrives in the kernel as ``[B,
+  G, H*D]`` (row g holds, for every KV head, its g-th query head), each
+  wave of pages is DMA'd ONCE and folded into all G online-softmax states.
+  With G > 1 the per-head sums go straight to the MXU: one ``[G, D] x [D,
+  rows]`` product a KV head for the scores and one ``[G, rows] x [rows, D]``
+  for the weighted sum, over the head's own lane slice of the row, which on
+  the chip has to be whole lane tiles (``D % 128 == 0``; the gate says so).
+  G = 1 keeps the head-membership matmuls above, which take any ``H*D %
+  128 == 0`` (GPT-2 small's heads of 64). One kernel, one DMA loop, the
+  fold chosen by the geometry;
 - grid is ``(slots,)``; the page table (flattened) and per-slot ``ctx_len``
   ride in SMEM via ``PrefetchScalarGridSpec`` scalar prefetch, so page
   addresses are known before the body runs;
@@ -85,7 +96,8 @@ _HIGHEST = jax.lax.Precision.HIGHEST
 
 
 def paged_attention_gate(dtype, n_head: int, d_head: int, page_size: int,
-                         interpret: bool = False) -> Optional[str]:
+                         interpret: bool = False, q_per_kv: int = 1
+                         ) -> Optional[str]:
     """None when the kernel takes this cache geometry, else the rule that
     excludes it (what ``ServingEngine.decode_kernel_info`` reports when
     ``auto`` keeps the XLA gather path). The dtype rule always holds; the
@@ -100,6 +112,10 @@ def paged_attention_gate(dtype, n_head: int, d_head: int, page_size: int,
     if hd % _LANES or hd > _MAX_ROW_WIDTH:
         return ("n_head*d_head=%d is not a multiple of %d up to %d"
                 % (hd, _LANES, _MAX_ROW_WIDTH))
+    if q_per_kv > 1 and int(d_head) % _LANES:
+        return ("%d query heads a KV head need d_head=%d to be a multiple "
+                "of %d (a head's lanes are sliced out of the row)"
+                % (q_per_kv, d_head, _LANES))
     sublanes = 32 // dt.itemsize  # rows per tile: 8 for f32, 16 for bf16
     if page_size % sublanes:
         return ("page_size=%d is not a multiple of the %s tile's %d rows"
@@ -108,9 +124,10 @@ def paged_attention_gate(dtype, n_head: int, d_head: int, page_size: int,
 
 
 def paged_attention_supported(dtype, n_head: int, d_head: int,
-                              page_size: int, interpret: bool = False) -> bool:
+                              page_size: int, interpret: bool = False,
+                              q_per_kv: int = 1) -> bool:
     return paged_attention_gate(dtype, n_head, d_head, page_size,
-                                interpret) is None
+                                interpret, q_per_kv) is None
 
 
 def _default_block_pages(page_size: int, pages_per_slot: int, hd: int,
@@ -168,16 +185,19 @@ def _page_dma(pool_ref, scr_ref, sem, layer, row, slot_row, ps):
 def _paged_attn_kernel(pt_ref, len_ref, layer_ref, q_ref, seg_ref, segt_ref,
                        k_hbm, v_hbm, o_ref, k_scr, v_scr, sems, *,
                        block_pages, page_size, pages_per_slot, num_pages,
-                       sm_scale, mask_value):
+                       sm_scale, mask_value, d_head, grouped):
     b = pl.program_id(0)
     ps = page_size
     ctx = len_ref[b]
     layer = layer_ref[0]
-    q = q_ref[0].astype(jnp.float32) * sm_scale  # [1, HD]
+    q = q_ref[0].astype(jnp.float32)  # [G, HD] (G = 1 where not grouped)
     seg = seg_ref[...]    # [HD, HP]: lane j belongs to head seg[j].argmax()
     segt = segt_ref[...]  # [HP, HD]
     hd, hp = seg.shape
     rows = block_pages * ps
+    n_kv = hd // d_head
+    gq = q.shape[0]
+    qs = q * sm_scale     # the one-state-a-head fold scales its query once
 
     def per_head(x):
         """[R, HD] -> [R, HP]: sum each head's lanes."""
@@ -205,8 +225,6 @@ def _paged_attn_kernel(pt_ref, len_ref, layer_ref, q_ref, seg_ref, segt_ref,
         return (pidx < pages_per_slot) & (pidx * ps < ctx)
 
     def wave_body(w, carry):
-        m, l, acc = carry
-
         def start(i, _):
             @pl.when(page_valid(i, w))
             def _():
@@ -243,7 +261,10 @@ def _paged_attn_kernel(pt_ref, len_ref, layer_ref, q_ref, seg_ref, segt_ref,
         # the exactly-0 probabilities below cannot meet an Inf/NaN residue
         kb = jnp.where(valid, k_scr[...].astype(jnp.float32), 0.0)  # [R,HD]
         vb = jnp.where(valid, v_scr[...].astype(jnp.float32), 0.0)
-        s = jnp.where(valid, per_head(kb * q), mask_value)  # [R,HP]
+        if grouped:
+            return fold_grouped(w, kb, vb, carry)
+        m, l, acc = carry
+        s = jnp.where(valid, per_head(kb * qs), mask_value)
         m_new = jnp.maximum(m, jnp.max(s, axis=0, keepdims=True))  # [1,HP]
         alpha = jnp.exp(m - m_new)
         p = jnp.exp(s - m_new)  # masked rows underflow to exactly 0.0
@@ -252,15 +273,53 @@ def _paged_attn_kernel(pt_ref, len_ref, layer_ref, q_ref, seg_ref, segt_ref,
                    + jnp.sum(over_lanes(p) * vb, axis=0, keepdims=True))
         return m_new, l_new, acc_new
 
+    def fold_grouped(w, kb, vb, carry):
+        """The wave folded into the G states of every KV head: scores
+        ``[G, R]`` and weighted sums ``[G, D]`` on the MXU, a head's lanes
+        sliced out of the flat row."""
+        ms, ls, accs = carry
+        pos = (w * rows
+               + jax.lax.broadcasted_iota(jnp.int32, (1, rows), 1))  # [1,R]
+        valid = pos < ctx
+        out_m, out_l, out_acc = [], [], []
+        for h in range(n_kv):
+            lanes = slice(h * d_head, (h + 1) * d_head)
+            s = jax.lax.dot_general(
+                q[:, lanes], kb[:, lanes], (((1,), (1,)), ((), ())),
+                precision=_HIGHEST,
+                preferred_element_type=jnp.float32) * sm_scale   # [G,R]
+            s = jnp.where(valid, s, mask_value)
+            m_new = jnp.maximum(ms[h], jnp.max(s, axis=1, keepdims=True))
+            alpha = jnp.exp(ms[h] - m_new)                        # [G,1]
+            p = jnp.exp(s - m_new)
+            out_m.append(m_new)
+            out_l.append(ls[h] * alpha + jnp.sum(p, axis=1, keepdims=True))
+            out_acc.append(accs[h] * alpha + jnp.dot(
+                p, vb[:, lanes], precision=_HIGHEST,
+                preferred_element_type=jnp.float32))              # [G,D]
+        return tuple(out_m), tuple(out_l), tuple(out_acc)
+
+    n_waves = -(-pages_per_slot // block_pages)
+    live_waves = jnp.minimum((ctx + rows - 1) // rows, n_waves)
+    # ctx_len >= 1 in the engine (position of the current token + 1); the
+    # clamps only guard a degenerate ctx_len <= 0 call from dividing 0/0
+    tiny = jnp.asarray(1e-30, jnp.float32)
+    if grouped:
+        init = (tuple(jnp.full((gq, 1), mask_value, jnp.float32)
+                      for _ in range(n_kv)),
+                tuple(jnp.zeros((gq, 1), jnp.float32) for _ in range(n_kv)),
+                tuple(jnp.zeros((gq, d_head), jnp.float32)
+                      for _ in range(n_kv)))
+        _, ls, accs = jax.lax.fori_loop(0, live_waves, wave_body, init)
+        out = jnp.concatenate(
+            [accs[h] / jnp.maximum(ls[h], tiny) for h in range(n_kv)], axis=1)
+        o_ref[0] = out.astype(o_ref.dtype)
+        return
     m0 = jnp.full((1, hp), mask_value, jnp.float32)
     l0 = jnp.zeros((1, hp), jnp.float32)
     acc0 = jnp.zeros((1, hd), jnp.float32)
-    n_waves = -(-pages_per_slot // block_pages)
-    live_waves = jnp.minimum((ctx + rows - 1) // rows, n_waves)
     m, l, acc = jax.lax.fori_loop(0, live_waves, wave_body, (m0, l0, acc0))
-    # ctx_len >= 1 in the engine (position of the current token + 1); the
-    # clamp only guards a degenerate ctx_len <= 0 call from dividing 0/0
-    out = acc / jnp.maximum(row_over_lanes(l), jnp.asarray(1e-30, jnp.float32))
+    out = acc / jnp.maximum(row_over_lanes(l), tiny)
     o_ref[0] = out.astype(o_ref.dtype)
 
 
@@ -269,34 +328,38 @@ def paged_decode_attention(q, k_pages, v_pages, page_table, ctx_len, *,
                            block_pages=None, interpret: bool = False):
     """Fused ragged paged decode attention.
 
-    ``q`` [B,H,D] — current position's query per slot. ``k_pages``/
+    ``q`` [B,Hq,D] — current position's query per slot, ``Hq`` = ``G * H``
+    query heads over the pool's ``H`` KV heads (G = 1: one each; G > 1:
+    grouped queries, query head n on KV head ``n // G``). ``k_pages``/
     ``v_pages`` [n_layer, num_pages*page_size, H*D] — the WHOLE paged KV
     pool (serving.kv_cache.PagedKVCache state), of which the kernel reads
     layer ``layer`` (an int or an int32 scalar); or ONE layer as
     [num_pages*page_size, H*D] with ``layer`` left None. ``page_table`` [B,
     pages_per_slot] int32 — each slot's ordered page ids. ``ctx_len`` [B] —
-    valid leading positions per slot (must be >= 1 for slots whose output
+    valid leading rows per slot (must be >= 1 for slots whose output
     is consumed). ``block_pages=None`` = tuned-table lookup with the
-    analytic VMEM-budget fallback (see ``_block_pages``). Returns [B,H,D],
+    analytic VMEM-budget fallback (see ``_block_pages``). Returns [B,Hq,D],
     matching ``gather_reference`` (the XLA gather + decode_attention path)
     to float32 round-off on live rows and EXACTLY ignoring garbage beyond
     ``ctx_len``. Compiled (``interpret=False``) it takes the shapes
     :func:`paged_attention_gate` admits; callers gate on it.
     """
-    b, h, d = q.shape
-    hd = h * d
+    b, hq, d = q.shape
     slots, pages_per_slot = page_table.shape
     if slots != b:
         raise ValueError("page_table slots %d != q batch %d" % (slots, b))
     if k_pages.ndim == 2 and layer is None:
         # one layer is a pool of one: a leading 1 is free on tiled memory
         k_pages, v_pages, layer = k_pages[None], v_pages[None], 0
-    if k_pages.ndim != 3 or layer is None or k_pages.shape[2] != hd \
+    hd = k_pages.shape[-1]
+    h = hd // d
+    if k_pages.ndim != 3 or layer is None or hd % d or hq % max(h, 1) \
             or v_pages.shape != k_pages.shape:
         raise ValueError(
-            "pool must be [n_layer, rows, %d] with a layer, or one layer "
-            "[rows, %d] without: got k %s v %s layer=%r"
-            % (hd, hd, k_pages.shape, v_pages.shape, layer))
+            "pool must be [n_layer, rows, H*%d] with a layer, or one layer "
+            "[rows, H*%d] without, H dividing q's %d heads: got k %s v %s "
+            "layer=%r" % (d, d, hq, k_pages.shape, v_pages.shape, layer))
+    g = hq // h
     n_layer, num_rows = k_pages.shape[:2]
     if isinstance(layer, (int, np.integer)) and not 0 <= layer < n_layer:
         raise ValueError("layer %d outside a pool of %d" % (layer, n_layer))
@@ -314,21 +377,30 @@ def paged_decode_attention(q, k_pages, v_pages, page_table, ctx_len, *,
     hp = -(-h // _LANES) * _LANES
     seg = (np.arange(hd)[:, None] // d
            == np.arange(hp)[None, :]).astype(np.float32)
+    if g == 1:
+        gp, qk = 1, q.reshape(b, 1, hd)
+    else:
+        # row j of a slot: the j-th query head of every KV head, padded to
+        # whole sublanes with zero queries (uniform weights, sliced away)
+        gp = -(-g // 8) * 8
+        qk = q.reshape(b, h, g, d).transpose(0, 2, 1, 3).reshape(b, g, hd)
+        qk = jnp.pad(qk, ((0, 0), (0, gp - g), (0, 0)))
     kernel = functools.partial(
         _paged_attn_kernel, block_pages=bp, page_size=ps,
         pages_per_slot=pages_per_slot, num_pages=num_rows // ps,
-        sm_scale=float(sm_scale), mask_value=neg_inf_value(jnp.float32))
+        sm_scale=float(sm_scale), mask_value=neg_inf_value(jnp.float32),
+        d_head=d, grouped=g > 1)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
         grid=(b,),
         in_specs=[
-            pl.BlockSpec((1, 1, hd), lambda i, *_: (i, 0, 0)),  # q
+            pl.BlockSpec((1, gp, hd), lambda i, *_: (i, 0, 0)),  # q
             pl.BlockSpec((hd, hp), lambda i, *_: (0, 0)),       # seg
             pl.BlockSpec((hp, hd), lambda i, *_: (0, 0)),       # seg.T
             pl.BlockSpec(memory_space=pl.ANY),                  # K pool
             pl.BlockSpec(memory_space=pl.ANY),                  # V pool
         ],
-        out_specs=pl.BlockSpec((1, 1, hd), lambda i, *_: (i, 0, 0)),
+        out_specs=pl.BlockSpec((1, gp, hd), lambda i, *_: (i, 0, 0)),
         scratch_shapes=[
             pltpu.VMEM((bp * ps, hd), k_pages.dtype),
             pltpu.VMEM((bp * ps, hd), v_pages.dtype),
@@ -338,14 +410,16 @@ def paged_decode_attention(q, k_pages, v_pages, page_table, ctx_len, *,
     out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, 1, hd), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((b, gp, hd), q.dtype),
         interpret=interpret,
         name="paged_attention",
     )(page_table.reshape(-1).astype(jnp.int32),
       ctx_len.astype(jnp.int32), jnp.asarray(layer, jnp.int32).reshape(1),
-      q.reshape(b, 1, hd), jnp.asarray(seg), jnp.asarray(seg.T), k_pages,
-      v_pages)
-    return out.reshape(b, h, d)
+      qk, jnp.asarray(seg), jnp.asarray(seg.T), k_pages, v_pages)
+    if g == 1:
+        return out.reshape(b, hq, d)
+    return out[:, :g].reshape(b, g, h, d).transpose(0, 2, 1, 3).reshape(
+        b, hq, d)
 
 
 def gather_reference(q, k_pages, v_pages, page_table, ctx_len, page_size,
@@ -356,7 +430,8 @@ def gather_reference(q, k_pages, v_pages, page_table, ctx_len, page_size,
     supplies the SHARED neg_inf masking constant — the parity contract the
     selftest asserts)."""
     ps = int(page_size)
-    b, h, d = q.shape
+    b, _, d = q.shape
+    h = k_pages.shape[-1] // d
     rows = (page_table * ps)[:, :, None] + jnp.arange(ps)[None, None, :]
     rows = rows.reshape(b, -1)
     from ..attention_ops import decode_attention

@@ -38,7 +38,8 @@ from ..reliability import faults as _faults
 from . import metrics as _sm
 from . import speculative as _speculative
 from . import trace as _trace
-from .kv_cache import ContiguousKVCache, Int8PagedKVCache, PagedKVCache
+from .kv_cache import (CacheGroup, ContiguousKVCache, Int8PagedKVCache,
+                       PagedKVCache)
 from .page_pool import PagePool, PagePoolExhausted
 from .request import (FAILED, FINISHED, REJECTED, TIMEOUT, DrainingError,
                       Request)
@@ -107,7 +108,11 @@ class ServingConfig:
     budget (prompt + generated), a multiple of ``page_size``. ``num_pages``
     defaults to full-occupancy worst case (``slots * max_seq/page_size``);
     size it SMALLER to oversubscribe — admission then backpressures on the
-    pool instead of the slots. ``decode_fuse`` fuses that many decode steps
+    pool instead of the slots. It sizes the model's FIRST cache group (the
+    only one of a model whose layers all keep every position);
+    ``group_pages`` ``{group name: pages}`` sizes any group by name, each
+    defaulting to its own worst case (``slots`` times the group's pages a
+    slot: for a window group its ring). ``decode_fuse`` fuses that many decode steps
     into one dispatched scan (admission/retirement happen at chunk
     boundaries — latency trades against host dispatch overhead);
     ``decode_fuse="auto"`` consults the autotuned config table
@@ -168,7 +173,8 @@ class ServingConfig:
                  drain_timeout_s: float = 30.0,
                  kv_dtype: Optional[str] = None,
                  prefix_cache_pages: int = 0,
-                 speculation=None, spec_drafter: str = "ngram"):
+                 speculation=None, spec_drafter: str = "ngram",
+                 group_pages: Optional[Dict[str, int]] = None):
         if kv_dtype not in (None, "int8"):
             raise ValueError("kv_dtype must be None or 'int8', got %r"
                              % (kv_dtype,))
@@ -180,6 +186,7 @@ class ServingConfig:
         self.max_seq = int(max_seq)
         self.num_pages = (self.slots * (self.max_seq // self.page_size)
                           if num_pages is None else int(num_pages))
+        self.group_pages = dict(group_pages or {})
         self.prompt_buckets = tuple(sorted(
             prompt_buckets if prompt_buckets is not None
             else _pow2_buckets(min(8, max_seq), max_seq)))
@@ -252,11 +259,25 @@ class ServingEngine:
     """Drives a model implementing the serving contract:
 
     * ``model.cfg`` — exposes ``n_layer``/``n_head``/``d_head``/``max_seq``
-      /``dtype`` (models.decoder_lm.DecoderConfig shape),
+      /``dtype`` (models.decoder_lm.DecoderConfig shape); optionally
+      ``n_kv_head`` (the heads of K and V, which size the cache; default
+      ``n_head``: the queries then are not grouped) and ``cache_groups``,
+      a list of ``(name, layers, window)`` (default: one group of every
+      layer that keeps every position; see serving.kv_cache),
     * ``model.prefill(params, tokens[B,S], lengths[B]) -> (logits[B,S,V],
-      kvs)`` with ``kvs`` one ``(k, v)`` ``[B,S,H,D]`` pair per layer,
+      kvs)`` with ``kvs`` one ``(k, v)`` ``[B,S,H,D]`` pair per layer (H
+      the KV heads); a model with ``prefill_last`` is asked for that
+      instead: the same with ``logits[B,V]`` of each prompt's last row,
     * ``model.decode(params, cache, cache_ops, tokens[B], pos[B],
-      active[B]) -> (logits[B,V], cache)``.
+      active[B]) -> (logits[B,V], cache)`` or ``(logits, cache, stats)``
+      with ``stats`` a dict of small int arrays a step; the engine feeds
+      ``moe_experts_touched`` and ``moe_max_expert_rows`` [n_layer] to the
+      ``serving/*`` histograms of those names.
+
+    Over a cache of more than one group the engine refuses, at
+    construction, what cannot work there: speculative verify (a ring
+    cannot be rolled back), the int8 KV pool, the prefix cache and the
+    contiguous layout; page export/import raise when called.
     """
 
     def __init__(self, model, config: Optional[ServingConfig] = None,
@@ -269,28 +290,52 @@ class ServingEngine:
                 "model max_seq %d < serving max_seq %d (position table too "
                 "small for the context budget)" % (mcfg.max_seq, self.cfg.max_seq))
         self.params = params if params is not None else model.params
+        n_kv = getattr(mcfg, "n_kv_head", mcfg.n_head)
+        q_per_kv = mcfg.n_head // n_kv
+        layer_groups = getattr(mcfg, "cache_groups", None) or [
+            ("global", tuple(range(mcfg.n_layer)), None)]
+        if len(layer_groups) > 1:
+            self._refuse_over_groups(layer_groups)
+        self.pools: List[PagePool] = []
         if self.cfg.paged:
+            ps = self.cfg.page_size
+            groups = []
+            for gi, (name, layers, window) in enumerate(layer_groups):
+                rows = self.cfg.max_seq if window is None \
+                    else min(int(window), self.cfg.max_seq)
+                pages = self.cfg.group_pages.get(
+                    name, self.cfg.num_pages if gi == 0
+                    else self.cfg.slots * (rows // ps))
+                groups.append(CacheGroup(name, tuple(layers), window, pages))
+            unknown = set(self.cfg.group_pages) - {g.name for g in groups}
+            if unknown:
+                raise ValueError("group_pages names %s; the model's cache "
+                                 "groups are %s" % (sorted(unknown),
+                                                    [g.name for g in groups]))
             kv_scales = None
             if self.cfg.kv_dtype == "int8":
                 kv_scales = self._calibrated_kv_scales(mcfg)
+            geometry = dict(dtype=mcfg.dtype, groups=groups,
+                            q_per_kv=q_per_kv)
             if kv_scales is not None:
                 self.cache_ops = Int8PagedKVCache(
-                    mcfg.n_layer, mcfg.n_head, mcfg.d_head, self.cfg.slots,
-                    self.cfg.max_seq, self.cfg.page_size, self.cfg.num_pages,
-                    k_scale=kv_scales[0], v_scale=kv_scales[1],
-                    dtype=mcfg.dtype)
+                    mcfg.n_layer, n_kv, mcfg.d_head, self.cfg.slots,
+                    self.cfg.max_seq, ps, groups[0].num_pages,
+                    k_scale=kv_scales[0], v_scale=kv_scales[1], **geometry)
             else:
                 self.cache_ops = PagedKVCache(
-                    mcfg.n_layer, mcfg.n_head, mcfg.d_head, self.cfg.slots,
-                    self.cfg.max_seq, self.cfg.page_size, self.cfg.num_pages,
-                    dtype=mcfg.dtype)
-            self.pool: Optional[PagePool] = PagePool(
-                self.cfg.num_pages, self.cfg.page_size)
+                    mcfg.n_layer, n_kv, mcfg.d_head, self.cfg.slots,
+                    self.cfg.max_seq, ps, groups[0].num_pages, **geometry)
+            self.pools = [PagePool(g.num_pages, ps, name=g.name,
+                                   primary=(gi == 0))
+                          for gi, g in enumerate(groups)]
         else:
             self.cache_ops = ContiguousKVCache(
-                mcfg.n_layer, mcfg.n_head, mcfg.d_head, self.cfg.slots,
+                mcfg.n_layer, n_kv, mcfg.d_head, self.cfg.slots,
                 self.cfg.max_seq, dtype=mcfg.dtype)
-            self.pool = None
+        # the first group's pool, under the name a one-group engine's only
+        # pool always had
+        self.pool: Optional[PagePool] = self.pools[0] if self.pools else None
         self.scheduler = Scheduler(self.cfg.slots, self.cfg.max_queue,
                                    continuous=self.cfg.continuous)
         self._cache = self.cache_ops.init_state()
@@ -368,6 +413,22 @@ class ServingEngine:
                     "PADDLE_TPU_TELEMETRY_DIR is unset — no export ticks "
                     "will run, so the SLOs are inert (health() cannot "
                     "degrade on them)", len(specs))
+
+    def _refuse_over_groups(self, layer_groups) -> None:
+        """What cannot work over a cache of more than one group, said at
+        construction rather than computed wrong."""
+        cfg = self.cfg
+        names = [g[0] for g in layer_groups]
+        for on, what in (
+                (not cfg.paged, "the contiguous layout (paged=False)"),
+                (cfg.kv_dtype == "int8", "the int8 KV pool"),
+                (cfg.prefix_cache_pages > 0, "the prefix cache"),
+                (cfg.speculation > 0 and hasattr(self.model, "verify"),
+                 "speculative verify")):
+            if on:
+                raise ValueError(
+                    "%s is not supported over a cache with %d groups %s"
+                    % (what, len(names), names))
 
     @staticmethod
     def _calibrated_kv_scales(mcfg):
@@ -454,11 +515,12 @@ class ServingEngine:
             raise ValueError(
                 "prompt+max_new_tokens=%d exceeds max_seq=%d" %
                 (total, self.cfg.max_seq))
-        if self.pool is not None and \
-                self.pool.pages_needed(total) > self.pool.num_pages:
-            raise ValueError(
-                "request needs %d pages but the pool only has %d"
-                % (self.pool.pages_needed(total), self.pool.num_pages))
+        for gi, pool in enumerate(self.pools):
+            need = self.cache_ops.pages_needed(gi, total)
+            if need > pool.num_pages:
+                raise ValueError(
+                    "request needs %d pages but the %s pool only has %d"
+                    % (need, pool.name, pool.num_pages))
         req = self.scheduler.submit(req)
         _trace.on_submitted(req)
         return req
@@ -587,7 +649,7 @@ class ServingEngine:
 
             _c, src = tune.lookup(
                 "paged_attention",
-                tune.bucket_ctx(self.cfg.max_seq, mcfg.n_head * mcfg.d_head))
+                tune.bucket_ctx(self.cfg.max_seq, self.cache_ops.row_width))
         except Exception:
             src = "default"
         return "paged", src
@@ -631,6 +693,8 @@ class ServingEngine:
         if self.pool is not None:
             out["pages_in_use"] = self.pool.num_used
             out["page_pool_utilization"] = round(self.pool.utilization, 4)
+            out["pages_by_group"] = {p.name: [p.num_used, p.num_pages]
+                                     for p in self.pools}
         if self.prefix_cache is not None:
             out["prefix_cache"] = self.prefix_cache.stats()
         return out
@@ -668,14 +732,17 @@ class ServingEngine:
         return out
 
     def page_accounting_ok(self) -> bool:
-        """The no-leak invariant every retirement path must preserve: pages
-        the pool counts as used == pages held by running requests."""
-        if self.pool is None:
-            return True
-        held = sum(len(r.pages) for r in self.scheduler.running())
-        if self.prefix_cache is not None:
-            held += self.prefix_cache.pages_held
-        return self.pool.num_used == held
+        """The no-leak invariant every retirement path must preserve, in
+        every cache group: pages the group's pool counts as used == pages
+        its running requests hold there."""
+        for gi, pool in enumerate(self.pools):
+            held = sum(len(r.group_pages[gi])
+                       for r in self.scheduler.running())
+            if gi == 0 and self.prefix_cache is not None:
+                held += self.prefix_cache.pages_held
+            if pool.num_used != held:
+                return False
+        return True
 
     # -- cross-replica page migration -----------------------------------------
     # The shippable unit of state is a prefix-cache entry: page-aligned
@@ -778,34 +845,49 @@ class ServingEngine:
             req = self.scheduler.peek()
             if req is None:
                 break
-            pages: List[int] = []
-            if self.pool is not None:
-                need = self.pool.pages_needed(
-                    req.prompt_len + req.max_new_tokens)
-                try:
-                    pages = self.pool.alloc(need)
-                except PagePoolExhausted:
-                    # graceful backpressure: the request stays at the queue
-                    # head; retirements will free pages. Recorded for the
-                    # flight recorder so a post-mortem sees the pressure.
-                    self.scheduler.requeue_head_blocked()
-                    fr = _dev.flight_recorder()
-                    if fr is not None:
-                        fr.record_event(
-                            "serving_admission_blocked",
-                            request_id=req.id, need_pages=need,
-                            free_pages=self.pool.num_free,
-                            batch=self._batch_spec())
-                    break
+            total = req.prompt_len + req.max_new_tokens
+            group_pages = self._reserve(req, total)
+            if group_pages is None:
+                break
             req = self.scheduler.admit(slot)
             req.admitted_t = time.perf_counter()
-            req.pages = pages
+            req.group_pages = group_pages
+            req.pages = group_pages[0] if group_pages else []
             _trace.on_admitted(req, slot)
             bucket = wave_bucket or self._bucket_for(req.prompt_len)
             done = self._prefill(req, slot, bucket)
             if done is not None:
                 finished.append(done)
         return finished
+
+    def _reserve(self, req: Request, total: int) -> Optional[List[List[int]]]:
+        """A request's pages in EVERY cache group (its worst case there:
+        all its positions, or the whole ring where that is shorter), all or
+        nothing. None where a group is short: the request stays at the
+        queue head (graceful backpressure; retirements will free pages),
+        what the other groups gave goes back, and the flight recorder and
+        the trace (``serving/admit.blocked``, args ``group``) say which
+        group it was."""
+        got: List[List[int]] = []
+        for gi, pool in enumerate(self.pools):
+            need = self.cache_ops.pages_needed(gi, total)
+            try:
+                got.append(pool.alloc(need))
+            except PagePoolExhausted:
+                for gj, pages in enumerate(got):
+                    self.pools[gj].free(pages)
+                self.scheduler.requeue_head_blocked()
+                with _span("serving/admit.blocked", group=pool.name,
+                           need_pages=need, free_pages=pool.num_free):
+                    fr = _dev.flight_recorder()
+                    if fr is not None:
+                        fr.record_event(
+                            "serving_admission_blocked",
+                            request_id=req.id, need_pages=need,
+                            free_pages=pool.num_free, group=pool.name,
+                            batch=self._batch_spec())
+                return None
+        return got
 
     def _prefill(self, req: Request, slot: int, bucket: int
                  ) -> Optional[Request]:
@@ -826,9 +908,10 @@ class ServingEngine:
                 prompt = np.full((bucket,), cfg.pad_id, np.int32)
                 prompt[:req.prompt_len] = req.prompt
                 if cfg.paged:
-                    dest_np = self.cache_ops.prompt_dest(req.pages)
-                    dest = jnp.asarray(dest_np)
-                    self._cache["pt"] = self._cache["pt"].at[slot].set(dest)
+                    dest = jnp.asarray(
+                        self.cache_ops.prompt_dest_groups(req.group_pages))
+                    self._cache = self.cache_ops.set_page_table(
+                        self._cache, slot, dest)
                 else:
                     dest = jnp.asarray(self.cache_ops.prompt_dest(slot))
                 exe = self._get_prefill_exe(bucket)
@@ -1052,13 +1135,10 @@ class ServingEngine:
                                   self._tok, self._active, self._gen,
                                   self._maxnew, self._temp, self._topk,
                                   self._seed, *extra)
-                    if self.cfg.collect_logits:
-                        (self._cache, self._len, self._tok, self._active,
-                         self._gen, toks, emitted, fin, logseq) = out
-                    else:
-                        (self._cache, self._len, self._tok, self._active,
-                         self._gen, toks, emitted, fin) = out
-                        logseq = None
+                    (self._cache, self._len, self._tok, self._active,
+                     self._gen, toks, emitted, fin, *rest) = out
+                    logseq = rest.pop(0) if self.cfg.collect_logits else None
+                    stats = rest[0] if rest else None
                     # one host sync per dispatch: the retire/admit decision
                     # needs the emitted tokens (the serving analog of
                     # run_steps' fetch)
@@ -1066,6 +1146,9 @@ class ServingEngine:
                         toks = np.asarray(toks)
                         emitted = np.asarray(emitted)
                         fin = np.asarray(fin)
+                        if stats is not None:
+                            stats = {k: np.asarray(v)
+                                     for k, v in stats.items()}
                     break
                 except Exception as e:
                     (self._cache, self._len, self._tok, self._active,
@@ -1105,6 +1188,12 @@ class ServingEngine:
         # tokens/steps > 1 is exactly the speculative win
         _sm.DECODE_STEPS.inc(1 if dlen_np is not None else steps)
         _sm.TOKENS_GENERATED.inc(int(emitted.sum()))
+        if stats is not None:
+            for name, hist in (
+                    ("moe_experts_touched", _sm.MOE_EXPERTS_TOUCHED),
+                    ("moe_max_expert_rows", _sm.MOE_MAX_EXPERT_ROWS)):
+                for x in stats.get(name, np.zeros(0)).reshape(-1):
+                    hist.observe(float(x))
         if dlen_np is not None:
             _sm.SPEC_PROPOSED.inc(proposed)
             _sm.SPEC_ACCEPTED.inc(accepted)
@@ -1144,7 +1233,10 @@ class ServingEngine:
                 donated = self._donate_prefix_pages(req, state)
             if donated < len(req.pages):
                 self.pool.free(req.pages[donated:])
+            for gi in range(1, len(self.pools)):
+                self.pools[gi].free(req.group_pages[gi])
             req.pages = []
+            req.group_pages = [[] for _ in self.pools]
         req.finished_t = time.perf_counter()
         _trace.on_terminal(req, state, slot)
         if state == FINISHED:
@@ -1273,11 +1365,19 @@ class ServingEngine:
             return exe
         model, ops, cfg = self.model, self.cache_ops, self.cfg
 
+        last_only = hasattr(model, "prefill_last")
+
         def prefill(params, cache, dest, prompt, length, temp, topk, seed):
-            logits, kvs = model.prefill(params, prompt[None], length[None])
+            if last_only:
+                logits, kvs = model.prefill_last(params, prompt[None],
+                                                 length[None])
+                last = logits[0]
+            else:
+                logits, kvs = model.prefill(params, prompt[None],
+                                            length[None])
+                last = logits[0, length - 1]
             for i, (k, v) in enumerate(kvs):
                 cache = ops.write_prompt(cache, i, k[0], v[0], dest, length)
-            last = logits[0, length - 1]
             # first generated token: same sampler as the decode scan, keyed
             # by the last PROMPT position (decode steps then key length,
             # length+1, ... — the streams can't collide)
@@ -1285,7 +1385,7 @@ class ServingEngine:
                                  seed[None], (length - 1)[None])[0]
             return cache, tok, last
 
-        dest_abs = (jax.ShapeDtypeStruct((ops.pages_per_slot,), jnp.int32)
+        dest_abs = (jax.ShapeDtypeStruct((ops.page_table_len,), jnp.int32)
                     if cfg.paged else jax.ShapeDtypeStruct((), jnp.int32))
         exe = aot_compile(
             prefill,
@@ -1312,7 +1412,8 @@ class ServingEngine:
                   temp, topk, seed):
             def body(carry, _):
                 cache, ln, tk, ac, gc = carry
-                logits, cache = model.decode(params, cache, ops, tk, ln, ac)
+                logits, cache, *stats = model.decode(params, cache, ops, tk,
+                                                     ln, ac)
                 # device-side sampling: keyed by ln (the consumed token's
                 # absolute position), which advances per STEP not per
                 # dispatch — fuse=1 and fuse=4 draw identical streams
@@ -1325,7 +1426,8 @@ class ServingEngine:
                 ac = ac & ~fin
                 out = (nxt, emitted, fin, logits) if collect \
                     else (nxt, emitted, fin)
-                return (cache, ln, nxt, ac, gc), out
+                # a model's own per-step counts ride last, stacked [fuse,..]
+                return (cache, ln, nxt, ac, gc), out + tuple(stats)
 
             (cache, lengths, tokens, active, gen), outs = jax.lax.scan(
                 body, (cache, lengths, tokens, active, gen), None,
@@ -1463,8 +1565,8 @@ class ServingEngine:
                 ac = slotmask & (pos < length)
                 tkb = jnp.where(slotmask, toks[i], 0).astype(jnp.int32)
                 posb = jnp.full((b,), pos, jnp.int32)
-                logits, cache = model.decode(params, cache, ops, tkb,
-                                             posb, ac)
+                logits, cache, *_ = model.decode(params, cache, ops, tkb,
+                                                 posb, ac)
                 is_last = ac & (pos == length - 1)
                 cand = _sample_tokens(logits, tempv, topkv, seedv, posb)
                 tok_acc = tok_acc + jnp.sum(

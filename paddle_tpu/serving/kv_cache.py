@@ -27,6 +27,22 @@ loop is layout-blind and the two paths are bit-comparable:
   (tests/test_serving.py asserts bit-identical tokens/logits) and the
   padded-baseline cache.
 
+Cache GROUPS (:class:`CacheGroup`): a model whose layers do not all keep
+the same positions names, for each group of layers, the window it keeps
+(none: every position; W: the last W). A group has its own pool
+``[layers_in_group, rows, H*D]``, its own page table a slot and its own
+free list (serving.page_pool.PagePool, one a group, held by the engine). A
+window group's slot uses its pages as a RING: position p lives at row ``p
+mod W`` of the ring, so a slot never holds more than ``W / page_size``
+pages there however long its context grows; K is stored after any rotary
+rotation, so the order of a ring's rows does not matter to the softmax and
+attention needs only ``min(ctx, W)`` as its length. A model with one kind
+of layer (GPT-2) is ONE global group: the same class, the same code.
+
+``n_head`` counts the heads of K and V. A model with grouped queries hands
+``decode_attention`` a ``q`` of ``G * n_head`` heads (query head n reads KV
+head ``n // G``); ``q_per_kv`` = G tells the kernel's gate.
+
 Both write paths scatter with ``mode="drop"`` on out-of-bounds destination
 rows, so inactive slots / padding positions are dropped INSIDE the compiled
 step — no host-side branching, and unwritten rows stay zero in both
@@ -35,12 +51,23 @@ layouts, which is what makes the gathered contexts bit-identical.
 
 from __future__ import annotations
 
-from typing import Dict, Tuple
+from typing import Dict, List, NamedTuple, Optional, Sequence, Tuple
 
 import jax.numpy as jnp
 import numpy as np
 
-__all__ = ["PagedKVCache", "Int8PagedKVCache", "ContiguousKVCache"]
+__all__ = ["CacheGroup", "PagedKVCache", "Int8PagedKVCache",
+           "ContiguousKVCache"]
+
+
+class CacheGroup(NamedTuple):
+    """Layers that keep the same positions: ``window`` None keeps every
+    position of a slot's context, W keeps the last W as a ring."""
+
+    name: str
+    layers: Tuple[int, ...]
+    window: Optional[int]
+    num_pages: int
 
 Cache = Dict[str, jnp.ndarray]
 
@@ -91,16 +118,87 @@ class PagedKVCache(_KVCacheBase):
 
     def __init__(self, n_layer: int, n_head: int, d_head: int, slots: int,
                  max_ctx: int, page_size: int, num_pages: int,
-                 dtype=jnp.float32):
+                 dtype=jnp.float32,
+                 groups: Optional[Sequence[CacheGroup]] = None,
+                 q_per_kv: int = 1):
         super().__init__(n_layer, n_head, d_head, slots, max_ctx, dtype)
         if max_ctx % page_size != 0:
             raise ValueError("max_ctx=%d must be a multiple of page_size=%d"
                              % (max_ctx, page_size))
         self.page_size = int(page_size)
-        self.num_pages = int(num_pages)
-        self.pages_per_slot = self.max_ctx // self.page_size
-        self.num_rows = self.num_pages * self.page_size  # flat KV rows
+        self.q_per_kv = int(q_per_kv)
         self.row_width = self.n_head * self.d_head  # lanes of one KV row
+        if groups is None:
+            groups = [CacheGroup("global", tuple(range(self.n_layer)), None,
+                                 int(num_pages))]
+        self.groups: List[CacheGroup] = [
+            CacheGroup(g.name, tuple(g.layers),
+                       None if g.window is None
+                       else min(int(g.window), self.max_ctx),
+                       int(g.num_pages)) for g in groups]
+        # layer -> (its group's index, its index inside that group's pool)
+        self._where: Dict[int, Tuple[int, int]] = {}
+        for gi, g in enumerate(self.groups):
+            if g.window is not None and g.window % self.page_size:
+                raise ValueError("group %r: window=%d must be a multiple of "
+                                 "page_size=%d" % (g.name, g.window,
+                                                   self.page_size))
+            for li, layer in enumerate(g.layers):
+                if layer in self._where:
+                    raise ValueError("layer %d is in two cache groups" % layer)
+                self._where[layer] = (gi, li)
+        if sorted(self._where) != list(range(self.n_layer)):
+            raise ValueError("the cache groups must cover layers 0..%d once "
+                             "each, got %s" % (self.n_layer - 1,
+                                               sorted(self._where)))
+        # the first group's geometry under the names a one-group cache
+        # always had
+        self.num_pages = self.groups[0].num_pages
+        self.pages_per_slot = self.group_pages_per_slot(0)
+        # where each group's row starts in a slot's page-table rows laid on
+        # end (prompt_dest_groups); the last entry is their whole length
+        self._pt_start = [0]
+        for gi in range(len(self.groups)):
+            self._pt_start.append(self._pt_start[-1]
+                                  + self.group_pages_per_slot(gi))
+        self.num_rows = self.num_pages * self.page_size  # flat KV rows
+
+    # -- groups ---------------------------------------------------------------
+    def group_rows(self, gi: int) -> int:
+        """Context rows a slot can hold in group ``gi``."""
+        w = self.groups[gi].window
+        return self.max_ctx if w is None else w
+
+    def group_pages_per_slot(self, gi: int) -> int:
+        return self.group_rows(gi) // self.page_size
+
+    @property
+    def page_table_len(self) -> int:
+        """Entries of one slot's page-table rows, every group's on end:
+        the length of :meth:`prompt_dest_groups`'s row."""
+        return self._pt_start[-1]
+
+    def pages_needed(self, gi: int, total_tokens: int) -> int:
+        """Pages group ``gi`` reserves for a request of ``total_tokens``
+        positions: all of them, or the whole ring where it is shorter."""
+        need = -(-int(total_tokens) // self.page_size)
+        return min(need, self.group_pages_per_slot(gi))
+
+    def _key(self, gi: int, what: str) -> str:
+        """State key of group ``gi``'s ``k``/``v``/``pt``: the first group
+        keeps the plain names a one-group cache always had."""
+        return what if gi == 0 else "%s.%s" % (what, self.groups[gi].name)
+
+    def _group_len(self, gi: int, ctx_len):
+        """Rows of a slot's context that group ``gi`` holds."""
+        w = self.groups[gi].window
+        return ctx_len if w is None else jnp.minimum(ctx_len, w)
+
+    def _single_group(self, what: str) -> None:
+        if len(self.groups) > 1:
+            raise ValueError("%s is not supported over a cache with %d "
+                             "groups (%s)" % (what, len(self.groups),
+                                              [g.name for g in self.groups]))
 
     def _storage_dtype(self):
         """What a pool row is stored as (``self.dtype`` is what ``context``
@@ -108,59 +206,91 @@ class PagedKVCache(_KVCacheBase):
         return self.dtype
 
     def init_state(self) -> Cache:
-        shp = (self.n_layer, self.num_rows, self.row_width)
-        return {
-            "k": jnp.zeros(shp, self._storage_dtype()),
-            "v": jnp.zeros(shp, self._storage_dtype()),
+        state = {}
+        for gi, g in enumerate(self.groups):
+            shp = (len(g.layers), g.num_pages * self.page_size,
+                   self.row_width)
+            state[self._key(gi, "k")] = jnp.zeros(shp, self._storage_dtype())
+            state[self._key(gi, "v")] = jnp.zeros(shp, self._storage_dtype())
             # page table: slot -> ordered page ids; rows beyond a slot's
             # reservation are whatever the allocator last left (reads are
             # masked by length, writes by the drop scatter)
-            "pt": jnp.zeros((self.slots, self.pages_per_slot), jnp.int32),
-        }
+            state[self._key(gi, "pt")] = jnp.zeros(
+                (self.slots, self.group_pages_per_slot(gi)), jnp.int32)
+        return state
+
+    def cache_bytes(self, state: Cache) -> int:
+        return int(sum(state[self._key(gi, w)].nbytes
+                       for gi in range(len(self.groups)) for w in "kv"))
+
+    def set_page_table(self, state: Cache, slot: int, dest) -> Cache:
+        """Point ``slot`` at the pages of ``dest`` (what
+        :meth:`prompt_dest_groups` made) in every group."""
+        out = dict(state)
+        for gi in range(len(self.groups)):
+            key = self._key(gi, "pt")
+            out[key] = state[key].at[slot].set(
+                dest[self._pt_start[gi]:self._pt_start[gi + 1]])
+        return out
 
     # -- decode (one token per slot) -----------------------------------------
     def write_token(self, state: Cache, layer: int, k_new, v_new, pos,
                     active) -> Cache:
         """k_new/v_new [B,H,D] written at logical position ``pos[b]`` of
-        slot b; inactive slots dropped via an OOB destination row."""
+        slot b (in a window group: at its place in the ring); inactive
+        slots dropped via an OOB destination row."""
         ps = self.page_size
-        pt = state["pt"]
+        gi, _ = self._where[layer]
+        pt = state[self._key(gi, "pt")]
         b_idx = jnp.arange(pt.shape[0])
-        page = pt[b_idx, pos // ps]
-        dest = page * ps + pos % ps
-        dest = jnp.where(active, dest, self.num_rows)
+        idx = pos // ps
+        if self.groups[gi].window is not None:
+            idx = idx % self.group_pages_per_slot(gi)
+        dest = pt[b_idx, idx] * ps + pos % ps
+        dest = jnp.where(active, dest, self._drop_row(gi))
         return self._write_rows(state, layer, dest, k_new, v_new)
+
+    def _drop_row(self, gi: int) -> int:
+        """One past group ``gi``'s last pool row: a scatter to it drops."""
+        return self.groups[gi].num_pages * self.page_size
 
     def _write_rows(self, state: Cache, layer: int, dest, k_new, v_new
                     ) -> Cache:
-        """Scatter ``[N, H, D]`` updates into pool rows ``dest`` [N] of
-        ``layer``; rows at ``num_rows`` are dropped."""
+        """Scatter ``[N, H, D]`` updates into rows ``dest`` [N] of
+        ``layer`` in its group's pool; rows at :meth:`_drop_row` are
+        dropped."""
+        gi, li = self._where[layer]
+        kk, vk = self._key(gi, "k"), self._key(gi, "v")
         return {
             **state,
-            "k": state["k"].at[layer, dest].set(
+            kk: state[kk].at[li, dest].set(
                 k_new.reshape(-1, self.row_width), mode="drop"),
-            "v": state["v"].at[layer, dest].set(
+            vk: state[vk].at[li, dest].set(
                 v_new.reshape(-1, self.row_width), mode="drop"),
         }
 
     def context(self, state: Cache, layer: int) -> Tuple[jnp.ndarray, jnp.ndarray]:
-        """Gather every slot's pages back into logical order:
-        ``[slots, max_ctx, H, D]`` — the XLA-gather paged-attention path."""
-        rows = self._context_rows(state["pt"])
-        return (self._gather(state["k"], layer, rows),
-                self._gather(state["v"], layer, rows))
+        """Gather every slot's pages of ``layer``'s group back into page-
+        table order: ``[slots, rows, H, D]`` with ``rows`` = ``max_ctx``, or
+        the ring's length in a window group (ring order, not position
+        order) — the XLA-gather paged-attention path."""
+        gi, li = self._where[layer]
+        rows = self._context_rows(state[self._key(gi, "pt")])
+        return (self._gather(state[self._key(gi, "k")], li, rows),
+                self._gather(state[self._key(gi, "v")], li, rows))
 
     def _context_rows(self, pt) -> jnp.ndarray:
-        """Pool row of every logical position: ``[slots, max_ctx]``."""
+        """Pool row of every row of a slot's page table: ``[slots,
+        pages * page_size]``."""
         ps = self.page_size
         rows = (pt * ps)[:, :, None] + jnp.arange(ps)[None, None, :]
-        return rows.reshape(pt.shape[0], self.max_ctx)
+        return rows.reshape(pt.shape[0], pt.shape[1] * ps)
 
-    def _gather(self, pool, layer: int, rows) -> jnp.ndarray:
-        """``pool[layer, rows]`` with the heads split AFTER the gather:
-        ``[slots, max_ctx, H, D]``."""
-        return pool[layer, rows].reshape(rows.shape + (self.n_head,
-                                                       self.d_head))
+    def _gather(self, pool, li: int, rows) -> jnp.ndarray:
+        """``pool[li, rows]`` with the heads split AFTER the gather:
+        ``[slots, rows, H, D]``."""
+        return pool[li, rows].reshape(rows.shape + (self.n_head,
+                                                    self.d_head))
 
     def kernel_mode(self):
         """``(mode, why_not)``: ``mode`` is "compiled"/"interpret" when the
@@ -179,33 +309,39 @@ class PagedKVCache(_KVCacheBase):
             return None, "n/a"
         why_not = paged_attention_gate(
             self.dtype, self.n_head, self.d_head, self.page_size,
-            interpret=(mode == "interpret"))
+            interpret=(mode == "interpret"), q_per_kv=self.q_per_kv)
         if why_not is not None:
             return None, "gate: " + why_not
         return mode, None
 
     def decode_attention(self, state: Cache, layer: int, q, ctx_len,
                          sm_scale: float = 1.0) -> jnp.ndarray:
-        """One decode-attention step [B,H,D] over this layer's ragged
-        contexts. Where :meth:`kernel_mode` arms it, the Pallas kernel
-        takes the WHOLE pool and reads this layer's K/V pages straight from
-        it via the device-resident page table — neither a layer slice nor
-        the ``[B, max_ctx, H, D]`` gather ever materializes; otherwise the
-        XLA gather + ops.attention_ops.decode_attention path runs. Both mask positions >= ctx_len with the SAME neg_inf
-        constant, so the paths agree to float round-off (tier-1 parity
-        tests pin it)."""
+        """One decode-attention step over this layer's ragged contexts:
+        ``q`` [B, G*H, D] (G = 1: as many query heads as KV heads) in,
+        [B, G*H, D] out. A window group attends over ``min(ctx_len,
+        window)`` rows of its ring. Where :meth:`kernel_mode` arms it, the
+        Pallas kernel takes the group's WHOLE pool and reads this layer's
+        K/V pages straight from it via the device-resident page table,
+        once for all G query heads of a KV head — neither a layer slice nor
+        the ``[B, rows, H, D]`` gather ever materializes; otherwise the
+        XLA gather + ops.attention_ops.decode_attention path runs. Both
+        mask rows >= the length with the SAME neg_inf constant, so the
+        paths agree to float round-off (tier-1 parity tests pin it)."""
         from ..ops import attention_ops
 
+        gi, li = self._where[layer]
+        length = self._group_len(gi, ctx_len)
         mode, _ = self.kernel_mode()
         if mode is not None:
             from ..ops.pallas_kernels import paged_attention as _pa
 
             return _pa.paged_decode_attention(
-                q, state["k"], state["v"], state["pt"], ctx_len,
-                page_size=self.page_size, layer=layer, sm_scale=sm_scale,
+                q, state[self._key(gi, "k")], state[self._key(gi, "v")],
+                state[self._key(gi, "pt")], length,
+                page_size=self.page_size, layer=li, sm_scale=sm_scale,
                 interpret=(mode == "interpret"))
         ctx_k, ctx_v = self.context(state, layer)
-        return attention_ops.decode_attention(q, ctx_k, ctx_v, ctx_len,
+        return attention_ops.decode_attention(q, ctx_k, ctx_v, length,
                                               sm_scale=sm_scale)
 
     def decode_verify(self, state: Cache, layer: int, q, ctx_len,
@@ -218,9 +354,11 @@ class PagedKVCache(_KVCacheBase):
         with length ctx_len+j, which is exactly the per-slot raggedness the
         kernel already handles; no kernel change, one dispatch. The XLA
         gather + ops.attention_ops.verify_attention path stays the parity
-        reference (one ``context`` gather serves all W rows)."""
+        reference (one ``context`` gather serves all W rows). One group
+        only: a ring cannot be rolled back."""
         from ..ops import attention_ops
 
+        self._single_group("speculative verify")
         b, w = q.shape[0], q.shape[1]
         mode, _ = self.kernel_mode()
         if mode is not None:
@@ -241,22 +379,43 @@ class PagedKVCache(_KVCacheBase):
 
     # -- prefill (one sequence) ----------------------------------------------
     def prompt_dest(self, pages) -> np.ndarray:
-        """Host-side: the ``dest`` operand for ``write_prompt`` — a full
-        page-table row (reserved pages first, rest parked on page 0;
-        unused entries are never read or written)."""
-        row = np.zeros(self.pages_per_slot, np.int32)
-        row[:len(pages)] = np.asarray(pages, np.int32)
-        return row
+        """:meth:`prompt_dest_groups` of a one-group cache."""
+        return self.prompt_dest_groups([pages])
+
+    def prompt_dest_groups(self, group_pages) -> np.ndarray:
+        """Host-side: the ``dest`` operand for ``write_prompt`` and
+        ``set_page_table`` — every group's full page-table row, one after
+        another (reserved pages first, rest parked on page 0; unused
+        entries are never read or written)."""
+        if len(group_pages) != len(self.groups):
+            raise ValueError("pages for %d groups, the cache has %d"
+                             % (len(group_pages), len(self.groups)))
+        rows = []
+        for gi, pages in enumerate(group_pages):
+            row = np.zeros(self.group_pages_per_slot(gi), np.int32)
+            row[:len(pages)] = np.asarray(pages, np.int32)
+            rows.append(row)
+        return np.concatenate(rows)
 
     def write_prompt(self, state: Cache, layer: int, k_new, v_new, dest,
                      length) -> Cache:
-        """k_new/v_new [S,H,D] for ONE sequence; ``dest`` is its page-table
-        row [pages_per_slot]; positions >= length are dropped."""
+        """k_new/v_new [S,H,D] for ONE sequence; ``dest`` is
+        :meth:`prompt_dest_groups`'s row; positions >= length are dropped,
+        and in a window group the positions that have already left the
+        window (< length - window) too: the last ``min(length, window)``
+        land at their places in the ring."""
         ps = self.page_size
+        gi, _ = self._where[layer]
+        off = self._pt_start[gi]
         s = k_new.shape[0]
         j = jnp.arange(s)
-        flat = dest[j // ps] * ps + j % ps
-        flat = jnp.where(j < length, flat, self.num_rows)
+        keep = j < length
+        idx = j // ps
+        if self.groups[gi].window is not None:
+            keep = keep & (j >= length - self.groups[gi].window)
+            idx = idx % self.group_pages_per_slot(gi)
+        flat = dest[off + idx] * ps + j % ps
+        flat = jnp.where(keep, flat, self._drop_row(gi))
         return self._write_rows(state, layer, flat, k_new, v_new)
 
     # -- page migration ------------------------------------------------------
@@ -288,6 +447,7 @@ class PagedKVCache(_KVCacheBase):
         C-order bytes of the K rows then the V rows, ``[n_layer,
         n_pages*page_size, H, D]`` each (which the ``[.., H*D]`` pool's
         rows are, byte for byte) — bit-exact, no float formatting."""
+        self._single_group("page export")
         rows = self._page_rows(pages)
         k = np.ascontiguousarray(np.asarray(state["k"][:, rows]))
         v = np.ascontiguousarray(np.asarray(state["v"][:, rows]))
@@ -300,6 +460,7 @@ class PagedKVCache(_KVCacheBase):
         ``ValueError`` (typed, caller frees its reservation) on any
         geometry/dtype/size mismatch. Row bytes land verbatim, so an
         export of the same pages round-trips bit-identical."""
+        self._single_group("page import")
         self._check_meta(meta, 2, blobs)
         n = int(meta.get("n_pages", -1))
         if n != len(pages):
@@ -349,9 +510,13 @@ class Int8PagedKVCache(PagedKVCache):
 
     def __init__(self, n_layer: int, n_head: int, d_head: int, slots: int,
                  max_ctx: int, page_size: int, num_pages: int,
-                 k_scale: float, v_scale: float, dtype=jnp.float32):
+                 k_scale: float, v_scale: float, dtype=jnp.float32,
+                 groups: Optional[Sequence[CacheGroup]] = None,
+                 q_per_kv: int = 1):
         super().__init__(n_layer, n_head, d_head, slots, max_ctx,
-                         page_size, num_pages, dtype)
+                         page_size, num_pages, dtype, groups=groups,
+                         q_per_kv=q_per_kv)
+        self._single_group("the int8 KV pool")
         if not (float(k_scale) > 0.0 and float(v_scale) > 0.0):
             raise ValueError(
                 "Int8PagedKVCache needs calibrated positive scales, got "

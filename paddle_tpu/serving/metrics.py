@@ -22,6 +22,7 @@ __all__ = [
     "DRAINS", "DRAINED_REQUESTS", "DRAIN_REJECTED",
     "SPEC_PROPOSED", "SPEC_ACCEPTED", "SPEC_REJECTED", "SPEC_DRAFTS",
     "SPEC_VERIFY_DISPATCHES", "SPEC_ACCEPT_RATE",
+    "MOE_EXPERTS_TOUCHED", "MOE_MAX_EXPERT_ROWS", "pages_used",
 ]
 
 REQUESTS_SUBMITTED = _mx.counter(
@@ -116,3 +117,17 @@ SPEC_VERIFY_DISPATCHES = _mx.counter(
 SPEC_ACCEPT_RATE = _mx.histogram(
     "serving/spec_accept_rate",
     help="per-dispatch accepted/proposed draft-token ratio, 0..1")
+MOE_EXPERTS_TOUCHED = _mx.histogram(
+    "serving/moe_experts_touched",
+    help="experts that received at least one live row, one observation a "
+         "layer a decode step (a model whose decode returns the count)")
+MOE_MAX_EXPERT_ROWS = _mx.histogram(
+    "serving/moe_max_expert_rows",
+    help="rows of the fullest expert, one observation a layer a decode step")
+
+
+def pages_used(group: str):
+    """``serving/pages_used.<group>``: pages a cache group has allocated
+    (a gauge a group; get-or-create, so engines of one process share it)."""
+    return _mx.gauge("serving/pages_used.%s" % group,
+                     help="KV-cache pages allocated in cache group %r" % group)

@@ -31,11 +31,19 @@ class PagePoolExhausted(BackpressureError):
 
 
 class PagePool:
-    def __init__(self, num_pages: int, page_size: int):
+    """One free list. An engine holds one a cache group (``name``: the
+    group's; every group publishes ``serving/pages_used.<name>``, the
+    first also the two gauges a one-group engine always had)."""
+
+    def __init__(self, num_pages: int, page_size: int,
+                 name: str = "global", primary: bool = True):
         if num_pages < 1 or page_size < 1:
             raise ValueError("num_pages and page_size must be >= 1")
         self.num_pages = int(num_pages)
         self.page_size = int(page_size)
+        self.name = str(name)
+        self._primary = bool(primary)
+        self._used_gauge = _sm.pages_used(self.name)
         # LIFO free list: recently-freed (cache-warm) pages are reused first
         self._free: List[int] = list(range(self.num_pages - 1, -1, -1))
         self._free_set = set(self._free)
@@ -59,8 +67,10 @@ class PagePool:
         return -(-int(total_tokens) // self.page_size)
 
     def _update_gauges(self):
-        _sm.PAGES_IN_USE.set(self.num_used)
-        _sm.PAGE_POOL_UTILIZATION.set(self.utilization)
+        self._used_gauge.set(self.num_used)
+        if self._primary:
+            _sm.PAGES_IN_USE.set(self.num_used)
+            _sm.PAGE_POOL_UTILIZATION.set(self.utilization)
 
     # -- alloc/free -----------------------------------------------------------
     def alloc(self, n: int) -> List[int]:

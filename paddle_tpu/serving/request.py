@@ -70,6 +70,7 @@ class Request:
     """
 
     __slots__ = ("id", "prompt", "max_new_tokens", "state", "slot", "pages",
+                 "group_pages",
                  "tokens_out", "submitted_t", "admitted_t", "first_token_t",
                  "finished_t", "deadline_s", "error", "trace_id", "attempt",
                  "temperature", "top_k", "seed", "speculation")
@@ -103,7 +104,11 @@ class Request:
         self.max_new_tokens = int(max_new_tokens)
         self.state = QUEUED
         self.slot: Optional[int] = None
+        # KV pages reserved at admission: ``group_pages`` one list a cache
+        # group, ``pages`` the first group's (all there is in a one-group
+        # cache)
         self.pages: List[int] = []
+        self.group_pages: List[List[int]] = []
         self.tokens_out: List[int] = []
         self.submitted_t = time.perf_counter()
         self.admitted_t: Optional[float] = None

@@ -312,3 +312,127 @@ def test_serving_executables_leave_the_pool_in_place(chip, monkeypatch, exe):
         r"\((\d+), \{\}, (?:may|must)-alias\)", text.split("\n", 1)[0])}
     assert {n_params, n_params + 2} <= aliased
     assert compiled.memory_analysis().temp_size_in_bytes < 64 * 2 ** 20
+
+
+# -- the sparse decoder's executables at smallthinker-21b-a3b-serve's widths ----
+
+MOE_SERVE = dict(slots=16, page_size=16, max_seq=16384, global_pages=6144,
+                 window_pages=4096, vocab=151936, bucket=8192)
+
+
+def _moe_case(name, chip, n_layer=4, sampler=None, bucket=None):
+    """``(fn, abstract args, cache ops)`` of the decode chunk or a prefill
+    bucket over SmallThinker's block at its published widths (one period of
+    its layer pattern by default: the widths are what the chip's compiler
+    judges), composed as the engine composes them, over a cache of two
+    groups."""
+    from paddle_tpu.models import smallthinker as st
+    from paddle_tpu.serving.kv_cache import CacheGroup, PagedKVCache
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
+
+    g = MOE_SERVE
+    pattern = ([0, 1, 1, 1] * n_layer)[:n_layer]
+    cfg = st.SmallThinkerConfig(
+        vocab_size=g["vocab"], n_layer=n_layer, d_model=2560, n_head=28,
+        n_kv_head=4, d_head=128, n_expert=64, top_k=6, d_expert=768,
+        window=4096, rope_layout=pattern, window_layout=pattern,
+        max_seq=g["max_seq"], dtype="bfloat16")
+    model = st.SmallThinkerLM(cfg, params={})
+    groups = [CacheGroup(n, l, w, g[n + "_pages"])
+              for n, l, w in cfg.cache_groups]
+    ops = PagedKVCache(n_layer, 4, 128, g["slots"], g["max_seq"],
+                       g["page_size"], g["global_pages"], dtype=cfg.dtype,
+                       groups=groups, q_per_kv=7)
+
+    def abstract(fn):
+        return jax.tree_util.tree_map(lambda x: sds(x.shape, x.dtype),
+                                      jax.eval_shape(fn))
+
+    params = abstract(lambda: st.init_params(cfg, 0))
+    cache = abstract(ops.init_state)
+    b = g["slots"]
+    ints, flags = sds((b,), jnp.int32), sds((b,), jnp.bool_)
+    pick = sampler or (lambda logits, pos: jnp.argmax(logits, -1)
+                       .astype(jnp.int32))
+
+    def chunk(params, cache, lengths, tokens, active):
+        def body(carry, _):
+            cache, ln, tk, ac = carry
+            logits, cache, stats = model.decode(params, cache, ops, tk, ln,
+                                                ac)
+            nxt = jnp.where(ac, pick(logits, ln), tk)
+            return (cache, ln + ac, nxt, ac), (nxt, stats)
+
+        return jax.lax.scan(body, (cache, lengths, tokens, active), None,
+                            length=1)
+
+    def prefill(params, cache, dest, prompt, length):
+        logits, kvs = model.prefill_last(params, prompt[None], length[None])
+        for i, (k, v) in enumerate(kvs):
+            cache = ops.write_prompt(cache, i, k[0], v[0], dest, length)
+        return cache, pick(logits, (length - 1)[None])[0]
+
+    dest = sds((ops.page_table_len,), jnp.int32)
+    return {
+        "chunk": (chunk, (params, cache, ints, ints, flags)),
+        "prefill": (prefill, (params, cache, dest,
+                              sds((bucket or g["bucket"],), jnp.int32),
+                              sds((), jnp.int32))),
+    }[name], ops
+
+
+def test_grouped_query_paged_kernel_at_the_served_geometry(chip):
+    """Row width 512 (4 KV heads of 128), 7 query heads a KV head, page 16,
+    bf16: both cache groups' pools, the layer in the middle."""
+    why = pa.paged_attention_gate(jnp.bfloat16, 4, 128, 16, q_per_kv=7)
+    assert why is None
+    for n_layer, pages, pps in ((3, 6144, 1024), (9, 4096, 256)):
+        pool = ((n_layer, pages * 16, 512), jnp.bfloat16)
+        text = compiled_text(
+            chip,
+            functools.partial(pa.paged_decode_attention, page_size=16,
+                              layer=1, sm_scale=128 ** -0.5),
+            ((16, 28, 128), jnp.bfloat16), pool, pool,
+            ((16, pps), jnp.int32), ((16,), jnp.int32))
+        kernel, = [ln for ln in text.split("\n") if "tpu_custom_call" in ln]
+        assert kernel.strip().startswith("%paged_attention")
+        assert [op for _, rtype, op, _ in _instructions(text)
+                if _has_dim(rtype, pages * 16) and op != "parameter"] == []
+
+
+@pytest.mark.parametrize("exe", ["chunk", "prefill"])
+def test_sparse_decoder_executables(chip, monkeypatch, exe):
+    """The decode chunk runs the grouped-query kernel once a layer and the
+    compiler's grouped matmul three times a layer, and neither it nor an
+    8,192-token prefill (two windows: the banded blocks) copies, slices or
+    transposes a pool of either group; every pool is aliased from input to
+    output."""
+    # the default backend here is the CPU; only the compile's target is the
+    # chip, so the two choices made by asking the backend are made here
+    monkeypatch.setattr(attention_ops, "paged_kernel_mode",
+                        lambda: "compiled")
+    monkeypatch.setattr(attention_ops, "_on_tpu", lambda: True)
+    (fn, args), ops = _moe_case(exe, chip)
+    compiled = jax.jit(fn, donate_argnums=(1,)).lower(*args).compile()
+    text = compiled.as_text()
+    if exe == "chunk":
+        assert text.count("%paged_attention") >= 4
+        assert len(re.findall(r"= \S+ custom-call\([^\n]*ragged-dot", text)) \
+            >= 3 * 4
+    instructions = list(_instructions(text))
+    types = {name: rtype for name, rtype, _, _ in instructions}
+    for gi, grp in enumerate(ops.groups):
+        rows = grp.num_pages * ops.page_size
+        moved = [(op, rtype) for _, rtype, op, operands in instructions
+                 if op in ("copy", "copy-start", "slice", "dynamic-slice",
+                           "transpose")
+                 and any(_has_dim(t, rows) for t in
+                         [rtype] + [types.get(o, "") for o in operands])]
+        assert moved == [], (grp.name, moved)
+    n_params = len(jax.tree_util.tree_leaves(args[0]))
+    aliased = {int(p) for p in re.findall(
+        r"\((\d+), \{\}, (?:may|must)-alias\)", text.split("\n", 1)[0])}
+    # "k" "k.window" "pt" "pt.window" "v" "v.window" in key order
+    assert {n_params, n_params + 1, n_params + 4, n_params + 5} <= aliased

@@ -26,6 +26,10 @@ TOY = {
                   page_size=8, slots=4, requests=3, prompt_min=4,
                   prompt_max=12, new_tokens=3, buckets=(16,),
                   reference_requests=1),
+    "serve_moe": dict(vocab=64, n_layer=4, d_model=32, n_head=4, n_kv_head=2,
+                      d_head=8, n_expert=4, top_k=2, d_expert=16, window=8,
+                      max_seq=32, page_size=4, slots=2, prompts=(3, 6),
+                      new_tokens=6, buckets=(8,)),
     "dp": dict(steps=2),
 }
 
@@ -52,13 +56,13 @@ def run(mod, capsys, argv):
     return rc, lines
 
 
-def test_one_chip_runs_four_phases_and_ends_with_the_contract_line(
+def test_one_chip_runs_five_phases_and_ends_with_the_contract_line(
         smoke, monkeypatch, capsys):
     as_tpu(smoke, monkeypatch, 1)
     rc, lines = run(smoke, capsys, [])
     assert rc == 0, lines
     assert [ln.get("phase") for ln in lines[:-1]] == [
-        "start", "train", "ctr", "kernels", "serve"]
+        "start", "train", "ctr", "kernels", "serve", "serve_moe"]
     for ln in lines[1:-1]:
         assert ln["ok"] is True
         for key in ("seconds", "compile_seconds", "compiles",
