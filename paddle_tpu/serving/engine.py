@@ -64,11 +64,13 @@ def _pow2_buckets(lo: int, hi: int) -> tuple:
     return tuple(sorted(set(out)))
 
 
-def _sample_tokens(logits, temp, top_k, seed, position):
+def _sample_tokens(logits, temp, top_k, seed, position, live=None):
     """Device-side per-slot token selection, shared by the prefill
-    executable and the fused decode scan.
+    executable, the fused decode scan, the verify window and the resume
+    scan.
 
-    ``logits`` [B,V]; ``temp``/``top_k``/``seed``/``position`` [B].
+    ``logits`` [B,V]; ``temp``/``top_k``/``seed``/``position`` [B];
+    ``live`` [B] bool, every row when None.
     ``temp[b] == 0`` returns EXACTLY ``argmax(logits[b])`` — the greedy
     path's own computation, selected by ``where``, so greedy requests are
     bit-identical whether or not sampling requests share the batch.
@@ -80,25 +82,60 @@ def _sample_tokens(logits, temp, top_k, seed, position):
     a pure function of the request's own seed and the absolute context
     position of the token being consumed, so the stream is reproducible
     across ``decode_fuse`` widths and a slot re-admitted to a new request
-    (new seed) can never replay the previous tenant's draws."""
+    (new seed) can never replay the previous tenant's draws.
+
+    A step pays only for what its LIVE rows ask for, read from the
+    arguments on the device (one ``lax.switch`` of three tiers in one
+    executable): (greedy) no live row with ``temp > 0`` — the argmax and
+    nothing else; (draw) some, none of them with ``top_k > 0`` — the
+    scaling and the Gumbel draw, no sort (the threshold at ``k = V`` is
+    the row's minimum and masks nothing); (sort) one with ``top_k > 0`` —
+    the sort too, for every row as before. ``live`` belongs to the
+    predicate because a retired slot keeps its last tenant's
+    ``temp``/``top_k``. A live row's token is the same in whichever tier
+    the step takes; a dead row's may be its argmax where a stale tenant's
+    draw used to be, and every caller discards it."""
     from ..ops.attention_ops import neg_inf
 
     greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
     v = logits.shape[-1]
-    scaled = logits.astype(jnp.float32) / jnp.maximum(
-        temp.astype(jnp.float32), 1e-6)[:, None]
-    k = jnp.clip(jnp.where(top_k > 0, top_k, v), 1, v)
-    srt = jax.lax.sort(scaled, dimension=-1)[:, ::-1]  # descending
-    kth = jnp.take_along_axis(srt, (k - 1)[:, None], axis=-1)
-    masked = jnp.where(scaled >= kth, scaled, neg_inf(jnp.float32))
+    draws = temp > 0 if live is None else live & (temp > 0)
+    cuts = draws & (top_k > 0)
 
-    def draw(seed_b, pos_b):
-        key = jax.random.fold_in(jax.random.PRNGKey(seed_b), pos_b)
-        return jax.random.gumbel(key, (v,), jnp.float32)
+    def sampled(cut):
+        scaled = logits.astype(jnp.float32) / jnp.maximum(
+            temp.astype(jnp.float32), 1e-6)[:, None]
+        if cut:
+            k = jnp.clip(jnp.where(top_k > 0, top_k, v), 1, v)
+            srt = jax.lax.sort(scaled, dimension=-1)[:, ::-1]  # descending
+            kth = jnp.take_along_axis(srt, (k - 1)[:, None], axis=-1)
+            scaled = jnp.where(scaled >= kth, scaled, neg_inf(jnp.float32))
 
-    sampled = jnp.argmax(masked + jax.vmap(draw)(seed, position),
+        def draw(seed_b, pos_b):
+            key = jax.random.fold_in(jax.random.PRNGKey(seed_b), pos_b)
+            return jax.random.gumbel(key, (v,), jnp.float32)
+
+        tok = jnp.argmax(scaled + jax.vmap(draw)(seed, position),
                          axis=-1).astype(jnp.int32)
-    return jnp.where(temp > 0, sampled, greedy)
+        return jnp.where(temp > 0, tok, greedy)
+
+    # cuts implies draws, so the sum is the tier: 0, 1 or 2
+    return jax.lax.switch(
+        jnp.any(draws).astype(jnp.int32) + jnp.any(cuts).astype(jnp.int32),
+        (lambda: greedy, lambda: sampled(False), lambda: sampled(True)))
+
+
+def _sampler_tier(requests) -> int:
+    """The tier of :func:`_sample_tokens` that a dispatch over these
+    requests (None: an empty slot) selects at launch, told on the host:
+    0 greedy, 1 draw, 2 sort."""
+    tier = 0
+    for r in requests:
+        if r is not None and r.temperature > 0:
+            if r.top_k > 0:
+                return 2
+            tier = 1
+    return tier
 
 
 class ServingConfig:
@@ -900,6 +937,7 @@ class ServingEngine:
         entry = None
         if self.prefix_cache is not None:
             entry = self.prefix_cache.lookup(req.prompt)
+        _sm.SAMPLER_DISPATCHES[_sampler_tier((req,))].inc()
         with _span("serving/prefill", trace_id=req.trace_id, slot=slot,
                    bucket=bucket, cause="local" if entry is None else "resume"):
             if entry is not None:
@@ -1120,6 +1158,9 @@ class ServingEngine:
         # snapshot first (the donated cache may be gone; _cache_lost() on
         # the restored ref detects that and downgrades retry to recovery).
         snap = (self._cache, self._len, self._tok, self._active, self._gen)
+        inflight = [self.scheduler.slot_request(s)
+                    for s in range(self.cfg.slots)]
+        _sm.SAMPLER_DISPATCHES[_sampler_tier(inflight)].inc()
         # serving/decode is the interval serving/decode_step_ms observes:
         # every launch (one more for each retry) up to the end of the sync
         with _span("serving/decode", steps=steps,
@@ -1178,9 +1219,7 @@ class ServingEngine:
             accepted = int(np.maximum(runs - 1, 0).sum())
             spec_args = _speculative.verify_window_args(steps, proposed,
                                                         accepted)
-        _trace.on_decode_chunk(
-            [self.scheduler.slot_request(s) for s in range(self.cfg.slots)],
-            steps, t0, t1, spec=spec_args)
+        _trace.on_decode_chunk(inflight, steps, t0, t1, spec=spec_args)
         _sm.DECODE_STEP_MS.observe((t1 - t0) * 1e3)
         _sm.DECODE_DISPATCHES.inc()
         # a verify dispatch is ONE windowed model step however wide the
@@ -1417,7 +1456,7 @@ class ServingEngine:
                 # device-side sampling: keyed by ln (the consumed token's
                 # absolute position), which advances per STEP not per
                 # dispatch — fuse=1 and fuse=4 draw identical streams
-                nxt = _sample_tokens(logits, temp, topk, seed, ln)
+                nxt = _sample_tokens(logits, temp, topk, seed, ln, ac)
                 nxt = jnp.where(ac, nxt, tk)
                 emitted = ac
                 gc = gc + ac
@@ -1495,7 +1534,7 @@ class ServingEngine:
             tt = _sample_tokens(
                 logits.reshape(b * w, -1), jnp.repeat(temp, w),
                 jnp.repeat(topk, w), jnp.repeat(seed, w),
-                posw.reshape(b * w)).reshape(b, w)
+                posw.reshape(b * w), jnp.repeat(active, w)).reshape(b, w)
             # token consumed by step j+1 (draft j); dummy past the window
             nxt_cons = jnp.concatenate(
                 [draft, jnp.zeros((b, 1), jnp.int32)], axis=1)
@@ -1568,7 +1607,9 @@ class ServingEngine:
                 logits, cache, *_ = model.decode(params, cache, ops, tkb,
                                                  posb, ac)
                 is_last = ac & (pos == length - 1)
-                cand = _sample_tokens(logits, tempv, topkv, seedv, posb)
+                # only the last prompt position's draw is kept
+                cand = _sample_tokens(logits, tempv, topkv, seedv, posb,
+                                      is_last)
                 tok_acc = tok_acc + jnp.sum(
                     jnp.where(is_last, cand, 0).astype(jnp.int32))
                 log_acc = log_acc + jnp.sum(

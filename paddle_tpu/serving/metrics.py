@@ -16,7 +16,7 @@ __all__ = [
     "REQUESTS_REJECTED", "QUEUE_DEPTH", "SLOT_OCCUPANCY",
     "PAGES_IN_USE", "PAGE_POOL_UTILIZATION", "ADMISSION_BLOCKED",
     "PREFILL_COUNT", "DECODE_STEPS", "DECODE_DISPATCHES",
-    "TOKENS_GENERATED", "CYCLES",
+    "TOKENS_GENERATED", "CYCLES", "SAMPLER_DISPATCHES",
     "REQUEST_LATENCY_MS", "TTFT_MS", "DECODE_STEP_MS", "PREFILL_MS",
     "FAULTS", "RETRIES", "TIMEOUTS", "REQUESTS_FAILED",
     "DRAINS", "DRAINED_REQUESTS", "DRAIN_REJECTED",
@@ -59,6 +59,18 @@ CYCLES = _mx.counter(
     "serving/cycles",
     help="engine.step() calls: the base of every per-cycle ratio "
          "(prefills a cycle, tokens a cycle)")
+# indexed by the tier engine._sampler_tier returns
+SAMPLER_DISPATCHES = tuple(
+    _mx.counter("serving/sampler_dispatches.%s" % tier, help=what)
+    for tier, what in (
+        ("greedy", "decode dispatches and prefills launched with no request "
+                   "that samples: the sampler is the argmax alone"),
+        ("draw", "decode dispatches and prefills launched with a request "
+                 "of temperature > 0 and none of those with top_k > 0: the "
+                 "sampler scales and draws, and does not sort"),
+        ("sort", "decode dispatches and prefills launched with a request of "
+                 "temperature > 0 and top_k > 0: the sampler sorts the "
+                 "vocabulary")))
 REQUEST_LATENCY_MS = _mx.histogram(
     "serving/request_latency_ms",
     help="submit -> finish wall time per retired request")

@@ -201,12 +201,25 @@ SERVE = dict(slots=32, page_size=16, num_pages=2048, max_seq=1024,
              window=5)
 
 
-def _serve_case(name, chip):
+def _pick(logits, sampling, position, live=None):
+    """A step's next tokens: the engine's sampler where the executable was
+    given ``(temp, top_k, seed)``, the plain argmax where it was not."""
+    from paddle_tpu.serving.engine import _sample_tokens
+
+    if sampling:
+        return _sample_tokens(logits, *sampling, position, live)
+    return jnp.argmax(logits, -1).astype(jnp.int32)
+
+
+def _serve_case(name, chip, n_layer=None):
     """``(fn, abstract args)`` of one of ``ServingEngine``'s executables,
     composed as ``engine._get_*_exe`` composes it: the model's forward with
     the cache's own ``write_*``/``decode_*`` calls, the cache first in the
     result. Greedy ``argmax`` stands where the engine calls its sampler (the
-    vocabulary sort alone compiles for 22 s and touches no pool)."""
+    vocabulary sort alone compiles for 22 s and touches no pool), but in
+    ``chunk_sampler`` and ``prefill_sampler``: the same two with ``temp``,
+    ``top_k`` and ``seed`` among their arguments and the engine's own
+    ``_sample_tokens``."""
     from paddle_tpu.models import decoder_lm
     from paddle_tpu.serving.kv_cache import PagedKVCache
 
@@ -215,7 +228,8 @@ def _serve_case(name, chip):
 
     g = SERVE
     cfg = decoder_lm.DecoderConfig(
-        vocab_size=g["vocab"], n_layer=g["n_layer"], d_model=g["d_model"],
+        vocab_size=g["vocab"], n_layer=n_layer or g["n_layer"],
+        d_model=g["d_model"],
         n_head=g["n_head"], max_seq=g["max_seq"], dtype="bfloat16")
     model = decoder_lm.DecoderLM(cfg, params={})
     ops = PagedKVCache(cfg.n_layer, cfg.n_head, cfg.d_head, g["slots"],
@@ -231,11 +245,11 @@ def _serve_case(name, chip):
     b, w = g["slots"], g["window"]
     ints, flags = sds((b,), jnp.int32), sds((b,), jnp.bool_)
 
-    def chunk(params, cache, lengths, tokens, active):
+    def chunk(params, cache, lengths, tokens, active, *sampling):
         def body(carry, _):
             cache, ln, tk, ac = carry
             logits, cache = model.decode(params, cache, ops, tk, ln, ac)
-            nxt = jnp.where(ac, jnp.argmax(logits, -1).astype(jnp.int32), tk)
+            nxt = jnp.where(ac, _pick(logits, sampling, ln, ac), tk)
             return (cache, ln + ac, nxt, ac), nxt
 
         return jax.lax.scan(body, (cache, lengths, tokens, active), None,
@@ -246,11 +260,13 @@ def _serve_case(name, chip):
                                      active, write_mask)
         return cache, jnp.argmax(logits, -1)
 
-    def prefill(params, cache, dest, prompt, length):
+    def prefill(params, cache, dest, prompt, length, *sampling):
         logits, kvs = model.prefill(params, prompt[None], length[None])
         for i, (k, v) in enumerate(kvs):
             cache = ops.write_prompt(cache, i, k[0], v[0], dest, length)
-        return cache, jnp.argmax(logits[0, length - 1])
+        return cache, _pick(logits[0, length - 1][None],
+                            [x[None] for x in sampling],
+                            (length - 1)[None])[0]
 
     def resume(params, cache, toks, start, length, slot):
         mask = jnp.arange(b, dtype=jnp.int32) == slot
@@ -265,13 +281,17 @@ def _serve_case(name, chip):
         return jax.lax.scan(body, cache, jnp.arange(g["page_size"]))
 
     scalar = sds((), jnp.int32)
+    prefill_args = (params, cache, sds((ops.pages_per_slot,), jnp.int32),
+                    sds((g["bucket"],), jnp.int32), scalar)
     return {
         "chunk": (chunk, (params, cache, ints, ints, flags)),
+        "chunk_sampler": (chunk, (params, cache, ints, ints, flags,
+                                  sds((b,), jnp.float32), ints, ints)),
+        "prefill_sampler": (prefill, prefill_args + (
+            sds((), jnp.float32), scalar, scalar)),
         "verify": (verify, (params, cache, ints, sds((b, w), jnp.int32),
                             flags, sds((b, w), jnp.bool_))),
-        "prefill": (prefill, (params, cache,
-                              sds((ops.pages_per_slot,), jnp.int32),
-                              sds((g["bucket"],), jnp.int32), scalar)),
+        "prefill": (prefill, prefill_args),
         "resume": (resume, (params, cache, sds((g["page_size"],), jnp.int32),
                             scalar, scalar, scalar)),
     }[name], ops.num_rows
@@ -314,18 +334,95 @@ def test_serving_executables_leave_the_pool_in_place(chip, monkeypatch, exe):
     assert compiled.memory_analysis().temp_size_in_bytes < 64 * 2 ** 20
 
 
+def _computations(text):
+    """``{name: lines}`` of an HLO module's computations, and the entry's
+    name."""
+    comps, entry, cur = {}, None, None
+    for line in text.split("\n"):
+        m = re.match(r"(ENTRY )?%(\S+) \(.*\{\s*$", line)
+        if m and not line.startswith(" "):
+            cur = m.group(2)
+            comps[cur] = []
+            entry = cur if m.group(1) else entry
+        elif line.startswith("}"):
+            cur = None
+        elif cur is not None:
+            comps[cur].append(line)
+    return comps, entry
+
+
+def _runs_every_step(comps, entry):
+    """The computations reached from the entry without passing through a
+    ``conditional``'s branch: what runs whichever branch is taken."""
+    seen, todo = set(), [entry]
+    while todo:
+        name = todo.pop()
+        if name in seen or name not in comps:
+            continue
+        seen.add(name)
+        for line in comps[name]:
+            if re.search(r" conditional\(", line):
+                continue
+            todo += re.findall(r"%([\w.\-]+)", " ".join(re.findall(
+                r"(?:calls|to_apply|body|condition)=(\{[^}]*\}|\S+)", line)))
+    return seen
+
+
+@pytest.mark.parametrize("exe", ["chunk", "prefill"])
+@pytest.mark.parametrize("config", ["gpt2_small", "smallthinker"])
+def test_sampler_sorts_only_inside_a_branch(chip, monkeypatch, config, exe):
+    """With the engine's own sampler in the decode chunk and in a prefill
+    bucket of both served models (their widths and vocabularies, a toy
+    depth), every sort over the vocabulary and the ``[B, V]`` division by
+    the temperature are compiled into a ``conditional``'s branches, none
+    into what the step runs whichever branch it takes: a step whose live
+    slots are all greedy runs neither."""
+    monkeypatch.setattr(attention_ops, "paged_kernel_mode",
+                        lambda: "compiled")
+    if config == "gpt2_small":
+        (fn, args), _ = _serve_case(exe + "_sampler", chip, n_layer=2)
+        b, v = SERVE["slots"], SERVE["vocab"]
+    else:
+        monkeypatch.setattr(attention_ops, "_on_tpu", lambda: True)
+        (fn, args), _ = _moe_case(exe + "_sampler", chip, n_layer=1,
+                                  bucket=1024)
+        b, v = MOE_SERVE["slots"], MOE_SERVE["vocab"]
+    if exe == "prefill":
+        b = 1
+    text = jax.jit(fn, donate_argnums=(1,)).lower(*args).compile().as_text()
+    comps, entry = _computations(text)
+    always = _runs_every_step(comps, entry)
+    assert entry in always and len(always) > 1
+
+    def holding(opcode):  # computations with a [b, v] result of `opcode`
+        return {name for name, lines in comps.items() for ln in lines
+                if " %s(" % opcode in ln
+                and "[%d,%d]" % (b, v) in ln.split(" %s(" % opcode)[0]}
+
+    sorts = holding("sort")
+    assert sorts, "no sort of the vocabulary: is the sampler in the step?"
+    assert not sorts & always, sorts & always
+    assert not holding("divide") & always
+    # and the branches hang off one conditional of three
+    cond, = [ln for lines in comps.values() for ln in lines
+             if re.search(r" conditional\(", ln)]
+    assert len(re.findall(r"%", cond.split("branch_computations=")[1]
+                          .split("}")[0])) == 3
+
+
 # -- the sparse decoder's executables at smallthinker-21b-a3b-serve's widths ----
 
 MOE_SERVE = dict(slots=16, page_size=16, max_seq=16384, global_pages=6144,
                  window_pages=4096, vocab=151936, bucket=8192)
 
 
-def _moe_case(name, chip, n_layer=4, sampler=None, bucket=None):
+def _moe_case(name, chip, n_layer=4, bucket=None):
     """``(fn, abstract args, cache ops)`` of the decode chunk or a prefill
     bucket over SmallThinker's block at its published widths (one period of
     its layer pattern by default: the widths are what the chip's compiler
     judges), composed as the engine composes them, over a cache of two
-    groups."""
+    groups. ``chunk_sampler`` and ``prefill_sampler`` call the engine's own
+    sampler in place of the argmax, as in ``_serve_case``."""
     from paddle_tpu.models import smallthinker as st
     from paddle_tpu.serving.kv_cache import CacheGroup, PagedKVCache
 
@@ -354,32 +451,35 @@ def _moe_case(name, chip, n_layer=4, sampler=None, bucket=None):
     cache = abstract(ops.init_state)
     b = g["slots"]
     ints, flags = sds((b,), jnp.int32), sds((b,), jnp.bool_)
-    pick = sampler or (lambda logits, pos: jnp.argmax(logits, -1)
-                       .astype(jnp.int32))
 
-    def chunk(params, cache, lengths, tokens, active):
+    def chunk(params, cache, lengths, tokens, active, *sampling):
         def body(carry, _):
             cache, ln, tk, ac = carry
             logits, cache, stats = model.decode(params, cache, ops, tk, ln,
                                                 ac)
-            nxt = jnp.where(ac, pick(logits, ln), tk)
+            nxt = jnp.where(ac, _pick(logits, sampling, ln, ac), tk)
             return (cache, ln + ac, nxt, ac), (nxt, stats)
 
         return jax.lax.scan(body, (cache, lengths, tokens, active), None,
                             length=1)
 
-    def prefill(params, cache, dest, prompt, length):
+    def prefill(params, cache, dest, prompt, length, *sampling):
         logits, kvs = model.prefill_last(params, prompt[None], length[None])
         for i, (k, v) in enumerate(kvs):
             cache = ops.write_prompt(cache, i, k[0], v[0], dest, length)
-        return cache, pick(logits, (length - 1)[None])[0]
+        return cache, _pick(logits, [x[None] for x in sampling],
+                            (length - 1)[None])[0]
 
-    dest = sds((ops.page_table_len,), jnp.int32)
+    scalar = sds((), jnp.int32)
+    prefill_args = (params, cache, sds((ops.page_table_len,), jnp.int32),
+                    sds((bucket or g["bucket"],), jnp.int32), scalar)
     return {
         "chunk": (chunk, (params, cache, ints, ints, flags)),
-        "prefill": (prefill, (params, cache, dest,
-                              sds((bucket or g["bucket"],), jnp.int32),
-                              sds((), jnp.int32))),
+        "chunk_sampler": (chunk, (params, cache, ints, ints, flags,
+                                  sds((b,), jnp.float32), ints, ints)),
+        "prefill": (prefill, prefill_args),
+        "prefill_sampler": (prefill, prefill_args + (
+            sds((), jnp.float32), scalar, scalar)),
     }[name], ops
 
 
