@@ -1269,7 +1269,7 @@ def bench_scaling(axes_str="data=8"):
     return out
 
 
-def _run_ledger_section(kind, configs, extra=None):
+def _run_ledger_section(kind, configs):
     """Append one provenance-stamped record to the run ledger (armed via
     PADDLE_TPU_RUN_LEDGER — see monitor.runlog) and return the tail keys
     (run_id, ledger path) every summary carries so ledger, telemetry ring
@@ -1277,7 +1277,7 @@ def _run_ledger_section(kind, configs, extra=None):
     try:
         from paddle_tpu.monitor import runlog
 
-        runlog.record_run(kind, configs, extra=extra)
+        runlog.record_run(kind, configs)
         return runlog.tail_info()
     except Exception as e:
         return {"run_id": None, "run_ledger_error": repr(e)[:80]}
@@ -1291,73 +1291,6 @@ def main():
     pipeline = "--pipeline" in sys.argv
     if pipeline:
         sys.argv.remove("--pipeline")
-    if len(sys.argv) > 1 and sys.argv[1] == "--quick":
-        # ~1s CPU probe through tools/perf_gate's tiny MLP train loop:
-        # the cheap way to grow the run ledger a baseline point per
-        # commit; same summary-tail shape as the full bench.
-        from tools import perf_gate as _pg
-
-        configs, breakdowns = _pg.run_probe()
-        summary = dict(configs)
-        summary["autotune"] = _autotune_summary()
-        summary.update(_run_ledger_section("bench", configs,
-                                           extra={"stepstats": breakdowns}))
-        print(json.dumps({"summary": summary}))
-        return 0
-    if len(sys.argv) > 1 and sys.argv[1] == "--serve":
-        # serving-stack leg (paddle_tpu.serving): ragged continuous batching
-        # + paged KV-cache vs the padded static-batch baseline on one
-        # synthetic mixed-length stream. CPU-sim OK; the compact summary
-        # (p50/p99 latency + sustained QPS) rides the truncation-proof tail.
-        from tools import serve_bench as _sb
-
-        res = _sb.serve_bench()
-        cont = res["continuous_paged"]
-        print(json.dumps({
-            "metric": "serving_sustained_qps_mixed_stream",
-            "value": cont["qps"],
-            "unit": "requests/sec",
-            "vs_baseline": res["qps_ratio_vs_padded"],
-            "detail": res,
-            "metrics": _monitor_metrics_section(),
-        }))
-        serve_summary = {
-            "qps": cont["qps"],
-            "latency_p50_ms": cont["latency_p50_ms"],
-            "latency_p99_ms": cont["latency_p99_ms"],
-            "tokens_per_sec": cont["tokens_per_sec"],
-            "qps_ratio_vs_padded": res["qps_ratio_vs_padded"],
-            "decode_fuse": "%s(%s)" % (res["config"]["decode_fuse"],
-                                       res["config"]["decode_fuse_source"]),
-            # which decode-attention inner loop the headline leg ran +
-            # the tune-table layer that supplied its block config
-            "decode_kernel": "%s(%s)" % (cont["decode_kernel"],
-                                         cont["decode_kernel_source"]),
-        }
-        # the paged-kernel A/B leg (present when the kernel compiled, i.e.
-        # --kernel paged or auto-on-TPU): kernel:gather ratios + the
-        # kernel leg's own provenance ride the tail
-        kleg = res.get("continuous_paged_kernel")
-        if isinstance(kleg, dict) and "error" not in kleg:
-            serve_summary["kernel_qps_ratio"] = (
-                res["kernel_vs_gather"]["qps_ratio"])
-            serve_summary["kernel_tokens_per_sec_ratio"] = (
-                res["kernel_vs_gather"]["tokens_per_sec_ratio"])
-            serve_summary["kernel_leg"] = "%s(%s)" % (
-                kleg["decode_kernel"], kleg["decode_kernel_source"])
-        # observability artifacts (armed via PADDLE_TPU_TRACE_FILE /
-        # PADDLE_TPU_TELEMETRY_DIR) surface in the truncation-proof tail
-        for key in ("trace_file", "telemetry_dir"):
-            if key in res:
-                serve_summary[key] = res[key]
-        tail = {"serve": serve_summary, "autotune": _autotune_summary()}
-        tail.update(_run_ledger_section(
-            "serve_bench", {"serve_mixed_stream": {
-                k: v for k, v in serve_summary.items()
-                if isinstance(v, (int, float))}}))
-        print(json.dumps({"summary": tail}))
-        return 0
-
     if len(sys.argv) > 1 and sys.argv[1] == "--mesh":
         if len(sys.argv) < 3:
             print(json.dumps({"error": "usage: bench.py --mesh data=8"}))
@@ -1590,11 +1523,6 @@ def main():
     except Exception as e:
         detail["deepfm_ctr"] = {"error": repr(e)[:200]}
 
-    try:
-        device_profile = _device_profile_section()
-    except Exception as e:
-        device_profile = {"error": repr(e)[:200]}
-
     vs = (tfm_eps / ROUND1_BASELINE_EXAMPLES_PER_SEC
           if ROUND1_BASELINE_EXAMPLES_PER_SEC else 1.0)
     print(json.dumps({
@@ -1603,7 +1531,6 @@ def main():
         "unit": "examples/sec",
         "vs_baseline": round(vs, 3),
         "detail": detail,
-        "device_profile": device_profile,
         "metrics": _monitor_metrics_section(),
     }))
     # the compact per-config digest is the LAST line on purpose: a log tail
@@ -1634,7 +1561,6 @@ def _autotune_summary():
             ("flash_attention", tune.bucket_seq(8192, 8192)),
             ("sparse_adam", tune.bucket_rows(1024, 64)),
             ("softmax_xent", tune.bucket_nv(4096, 32768)),
-            ("serving.decode_fuse", tune.bucket_slots(8)),
         )
         prov = tune.provenance_snapshot()
         for kern, bucket in probes:
@@ -1717,41 +1643,6 @@ def _graph_opt_section():
         "softmax_xent_rewrites": val(
             "passes/softmax_xent_fuse_pass/rewrites_matched"),
     }}
-
-
-def _device_profile_section(batch=64):
-    """The ``device_profile`` section: per-op flops/bytes attribution +
-    measured XLA cost/memory analysis for the canonical MLP train config
-    (tools/profile_report's demo shape at bench batch). AOT-compiled via
-    ``Executor.prepare`` — one extra small compile, no step execution —
-    so every bench JSON carries a roofline table whose ``slot`` ids match
-    the ``<slot>:<type>`` named scopes in any xprof trace taken alongside.
-    Render it with ``python -m tools.profile_report <bench.json>``."""
-    import paddle_tpu as fluid
-    from paddle_tpu.monitor import device as _dev
-
-    with fluid.unique_name.guard():
-        with fluid.scope_guard(fluid.Scope()):
-            main, startup = fluid.Program(), fluid.Program()
-            with fluid.program_guard(main, startup):
-                x = fluid.layers.data("x", shape=[32])
-                y = fluid.layers.data("y", shape=[1], dtype="int64")
-                h = fluid.layers.fc(x, size=64, act="relu")
-                logits = fluid.layers.fc(h, size=10)
-                loss = fluid.layers.mean(
-                    fluid.layers.softmax_with_cross_entropy(logits, y))
-                fluid.optimizer.SGD(0.1).minimize(loss)
-            exe = fluid.Executor(fluid.TPUPlace(0))
-            exe.run(startup)
-            compiled = exe.prepare(
-                main, feed={"x": ((batch, 32), "float32"),
-                            "y": ((batch, 1), "int64")},
-                fetch_list=[loss])
-    rep = _dev.step_report(compiled.program,
-                           getattr(compiled, "_aot", None),
-                           batch_size=batch, top=12)
-    rep["config"] = "mlp_train_b%d" % batch
-    return rep
 
 
 def _monitor_metrics_section():

@@ -222,7 +222,7 @@ def numerics_mode(steps=40, reps=3):
                     best.append((time.perf_counter() - t0) / steps * 1e3)
                 best.sort()
                 # mean of the fastest half: cross-process stable where a
-                # bare median wobbles (perf_gate --record discipline)
+                # bare median wobbles
                 half = best[:max(1, len(best) // 2)]
                 return sum(half) / len(half), losses
 

@@ -1761,7 +1761,7 @@ class Executor:
                           jax.ShapeDtypeStruct((), np.dtype("uint32"))))
         # the AOT artifacts are the attribution surface: the executable's
         # cost_analysis/memory_analysis feed the device_profile/* gauges
-        # (memory_report, tools/profile_report read them), and the lowered
+        # (memory_report, monitor.stepstats read them), and the lowered
         # module keeps the FULL per-op named-scope coverage that XLA's
         # fusion passes strip from the compiled text
         # (monitor.device.lowered_scope_text) — free here, prepare() paid

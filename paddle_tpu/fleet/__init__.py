@@ -16,7 +16,7 @@ rings (:mod:`.slo`), the run-stamped fleet event journal
 ``serving.phases``): per-request phase ledgers derived from the merged
 span stream, ``fleet/phase/*`` latency budgets, and automatic
 SLO-breach root-cause verdicts (tools/fleet_autopsy.py). See ROADMAP
-item 2, tools/fleet_bench.py and tools/fleet_top.py.
+item 2 and tools/fleet_top.py.
 """
 
 from . import metrics  # registers every fleet/* instrument

@@ -1,8 +1,8 @@
 """fleet/* instruments: the monitor-registry face of the fleet router.
 
 One module owns every ``fleet/*`` name so the router, replicas and the
-prefix cache never race a get-or-create, and tools (``tools/fleet_bench``,
-``tools/dump_metrics --selftest``) can assert the full set exists by
+prefix cache never race a get-or-create, and tools
+(``tools/dump_metrics --selftest``) can assert the full set exists by
 importing this module alone. Same hot-path contract as serving.metrics:
 module-level handles, a single disabled-branch per call.
 """

@@ -67,21 +67,16 @@ def sim_token(seed: int, pos: int, vocab: int) -> int:
 class SimConfig:
     """Geometry + the modeled device latency of one sim replica.
 
-    The prefill cost model (all default-off, so existing benches are
-    untouched): admission of a prompt blocks ``prefill_ms_per_token`` per
-    token NOT covered by a known prefix — prefill is compute-bound and
-    stalls the whole engine, exactly the contention continuous batching
-    suffers. ``interference`` multiplies that stall while any slot is
-    mid-decode (mixed prefill/decode batches thrash batch shapes and HBM
-    — the published motivation for prefill/decode disaggregation): a
-    replica doing ONLY prefill (or only decode) never pays it.
+    The prefill cost model (default off): admission of a prompt blocks
+    ``prefill_ms_per_token`` per token NOT covered by a known prefix —
+    prefill is compute-bound and stalls the whole engine, exactly the
+    contention continuous batching suffers.
     ``page_size`` is the prefix granularity for the migration surface."""
 
     def __init__(self, slots: int = 4, step_ms: float = 0.0,
                  vocab: int = 256, max_queue: int = 1024,
                  drain_timeout_s: float = 30.0, page_size: int = 16,
                  prefill_ms_per_token: float = 0.0,
-                 interference: float = 1.0,
                  serving_spans: bool = False):
         self.slots = int(slots)
         self.step_ms = float(step_ms)
@@ -90,7 +85,6 @@ class SimConfig:
         self.drain_timeout_s = float(drain_timeout_s)
         self.page_size = max(1, int(page_size))
         self.prefill_ms_per_token = float(prefill_ms_per_token)
-        self.interference = max(1.0, float(interference))
         # Emit the serving-cat request-lifecycle spans (serving.trace) when
         # the host tracer is armed. Default OFF: serving spans ride virtual
         # tracks keyed by track NAME, so two in-process sims would collide
@@ -166,9 +160,7 @@ class SimEngine:
 
     def _prefill_stall(self, req: Request) -> int:
         """The modeled prefill cost of admitting ``req``: per uncovered
-        token, multiplied by ``interference`` when the stall lands in the
-        middle of live decodes (the mixed-batch penalty disaggregation
-        exists to remove). Returns the known-prefix length (the phase
+        token. Returns the known-prefix length (the phase
         ledger's local/resume cause attribution)."""
         if self.cfg.prefill_ms_per_token <= 0:
             if self.cfg.serving_spans:
@@ -180,8 +172,6 @@ class SimEngine:
         else:
             self._prefills += 1
         ms = (req.prompt_len - known) * self.cfg.prefill_ms_per_token
-        if any(len(r.tokens_out) < r.max_new_tokens for r in self._running):
-            ms *= self.cfg.interference
         if ms > 0:
             time.sleep(ms / 1e3)
         return known

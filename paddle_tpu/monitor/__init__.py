@@ -35,11 +35,10 @@ TPU-native in three pieces:
 * :mod:`~paddle_tpu.monitor.budgets` — checked-in closed-form
   collective-traffic budgets asserted against the measured
   ``collectives/*`` counters (``tools/check_budgets.py``).
-* :mod:`~paddle_tpu.monitor.runlog` / :mod:`~paddle_tpu.monitor.regress`
-  / :mod:`~paddle_tpu.monitor.stepstats` — the ACROSS-run layer: a
-  provenance-stamped run ledger (``PADDLE_TPU_RUN_LEDGER``), noise-aware
-  regression verdicts over its trailing baselines, and step-time
-  bottleneck attribution (``tools/perf_gate.py`` is the CLI).
+* :mod:`~paddle_tpu.monitor.runlog` / :mod:`~paddle_tpu.monitor.stepstats`
+  — the ACROSS-run layer: a provenance-stamped run ledger
+  (``PADDLE_TPU_RUN_LEDGER``) whose ``run_id`` cross-links telemetry,
+  traces and fleet events, and step-time bottleneck attribution.
 
 Quick tour::
 
@@ -57,7 +56,7 @@ from __future__ import annotations
 import os
 
 from . import (  # noqa: F401
-    budgets, device, metrics, numerics, regress, runlog, slo, stepstats,
+    budgets, device, metrics, numerics, runlog, slo, stepstats,
     telemetry, tracer,
 )
 from .metrics import (  # noqa: F401
@@ -69,7 +68,7 @@ from .step_logger import StepLogger  # noqa: F401
 from .telemetry import TelemetryExporter  # noqa: F401
 
 __all__ = [
-    "budgets", "device", "metrics", "numerics", "regress", "runlog", "slo",
+    "budgets", "device", "metrics", "numerics", "runlog", "slo",
     "stepstats", "telemetry", "tracer",
     "StepLogger", "SLO", "SLOMonitor", "TelemetryExporter",
     "counter", "gauge", "histogram", "enabled", "enable", "disable",

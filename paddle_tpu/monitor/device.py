@@ -15,7 +15,7 @@ counted. This module is the device-side layer, four pieces:
    The Executor's ``prepare``/AOT path (and ``PADDLE_TPU_DEVICE_PROFILE=1``
    on a compile miss) publishes ``cost_analysis()`` + ``memory_analysis()``
    of the compiled step as the ``device_profile/*`` gauges;
-   ``tools/profile_report.py`` renders the per-op roofline table.
+   :func:`step_report` is the per-op roofline table.
 
 2. **In-graph numerics watchdog** — ``PADDLE_TPU_CHECK_NUMERICS``:
    ``0`` off; ``1`` the post-step check is ONE fused device-side
@@ -366,7 +366,7 @@ def op_scope_coverage(hlo_text: str) -> Dict[str, int]:
     (``executable.as_text()``, ``op_name="..."`` metadata — post-fusion,
     partial coverage) and :func:`lowered_scope_text` output
     (``loc("...")`` debug locations — full pre-optimization coverage).
-    The presence/coverage check behind tests and ``profile_report``.
+    The presence/coverage check the tests use.
     Autodiff re-derives forward ops under ``jvp(<scope>)`` /
     ``transpose(jvp(<scope>))`` path segments — those count toward the
     same ``<slot>:<type>`` scope (it IS the same Program op's work)."""

@@ -4,8 +4,8 @@ PR 8 made a single process observable while it runs (telemetry ring, SLO
 monitor); nothing connected runs to each other — the measured trajectory
 lived in log tails a human had to reread. This module is the ACROSS-run
 layer: with ``PADDLE_TPU_RUN_LEDGER=/path/ledger.jsonl`` armed, every
-``bench.py`` / ``tools/serve_bench.py`` / ``tools/autotune.py`` /
-``tools/perf_gate.py`` invocation appends one record carrying
+``bench.py`` / ``tools/autotune.py`` invocation appends one record
+carrying
 
 * ``run_id`` — one id per process (also printed in the summary tail and
   embedded in flight-recorder dumps, so ledger <-> telemetry <-> crash
@@ -24,10 +24,6 @@ line; the file rotates to ``<path>.<k>`` every
 once and disables the on-disk ledger — it never masks the run it records.
 Read-back (:func:`read_ledger`) tolerates torn trailing lines and skips
 foreign schemas, so a ledger shared across versions stays loadable.
-
-:mod:`paddle_tpu.monitor.regress` consumes the ledger as the baseline
-window for noise-aware regression verdicts; ``tools/perf_gate.py`` is the
-CLI over both.
 """
 
 from __future__ import annotations
@@ -264,7 +260,7 @@ def record_run(kind: str, configs: Dict[str, dict],
 
     ``configs`` is the {config: {metric: value}} map the caller's summary
     tail prints; ``kind`` names the producing surface ("bench",
-    "serve_bench", "autotune", "perf_gate"). Returns the record either
+    "autotune"). Returns the record either
     way — callers embed ``run_id`` in their tails unconditionally, and
     ``record["ledger_path"]`` says whether it also landed on disk."""
     record = {

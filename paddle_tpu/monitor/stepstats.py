@@ -1,11 +1,10 @@
 """Step-time decomposition: WHY a step costs what it costs.
 
-The regression detector (monitor.regress) says a run got slower; this
-module says where the time went, fusing what the repo already measures
+Says where a step's time went, fusing what the repo already measures
 into per-term millisecond estimates for one step:
 
 * ``compute_ms`` — ``device_profile/flops`` / peak FLOP/s (the roofline
-  numerator ``tools/profile_report`` renders per op);
+  numerator ``monitor.device.step_report`` lists per op);
 * ``memory_ms`` — ``device_profile/bytes_accessed`` / HBM bandwidth;
 * ``comms_ms``  — the closed-form ``collectives/*/bytes`` counters /
   ICI bandwidth (per-device bytes one step moves, trace-time accounting);
@@ -21,8 +20,7 @@ so attribution still ranks measured terms instead of going silent.
 :func:`attribute` labels the step **compute- / comms- / host- /
 input-bound** by the dominant term (the device roofline pair compute +
 memory both map to "compute" — they are the same knob family) and
-attaches an actionable hint. Rendered in bench summary tails and by
-``tools/perf_gate.py --explain``.
+attaches an actionable hint; :func:`render` is its text form.
 """
 
 from __future__ import annotations
@@ -48,7 +46,7 @@ PEAKS: Dict[str, Dict[str, float]] = {
 # which Program-level knob each bound label points at
 HINTS = {
     "compute": "device-bound: check MFU vs roofline per op "
-               "(tools/profile_report), precision, and fusion rewrites",
+               "(monitor.device.step_report), precision, and fusion rewrites",
     "comms": "comms-bound: check collectives/* vs the closed-form budgets "
              "(tools/check_budgets) and overlap/sharding layout",
     "host": "host-bound: use the fused run_steps driver / AOT prepare so "
@@ -182,7 +180,7 @@ def decompose(snapshot: Optional[Dict[str, dict]] = None, *,
 
 
 def render(breakdown: Dict[str, Any], config: str = "step") -> str:
-    """One short human block for ``perf_gate --explain``."""
+    """One short human block for a breakdown."""
     lines = ["%s: %s-bound (dominant: %s)"
              % (config, breakdown.get("bound", "unknown"),
                 breakdown.get("dominant"))]

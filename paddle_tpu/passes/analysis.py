@@ -169,7 +169,7 @@ def producer_map(block) -> Dict[str, object]:
 def stamp_op_slots(program) -> None:
     """Freeze every op's original position into ``__op_slot__`` — the
     device-side attribution identity: ``jax.named_scope`` labels, the
-    numerics watchdog and ``tools/profile_report`` all report
+    numerics watchdog and ``monitor.device.step_report`` all report
     ``<slot>:<type>``, so op deletion/motion by the passes never shifts
     a reported op identity away from the SOURCE program's numbering.
     Idempotent (already-stamped ops keep their slot); ops inserted by

@@ -29,8 +29,7 @@ Quick start::
     print(reqs[0].tokens_out, reqs[0].latency_s)
     eng.close()               # releases the continuous-telemetry exporter
 
-Benchmarks: ``python bench.py --serve`` (ragged continuous batching vs the
-padded static baseline), ``python -m tools.serve_bench --selftest``.
+Measured by the serving cells of ``python3 -m grid.run`` (``BENCHMARK.json``).
 """
 
 from . import trace  # noqa: F401

@@ -4,7 +4,7 @@ The device-resident serving loop the ROADMAP's item-1 gap called for. One
 :class:`ServingEngine` owns:
 
 * a :class:`~.scheduler.Scheduler` (bounded queue → fixed batch slots,
-  continuous in-flight admission or the static wave-drain baseline),
+  continuous in-flight admission),
 * a :class:`~.page_pool.PagePool` + :class:`~.kv_cache.PagedKVCache` (or
   the :class:`~.kv_cache.ContiguousKVCache` reference layout),
 * AOT-compiled step functions built through ``executor.aot_compile`` —
@@ -157,7 +157,6 @@ class ServingConfig:
     count + device kind) and falls back to 1 when no tuned entry exists —
     ``decode_fuse_source`` records which layer answered
     (tuned/shipped/default vs "explicit" for a literal int).
-    ``continuous=False`` degrades to the padded static wave-drain baseline;
     ``paged=False`` swaps in the contiguous reference cache. ``eos_id=None``
     disables EOS stopping (generation runs to ``max_new_tokens``).
     ``kv_dtype="int8"`` requests quantized KV pages
@@ -203,7 +202,7 @@ class ServingConfig:
                  prompt_buckets: Optional[Sequence[int]] = None,
                  max_queue: int = 1024, eos_id: Optional[int] = None,
                  decode_fuse=1, paged: bool = True,
-                 continuous: bool = True, collect_logits: bool = False,
+                 collect_logits: bool = False,
                  pad_id: int = 0, decode_retries: int = 2,
                  fail_fast: bool = False,
                  slos: Optional[Sequence] = None,
@@ -237,7 +236,6 @@ class ServingConfig:
             decode_fuse, self.decode_fuse_source = self._tuned_decode_fuse()
         self.decode_fuse = max(1, int(decode_fuse))
         self.paged = bool(paged)
-        self.continuous = bool(continuous)
         self.collect_logits = bool(collect_logits)
         self.pad_id = int(pad_id)
         self.decode_retries = max(0, int(decode_retries))
@@ -275,9 +273,7 @@ class ServingConfig:
     def _tuned_decode_fuse(self):
         """(value, source) from the autotuned config table; (1, "default")
         when no entry (or any table failure — serving must come up even
-        with a corrupt table on disk). tools/serve_bench reports through
-        the SAME tune.resolve_decode_fuse, so bench and engine can't
-        diverge."""
+        with a corrupt table on disk)."""
         from .. import tune
 
         return tune.resolve_decode_fuse(self.slots)
@@ -373,8 +369,7 @@ class ServingEngine:
         # the first group's pool, under the name a one-group engine's only
         # pool always had
         self.pool: Optional[PagePool] = self.pools[0] if self.pools else None
-        self.scheduler = Scheduler(self.cfg.slots, self.cfg.max_queue,
-                                   continuous=self.cfg.continuous)
+        self.scheduler = Scheduler(self.cfg.slots, self.cfg.max_queue)
         self._cache = self.cache_ops.init_state()
         b = self.cfg.slots
         self._len = jnp.zeros((b,), jnp.int32)
@@ -872,12 +867,6 @@ class ServingEngine:
         slots = self.scheduler.admissible_slots()
         if not slots or self.scheduler.peek() is None:
             return finished
-        wave_bucket = None
-        if not self.cfg.continuous:
-            # the padded static baseline: every prompt of the wave pays the
-            # wave-max bucket, the classic fully-padded batch
-            wave = self.scheduler.peek_n(len(slots))
-            wave_bucket = self._bucket_for(max(r.prompt_len for r in wave))
         for slot in slots:
             req = self.scheduler.peek()
             if req is None:
@@ -891,8 +880,8 @@ class ServingEngine:
             req.group_pages = group_pages
             req.pages = group_pages[0] if group_pages else []
             _trace.on_admitted(req, slot)
-            bucket = wave_bucket or self._bucket_for(req.prompt_len)
-            done = self._prefill(req, slot, bucket)
+            done = self._prefill(req, slot,
+                                 self._bucket_for(req.prompt_len))
             if done is not None:
                 finished.append(done)
         return finished

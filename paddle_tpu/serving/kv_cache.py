@@ -496,8 +496,7 @@ class Int8PagedKVCache(PagedKVCache):
     loop and the attention ops stay layout-blind; only the pool storage and
     ``cache_bytes`` see int8 — half the page bytes of bf16, a quarter of
     fp32, which under the PagePool's unchanged reservation math doubles
-    (resp. quadruples) the page capacity of the same byte budget
-    (tools/serve_bench.py asserts the capacity and decode-parity claims).
+    (resp. quadruples) the page capacity of the same byte budget.
 
     ``decode_attention``/``decode_verify`` always take the gather path
     (``kernel_mode`` says so): the ragged Pallas kernel reads raw pool rows

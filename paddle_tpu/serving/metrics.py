@@ -1,8 +1,8 @@
 """serving/* instruments: the monitor-registry face of the serving stack.
 
 One module owns every ``serving/*`` name so the scheduler, page pool and
-decode driver never race a get-or-create, and tools (``tools/serve_bench``,
-``tools/dump_metrics --selftest``) can assert the full set exists by
+decode driver never race a get-or-create, and tools
+(``tools/dump_metrics --selftest``) can assert the full set exists by
 importing this module alone. Same hot-path contract as the executor
 instruments: module-level handles, a single disabled-branch per call.
 """

@@ -300,7 +300,7 @@ def ledgers_from_spans(spans: Sequence[dict],
                        ) -> Dict[str, RequestLedger]:
     """One :class:`RequestLedger` per ``args.trace_id`` in ``spans``.
 
-    Works on a single-engine serving stream (serve_bench traces) and on a
+    Works on a single-engine serving stream and on a
     merged fleet stream (``fleet.trace.load_fragments`` output — pass the
     manifest-derived ``pid_to_replica`` so engine-side intervals carry
     replica attribution). Migration (``ship``) windows are joined in from

@@ -5,10 +5,7 @@ ingestion role from the reference, SURVEY L4, re-shaped for autoregressive
 decode): a bounded FIFO queue feeds ``n_slots`` fixed batch-bucket slots.
 Each decode step the engine retires finished slots and admits queued
 requests into the holes, so new requests join the running batch mid-flight
-instead of waiting for it to drain. ``continuous=False`` degrades to the
-classic static-batch policy — admit only when EVERY slot is free, drain the
-whole wave — which is exactly the padded baseline ``bench.py --serve``
-compares against.
+instead of waiting for it to drain.
 
 Pure host-side bookkeeping (no device state) so its invariants are testable
 under churn without compiling anything; the engine owns pages and device
@@ -28,13 +25,11 @@ __all__ = ["Scheduler"]
 
 
 class Scheduler:
-    def __init__(self, n_slots: int, max_queue: int = 1024,
-                 continuous: bool = True):
+    def __init__(self, n_slots: int, max_queue: int = 1024):
         if n_slots < 1:
             raise ValueError("need at least one slot")
         self.n_slots = int(n_slots)
         self.max_queue = int(max_queue)
-        self.continuous = bool(continuous)
         self._queue: Deque[Request] = deque()
         self._slots: List[Optional[Request]] = [None] * self.n_slots
 
@@ -75,19 +70,10 @@ class Scheduler:
     def peek(self) -> Optional[Request]:
         return self._queue[0] if self._queue else None
 
-    def peek_n(self, n: int) -> List[Request]:
-        """The first ``n`` queued requests (fewer if the queue is shorter) —
-        the static wave policy sizes its padding bucket from these."""
-        return [self._queue[i] for i in range(min(n, len(self._queue)))]
-
     # -- slot side ------------------------------------------------------------
     def admissible_slots(self) -> List[int]:
-        """Slots the policy allows filling now: any free slot when
-        continuous, and only a fully-drained batch otherwise."""
-        free = [i for i, r in enumerate(self._slots) if r is None]
-        if not self.continuous and len(free) != self.n_slots:
-            return []
-        return free
+        """Slots that can be filled now: every free one."""
+        return [i for i, r in enumerate(self._slots) if r is None]
 
     def admit(self, slot: int) -> Request:
         """Move the queue head into ``slot`` (caller has already secured

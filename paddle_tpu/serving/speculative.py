@@ -34,10 +34,8 @@ the trailing n-gram of (prompt + generated) against its own history and
 propose the continuation that followed last time.  Zero weights, zero
 device work, and it wins exactly on the repetitive traffic the PR-14
 prefix-cached fleet implies (and on the loops tiny greedy models collapse
-into).  Draft-k is one more measured tunable (TVM, PAPERS.md): resolve it
-through the tune table with ``speculation="auto"``
-(``tune.resolve_speculation_k``, sweep via ``tools/autotune.py --kernel
-speculation_k``).
+into).  Draft-k is one more tune-table entry: resolve it with
+``speculation="auto"`` (``tune.resolve_speculation_k``).
 """
 
 from __future__ import annotations

@@ -25,8 +25,8 @@ spec.
 Everything here guards on ``tracer.active()`` — an untraced engine pays
 one bool read per call site.
 
-:func:`validate_request_spans` is the invariant checker serve_bench's
-selftest (and tests) run over a drained stream: every terminal request
+:func:`validate_request_spans` is the invariant checker the tests run
+over a drained stream: every terminal request
 must have a COMPLETE, WELL-NESTED span set — no orphan ``queued``
 without a terminal instant, no partially-overlapping spans on a track.
 """
@@ -273,7 +273,7 @@ def assert_well_nested(spans: Sequence[dict], cat: str = CAT,
 
 def slot_assignments_from_spans(spans: Sequence[dict]) -> Dict[int, List[str]]:
     """{tid: [trace ids in start order]} from lifetime spans — the
-    schedule reconstruction serve_bench cross-checks against the
+    schedule reconstruction that is cross-checked against the
     ``serving/*`` counters (sum of assignments == requests admitted)."""
     out: Dict[int, List[tuple]] = {}
     for s in spans:

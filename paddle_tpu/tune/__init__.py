@@ -14,13 +14,14 @@ infrastructure (TVM's measured schedule search, PAPERS.md):
   VMEM pruning, warmup + median-of-k timing with compile excluded,
   ``autotune/*`` counters, atomic table writes.
 * :mod:`~paddle_tpu.tune.tunables` — the registered knobs: flash
-  BlockSizes, sparse-adam row blocks, softmax-xent tiles, per-program
-  pass gates (end-to-end measured), serving ``decode_fuse``.
+  BlockSizes, sparse-adam row blocks, softmax-xent tiles, paged-attention
+  page blocks, per-program pass gates (end-to-end measured).
 
 Entry points: ``tools/autotune.py`` (sweep + write + before/after table);
 ``ops/attention_ops._tuned_block_sizes``, ``sparse_adam._block_size`` and
 the softmax-xent tile choice consult :func:`lookup` at trace time;
-``ServingConfig(decode_fuse="auto")`` does the same for serving.
+``ServingConfig(decode_fuse="auto")`` and ``FleetConfig(replicas="auto")``
+read the same table through the ``resolve_*`` functions.
 """
 
 from .table import (  # noqa: F401
@@ -58,7 +59,7 @@ __all__ = [
 
 
 def __getattr__(name):
-    # tunables pull in ops/serving/passes machinery — load them only when
+    # tunables pull in ops/passes machinery — load them only when
     # someone actually asks for the registry (the CLI, tests), keeping
     # `import paddle_tpu.tune` cheap for the trace-time lookup path
     if name in ("Tunable", "register_tunable", "get_tunable",

@@ -334,10 +334,9 @@ def lookup(kernel: str, bucket: str, device: Optional[str] = None,
 
 def resolve_decode_fuse(slots: int) -> Tuple[int, str]:
     """(decode_fuse, source) for a serving engine with ``slots`` batch
-    slots — THE shared resolution ``ServingConfig(decode_fuse="auto")``
-    and ``tools/serve_bench`` both use, so the value the bench reports is
-    by construction the value the engine runs. (1, "default") on no entry
-    or any table failure: serving must come up even with a corrupt table."""
+    slots — the resolution behind ``ServingConfig(decode_fuse="auto")``.
+    (1, "default") on no entry or any table failure: serving must come up
+    even with a corrupt table."""
     try:
         cfg, src = lookup("serving.decode_fuse", bucket_slots(slots))
         if cfg and int(cfg.get("decode_fuse", 0)) > 0:
@@ -349,12 +348,11 @@ def resolve_decode_fuse(slots: int) -> Tuple[int, str]:
 
 def resolve_speculation_k(slots: int) -> Tuple[int, str]:
     """(draft k, source) for speculative decoding on a serving engine with
-    ``slots`` batch slots — THE shared resolution
-    ``ServingConfig(speculation="auto")`` and ``tools/serve_bench`` both
-    use, mirroring :func:`resolve_decode_fuse`. The useful k trades
-    verify-window compute against acceptance decay, so it is measured per
-    (slot bucket, device kind) by ``tools/autotune.py --kernel
-    speculation_k``. (4, "default") on no entry or any table failure:
+    ``slots`` batch slots — the resolution behind
+    ``ServingConfig(speculation="auto")``, mirroring
+    :func:`resolve_decode_fuse`. The useful k trades verify-window compute
+    against acceptance decay, so the table keys it per (slot bucket,
+    device kind). (4, "default") on no entry or any table failure:
     serving must come up even with a corrupt table."""
     try:
         cfg, src = lookup("serving.speculation_k", bucket_slots(slots))
@@ -367,9 +365,8 @@ def resolve_speculation_k(slots: int) -> Tuple[int, str]:
 
 def resolve_fleet_router(cpus: Optional[int] = None
                          ) -> Tuple[Dict[str, object], str]:
-    """(router config, source) for the fleet router — THE shared
-    resolution ``fleet.FleetConfig(replicas="auto")`` and
-    ``tools/fleet_bench`` both use. The config dict carries ``replicas``
+    """(router config, source) for the fleet router — the resolution
+    behind ``fleet.FleetConfig(replicas="auto")``. The config dict carries ``replicas``
     (int) and ``affinity`` (``"prefix"``/``"round_robin"``), bucketed by
     host CPU count (replica workers are processes — the useful count
     tracks cores, not devices). ``({"replicas": 2, "affinity": "prefix"},
@@ -392,9 +389,8 @@ def resolve_fleet_router(cpus: Optional[int] = None
 
 def resolve_fleet_roles(cpus: Optional[int] = None
                         ) -> Tuple[Dict[str, int], str]:
-    """(role mix, source) for a disaggregated fleet — THE shared
-    resolution ``fleet.FleetConfig(roles="auto")`` and
-    ``tools/fleet_bench`` both use. The config dict carries ``prefill``
+    """(role mix, source) for a disaggregated fleet — the resolution
+    behind ``fleet.FleetConfig(roles="auto")``. The config dict carries ``prefill``
     and ``decode`` (replica counts per role), bucketed by host CPU count
     like ``fleet.router``. ``({"prefill": 1, "decode": 1}, "default")``
     on no entry or any table failure: a role-split fleet must come up

@@ -19,11 +19,7 @@ Registered here:
   measured END-TO-END on the optimized clone's step time (a pass that
   costs more than it saves on a given program gets turned off for it);
 * ``paged_attention`` — ``block_pages`` of the ragged paged-attention
-  decode kernel (KV pages DMA'd per online-softmax wave);
-* ``serving.decode_fuse`` — how many serving decode steps fuse into one
-  dispatched scan (host dispatch overhead vs admission latency);
-* ``serving.speculation_k`` — draft length of the speculative
-  draft-verify fast path (tokens-per-dispatch vs rejected-verify waste).
+  decode kernel (KV pages DMA'd per online-softmax wave).
 
 On CPU every tunable still builds and times (Pallas interpret mode / XLA
 CPU) so CI exercises the full mechanism; TPU numbers land via the same CLI
@@ -503,304 +499,3 @@ class PassGatesTunable(Tunable):
 
     def cleanup(self):
         self._built.clear()
-
-
-# -- serving decode_fuse ------------------------------------------------------
-
-
-@register_tunable("serving.decode_fuse")
-class DecodeFuseTunable(Tunable):
-    """How many decode steps the serving engine fuses into one dispatched
-    scan. Measured as end-to-end drain time of a fixed mixed-length request
-    stream — fusing amortizes host dispatch but coarsens admission/
-    retirement granularity, so the winner is stream- and device-dependent
-    (exactly why it is a measured knob, not a constant)."""
-
-    kernel = "serving.decode_fuse"
-
-    def __init__(self):
-        self._open: list = []
-        self._models: Dict[str, object] = {}
-
-    def default_shapes(self):
-        return [dict(slots=4, vocab=64, n_layer=2, d_model=32, n_head=2,
-                     max_seq=64, page_size=8, n_requests=10, max_prompt=20,
-                     max_new=8)]
-
-    def bucket(self, shape):
-        return _table.bucket_slots(shape["slots"])
-
-    def candidates(self, shape):
-        return [{"decode_fuse": k} for k in (1, 2, 4)
-                if k <= shape.get("max_new", 8)]
-
-    def default_config(self, shape):
-        return {"decode_fuse": 1}  # ServingConfig's untuned default
-
-    def _stream(self, shape):
-        import numpy as np
-
-        rng = np.random.RandomState(int(shape.get("seed", 0)))
-        return [(list(rng.randint(0, shape["vocab"],
-                                  int(rng.randint(3, shape["max_prompt"])))),
-                 int(rng.randint(2, shape["max_new"] + 1)))
-                for _ in range(shape["n_requests"])]
-
-    def build(self, shape, config):
-        from .. import serving
-        from ..models import decoder_lm
-
-        mkey = repr(sorted(shape.items()))
-        model = self._models.get(mkey)
-        if model is None:
-            cfg = decoder_lm.DecoderConfig(
-                vocab_size=shape["vocab"], n_layer=shape["n_layer"],
-                d_model=shape["d_model"], n_head=shape["n_head"],
-                max_seq=shape["max_seq"])
-            model = decoder_lm.DecoderLM(cfg, seed=0)
-            self._models[mkey] = model
-        eng = serving.ServingEngine(model, serving.ServingConfig(
-            slots=shape["slots"], page_size=shape["page_size"],
-            max_seq=shape["max_seq"],
-            decode_fuse=int(config["decode_fuse"])))
-        eng.warmup()
-        self._open.append(eng)
-        stream = self._stream(shape)
-
-        def drain():
-            reqs = [eng.submit(p, m) for p, m in stream]
-            done = eng.run()
-            assert len(done) == len(reqs)
-            return len(done)
-
-        return drain, ()
-
-    def cleanup(self):
-        for eng in self._open:
-            try:
-                eng.close()
-            except Exception:
-                pass
-        self._open.clear()
-        self._models.clear()
-
-
-@register_tunable("serving.speculation_k")
-class SpeculationKTunable(Tunable):
-    """Draft length k of the speculative draft-verify fast path
-    (serving.speculative). Measured as end-to-end drain time of a fixed
-    repetitive request stream — longer drafts emit more tokens per verify
-    dispatch while acceptance holds, but every rejected tail is verify
-    compute thrown away, so the winner tracks the traffic's repetitiveness
-    and the device's marginal cost of a wider ragged window (near-free on
-    the memory-bound paged kernel, real on CPU). ``k=0`` (plain decode) is
-    in the space, so a stream speculation cannot help reports an honest
-    "leave it off"."""
-
-    kernel = "serving.speculation_k"
-
-    def __init__(self):
-        self._open: list = []
-        self._models: Dict[str, object] = {}
-
-    def default_shapes(self):
-        return [dict(slots=4, vocab=48, n_layer=2, d_model=32, n_head=2,
-                     max_seq=64, page_size=8, n_requests=8, max_new=24)]
-
-    def bucket(self, shape):
-        return _table.bucket_slots(shape["slots"])
-
-    def candidates(self, shape):
-        return [{"k": k} for k in (0, 2, 4, 8)
-                if k < shape.get("max_new", 8)]
-
-    def default_config(self, shape):
-        return {"k": 4}  # tune.resolve_speculation_k's untuned default
-
-    def _stream(self, shape):
-        import numpy as np
-
-        # repetitive prompts (repeated trigrams) — the traffic class the
-        # n-gram drafter serves; greedy tiny-model loops extend the pattern
-        rng = np.random.RandomState(int(shape.get("seed", 0)))
-        out = []
-        for _ in range(shape["n_requests"]):
-            motif = list(rng.randint(0, shape["vocab"], 3))
-            out.append((motif * 4, int(shape["max_new"])))
-        return out
-
-    def build(self, shape, config):
-        from .. import serving
-        from ..models import decoder_lm
-
-        mkey = repr(sorted(shape.items()))
-        model = self._models.get(mkey)
-        if model is None:
-            cfg = decoder_lm.DecoderConfig(
-                vocab_size=shape["vocab"], n_layer=shape["n_layer"],
-                d_model=shape["d_model"], n_head=shape["n_head"],
-                max_seq=shape["max_seq"])
-            model = decoder_lm.DecoderLM(cfg, seed=0)
-            self._models[mkey] = model
-        eng = serving.ServingEngine(model, serving.ServingConfig(
-            slots=shape["slots"], page_size=shape["page_size"],
-            max_seq=shape["max_seq"], speculation=int(config["k"])))
-        eng.warmup()
-        self._open.append(eng)
-        stream = self._stream(shape)
-
-        def drain():
-            reqs = [eng.submit(p, m) for p, m in stream]
-            done = eng.run()
-            assert len(done) == len(reqs)
-            return len(done)
-
-        return drain, ()
-
-    def cleanup(self):
-        for eng in self._open:
-            try:
-                eng.close()
-            except Exception:
-                pass
-        self._open.clear()
-        self._models.clear()
-
-
-@register_tunable("fleet.router")
-class FleetRouterTunable(Tunable):
-    """Replica count + affinity policy for the fleet router. Measured as
-    end-to-end drain time of a fixed request stream through an in-process
-    sim fleet (device-latency model): more replicas overlap more modeled
-    device wait but add routing/protocol overhead, and prefix affinity
-    trades spread for locality — host- and stream-dependent, so measured.
-    Bucketed by host CPU count (replica workers are processes)."""
-
-    kernel = "fleet.router"
-
-    def __init__(self):
-        self._open: list = []
-
-    def default_shapes(self):
-        import os as _os
-
-        return [dict(cpus=_os.cpu_count() or 1, slots=4, step_ms=2.0,
-                     n_requests=32, max_new=8)]
-
-    def bucket(self, shape):
-        return _table.bucket_slots(shape["cpus"])
-
-    def candidates(self, shape):
-        return [{"replicas": n, "affinity": a}
-                for n in (1, 2, 4)
-                for a in ("prefix", "round_robin")]
-
-    def default_config(self, shape):
-        return {"replicas": 2, "affinity": "prefix"}
-
-    def build(self, shape, config):
-        from ..fleet import FleetConfig, Router, SimConfig, SimEngine
-
-        router = Router(FleetConfig(
-            replicas=int(config["replicas"]),
-            mode="inprocess", affinity=config["affinity"],
-            engine_factory=lambda i: SimEngine(SimConfig(
-                slots=shape["slots"], step_ms=shape["step_ms"]))))
-        self._open.append(router)
-        n_requests = int(shape["n_requests"])
-        max_new = int(shape["max_new"])
-
-        def drive():
-            frs = [router.submit([1, 2, 3, i % 7], max_new)
-                   for i in range(n_requests)]
-            ok = router.wait_all(60.0)
-            assert ok and all(f.state == "finished" for f in frs)
-            return len(frs)
-
-        return drive, ()
-
-    def cleanup(self):
-        for router in self._open:
-            try:
-                router.close()
-            except Exception:
-                pass
-        self._open.clear()
-
-
-@register_tunable("fleet.roles")
-class FleetRolesTunable(Tunable):
-    """Prefill/decode role mix for a disaggregated fleet. Measured as
-    end-to-end drain time of a bursty mixed stream (long shared-prefix
-    prompts + short follow-ups) through an in-process sim fleet whose
-    cost model charges per-token prefill time, multiplied when prefill
-    interleaves with in-flight decode (the mixed-batch interference that
-    motivates disaggregation). More prefill replicas absorb prompt
-    bursts; more decode replicas carry the token streams — the right
-    split depends on the host, so it is measured. Bucketed by host CPU
-    count, like ``fleet.router``."""
-
-    kernel = "fleet.roles"
-
-    def __init__(self):
-        self._open: list = []
-
-    def default_shapes(self):
-        import os as _os
-
-        return [dict(cpus=_os.cpu_count() or 1, slots=4, step_ms=0.5,
-                     prefill_ms_per_token=0.2, interference=3.0,
-                     page_size=16, n_requests=24, prompt_len=48,
-                     max_new=8)]
-
-    def bucket(self, shape):
-        return _table.bucket_slots(shape["cpus"])
-
-    def candidates(self, shape):
-        return [{"prefill": p, "decode": d}
-                for p, d in ((1, 1), (1, 2), (1, 3), (2, 2))]
-
-    def default_config(self, shape):
-        return {"prefill": 1, "decode": 1}
-
-    def build(self, shape, config):
-        from ..fleet import FleetConfig, Router, SimConfig, SimEngine
-
-        ps = int(shape["page_size"])
-        router = Router(FleetConfig(
-            roles={"prefill": int(config["prefill"]),
-                   "decode": int(config["decode"])},
-            mode="inprocess", affinity="round_robin", page_size=ps,
-            engine_factory=lambda i: SimEngine(SimConfig(
-                slots=shape["slots"], step_ms=shape["step_ms"],
-                page_size=ps,
-                prefill_ms_per_token=shape["prefill_ms_per_token"],
-                interference=shape["interference"]))))
-        self._open.append(router)
-        n_requests = int(shape["n_requests"])
-        prompt_len = int(shape["prompt_len"])
-        max_new = int(shape["max_new"])
-
-        def drive():
-            frs = []
-            for i in range(n_requests):
-                # a burst of distinct long prompts (prefill-heavy) mixed
-                # with short follow-ups (decode-heavy)
-                if i % 3:
-                    prompt = [i * 131 + t for t in range(prompt_len)]
-                else:
-                    prompt = [7, 11, i % 5]
-                frs.append(router.submit(prompt, max_new))
-            ok = router.wait_all(120.0)
-            assert ok and all(f.terminal for f in frs)
-            return len(frs)
-
-        return drive, ()
-
-    def cleanup(self):
-        for router in self._open:
-            try:
-                router.close()
-            except Exception:
-                pass
-        self._open.clear()

@@ -7,7 +7,7 @@ CPU, the rerouted ``_tuned_block_sizes``/``_block_size``/softmax-xent tile
 lookups, interpret-mode parity of every candidate the sweeps emit for
 flash and sparse-adam (reusing the existing parity harness style), the
 end-to-end-measured pass-gate tunable, and the serving ``decode_fuse``
-knob + serve_bench provenance reporting.
+table read.
 """
 
 import json
@@ -391,20 +391,3 @@ def test_decode_fuse_auto_consults_table(tuned_table):
     cfg = serving.ServingConfig(slots=4, page_size=8, max_seq=64,
                                 decode_fuse=3)
     assert cfg.decode_fuse == 3 and cfg.decode_fuse_source == "explicit"
-
-
-def test_serve_bench_reports_decode_fuse_source(tuned_table):
-    from tools.serve_bench import resolve_decode_fuse
-
-    assert resolve_decode_fuse(2, 8) == (2, "explicit")
-    assert resolve_decode_fuse(None, 8) == (1, "default")
-    tune.record("serving.decode_fuse", tt.bucket_slots(8), {"decode_fuse": 4})
-    assert resolve_decode_fuse(None, 8) == (4, "tuned")
-
-
-def test_decode_fuse_tunable_space():
-    tun = tune.get_tunable("serving.decode_fuse")
-    shape = tun.default_shapes()[0]
-    assert tun.default_config(shape) == {"decode_fuse": 1}
-    assert {c["decode_fuse"] for c in tun.candidates(shape)} == {1, 2, 4}
-    assert tun.bucket(shape) == "slots4"

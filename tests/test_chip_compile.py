@@ -113,7 +113,7 @@ PAGED_SHAPES = {
     # slots, heads, d_head, page_size, pages/slot, dtype
     "gpt2_small_f32": (8, 12, 64, 16, 64, jnp.float32),
     "gpt2_small_bf16": (8, 12, 64, 16, 64, jnp.bfloat16),
-    "serve_bench": (8, 4, 32, 16, 16, jnp.float32),
+    "heads4_d32": (8, 4, 32, 16, 16, jnp.float32),
     "speculative_window": (8 * 5, 12, 64, 16, 64, jnp.float32),
     "tiny_test_model": (4, 2, 16, 8, 8, jnp.float32),
 }

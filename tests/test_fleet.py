@@ -5,7 +5,7 @@ accounting under kill/restart, cross-process telemetry aggregation
 serialization round-trips on every cache layout, cross-replica prefix
 shipping, and scale-down migration (ISSUE 18). Router tests run on
 in-process sim engines — the process-worker path is covered by
-tools/fleet_bench and tools/chaos_drill (smoke gates)."""
+tools/chaos_drill (a smoke gate)."""
 
 import io
 import os

@@ -1,6 +1,5 @@
-"""Performance observatory tests: run ledger (monitor.runlog), noise-aware
-regression verdicts (monitor.regress), step-time attribution
-(monitor.stepstats), and the P99 satellite columns. All series are seeded
+"""Performance observatory tests: run ledger (monitor.runlog), step-time
+attribution (monitor.stepstats), and the P99 satellite columns. All series are seeded
 and synthetic — no wall-clock timing in any assertion."""
 
 import json
@@ -9,7 +8,7 @@ import os
 import pytest
 
 from paddle_tpu.monitor import metrics as mx
-from paddle_tpu.monitor import regress, runlog, stepstats
+from paddle_tpu.monitor import runlog, stepstats
 
 
 @pytest.fixture(autouse=True)
@@ -28,7 +27,7 @@ def ledger_env(tmp_path, monkeypatch):
     runlog._ledger = None
 
 
-def _rec(config, metrics, seq, kind="perf_gate"):
+def _rec(config, metrics, seq, kind="bench"):
     return {"schema": runlog.RUN_SCHEMA, "run_id": "rtest-%d" % seq,
             "t": float(seq), "kind": kind, "configs": {config: metrics}}
 
@@ -99,92 +98,6 @@ def test_record_run_without_ledger_still_returns_record(monkeypatch):
     assert rec["ledger_path"] is None and rec["run_id"] == runlog.run_id()
     info = runlog.tail_info()
     assert info == {"run_id": runlog.run_id()}
-
-
-# -- regression detection -----------------------------------------------------
-
-BASE = [10.0, 10.2, 9.8, 10.1, 9.9, 10.0, 10.3, 9.7]
-
-
-def test_injected_step_time_regression_is_regressed():
-    history = [_rec("tfm", {"step_ms_p50": v}, i) for i, v in enumerate(BASE)]
-    head = _rec("tfm", {"step_ms_p50": 13.0}, 99)  # 1.3x slower
-    verdicts = regress.compare_run(head, history)
-    assert len(verdicts) == 1
-    v = verdicts[0]
-    assert v.verdict == regress.REGRESSED
-    assert v.config == "tfm" and v.metric == "step_ms_p50"
-    assert v.n_baseline == len(BASE)
-    assert v.delta_frac == pytest.approx(0.3, abs=0.02)
-
-
-def test_throughput_direction_down_is_regressed_up_is_improved():
-    history = [_rec("tfm", {"examples_per_sec": 100 * v}, i)
-               for i, v in enumerate(BASE)]
-    down = regress.compare_run(
-        _rec("tfm", {"examples_per_sec": 770.0}, 99), history)
-    assert down[0].verdict == regress.REGRESSED
-    up = regress.compare_run(
-        _rec("tfm", {"examples_per_sec": 1300.0}, 99), history)
-    assert up[0].verdict == regress.IMPROVED
-
-
-def test_noisy_but_flat_series_is_not_regressed():
-    noisy = [9.6, 10.4, 9.8, 10.2, 10.0, 9.7, 10.3, 10.1]
-    history = [_rec("tfm", {"step_ms_p50": v}, i)
-               for i, v in enumerate(noisy)]
-    verdicts = regress.compare_run(
-        _rec("tfm", {"step_ms_p50": 10.05}, 99), history)
-    assert verdicts[0].verdict == regress.NEUTRAL
-    # a wobble inside the MAD-widened band stays NEUTRAL too
-    verdicts = regress.compare_run(
-        _rec("tfm", {"step_ms_p50": 10.9}, 99), history)
-    assert verdicts[0].verdict == regress.NEUTRAL
-
-
-def test_three_sample_ledger_is_insufficient_data():
-    history = [_rec("tfm", {"step_ms_p50": v}, i)
-               for i, v in enumerate([10.0, 10.1, 9.9])]
-    verdicts = regress.compare_run(
-        _rec("tfm", {"step_ms_p50": 13.0}, 99), history)
-    assert verdicts[0].verdict == regress.INSUFFICIENT_DATA
-    # and an empty baseline likewise
-    verdicts = regress.compare_run(_rec("tfm", {"step_ms_p50": 13.0}, 99), [])
-    assert verdicts[0].verdict == regress.INSUFFICIENT_DATA
-
-
-def test_unknown_direction_metrics_are_skipped():
-    history = [_rec("tfm", {"mystery_number": v}, i)
-               for i, v in enumerate(BASE)]
-    verdicts = regress.compare_run(
-        _rec("tfm", {"mystery_number": 130.0}, 99), history)
-    assert verdicts == []
-    assert regress.metric_direction("examples_per_sec") == 1
-    assert regress.metric_direction("latency_p99_ms") == -1
-    assert regress.metric_direction("mystery_number") == 0
-
-
-def test_check_verdicts_ticks_counter_and_fires_hook():
-    history = [_rec("tfm", {"step_ms_p50": v}, i) for i, v in enumerate(BASE)]
-    verdicts = regress.compare_run(
-        _rec("tfm", {"step_ms_p50": 13.0}, 99), history)
-    before = mx.snapshot()["perf/regressions"]["value"]
-    hits = []
-    regressed = regress.check_verdicts(verdicts, on_regression=hits.append)
-    assert [v.metric for v in regressed] == ["step_ms_p50"]
-    assert hits == regressed
-    assert mx.snapshot()["perf/regressions"]["value"] == before + 1
-    doc = regressed[0].to_doc()
-    assert doc["verdict"] == regress.REGRESSED and doc["config"] == "tfm"
-
-
-def test_baseline_window_trails():
-    # old slow epoch must age out of the trailing window
-    history = [_rec("tfm", {"step_ms_p50": 20.0}, i) for i in range(10)]
-    history += [_rec("tfm", {"step_ms_p50": v}, 10 + i)
-                for i, v in enumerate(BASE)]
-    series = regress.baseline_series(history, "tfm", "step_ms_p50", window=8)
-    assert series == BASE
 
 
 # -- step-time attribution ----------------------------------------------------

@@ -88,18 +88,6 @@ def test_scheduler_bounded_queue_and_slot_errors():
         sched.retire(0)  # empty slot
 
 
-def test_scheduler_static_mode_admits_only_full_drain():
-    sched = serving.Scheduler(n_slots=2, continuous=False)
-    for _ in range(3):
-        sched.submit(Request([1], 1))
-    assert sched.admissible_slots() == [0, 1]
-    sched.admit(0)
-    # one slot busy -> static policy refuses the other
-    assert sched.admissible_slots() == []
-    sched.retire(0)
-    assert sched.admissible_slots() == [0, 1]
-
-
 # -- page pool ---------------------------------------------------------------
 
 def test_page_pool_accounting_and_atomic_exhaustion():
@@ -185,13 +173,6 @@ def test_decode_fuse_token_parity(rng):
     _, r4 = drive_stream(stream, decode_fuse=4)
     for a, b in zip(r1, r4):
         assert a.tokens_out == b.tokens_out
-
-
-def test_static_wave_mode_drains(rng):
-    stream = make_stream(6, rng)
-    _, reqs = drive_stream(stream, paged=False, continuous=False)
-    assert all(r.state == "finished" for r in reqs)
-    assert all(len(r.tokens_out) == r.max_new_tokens for r in reqs)
 
 
 # -- backpressure + observability --------------------------------------------

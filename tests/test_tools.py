@@ -1,0 +1,65 @@
+"""The tool surface the documents promise: every smoke gate
+``tools/ci_smokes.py`` lists resolves to a module with a ``--selftest``
+entry, and README.md, ROADMAP.md's smoke paragraphs and the verify skill
+name only tools that are files and only environment variables the source
+reads."""
+
+import importlib
+import inspect
+import os
+import re
+
+import pytest
+
+from tools import ci_smokes
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+
+def _read(*parts):
+    with open(os.path.join(REPO, *parts)) as f:
+        return f.read()
+
+
+@pytest.mark.parametrize("label", [label for label, _ in ci_smokes.GATES])
+def test_ci_smoke_gate_resolves(label):
+    gates = dict(ci_smokes.GATES)
+    assert sorted(ci_smokes.BUDGETS) == sorted(gates)
+    src = inspect.getsource(importlib.import_module(gates[label]))
+    assert '"--selftest"' in src and '__name__ == "__main__"' in src
+
+
+def _paragraph(text, start):
+    """The paragraph of ``text`` that opens with ``start``."""
+    return text[text.index(start):].split("\n\n", 1)[0]
+
+
+def test_documents_name_only_what_exists():
+    readme, roadmap = _read("README.md"), _read("ROADMAP.md")
+    docs = {
+        "README.md": readme,
+        "ROADMAP.md Fast smoke": _paragraph(roadmap, "**Fast smoke"),
+        "ROADMAP.md Chip smoke": _paragraph(roadmap, "**Chip smoke"),
+        "verify skill": _read(".claude", "skills", "verify", "SKILL.md"),
+    }
+    missing = sorted(
+        (doc, name) for doc, text in docs.items()
+        for name in set(re.findall(r"\btools[./]([a-z_][a-z0-9_]*)", text))
+        if not os.path.isfile(os.path.join(REPO, "tools", name + ".py")))
+    assert not missing, "documents name tools that are not files: %r" % missing
+
+    source = [_read("bench.py"), _read("chip_smoke.py")]
+    for top in ("paddle_tpu", "tools", "grid"):
+        for root, _dirs, files in os.walk(os.path.join(REPO, top)):
+            source += [_read(root, f) for f in files if f.endswith(".py")]
+    read = set(re.findall(r"PADDLE_TPU_[A-Z0-9_]+", "\n".join(source)))
+    # a name that ends in an underscore is a prefix, in the README
+    # (`PADDLE_TPU_PASS_*`) and in the source, which composes the pass
+    # gates as "PADDLE_TPU_PASS_" + name
+    prefixes = tuple(r for r in read if r.endswith("_"))
+    unread = sorted(
+        name for name in set(re.findall(r"PADDLE_TPU_[A-Z0-9_]+", readme))
+        if not (name in read or name.startswith(prefixes)
+                or name.endswith("_")
+                and any(r.startswith(name) for r in read)))
+    assert not unread, "README.md names variables nothing reads: %r" % unread

@@ -2,8 +2,8 @@
 
     python -m tools.autotune --all [--reps K] [--table FILE] [--dry-run]
         Sweep every registered tunable (flash-attention BlockSizes,
-        sparse-adam row blocks, softmax-xent tiles, per-program pass
-        gates, serving decode_fuse) over its default shape points on the
+        sparse-adam row blocks, softmax-xent tiles, paged-attention page
+        blocks, per-program pass gates) over its default shape points on the
         CURRENT backend, write the winners into the persistent config
         table (PADDLE_TPU_TUNE_TABLE, or autotune_table.json in the
         compile cache's directory), and print a before/after table.

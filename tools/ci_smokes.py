@@ -12,8 +12,6 @@ Each gate also has a wall-time BUDGET (the ROADMAP's per-gate bound): a
 passing gate that runs over budget prints a visible ``SLOW`` warning —
 never a failure, so a loaded CI host cannot flake the gate, but drift
 shows up in the log the day it starts, not the day the suite times out.
-
-The gate list mirrors ROADMAP.md's "fast smokes" — keep both in sync.
 """
 
 from __future__ import annotations
@@ -25,25 +23,21 @@ import time
 
 _REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
-# (label, module) — ROADMAP.md order
+# (label, module)
 GATES = (
     ("dump_metrics", "tools.dump_metrics"),
     ("dump_program", "tools.dump_program"),
     ("sparse_adam", "paddle_tpu.ops.pallas_kernels.sparse_adam"),
     ("paged_attention", "paddle_tpu.ops.pallas_kernels.paged_attention"),
-    ("profile_report", "tools.profile_report"),
-    ("serve_bench", "tools.serve_bench"),
-    ("fleet_bench", "tools.fleet_bench"),
     ("chaos_drill", "tools.chaos_drill"),
     ("fleet_trace", "tools.fleet_trace"),
     ("fleet_autopsy", "tools.fleet_autopsy"),
     ("autotune", "tools.autotune"),
     ("check_budgets", "tools.check_budgets"),
-    ("perf_gate", "tools.perf_gate"),
     ("numerics_report", "tools.numerics_report"),
 )
 
-# label -> wall-time budget in seconds (the ROADMAP per-gate bounds).
+# label -> wall-time budget in seconds.
 # Exceeding a budget WARNS (visibly, in the gate line) but never fails:
 # budgets catch drift, timeouts catch hangs.
 BUDGETS = {
@@ -51,9 +45,6 @@ BUDGETS = {
     "dump_program": 10.0,
     "sparse_adam": 15.0,
     "paged_attention": 15.0,
-    "profile_report": 15.0,
-    "serve_bench": 75.0,   # speculative leg + its repetitive-stream drill
-    "fleet_bench": 75.0,  # + disagg QPS, remote-hit, and kill-migration legs
     # its restarted-process twins compile cold: JAX's own thresholds keep
     # sub-second CPU executables out of the persistent cache
     "chaos_drill": 75.0,
@@ -61,7 +52,6 @@ BUDGETS = {
     "fleet_autopsy": 10.0,
     "autotune": 20.0,  # two interpret-mode kernel micro-sweeps dominate
     "check_budgets": 10.0,
-    "perf_gate": 10.0,
     "numerics_report": 15.0,
 }
 

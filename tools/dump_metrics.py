@@ -328,8 +328,7 @@ def selftest() -> int:
             os.environ["PADDLE_TPU_CHECK_NUMERICS"] = prev
     # 5. serving/* counters: the multiplexer's host-side bookkeeping
     #    (scheduler + page pool) must feed the registry; the full compiled
-    #    prefill->decode->retire path has its own gate (tools/serve_bench
-    #    --selftest)
+    #    prefill->decode->retire path is tests/test_serving.py's
     from paddle_tpu.serving import (PagePool, PagePoolExhausted, Request,
                                     Scheduler)
 

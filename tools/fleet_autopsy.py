@@ -172,7 +172,7 @@ def selftest() -> int:
     mx.enable()
     # pin the workers' export interval above the run length: one final
     # flushed sample per worker -> the close()-time SLO pass judges the
-    # whole run deterministically (same recipe as fleet_bench's SLO leg)
+    # whole run deterministically
     prev = os.environ.get("PADDLE_TPU_TELEMETRY_INTERVAL_S")
     os.environ["PADDLE_TPU_TELEMETRY_INTERVAL_S"] = "60"
     try:

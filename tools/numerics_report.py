@@ -20,8 +20,7 @@ tables the int8 KV-page path is gated behind.
             corrupt-table lookups degrade to None, never raise);
         (4) int8 KV decode parity: quantized pages decode within the
             symmetric-int8 tolerance of fp pages at ragged lengths, and
-            2x the pages fit under the fp byte budget (the capacity win
-            serve_bench asserts end-to-end).
+            2x the pages fit under the fp byte budget.
 
     python -m tools.numerics_report --probe
         Run a tiny armed MLP step in-process and print the per-op stats

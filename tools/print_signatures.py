@@ -34,7 +34,6 @@ MODULES = [
     "paddle_tpu.monitor.device",
     "paddle_tpu.monitor.metrics",
     "paddle_tpu.monitor.numerics",
-    "paddle_tpu.monitor.regress",
     "paddle_tpu.monitor.runlog",
     "paddle_tpu.monitor.slo",
     "paddle_tpu.monitor.stepstats",
