@@ -135,11 +135,13 @@ def decode_forward(params: Dict, cfg: DecoderConfig, cache, cache_ops,
 
     ``tokens``/``pos``/``active`` are [B]; the token at ``pos[b]`` has its
     K/V written into the cache (inactive slots dropped inside the scatter)
-    BEFORE attention over the context masked to ``pos+1`` valid positions —
-    dispatched through ``cache_ops.decode_attention``, so the layout owns
-    the gather-vs-fused-Pallas-kernel choice and this loop stays
-    layout-blind. Returns (logits [B,V], cache') — the cache pytree threads
-    functionally so the engine's fused scan carries it on device.
+    BEFORE attention over the context masked to ``pos+1`` valid positions
+    (the cache makes that 0 for a slot that is not ``active``: ``pos`` stays
+    where a slot's last request ended) — dispatched through
+    ``cache_ops.decode_attention``, so the layout owns the
+    gather-vs-fused-Pallas-kernel choice and this loop stays layout-blind.
+    Returns (logits [B,V], cache') — the cache pytree threads functionally
+    so the engine's fused scan carries it on device.
     """
     b = tokens.shape[0]
     pos_c = jnp.clip(pos, 0, cfg.max_seq - 1)
@@ -150,7 +152,7 @@ def decode_forward(params: Dict, cfg: DecoderConfig, cache, cache_ops,
         k = (h @ lp["wk"]).reshape(b, cfg.n_head, cfg.d_head)
         v = (h @ lp["wv"]).reshape(b, cfg.n_head, cfg.d_head)
         cache = cache_ops.write_token(cache, i, k, v, pos, active)
-        o = cache_ops.decode_attention(cache, i, q, pos + 1,
+        o = cache_ops.decode_attention(cache, i, q, pos + 1, active,
                                        sm_scale=cfg.sm_scale)
         x = x + o.reshape(b, cfg.d_model) @ lp["wo"]
         x = x + _ffn(_ln(x, lp["ln2_g"], lp["ln2_b"]), lp)
@@ -172,7 +174,8 @@ def verify_forward(params: Dict, cfg: DecoderConfig, cache, cache_ops,
     ``pos + j >= max_ctx``), because those positions' page-table entries
     are unreserved and an unguarded scatter would land on another slot's
     page. Attention dispatches through ``cache_ops.decode_verify`` (ragged
-    per-row lengths ``pos + 1 + j`` give in-window causality), so the
+    per-row lengths ``pos + 1 + j`` give in-window causality; 0 in every
+    row of a slot that is not ``active``), so the
     layout again owns the gather-vs-fused-kernel choice. Returns (logits
     [B,W,V], cache'). With W=1 and write_mask=active this is
     ``decode_forward`` on the same math.
@@ -189,7 +192,7 @@ def verify_forward(params: Dict, cfg: DecoderConfig, cache, cache_ops,
         for jj in range(w):
             cache = cache_ops.write_token(cache, i, k[:, jj], v[:, jj],
                                           posw[:, jj], write_mask[:, jj])
-        o = cache_ops.decode_verify(cache, i, q, pos + 1,
+        o = cache_ops.decode_verify(cache, i, q, pos + 1, active,
                                     sm_scale=cfg.sm_scale)
         x = x + o.reshape(b, w, cfg.d_model) @ lp["wo"]
         x = x + _ffn(_ln(x, lp["ln2_g"], lp["ln2_b"]), lp)

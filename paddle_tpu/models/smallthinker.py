@@ -214,7 +214,7 @@ def decode_forward(params: Dict, cfg: SmallThinkerConfig, cache, cache_ops,
         cache = cache_ops.write_token(cache, i, k, v, pos, active)
         with jax.named_scope("attn/window" if cfg.window_layout[i]
                              else "attn/global"):
-            o = cache_ops.decode_attention(cache, i, q, pos + 1,
+            o = cache_ops.decode_attention(cache, i, q, pos + 1, active,
                                            sm_scale=cfg.sm_scale)
         x = x + o.reshape(b, cfg.n_head * cfg.d_head) @ lp["wo"]
         idx, w = moe_ops.route_topk(h, lp["wr"], cfg.top_k)

@@ -52,12 +52,14 @@ Design:
   knob, table kernel key ``paged_attention``): each wave starts
   ``2 * block_pages`` row-range DMAs back-to-back (K and V per page), waits
   once, then folds the wave into the online-softmax state ``(m, l, acc)``;
-- the ragged bound: only the waves that hold a position below ``ctx_len``
-  run, a page entirely at/after ``ctx_len`` skips its DMA, and the position
-  mask uses attention_ops.neg_inf — the SAME masking constant as the gather
-  path — with K and V rows beyond ``ctx_len`` zeroed before use, so stale
-  rows (retired requests, unreserved pages, whatever the scratch last held)
-  contribute exactly 0.0;
+- the ragged bound: a slot of ``ctx_len`` 0 (one that holds no request:
+  serving.kv_cache hands the kernel LIVE lengths) writes zeros and ends its
+  grid step there; elsewhere only the waves that hold a position below
+  ``ctx_len`` run, a page entirely at/after ``ctx_len`` skips its DMA, and
+  the position mask uses attention_ops.neg_inf — the SAME masking constant
+  as the gather path — with K and V rows beyond ``ctx_len`` zeroed before
+  use, so stale rows (retired requests, unreserved pages, whatever the
+  scratch last held) contribute exactly 0.0;
 - page ids from the table are clamped to the pool, so a corrupt table row
   degrades to wrong-but-safe reads, never an OOB DMA.
 
@@ -183,10 +185,29 @@ def _page_dma(pool_ref, scr_ref, sem, layer, row, slot_row, ps):
 
 
 def _paged_attn_kernel(pt_ref, len_ref, layer_ref, q_ref, seg_ref, segt_ref,
-                       k_hbm, v_hbm, o_ref, k_scr, v_scr, sems, *,
-                       block_pages, page_size, pages_per_slot, num_pages,
-                       sm_scale, mask_value, d_head, grouped):
-    b = pl.program_id(0)
+                       k_hbm, v_hbm, o_ref, k_scr, v_scr, sems, **static):
+    """One grid step, one slot. A slot that holds nothing (``ctx_len`` <= 0)
+    writes a zero block and is done: no ``q`` cast, no wave, no epilogue
+    (whose G = 1 form is a matmul that ~30 rowless slots a layer would
+    pay for nobody)."""
+    b = pl.program_id(0)  # out here: the interpreter has none in a branch
+    live = len_ref[b] > 0
+
+    @pl.when(live)
+    def _():
+        _attend_slot(b, pt_ref, len_ref, layer_ref, q_ref, seg_ref, segt_ref,
+                     k_hbm, v_hbm, o_ref, k_scr, v_scr, sems, **static)
+
+    @pl.when(jnp.logical_not(live))
+    def _():
+        o_ref[...] = jnp.zeros_like(o_ref)
+
+
+def _attend_slot(b, pt_ref, len_ref, layer_ref, q_ref, seg_ref, segt_ref,
+                 k_hbm, v_hbm, o_ref, k_scr, v_scr, sems, *,
+                 block_pages, page_size, pages_per_slot, num_pages,
+                 sm_scale, mask_value, d_head, grouped):
+    """Slot ``b`` over its ``ctx_len`` >= 1 leading rows."""
     ps = page_size
     ctx = len_ref[b]
     layer = layer_ref[0]
@@ -301,9 +322,7 @@ def _paged_attn_kernel(pt_ref, len_ref, layer_ref, q_ref, seg_ref, segt_ref,
 
     n_waves = -(-pages_per_slot // block_pages)
     live_waves = jnp.minimum((ctx + rows - 1) // rows, n_waves)
-    # ctx_len >= 1 in the engine (position of the current token + 1); the
-    # clamps only guard a degenerate ctx_len <= 0 call from dividing 0/0
-    tiny = jnp.asarray(1e-30, jnp.float32)
+    # ctx >= 1 here, so every state has folded a valid row: l >= 1
     if grouped:
         init = (tuple(jnp.full((gq, 1), mask_value, jnp.float32)
                       for _ in range(n_kv)),
@@ -312,14 +331,14 @@ def _paged_attn_kernel(pt_ref, len_ref, layer_ref, q_ref, seg_ref, segt_ref,
                       for _ in range(n_kv)))
         _, ls, accs = jax.lax.fori_loop(0, live_waves, wave_body, init)
         out = jnp.concatenate(
-            [accs[h] / jnp.maximum(ls[h], tiny) for h in range(n_kv)], axis=1)
+            [accs[h] / ls[h] for h in range(n_kv)], axis=1)
         o_ref[0] = out.astype(o_ref.dtype)
         return
     m0 = jnp.full((1, hp), mask_value, jnp.float32)
     l0 = jnp.zeros((1, hp), jnp.float32)
     acc0 = jnp.zeros((1, hd), jnp.float32)
     m, l, acc = jax.lax.fori_loop(0, live_waves, wave_body, (m0, l0, acc0))
-    out = acc / jnp.maximum(row_over_lanes(l), tiny)
+    out = acc / row_over_lanes(l)
     o_ref[0] = out.astype(o_ref.dtype)
 
 
@@ -337,7 +356,9 @@ def paged_decode_attention(q, k_pages, v_pages, page_table, ctx_len, *,
     [num_pages*page_size, H*D] with ``layer`` left None. ``page_table`` [B,
     pages_per_slot] int32 — each slot's ordered page ids. ``ctx_len`` [B] —
     valid leading rows per slot (must be >= 1 for slots whose output
-    is consumed). ``block_pages=None`` = tuned-table lookup with the
+    is consumed; 0 = the slot holds nothing: its rows of the result are
+    exactly 0.0 and it costs a grid step, no DMA and no arithmetic).
+    ``block_pages=None`` = tuned-table lookup with the
     analytic VMEM-budget fallback (see ``_block_pages``). Returns [B,Hq,D],
     matching ``gather_reference`` (the XLA gather + decode_attention path)
     to float32 round-off on live rows and EXACTLY ignoring garbage beyond
@@ -526,9 +547,36 @@ def _selftest() -> int:
         got_l, clean,
         err_msg="layer 1 of a pool differs from the same layer alone")
 
+    # rowless slots (ctx_len 0: the slot holds no request) among live ones,
+    # their table rows on a stretch of Inf and NaN: exactly 0.0 out, and
+    # the live slots bit-equal to the call without them
+    dead = np.array([False, True, False, True, False])
+    pt3 = np.where(dead[:, None], pt + num_pages, pt + 2 * num_pages)
+    bad = np.where(np.arange(num_pages * ps)[:, None] % 2, np.inf, np.nan)
+    bad = np.broadcast_to(bad, k_pool.shape).astype(np.float32)
+
+    def rowless(keep):
+        return np.asarray(paged_decode_attention(
+            jnp.asarray(q[keep]),
+            jnp.asarray(np.concatenate([k_pool, bad, k_pool])),
+            jnp.asarray(np.concatenate([v_pool, -bad, v_pool])),
+            jnp.asarray(pt3[keep]),
+            jnp.asarray(np.where(dead, 0, ctx_len)[keep]), page_size=ps,
+            sm_scale=sm, block_pages=2, interpret=True))
+
+    got_r = rowless(np.ones(slots, bool))
+    np.testing.assert_array_equal(
+        got_r[dead], np.zeros_like(got_r[dead]),
+        err_msg="a slot of ctx_len 0 did not come back exactly 0.0")
+    np.testing.assert_array_equal(
+        got_r[~dead], rowless(~dead),
+        err_msg="rowless slots moved the live slots' output")
+    np.testing.assert_array_equal(got_r[~dead], clean[~dead])
+
     print("paged_attention selftest OK (%.2fs): kernel == gather on %d "
           "ragged slots (ctx %s), garbage pages and neighbouring layers "
-          "contribute exactly zero"
+          "contribute exactly zero, slots of ctx_len 0 return 0.0 and read "
+          "nothing"
           % (time.time() - t0, slots, list(map(int, ctx_len))))
     return 0
 

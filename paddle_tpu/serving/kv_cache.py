@@ -72,6 +72,16 @@ class CacheGroup(NamedTuple):
 Cache = Dict[str, jnp.ndarray]
 
 
+def _live_len(ctx_len, active, window: int = 1):
+    """The lengths a decode step's attention is given: ``ctx_len`` [B]
+    where a slot is ``active``, and elsewhere the length at which none of
+    the slot's ``window`` rows (row j attends over ``length + j``) sees a
+    row: 0 for the one row of a plain step. The engine leaves a retired
+    slot's length where its request ended, so without this every layer of
+    every step would stream that request's whole context for nobody."""
+    return jnp.where(active, ctx_len, 1 - window)
+
+
 def _dtype_by_name(name: str) -> np.dtype:
     """Resolve a dtype by its ``.name`` — including the ml_dtypes extended
     set (bfloat16 etc.) that ``np.dtype(str)`` does not know."""
@@ -315,22 +325,28 @@ class PagedKVCache(_KVCacheBase):
         return mode, None
 
     def decode_attention(self, state: Cache, layer: int, q, ctx_len,
-                         sm_scale: float = 1.0) -> jnp.ndarray:
+                         active, sm_scale: float = 1.0) -> jnp.ndarray:
         """One decode-attention step over this layer's ragged contexts:
         ``q`` [B, G*H, D] (G = 1: as many query heads as KV heads) in,
-        [B, G*H, D] out. A window group attends over ``min(ctx_len,
-        window)`` rows of its ring. Where :meth:`kernel_mode` arms it, the
-        Pallas kernel takes the group's WHOLE pool and reads this layer's
-        K/V pages straight from it via the device-resident page table,
-        once for all G query heads of a KV head — neither a layer slice nor
-        the ``[B, rows, H, D]`` gather ever materializes; otherwise the
-        XLA gather + ops.attention_ops.decode_attention path runs. Both
-        mask rows >= the length with the SAME neg_inf constant, so the
-        paths agree to float round-off (tier-1 parity tests pin it)."""
+        [B, G*H, D] out. A slot attends over its LIVE length
+        (:func:`_live_len`: 0 where ``active`` [B] is false, so a slot that
+        holds no request streams none of the rows its last one left), a
+        window group over ``min(that, window)`` rows of its ring. Where
+        :meth:`kernel_mode` arms it, the Pallas kernel takes the group's
+        WHOLE pool and reads this layer's K/V pages straight from it via
+        the device-resident page table, once for all G query heads of a KV
+        head — neither a layer slice nor the ``[B, rows, H, D]`` gather
+        ever materializes, and a slot of length 0 costs a grid step and
+        nothing else; otherwise the XLA gather +
+        ops.attention_ops.decode_attention path runs. Both mask rows >= the
+        length with the SAME neg_inf constant, so the paths agree to float
+        round-off (tier-1 parity tests pin it). A slot of length 0 comes
+        back finite from both and is nobody's to read: exactly 0.0 from
+        the kernel, the mean of its table's V rows from the gather."""
         from ..ops import attention_ops
 
         gi, li = self._where[layer]
-        length = self._group_len(gi, ctx_len)
+        length = self._group_len(gi, _live_len(ctx_len, active))
         mode, _ = self.kernel_mode()
         if mode is not None:
             from ..ops.pallas_kernels import paged_attention as _pa
@@ -345,10 +361,11 @@ class PagedKVCache(_KVCacheBase):
                                               sm_scale=sm_scale)
 
     def decode_verify(self, state: Cache, layer: int, q, ctx_len,
-                      sm_scale: float = 1.0) -> jnp.ndarray:
+                      active, sm_scale: float = 1.0) -> jnp.ndarray:
         """Speculative verify-window attention [B,W,H,D] over this layer's
         ragged contexts (window position j = logical position ctx_len-1+j;
-        the caller wrote all W positions' K/V first). Rides the SAME ragged
+        the caller wrote all W positions' K/V first; every window row of a
+        slot that is not ``active`` has length 0). Rides the SAME ragged
         Pallas kernel as ``decode_attention`` by flattening the window into
         B*W pseudo-slots — each window row replays its slot's page table
         with length ctx_len+j, which is exactly the per-slot raggedness the
@@ -360,11 +377,12 @@ class PagedKVCache(_KVCacheBase):
 
         self._single_group("speculative verify")
         b, w = q.shape[0], q.shape[1]
+        live = _live_len(ctx_len, active, w)
         mode, _ = self.kernel_mode()
         if mode is not None:
             from ..ops.pallas_kernels import paged_attention as _pa
 
-            lens = ctx_len[:, None] + jnp.arange(w)[None, :]
+            lens = live[:, None] + jnp.arange(w)[None, :]
             lens = jnp.clip(lens.reshape(b * w), 0, self.max_ctx)
             out = _pa.paged_decode_attention(
                 q.reshape(b * w, self.n_head, self.d_head),
@@ -374,7 +392,7 @@ class PagedKVCache(_KVCacheBase):
                 interpret=(mode == "interpret"))
             return out.reshape(b, w, self.n_head, self.d_head)
         ctx_k, ctx_v = self.context(state, layer)
-        return attention_ops.verify_attention(q, ctx_k, ctx_v, ctx_len,
+        return attention_ops.verify_attention(q, ctx_k, ctx_v, live,
                                               sm_scale=sm_scale)
 
     # -- prefill (one sequence) ----------------------------------------------
@@ -622,22 +640,24 @@ class ContiguousKVCache(_KVCacheBase):
         return state["k"][layer], state["v"][layer]
 
     def decode_attention(self, state: Cache, layer: int, q, ctx_len,
-                         sm_scale: float = 1.0) -> jnp.ndarray:
+                         active, sm_scale: float = 1.0) -> jnp.ndarray:
         """Dense layout has no gather to fuse away — always the XLA path
-        (the parity yardstick the paged kernel is measured against)."""
+        (the parity yardstick the paged kernel is measured against), over
+        the same live lengths as the paged layout."""
         from ..ops import attention_ops
 
         ctx_k, ctx_v = self.context(state, layer)
-        return attention_ops.decode_attention(q, ctx_k, ctx_v, ctx_len,
-                                              sm_scale=sm_scale)
+        return attention_ops.decode_attention(
+            q, ctx_k, ctx_v, _live_len(ctx_len, active), sm_scale=sm_scale)
 
     def decode_verify(self, state: Cache, layer: int, q, ctx_len,
-                      sm_scale: float = 1.0) -> jnp.ndarray:
+                      active, sm_scale: float = 1.0) -> jnp.ndarray:
         from ..ops import attention_ops
 
         ctx_k, ctx_v = self.context(state, layer)
-        return attention_ops.verify_attention(q, ctx_k, ctx_v, ctx_len,
-                                              sm_scale=sm_scale)
+        return attention_ops.verify_attention(
+            q, ctx_k, ctx_v, _live_len(ctx_len, active, q.shape[1]),
+            sm_scale=sm_scale)
 
     def prompt_dest(self, slot: int) -> np.int32:
         return np.int32(slot)

@@ -57,3 +57,73 @@ def _fresh_programs():
 @pytest.fixture
 def rng():
     return np.random.RandomState(42)
+
+
+@pytest.fixture
+def attention_spy(monkeypatch):
+    """What the attention of every decode step is given, for engines built
+    inside the test: wraps the caches' ``decode_attention`` /
+    ``decode_verify`` (which see each slot's length as the engine keeps it
+    and the ``active`` mask) and the three functions that consume a length
+    (the gather path's ``decode_attention`` and ``verify_attention``, the
+    kernel's entry). Call the fixture's value after driving an engine: a
+    list of ``(active [B], kept [B], rows [B, W])`` a call, ``kept`` the
+    engine's lengths and ``rows`` the rows each slot's W window positions
+    attend over, CHECKED: 0 rows for every slot that is not active, at
+    least its own token for every one that is, and some inactive slot did
+    keep the length of a request that left (or the case shows nothing)."""
+    import jax
+    import jax.numpy as jnp
+
+    from paddle_tpu.ops import attention_ops
+    from paddle_tpu.ops.pallas_kernels import paged_attention as pa
+    from paddle_tpu.serving import kv_cache
+
+    seen = []
+
+    def note(tag, *arrays):
+        jax.debug.callback(
+            lambda *a: seen.append((tag,) + tuple(np.asarray(x) for x in a)),
+            *arrays, ordered=True)
+
+    def spy_method(cls, name):
+        real = getattr(cls, name)
+
+        def method(self, state, layer, q, ctx_len, active, **kw):
+            note("given", active, ctx_len)
+            return real(self, state, layer, q, ctx_len, active, **kw)
+
+        monkeypatch.setattr(cls, name, method)
+
+    def spy_consumer(mod, name, rows_of):
+        real = getattr(mod, name)
+
+        def fn(*args, **kw):
+            note("rows", rows_of(*args))
+            return real(*args, **kw)
+
+        monkeypatch.setattr(mod, name, fn)
+
+    for cls in (kv_cache.PagedKVCache, kv_cache.ContiguousKVCache):
+        for name in ("decode_attention", "decode_verify"):
+            spy_method(cls, name)
+    spy_consumer(attention_ops, "decode_attention",
+                 lambda q, k, v, n: n)
+    spy_consumer(attention_ops, "verify_attention",
+                 lambda q, k, v, n: jnp.maximum(
+                     n[:, None] + jnp.arange(q.shape[1])[None, :], 0))
+    spy_consumer(pa, "paged_decode_attention",
+                 lambda q, k, v, pt, n: n)
+
+    def calls():
+        jax.effects_barrier()
+        assert [s[0] for s in seen] == ["given", "rows"] * (len(seen) // 2)
+        out = [(a, kept, rows.reshape(a.shape[0], -1))
+               for (_, a, kept), (_, rows) in zip(seen[::2], seen[1::2])]
+        for active, kept, rows in out:
+            assert (rows[~active] == 0).all(), (active, kept, rows)
+            assert (rows[active] >= 1).all(), (active, kept, rows)
+        assert any((kept[~active] > 0).any() for active, kept, _ in out)
+        return out
+
+    return calls

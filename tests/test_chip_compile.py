@@ -502,6 +502,50 @@ def test_grouped_query_paged_kernel_at_the_served_geometry(chip):
                 if _has_dim(rtype, pages * 16) and op != "parameter"] == []
 
 
+@pytest.mark.parametrize("cell,layer,q_block", [
+    ("gpt2-small-serve", 5, "[32,1,768]"),
+    ("smallthinker global layer", 0, "[16,8,512]"),
+    ("smallthinker window layer", 1, "[16,8,512]")])
+def test_cache_attention_over_live_lengths_at_the_cells_shapes(
+        chip, monkeypatch, cell, layer, q_block):
+    """``decode_attention`` as the models call it, ``active`` beside the
+    lengths, at the serve cells' geometries: the kernel whose rowless grid
+    step returns at once compiles for the chip with the q block a device
+    trace names it by, the lengths it is handed come out of a ``select`` on
+    the live mask (then the window's ``minimum``), and the pool is still
+    handed over whole."""
+    monkeypatch.setattr(attention_ops, "paged_kernel_mode",
+                        lambda: "compiled")
+    if cell == "gpt2-small-serve":
+        (_, args), rows = _serve_case("chunk", chip)
+        from paddle_tpu.serving.kv_cache import PagedKVCache
+        g = SERVE
+        ops = PagedKVCache(g["n_layer"], g["n_head"], 64, g["slots"],
+                           g["max_seq"], g["page_size"], g["num_pages"],
+                           dtype="bfloat16")
+        heads = g["n_head"]
+    else:
+        (_, args), ops = _moe_case("chunk", chip)
+        rows = ops.groups[ops._where[layer][0]].num_pages * ops.page_size
+        heads = 28
+    cache, lengths, active = args[1], args[2], args[4]
+    q = jax.ShapeDtypeStruct((ops.slots, heads, ops.d_head), jnp.bfloat16,
+                             sharding=chip)
+    text = jax.jit(
+        lambda cache, q, n, live: ops.decode_attention(
+            cache, layer, q, n, live, sm_scale=0.125)
+    ).lower(cache, q, lengths, active).compile().as_text()
+    kernel, = [ln for ln in text.split("\n") if "tpu_custom_call" in ln]
+    assert kernel.strip().startswith("%paged_attention")
+    assert "bf16" + q_block in kernel
+    ops_on_lengths = {op for _, rtype, op, _ in _instructions(text)
+                      if rtype.startswith("s32[%d]" % ops.slots)}
+    assert "select" in ops_on_lengths
+    assert ("minimum" in ops_on_lengths) == cell.endswith("window layer")
+    assert [op for _, rtype, op, _ in _instructions(text)
+            if _has_dim(rtype, rows) and op != "parameter"] == []
+
+
 @pytest.mark.parametrize("exe", ["chunk", "prefill"])
 def test_sparse_decoder_executables(chip, monkeypatch, exe):
     """The decode chunk runs the grouped-query kernel once a layer and the

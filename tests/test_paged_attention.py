@@ -119,6 +119,40 @@ def test_layer_of_a_pool_is_bit_equal_to_the_layer_alone(rng, layer):
     np.testing.assert_array_equal(np.asarray(traced), np.asarray(alone))
 
 
+@pytest.mark.parametrize("g", [1, 7], ids=["one_query_head_a_kv_head",
+                                           "seven_padded_to_eight"])
+def test_rowless_slots_cost_no_read_and_return_zero(rng, g):
+    """``ctx_len`` 0 = the slot holds nothing (the cache hands the kernel
+    LIVE lengths). Such slots sit among live ones with their page-table
+    rows on pages of Inf and NaN: they come back exactly 0.0, and the live
+    slots are bit-equal to the same call without them."""
+    slots, h, d, ps, pps = 6, 2, 16, 8, 4
+    live_pages = 16
+    _, k, v, pt = make_pool(rng, slots, pps, live_pages, ps, h, d)
+    q = jnp.asarray(rng.randn(slots, g * h, d).astype(np.float32))
+    ctx = np.asarray([5, 0, 32, 0, 17, 0], np.int32)
+    dead = ctx == 0
+    # four more pages, poisoned, and only the rowless slots point at them
+    poison = np.full((4 * ps, h * d), np.nan, np.float32)
+    poison[::2] = np.inf
+    k = jnp.concatenate([k, jnp.asarray(poison)])
+    v = jnp.concatenate([v, jnp.asarray(-poison)])
+    pt = np.asarray(pt).copy()
+    pt[dead] = live_pages + np.arange(4)
+    kw = dict(page_size=ps, sm_scale=0.25, block_pages=2, interpret=True)
+    got = np.asarray(pa.paged_decode_attention(
+        q, k, v, jnp.asarray(pt), jnp.asarray(ctx), **kw))
+    np.testing.assert_array_equal(got[dead], np.zeros_like(got[dead]))
+    alone = np.asarray(pa.paged_decode_attention(
+        q[~dead], k, v, jnp.asarray(pt[~dead]), jnp.asarray(ctx[~dead]),
+        **kw))
+    np.testing.assert_array_equal(got[~dead], alone)
+    want = pa.gather_reference(q[~dead], k[:live_pages * ps],
+                               v[:live_pages * ps], jnp.asarray(pt[~dead]),
+                               jnp.asarray(ctx[~dead]), ps, sm_scale=0.25)
+    np.testing.assert_allclose(alone, want, rtol=1e-6, atol=1e-6)
+
+
 def test_pool_shape_errors_are_typed(rng):
     """A pool without a layer, a layer outside the pool, and a ``[rows, H,
     D]`` layer (the layout this kernel no longer takes) all raise before

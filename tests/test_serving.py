@@ -132,6 +132,50 @@ def test_paged_vs_contiguous_bit_parity(rng):
             assert np.array_equal(x, y), "paged logits diverged bitwise"
 
 
+@pytest.mark.parametrize("kernel,speculation", [
+    ("off", 0), ("interpret", 0), ("off", 3), ("interpret", 3)])
+def test_a_retired_slot_streams_nothing_and_moves_no_live_token(
+        rng, attention_spy, kernel, speculation):
+    """Requests with long contexts finish in slots 0 and 2 while two with
+    short prompts decode on in slots 1 and 3. From then on the attention is
+    given length 0 for the two retired slots (the engine's ``_len`` stays
+    where their requests ended), by the gather path and by the interpreted
+    kernel, in plain decode steps and in the speculative verify window; and
+    the survivors' tokens and logits are those of a run in which no other
+    slot was ever used."""
+    from paddle_tpu.flags import set_flag
+
+    motif = list(rng.randint(0, 64, 3))
+    leavers = [(list(rng.randint(0, 64, 16)), 2),
+               (list(rng.randint(0, 64, 14)), 3)]
+    stayers = [(motif * 2, 20), (motif * 3, 24)]
+
+    def drive(stream):
+        eng = serving.ServingEngine(get_model(),
+                                    small_config(collect_logits=True))
+        reqs = [eng.submit(p, m, speculation=speculation) for p, m in stream]
+        eng.run()
+        out = [(r.tokens_out, eng.captured_logits(r)) for r in reqs]
+        assert eng.page_accounting_ok()
+        eng.close()
+        return out
+
+    set_flag("paged_attention_kernel", kernel)
+    try:
+        mixed = drive([leavers[0], stayers[0], leavers[1], stayers[1]])
+        calls = attention_spy()
+        alone = drive(stayers)
+    finally:
+        set_flag("paged_attention_kernel", "auto")
+    # W > 1 only in the verify window: the case did go through it
+    assert (max(rows.shape[1] for _, _, rows in calls) > 1) == (
+        speculation > 0)
+    for (toks, logits), (toks_alone, logits_alone) in zip(mixed[1::2], alone):
+        assert toks == toks_alone
+        for x, y in zip(logits, logits_alone):
+            np.testing.assert_allclose(x, y, rtol=1e-5, atol=1e-5)
+
+
 def test_ragged_vs_padded_full_recompute_logit_parity(rng):
     """Bucket-padded prefill + incremental paged decode at mixed lengths
     must match the O(S^2) full-recompute reference on the unpadded

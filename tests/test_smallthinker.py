@@ -191,6 +191,40 @@ def test_decode_through_the_groups_equals_the_reference(toy, kernel, rng):
         set_flag("paged_attention_kernel", "auto")
 
 
+@pytest.mark.parametrize("kernel", ["off", "interpret"])
+def test_a_retired_slot_reads_no_row_of_either_group(toy, kernel, rng,
+                                                      attention_spy):
+    """A request whose context (19 + 3) is past the 8-row window retires
+    from slot 0 while a short one decodes on in slot 1: from then on slot
+    0's length is 0 in the global layer AND in the window layers (the clamp
+    to the ring comes after, so 0 stays 0), and slot 1's logits are those
+    of a run in which slot 0 was never used."""
+    leaver = (list(rng.randint(0, 96, 19)), 3)
+    stayer = (list(rng.randint(0, 96, 4)), 14)
+
+    def drive(stream):
+        with _engine(toy) as eng:
+            reqs = [eng.submit(p, m) for p, m in stream]
+            eng.run()
+            assert eng.page_accounting_ok()
+            return [np.stack(eng.captured_logits(r)) for r in reqs]
+
+    set_flag("paged_attention_kernel", kernel)
+    try:
+        mixed = drive([leaver, stayer])
+        calls = attention_spy()
+        alone = drive([stayer])
+    finally:
+        set_flag("paged_attention_kernel", "auto")
+    assert all((rows <= kept[:, None]).all() for _, kept, rows in calls)
+    retired = [rows[1, 0] for active, kept, rows in calls
+               if active[1] and not active[0] and kept[0] > 8]
+    # slot 1 alive beside the retired slot 0, in every one of the 4 layers;
+    # its own rows: the whole context (global) or the ring's 8 (window)
+    assert len(retired) >= 4 * 8 and max(retired) > 8 and 8 in retired
+    np.testing.assert_allclose(mixed[1], alone[0], atol=TOL, rtol=0)
+
+
 def test_decode_counts_the_experts_it_touched(toy):
     from paddle_tpu.serving import metrics as sm
 
@@ -372,7 +406,7 @@ def test_page_export_is_refused_over_two_groups(toy):
         with pytest.raises(ValueError, match="page export"):
             eng.cache_ops.export_pages(eng._cache, [0])
         with pytest.raises(ValueError, match="speculative verify"):
-            eng.cache_ops.decode_verify(eng._cache, 0, None, None)
+            eng.cache_ops.decode_verify(eng._cache, 0, None, None, None)
 
 
 def test_one_group_is_the_same_cache(rng):

@@ -307,8 +307,9 @@ def _selftest_int8_kv():
     # fused decode_attention here)
     q = jnp.array(rng.randn(slots, n_head, d_head), jnp.float32)
     ctx_len = jnp.array(lens, jnp.int32)
-    of = fp.decode_attention(sf, 0, q, ctx_len, sm_scale=0.3)
-    oi = q8.decode_attention(si, 0, q, ctx_len, sm_scale=0.3)
+    live = jnp.ones((slots,), jnp.bool_)
+    of = fp.decode_attention(sf, 0, q, ctx_len, live, sm_scale=0.3)
+    oi = q8.decode_attention(si, 0, q, ctx_len, live, sm_scale=0.3)
     err = float(jnp.abs(of - oi).max())
     assert err < 0.05, "int8 decode attention error %.4g" % err
     # the capacity win: int8 at 2x the pages still fits under the fp
