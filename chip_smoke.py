@@ -1,6 +1,7 @@
 """The standing proof that the trainer and the server start on the TPU.
 
-    python chip_smoke.py            one chip: train, ctr, kernels, serve, serve_moe
+    python chip_smoke.py            one chip: train, ctr, kernels, serve,
+                                    serve_moe, serve_mla
     python chip_smoke.py --chips 4  four chips: the data-parallel phase only
 
 One process, no children, no JAX_PLATFORMS set here: a chip belongs to the
@@ -55,6 +56,15 @@ SIZES = {
                       d_expert=128, window=256, max_seq=1024, page_size=16,
                       slots=4, prompts=(40, 200), new_tokens=120,
                       buckets=(64, 256)),
+    # Kimi-K2's block (models/kimi_k2.py) at its published widths, two
+    # layers (the dense one and one expert layer), the share of one chip of
+    # 32: 12 of 384 experts, 20,480 rows of the vocabulary
+    "serve_mla": dict(vocab=20480, n_layer=2, d_model=7168, n_head=64,
+                      q_rank=1536, kv_rank=512, d_nope=128, d_rope=64,
+                      d_v=128, d_dense=18432, n_expert=384, top_k=8,
+                      d_expert=2048, held=12, yarn_positions=4096,
+                      dtype="bfloat16", max_seq=1024, page_size=16, slots=4,
+                      prompt=200, new_tokens=17, buckets=(256,)),
     "dp": dict(steps=4),
 }
 
@@ -494,12 +504,65 @@ def _kernel_paged(seed, interpret):
             "max_abs_err": err}
 
 
+def _kernel_mla(seed, interpret):
+    """The latent decode kernel at the served row (64 heads over 640 lanes,
+    512 of them the latent), four double-buffered waves a full slot: in
+    float32 against the gather path at full precision, and in bfloat16
+    (the MXU's own type, as served) against the same rows in float32."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from paddle_tpu.ops.pallas_kernels import mla_attention as mla
+
+    cfg = SIZES["serve_mla"]
+    h, rank = cfg["n_head"], cfg["kv_rank"]
+    values = rank + cfg["d_rope"]
+    width = -(-values // 128) * 128
+    ps, slots, pps = cfg["page_size"], 8, 128
+    why = mla.mla_decode_gate(jnp.bfloat16, width, rank, ps, interpret)
+    if why is not None:
+        return {"gate": why}
+    rng = np.random.RandomState(seed)
+    pt = rng.permutation(slots * pps).reshape(slots, pps).astype(np.int32)
+    ctx = np.resize(np.array([1, ps, ps + 1, 515, 0, pps * ps // 2 + 3],
+                             np.int32), slots)
+    ctx[-1] = pps * ps
+    pool = np.zeros((slots * pps * ps, width), np.float32)
+    pool[:, :values] = rng.randn(pool.shape[0], values)
+    q = np.zeros((slots, h, width), np.float32)
+    q[..., :values] = rng.randn(slots, h, values)
+    sm = 1.0 / float(values) ** 0.5
+    errs = {}
+    for dtype, bound in (("float32", 1e-4), ("bfloat16", 3e-2)):
+        qd, pd = jnp.asarray(q, dtype), jnp.asarray(pool, dtype)
+        got = jax.jit(lambda *a: mla.mla_paged_decode(
+            *a, page_size=ps, rank=rank, sm_scale=sm, interpret=interpret))(
+                qd, pd, jnp.asarray(pt), jnp.asarray(ctx))
+        with jax.default_matmul_precision("highest"):
+            want = mla.mla_gather_reference(
+                qd.astype(jnp.float32), pd.astype(jnp.float32),
+                jnp.asarray(pt), jnp.asarray(ctx), ps, rank, sm_scale=sm)
+        live = ctx > 0
+        err, _ = _max_err(np.asarray(got, np.float32)[live],
+                          np.asarray(want)[live])
+        check(err <= bound, "latent kernel (%s) differs from gather by %g"
+              % (dtype, err))
+        check(not np.asarray(got, np.float32)[~live].any(),
+              "a slot of length 0 did not come back exactly 0.0")
+        errs[dtype] = err
+    return {"shape": {"slots": slots, "n_head": h, "row": width,
+                      "rank": rank, "page_size": ps, "pages_per_slot": pps},
+            "ctx_len": [int(c) for c in ctx], "max_abs_err": errs}
+
+
 def phase_kernels(seed, meter):
     interpret = not on_tpu()
     out = {"flash_attention": _kernel_flash(seed, interpret),
            "softmax_xent": _kernel_xent(seed, interpret),
            "sparse_rows": _kernel_sparse(seed, interpret),
-           "paged_attention": _kernel_paged(seed, interpret)}
+           "paged_attention": _kernel_paged(seed, interpret),
+           "mla_latent_decode": _kernel_mla(seed, interpret)}
     return {"checked": "each Pallas kernel against its plain reference",
             "kernel_path": "interpreted" if interpret else "compiled",
             "kernels": out, "tune": tune_layers()}
@@ -661,6 +724,83 @@ def phase_serve_moe(seed, meter):
             "tune": tune_layers()}
 
 
+def phase_serve_mla(seed, meter):
+    """The latent-attention decoder through the same engine at its
+    published widths: one expanded prefill, sixteen absorbed decode steps
+    through the latent cache, the served tokens within the configuration's
+    margin of its float32 reference given the same share."""
+    import numpy as np
+
+    from paddle_tpu.models import kimi_k2_reference as reference
+    from paddle_tpu.models.kimi_k2 import KimiK2Config, KimiK2LM
+    from paddle_tpu.serving import ServingConfig, ServingEngine
+
+    cfg = SIZES["serve_mla"]
+    yarn = {"beta_fast": 1, "beta_slow": 1, "factor": 32, "mscale": 1,
+            "mscale_all_dim": 1, "type": "yarn",
+            "original_max_position_embeddings": cfg["yarn_positions"]}
+    held = list(range(cfg["held"]))
+    mcfg = KimiK2Config(
+        vocab_size=cfg["vocab"], n_layer=cfg["n_layer"],
+        d_model=cfg["d_model"], n_head=cfg["n_head"], q_rank=cfg["q_rank"],
+        kv_rank=cfg["kv_rank"], d_nope=cfg["d_nope"], d_rope=cfg["d_rope"],
+        d_v=cfg["d_v"], d_dense=cfg["d_dense"], n_dense=1,
+        n_expert=cfg["n_expert"], top_k=cfg["top_k"],
+        d_expert=cfg["d_expert"], routed_scale=2.827, rope_scaling=yarn,
+        max_seq=cfg["max_seq"], dtype=cfg["dtype"], experts_held=held)
+    published = {
+        "num_attention_heads": cfg["n_head"],
+        "qk_nope_head_dim": cfg["d_nope"], "qk_rope_head_dim": cfg["d_rope"],
+        "num_experts_per_tok": cfg["top_k"], "routed_scaling_factor": 2.827,
+        "rope_theta": mcfg.rope_theta, "rope_scaling": yarn,
+        "rms_norm_eps": mcfg.rms_eps, "experts_held": held}
+    prompt = np.random.RandomState(seed).randint(
+        0, cfg["vocab"], (cfg["prompt"],)).tolist()
+    model = KimiK2LM(mcfg, seed=seed)
+    engine = ServingEngine(model, ServingConfig(
+        slots=cfg["slots"], page_size=cfg["page_size"],
+        max_seq=cfg["max_seq"], prompt_buckets=cfg["buckets"],
+        collect_logits=True))
+    with engine:
+        kernel_info = engine.decode_kernel_info()
+        engine.warmup()
+        req = engine.submit(prompt, cfg["new_tokens"])
+        engine.run()
+        logits = np.stack(engine.captured_logits(req)).astype(np.float32)
+        accounting = engine.page_accounting_ok()
+        layout = sorted(engine._cache)
+    check(req.state == "finished" and len(req.tokens_out)
+          == cfg["new_tokens"], "the request ended %s with %d tokens"
+          % (req.state, len(req.tokens_out)))
+    check(accounting, "page accounting does not balance after the drain")
+    check(layout == ["c", "pt"], "the cache holds %s" % layout)
+    check(np.isfinite(logits).all(), "a served logit is not finite")
+    worst = reference.worst_margin(model.params, published, prompt,
+                                   list(req.tokens_out))
+    check(worst <= reference.LOGIT_MARGIN,
+          "a served token ranks %.4f below the float32 reference's argmax "
+          "(margin %.4f)" % (worst, reference.LOGIT_MARGIN))
+    if on_tpu():
+        check(kernel_info[0] == "mla_paged",
+              "default flags did not arm the latent kernel: %s"
+              % (kernel_info,))
+    return {"checked": "a prompt of %d: one expanded prefill and %d absorbed "
+                       "decode steps through a latent cache of one [c | kr] "
+                       "row a token; logits finite; the served tokens rank "
+                       "%.4f below the float32 reference's best at worst "
+                       "(models/kimi_k2_reference.py given experts 0-%d, "
+                       "margin %.2f); the pool balances"
+                       % (cfg["prompt"], cfg["new_tokens"] - 1, worst,
+                          cfg["held"] - 1, reference.LOGIT_MARGIN),
+            "matmul_precision": "default (%s weights)" % cfg["dtype"],
+            "kernel_path": {"decode_attention": kernel_info[0],
+                            "prefill_attention": "composed, expanded",
+                            "experts": "ragged_dot over the share"},
+            "decode_kernel_info": list(kernel_info),
+            "reference_margin": worst,
+            "tune": tune_layers()}
+
+
 # -- data parallel (--chips 4) ------------------------------------------------
 
 
@@ -727,7 +867,8 @@ def phase_data_parallel(seed, meter):
 
 PHASES = {1: (("train", phase_train), ("ctr", phase_ctr),
               ("kernels", phase_kernels), ("serve", phase_serve),
-              ("serve_moe", phase_serve_moe)),
+              ("serve_moe", phase_serve_moe),
+              ("serve_mla", phase_serve_mla)),
           4: (("data_parallel", phase_data_parallel),)}
 
 
@@ -769,9 +910,9 @@ def run_phase(name, fn, seed, meter):
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--chips", type=int, choices=sorted(PHASES), default=1,
-                    help="1: train, ctr, kernels, serve, serve_moe. 4: the "
-                         "data-parallel phase and what it is compared with, "
-                         "and no other")
+                    help="1: train, ctr, kernels, serve, serve_moe, "
+                         "serve_mla. 4: the data-parallel phase and what it "
+                         "is compared with, and no other")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
 

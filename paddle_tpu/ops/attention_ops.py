@@ -462,3 +462,61 @@ def windowed_causal_attention(q, k, v, window: int, sm_scale=1.0,
 
         out = jax.lax.map(block, (jnp.arange(s // bq), qb))
         return out.reshape(s, hq, d)
+
+
+def mla_causal_attention(q, k_nope, k_rope, v, sm_scale=1.0):
+    """Causal attention of ONE sequence with latent-attention heads,
+    EXPANDED: ``q`` [S, H, Dn + Dr], ``k_nope`` [S, H, Dn], ``k_rope`` [S,
+    Dr] (the one rotary key every head shares), ``v`` [S, H, Dv]. The key
+    of head n is ``[k_nope_n | k_rope]``; the queries and keys are wider
+    than the values (192 against 128 at DeepSeek-V3's sizes). Where the
+    flash kernel's gate admits the length (the chip, S >=
+    ``FLAGS_flash_attention_min_seq``) q, k and v are zero-padded to one
+    head width of whole lane tiles, which that kernel needs (zero lanes
+    add nothing to a score, and the padding of the result is cut), so no S
+    x S tensor exists at long S; below it the scores are composed here,
+    with the softmax in float32. Returns [S, H, Dv]."""
+    s, h, _ = q.shape
+    dv = v.shape[-1]
+    with jax.named_scope("attn/mla"):
+        k = jnp.concatenate(
+            [k_nope, jnp.broadcast_to(k_rope[:, None, :],
+                                      (s, h, k_rope.shape[-1]))], axis=-1)
+        qh = q.transpose(1, 0, 2)[None]
+        if _flash_ok(qh, qh, True):
+            wide = -(-max(q.shape[-1], dv) // 128) * 128
+
+            def padded(x):
+                x = jnp.pad(x, ((0, 0), (0, 0), (0, wide - x.shape[-1])))
+                return x.transpose(1, 0, 2)[None]
+
+            o = sdpa(padded(q), padded(k), padded(v), causal=True,
+                     sm_scale=sm_scale)
+            return o[0].transpose(1, 0, 2)[..., :dv]
+        sc = jnp.einsum("qhd,khd->hqk", q, k,
+                        preferred_element_type=jnp.float32) * sm_scale
+        sc = jnp.where(jnp.tril(jnp.ones((s, s), bool))[None], sc,
+                       neg_inf(jnp.float32))
+        p = jax.nn.softmax(sc, axis=-1)
+        o = jnp.einsum("hqk,khd->qhd", p.astype(v.dtype), v,
+                       preferred_element_type=jnp.float32)
+        return o.astype(q.dtype)
+
+
+def mla_decode_attention(q, ctx_rows, ctx_len, rank: int, sm_scale=1.0):
+    """Single-position latent attention, ABSORBED, over gathered rows:
+    ``q`` [B, H, W] (each head's absorbed query over the row's lanes),
+    ``ctx_rows`` [B, L, W] (every head reads the same rows), ``ctx_len``
+    [B]. Scores over all W lanes, the weighted sum over the first ``rank``
+    (the latent): [B, H, rank]. The XLA path the latent paged kernel
+    (ops/pallas_kernels/mla_attention.py) replaces, with the same masking
+    constant and a float32 softmax."""
+    sc = jnp.einsum("bhw,blw->bhl", q, ctx_rows,
+                    preferred_element_type=jnp.float32) * sm_scale
+    mask = jnp.arange(ctx_rows.shape[1])[None, None, :] \
+        < ctx_len[:, None, None]
+    sc = jnp.where(mask, sc, neg_inf(jnp.float32))
+    p = jax.nn.softmax(sc, axis=-1)
+    o = jnp.einsum("bhl,blr->bhr", p.astype(ctx_rows.dtype),
+                   ctx_rows[..., :rank], preferred_element_type=jnp.float32)
+    return o.astype(q.dtype)

@@ -13,10 +13,21 @@ The layer is told which experts it HOLDS and routes over all of them: a
 pair routed to an expert that lives on another chip contributes nothing
 here (that chip adds its part), so the one-chip share of a wider
 deployment is this function with a shorter ``held``. On one chip ``held``
-is every expert.
+is every expert. A share computes its own pairs only: the sorted pairs go
+through the grouped matmul in chunks of a bound derived from the share
+(twice the rows an even router would send it), so a 12-of-384 share of an
+8,192-token prefill gathers 4,096 rows and not the 65,536 of which 31 in
+32 belong to absent experts; a router that sends it more makes the loop
+run again, and nothing is dropped.
 
-Precision: the router's logits accumulate in float32 and its softmax is
-float32; the experts' outputs are combined in float32.
+The routing rule and the experts' activation are the caller's:
+:func:`route_topk` (softmax over the chosen logits) or
+:func:`route_sigmoid_topk` (sigmoid scores, a bias that selects and never
+weighs), and ``expert_layer(..., activation=)`` (ReLU by default: ReGLU
+experts; ``jax.nn.silu`` gives SwiGLU).
+
+Precision: the router's logits accumulate in float32 and its softmax or
+sigmoid is float32; the experts' outputs are combined in float32.
 """
 
 from __future__ import annotations
@@ -27,7 +38,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-__all__ = ["route_topk", "expert_layer"]
+__all__ = ["route_topk", "route_sigmoid_topk", "expert_layer"]
 
 
 def route_topk(h, wr, top_k: int) -> Tuple[jnp.ndarray, jnp.ndarray]:
@@ -41,13 +52,39 @@ def route_topk(h, wr, top_k: int) -> Tuple[jnp.ndarray, jnp.ndarray]:
         return idx.astype(jnp.int32), jax.nn.softmax(top, axis=-1)
 
 
-def expert_layer(u, idx, w, wg, wu, wd, n_expert: Optional[int] = None,
-                 held: Optional[Sequence[int]] = None, row_valid=None
-                 ) -> Tuple[jnp.ndarray, Dict[str, jnp.ndarray]]:
-    """``sum_k w[n, k] * (relu(u Wg_e) * (u Wu_e)) Wd_e`` with ``e = idx[n,
-    k]``, for the experts in ``held``.
+def route_sigmoid_topk(h, wr, bias, top_k: int, scale: float = 1.0
+                       ) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """The DeepSeek-V3 family's router without group limits: ``s =
+    sigmoid(h wr)`` [N, E]; the ``top_k`` largest of ``s + bias`` are
+    CHOSEN, and weighed by ``s`` alone (the bias selects, never weighs),
+    normalised over the chosen ones and multiplied by ``scale``. Returns
+    ``(idx [N, k] int32, w [N, k] float32)``."""
+    with jax.named_scope("moe/router"):
+        s = jax.nn.sigmoid(jnp.dot(h, wr, preferred_element_type=jnp.float32))
+        _, idx = jax.lax.top_k(s + bias.astype(jnp.float32), top_k)
+        chosen = jnp.take_along_axis(s, idx, axis=-1)
+        w = chosen / (jnp.sum(chosen, axis=-1, keepdims=True) + 1e-20)
+        return idx.astype(jnp.int32), w * scale
 
-    ``u`` [N, d]; ``idx``/``w`` [N, k] from :func:`route_topk`; ``wg``/
+
+def _share_rows(n_pairs: int, e_held: int, n_expert: int) -> int:
+    """Rows of one pass of a share's grouped matmul: twice what an even
+    router sends ``e_held`` of ``n_expert`` experts, in whole tiles of 256,
+    and never more than there are pairs."""
+    even = -(-n_pairs * e_held // n_expert)
+    return min(n_pairs, -(-max(2 * even, 1) // 256) * 256)
+
+
+def expert_layer(u, idx, w, wg, wu, wd, n_expert: Optional[int] = None,
+                 held: Optional[Sequence[int]] = None, row_valid=None,
+                 activation=jax.nn.relu
+                 ) -> Tuple[jnp.ndarray, Dict[str, jnp.ndarray]]:
+    """``sum_k w[n, k] * (act(u Wg_e) * (u Wu_e)) Wd_e`` with ``e = idx[n,
+    k]``, for the experts in ``held``; ``activation`` is ``act`` (ReLU:
+    ReGLU experts; ``jax.nn.silu``: SwiGLU).
+
+    ``u`` [N, d]; ``idx``/``w`` [N, k] from :func:`route_topk` or
+    :func:`route_sigmoid_topk`; ``wg``/
     ``wu`` [E_held, d, f] and ``wd`` [E_held, f, d] are the held experts'
     weights in the order of ``held`` (global expert ids; default: all
     ``n_expert`` = ``wg.shape[0]`` of them). ``row_valid`` [N] bool marks
@@ -82,16 +119,56 @@ def expert_layer(u, idx, w, wg, wu, wd, n_expert: Optional[int] = None,
         # unused rows) carry the sentinel e_held and sort past every group
         order = jnp.argsort(flat, stable=True)
         sizes = jnp.zeros((e_held + 1,), jnp.int32).at[flat].add(1)[:e_held]
+        if e_held < n_expert:
+            return _share(u, w, wg, wu, wd, order, sizes, activation,
+                          _share_rows(n * k, e_held, n_expert)), \
+                _group_stats(sizes)
         xs = u[order // k]
         gate = jax.lax.ragged_dot(xs, wg, sizes)
         up = jax.lax.ragged_dot(xs, wu, sizes)
-        out = jax.lax.ragged_dot(jax.nn.relu(gate) * up, wd, sizes)
+        out = jax.lax.ragged_dot(activation(gate) * up, wd, sizes)
         # rows past the last group are whatever the grouped matmul left
         out = jnp.where((flat[order] < e_held)[:, None], out, 0)
         back = jnp.zeros((n * k,), jnp.int32).at[order].set(
             jnp.arange(n * k, dtype=jnp.int32))
         y = jnp.sum(out[back].reshape(n, k, -1).astype(jnp.float32)
                     * w.astype(jnp.float32)[:, :, None], axis=1)
-        stats = {"experts_touched": jnp.sum(sizes > 0).astype(jnp.int32),
-                 "max_expert_rows": jnp.max(sizes).astype(jnp.int32)}
+        stats = _group_stats(sizes)
     return y, stats
+
+
+def _group_stats(sizes) -> Dict[str, jnp.ndarray]:
+    return {"experts_touched": jnp.sum(sizes > 0).astype(jnp.int32),
+            "max_expert_rows": jnp.max(sizes).astype(jnp.int32)}
+
+
+def _share(u, w, wg, wu, wd, order, sizes, activation, rows: int):
+    """A share's part of the layer: the sorted pairs of the HELD experts
+    (the first ``sum(sizes)`` of ``order``), ``rows`` of them a pass, each
+    pass one grouped matmul over its own slice of every group, its rows
+    weighed and added to their tokens in float32. An even router fills
+    half a pass; the loop runs until the last held pair is done."""
+    n, d = u.shape
+    k = w.shape[1]
+    ends = jnp.cumsum(sizes)
+    total = ends[-1]
+    wf = w.astype(jnp.float32).reshape(n * k)
+
+    def one_pass(i, y):
+        lo = i * rows
+        at = lo + jnp.arange(rows, dtype=jnp.int32)
+        pair = order[jnp.minimum(at, n * k - 1)]
+        live = at < total
+        part = (jnp.clip(ends, lo, lo + rows)
+                - jnp.clip(ends - sizes, lo, lo + rows))
+        xs = u[pair // k]
+        gate = jax.lax.ragged_dot(xs, wg, part)
+        up = jax.lax.ragged_dot(xs, wu, part)
+        out = jax.lax.ragged_dot(activation(gate) * up, wd, part)
+        # rows past the last group are whatever the grouped matmul left
+        out = jnp.where(live[:, None],
+                        out.astype(jnp.float32) * wf[pair][:, None], 0)
+        return y.at[jnp.where(live, pair // k, n)].add(out, mode="drop")
+
+    return jax.lax.fori_loop(0, -(-total // rows), one_pass,
+                             jnp.zeros((n, d), jnp.float32))

@@ -35,7 +35,7 @@ Measured by the serving cells of ``python3 -m grid.run`` (``BENCHMARK.json``).
 from . import trace  # noqa: F401
 from .engine import ServingConfig, ServingEngine  # noqa: F401
 from .kv_cache import (  # noqa: F401
-    ContiguousKVCache, Int8PagedKVCache, PagedKVCache)
+    ContiguousKVCache, Int8PagedKVCache, LatentPagedCache, PagedKVCache)
 from .page_pool import PagePool, PagePoolExhausted  # noqa: F401
 from .request import (  # noqa: F401
     FAILED, FINISHED, QUEUED, REJECTED, RUNNING, TIMEOUT, BackpressureError,
@@ -44,7 +44,8 @@ from .scheduler import Scheduler  # noqa: F401
 
 __all__ = [
     "ServingConfig", "ServingEngine",
-    "PagedKVCache", "Int8PagedKVCache", "ContiguousKVCache",
+    "PagedKVCache", "Int8PagedKVCache", "LatentPagedCache",
+    "ContiguousKVCache",
     "PagePool", "PagePoolExhausted",
     "Scheduler", "Request", "BackpressureError", "DrainingError",
     "QUEUED", "RUNNING", "FINISHED", "TIMEOUT", "FAILED", "REJECTED",

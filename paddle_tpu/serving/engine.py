@@ -39,7 +39,7 @@ from . import metrics as _sm
 from . import speculative as _speculative
 from . import trace as _trace
 from .kv_cache import (CacheGroup, ContiguousKVCache, Int8PagedKVCache,
-                       PagedKVCache)
+                       LatentPagedCache, PagedKVCache)
 from .page_pool import PagePool, PagePoolExhausted
 from .request import (FAILED, FINISHED, REJECTED, TIMEOUT, DrainingError,
                       Request)
@@ -296,21 +296,28 @@ class ServingEngine:
       ``n_kv_head`` (the heads of K and V, which size the cache; default
       ``n_head``: the queries then are not grouped) and ``cache_groups``,
       a list of ``(name, layers, window)`` (default: one group of every
-      layer that keeps every position; see serving.kv_cache),
+      layer that keeps every position; see serving.kv_cache), or
+      ``latent_row``, ``(rank, rope)``: the model keeps ONE ``[c | kr]``
+      row a token a layer (latent attention) and the cache is a
+      :class:`~.kv_cache.LatentPagedCache` sized from it,
     * ``model.prefill(params, tokens[B,S], lengths[B]) -> (logits[B,S,V],
       kvs)`` with ``kvs`` one ``(k, v)`` ``[B,S,H,D]`` pair per layer (H
-      the KV heads); a model with ``prefill_last`` is asked for that
+      the KV heads), or one ``(row,)`` ``[B,S,rank+rope]`` per layer of a
+      latent model: what the cache's ``write_prompt`` takes; a model with
+      ``prefill_last`` is asked for that
       instead: the same with ``logits[B,V]`` of each prompt's last row,
     * ``model.decode(params, cache, cache_ops, tokens[B], pos[B],
       active[B]) -> (logits[B,V], cache)`` or ``(logits, cache, stats)``
       with ``stats`` a dict of small int arrays a step; the engine feeds
-      ``moe_experts_touched`` and ``moe_max_expert_rows`` [n_layer] to the
-      ``serving/*`` histograms of those names.
+      ``moe_experts_touched``, ``moe_max_expert_rows`` and
+      ``moe_held_pairs`` [n_layer] to the ``serving/*`` histograms of
+      those names.
 
-    Over a cache of more than one group the engine refuses, at
-    construction, what cannot work there: speculative verify (a ring
-    cannot be rolled back), the int8 KV pool, the prefix cache and the
-    contiguous layout; page export/import raise when called.
+    Over a cache of more than one group, and over a latent cache, the
+    engine refuses, at construction, what cannot work there: speculative
+    verify (a ring cannot be rolled back; a latent row is no K and V),
+    the int8 KV pool, the prefix cache and the contiguous layout; page
+    export/import raise when called.
     """
 
     def __init__(self, model, config: Optional[ServingConfig] = None,
@@ -327,10 +334,17 @@ class ServingEngine:
         q_per_kv = mcfg.n_head // n_kv
         layer_groups = getattr(mcfg, "cache_groups", None) or [
             ("global", tuple(range(mcfg.n_layer)), None)]
-        if len(layer_groups) > 1:
-            self._refuse_over_groups(layer_groups)
+        latent = getattr(mcfg, "latent_row", None)
+        if len(layer_groups) > 1 or latent:
+            self._refuse_over_groups(layer_groups, latent)
         self.pools: List[PagePool] = []
-        if self.cfg.paged:
+        if latent:
+            pages = self.cfg.group_pages.get("latent", self.cfg.num_pages)
+            self.cache_ops = LatentPagedCache(
+                mcfg.n_layer, latent[0], latent[1], self.cfg.slots,
+                self.cfg.max_seq, self.cfg.page_size, pages,
+                dtype=mcfg.dtype)
+        elif self.cfg.paged:
             ps = self.cfg.page_size
             groups = []
             for gi, (name, layers, window) in enumerate(layer_groups):
@@ -359,13 +373,14 @@ class ServingEngine:
                 self.cache_ops = PagedKVCache(
                     mcfg.n_layer, n_kv, mcfg.d_head, self.cfg.slots,
                     self.cfg.max_seq, ps, groups[0].num_pages, **geometry)
-            self.pools = [PagePool(g.num_pages, ps, name=g.name,
-                                   primary=(gi == 0))
-                          for gi, g in enumerate(groups)]
         else:
             self.cache_ops = ContiguousKVCache(
                 mcfg.n_layer, n_kv, mcfg.d_head, self.cfg.slots,
                 self.cfg.max_seq, dtype=mcfg.dtype)
+        if self.cfg.paged:      # one free list a cache group
+            self.pools = [PagePool(g.num_pages, self.cfg.page_size,
+                                   name=g.name, primary=(gi == 0))
+                          for gi, g in enumerate(self.cache_ops.groups)]
         # the first group's pool, under the name a one-group engine's only
         # pool always had
         self.pool: Optional[PagePool] = self.pools[0] if self.pools else None
@@ -446,11 +461,13 @@ class ServingEngine:
                     "will run, so the SLOs are inert (health() cannot "
                     "degrade on them)", len(specs))
 
-    def _refuse_over_groups(self, layer_groups) -> None:
-        """What cannot work over a cache of more than one group, said at
-        construction rather than computed wrong."""
+    def _refuse_over_groups(self, layer_groups, latent=None) -> None:
+        """What cannot work over a cache of more than one group, or over a
+        latent one, said at construction rather than computed wrong."""
         cfg = self.cfg
-        names = [g[0] for g in layer_groups]
+        over = ("a latent cache (one [c | kr] row a token, no V pool)"
+                if latent else "a cache with %d groups %s"
+                % (len(layer_groups), [g[0] for g in layer_groups]))
         for on, what in (
                 (not cfg.paged, "the contiguous layout (paged=False)"),
                 (cfg.kv_dtype == "int8", "the int8 KV pool"),
@@ -458,9 +475,7 @@ class ServingEngine:
                 (cfg.speculation > 0 and hasattr(self.model, "verify"),
                  "speculative verify")):
             if on:
-                raise ValueError(
-                    "%s is not supported over a cache with %d groups %s"
-                    % (what, len(names), names))
+                raise ValueError("%s is not supported over %s" % (what, over))
 
     @staticmethod
     def _calibrated_kv_scales(mcfg):
@@ -675,7 +690,8 @@ class ServingEngine:
         mode, why_not = self.cache_ops.kernel_mode()
         if mode is None:
             return "gather", why_not
-        mcfg = self.model.cfg
+        if isinstance(self.cache_ops, LatentPagedCache):
+            return "mla_paged", "default"     # no tuned table of its own
         try:
             from .. import tune
 
@@ -1219,7 +1235,8 @@ class ServingEngine:
         if stats is not None:
             for name, hist in (
                     ("moe_experts_touched", _sm.MOE_EXPERTS_TOUCHED),
-                    ("moe_max_expert_rows", _sm.MOE_MAX_EXPERT_ROWS)):
+                    ("moe_max_expert_rows", _sm.MOE_MAX_EXPERT_ROWS),
+                    ("moe_held_pairs", _sm.MOE_HELD_PAIRS)):
                 for x in stats.get(name, np.zeros(0)).reshape(-1):
                     hist.observe(float(x))
         if dlen_np is not None:
@@ -1404,8 +1421,9 @@ class ServingEngine:
                 logits, kvs = model.prefill(params, prompt[None],
                                             length[None])
                 last = logits[0, length - 1]
-            for i, (k, v) in enumerate(kvs):
-                cache = ops.write_prompt(cache, i, k[0], v[0], dest, length)
+            for i, kv in enumerate(kvs):
+                cache = ops.write_prompt(cache, i, *(t[0] for t in kv), dest,
+                                         length)
             # first generated token: same sampler as the decode scan, keyed
             # by the last PROMPT position (decode steps then key length,
             # length+1, ... — the streams can't collide)

@@ -43,6 +43,13 @@ of layer (GPT-2) is ONE global group: the same class, the same code.
 ``decode_attention`` a ``q`` of ``G * n_head`` heads (query head n reads KV
 head ``n // G``); ``q_per_kv`` = G tells the kernel's gate.
 
+A LATENT group (:class:`LatentPagedCache`): a model with latent (MLA)
+attention keeps ONE row a token a layer, ``[c | kr]`` (the compressed KV
+latent and the one rotary key every head shares), and no V pool: decode
+attention, absorbed, scores every query head against that row and sums
+over its first ``rank`` lanes. Pages, page tables, the pool's free list and
+the drop scatter are the paged cache's own.
+
 Both write paths scatter with ``mode="drop"`` on out-of-bounds destination
 rows, so inactive slots / padding positions are dropped INSIDE the compiled
 step — no host-side branching, and unwritten rows stay zero in both
@@ -57,7 +64,7 @@ import jax.numpy as jnp
 import numpy as np
 
 __all__ = ["CacheGroup", "PagedKVCache", "Int8PagedKVCache",
-           "ContiguousKVCache"]
+           "LatentPagedCache", "ContiguousKVCache"]
 
 
 class CacheGroup(NamedTuple):
@@ -312,17 +319,22 @@ class PagedKVCache(_KVCacheBase):
         decision both decode paths and ``ServingEngine.decode_kernel_info``
         read."""
         from ..ops import attention_ops
-        from ..ops.pallas_kernels.paged_attention import paged_attention_gate
 
         mode = attention_ops.paged_kernel_mode()
         if mode is None:
             return None, "n/a"
-        why_not = paged_attention_gate(
-            self.dtype, self.n_head, self.d_head, self.page_size,
-            interpret=(mode == "interpret"), q_per_kv=self.q_per_kv)
+        why_not = self._kernel_gate(interpret=(mode == "interpret"))
         if why_not is not None:
             return None, "gate: " + why_not
         return mode, None
+
+    def _kernel_gate(self, interpret: bool) -> Optional[str]:
+        """The kernel's static gate over this cache's geometry."""
+        from ..ops.pallas_kernels.paged_attention import paged_attention_gate
+
+        return paged_attention_gate(
+            self.dtype, self.n_head, self.d_head, self.page_size,
+            interpret=interpret, q_per_kv=self.q_per_kv)
 
     def decode_attention(self, state: Cache, layer: int, q, ctx_len,
                          active, sm_scale: float = 1.0) -> jnp.ndarray:
@@ -616,6 +628,117 @@ class Int8PagedKVCache(PagedKVCache):
             "ks": state["ks"].at[:, p].set(jnp.asarray(ks)),
             "vs": state["vs"].at[:, p].set(jnp.asarray(vs)),
         }
+
+
+class LatentPagedCache(PagedKVCache):
+    """The paged layout with ONE pool of latent rows, ``"c"``
+    ``[n_layer, num_pages*page_size, row_width]``, and no V pool.
+
+    A token's row is ``[c (rank) | kr (rope) | 0...]``: ``rank + rope``
+    values (512 + 64 at DeepSeek-V3's sizes, against 64 heads x (192 + 128)
+    of unabsorbed K and V), zero-padded to ``row_width``, the next whole
+    lane tile (640), because the chip moves whole (8, 128) tiles and a
+    buffer's last dimension is padded to them in memory whatever its
+    declared size: the padding is stated here and not hidden in the
+    layout. ``write_token``/``write_prompt`` take the unpadded row;
+    ``decode_attention`` takes the ABSORBED query ``[B, H, rank + rope]``
+    and returns ``[B, H, rank]`` (ops.attention_ops.mla_decode_attention or
+    the kernel of ops/pallas_kernels/mla_attention.py, by the same flag
+    as the paged kernel). One global group. What needs a K and a V row
+    (speculative verify, the int8 pool, page export and import, with them
+    the prefix cache) is refused: nobody needs it yet."""
+
+    layout = "paged-latent"
+
+    def __init__(self, n_layer: int, rank: int, rope: int, slots: int,
+                 max_ctx: int, page_size: int, num_pages: int,
+                 dtype=jnp.float32):
+        self.rank, self.rope = int(rank), int(rope)
+        self.row_values = self.rank + self.rope
+        width = -(-self.row_values // 128) * 128
+        super().__init__(n_layer, 1, width, slots, max_ctx, page_size,
+                         num_pages, dtype,
+                         groups=[CacheGroup("latent",
+                                            tuple(range(int(n_layer))), None,
+                                            int(num_pages))])
+
+    def init_state(self) -> Cache:
+        g = self.groups[0]
+        return {"c": jnp.zeros((len(g.layers), g.num_pages * self.page_size,
+                                self.row_width), self.dtype),
+                "pt": jnp.zeros((self.slots, self.pages_per_slot),
+                                jnp.int32)}
+
+    def cache_bytes(self, state: Cache) -> int:
+        """The pool as stored: the padding lanes count."""
+        return int(state["c"].nbytes)
+
+    def write_token(self, state: Cache, layer: int, row_new, pos, active
+                    ) -> Cache:
+        """``row_new`` [B, rank + rope] written at position ``pos[b]`` of
+        slot b; inactive slots dropped."""
+        return super().write_token(state, layer, row_new, None, pos, active)
+
+    def write_prompt(self, state: Cache, layer: int, row_new, dest, length
+                     ) -> Cache:
+        """``row_new`` [S, rank + rope] of ONE sequence; positions >=
+        ``length`` are dropped."""
+        return super().write_prompt(state, layer, row_new, None, dest, length)
+
+    def _write_rows(self, state: Cache, layer: int, dest, row_new, _v
+                    ) -> Cache:
+        """The paged cache's destinations, one padded row each."""
+        rows = row_new.reshape(-1, self.row_values).astype(self.dtype)
+        rows = jnp.pad(rows, ((0, 0), (0, self.row_width - self.row_values)))
+        return {**state,
+                "c": state["c"].at[layer, dest].set(rows, mode="drop")}
+
+    def context(self, state: Cache, layer: int) -> jnp.ndarray:
+        """Every slot's rows of ``layer`` in page-table order: ``[slots,
+        max_ctx, row_width]`` (the XLA-gather path)."""
+        return state["c"][layer, self._context_rows(state["pt"])]
+
+    def _kernel_gate(self, interpret: bool) -> Optional[str]:
+        from ..ops.pallas_kernels.mla_attention import mla_decode_gate
+
+        return mla_decode_gate(self.dtype, self.row_width, self.rank,
+                               self.page_size, interpret=interpret)
+
+    def decode_attention(self, state: Cache, layer: int, q, ctx_len,
+                         active, sm_scale: float = 1.0) -> jnp.ndarray:
+        """``q`` [B, H, rank + rope], absorbed; [B, H, rank] out, over each
+        slot's LIVE length (:func:`_live_len`)."""
+        from ..ops import attention_ops
+
+        length = _live_len(ctx_len, active)
+        q = jnp.pad(q, ((0, 0), (0, 0), (0, self.row_width - q.shape[-1])))
+        mode, _ = self.kernel_mode()
+        if mode is not None:
+            from ..ops.pallas_kernels import mla_attention as _mla
+
+            return _mla.mla_paged_decode(
+                q, state["c"], state["pt"], length,
+                page_size=self.page_size, rank=self.rank, layer=layer,
+                sm_scale=sm_scale, interpret=(mode == "interpret"))
+        return attention_ops.mla_decode_attention(
+            q, self.context(state, layer), length, self.rank,
+            sm_scale=sm_scale)
+
+    def _no_kv_rows(self, what: str):
+        raise ValueError("%s is not supported over a latent cache (one "
+                         "[c | kr] row a token and no V pool)" % what)
+
+    def decode_verify(self, *args, **kwargs):
+        self._no_kv_rows("speculative verify")
+
+    def page_meta(self) -> dict:
+        self._no_kv_rows("page export")
+
+    def export_pages(self, state: Cache, pages):
+        self._no_kv_rows("page export")
+
+    def import_pages(self, state: Cache, pages, meta: dict, blobs) -> Cache:
+        self._no_kv_rows("page import")
 
 
 class ContiguousKVCache(_KVCacheBase):

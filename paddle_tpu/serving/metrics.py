@@ -22,7 +22,8 @@ __all__ = [
     "DRAINS", "DRAINED_REQUESTS", "DRAIN_REJECTED",
     "SPEC_PROPOSED", "SPEC_ACCEPTED", "SPEC_REJECTED", "SPEC_DRAFTS",
     "SPEC_VERIFY_DISPATCHES", "SPEC_ACCEPT_RATE",
-    "MOE_EXPERTS_TOUCHED", "MOE_MAX_EXPERT_ROWS", "pages_used",
+    "MOE_EXPERTS_TOUCHED", "MOE_MAX_EXPERT_ROWS", "MOE_HELD_PAIRS",
+    "pages_used",
 ]
 
 REQUESTS_SUBMITTED = _mx.counter(
@@ -136,6 +137,11 @@ MOE_EXPERTS_TOUCHED = _mx.histogram(
 MOE_MAX_EXPERT_ROWS = _mx.histogram(
     "serving/moe_max_expert_rows",
     help="rows of the fullest expert, one observation a layer a decode step")
+MOE_HELD_PAIRS = _mx.histogram(
+    "serving/moe_held_pairs",
+    help="(token, expert) pairs routed to an expert this chip holds, one "
+         "observation a layer a decode step: the load of a share of a "
+         "wider expert-parallel deployment")
 
 
 def pages_used(group: str):

@@ -580,3 +580,93 @@ def test_sparse_decoder_executables(chip, monkeypatch, exe):
         r"\((\d+), \{\}, (?:may|must)-alias\)", text.split("\n", 1)[0])}
     # "k" "k.window" "pt" "pt.window" "v" "v.window" in key order
     assert {n_params, n_params + 1, n_params + 4, n_params + 5} <= aliased
+
+
+# -- the latent (MLA) decoder ---------------------------------------------------
+
+
+def test_latent_decode_kernel_at_the_served_geometry(chip):
+    """64 heads over one 640-lane row (512 latent + 64 rotary + padding),
+    page 16, bf16, the cell's whole pool, a layer in the middle: the kernel
+    compiles under its own name and nothing outside it touches the pool."""
+    from paddle_tpu.ops.pallas_kernels import mla_attention as mla
+
+    assert mla.mla_decode_gate(jnp.bfloat16, 640, 512, 16) is None
+    rows = 18432 * 16
+    text = compiled_text(
+        chip,
+        functools.partial(mla.mla_paged_decode, page_size=16, rank=512,
+                          layer=3, sm_scale=0.1309),
+        ((32, 64, 640), jnp.bfloat16), ((7, rows, 640), jnp.bfloat16),
+        ((32, 1024), jnp.int32), ((32,), jnp.int32))
+    kernel, = [ln for ln in text.split("\n") if "tpu_custom_call" in ln]
+    assert kernel.strip().startswith(("%mla_latent_decode",
+                                      "ROOT %mla_latent_decode"))
+    assert "bf16[32,64,512]" in kernel
+    assert [op for _, rtype, op, _ in _instructions(text)
+            if _has_dim(rtype, rows) and op != "parameter"] == []
+
+
+def _mla_case(chip, n_layer=2):
+    """The decode step of the latent decoder at the published widths, as
+    one chip of 32 holds it (12 of 384 experts, 20,480 rows of the
+    vocabulary), over the cell's pool; ``n_layer`` 2 is the dense layer and
+    one expert layer."""
+    from paddle_tpu.models import kimi_k2 as kk
+    from paddle_tpu.serving.kv_cache import LatentPagedCache
+
+    yarn = {"beta_fast": 1, "beta_slow": 1, "factor": 32, "mscale": 1,
+            "mscale_all_dim": 1, "type": "yarn",
+            "original_max_position_embeddings": 4096}
+    cfg = kk.KimiK2Config(
+        vocab_size=20480, n_layer=n_layer, d_model=7168, n_head=64,
+        q_rank=1536, kv_rank=512, d_nope=128, d_rope=64, d_v=128,
+        d_dense=18432, n_dense=1, n_expert=384, top_k=8, d_expert=2048,
+        routed_scale=2.827, rope_scaling=yarn, max_seq=16384,
+        dtype="bfloat16", experts_held=tuple(range(12)))
+    model = kk.KimiK2LM(cfg, params={})
+
+    def sds(x):
+        return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=chip)
+
+    params = jax.tree_util.tree_map(
+        sds, jax.eval_shape(lambda: kk.init_params(cfg, 0)))
+    ops = LatentPagedCache(n_layer, 512, 64, 32, 16384, 16, 18432,
+                           dtype="bfloat16")
+    cache = jax.tree_util.tree_map(sds, jax.eval_shape(ops.init_state))
+    ints = jax.ShapeDtypeStruct((32,), jnp.int32, sharding=chip)
+    flags = jax.ShapeDtypeStruct((32,), jnp.bool_, sharding=chip)
+
+    def chunk(params, cache, lengths, tokens, active):
+        logits, cache, stats = model.decode(params, cache, ops, tokens,
+                                            lengths, active)
+        return cache, jnp.argmax(logits, -1), stats
+
+    return chunk, (params, cache, ints, ints, flags), ops
+
+
+def test_latent_decoder_decode_step(chip, monkeypatch):
+    """The decode step runs the latent kernel once a layer and the
+    compiler's grouped matmul three times an expert layer (inside the
+    share's loop), and does not copy, slice or transpose the latent pool,
+    which is aliased from input to output."""
+    monkeypatch.setattr(attention_ops, "paged_kernel_mode",
+                        lambda: "compiled")
+    monkeypatch.setattr(attention_ops, "_on_tpu", lambda: True)
+    fn, args, ops = _mla_case(chip)
+    text = jax.jit(fn, donate_argnums=(1,)).lower(*args).compile().as_text()
+    assert text.count("%mla_latent_decode") >= 2
+    assert len(re.findall(r"= \S+ custom-call\([^\n]*ragged-dot", text)) >= 3
+    instructions = list(_instructions(text))
+    types = {name: rtype for name, rtype, _, _ in instructions}
+    rows = ops.groups[0].num_pages * ops.page_size
+    moved = [(op, rtype) for _, rtype, op, operands in instructions
+             if op in ("copy", "copy-start", "slice", "dynamic-slice",
+                       "transpose")
+             and any(_has_dim(t, rows) for t in
+                     [rtype] + [types.get(o, "") for o in operands])]
+    assert moved == [], moved
+    n_params = len(jax.tree_util.tree_leaves(args[0]))
+    aliased = {int(p) for p in re.findall(
+        r"\((\d+), \{\}, (?:may|must)-alias\)", text.split("\n", 1)[0])}
+    assert n_params in aliased           # "c" before "pt" in key order

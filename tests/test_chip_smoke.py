@@ -30,6 +30,12 @@ TOY = {
                       d_head=8, n_expert=4, top_k=2, d_expert=16, window=8,
                       max_seq=32, page_size=4, slots=2, prompts=(3, 6),
                       new_tokens=6, buckets=(8,)),
+    "serve_mla": dict(vocab=64, n_layer=2, d_model=32, n_head=4, q_rank=16,
+                      kv_rank=16, d_nope=8, d_rope=8, d_v=8, d_dense=64,
+                      n_expert=8, top_k=2, d_expert=16, held=4,
+                      yarn_positions=16, dtype="float32", max_seq=64,
+                      page_size=8, slots=2, prompt=11, new_tokens=17,
+                      buckets=(16,)),
     "dp": dict(steps=2),
 }
 
@@ -56,13 +62,14 @@ def run(mod, capsys, argv):
     return rc, lines
 
 
-def test_one_chip_runs_five_phases_and_ends_with_the_contract_line(
+def test_one_chip_runs_six_phases_and_ends_with_the_contract_line(
         smoke, monkeypatch, capsys):
     as_tpu(smoke, monkeypatch, 1)
     rc, lines = run(smoke, capsys, [])
     assert rc == 0, lines
     assert [ln.get("phase") for ln in lines[:-1]] == [
-        "start", "train", "ctr", "kernels", "serve", "serve_moe"]
+        "start", "train", "ctr", "kernels", "serve", "serve_moe",
+        "serve_mla"]
     for ln in lines[1:-1]:
         assert ln["ok"] is True
         for key in ("seconds", "compile_seconds", "compiles",
@@ -75,6 +82,8 @@ def test_one_chip_runs_five_phases_and_ends_with_the_contract_line(
     # on the CPU `auto` keeps every XLA path, and the lines say so
     by_phase = {ln["phase"]: ln for ln in lines[1:-1]}
     assert by_phase["serve"]["decode_kernel_info"] == ["gather", "n/a"]
+    assert by_phase["serve_mla"]["decode_kernel_info"] == ["gather", "n/a"]
+    assert by_phase["serve_mla"]["reference_margin"] < 1e-3
     assert "xla scatter" in by_phase["ctr"]["kernel_path"]["sparse_emb"]
     assert by_phase["kernels"]["kernel_path"] == "interpreted"
 
