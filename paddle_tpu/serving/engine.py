@@ -25,7 +25,7 @@ from __future__ import annotations
 
 import os
 import time
-from typing import Any, Dict, List, Optional, Sequence
+from typing import Any, Dict, List, NamedTuple, Optional, Sequence
 
 import jax
 import jax.numpy as jnp
@@ -136,6 +136,19 @@ def _sampler_tier(requests) -> int:
                 return 2
             tier = 1
     return tier
+
+
+class _Dispatch(NamedTuple):
+    """One decode dispatch from its launch to the read of its outputs."""
+
+    snap: tuple         # the per-slot state it was launched on: what a
+                        # failure rolls back to
+    tenants: list       # the requests that held the slots then: whom its
+                        # tokens are for
+    steps: int          # its fused steps
+    dlen: Any           # its draft lengths (a verify dispatch; else None)
+    outs: list          # its outputs, still on the device
+    t0: float           # the instant its launch began
 
 
 class ServingConfig:
@@ -420,6 +433,8 @@ class ServingEngine:
             self.prefix_cache = PrefixCache(self.cfg.prefix_cache_pages,
                                             self.cfg.page_size)
         self._captured_logits: Dict[int, List[np.ndarray]] = {}
+        # the decode dispatch that was launched and is not read yet
+        self._unread: Optional[_Dispatch] = None
         self._consecutive_failures = 0
         self._faults_absorbed = 0
         # per-ENGINE prefill accounting (the registry counters are shared
@@ -504,10 +519,13 @@ class ServingEngine:
         """Release the engine's telemetry resources: unhook the SLO
         monitor and drop the exporter reference (the LAST engine or
         supervisor releasing it stops the thread and flushes the final
-        partial interval). Idempotent; compiled executables stay usable."""
+        partial interval). A decode dispatch still unread is read first:
+        its tokens reach their requests. Idempotent; compiled executables
+        stay usable."""
         if self._closed:
             return
         self._closed = True
+        self._read_unread()
         if self._telemetry is not None:
             if self._slo_monitor is not None:
                 self._telemetry.remove_listener(self._slo_monitor.on_sample)
@@ -574,9 +592,12 @@ class ServingEngine:
 
     def step(self) -> List[Request]:
         """One multiplexer cycle: expire deadlines, retire/admit into free
-        slots, prefill the admissions, then one fused decode dispatch.
-        Returns requests that reached a terminal state during the cycle
-        (FINISHED, TIMEOUT or FAILED — check ``req.state``)."""
+        slots, prefill the admissions, launch one fused decode dispatch
+        and read the one the cycle before launched (the device runs the
+        new one meanwhile: :meth:`_decode_dispatch`). A token that
+        dispatch N computed is handed over by the ``step()`` that launches
+        N+1. Returns requests that reached a terminal state during the
+        cycle (FINISHED, TIMEOUT or FAILED — check ``req.state``)."""
         self._cycles += 1
         _sm.CYCLES.inc()
         sched = self.scheduler
@@ -586,7 +607,7 @@ class ServingEngine:
                 finished = self._expire_deadlines()
             with _span("serving/admit"):
                 finished.extend(self._admit())
-            if sched.occupancy:
+            if sched.occupancy or self._unread is not None:
                 finished.extend(self._decode_dispatch())
         return finished
 
@@ -605,6 +626,7 @@ class ServingEngine:
                 break
             done.extend(self.step())
             steps += 1
+        done.extend(self._read_unread())    # a cut drive leaves none behind
         return done
 
     def request_drain(self) -> None:
@@ -647,13 +669,20 @@ class ServingEngine:
                 req.finished_t = now
                 _trace.on_terminal(req, REJECTED, None)
                 summary["rejected"] += 1
-            deadline = time.monotonic() + timeout_s
-            while self.scheduler.occupancy and time.monotonic() < deadline:
-                for req in self.step():
+
+            def tally(reqs):
+                for req in reqs:
                     key = {FINISHED: "finished", TIMEOUT: "timed_out",
                            FAILED: "failed"}.get(req.state)
                     if key is not None:
                         summary[key] += 1
+
+            deadline = time.monotonic() + timeout_s
+            while self.scheduler.occupancy and time.monotonic() < deadline:
+                tally(self.step())
+            # past the budget with a dispatch unread: its tokens are
+            # computed, and some of its requests may have ended in it
+            tally(self._read_unread())
             for slot in range(self.cfg.slots):
                 if self.scheduler.slot_request(slot) is not None:
                     # past the drain budget: cut the straggler loose —
@@ -1130,21 +1159,133 @@ class ServingEngine:
         return draft, dlen, kmax + 1
 
     def _decode_dispatch(self) -> List[Request]:
-        """One fused decode dispatch with the recovery ladder: transient
-        failures retry in place (bounded by ``decode_retries``); a failure
-        that exhausts the budget — or classifies fatal — FAILS the
-        in-flight batch (pages reclaimed, requests marked FAILED, device
-        slot state reset) and the engine keeps serving the queue. The
-        flight recorder captures the batch spec either way.
+        """What one ``step()`` does about decoding. A plain dispatch
+        carries every per-slot state on the device and decides there who
+        finishes, so dispatch N+1 needs nothing of N that the host has to
+        read first: it is launched on N's outputs while N is unread, and
+        the host reads N's tokens while the device runs N+1
+        (:meth:`_decode_cycle`). N is read BEFORE anything is launched
+        where the engine can tell that N+1 would be built from N's tokens,
+        or would be empty (:meth:`_launches_ahead`)."""
+        finished: List[Request] = []
+        if self._unread is not None and not self._launches_ahead(self._unread):
+            finished = self._decode_cycle(launch=False)
+        if self.scheduler.occupancy:
+            finished.extend(self._decode_cycle(launch=True))
+        return finished
 
-        With speculation armed and the drafter proposing, the tick runs
+    def _read_unread(self) -> List[Request]:
+        """Read the dispatch left unread, if any, and launch nothing: what
+        ``run()``, ``drain()`` and ``close()`` end with."""
+        return self._decode_cycle(launch=False) \
+            if self._unread is not None else []
+
+    def _launches_ahead(self, prev: _Dispatch) -> bool:
+        """Whether the dispatch after ``prev`` is launched before ``prev``
+        is read. Not while a running slot drafts: a verify window is built
+        on the host from the tokens read so far. And not where, by the
+        host's own counts, every running request's budget
+        (``max_new_tokens``, ``max_seq``) ends in ``prev``: the next
+        dispatch would be empty. (An EOS the host cannot foresee may still
+        leave one dispatch with no live slot; its outputs are dropped.)"""
+        if self._a_slot_drafts():
+            return False
+        for slot, was in enumerate(prev.tenants):
+            req = self.scheduler.slot_request(slot)
+            if req is None:
+                continue
+            gen = len(req.tokens_out) + prev.steps
+            # a request armed after prev's launch has its whole budget left
+            if req is not was or (
+                    gen < req.max_new_tokens
+                    and req.prompt_len + gen - 1 < self.cfg.max_seq):
+                return True
+        return False
+
+    def _a_slot_drafts(self) -> bool:
+        return self._spec_enabled and any(
+            self._spec_k[r.slot] > 0 for r in self.scheduler.running())
+
+    def _launch(self, exe, extra, steps: int, dlen) -> _Dispatch:
+        """Call ``exe`` on the per-slot state as it stands (the outputs of
+        the dispatch before it, read or not; the cache stays donated) and
+        start the host copies of its small outputs, so that a cycle later
+        they have landed. On a failure nothing is reassigned."""
+        snap = (self._cache, self._len, self._tok, self._active, self._gen)
+        tenants = [self.scheduler.slot_request(s)
+                   for s in range(self.cfg.slots)]
+        with _span("serving/decode.launch") as launch:
+            spec = _faults.fire("serving.decode")  # chaos drills
+            if spec is not None and spec.kind == "exhausted":
+                raise PagePoolExhausted(
+                    "injected pool exhaustion at serving.decode")
+            out = exe(self.params, self._cache, self._len, self._tok,
+                      self._active, self._gen, self._maxnew, self._temp,
+                      self._topk, self._seed, *extra)
+            (self._cache, self._len, self._tok, self._active, self._gen,
+             *outs) = out
+            for x in jax.tree_util.tree_leaves(outs):
+                x.copy_to_host_async()
+        _sm.SAMPLER_DISPATCHES[_sampler_tier(tenants)].inc()
+        return _Dispatch(snap, tenants, steps, dlen, outs, launch.t0)
+
+    def _sync(self, d: _Dispatch):
+        """``d``'s outputs on the host: ``(toks, emitted, fin, logits or
+        None, the model's counters or None)``. The one host sync of a
+        cycle: the retire/admit decision needs the emitted tokens (the
+        serving analog of run_steps' fetch)."""
+        with _span("serving/decode.sync"):
+            toks, emitted, fin, *rest = jax.tree_util.tree_map(
+                np.asarray, d.outs)
+        logseq = rest.pop(0) if self.cfg.collect_logits else None
+        return toks, emitted, fin, logseq, rest[0] if rest else None
+
+    def _roll_back(self, d: _Dispatch) -> bool:
+        """Put the per-slot state back to what ``d`` was launched on: a
+        failed dispatch often surfaces at host materialization, AFTER the
+        ``self._*`` slots were reassigned to its outputs (and the next
+        dispatch launched on them) — a retry from those half-advanced
+        values would double-step every in-flight request. Returns whether
+        ``d`` can be launched again from there: not where the donated
+        cache is gone (recovery must re-init it), nor where a slot was
+        vacated or armed since (the snapshot knows nothing of it)."""
+        (self._cache, self._len, self._tok, self._active,
+         self._gen) = d.snap
+        return not self._cache_lost() and all(
+            self.scheduler.slot_request(slot) is was
+            for slot, was in enumerate(d.tenants))
+
+    def _decode_cycle(self, launch: bool) -> List[Request]:
+        """One ``serving/decode`` span, the interval
+        ``serving/decode_step_ms`` observes: the launch of a dispatch
+        (``launch``; one more for each retry), then the sync that reads
+        the dispatch launched a cycle ago, while the device runs the new
+        one; ``serving/retire`` hands its tokens over. With nothing unread
+        the new dispatch is left unread and the span ends with the launch —
+        unless a slot drafts: the next window is then built from this
+        dispatch's tokens, so it is read here, as a verify dispatch always
+        is. With ``launch`` false the span is the sync alone.
+
+        The recovery ladder: transient failures retry in place (bounded by
+        ``decode_retries``); a failure that exhausts the budget — or
+        classifies fatal — FAILS the in-flight batch (pages reclaimed,
+        requests marked FAILED, device slot state reset) and the engine
+        keeps serving the queue. The flight recorder captures the batch
+        spec either way. A failure of the launch leaves the state as it
+        was; one that surfaces at the sync rolls it back to what the
+        dispatch being read was launched on (:meth:`_roll_back`), the
+        dispatch launched ahead of it is abandoned unread, and a retry
+        launches the failed one again and reads it at once.
+
+        With speculation armed and the drafter proposing, the launch is
         the verify executable instead: ONE windowed forward over each
         slot's (pending token + draft) window, per-step accept/rollback
         on device — up to k+1 tokens per dispatch, bit-identical stream
         (serving.speculative). Rollback is free under the worst-case page
         reservation: rejected positions sit beyond the rolled-back
         ``ctx_len``, masked out of every later read until overwritten."""
-        drafts = self._build_drafts()
+        prev, self._unread = self._unread, None
+        drafts = self._build_drafts() if launch else None
         if drafts is not None:
             draft_np, dlen_np, steps = drafts
             exe = self._get_verify_exe(steps)
@@ -1154,54 +1295,29 @@ class ServingEngine:
             steps = self.cfg.decode_fuse
             exe = self._get_decode_exe(steps)
             extra = ()
+        read_now = prev is None and self._a_slot_drafts()
         attempt = 0
-        # Pre-dispatch snapshot: on an async backend a failed dispatch often
-        # surfaces at host materialization (np.asarray below), AFTER the
-        # self._* slots were reassigned to the failed step's outputs — a
-        # retry from those half-advanced values would double-step every
-        # in-flight request. The failure path always rolls back to this
-        # snapshot first (the donated cache may be gone; _cache_lost() on
-        # the restored ref detects that and downgrades retry to recovery).
-        snap = (self._cache, self._len, self._tok, self._active, self._gen)
-        inflight = [self.scheduler.slot_request(s)
-                    for s in range(self.cfg.slots)]
-        _sm.SAMPLER_DISPATCHES[_sampler_tier(inflight)].inc()
-        # serving/decode is the interval serving/decode_step_ms observes:
-        # every launch (one more for each retry) up to the end of the sync
         with _span("serving/decode", steps=steps,
-                   kind="plain" if dlen_np is None else "verify") as dispatch:
+                   kind="plain" if dlen_np is None else "verify") as window:
             while True:
+                cur = read = None
                 try:
-                    with _span("serving/decode.launch"):
-                        spec = _faults.fire("serving.decode")  # chaos drills
-                        if spec is not None and spec.kind == "exhausted":
-                            raise PagePoolExhausted(
-                                "injected pool exhaustion at serving.decode")
-                        out = exe(self.params, self._cache, self._len,
-                                  self._tok, self._active, self._gen,
-                                  self._maxnew, self._temp, self._topk,
-                                  self._seed, *extra)
-                    (self._cache, self._len, self._tok, self._active,
-                     self._gen, toks, emitted, fin, *rest) = out
-                    logseq = rest.pop(0) if self.cfg.collect_logits else None
-                    stats = rest[0] if rest else None
-                    # one host sync per dispatch: the retire/admit decision
-                    # needs the emitted tokens (the serving analog of
-                    # run_steps' fetch)
-                    with _span("serving/decode.sync"):
-                        toks = np.asarray(toks)
-                        emitted = np.asarray(emitted)
-                        fin = np.asarray(fin)
-                        if stats is not None:
-                            stats = {k: np.asarray(v)
-                                     for k, v in stats.items()}
+                    if launch:
+                        cur = self._launch(exe, extra, steps, dlen_np)
+                        if prev is not None:
+                            _sm.DECODE_LAUNCHED_AHEAD.inc()
+                    read = prev if prev is not None \
+                        else cur if read_now else None
+                    host = None if read is None else self._sync(read)
                     break
                 except Exception as e:
-                    (self._cache, self._len, self._tok, self._active,
-                     self._gen) = snap
-                    if (_faults.classify(e) == "transient"
-                            and attempt < self.cfg.decode_retries
-                            and not self._cache_lost()):
+                    if read is None:    # the launch: nothing was reassigned
+                        again = not self._cache_lost()
+                    else:
+                        again = self._roll_back(read)
+                        prev, launch, read_now = None, True, True
+                    if (again and _faults.classify(e) == "transient"
+                            and attempt < self.cfg.decode_retries):
                         attempt += 1
                         _sm.RETRIES.inc()
                         continue
@@ -1210,28 +1326,67 @@ class ServingEngine:
                         fr.record_event("serving_inflight_batch",
                                         **self._batch_spec())
                     _safe_flight_dump(fr, "serving.decode", e)
+                    # a launch that gives up with a dispatch unread: that
+                    # one's tokens are whole, and go to their requests
+                    # before the batch fails
+                    whole = self._hand_over_whole(prev, window.t0) \
+                        if prev is not None else []
                     if self.cfg.fail_fast:
                         raise
-                    return self._fail_inflight_batch(e)
+                    return whole + self._fail_inflight_batch(e)
+        if cur is not read:
+            self._unread = cur
+        if read is None:
+            return []
         self._consecutive_failures = 0
-        t0, t1 = dispatch.t0, dispatch.t1
+        finished = self._hand_over(read, host, window.t0, window.t1)
+        if not self.scheduler.occupancy:
+            # an EOS the host could not foresee: the dispatch launched
+            # ahead has no live slot, and nobody to give its outputs to
+            self._unread = None
+        return finished
+
+    def _hand_over_whole(self, d: _Dispatch, t0: float) -> List[Request]:
+        """Read ``d`` and hand its tokens over on the way to failing its
+        batch; a read that fails too loses them with the batch."""
+        try:
+            return self._hand_over(d, self._sync(d), t0, time.perf_counter())
+        except Exception:
+            return []
+
+    def _hand_over(self, d: _Dispatch, host, t0: float, t1: float
+                   ) -> List[Request]:
+        """Dispatch ``d``'s tokens, read, to the requests that held the
+        slots when it was launched — and only to one that still runs in
+        its slot: a request retired since (a deadline, a failed batch)
+        gets none of them. ``t0``..``t1`` is the cycle's ``serving/decode``
+        interval, which the requests' tracks show; ``serving/decode_step_ms``
+        observes ``d``'s launch to ``t1``, its tokens on the host."""
+        toks, emitted, fin, logseq, stats = host
+        steps = d.steps
+        live = [req if req is not None
+                and self.scheduler.slot_request(slot) is req else None
+                for slot, req in enumerate(d.tenants)]
         spec_args = None
-        if dlen_np is not None:
+        if d.dlen is not None:
             # accepted drafts per slot = its run-steps beyond the first
             # (step 0 consumes the pending token, never a draft)
             runs = emitted.sum(axis=0)
-            proposed = int(dlen_np.sum())
+            proposed = int(d.dlen.sum())
             accepted = int(np.maximum(runs - 1, 0).sum())
             spec_args = _speculative.verify_window_args(steps, proposed,
                                                         accepted)
-        _trace.on_decode_chunk(inflight, steps, t0, t1, spec=spec_args)
-        _sm.DECODE_STEP_MS.observe((t1 - t0) * 1e3)
+        _trace.on_decode_chunk(live, steps, t0, t1, spec=spec_args)
+        # the dispatch's own latency, not the cycle's interval: a host-bound
+        # loop launches in a fraction of its cycle, and what a caller paces
+        # itself by (an SLO, a load generator's lateness) is how long a
+        # dispatch takes to give its tokens
+        _sm.DECODE_STEP_MS.observe((t1 - d.t0) * 1e3)
         _sm.DECODE_DISPATCHES.inc()
         # a verify dispatch is ONE windowed model step however wide the
         # window — DECODE_STEPS keeps meaning "model forwards", so
         # tokens/steps > 1 is exactly the speculative win
-        _sm.DECODE_STEPS.inc(1 if dlen_np is not None else steps)
-        _sm.TOKENS_GENERATED.inc(int(emitted.sum()))
+        _sm.DECODE_STEPS.inc(1 if d.dlen is not None else steps)
         if stats is not None:
             for name, hist in (
                     ("moe_experts_touched", _sm.MOE_EXPERTS_TOUCHED),
@@ -1239,29 +1394,30 @@ class ServingEngine:
                     ("moe_held_pairs", _sm.MOE_HELD_PAIRS)):
                 for x in stats.get(name, np.zeros(0)).reshape(-1):
                     hist.observe(float(x))
-        if dlen_np is not None:
+        if d.dlen is not None:
             _sm.SPEC_PROPOSED.inc(proposed)
             _sm.SPEC_ACCEPTED.inc(accepted)
             _sm.SPEC_REJECTED.inc(proposed - accepted)
-            _sm.SPEC_DRAFTS.inc(int((dlen_np > 0).sum()))
+            _sm.SPEC_DRAFTS.inc(int((d.dlen > 0).sum()))
             _sm.SPEC_VERIFY_DISPATCHES.inc()
             _sm.SPEC_ACCEPT_RATE.observe(accepted / max(1, proposed))
         finished: List[Request] = []
+        handed = 0
         with _span("serving/retire"):
-            for slot in range(self.cfg.slots):
-                req = self.scheduler.slot_request(slot)
+            for slot, req in enumerate(live):
                 if req is None:
                     continue
                 for f in range(steps):
                     if emitted[f, slot]:
                         req.tokens_out.append(int(toks[f, slot]))
+                        handed += 1
                         if logseq is not None:
                             self._captured_logits.setdefault(
-                                req.id, []).append(
-                                    np.asarray(logseq[f, slot]))
+                                req.id, []).append(logseq[f, slot])
                     if fin[f, slot]:
                         finished.append(self._retire(slot))
                         break
+        _sm.TOKENS_GENERATED.inc(handed)
         return finished
 
     def _retire(self, slot: int, state: str = FINISHED,

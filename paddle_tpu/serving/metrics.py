@@ -16,6 +16,7 @@ __all__ = [
     "REQUESTS_REJECTED", "QUEUE_DEPTH", "SLOT_OCCUPANCY",
     "PAGES_IN_USE", "PAGE_POOL_UTILIZATION", "ADMISSION_BLOCKED",
     "PREFILL_COUNT", "DECODE_STEPS", "DECODE_DISPATCHES",
+    "DECODE_LAUNCHED_AHEAD",
     "TOKENS_GENERATED", "CYCLES", "SAMPLER_DISPATCHES",
     "REQUEST_LATENCY_MS", "TTFT_MS", "DECODE_STEP_MS", "PREFILL_MS",
     "FAULTS", "RETRIES", "TIMEOUTS", "REQUESTS_FAILED",
@@ -54,6 +55,11 @@ DECODE_STEPS = _mx.counter(
 DECODE_DISPATCHES = _mx.counter(
     "serving/decode_dispatches",
     help="decode dispatches issued (each fuses >=1 decode steps)")
+DECODE_LAUNCHED_AHEAD = _mx.counter(
+    "serving/decode_launched_ahead",
+    help="decode dispatches launched while the one before was unread: "
+         "over serving/decode_dispatches, how often the host reads one "
+         "dispatch's tokens while the device runs the next")
 TOKENS_GENERATED = _mx.counter(
     "serving/tokens_generated", help="tokens emitted to finished+running requests")
 CYCLES = _mx.counter(
@@ -79,8 +85,10 @@ TTFT_MS = _mx.histogram(
     "serving/ttft_ms", help="submit -> first token wall time per request")
 DECODE_STEP_MS = _mx.histogram(
     "serving/decode_step_ms",
-    help="host wall time of one decode dispatch, launch to the end of "
-         "the host sync (all its fused steps, retries included)")
+    help="host wall time of one decode dispatch, from its launch to its "
+         "tokens on the host: the end of the sync that reads it, which is "
+         "a cycle later where the next dispatch was launched ahead of the "
+         "read (all its fused steps; one observation a dispatch read)")
 PREFILL_MS = _mx.histogram(
     "serving/prefill_ms", help="host wall time of one compiled prefill call")
 FAULTS = _mx.counter(

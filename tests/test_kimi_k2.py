@@ -60,10 +60,14 @@ def _scaled(params):
         lambda a: a * 6.0 if a.ndim > 1 else a, params)
 
 
-@pytest.fixture(scope="module")
-def toy():
+def toy_model():
     cfg = toy_cfg()
     return kk.KimiK2LM(cfg, params=_scaled(kk.init_params(cfg, 3)))
+
+
+@pytest.fixture(scope="module")
+def toy():
+    return toy_model()
 
 
 def reference_rows(model, seq, rows, **over):

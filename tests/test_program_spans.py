@@ -95,34 +95,67 @@ def _assert_nested(spans):
                    for p in spans), "%s escapes %s" % (s["name"], s["parent"])
 
 
-@pytest.mark.parametrize("case", ["one_admission", "retried_decode"])
+# what each kind of cycle leaves out of the tree (ISSUE 33: a step launches
+# dispatch N+1, THEN reads dispatch N)
+PREFILL = {"serving/prefill", "serving/prefill.launch", "serving/prefill.sync"}
+READ = {"serving/decode.sync", "serving/retire"}
+CASES = {
+    # the first dispatch after the engine held nobody: nothing to read yet
+    "one_admission": (0, None, READ),
+    # dispatch 2 is launched, then dispatch 1 is read
+    "launched_ahead": (1, None, PREFILL),
+    "retried_decode": (1, "transient", PREFILL),
+    # every budget ends in the dispatch in flight: it is read, none launched
+    "budget_ends": (2, None, PREFILL | {"serving/decode.launch"}),
+}
+
+
+@pytest.mark.parametrize("case", list(CASES))
 def test_step_records_the_tree(case, host_tracer):
+    steps_before, fault, absent = CASES[case]
     eng = _engine()
-    req = eng.submit([3, 1, 4, 1, 5], 4)
-    if case == "retried_decode":
-        faults.install(FaultPlan([faults.FaultSpec(
-            "serving.decode", "transient", at=1)]))
+    req = eng.submit([3, 1, 4, 1, 5], 3)
     try:
+        for _ in range(steps_before):
+            eng.step()
+        had = len(req.tokens_out)
+        tracer.clear_spans()
+        if fault:
+            faults.install(FaultPlan([faults.FaultSpec(
+                "serving.decode", fault, at=1)]))
         eng.step()
+        spans = _engine_spans()
+        got, state = len(req.tokens_out), req.state
     finally:
         faults.clear()
         eng.close()
-    spans = _engine_spans()
     names = [s["name"] for s in spans]
-    launches = 2 if case == "retried_decode" else 1
+    launches = 2 if fault else 1
     assert sorted(names) == sorted(
-        list(STEP_TREE) + ["serving/decode.launch"] * (launches - 1))
+        [n for n in STEP_TREE if n not in absent]
+        + ["serving/decode.launch"] * (launches - 1))
     for s in spans:
         assert s.get("parent") == STEP_TREE[s["name"]], s
     _assert_nested(spans)
     by_name = {s["name"]: s for s in spans}
-    assert by_name["serving/prefill"]["args"]["trace_id"] == req.trace_id
-    assert by_name["serving/prefill"]["args"]["cause"] == "local"
-    assert by_name["serving/step"]["args"] == {"cycle": 1, "occupancy": 0,
-                                               "queue": 1}
+    assert by_name["serving/step"]["args"] == {
+        "cycle": steps_before + 1, "occupancy": min(steps_before, 1),
+        "queue": 0 if steps_before else 1}
     assert by_name["serving/decode"]["args"]["kind"] == "plain"
-    # the histograms read the spans' own clocks: one dispatch, one prefill
-    assert len(req.tokens_out) >= 2
+    if case == "one_admission":
+        assert by_name["serving/prefill"]["args"]["trace_id"] == req.trace_id
+        assert by_name["serving/prefill"]["args"]["cause"] == "local"
+        # the prefill's token alone: the dispatch is launched and unread
+        assert got == had + 1 == 1
+    else:
+        # one dispatch's token a step, and the launch comes before the sync
+        assert got == had + 1
+        if "serving/decode.launch" not in absent:
+            assert all(s["ts_us"] <= by_name["serving/decode.sync"]["ts_us"]
+                       for s in spans if s["name"] == "serving/decode.launch")
+    assert (state == "finished") == (case == "budget_ends")
+    # close() read what the step had left unread: nothing is lost
+    assert len(req.tokens_out) == min(got + 1, 3)
 
 
 def test_untraced_run_records_nothing_counts_cycles_and_serves_the_same():

@@ -392,3 +392,62 @@ def test_serving_page_accounting_every_retirement_path(rng):
     assert r_after.state == "finished"
     assert_balanced(eng_f, "post-failure traffic")
     assert eng_f.health()["status"] == "ok"
+
+
+@pytest.mark.parametrize("path", ["eos", "timeout", "fatal", "drain"])
+def test_serving_page_accounting_with_a_dispatch_in_flight(path, rng):
+    """ISSUE 33: ``step()`` launches dispatch N+1 before it reads N. Every
+    way out of a slot while a dispatch is unread gives the pages back once,
+    leaves no dispatch unread behind, and the request behind is served
+    whole in the pages that came back."""
+    from paddle_tpu import serving
+    from paddle_tpu.models import decoder_lm
+
+    cfg = decoder_lm.DecoderConfig(vocab_size=64, n_layer=2, d_model=32,
+                                   n_head=2, max_seq=32)
+    model = decoder_lm.DecoderLM(cfg, seed=0)
+    for _ in range(50):     # a stream whose second token is a new one
+        first = list(rng.randint(0, 64, 5))
+        alone, _ = decoder_lm.reference_decode(model.params, cfg, first, 12)
+        if alone[1] != alone[0]:
+            break
+    second = list(rng.randint(0, 64, 9))
+    want, _ = decoder_lm.reference_decode(model.params, cfg, second, 5)
+    # an EOS the host cannot foresee: dispatch 1 ends the request on the
+    # device while dispatch 2, launched ahead, has no live slot
+    eos = {"eos_id": int(alone[1])} if path == "eos" else {}
+    assert alone[1] != alone[0] and (path != "eos" or eos["eos_id"] not in want)
+    eng = serving.ServingEngine(model, serving.ServingConfig(
+        slots=1, page_size=8, max_seq=32, decode_retries=0, **eos))
+    r1 = eng.submit(first, 12, deadline_s=600.0)
+    r2 = None if path == "drain" else eng.submit(second, 5)
+    for _ in range(1 if path == "eos" else 3):
+        eng.step()
+    assert eng._unread is not None
+    if path == "eos":
+        assert eng.step() == [r1] and r1.state == "finished"
+        assert r1.tokens_out == alone[:2]
+    elif path == "timeout":
+        r1.deadline_s = 0.0
+        assert eng.step() == [r1] and r1.state == "timeout"
+        assert r1.tokens_out == alone[:3]
+    elif path == "fatal":
+        with FaultPlan([faults.FaultSpec("serving.decode", "fatal", at=1)]):
+            assert eng.step() == [r1]
+        # the dispatch in flight was read before its batch failed
+        assert r1.state == "failed" and r1.tokens_out == alone[:4]
+    else:
+        summary = eng.drain(timeout_s=0.0)
+        assert summary["timed_out"] == 1 and r1.state == "timeout"
+        assert r1.tokens_out == alone[:4]
+    if path == "timeout":   # the same cycle admitted r2 and launched for it
+        assert r2.state == "running" and eng._unread.tenants == [r2]
+    else:                   # read, or dropped with nobody to give it to
+        assert eng._unread is None
+    assert not r1.pages and eng.page_accounting_ok()
+    if r2 is not None:
+        eng.run(max_steps=100)
+        assert r2.state == "finished" and r2.tokens_out == want
+    assert eng._unread is None and eng.pool.num_used == 0
+    assert eng.page_accounting_ok()
+    eng.close()

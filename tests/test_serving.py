@@ -418,3 +418,291 @@ def test_close_is_idempotent_and_drain_after_close(rng):
     s1 = eng.drain(timeout_s=1.0)
     assert s1["finished"] == 0 and s1["rejected"] == 0
     assert eng.drain() is s1
+
+
+# -- the dispatch launched ahead (ISSUE 33) -----------------------------------
+# step() launches dispatch N+1 on dispatch N's outputs, THEN reads N's tokens.
+
+def _ahead_counts():
+    from paddle_tpu.monitor import metrics as mx
+
+    snap = mx.snapshot()
+    return np.array([snap[n]["value"] for n in (
+        "serving/decode_launched_ahead", "serving/decode_dispatches",
+        "serving/retries", "serving/faults")])
+
+
+def _toy(family):
+    """``(model, check)``: a toy of the family and ``check(prompt, toks)``,
+    which holds where ``toks`` is what the model's own greedy decode gives
+    step by step: each token the argmax of the family's plain reference
+    over the tokens before it (one teacher-forced pass)."""
+    if family == "gpt2":
+        model = get_model()
+
+        def check(prompt, toks):
+            assert decoder_lm.reference_tokens(
+                model.params, model.cfg, prompt, toks) == toks
+        return model, check
+    # the sparse families' toys and references are their own test files'
+    import test_kimi_k2
+    import test_smallthinker
+
+    mod = {"smallthinker": test_smallthinker, "kimi": test_kimi_k2}[family]
+    model = mod.toy_model()
+
+    def check(prompt, toks):
+        first = len(prompt) - 1
+        rows = mod.reference_rows(model, list(prompt) + toks[:-1],
+                                  np.arange(first, first + len(toks)))
+        assert list(rows.argmax(-1)) == toks
+    return model, check
+
+
+_TOYS = {}
+
+
+@pytest.mark.parametrize("fuse", [1, 4])
+@pytest.mark.parametrize("family", ["gpt2", "smallthinker", "kimi"])
+def test_streams_admitted_over_several_cycles_equal_the_models_own_decode(
+        family, fuse, rng):
+    """Seven requests of mixed lengths through two slots: each is admitted
+    behind a dispatch in flight and prefilled into pages a retired request
+    gave back, and every stream is the model's own."""
+    if family not in _TOYS:
+        _TOYS[family] = _toy(family)
+    model, check = _TOYS[family]
+    vocab = model.cfg.vocab_size
+    eng = serving.ServingEngine(model, serving.ServingConfig(
+        slots=2, page_size=8, max_seq=64, prompt_buckets=(8, 16, 32),
+        decode_fuse=fuse))
+    stream = [(list(rng.randint(0, vocab, n)), m) for n, m in (
+        (3, 9), (19, 5), (5, 14), (11, 2), (8, 1), (27, 11), (4, 7))]
+    before = _ahead_counts()
+    reqs = [eng.submit(p, m) for p, m in stream[:4]]
+    for _ in range(3):
+        eng.step()
+    reqs += [eng.submit(p, m) for p, m in stream[4:]]   # late arrivals
+    eng.run()
+    ahead, dispatches = (_ahead_counts() - before)[:2]
+    assert 0 < ahead < dispatches
+    assert eng.page_accounting_ok() and eng._unread is None
+    eng.close()
+    for (prompt, m), req in zip(stream, reqs):
+        assert req.state == "finished" and len(req.tokens_out) == m
+        check(prompt, req.tokens_out)
+
+
+def test_one_long_request_launches_every_dispatch_but_the_first_ahead(rng):
+    """Cycle k+1 launches dispatch k+1, then reads dispatch k: after j
+    cycles the request holds the prefill's token and j-1 dispatches'
+    tokens, and in the host tracer's record each launch begins before the
+    sync of its cycle ends."""
+    from paddle_tpu.monitor import tracer
+
+    model = get_model()
+    prompt = list(rng.randint(0, 64, 9))
+    want, _ = decoder_lm.reference_decode(model.params, model.cfg, prompt, 12)
+    eng = serving.ServingEngine(model, small_config())
+    req = eng.submit(prompt, 12)
+    before = _ahead_counts()
+    tracer.clear_spans()
+    tracer.start_tracing()
+    try:
+        cycles = 0
+        while not eng.scheduler.idle():
+            eng.step()
+            cycles += 1
+            if req.state == "running":
+                # the sync of cycle j read dispatch j-1's outputs
+                assert req.tokens_out == want[:cycles]
+        spans = [s for s in tracer.get_spans() if s["cat"] == "engine"]
+    finally:
+        tracer.stop_tracing()
+        tracer.clear_spans()
+        eng.close()
+    assert req.tokens_out == want
+    ahead, dispatches = (_ahead_counts() - before)[:2]
+    assert dispatches == 11 and ahead == dispatches - 1
+    assert cycles == 12     # the last cycle reads, and launches nothing
+    by_cycle = {}
+    for s in spans:
+        if s["name"] == "serving/step":
+            by_cycle[s["args"]["cycle"]] = (s["ts_us"],
+                                            s["ts_us"] + s["dur_us"])
+
+    def of_cycle(name, k):
+        lo, hi = by_cycle[k]
+        return [s for s in spans if s["name"] == name
+                and lo <= s["ts_us"] and s["ts_us"] + s["dur_us"] <= hi]
+
+    assert not of_cycle("serving/decode.sync", 1)
+    assert not of_cycle("serving/decode.launch", 12)
+    for k in range(2, 12):
+        launch, = of_cycle("serving/decode.launch", k)
+        sync, = of_cycle("serving/decode.sync", k)
+        assert launch["ts_us"] + launch["dur_us"] <= sync["ts_us"] \
+            + sync["dur_us"] and launch["ts_us"] <= sync["ts_us"]
+
+
+def test_a_deadline_that_expires_with_a_dispatch_in_flight(rng):
+    """The request ends TIMEOUT with the tokens it had: the dispatch in
+    flight was launched for it and gives it nothing; its pages go back
+    once, and the request behind it is served whole in the same slot."""
+    model = get_model()
+    eng = serving.ServingEngine(model, small_config(slots=1))
+    doomed = eng.submit(list(rng.randint(0, 64, 8)), 20, deadline_s=600.0)
+    prompt = list(rng.randint(0, 64, 11))
+    behind = eng.submit(prompt, 6)
+    for _ in range(3):
+        eng.step()
+    assert eng._unread is not None and doomed.state == "running"
+    had = list(doomed.tokens_out)
+    assert len(had) == 3
+    doomed.deadline_s = 0.0
+    done = eng.step()
+    assert doomed in done and doomed.state == "timeout"
+    assert doomed.tokens_out == had and not doomed.pages
+    assert eng.page_accounting_ok()
+    assert behind.state == "running" and behind.slot == 0
+    eng.run()
+    assert doomed.tokens_out == had
+    assert behind.state == "finished"
+    want, _ = decoder_lm.reference_decode(model.params, model.cfg, prompt, 6)
+    assert behind.tokens_out == want
+    assert eng.pool.num_used == 0 and eng.page_accounting_ok()
+    eng.close()
+
+
+@pytest.mark.parametrize("kind", ["transient", "fatal"])
+def test_a_fault_at_the_launch_with_a_dispatch_in_flight(kind, rng):
+    """Injected at ``serving.decode`` while the dispatch before is unread.
+    Transient: the launch is retried, every stream whole and nothing
+    doubled. Fatal: the unread dispatch's tokens are handed over, then the
+    batch FAILS once, and the queue is served after."""
+    from paddle_tpu.reliability import FaultPlan, faults
+
+    model = get_model()
+    stream = [(list(rng.randint(0, 64, n)), m)
+              for n, m in ((7, 9), (12, 6), (5, 8))]
+    want = [decoder_lm.reference_decode(model.params, model.cfg, p, m)[0]
+            for p, m in stream]
+    eng = serving.ServingEngine(model, small_config(slots=2,
+                                                    decode_retries=1))
+    reqs = [eng.submit(p, m) for p, m in stream]
+    eng.step()
+    eng.step()
+    assert eng._unread is not None
+    assert [len(r.tokens_out) for r in reqs] == [2, 2, 0]
+    before = _ahead_counts()
+    with FaultPlan([faults.FaultSpec("serving.decode", kind, at=1)]):
+        done = eng.step()
+    _, _, retries, absorbed = _ahead_counts() - before
+    if kind == "transient":
+        assert (retries, absorbed) == (1, 0) and done == []
+        assert [len(r.tokens_out) for r in reqs] == [3, 3, 0]
+    else:
+        assert (retries, absorbed) == (0, 1)
+        assert done == reqs[:2] and all(r.state == "failed" for r in done)
+        # no token lost: the dispatch in flight was read before the batch
+        # failed
+        assert [r.tokens_out for r in done] == [w[:3] for w in want[:2]]
+        assert eng._unread is None and eng.page_accounting_ok()
+    eng.run()
+    eng.close()
+    served = reqs if kind == "transient" else reqs[2:]
+    assert all(r.state == "finished" for r in served)
+    assert [r.tokens_out for r in served] == want[-len(served):]
+    assert eng.pool.num_used == 0 and eng.health()["status"] == "ok"
+
+
+def test_a_failure_that_surfaces_at_the_read_abandons_the_one_launched_ahead(
+        rng):
+    """Dispatch N fails at host materialization, after N+1 was launched on
+    its outputs: N+1 is abandoned unread and the state goes back to what N
+    was launched on. The cache N was given is donated and gone, so the
+    ladder does what it always did there: no retry, the batch FAILS with
+    the tokens it had, the cache is made anew and the queue is served."""
+    model = get_model()
+    stream = [(list(rng.randint(0, 64, n)), m) for n, m in ((9, 10), (6, 5))]
+    want = [decoder_lm.reference_decode(model.params, model.cfg, p, m)[0]
+            for p, m in stream]
+    eng = serving.ServingEngine(model, small_config(slots=1))
+    reqs = [eng.submit(p, m) for p, m in stream]
+    eng.step()
+    eng.step()
+    real_sync, calls = eng._sync, []
+
+    def sync_fails_once(d):
+        calls.append(d)
+        if len(calls) == 1:
+            raise RuntimeError("UNAVAILABLE: injected at the sync")
+        return real_sync(d)
+
+    eng._sync = sync_fails_once
+    before = _ahead_counts()
+    assert eng.step() == reqs[:1]
+    assert list(_ahead_counts() - before) == [1, 0, 0, 1]   # no retry
+    assert reqs[0].state == "failed" and reqs[0].tokens_out == want[0][:2]
+    assert eng._unread is None and eng.page_accounting_ok()
+    eng.run()
+    eng.close()
+    assert len(calls) > 1 and reqs[1].state == "finished"
+    assert reqs[1].tokens_out == want[1] and eng.health()["status"] == "ok"
+
+
+def test_nothing_is_launched_ahead_while_a_slot_drafts(rng):
+    """A verify window is built on the host from the tokens read so far:
+    while a speculative request runs, every dispatch is read in the cycle
+    that launches it. Once it has retired the plain request beside it is
+    launched ahead again; both streams are the non-speculative ones."""
+    motif = list(rng.randint(0, 64, 3))
+    stream = [(motif * 3, 10, 3), (list(rng.randint(0, 64, 9)), 30, 0)]
+
+    def drive(speculate):
+        eng = serving.ServingEngine(get_model(), small_config(slots=2))
+        reqs = [eng.submit(p, m, speculation=k if speculate else 0)
+                for p, m, k in stream]
+        during = None
+        base = _ahead_counts()
+        while not eng.scheduler.idle():
+            eng.step()
+            if during is None and reqs[0].state == "finished":
+                during = (_ahead_counts() - base)[0]
+        after = (_ahead_counts() - base)[0]
+        eng.close()
+        return [r.tokens_out for r in reqs], during, after
+
+    spec, during, after = drive(True)
+    plain, _, plain_ahead = drive(False)
+    assert spec == plain
+    assert during == 0 and after > 0 and plain_ahead > after
+
+
+@pytest.mark.parametrize("how", ["run", "drain", "close", "exit"])
+def test_no_dispatch_is_left_unread_and_no_token_lost(how, rng):
+    model = get_model()
+    prompt = list(rng.randint(0, 64, 9))
+    want, _ = decoder_lm.reference_decode(model.params, model.cfg, prompt, 9)
+    eng = serving.ServingEngine(model, small_config())
+    req = eng.submit(prompt, 9)
+    if how == "run":
+        assert eng.run(max_steps=3) == []
+    else:
+        for _ in range(3):
+            eng.step()
+        assert eng._unread is not None and len(req.tokens_out) == 3
+        if how == "drain":
+            assert eng.drain(timeout_s=0.0)["timed_out"] == 1
+        elif how == "close":
+            eng.close()
+        else:
+            with eng:
+                pass
+    # the prefill's token and all three dispatches'
+    assert eng._unread is None and req.tokens_out == want[:4]
+    if how == "run":
+        eng.run()
+        assert req.tokens_out == want
+    assert eng.page_accounting_ok()
+    eng.close()

@@ -45,14 +45,18 @@ def toy_cfg(**over):
     return st.SmallThinkerConfig(**kw)
 
 
-@pytest.fixture(scope="module")
-def toy():
+def toy_model():
     """Seeded weights, scaled up from the 0.02 a real width wants so that
     attention and routing are decisive at d = 64."""
     cfg = toy_cfg()
     params = jax.tree_util.tree_map(
         lambda a: a * 6.0 if a.ndim > 1 else a, st.init_params(cfg, 3))
     return st.SmallThinkerLM(cfg, params=params)
+
+
+@pytest.fixture(scope="module")
+def toy():
+    return toy_model()
 
 
 def reference_rows(model, seq, rows):
