@@ -36,7 +36,6 @@ from typing import Any, Dict, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 
 from ..ops import attention_ops, moe_ops
 from . import kimi_k2_reference as _ref
@@ -203,13 +202,8 @@ def _feed_forward(cfg, lp, x, row_valid):
         held=(None if len(cfg.experts_held) == cfg.n_expert
               else cfg.experts_held), row_valid=row_valid,
         activation=jax.nn.silu)
-    # the share's load: pairs of live rows routed to an expert held here
-    here = np.zeros((cfg.n_expert,), bool)
-    here[list(cfg.experts_held)] = True
-    on_share = jnp.asarray(here)[idx]
-    if row_valid is not None:
-        on_share = on_share & row_valid[:, None]
-    stats = dict(stats, held_pairs=jnp.sum(on_share).astype(jnp.int32))
+    stats = dict(stats, held_pairs=moe_ops.held_pairs(
+        idx, cfg.experts_held, cfg.n_expert, row_valid))
     with jax.named_scope("moe/shared"):
         shared = _swiglu(u, lp["sg"], lp["su"], lp["sd"])
     return x + (y + shared.astype(jnp.float32)).astype(x.dtype), stats

@@ -38,7 +38,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-__all__ = ["route_topk", "route_sigmoid_topk", "expert_layer"]
+__all__ = ["route_topk", "route_sigmoid_topk", "expert_layer", "held_pairs"]
 
 
 def route_topk(h, wr, top_k: int) -> Tuple[jnp.ndarray, jnp.ndarray]:
@@ -65,6 +65,18 @@ def route_sigmoid_topk(h, wr, bias, top_k: int, scale: float = 1.0
         chosen = jnp.take_along_axis(s, idx, axis=-1)
         w = chosen / (jnp.sum(chosen, axis=-1, keepdims=True) + 1e-20)
         return idx.astype(jnp.int32), w * scale
+
+
+def held_pairs(idx, held: Sequence[int], n_expert: int, row_valid=None):
+    """The share's load: how many of the pairs ``idx`` [N, k] of the rows
+    marked ``row_valid`` [N] are routed to an expert in ``held`` (global
+    ids of ``n_expert``). An int32 scalar."""
+    here = np.zeros((n_expert,), bool)
+    here[list(held)] = True
+    on_share = jnp.asarray(here)[idx]
+    if row_valid is not None:
+        on_share = on_share & row_valid[:, None]
+    return jnp.sum(on_share).astype(jnp.int32)
 
 
 def _share_rows(n_pairs: int, e_held: int, n_expert: int) -> int:

@@ -301,15 +301,40 @@ class ServingConfig:
         return tune.resolve_speculation_k(self.slots)
 
 
+def _query_groups(mcfg, layer_groups):
+    """``(n_kv, {group: G})`` of a model config: the KV heads, and for each
+    cache group the query heads a KV head of its layers. ``n_head`` is one
+    number (every layer; with no ``n_kv_head``, G = 1) or one a layer; the
+    layers of a group must agree, since a group's decode attention is one
+    kernel shape."""
+    heads = mcfg.n_head
+    if isinstance(heads, int):
+        heads = (heads,) * mcfg.n_layer
+    n_kv = getattr(mcfg, "n_kv_head", heads[0])
+    q_per_kv = {}
+    for name, layers, _window in layer_groups:
+        of_group = sorted({int(heads[l]) for l in layers})
+        if len(of_group) != 1 or of_group[0] % n_kv:
+            raise ValueError(
+                "cache group %r: its layers have %s query heads over %d KV "
+                "heads; a group has one number, a multiple of the KV heads"
+                % (name, of_group, n_kv))
+        q_per_kv[name] = of_group[0] // n_kv
+    return n_kv, q_per_kv
+
+
 class ServingEngine:
     """Drives a model implementing the serving contract:
 
     * ``model.cfg`` — exposes ``n_layer``/``n_head``/``d_head``/``max_seq``
-      /``dtype`` (models.decoder_lm.DecoderConfig shape); optionally
-      ``n_kv_head`` (the heads of K and V, which size the cache; default
-      ``n_head``: the queries then are not grouped) and ``cache_groups``,
-      a list of ``(name, layers, window)`` (default: one group of every
-      layer that keeps every position; see serving.kv_cache), or
+      /``dtype`` (models.decoder_lm.DecoderConfig shape); ``n_head`` counts
+      the QUERY heads, one number or one a layer; optionally ``n_kv_head``
+      (the heads of K and V, the same in every layer, which size the
+      cache; default ``n_head``: the queries then are not grouped) and
+      ``cache_groups``, a list of ``(name, layers, window)`` (default: one
+      group of every layer that keeps every position; see
+      serving.kv_cache). The layers of a group have one ``n_head``, so
+      the query heads a KV head are the GROUP's; or
       ``latent_row``, ``(rank, rope)``: the model keeps ONE ``[c | kr]``
       row a token a layer (latent attention) and the cache is a
       :class:`~.kv_cache.LatentPagedCache` sized from it,
@@ -324,7 +349,8 @@ class ServingEngine:
       with ``stats`` a dict of small int arrays a step; the engine feeds
       ``moe_experts_touched``, ``moe_max_expert_rows`` and
       ``moe_held_pairs`` [n_layer] to the ``serving/*`` histograms of
-      those names.
+      those names, and ``attn_rows_read.<group>`` (what the cache's
+      ``rows_read`` gives) to ``serving/attn_rows_read.<group>``.
 
     Over a cache of more than one group, and over a latent cache, the
     engine refuses, at construction, what cannot work there: speculative
@@ -343,10 +369,9 @@ class ServingEngine:
                 "model max_seq %d < serving max_seq %d (position table too "
                 "small for the context budget)" % (mcfg.max_seq, self.cfg.max_seq))
         self.params = params if params is not None else model.params
-        n_kv = getattr(mcfg, "n_kv_head", mcfg.n_head)
-        q_per_kv = mcfg.n_head // n_kv
         layer_groups = getattr(mcfg, "cache_groups", None) or [
             ("global", tuple(range(mcfg.n_layer)), None)]
+        n_kv, q_per_kv = _query_groups(mcfg, layer_groups)
         latent = getattr(mcfg, "latent_row", None)
         if len(layer_groups) > 1 or latent:
             self._refuse_over_groups(layer_groups, latent)
@@ -1388,12 +1413,11 @@ class ServingEngine:
         # tokens/steps > 1 is exactly the speculative win
         _sm.DECODE_STEPS.inc(1 if d.dlen is not None else steps)
         if stats is not None:
-            for name, hist in (
-                    ("moe_experts_touched", _sm.MOE_EXPERTS_TOUCHED),
-                    ("moe_max_expert_rows", _sm.MOE_MAX_EXPERT_ROWS),
-                    ("moe_held_pairs", _sm.MOE_HELD_PAIRS)):
-                for x in stats.get(name, np.zeros(0)).reshape(-1):
-                    hist.observe(float(x))
+            for name, xs in stats.items():
+                hist = _sm.model_stat(name)
+                if hist is not None:
+                    for x in xs.reshape(-1):
+                        hist.observe(float(x))
         if d.dlen is not None:
             _sm.SPEC_PROPOSED.inc(proposed)
             _sm.SPEC_ACCEPTED.inc(accepted)

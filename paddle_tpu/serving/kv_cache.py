@@ -39,9 +39,12 @@ rotation, so the order of a ring's rows does not matter to the softmax and
 attention needs only ``min(ctx, W)`` as its length. A model with one kind
 of layer (GPT-2) is ONE global group: the same class, the same code.
 
-``n_head`` counts the heads of K and V. A model with grouped queries hands
-``decode_attention`` a ``q`` of ``G * n_head`` heads (query head n reads KV
-head ``n // G``); ``q_per_kv`` = G tells the kernel's gate.
+``n_head`` counts the heads of K and V, the same in every group. A model
+with grouped queries hands ``decode_attention`` a ``q`` of ``G * n_head``
+heads (query head n reads KV head ``n // G``), and G belongs to the GROUP:
+layers of different kinds may put different numbers of query heads over the
+same KV heads. ``q_per_kv`` names G, one value for every group or a mapping
+by group name, and the kernel's gate is asked for each.
 
 A LATENT group (:class:`LatentPagedCache`): a model with latent (MLA)
 attention keeps ONE row a token a layer, ``[c | kr]`` (the compressed KV
@@ -58,7 +61,8 @@ layouts, which is what makes the gathered contexts bit-identical.
 
 from __future__ import annotations
 
-from typing import Dict, List, NamedTuple, Optional, Sequence, Tuple
+from typing import (Dict, List, Mapping, NamedTuple, Optional, Sequence,
+                    Tuple, Union)
 
 import jax.numpy as jnp
 import numpy as np
@@ -137,13 +141,12 @@ class PagedKVCache(_KVCacheBase):
                  max_ctx: int, page_size: int, num_pages: int,
                  dtype=jnp.float32,
                  groups: Optional[Sequence[CacheGroup]] = None,
-                 q_per_kv: int = 1):
+                 q_per_kv: Union[int, Mapping[str, int]] = 1):
         super().__init__(n_layer, n_head, d_head, slots, max_ctx, dtype)
         if max_ctx % page_size != 0:
             raise ValueError("max_ctx=%d must be a multiple of page_size=%d"
                              % (max_ctx, page_size))
         self.page_size = int(page_size)
-        self.q_per_kv = int(q_per_kv)
         self.row_width = self.n_head * self.d_head  # lanes of one KV row
         if groups is None:
             groups = [CacheGroup("global", tuple(range(self.n_layer)), None,
@@ -168,6 +171,15 @@ class PagedKVCache(_KVCacheBase):
             raise ValueError("the cache groups must cover layers 0..%d once "
                              "each, got %s" % (self.n_layer - 1,
                                                sorted(self._where)))
+        # query heads a KV head, by group name
+        if not isinstance(q_per_kv, Mapping):
+            q_per_kv = {g.name: q_per_kv for g in self.groups}
+        if set(q_per_kv) != {g.name for g in self.groups}:
+            raise ValueError("q_per_kv names %s; the cache groups are %s"
+                             % (sorted(q_per_kv),
+                                [g.name for g in self.groups]))
+        self.q_per_kv: Dict[str, int] = {
+            g.name: int(q_per_kv[g.name]) for g in self.groups}
         # the first group's geometry under the names a one-group cache
         # always had
         self.num_pages = self.groups[0].num_pages
@@ -329,12 +341,29 @@ class PagedKVCache(_KVCacheBase):
         return mode, None
 
     def _kernel_gate(self, interpret: bool) -> Optional[str]:
-        """The kernel's static gate over this cache's geometry."""
+        """The kernel's static gate over this cache's geometry, asked for
+        each group's query heads a KV head: the first rule that excludes
+        one keeps every group on the gather path."""
         from ..ops.pallas_kernels.paged_attention import paged_attention_gate
 
-        return paged_attention_gate(
-            self.dtype, self.n_head, self.d_head, self.page_size,
-            interpret=interpret, q_per_kv=self.q_per_kv)
+        for g in sorted(set(self.q_per_kv.values())):
+            why_not = paged_attention_gate(
+                self.dtype, self.n_head, self.d_head, self.page_size,
+                interpret=interpret, q_per_kv=g)
+            if why_not is not None:
+                return why_not
+        return None
+
+    def rows_read(self, ctx_len, active) -> Dict[str, jnp.ndarray]:
+        """``{"attn_rows_read.<group>": rows}``: the context rows ONE layer
+        of each group reads in a decode step at ``ctx_len`` [B], summed
+        over the slots (the lengths :meth:`decode_attention` attends over:
+        0 for a slot that is not ``active``). A model hands them back among
+        its decode ``stats`` for ``serving/attn_rows_read.<group>``."""
+        live = _live_len(ctx_len, active)
+        return {"attn_rows_read." + g.name:
+                jnp.sum(self._group_len(gi, live)).astype(jnp.int32)
+                for gi, g in enumerate(self.groups)}
 
     def decode_attention(self, state: Cache, layer: int, q, ctx_len,
                          active, sm_scale: float = 1.0) -> jnp.ndarray:
@@ -541,7 +570,7 @@ class Int8PagedKVCache(PagedKVCache):
                  max_ctx: int, page_size: int, num_pages: int,
                  k_scale: float, v_scale: float, dtype=jnp.float32,
                  groups: Optional[Sequence[CacheGroup]] = None,
-                 q_per_kv: int = 1):
+                 q_per_kv: Union[int, Mapping[str, int]] = 1):
         super().__init__(n_layer, n_head, d_head, slots, max_ctx,
                          page_size, num_pages, dtype, groups=groups,
                          q_per_kv=q_per_kv)

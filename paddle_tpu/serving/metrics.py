@@ -24,7 +24,7 @@ __all__ = [
     "SPEC_PROPOSED", "SPEC_ACCEPTED", "SPEC_REJECTED", "SPEC_DRAFTS",
     "SPEC_VERIFY_DISPATCHES", "SPEC_ACCEPT_RATE",
     "MOE_EXPERTS_TOUCHED", "MOE_MAX_EXPERT_ROWS", "MOE_HELD_PAIRS",
-    "pages_used",
+    "pages_used", "attn_rows_read", "model_stat",
 ]
 
 REQUESTS_SUBMITTED = _mx.counter(
@@ -151,9 +151,36 @@ MOE_HELD_PAIRS = _mx.histogram(
          "observation a layer a decode step: the load of a share of a "
          "wider expert-parallel deployment")
 
+# a model's decode ``stats`` by name (:func:`model_stat`)
+_MODEL_STATS = {"moe_experts_touched": MOE_EXPERTS_TOUCHED,
+                "moe_max_expert_rows": MOE_MAX_EXPERT_ROWS,
+                "moe_held_pairs": MOE_HELD_PAIRS}
+
 
 def pages_used(group: str):
     """``serving/pages_used.<group>``: pages a cache group has allocated
     (a gauge a group; get-or-create, so engines of one process share it)."""
     return _mx.gauge("serving/pages_used.%s" % group,
                      help="KV-cache pages allocated in cache group %r" % group)
+
+
+def attn_rows_read(group: str):
+    """``serving/attn_rows_read.<group>``: context rows one layer of a
+    cache group read in a decode step, summed over the live slots (a
+    histogram a group, one observation a step; get-or-create)."""
+    return _mx.histogram(
+        "serving/attn_rows_read.%s" % group,
+        help="context rows a layer of cache group %r read in a decode "
+             "step, over the live slots (a model whose decode returns "
+             "the cache's rows_read)" % group)
+
+
+def model_stat(name: str):
+    """The histogram the engine feeds a model's decode ``stats[name]`` to
+    (an observation a value a step), or None for a name it does not know:
+    the three ``moe_*`` above and ``attn_rows_read.<group>``, looked up
+    once a name."""
+    hist = _MODEL_STATS.get(name)
+    if hist is None and name.startswith("attn_rows_read."):
+        hist = _MODEL_STATS[name] = attn_rows_read(name.partition(".")[2])
+    return hist

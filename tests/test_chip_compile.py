@@ -670,3 +670,114 @@ def test_latent_decoder_decode_step(chip, monkeypatch):
     aliased = {int(p) for p in re.findall(
         r"\((\d+), \{\}, (?:may|must)-alias\)", text.split("\n", 1)[0])}
     assert n_params in aliased           # "c" before "pt" in key order
+
+
+# -- the decoder with query heads by layer type (laguna-s-ep2-serve) -------------
+
+MIXED_SERVE = dict(slots=16, page_size=16, max_seq=16384, global_pages=9216,
+                   window_pages=512, vocab=50176)
+
+
+@pytest.mark.parametrize("g,n_layer,pages,pps,rows", [
+    (6, 2, 9216, 1024, 8), (9, 3, 512, 32, 16)])
+def test_mixed_query_groups_paged_kernel_at_the_served_geometry(
+        chip, g, n_layer, pages, pps, rows):
+    """Row width 1,024 (8 KV heads of 128), page 16, bf16, each cache
+    group's own pool and query heads a KV head: 6 over the full layers'
+    pool, 9 over the rings'. The kernel's result is ``[slots, G padded to
+    whole sublanes, row width]``, the shape by which a trace tells the two
+    groups' calls apart (``grid/readers/mixed_gqa.py``)."""
+    assert pa.paged_attention_gate(jnp.bfloat16, 8, 128, 16,
+                                   q_per_kv=g) is None
+    pool = ((n_layer, pages * 16, 1024), jnp.bfloat16)
+    text = compiled_text(
+        chip,
+        functools.partial(pa.paged_decode_attention, page_size=16, layer=1,
+                          sm_scale=128 ** -0.5),
+        ((16, 8 * g, 128), jnp.bfloat16), pool, pool,
+        ((16, pps), jnp.int32), ((16,), jnp.int32))
+    kernel, = [ln for ln in text.split("\n") if "tpu_custom_call" in ln]
+    assert kernel.strip().startswith("%paged_attention")
+    assert "bf16[16,%d,1024]" % rows in kernel.split(" custom-call(")[0]
+
+
+def _mixed_case(chip):
+    """The decode step of the decoder with query heads by layer type at
+    its published widths, as one chip of two holds it (128 of 256 experts,
+    50,176 rows of the vocabulary): layers full (dense), sliding, sliding,
+    sliding, full over the cell's two pools."""
+    from paddle_tpu.models import laguna as lg
+    from paddle_tpu.serving.kv_cache import CacheGroup, PagedKVCache
+
+    g = MIXED_SERVE
+    rope = {lg.FULL: {"rope_theta": 500000, "rope_type": "yarn",
+                      "factor": 128, "beta_slow": 1, "beta_fast": 32,
+                      "original_max_position_embeddings": 8192,
+                      "attention_factor": 1.4852030263919618,
+                      "partial_rotary_factor": 0.5},
+            lg.SLIDING: {"rope_type": "default", "rope_theta": 10000,
+                         "partial_rotary_factor": 1}}
+    cfg = lg.LagunaConfig(
+        vocab_size=g["vocab"], n_layer=5, d_model=3072,
+        n_head=[48, 72, 72, 72, 48], n_kv_head=8, d_head=128,
+        layer_types=[lg.FULL] + [lg.SLIDING] * 3 + [lg.FULL], window=512,
+        rope=rope, d_dense=12288, dense_layers=[0], n_expert=256, top_k=10,
+        d_expert=1024, d_shared=1024, routed_scale=2.5, max_seq=g["max_seq"],
+        dtype="bfloat16", experts_held=tuple(range(128)))
+    model = lg.LagunaLM(cfg, params={})
+
+    def sds(x):
+        return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=chip)
+
+    params = jax.tree_util.tree_map(
+        sds, jax.eval_shape(lambda: lg.init_params(cfg, 0)))
+    groups = [CacheGroup(n, l, w, g[n + "_pages"])
+              for n, l, w in cfg.cache_groups]
+    ops = PagedKVCache(5, 8, 128, g["slots"], g["max_seq"], g["page_size"],
+                       g["global_pages"], dtype=cfg.dtype, groups=groups,
+                       q_per_kv={"global": 6, "window": 9})
+    cache = jax.tree_util.tree_map(sds, jax.eval_shape(ops.init_state))
+    ints = jax.ShapeDtypeStruct((16,), jnp.int32, sharding=chip)
+    flags = jax.ShapeDtypeStruct((16,), jnp.bool_, sharding=chip)
+
+    def chunk(params, cache, lengths, tokens, active):
+        logits, cache, stats = model.decode(params, cache, ops, tokens,
+                                            lengths, active)
+        return cache, jnp.argmax(logits, -1), stats
+
+    return chunk, (params, cache, ints, ints, flags), ops
+
+
+def test_mixed_query_groups_decoder_decode_step(chip, monkeypatch):
+    """The decode step runs the paged kernel once a layer, at the full
+    layers' query shape twice and at the window layers' three times, and
+    the compiler's grouped matmul three times an expert layer (inside the
+    share's loop); it does not copy, slice or transpose a pool of either
+    group, and every pool is aliased from input to output."""
+    monkeypatch.setattr(attention_ops, "paged_kernel_mode",
+                        lambda: "compiled")
+    monkeypatch.setattr(attention_ops, "_on_tpu", lambda: True)
+    fn, args, ops = _mixed_case(chip)
+    assert ops.kernel_mode() == ("compiled", None)
+    text = jax.jit(fn, donate_argnums=(1,)).lower(*args).compile().as_text()
+    kernels = [ln.split(" custom-call(")[0] for ln in text.split("\n")
+               if "tpu_custom_call" in ln and "%paged_attention" in ln]
+    assert sum("bf16[16,8,1024]" in k for k in kernels) == 2
+    assert sum("bf16[16,16,1024]" in k for k in kernels) == 3
+    assert len(re.findall(r"= \S+ custom-call\([^\n]*ragged-dot", text)) \
+        >= 3 * 4
+    instructions = list(_instructions(text))
+    types = {name: rtype for name, rtype, _, _ in instructions}
+    for grp in ops.groups:
+        rows = grp.num_pages * ops.page_size
+        moved = [(op, rtype) for _, rtype, op, operands in instructions
+                 if op in ("copy", "copy-start", "slice", "dynamic-slice",
+                           "transpose")
+                 and any(_has_dim(t, rows) for t in
+                         [rtype] + [types.get(o, "") for o in operands])]
+        assert moved == [], (grp.name, moved)
+    n_params = len(jax.tree_util.tree_leaves(args[0]))
+    aliased = {int(p) for p in re.findall(
+        r"\((\d+), \{\}, (?:may|must)-alias\)", text.split("\n", 1)[0])}
+    # "k" "k.window" "pt" "pt.window" "v" "v.window" in key order
+    assert {n_params, n_params + 1, n_params + 4, n_params + 5} <= aliased
