@@ -32,8 +32,9 @@ Design:
 - per-head reductions over the flat row ride the MXU: ``(k * q) @ seg``
   sums each head's D lanes (``seg`` is the 0/1 head-membership matrix
   ``[H*D, 128]``), and ``p @ seg.T`` spreads each head's probability back
-  over its lanes. Both at full f32 precision, so the kernel agrees with
-  the gather path to float round-off;
+  over its lanes. That fold widens the wave to float32 and multiplies at
+  full f32 precision, so with one query head a KV head the kernel agrees
+  with the gather path to float round-off whatever the pool's type;
 - GROUPED QUERIES: ``q`` may carry ``G`` query heads for each KV head
   (query head n reads KV head ``n // G``). It arrives in the kernel as ``[B,
   G, H*D]`` (row g holds, for every KV head, its g-th query head), each
@@ -42,24 +43,39 @@ Design:
   rows]`` product a KV head for the scores and one ``[G, rows] x [rows, D]``
   for the weighted sum, over the head's own lane slice of the row, which on
   the chip has to be whole lane tiles (``D % 128 == 0``; the gate says so).
+  Both products take the pool's rows in the POOL's type and accumulate in
+  float32: a bf16 pool is not widened (bf16 x bf16 products are exact in
+  the accumulator, so the scores are the float32 ones up to the order of
+  a sum), its probabilities go in as their bf16 high and low halves (16
+  bits), and scale, mask and the softmax state ``(m, l, acc)`` are float32;
+  a float32 pool multiplies at full precision. So with G > 1 "to float
+  round-off" holds for float32 pools only: a bf16 pool agrees with a
+  float32 reference over the same bf16 values to 2**-16 of the largest V.
   G = 1 keeps the head-membership matmuls above, which take any ``H*D %
   128 == 0`` (GPT-2 small's heads of 64). One kernel, one DMA loop, the
-  fold chosen by the geometry;
+  fold chosen by the geometry and its precision by the pool's type;
 - grid is ``(slots,)``; the page table (flattened) and per-slot ``ctx_len``
   ride in SMEM via ``PrefetchScalarGridSpec`` scalar prefetch, so page
   addresses are known before the body runs;
 - per slot, pages stream in waves of ``block_pages`` (the autotunable
-  knob, table kernel key ``paged_attention``): each wave starts
-  ``2 * block_pages`` row-range DMAs back-to-back (K and V per page), waits
-  once, then folds the wave into the online-softmax state ``(m, l, acc)``;
+  knob, table kernel key ``paged_attention``) into TWO K and two V
+  buffers: a wave is ``2 * block_pages`` row-range DMAs started
+  back-to-back (K and V per page), wave 0's before the loop and wave ``w +
+  1``'s before wave ``w`` is waited for and folded into the online-softmax
+  state ``(m, l, acc)``, so the copy of one wave hides behind the
+  arithmetic of the one before (what a wave costs on the chip, fold and
+  double buffer each alone and together: PERF.md, PR 35);
 - the ragged bound: a slot of ``ctx_len`` 0 (one that holds no request:
   serving.kv_cache hands the kernel LIVE lengths) writes zeros and ends its
   grid step there; elsewhere only the waves that hold a position below
-  ``ctx_len`` run, a page entirely at/after ``ctx_len`` skips its DMA, and
-  the position mask uses attention_ops.neg_inf — the SAME masking constant
-  as the gather path — with K and V rows beyond ``ctx_len`` zeroed before
-  use, so stale rows (retired requests, unreserved pages, whatever the
-  scratch last held) contribute exactly 0.0;
+  ``ctx_len`` run, a page entirely at/after ``ctx_len`` starts no DMA and
+  is not waited for, and the position mask uses attention_ops.neg_inf — the
+  SAME masking constant as the gather path — with the rows beyond
+  ``ctx_len`` zeroed before use where a product could see them (K and V in
+  the G = 1 fold; V in the grouped one, whose scores of such rows are
+  REPLACED by the mask), so stale rows (retired requests, unreserved
+  pages, whatever either buffer last held, Inf and NaN included)
+  contribute exactly 0.0;
 - page ids from the table are clamped to the pool, so a corrupt table row
   degrades to wrong-but-safe reads, never an OOB DMA.
 
@@ -93,7 +109,7 @@ __all__ = [
 
 _LANES = 128
 _MAX_ROW_WIDTH = 4096  # H*D: the two [H*D, 128] f32 head maps stay in VMEM
-_VMEM_WAVE_BUDGET = 2 * 1024 * 1024  # K+V scratch bytes one wave may hold
+_VMEM_WAVE_BUDGET = 2 * 1024 * 1024  # bytes the K and V wave buffers may hold
 _HIGHEST = jax.lax.Precision.HIGHEST
 
 
@@ -132,15 +148,19 @@ def paged_attention_supported(dtype, n_head: int, d_head: int,
                                 interpret, q_per_kv) is None
 
 
+def _wave_fits(page_size: int, hd: int, itemsize: int) -> int:
+    """Most pages a wave may hold: what the kernel keeps in fast memory is
+    two K and two V buffers of a wave each, in the pool's type."""
+    return max(1, _VMEM_WAVE_BUDGET // (4 * page_size * hd * itemsize))
+
+
 def _default_block_pages(page_size: int, pages_per_slot: int, hd: int,
                          itemsize: int = 4) -> int:
-    """Largest power-of-two pages-per-wave whose K+V VMEM scratch fits the
-    wave budget — the untuned fallback the autotune sweep measures
-    against."""
+    """Largest power-of-two pages-per-wave, up to the slot's pages, whose
+    buffers fit the wave budget — the untuned fallback the autotune sweep
+    measures against."""
     bp = 1
-    while (bp * 2 <= pages_per_slot
-           and 2 * (bp * 2) * page_size * hd * itemsize
-           <= _VMEM_WAVE_BUDGET):
+    while bp * 2 <= min(pages_per_slot, _wave_fits(page_size, hd, itemsize)):
         bp *= 2
     return bp
 
@@ -150,15 +170,14 @@ def _block_pages(block, page_size: int, pages_per_slot: int, max_ctx: int,
     """Pages per DMA wave. ``block=None`` (the entry point's default)
     consults the tuned config table (paddle_tpu.tune: kernel
     ``paged_attention``, bucketed by (max_ctx, H*D) + device_kind, with the
-    shipped v5e seed) and falls back to the analytic VMEM-budget default —
+    shipped v5e sweep) and falls back to the analytic VMEM-budget default —
     an explicit integer skips the table, which keeps the autotuner's own
     sweep from looping through the table it is writing. Either way the
-    result is clamped to the slot's page count and to the widest wave whose
-    f32 working tile ``[block*page_size, H*D]`` stays within the wave
-    budget: a table row is bucketed coarsely and must not hand a wide
-    model more fast memory than the chip compiler grants. The lookup never
-    raises; a corrupt table logs once inside tune.table and lands here as
-    the default."""
+    result is clamped to the slot's page count and to :func:`_wave_fits`:
+    a table row is bucketed coarsely and must not hand a wide model more
+    fast memory than the chip compiler grants. The lookup never raises; a
+    corrupt table logs once inside tune.table and lands here as the
+    default."""
     if block is None:
         block = _default_block_pages(page_size, pages_per_slot, hd, itemsize)
         try:
@@ -170,18 +189,8 @@ def _block_pages(block, page_size: int, pages_per_slot: int, max_ctx: int,
                 block = int(cfg["block_pages"])
         except Exception:
             pass
-    fits = _VMEM_WAVE_BUDGET // (page_size * hd * 4)
-    return max(1, min(int(block), pages_per_slot, fits))
-
-
-def _page_dma(pool_ref, scr_ref, sem, layer, row, slot_row, ps):
-    """Async copy of one page (``ps`` contiguous [H*D] rows of ``layer``)
-    from the HBM pool to VMEM scratch."""
-    return pltpu.make_async_copy(
-        pool_ref.at[layer, pl.ds(row, ps)],
-        scr_ref.at[pl.ds(slot_row, ps)],
-        sem,
-    )
+    return max(1, min(int(block), pages_per_slot,
+                      _wave_fits(page_size, hd, itemsize)))
 
 
 def _paged_attn_kernel(pt_ref, len_ref, layer_ref, q_ref, seg_ref, segt_ref,
@@ -207,18 +216,50 @@ def _attend_slot(b, pt_ref, len_ref, layer_ref, q_ref, seg_ref, segt_ref,
                  k_hbm, v_hbm, o_ref, k_scr, v_scr, sems, *,
                  block_pages, page_size, pages_per_slot, num_pages,
                  sm_scale, mask_value, d_head, grouped):
-    """Slot ``b`` over its ``ctx_len`` >= 1 leading rows."""
+    """Slot ``b`` over its ``ctx_len`` >= 1 leading rows: the shared
+    double-buffered wave loop around the fold the geometry chose."""
     ps = page_size
     ctx = len_ref[b]
     layer = layer_ref[0]
-    q = q_ref[0].astype(jnp.float32)  # [G, HD] (G = 1 where not grouped)
-    seg = seg_ref[...]    # [HD, HP]: lane j belongs to head seg[j].argmax()
-    segt = segt_ref[...]  # [HP, HD]
-    hd, hp = seg.shape
+    hd = k_scr.shape[-1]
     rows = block_pages * ps
     n_kv = hd // d_head
-    gq = q.shape[0]
-    qs = q * sm_scale     # the one-state-a-head fold scales its query once
+    # the scores of a float32 pool at full precision; a bf16 pool's
+    # products are exact in the float32 accumulator as they are
+    precision = _HIGHEST if k_scr.dtype == jnp.float32 else None
+
+    live_pages = jnp.minimum((ctx + ps - 1) // ps, pages_per_slot)
+
+    def each_page(w, buf, act):
+        """``act`` on the K and the V copy of every page of wave ``w`` that
+        holds a row below ``ctx``, into (or out of) buffer ``buf``: a page
+        wholly at or past ``ctx`` is not in the loop. A table entry is
+        clamped: a corrupt one reads a wrong page, never out of bounds."""
+        def body(i, _):
+            page = jnp.clip(pt_ref[b * pages_per_slot + w * block_pages + i],
+                            0, num_pages - 1)
+            for kv, (pool, scr) in enumerate(((k_hbm, k_scr),
+                                              (v_hbm, v_scr))):
+                act(pltpu.make_async_copy(
+                    pool.at[layer, pl.ds(page * ps, ps)],
+                    scr.at[buf, pl.ds(i * ps, ps)], sems.at[kv, buf, i]))
+            return 0
+
+        jax.lax.fori_loop(
+            0, jnp.clip(live_pages - w * block_pages, 0, block_pages), body, 0)
+
+    def below_ctx(w, axis):
+        """Which of wave ``w``'s scratch rows, laid along ``axis`` of a 2-D
+        tile, hold a context position below ``ctx``. The others hold
+        whatever the buffer last held: a page that was never copied, the
+        rows past the length in the last page, the slot before."""
+        shape = (rows, 1) if axis == 0 else (1, rows)
+        return w * rows + jax.lax.broadcasted_iota(jnp.int32, shape,
+                                                   axis) < ctx
+
+    seg = seg_ref[...]    # [HD, HP]: lane j belongs to head seg[j].argmax()
+    segt = segt_ref[...]  # [HP, HD]
+    hp = seg.shape[1]
 
     def per_head(x):
         """[R, HD] -> [R, HP]: sum each head's lanes."""
@@ -234,56 +275,15 @@ def _attend_slot(b, pt_ref, len_ref, layer_ref, q_ref, seg_ref, segt_ref,
         """:func:`over_lanes` of one row, as a full-sublane matmul."""
         return over_lanes(jnp.broadcast_to(x, (8, hp)))[0:1]
 
-    def page_row(i, wave):
-        """Pool row offset of wave-local page ``i`` (clamped: a corrupt
-        table entry reads a wrong page, never out of bounds)."""
-        pidx = jnp.minimum(wave * block_pages + i, pages_per_slot - 1)
-        page = pt_ref[b * pages_per_slot + pidx]
-        return jnp.clip(page, 0, num_pages - 1) * ps
-
-    def page_valid(i, wave):
-        pidx = wave * block_pages + i
-        return (pidx < pages_per_slot) & (pidx * ps < ctx)
-
-    def wave_body(w, carry):
-        def start(i, _):
-            @pl.when(page_valid(i, w))
-            def _():
-                row = page_row(i, w)
-                _page_dma(k_hbm, k_scr, sems.at[0, i], layer, row, i * ps,
-                          ps).start()
-                _page_dma(v_hbm, v_scr, sems.at[1, i], layer, row, i * ps,
-                          ps).start()
-
-            return 0
-
-        jax.lax.fori_loop(0, block_pages, start, 0)
-
-        def wait(i, _):
-            @pl.when(page_valid(i, w))
-            def _():
-                row = page_row(i, w)
-                _page_dma(k_hbm, k_scr, sems.at[0, i], layer, row, i * ps,
-                          ps).wait()
-                _page_dma(v_hbm, v_scr, sems.at[1, i], layer, row, i * ps,
-                          ps).wait()
-
-            return 0
-
-        jax.lax.fori_loop(0, block_pages, wait, 0)
-
-        # absolute context positions of this wave's scratch rows, and the
-        # ragged validity mask (also covers never-DMA'd pages: their
-        # positions are >= ctx by construction)
-        pos = (w * rows
-               + jax.lax.broadcasted_iota(jnp.int32, (rows, 1), 0))  # [R,1]
-        valid = pos < ctx
-        # invalid rows hold whatever the scratch last held — zero them so
-        # the exactly-0 probabilities below cannot meet an Inf/NaN residue
-        kb = jnp.where(valid, k_scr[...].astype(jnp.float32), 0.0)  # [R,HD]
-        vb = jnp.where(valid, v_scr[...].astype(jnp.float32), 0.0)
-        if grouped:
-            return fold_grouped(w, kb, vb, carry)
+    def fold_per_lane(w, buf, carry):
+        """G = 1, any head width: one state a head over the flat row, the
+        per-head sums through the head-membership matrices, in float32 at
+        full precision whatever the pool's type."""
+        valid = below_ctx(w, 0)
+        # zeroed so the exactly-0 probabilities below cannot meet an
+        # Inf/NaN residue
+        kb = jnp.where(valid, k_scr[buf].astype(jnp.float32), 0.0)  # [R,HD]
+        vb = jnp.where(valid, v_scr[buf].astype(jnp.float32), 0.0)
         m, l, acc = carry
         s = jnp.where(valid, per_head(kb * qs), mask_value)
         m_new = jnp.maximum(m, jnp.max(s, axis=0, keepdims=True))  # [1,HP]
@@ -294,51 +294,83 @@ def _attend_slot(b, pt_ref, len_ref, layer_ref, q_ref, seg_ref, segt_ref,
                    + jnp.sum(over_lanes(p) * vb, axis=0, keepdims=True))
         return m_new, l_new, acc_new
 
-    def fold_grouped(w, kb, vb, carry):
+    def weighted_sum(p, v):
+        """``p @ v``, ``[G, R] x [R, D]``, into a float32 accumulator. A
+        float32 pool multiplies at full precision. A bf16 pool is given
+        ``p`` as its high and low halves in the pool's type: two MXU passes
+        over operands that are bf16 as they are, 16 bits of ``p`` (on the
+        chip two such products also run FASTER than one: PERF.md, PR 35)."""
+        if v.dtype == jnp.float32:
+            return jnp.dot(p, v, precision=_HIGHEST,
+                           preferred_element_type=jnp.float32)
+        hi = p.astype(v.dtype)
+        lo = (p - hi.astype(jnp.float32)).astype(v.dtype)
+        return (jnp.dot(hi, v, preferred_element_type=jnp.float32)
+                + jnp.dot(lo, v, preferred_element_type=jnp.float32))
+
+    def fold_grouped(w, buf, carry):
         """The wave folded into the G states of every KV head: scores
         ``[G, R]`` and weighted sums ``[G, D]`` on the MXU, a head's lanes
-        sliced out of the flat row."""
+        sliced out of the flat row. Both products take the pool's rows in
+        the POOL's type and accumulate in float32 (bf16 x bf16 products are
+        exact there); scale, mask and the softmax state ``(m, l, acc)`` are
+        float32."""
         ms, ls, accs = carry
-        pos = (w * rows
-               + jax.lax.broadcasted_iota(jnp.int32, (1, rows), 1))  # [1,R]
-        valid = pos < ctx
+        valid = below_ctx(w, 1)
+        kb = k_scr[buf]  # a stale row's scores are REPLACED by the mask...
+        # ...but its V would meet a probability of exactly 0.0: Inf/NaN * 0
+        vb = jnp.where(below_ctx(w, 0), v_scr[buf], 0)
         out_m, out_l, out_acc = [], [], []
         for h in range(n_kv):
             lanes = slice(h * d_head, (h + 1) * d_head)
             s = jax.lax.dot_general(
                 q[:, lanes], kb[:, lanes], (((1,), (1,)), ((), ())),
-                precision=_HIGHEST,
+                precision=precision,
                 preferred_element_type=jnp.float32) * sm_scale   # [G,R]
             s = jnp.where(valid, s, mask_value)
             m_new = jnp.maximum(ms[h], jnp.max(s, axis=1, keepdims=True))
             alpha = jnp.exp(ms[h] - m_new)                        # [G,1]
-            p = jnp.exp(s - m_new)
+            p = jnp.exp(s - m_new)  # masked rows underflow to exactly 0.0
             out_m.append(m_new)
             out_l.append(ls[h] * alpha + jnp.sum(p, axis=1, keepdims=True))
-            out_acc.append(accs[h] * alpha + jnp.dot(
-                p, vb[:, lanes], precision=_HIGHEST,
-                preferred_element_type=jnp.float32))              # [G,D]
+            out_acc.append(accs[h] * alpha
+                           + weighted_sum(p, vb[:, lanes]))       # [G,D]
         return tuple(out_m), tuple(out_l), tuple(out_acc)
 
-    n_waves = -(-pages_per_slot // block_pages)
-    live_waves = jnp.minimum((ctx + rows - 1) // rows, n_waves)
-    # ctx >= 1 here, so every state has folded a valid row: l >= 1
     if grouped:
+        q = q_ref[0]      # [G, HD] in the pool's type
+        gq = q.shape[0]
+        fold = fold_grouped
         init = (tuple(jnp.full((gq, 1), mask_value, jnp.float32)
                       for _ in range(n_kv)),
                 tuple(jnp.zeros((gq, 1), jnp.float32) for _ in range(n_kv)),
                 tuple(jnp.zeros((gq, d_head), jnp.float32)
                       for _ in range(n_kv)))
-        _, ls, accs = jax.lax.fori_loop(0, live_waves, wave_body, init)
-        out = jnp.concatenate(
-            [accs[h] / ls[h] for h in range(n_kv)], axis=1)
-        o_ref[0] = out.astype(o_ref.dtype)
-        return
-    m0 = jnp.full((1, hp), mask_value, jnp.float32)
-    l0 = jnp.zeros((1, hp), jnp.float32)
-    acc0 = jnp.zeros((1, hd), jnp.float32)
-    m, l, acc = jax.lax.fori_loop(0, live_waves, wave_body, (m0, l0, acc0))
-    out = acc / row_over_lanes(l)
+    else:
+        # the one-state-a-head fold scales its query once
+        qs = q_ref[0].astype(jnp.float32) * sm_scale  # [1, HD]
+        fold = fold_per_lane
+        init = (jnp.full((1, hp), mask_value, jnp.float32),
+                jnp.zeros((1, hp), jnp.float32),
+                jnp.zeros((1, hd), jnp.float32))
+
+    live_waves = (live_pages + block_pages - 1) // block_pages
+    each_page(0, 0, lambda c: c.start())
+
+    def wave(w, carry):
+        """Wave ``w + 1``'s pages (none past the last wave) are in flight
+        while wave ``w`` is folded."""
+        buf = w % 2
+        each_page(w + 1, 1 - buf, lambda c: c.start())
+        each_page(w, buf, lambda c: c.wait())
+        return fold(w, buf, carry)
+
+    # ctx >= 1 here, so every state has folded a valid row: l >= 1
+    _, l, acc = jax.lax.fori_loop(0, live_waves, wave, init)
+    if grouped:
+        out = jnp.concatenate([acc[h] / l[h] for h in range(n_kv)], axis=1)
+    else:
+        out = acc / row_over_lanes(l)
     o_ref[0] = out.astype(o_ref.dtype)
 
 
@@ -361,8 +393,9 @@ def paged_decode_attention(q, k_pages, v_pages, page_table, ctx_len, *,
     ``block_pages=None`` = tuned-table lookup with the
     analytic VMEM-budget fallback (see ``_block_pages``). Returns [B,Hq,D],
     matching ``gather_reference`` (the XLA gather + decode_attention path)
-    to float32 round-off on live rows and EXACTLY ignoring garbage beyond
-    ``ctx_len``. Compiled (``interpret=False``) it takes the shapes
+    on live rows to float32 round-off (G > 1 over a bf16 pool: to 2**-16 of
+    the largest V, against that path in float32 over the same bf16 values)
+    and EXACTLY ignoring garbage beyond ``ctx_len``. Compiled (``interpret=False``) it takes the shapes
     :func:`paged_attention_gate` admits; callers gate on it.
     """
     b, hq, d = q.shape
@@ -406,6 +439,7 @@ def paged_decode_attention(q, k_pages, v_pages, page_table, ctx_len, *,
         gp = -(-g // 8) * 8
         qk = q.reshape(b, h, g, d).transpose(0, 2, 1, 3).reshape(b, g, hd)
         qk = jnp.pad(qk, ((0, 0), (0, gp - g), (0, 0)))
+        qk = qk.astype(k_pages.dtype)  # the grouped fold's MXU operand
     kernel = functools.partial(
         _paged_attn_kernel, block_pages=bp, page_size=ps,
         pages_per_slot=pages_per_slot, num_pages=num_rows // ps,
@@ -423,9 +457,9 @@ def paged_decode_attention(q, k_pages, v_pages, page_table, ctx_len, *,
         ],
         out_specs=pl.BlockSpec((1, gp, hd), lambda i, *_: (i, 0, 0)),
         scratch_shapes=[
-            pltpu.VMEM((bp * ps, hd), k_pages.dtype),
-            pltpu.VMEM((bp * ps, hd), v_pages.dtype),
-            pltpu.SemaphoreType.DMA((2, bp)),
+            pltpu.VMEM((2, bp * ps, hd), k_pages.dtype),  # two waves of K
+            pltpu.VMEM((2, bp * ps, hd), v_pages.dtype),  # and of V
+            pltpu.SemaphoreType.DMA((2, 2, bp)),  # K|V, buffer, page
         ],
     )
     out = pl.pallas_call(
@@ -467,8 +501,8 @@ def gather_reference(q, k_pages, v_pages, page_table, ctx_len, page_size,
 
 def _selftest() -> int:
     """CPU interpret-mode parity vs the XLA gather path at mixed ragged
-    lengths, including a garbage-page poisoning leg — the CI smoke next to
-    sparse_adam --selftest (<5 s)."""
+    lengths, including a garbage-page poisoning leg and a bf16 pool under
+    both folds — the CI smoke next to sparse_adam --selftest."""
     import time
 
     t0 = time.time()
@@ -573,10 +607,38 @@ def _selftest() -> int:
         err_msg="rowless slots moved the live slots' output")
     np.testing.assert_array_equal(got_r[~dead], clean[~dead])
 
+    # a bf16 pool, 1 and 7 query heads a KV head, against the gather path
+    # in float32 over the SAME bf16 values: the G = 1 fold widens the pool
+    # (1e-6), the grouped fold gives the MXU p's two bf16 halves (2**-16 of
+    # the largest V). Waves of one page: an odd count (ctx 33: five), an
+    # even one (64: eight) and a single one, the two buffers alternating
+    # and each slot starting on what the slot before left in them
+    def stored(x):
+        x = jnp.asarray(x).astype(jnp.bfloat16)
+        return x, x.astype(jnp.float32)
+
+    (k16, k32), (v16, v32) = stored(k_pool), stored(v_pool)
+    for g, blocks in ((1, (1,)), (7, (1, 3))):
+        qg = stored(rng.randn(slots, g * h, d))[1]
+        want = np.asarray(gather_reference(
+            qg, k32, v32, jnp.asarray(pt), jnp.asarray(ctx_len), ps,
+            sm_scale=sm))
+        tol = 1e-6 if g == 1 else 2.0 ** -16 * float(jnp.max(jnp.abs(v32)))
+        for block in blocks:
+            got = np.asarray(paged_decode_attention(
+                qg, k16, v16, jnp.asarray(pt), jnp.asarray(ctx_len),
+                page_size=ps, sm_scale=sm, block_pages=block,
+                interpret=True))
+            np.testing.assert_allclose(
+                got, want, rtol=1e-6, atol=tol,
+                err_msg="bf16 pool, %d query heads a KV head, "
+                        "block_pages=%d" % (g, block))
+
     print("paged_attention selftest OK (%.2fs): kernel == gather on %d "
-          "ragged slots (ctx %s), garbage pages and neighbouring layers "
-          "contribute exactly zero, slots of ctx_len 0 return 0.0 and read "
-          "nothing"
+          "ragged slots (ctx %s) in float32 and, with 1 and 7 query heads a "
+          "KV head, over a bf16 pool at odd and even wave counts; garbage "
+          "pages and neighbouring layers contribute exactly zero, slots of "
+          "ctx_len 0 return 0.0 and read nothing"
           % (time.time() - t0, slots, list(map(int, ctx_len))))
     return 0
 

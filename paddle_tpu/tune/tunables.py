@@ -326,19 +326,39 @@ class SoftmaxXentTunable(Tunable):
 class PagedAttentionTunable(Tunable):
     """``block_pages`` of the ragged paged-attention decode kernel: KV
     pages DMA'd per online-softmax wave. Wider waves amortize DMA issue
-    and rescale cost but grow the K/V VMEM scratch (and waste work on
-    short ragged contexts whose last wave is mostly masked); the engine's
-    trace-time ``_block_pages`` lookup serves whatever this sweep
-    persists."""
+    and rescale cost but grow the kernel's two K and two V wave buffers
+    (and waste work on short ragged contexts whose last wave is mostly
+    masked); the engine's trace-time ``_block_pages`` lookup serves
+    whatever this sweep persists.
+
+    On a TPU the shapes are the serve cells' own (PERF.md section 4): bf16
+    pools, the cell's slots, KV heads, query heads a KV head
+    (``q_per_kv``) and pages a slot, and ``live`` = (shortest, longest,
+    how many) live contexts of a decode step there, the other slots
+    holding no request. One timed unit is ``calls`` kernel calls in one
+    program, as a decode step makes one a layer."""
 
     kernel = "paged_attention"
 
     def default_shapes(self):
         if _on_tpu():
-            return [dict(slots=8, max_ctx=2048, page_size=16, n_head=8,
-                         d_head=64),
-                    dict(slots=16, max_ctx=1024, page_size=16, n_head=8,
-                         d_head=64)]
+            cell = dict(page_size=16, d_head=128, dtype="bfloat16",
+                        slots=16, calls=32)
+            return [
+                # laguna-s-ep2-serve: full layers, then the 512-row rings
+                dict(cell, max_ctx=16384, n_head=8, q_per_kv=6,
+                     live=(2400, 7200, 16)),
+                dict(cell, max_ctx=512, n_head=8, q_per_kv=9,
+                     live=(512, 512, 16)),
+                # smallthinker-21b-a3b-serve: global layers, 4,096-row rings
+                dict(cell, max_ctx=16384, n_head=4, q_per_kv=7,
+                     live=(700, 5400, 12)),
+                dict(cell, max_ctx=4096, n_head=4, q_per_kv=7,
+                     live=(700, 4096, 12)),
+                # gpt2-small-serve, as a loaded server would run it
+                dict(cell, slots=32, max_ctx=1024, n_head=12, d_head=64,
+                     q_per_kv=1, live=(100, 900, 24)),
+            ]
         # interpret-mode mechanism shape: seconds on CPU
         return [dict(slots=4, max_ctx=64, page_size=8, n_head=2, d_head=16)]
 
@@ -346,10 +366,21 @@ class PagedAttentionTunable(Tunable):
         return _table.bucket_ctx(shape["max_ctx"],
                                  shape["n_head"] * shape["d_head"])
 
+    @staticmethod
+    def _itemsize(shape):
+        import jax.numpy as jnp
+
+        return jnp.dtype(shape.get("dtype", "float32")).itemsize
+
     def candidates(self, shape):
+        from ..ops.pallas_kernels.paged_attention import _wave_fits
+
         pps = shape["max_ctx"] // shape["page_size"]
+        fits = _wave_fits(shape["page_size"],
+                          shape["n_head"] * shape["d_head"],
+                          self._itemsize(shape))
         out, bp = [], 1
-        while bp <= pps:
+        while bp <= min(pps, fits):  # wider ones are clamped to these
             out.append({"block_pages": bp})
             bp *= 2
         return out
@@ -359,14 +390,17 @@ class PagedAttentionTunable(Tunable):
 
         pps = shape["max_ctx"] // shape["page_size"]
         return {"block_pages": _default_block_pages(
-            shape["page_size"], pps, shape["n_head"] * shape["d_head"])}
+            shape["page_size"], pps, shape["n_head"] * shape["d_head"],
+            self._itemsize(shape))}
 
     def cost(self, shape, config):
-        # the K + V scratch one wave holds resident (f32 worst case)
-        return {"vmem_bytes": 2 * 4 * config["block_pages"]
-                * shape["page_size"] * shape["n_head"] * shape["d_head"]}
+        # the two K and two V wave buffers the kernel keeps resident
+        return {"vmem_bytes": 4 * self._itemsize(shape)
+                * config["block_pages"] * shape["page_size"]
+                * shape["n_head"] * shape["d_head"]}
 
     def build(self, shape, config):
+        import jax
         import jax.numpy as jnp
         import numpy as np
 
@@ -374,25 +408,42 @@ class PagedAttentionTunable(Tunable):
 
         slots, ps = shape["slots"], shape["page_size"]
         h, d, max_ctx = shape["n_head"], shape["d_head"], shape["max_ctx"]
+        g, calls = shape.get("q_per_kv", 1), shape.get("calls", 1)
+        dtype = jnp.dtype(shape.get("dtype", "float32"))
         pps = max_ctx // ps
         num_pages = slots * pps  # full-occupancy pool, like the engine's
-        rng = np.random.RandomState(0)
+        kk, kv, kq = jax.random.split(jax.random.PRNGKey(0), 3)
         # one layer as the pool stores it, [rows, H*D]: the tuner times the
-        # kernel, not a reshape
-        k_pool = jnp.asarray(rng.randn(num_pages * ps, h * d), jnp.float32)
-        v_pool = jnp.asarray(rng.randn(num_pages * ps, h * d), jnp.float32)
-        q = jnp.asarray(rng.randn(slots, h, d), jnp.float32)
-        pt = jnp.asarray(rng.permutation(num_pages)[:slots * pps]
+        # kernel, not a reshape (drawn on the device: a cell's layer is a
+        # quarter of a billion values)
+        k_pool = jax.random.normal(kk, (num_pages * ps, h * d), dtype)
+        v_pool = jax.random.normal(kv, (num_pages * ps, h * d), dtype)
+        q = jax.random.normal(kq, (slots, g * h, d), dtype)
+        pt = jnp.asarray(np.random.RandomState(0).permutation(num_pages)
                          .reshape(slots, pps).astype(np.int32))
         # the ragged mix the engine actually sees: a spread of live lengths
-        ctx = jnp.asarray(
-            np.linspace(1, max_ctx, slots).round().astype(np.int32))
+        lo, hi, live = shape.get("live", (1, max_ctx, slots))
+        ctx = np.zeros(slots, np.int32)
+        ctx[:live] = np.linspace(lo, hi, live).round()
+        ctx = jnp.asarray(ctx)
         fn = functools.partial(
             paged_decode_attention, page_size=ps,
             sm_scale=1.0 / float(d) ** 0.5,
             block_pages=int(config["block_pages"]),
             interpret=not _on_tpu())
-        return (lambda: fn(q, k_pool, v_pool, pt, ctx)), ()
+
+        @jax.jit
+        def step(q, k_pool, v_pool, pt, ctx):  # arguments, not constants
+            def body(i, acc):
+                # the lengths move round the slots, or the compiler finds
+                # the call the same in every turn and lifts it out
+                return acc + fn(q, k_pool, v_pool, pt,
+                                jnp.roll(ctx, i)).astype(jnp.float32)
+
+            return jax.lax.fori_loop(0, calls, body,
+                                     jnp.zeros(q.shape, jnp.float32))
+
+        return step, (q, k_pool, v_pool, pt, ctx)
 
 
 # -- pass gates (end-to-end measured) ----------------------------------------

@@ -194,6 +194,57 @@ def test_paged_decode_attention_single_layer(chip):
             if _has_dim(rtype, rows)} <= {"parameter", "bitcast"}
 
 
+# the paged kernel's calls in the serve cells: slots, query heads, KV heads,
+# head width, layers, pages, pages a slot, and the call's result
+CELL_KERNELS = {
+    "gpt2_small": (32, 12, 12, 64, 12, 2048, 64, "bf16[32,1,768]"),
+    "smallthinker_global": (16, 28, 4, 128, 3, 6144, 1024, "bf16[16,8,512]"),
+    "smallthinker_window": (16, 28, 4, 128, 9, 4096, 256, "bf16[16,8,512]"),
+    "laguna_global": (16, 48, 8, 128, 2, 9216, 1024, "bf16[16,8,1024]"),
+    "laguna_window": (16, 72, 8, 128, 3, 512, 32, "bf16[16,16,1024]"),
+}
+
+
+@pytest.mark.parametrize("cell", sorted(CELL_KERNELS))
+def test_paged_kernel_at_the_cells_shapes_and_shipped_waves(chip, cell):
+    """bf16 pools, the ``block_pages`` the shipped v5e table holds for the
+    call's bucket (a measured entry, not the wildcard): ONE custom call
+    named ``paged_attention`` with the result the trace readers tell the
+    calls by, two K and two V wave buffers in the pool's type within the
+    wave budget, and the pool handed over untouched."""
+    from paddle_tpu import tune
+
+    b, hq, h, d, n_layer, pages, pps, result = CELL_KERNELS[cell]
+    ps, hd = 16, h * d
+    bucket = tune.bucket_ctx(pps * ps, hd)
+    cfg, src = tune.lookup("paged_attention", bucket, device="tpu-v5e")
+    shipped = tune.table.read_entries(tune.table.shipped_path())
+    assert src == "shipped" and tune.table.entry_key(
+        "paged_attention", bucket, "tpu-v5e") in shipped, (bucket, src)
+    bp = pa._block_pages(cfg["block_pages"], ps, pps, pps * ps, hd, 2)
+    assert bp == cfg["block_pages"], "the shipped wave is clamped"
+    fn = functools.partial(pa.paged_decode_attention, page_size=ps, layer=1,
+                           sm_scale=d ** -0.5, block_pages=bp)
+    pool = ((n_layer, pages * ps, hd), jnp.bfloat16)
+    shapes = (((b, hq, d), jnp.bfloat16), pool, pool, ((b, pps), jnp.int32),
+              ((b,), jnp.int32))
+    text = compiled_text(chip, fn, *shapes)
+    kernel, = [ln for ln in text.split("\n") if "tpu_custom_call" in ln]
+    assert kernel.strip().startswith("%paged_attention")
+    assert result in kernel.split(" custom-call(")[0]
+    assert [op for _, rtype, op, _ in _instructions(text)
+            if _has_dim(rtype, pages * ps) and op != "parameter"] == []
+    # what the kernel keeps in fast memory, from its own scratch operands
+    jaxpr = jax.make_jaxpr(fn)(*[jax.ShapeDtypeStruct(*x) for x in shapes])
+    call, = [e for e in jaxpr.eqns if e.primitive.name == "pallas_call"]
+    n_scratch = call.params["grid_mapping"].num_scratch_operands
+    scratch = [v.aval for v in call.params["jaxpr"].invars[-n_scratch:]]
+    buffers = [a for a in scratch if a.shape == (2, bp * ps, hd)]
+    assert len(buffers) == 2 and len(scratch) == 3, scratch
+    assert all(a.dtype == jnp.bfloat16 for a in buffers)
+    assert sum(a.size * 2 for a in buffers) <= pa._VMEM_WAVE_BUDGET
+
+
 # -- the engine's executables at gpt2-small-serve's geometry -------------------
 
 SERVE = dict(slots=32, page_size=16, num_pages=2048, max_seq=1024,

@@ -4,7 +4,11 @@ decoding (ISSUE 13 tentpole coverage).
 Kernel parity runs in Pallas interpret mode on CPU against the XLA
 page-gather + ``decode_attention`` reference — same tolerance discipline
 as the sparse_adam kernel tests (rtol/atol 1e-6 on live rows, BIT-exact
-indifference to garbage beyond ``ctx_len``). Engine-level tests arm
+indifference to garbage beyond ``ctx_len``). A bf16 pool's grouped fold
+hands the MXU its probabilities as bf16 high and low halves: against a
+float32 reference over the SAME bf16 values it is held to that rounding's
+bound (``P_ROUNDOFF``).
+Engine-level tests arm
 ``FLAGS_paged_attention_kernel=interpret`` and assert the full serving
 stack emits the same token streams either way, that ``temperature=0`` is
 bit-identical to greedy, that seeded sampling is invariant to
@@ -50,6 +54,27 @@ def make_pool(rng, slots, pages_per_slot, num_pages, page_size, h, d):
     return jnp.asarray(q), jnp.asarray(k), jnp.asarray(v), jnp.asarray(pt)
 
 
+def as_pool(x, dtype):
+    """``x`` rounded to ``dtype`` as the pool stores it, and the SAME
+    values in float32 for the reference."""
+    stored = jnp.asarray(x).astype(dtype)
+    return stored, stored.astype(jnp.float32)
+
+
+# A bf16 pool's grouped fold hands the second product each probability as
+# its bf16 high and low halves: 16 bits, so ``p`` is off by at most 2**-17
+# of itself and the output, a weighted mean of V rows under weights that sum
+# to l within the same bound, by at most 2**-16 * max|v| (float32 pools and
+# the G = 1 fold, which round nothing, keep 1e-6).
+P_ROUNDOFF = 2.0 ** -16
+
+
+def tolerance(dtype, g, v):
+    if jnp.dtype(dtype) == jnp.float32 or g == 1:
+        return dict(rtol=1e-6, atol=1e-6)
+    return dict(rtol=1e-6, atol=P_ROUNDOFF * float(jnp.max(jnp.abs(v))))
+
+
 # -- kernel parity (interpret mode) ------------------------------------------
 
 def test_kernel_matches_gather_at_ragged_lengths(rng):
@@ -65,15 +90,26 @@ def test_kernel_matches_gather_at_ragged_lengths(rng):
                                    err_msg="block_pages=%r" % (bp,))
 
 
-def test_garbage_pages_move_no_output_bit(rng):
+@pytest.mark.parametrize("g", [1, 6], ids=["g1", "g6"])
+@pytest.mark.parametrize("dtype,junk", [("float32", (1e4, -1e4)),
+                                        ("bfloat16", (np.inf, np.nan))],
+                         ids=["float32_huge", "bfloat16_inf_nan"])
+def test_garbage_pages_move_no_output_bit(rng, dtype, junk, g):
     """Pages beyond ctx_len belong to OTHER requests (or are stale) — the
     kernel must ignore them EXACTLY, not approximately: trashing every
-    invalid row with large finite values moves no output bit."""
+    invalid row (large finite values; Inf and NaN in a bf16 pool, for both
+    folds) moves no output bit."""
     slots, h, d, ps, pps = 4, 2, 8, 8, 4
-    q, k, v, pt = make_pool(rng, slots, pps, 12, ps, h, d)
+    _, k, v, pt = make_pool(rng, slots, pps, 12, ps, h, d)
+    q = jnp.asarray(rng.randn(slots, g * h, d).astype(np.float32))
     ctx = jnp.asarray([3, 8, 17, 29], jnp.int32)
-    clean = pa.paged_decode_attention(q, k, v, pt, ctx, page_size=ps,
-                                      block_pages=2, interpret=True)
+
+    def run(kp, vp):
+        return np.asarray(pa.paged_decode_attention(
+            q, jnp.asarray(kp).astype(dtype), jnp.asarray(vp).astype(dtype),
+            pt, ctx, page_size=ps, block_pages=2, interpret=True))
+
+    clean = run(k, v)
     kp, vp = np.asarray(k).copy(), np.asarray(v).copy()
     used = np.zeros(kp.shape[0], bool)
     for s in range(slots):
@@ -82,11 +118,11 @@ def test_garbage_pages_move_no_output_bit(rng):
             row0 = int(pt[s, j]) * ps
             live = max(0, min(ps, n - j * ps))
             used[row0:row0 + live] = True
-    kp[~used], vp[~used] = 1e4, -1e4
-    got = pa.paged_decode_attention(q, jnp.asarray(kp), jnp.asarray(vp), pt,
-                                    ctx, page_size=ps, block_pages=2,
-                                    interpret=True)
-    np.testing.assert_array_equal(np.asarray(got), np.asarray(clean))
+    stale = np.flatnonzero(~used)
+    kp[stale], vp[stale] = junk
+    vp[stale[::2]] = junk[0]  # both kinds of junk in V as well
+    assert np.isfinite(clean).all()
+    np.testing.assert_array_equal(run(kp, vp), clean)
 
 
 @pytest.mark.parametrize("layer", [0, 1, 2])
@@ -119,9 +155,10 @@ def test_layer_of_a_pool_is_bit_equal_to_the_layer_alone(rng, layer):
     np.testing.assert_array_equal(np.asarray(traced), np.asarray(alone))
 
 
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
 @pytest.mark.parametrize("g", [1, 7], ids=["one_query_head_a_kv_head",
                                            "seven_padded_to_eight"])
-def test_rowless_slots_cost_no_read_and_return_zero(rng, g):
+def test_rowless_slots_cost_no_read_and_return_zero(rng, g, dtype):
     """``ctx_len`` 0 = the slot holds nothing (the cache hands the kernel
     LIVE lengths). Such slots sit among live ones with their page-table
     rows on pages of Inf and NaN: they come back exactly 0.0, and the live
@@ -129,14 +166,16 @@ def test_rowless_slots_cost_no_read_and_return_zero(rng, g):
     slots, h, d, ps, pps = 6, 2, 16, 8, 4
     live_pages = 16
     _, k, v, pt = make_pool(rng, slots, pps, live_pages, ps, h, d)
+    (k, k32), (v, v32) = as_pool(k, dtype), as_pool(v, dtype)
     q = jnp.asarray(rng.randn(slots, g * h, d).astype(np.float32))
+    q = as_pool(q, dtype)[1]
     ctx = np.asarray([5, 0, 32, 0, 17, 0], np.int32)
     dead = ctx == 0
     # four more pages, poisoned, and only the rowless slots point at them
     poison = np.full((4 * ps, h * d), np.nan, np.float32)
     poison[::2] = np.inf
-    k = jnp.concatenate([k, jnp.asarray(poison)])
-    v = jnp.concatenate([v, jnp.asarray(-poison)])
+    k = jnp.concatenate([k, jnp.asarray(poison).astype(dtype)])
+    v = jnp.concatenate([v, jnp.asarray(-poison).astype(dtype)])
     pt = np.asarray(pt).copy()
     pt[dead] = live_pages + np.arange(4)
     kw = dict(page_size=ps, sm_scale=0.25, block_pages=2, interpret=True)
@@ -147,10 +186,82 @@ def test_rowless_slots_cost_no_read_and_return_zero(rng, g):
         q[~dead], k, v, jnp.asarray(pt[~dead]), jnp.asarray(ctx[~dead]),
         **kw))
     np.testing.assert_array_equal(got[~dead], alone)
-    want = pa.gather_reference(q[~dead], k[:live_pages * ps],
-                               v[:live_pages * ps], jnp.asarray(pt[~dead]),
+    want = pa.gather_reference(q[~dead], k32, v32, jnp.asarray(pt[~dead]),
                                jnp.asarray(ctx[~dead]), ps, sm_scale=0.25)
-    np.testing.assert_allclose(alone, want, rtol=1e-6, atol=1e-6)
+    np.testing.assert_allclose(alone, want, **tolerance(dtype, g, v32))
+
+
+@pytest.mark.parametrize("g", [1, 6, 7, 9])
+def test_bf16_pool_against_float32_reference_over_the_same_values(rng, g):
+    """A bf16 pool under 1, 6, 7 and 9 query heads a KV head (GPT-2's,
+    Laguna's full layers', SmallThinker's, Laguna's rings'): the grouped
+    fold multiplies in bf16 with a float32 accumulator and keeps its
+    softmax state in float32, so against a float32 reference over the same
+    bf16 values it is off by the rounding of ``p`` to 16 bits alone; G = 1
+    widens the pool and keeps 1e-6."""
+    slots, h, d, ps, pps = 5, 2, 16, 8, 8
+    q, k, v, pt = make_pool(rng, slots, pps, 40, ps, h, d)
+    (k, k32), (v, v32) = as_pool(k, jnp.bfloat16), as_pool(v, jnp.bfloat16)
+    q = as_pool(rng.randn(slots, g * h, d).astype(np.float32),
+                jnp.bfloat16)[1]  # float32-typed: the result is not rounded
+    ctx = jnp.asarray([1, 7, 16, 33, 64], jnp.int32)
+    want = pa.gather_reference(q, k32, v32, pt, ctx, ps, sm_scale=0.25)
+    for bp in (1, 3, None):
+        got = pa.paged_decode_attention(q, k, v, pt, ctx, page_size=ps,
+                                        sm_scale=0.25, block_pages=bp,
+                                        interpret=True)
+        assert got.dtype == jnp.float32
+        np.testing.assert_allclose(got, want, err_msg="block_pages=%r" % bp,
+                                   **tolerance(jnp.bfloat16, g, v32))
+
+
+# ps = 8 and two pages a wave: a wave is 16 rows, a slot has 8 pages
+WAVE_EDGES = {
+    "one_row": ([1], 2),
+    "exactly_one_wave": ([16], 2),
+    "one_row_past_a_wave": ([17], 2),
+    "exactly_three_waves_odd": ([48], 2),
+    "exactly_four_waves_even": ([64], 2),
+    "block_pages_not_dividing_the_slots_pages": ([64, 41], 3),
+    "a_short_slot_after_a_long_one": ([64, 3, 50, 1], 2),
+    "a_rowless_slot_between_two_live_ones": ([40, 0, 23], 2),
+    "one_wave_holds_the_whole_slot": ([64, 9], 8),
+}
+
+
+@pytest.mark.parametrize("dtype,g", [("float32", 1), ("float32", 7),
+                                     ("bfloat16", 1), ("bfloat16", 6)])
+@pytest.mark.parametrize("edge", sorted(WAVE_EDGES))
+def test_double_buffered_waves_at_their_edges(rng, edge, dtype, g):
+    """Wave ``w + 1`` is copied into the other buffer while wave ``w`` is
+    folded, and both buffers keep what the slot before left in them: the
+    lengths at which a start, a wait or a mask could slip by one."""
+    ctx, bp = WAVE_EDGES[edge]
+    slots, h, d, ps, pps = len(ctx), 2, 16, 8, 8
+    _, k, v, pt = make_pool(rng, slots, pps, slots * pps, ps, h, d)
+    (k, k32), (v, v32) = as_pool(k, dtype), as_pool(v, dtype)
+    q = as_pool(rng.randn(slots, g * h, d).astype(np.float32), dtype)[1]
+    ctx = jnp.asarray(ctx, jnp.int32)
+    got = np.asarray(pa.paged_decode_attention(
+        q, k, v, pt, ctx, page_size=ps, sm_scale=0.25, block_pages=bp,
+        interpret=True))
+    live = np.asarray(ctx) > 0
+    want = pa.gather_reference(q[live], k32, v32, pt[live], ctx[live], ps,
+                               sm_scale=0.25)
+    np.testing.assert_allclose(got[live], want, **tolerance(dtype, g, v32))
+    np.testing.assert_array_equal(got[~live], 0.0)
+
+
+def test_wave_buffers_fit_their_budget_at_every_served_row_width():
+    """``_block_pages`` clamps whatever the table says to two K and two V
+    buffers of a wave in the pool's type."""
+    for hd, itemsize in ((512, 2), (768, 2), (1024, 2), (768, 4), (4096, 4)):
+        for want in (1, 8, 16, 32, 1 << 20):
+            bp = pa._block_pages(want, 16, 1024, 16384, hd, itemsize)
+            assert 1 <= bp <= want
+            assert 4 * bp * 16 * hd * itemsize <= pa._VMEM_WAVE_BUDGET \
+                or bp == 1
+    assert pa._block_pages(64, 16, 4, 64, 128, 2) == 4  # the slot's pages
 
 
 def test_pool_shape_errors_are_typed(rng):

@@ -174,10 +174,16 @@ def selftest() -> int:
             cfg, src = tune.lookup("sparse_adam", tune.bucket_rows(4096, 64),
                                    device="tpu-v5e")
             assert src == "shipped" and cfg["block"] == 128, (cfg, src)
+            # a bucket the chip sweep measured (GPT-2 small's), and one
+            # it did not: the wildcard
+            cfg, src = tune.lookup("paged_attention",
+                                   tune.bucket_ctx(1024, 768),
+                                   device="tpu-v5e")
+            assert src == "shipped" and cfg["block_pages"] == 8, (cfg, src)
             cfg, src = tune.lookup("paged_attention",
                                    tune.bucket_ctx(2048, 512),
                                    device="tpu-v5e")
-            assert src == "shipped" and cfg["block_pages"] == 8, (cfg, src)
+            assert src == "shipped" and cfg["block_pages"] == 16, (cfg, src)
             # unknown device -> default (hardcoded fallbacks stay in charge)
             cfg, src = tune.lookup("flash_attention",
                                    tune.bucket_seq(8192, 8192),

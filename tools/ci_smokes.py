@@ -44,7 +44,7 @@ BUDGETS = {
     "dump_metrics": 10.0,
     "dump_program": 10.0,
     "sparse_adam": 15.0,
-    "paged_attention": 15.0,
+    "paged_attention": 20.0,  # eleven interpreted kernel calls
     # its restarted-process twins compile cold: JAX's own thresholds keep
     # sub-second CPU executables out of the persistent cache
     "chaos_drill": 75.0,
