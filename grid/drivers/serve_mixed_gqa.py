@@ -24,8 +24,7 @@ from typing import Any, Dict, List, NamedTuple
 
 from .. import generate, runtime
 from ..reference import laguna as reference
-from .serve import drive, warm
-from .serve_mla import _harness_lateness
+from .serve import compared, drive, harness_lateness, warm
 from .serve_moe import plan
 
 LONG_CONTEXT = 4096    # one of the two compared requests is past this
@@ -179,7 +178,7 @@ def check(engine, record, job, compiles_in_window: int) -> Dict[str, Any]:
     short = [tr for tr in finished
              if len(tr.req.tokens_out) != tr.planned.max_new_tokens]
     starts = [c.start for c in record["cycles"]]
-    late = sorted(_harness_lateness(record["cycles"], starts, tr)
+    late = sorted(harness_lateness(record["cycles"], starts, tr)
                   for tr in in_window if not tr.refused)
     c0, c1 = marks["c_open"], marks["c_close"]
     decode_ms = ((c1["decode_ms"] - c0["decode_ms"])
@@ -247,7 +246,10 @@ def check(engine, record, job, compiles_in_window: int) -> Dict[str, Any]:
             "attempted": len(in_window), "failed": len(failed),
             "generator_late_ms": {"p50": late_p50,
                                   "max": late[-1] * 1e3 if late else 0.0},
-            "reference_margins": margins}
+            "reference_margins": margins,
+            "compared": compared(len(failed), len(short),
+                                 compiles_in_window, late_p50, decode_ms,
+                                 margins, reference)}
 
 
 def run(job) -> Dict[str, Any]:

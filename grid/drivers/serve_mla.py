@@ -16,13 +16,12 @@ the window's readers apply unchanged.
 
 from __future__ import annotations
 
-import bisect
 import time
 from typing import Any, Dict, List, NamedTuple
 
 from .. import generate, runtime
 from ..reference import kimi_k2 as reference
-from .serve import drive, warm
+from .serve import compared, drive, harness_lateness, warm
 from .serve_moe import plan
 
 LONG_CONTEXT = 6000    # one of the two compared requests is past this
@@ -129,20 +128,6 @@ def window_note(record) -> Dict[str, Any]:
     return note
 
 
-def _harness_lateness(cycles, starts, tr) -> float:
-    """Seconds the HARNESS added to a request's submission: from the end of
-    the engine cycle in progress when it fell due (the loop submits between
-    cycles, and here a cycle with an unchunked prefill in it lasts 0.2-0.8
-    s, so submission minus due time measures the engine's step) to its
-    submission; from its due instant where no cycle was in progress.
-    ``starts`` are the cycles' start instants, in order."""
-    i = bisect.bisect_right(starts, tr.due) - 1
-    free_at = tr.due
-    if i >= 0 and cycles[i].end > tr.due:
-        free_at = cycles[i].end
-    return max(tr.req.submitted_t - free_at, 0.0)
-
-
 def check(engine, record, job, compiles_in_window: int) -> Dict[str, Any]:
     """``correct``, decided outside the window: ``drivers/serve.py``'s rule
     (the served token's rank below the float32 reference's best logit, in
@@ -165,7 +150,7 @@ def check(engine, record, job, compiles_in_window: int) -> Dict[str, Any]:
     short = [tr for tr in finished
              if len(tr.req.tokens_out) != tr.planned.max_new_tokens]
     starts = [c.start for c in record["cycles"]]
-    late = sorted(_harness_lateness(record["cycles"], starts, tr)
+    late = sorted(harness_lateness(record["cycles"], starts, tr)
                   for tr in in_window if not tr.refused)
     c0, c1 = marks["c_open"], marks["c_close"]
     decode_ms = ((c1["decode_ms"] - c0["decode_ms"])
@@ -226,7 +211,10 @@ def check(engine, record, job, compiles_in_window: int) -> Dict[str, Any]:
             "attempted": len(in_window), "failed": len(failed),
             "generator_late_ms": {"p50": late_p50,
                                   "max": late[-1] * 1e3 if late else 0.0},
-            "reference_margins": margins}
+            "reference_margins": margins,
+            "compared": compared(len(failed), len(short),
+                                 compiles_in_window, late_p50, decode_ms,
+                                 margins, reference)}
 
 
 def run(job) -> Dict[str, Any]:

@@ -18,7 +18,7 @@ import numpy as np
 
 from .. import generate, runtime
 from ..reference import smallthinker as reference
-from .serve import drive, warm
+from .serve import compared, drive, warm
 
 
 def model_config(config: Dict[str, Any]):
@@ -207,7 +207,10 @@ def check(engine, record, job, compiles_in_window: int) -> Dict[str, Any]:
             "attempted": len(in_window), "failed": len(failed),
             "generator_late_ms": {"p50": late_p50,
                                   "max": late[-1] * 1e3 if late else 0.0},
-            "reference_margins": margins}
+            "reference_margins": margins,
+            "compared": compared(len(failed), len(short),
+                                 compiles_in_window, late_p50, decode_ms,
+                                 margins, reference)}
 
 
 def run(job) -> Dict[str, Any]:

@@ -200,6 +200,12 @@ def run(job) -> Dict[str, Any]:
             problems += _layout_problems(prepared, main, probe, job.chips)
         record.update(correct=not problems, problems=problems,
                       attempted=marks["steps"], failed=0,
+                      compared={
+                          "first_loss_off_ln_v": [abs(probe_before - init),
+                                                  0.01 * init],
+                          "probe_loss_after": [probe_after, probe_before],
+                          "compiles": [record["compiles"]
+                                       + record["specializations"], 0]},
                       loss={"probe_before": probe_before,
                             "probe_after": probe_after,
                             "first": loss_values[0],

@@ -102,16 +102,6 @@ def prefill_ms_mean(record, trace=None) -> Optional[float]:
     return _counter_delta(record, "prefill_ms") / n if n else None
 
 
-def engine_host_ms_per_step(record, trace=None) -> float:
-    """Cycle wall time minus the time inside prefill and decode dispatches
-    (the engine's own clocks around each dispatch, host sync included)."""
-    cyc = _cycles(record)
-    wall_ms = sum(c.end - c.start for c in cyc) * 1e3
-    inside = _counter_delta(record, "prefill_ms") \
-        + _counter_delta(record, "decode_ms")
-    return (wall_ms - inside) / len(cyc)
-
-
 # -- training ---------------------------------------------------------------------
 
 
@@ -135,7 +125,11 @@ def exe_host_ms_per_step(record, trace=None) -> float:
 
 def summary(record) -> Dict[str, Any]:
     """What is worth reading and is not judged: sample counts, and in a
-    serving run the queue and the first-token times."""
+    serving run the first-token times and the queue: how long it was
+    after the window's first and last cycles, the share of the window's
+    cycles that ended on an empty one (a queue STANDS where that is under
+    a twentieth: ``grid/sweep.py``), and the requests the engine refused
+    in the whole run."""
     if record["kind"] == "train":
         m = record["marks"]
         return {"steps": m["steps"], "window_s": m["close"] - m["open"]}
@@ -147,6 +141,10 @@ def summary(record) -> Dict[str, Any]:
                         "p95": stats.percentile(ttft, 95),
                         "max": max(ttft)} if ttft else None,
             "cycles": len(cyc), "window_s": _window_s(record),
+            "queue_depth_at_open": cyc[0].queue,
             "queue_depth_at_close": cyc[-1].queue,
             "queue_depth_max": max(c.queue for c in cyc),
+            "queue_empty_cycle_share":
+                sum(1 for c in cyc if not c.queue) / len(cyc),
+            "refused": sum(1 for tr in record["tracked"] if tr.refused),
             "slot_occupancy_mean": slot_occupancy_mean(record)}

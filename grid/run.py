@@ -7,10 +7,12 @@ than the cell asks for, builds the model on the device from ``--seed``,
 warms only the cell's own shapes, measures for ``--seconds``, checks
 correctness outside the window and prints ONE JSON object as the last line
 of its standard output: ``correct``, ``attempted``, ``failed``, ``metrics``,
-``device`` and, when traced, ``breakdown``. Earlier lines (one JSON object
-each, ``{"note": ...}``) carry whatever else is worth reading. ``--trace 0``
-gives the cell's end-to-end metrics; ``--trace 1`` profiles a few seconds
-after the window and gives its per-layer metrics.
+``device``, when traced ``breakdown``, and last ``compared``: each number
+the driver's ``check`` held to a limit, as ``[number, limit]`` under a short
+plain name, which are also the last lines on standard error. Earlier lines
+(one JSON object each, ``{"note": ...}``) carry whatever else is worth
+reading. ``--trace 0`` gives the cell's end-to-end metrics; ``--trace 1``
+profiles a few seconds after the window and gives its per-layer metrics.
 
 The compile cache is JAX's persistent one, at ``JAX_COMPILATION_CACHE_DIR``
 where that is set and else at ``<checkout>/.jax_cache``
@@ -82,9 +84,13 @@ def main(argv=None) -> int:
     record["first_compile_s"] = job.meter.seconds
 
     trace = None
+    spent = {}     # seconds after the traced stretch, by what took them
     if args.trace:
+        t0 = time.perf_counter()
         trace = reduce.load(reduce.find_xplane(trace_dir))
         record["trace_window"] = reduce.window(trace)
+        spent["load_s"] = time.perf_counter() - t0
+    t0 = time.perf_counter()
     metrics = {}
     for name in cell.reported(bool(args.trace)):
         spec = cell.metrics[name]
@@ -93,6 +99,7 @@ def main(argv=None) -> int:
         value = manifest.reader(spec["reader"])(record, trace)
         if value is not None:
             metrics[name] = {"value": value, "unit": spec["unit"]}
+    spent["readers_s"] = time.perf_counter() - t0
     device["memory_peak_bytes"] = sum(record["memory"].values())
     last = {"correct": bool(record["correct"]),
             "attempted": int(record["attempted"]),
@@ -102,7 +109,16 @@ def main(argv=None) -> int:
         lo, hi = record["trace_window"]
         device["busy_s"] = reduce.busy_seconds(trace, (lo, hi))
         device["window_s"] = hi - lo
+        t0 = time.perf_counter()
         last["breakdown"] = reduce.breakdown(trace, (lo, hi))
+        spent["breakdown_s"] = time.perf_counter() - t0
+        job.log({"phase": "reduce", "device_ops": sum(
+            len(v) for v in trace.ops.values()),
+            "grid_spans": len(trace.spans), **spent})
+    # each number compared beside its limit: last in the line, and the
+    # last lines on standard error
+    compared = record.get("compared") or {}
+    last["compared"] = compared
     job.log({k: record[k] for k in ("problems", "compiles", "setup_s",
                                     "first_compile_s", "memory",
                                     "generator_late_ms",
@@ -112,6 +128,9 @@ def main(argv=None) -> int:
 
     job.log(window_readers.summary(record))
     print(json.dumps(last), flush=True)
+    for name, (value, limit) in compared.items():
+        print("grid.run: compared %s %r limit %r" % (name, value, limit),
+              file=sys.stderr)
     return 0
 
 

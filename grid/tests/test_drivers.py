@@ -27,9 +27,15 @@ def _run(monkeypatch, capsys, toy_root, workload, trace, seconds="1.5"):
     monkeypatch.setattr(grid_run, "TRACE_SECONDS", 0.7)
     rc = grid_run.main(["--workload", workload, "--seed", str(2 ** 31 + 5),
                         "--seconds", seconds, "--trace", str(trace)])
-    lines = capsys.readouterr().out.strip().splitlines()
+    cap = capsys.readouterr()
+    lines = cap.out.strip().splitlines()
     notes = [json.loads(x)["note"] for x in lines[:-1]]
-    return rc, json.loads(lines[-1]), notes
+    last = json.loads(lines[-1])
+    # each number compared beside its limit: the last lines on standard
+    # error, one a number, as the last line's ``compared`` has them
+    said = cap.err.strip().splitlines()[-len(last["compared"]):]
+    assert [x.split()[2] for x in said] == list(last["compared"]), cap.err
+    return rc, last, notes
 
 
 def _well_formed(last, cell, traced):
@@ -38,6 +44,9 @@ def _well_formed(last, cell, traced):
     assert set(last["device"]) >= {"platform", "kind", "count",
                                    "memory_peak_bytes"}
     assert last["attempted"] > 0 and last["failed"] == 0
+    assert list(last)[-1] == "compared" and last["compared"]
+    assert all(len(pair) == 2 for pair in last["compared"].values())
+    assert last["compared"]["compiles"] == [0, 0]
     assert last["device"]["memory_peak_bytes"] > 1    # held 1 + scratch
     allowed = set(cell.reported(traced))
     assert set(last["metrics"]) <= allowed
@@ -69,7 +78,7 @@ def test_serve_traced_run_reports_per_layer_metrics(monkeypatch, capsys,
     # host-side readers answer; the device's find nothing to read in a CPU
     # trace (it has no /device:TPU plane) and are left out of the line
     assert {"tpot_p95_ms", "queue_wait_ms_p50", "prefill_ms_mean",
-            "decode_dispatch_ms_mean", "engine_host_ms_per_step"} \
+            "decode_dispatch_ms_mean"} \
         <= set(last["metrics"])
     assert "device_idle_share.serve" not in last["metrics"]
     assert "paged_attn_roofline" not in last["metrics"]
@@ -120,16 +129,23 @@ def test_a_compile_in_the_window_is_incorrect(monkeypatch, toy_root):
                for p in verdict["problems"])
 
 
-def test_sweep_finds_a_capacity(monkeypatch, capsys, toy_root):
-    from grid import sweep
+@pytest.mark.parametrize("due, submitted, want", [
+    (1.005, 1.0215, 0.0015),   # due inside a cycle: counted from its end
+    (1.030, 1.0304, 0.0004),   # due while nothing ran: from the due instant
+    (0.500, 0.5000, 0.0),      # before the first cycle, on time
+    (1.045, 1.0600, 0.0),      # due inside the last cycle, submitted at its end
+])
+def test_lateness_is_counted_from_the_end_of_the_cycle_in_progress(
+        due, submitted, want):
+    """The loop submits between engine cycles, so a request due inside one
+    waits for its end whatever the harness does: that wait is the
+    engine's, and ``check`` holds only the rest under a decode dispatch."""
+    from types import SimpleNamespace as NS
 
-    monkeypatch.setattr(manifest, "ROOT", toy_root)
-    monkeypatch.setattr(runtime, "require_chips", lambda chips: {})
-    rc = sweep.main(["--workload", "gpt2s-doc-steady", "--seconds", "1",
-                     "--fractions", "0.5"])
-    rows = [json.loads(x) for x in capsys.readouterr().out.splitlines()]
-    assert rc == 0 and [r.get("window") for r in rows] \
-        == ["backlog", None, "0.50 of capacity"]
-    assert rows[1]["capacity_requests_per_s"] > 0
-    assert rows[2]["rate_per_s"] == pytest.approx(
-        0.5 * rows[1]["capacity_requests_per_s"], abs=1e-3)
+    from grid.drivers import serve
+
+    cycles = [serve.Cycle(1.00, 1.02, 4, 0, 0, 4),
+              serve.Cycle(1.04, 1.06, 4, 0, 0, 4)]
+    tr = NS(due=due, req=NS(submitted_t=submitted))
+    got = serve.harness_lateness(cycles, [c.start for c in cycles], tr)
+    assert got == pytest.approx(want, abs=1e-12)

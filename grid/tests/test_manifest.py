@@ -22,7 +22,8 @@ def test_cells_and_manifest_agree():
     cells = _cells()
     assert {c.name for c in cells} == {
         "tfbase-train-1chip", "gpt2s-chat-sat", "gpt2s-doc-steady",
-        "tfbase-train-dp4"}
+        "tfbase-train-dp4", "gpt2s-chat-steady", "smallthinker-mixed-sat",
+        "kimi-k2-longctx-sat", "laguna-s-code-sat"}
     for name, m in declared.items():
         reporting = [c.name for c in cells if name in c.cell["reports"]]
         assert reporting, name
@@ -36,8 +37,11 @@ def test_cells_and_manifest_agree():
         assert "setup_s" in c.reported(False) and len(c.reported(False)) >= 2
         assert c.reported(True)
         assert len(c.cell["why"]) <= 200
-        assert c.config["reduced"] == [] and c.config["assumed"] \
-            and c.config["deployment"] and c.config["source"]
+        entry = next(e for e in bench["configs"]
+                     if e["name"] == c.cell["config"])
+        assert c.config["reduced"] == entry["reduced"] \
+            and c.config["source"] == entry["source"]
+        assert c.config["assumed"] and c.config["deployment"]
 
 
 def test_every_moves_names_a_metric_its_cells_report():
@@ -112,7 +116,7 @@ def test_adding_needs_only_new_files_and_new_entries(toy_root):
     # reader is found by name once its file sits in grid/readers
     assert cell.metrics["dummy_cycles"]["reader"] == "dummy.cycles"
     # the cells that were there still load
-    assert len(_cells(toy_root)) == 5
+    assert len(_cells(toy_root)) == 9
 
 
 def test_benchmark_json_keeps_to_the_contracts_form():

@@ -140,7 +140,7 @@ def test_the_cell_loads_and_offers_the_same_lengths_for_every_seed():
     assert cell.kind == "serve" and cell.chips == 1
     assert cell.reported(False) == ["tpot_p50_ms", "setup_s"]
     traced = cell.reported(True)
-    assert len(traced) == 20 and "slot_occupancy_mean" not in traced
+    assert len(traced) == 19 and "slot_occupancy_mean" not in traced
     bench = manifest.benchmark()
     for name in traced:
         entry = next(m for m in bench["per_layer"] if m["name"] == name)
@@ -151,7 +151,7 @@ def test_the_cell_loads_and_offers_the_same_lengths_for_every_seed():
            if cell.metrics[n]["reader"].startswith("spans.")]
     assert len(new) == 9 and all(
         cell.metrics[n]["source"] == "program_span" for n in new)
-    assert cell.traffic["arrivals"]["rate_per_s"] == 3.93
+    assert cell.traffic["arrivals"]["rate_per_s"] == 20.127
 
     def offered(seed):
         plan = generate.serve_plan(cell.traffic, 50257, seed, 40.0, 4.0)
@@ -164,6 +164,6 @@ def test_the_cell_loads_and_offers_the_same_lengths_for_every_seed():
     a, b = offered(101), offered(2147483747)
     assert a[:3] == b[:3] and a[3] != b[3]
     in_window = sum(1 for t in a[0] if 5.0 <= t < 45.0)
-    assert in_window == 154 and str(in_window) in cell.cell["why"]
+    assert in_window == 785 and str(in_window) in cell.cell["why"]
     assert 32 <= min(a[1]) and max(a[1]) <= 256
     assert 64 <= min(a[2]) and max(a[2]) <= 192
