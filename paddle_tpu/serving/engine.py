@@ -467,6 +467,11 @@ class ServingEngine:
         self._prefills = 0
         self._resumes = 0
         self._cycles = 0
+        # the prefill clock (:meth:`prefill_clock`): seconds inside the
+        # ``serving/prefill`` spans that have closed, and the start of the
+        # one that is open
+        self._prefill_closed_s = 0.0
+        self._prefill_open_t0: Optional[float] = None
         self._last_error: Optional[str] = None
         self._closed = False
         self._draining = False
@@ -833,6 +838,20 @@ class ServingEngine:
             out["pages_total"] = self.pool.num_pages
         return out
 
+    def prefill_clock(self, now: float) -> float:
+        """The seconds this engine has spent inside ``serving/prefill``
+        spans up to ``now`` (a ``time.perf_counter`` instant not before the
+        last span's start): the closed spans' own ``t1 - t0``, and an open
+        one as far as ``now``. Each entry of a request's ``timeline``
+        carries it, so what the request lost behind admissions between two
+        hand-overs is the difference of two stamps. An upper bound by up to
+        one decode step an admission: a prefill queues on the device behind
+        the decode dispatch in flight and ``serving/prefill.sync`` drains
+        that too, which the other slots would have waited for anyway."""
+        if self._prefill_open_t0 is None:
+            return self._prefill_closed_s
+        return self._prefill_closed_s + (now - self._prefill_open_t0)
+
     def page_accounting_ok(self) -> bool:
         """The no-leak invariant every retirement path must preserve, in
         every cache group: pages the group's pool counts as used == pages
@@ -991,44 +1010,64 @@ class ServingEngine:
         finished immediately (EOS first token / max_new_tokens == 1). With
         a prefix cache armed, a prompt whose page-aligned prefix is cached
         skips the full prefill: its pages are row-copied and only the
-        remainder runs (the resume executable)."""
-        cfg = self.cfg
+        remainder runs (the resume executable). Either way the admission
+        is ONE ``serving/prefill`` span, launch to slot armed: its length
+        is the request's ``prefill_s`` and what the prefill clock advances
+        by."""
         entry = None
         if self.prefix_cache is not None:
             entry = self.prefix_cache.lookup(req.prompt)
         _sm.SAMPLER_DISPATCHES[_sampler_tier((req,))].inc()
-        with _span("serving/prefill", trace_id=req.trace_id, slot=slot,
-                   bucket=bucket, cause="local" if entry is None else "resume"):
-            if entry is not None:
-                return self._prefill_from_prefix(req, slot, entry)
-            with _span("serving/prefill.launch"):
-                prompt = np.full((bucket,), cfg.pad_id, np.int32)
-                prompt[:req.prompt_len] = req.prompt
-                if cfg.paged:
-                    dest = jnp.asarray(
-                        self.cache_ops.prompt_dest_groups(req.group_pages))
-                    self._cache = self.cache_ops.set_page_table(
-                        self._cache, slot, dest)
-                else:
-                    dest = jnp.asarray(self.cache_ops.prompt_dest(slot))
-                exe = self._get_prefill_exe(bucket)
-                # serving/prefill_ms starts here, as it always has: at the
-                # transfers of the executable's own arguments
-                t0 = time.perf_counter()
-                self._cache, first_tok, last_logits = exe(
-                    self.params, self._cache, dest, jnp.asarray(prompt),
-                    jnp.asarray(req.prompt_len, jnp.int32),
-                    jnp.asarray(req.temperature, jnp.float32),
-                    jnp.asarray(req.top_k, jnp.int32),
-                    jnp.asarray(req.seed, jnp.int32))
-            with _span("serving/prefill.sync") as sync:
-                tok = int(np.asarray(first_tok))
-            t1 = sync.t1
-            _trace.on_prefill(req, slot, bucket, t0, t1, cause="local")
-            _sm.PREFILL_MS.observe((t1 - t0) * 1e3)
-            _sm.PREFILL_COUNT.inc()
-            self._prefills += 1
-            return self._finish_prefill(req, slot, tok, last_logits)
+        admission = _span("serving/prefill", trace_id=req.trace_id, slot=slot,
+                          bucket=bucket,
+                          cause="local" if entry is None else "resume")
+        try:
+            with admission:
+                self._prefill_open_t0 = admission.t0
+                if entry is not None:
+                    return self._prefill_from_prefix(req, slot, entry)
+                return self._prefill_cold(req, slot, bucket)
+        finally:
+            # the whole admission on the span's own two clock reads: launch,
+            # sync and the slot's arming (serving/prefill_ms stops before
+            # the arming and keeps its meaning)
+            req.prefill_s = admission.t1 - admission.t0
+            self._prefill_closed_s += req.prefill_s
+            self._prefill_open_t0 = None
+
+    def _prefill_cold(self, req: Request, slot: int, bucket: int
+                      ) -> Optional[Request]:
+        """The per-bucket compiled prefill of a whole prompt, inside
+        :meth:`_prefill`'s ``serving/prefill`` span."""
+        cfg = self.cfg
+        with _span("serving/prefill.launch"):
+            prompt = np.full((bucket,), cfg.pad_id, np.int32)
+            prompt[:req.prompt_len] = req.prompt
+            if cfg.paged:
+                dest = jnp.asarray(
+                    self.cache_ops.prompt_dest_groups(req.group_pages))
+                self._cache = self.cache_ops.set_page_table(
+                    self._cache, slot, dest)
+            else:
+                dest = jnp.asarray(self.cache_ops.prompt_dest(slot))
+            exe = self._get_prefill_exe(bucket)
+            # serving/prefill_ms starts here, as it always has: at the
+            # transfers of the executable's own arguments
+            t0 = time.perf_counter()
+            self._cache, first_tok, last_logits = exe(
+                self.params, self._cache, dest, jnp.asarray(prompt),
+                jnp.asarray(req.prompt_len, jnp.int32),
+                jnp.asarray(req.temperature, jnp.float32),
+                jnp.asarray(req.top_k, jnp.int32),
+                jnp.asarray(req.seed, jnp.int32))
+        with _span("serving/prefill.sync") as sync:
+            tok = int(np.asarray(first_tok))
+        t1 = sync.t1
+        _trace.on_prefill(req, slot, bucket, t0, t1, cause="local")
+        _sm.PREFILL_MS.observe((t1 - t0) * 1e3)
+        _sm.PREFILL_COUNT.inc()
+        self._prefills += 1
+        return self._finish_prefill(req, slot, tok, last_logits)
 
     def _prefill_from_prefix(self, req: Request, slot: int, entry
                              ) -> Optional[Request]:
@@ -1088,6 +1127,9 @@ class ServingEngine:
         req.first_token_t = now
         _sm.TTFT_MS.observe((now - req.submitted_t) * 1e3)
         req.tokens_out.append(tok)
+        # its own admission is open: what is left of it (the arming below)
+        # counts as its own stall, since its second token waits for it
+        req.timeline.append((now, 1, self.prefill_clock(now)))
         if cfg.collect_logits:
             self._captured_logits.setdefault(req.id, []).append(
                 np.asarray(last_logits))
@@ -1427,20 +1469,30 @@ class ServingEngine:
             _sm.SPEC_ACCEPT_RATE.observe(accepted / max(1, proposed))
         finished: List[Request] = []
         handed = 0
+        clock = self.prefill_clock(t1)
         with _span("serving/retire"):
             for slot, req in enumerate(live):
                 if req is None:
                     continue
+                had = len(req.tokens_out)
+                done = False
                 for f in range(steps):
                     if emitted[f, slot]:
                         req.tokens_out.append(int(toks[f, slot]))
-                        handed += 1
                         if logseq is not None:
                             self._captured_logits.setdefault(
                                 req.id, []).append(logseq[f, slot])
                     if fin[f, slot]:
-                        finished.append(self._retire(slot))
+                        done = True
                         break
+                n = len(req.tokens_out)
+                if n > had:
+                    # ONE entry however many tokens the dispatch brought,
+                    # at the instant serving/decode_step_ms observes
+                    req.timeline.append((t1, n, clock))
+                    handed += n - had
+                if done:
+                    finished.append(self._retire(slot))
         _sm.TOKENS_GENERATED.inc(handed)
         return finished
 
@@ -1467,6 +1519,11 @@ class ServingEngine:
         if state == FINISHED:
             _sm.REQUEST_LATENCY_MS.observe(
                 (req.finished_t - req.submitted_t) * 1e3)
+            (t0, n0, c0), (t1, n1, c1) = req.timeline[0], req.timeline[-1]
+            if n1 > n0:     # two tokens or more: it has a gap
+                _sm.TPOT_MS.observe((t1 - t0) * 1e3 / (n1 - n0))
+                _sm.PREFILL_STALL_MS_PER_TOKEN.observe(
+                    (c1 - c0) * 1e3 / (n1 - n0))
         elif state == TIMEOUT:
             _sm.TIMEOUTS.inc()
         elif state == FAILED:

@@ -19,6 +19,7 @@ __all__ = [
     "DECODE_LAUNCHED_AHEAD",
     "TOKENS_GENERATED", "CYCLES", "SAMPLER_DISPATCHES",
     "REQUEST_LATENCY_MS", "TTFT_MS", "DECODE_STEP_MS", "PREFILL_MS",
+    "TPOT_MS", "PREFILL_STALL_MS_PER_TOKEN",
     "FAULTS", "RETRIES", "TIMEOUTS", "REQUESTS_FAILED",
     "DRAINS", "DRAINED_REQUESTS", "DRAIN_REJECTED",
     "SPEC_PROPOSED", "SPEC_ACCEPTED", "SPEC_REJECTED", "SPEC_DRAFTS",
@@ -91,6 +92,20 @@ DECODE_STEP_MS = _mx.histogram(
          "read (all its fused steps; one observation a dispatch read)")
 PREFILL_MS = _mx.histogram(
     "serving/prefill_ms", help="host wall time of one compiled prefill call")
+TPOT_MS = _mx.histogram(
+    "serving/tpot_ms",
+    help="mean gap between a finished request's output tokens: the time "
+         "from its first token to its last hand-over over the tokens "
+         "between (Request.timeline; one observation a request that "
+         "finished with two tokens or more): what a streaming user feels")
+PREFILL_STALL_MS_PER_TOKEN = _mx.histogram(
+    "serving/prefill_stall_ms_per_token",
+    help="the part of serving/tpot_ms the request spent behind admissions: "
+         "the engine's prefill clock (seconds inside serving/prefill spans) "
+         "at its last hand-over minus at its first token, over the tokens "
+         "between; the rest of its own arming included. An upper bound by "
+         "up to one decode step an admission (the prefill's sync drains "
+         "the decode dispatch in flight too)")
 FAULTS = _mx.counter(
     "serving/faults",
     help="decode dispatch failures absorbed by the recovery path (the "

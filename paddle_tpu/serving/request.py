@@ -12,7 +12,7 @@ from __future__ import annotations
 
 import itertools
 import time
-from typing import List, Optional, Sequence
+from typing import List, Optional, Sequence, Tuple
 
 __all__ = ["Request", "BackpressureError", "DrainingError",
            "QUEUED", "RUNNING", "FINISHED", "REJECTED",
@@ -67,13 +67,26 @@ class Request:
     draft k, ``"auto"`` the tune-table k (serving.speculative). A pure
     scheduling knob — the emitted stream is bit-identical either way, so
     replays (fleet requeues) need not pin it.
+
+    ``timeline`` is the request's own record of when its tokens reached
+    it: one entry a HAND-OVER, ``(t, n, prefill_clock_s)``: the
+    ``time.perf_counter`` instant, ``len(tokens_out)`` after it, and the
+    engine's prefill clock at that instant (the seconds the engine has
+    spent inside ``serving/prefill`` spans so far). The first entry is the
+    prefill's token (``t`` is ``first_token_t``); each later one is a
+    decode dispatch that brought at least one token, however many, at the
+    end of the sync that read it. The difference of two entries' clocks is
+    what the request lost behind admissions between them (the rest of its
+    own arming included: its second token waits for it). ``prefill_s`` is
+    the length of its own ``serving/prefill`` span, launch to slot armed.
     """
 
     __slots__ = ("id", "prompt", "max_new_tokens", "state", "slot", "pages",
                  "group_pages",
                  "tokens_out", "submitted_t", "admitted_t", "first_token_t",
                  "finished_t", "deadline_s", "error", "trace_id", "attempt",
-                 "temperature", "top_k", "seed", "speculation")
+                 "temperature", "top_k", "seed", "speculation",
+                 "timeline", "prefill_s")
 
     def __init__(self, prompt: Sequence[int], max_new_tokens: int,
                  deadline_s: Optional[float] = None,
@@ -114,6 +127,8 @@ class Request:
         self.admitted_t: Optional[float] = None
         self.first_token_t: Optional[float] = None
         self.finished_t: Optional[float] = None
+        self.timeline: List[Tuple[float, int, float]] = []
+        self.prefill_s: Optional[float] = None
         self.deadline_s = None if deadline_s is None else float(deadline_s)
         self.error: Optional[str] = None
         self.temperature = float(temperature)
