@@ -138,6 +138,43 @@ def _sampler_tier(requests) -> int:
     return tier
 
 
+# the per-slot state every decode executable carries from step to step, in
+# the order the executables take it
+_SLOT_STATE = ("_len", "_tok", "_active", "_gen", "_maxnew", "_temp",
+               "_topk", "_seed")
+
+
+def _arm_slot(state, slot, length, tok, maxnew, temp, topk, seed, eos):
+    """``state`` (the arrays of ``_SLOT_STATE``) with ``slot`` armed for a
+    request whose prompt of ``length`` tokens gave ``tok``: traced inside
+    the prefill and resume executables, which know the token before the
+    host does. A request that ends at its first token (``eos``, or
+    ``maxnew == 1``) is armed not live, so no dispatch decodes a ghost of
+    it."""
+    ln, tk, ac, gc, mn, tp, kk, sd = state
+    live = maxnew > 1
+    if eos is not None:
+        live = live & (tok != eos)
+    return (ln.at[slot].set(length), tk.at[slot].set(tok),
+            ac.at[slot].set(live), gc.at[slot].set(1),
+            mn.at[slot].set(maxnew), tp.at[slot].set(temp),
+            kk.at[slot].set(topk), sd.at[slot].set(seed))
+
+
+def _request_scalars(req: Request, slot: int, start: int = 0):
+    """What an admission's executable is told of its request, as the two
+    host arrays that cross with it: ``(slot, prompt_len, max_new_tokens,
+    top_k, seed, start)`` int32 (``start``: the prompt tokens a resume
+    finds cached) and the temperature float32."""
+    return (np.array([slot, req.prompt_len, req.max_new_tokens, req.top_k,
+                      req.seed, start], np.int32),
+            np.asarray(req.temperature, np.float32))
+
+
+_SCALARS_ABS = (jax.ShapeDtypeStruct((6,), jnp.int32),
+                jax.ShapeDtypeStruct((), jnp.float32))
+
+
 class _Dispatch(NamedTuple):
     """One decode dispatch from its launch to the read of its outputs."""
 
@@ -425,16 +462,7 @@ class ServingEngine:
         self.scheduler = Scheduler(self.cfg.slots, self.cfg.max_queue)
         self._cache = self.cache_ops.init_state()
         b = self.cfg.slots
-        self._len = jnp.zeros((b,), jnp.int32)
-        self._tok = jnp.zeros((b,), jnp.int32)
-        self._active = jnp.zeros((b,), jnp.bool_)
-        self._gen = jnp.zeros((b,), jnp.int32)
-        self._maxnew = jnp.ones((b,), jnp.int32)
-        # per-slot sampling params (ride the decode dispatch as plain
-        # arguments; 0-temperature slots run the exact greedy path)
-        self._temp = jnp.zeros((b,), jnp.float32)
-        self._topk = jnp.zeros((b,), jnp.int32)
-        self._seed = jnp.zeros((b,), jnp.int32)
+        self._reset_slot_state()
         self._prefill_exe: Dict[int, Any] = {}   # bucket -> AOT executable
         self._decode_exe: Dict[int, Any] = {}    # fuse length -> executable
         self._resume_exe: Dict[int, Any] = {}    # remainder bucket -> exe
@@ -505,6 +533,23 @@ class ServingEngine:
                     "PADDLE_TPU_TELEMETRY_DIR is unset — no export ticks "
                     "will run, so the SLOs are inert (health() cannot "
                     "degrade on them)", len(specs))
+
+    def _reset_slot_state(self) -> None:
+        """Every slot empty: nobody live, nothing generated."""
+        b = self.cfg.slots
+        self._len = jnp.zeros((b,), jnp.int32)
+        self._tok = jnp.zeros((b,), jnp.int32)
+        self._active = jnp.zeros((b,), jnp.bool_)
+        self._gen = jnp.zeros((b,), jnp.int32)
+        self._maxnew = jnp.ones((b,), jnp.int32)
+        # per-slot sampling params (ride the decode dispatch as plain
+        # arguments; 0-temperature slots run the exact greedy path)
+        self._temp = jnp.zeros((b,), jnp.float32)
+        self._topk = jnp.zeros((b,), jnp.int32)
+        self._seed = jnp.zeros((b,), jnp.int32)
+
+    def _slot_state(self) -> tuple:
+        return tuple(getattr(self, name) for name in _SLOT_STATE)
 
     def _refuse_over_groups(self, layer_groups, latent=None) -> None:
         """What cannot work over a cache of more than one group, or over a
@@ -1011,9 +1056,14 @@ class ServingEngine:
         a prefix cache armed, a prompt whose page-aligned prefix is cached
         skips the full prefill: its pages are row-copied and only the
         remainder runs (the resume executable). Either way the admission
-        is ONE ``serving/prefill`` span, launch to slot armed: its length
-        is the request's ``prefill_s`` and what the prefill clock advances
-        by."""
+        is ONE device program (``serving/admission_programs`` counts them)
+        and one read: the executable points the slot's page table at the
+        request's pages, writes the prompt's rows, draws the first token
+        and arms the slot's per-slot state with it (:func:`_arm_slot`), so
+        the host launches nothing after the token is read. And it is ONE
+        ``serving/prefill`` span, launch to slot armed: its length is the
+        request's ``prefill_s`` (``serving/admission_ms``) and what the
+        prefill clock advances by."""
         entry = None
         if self.prefix_cache is not None:
             entry = self.prefix_cache.lookup(req.prompt)
@@ -1029,9 +1079,11 @@ class ServingEngine:
                 return self._prefill_cold(req, slot, bucket)
         finally:
             # the whole admission on the span's own two clock reads: launch,
-            # sync and the slot's arming (serving/prefill_ms stops before
-            # the arming and keeps its meaning)
+            # sync and the host's bookkeeping (serving/prefill_ms starts at
+            # the executable's argument transfers and stops when the token
+            # is on the host, and keeps its meaning)
             req.prefill_s = admission.t1 - admission.t0
+            _sm.ADMISSION_MS.observe(req.prefill_s * 1e3)
             self._prefill_closed_s += req.prefill_s
             self._prefill_open_t0 = None
 
@@ -1043,26 +1095,15 @@ class ServingEngine:
         with _span("serving/prefill.launch"):
             prompt = np.full((bucket,), cfg.pad_id, np.int32)
             prompt[:req.prompt_len] = req.prompt
-            if cfg.paged:
-                dest = jnp.asarray(
-                    self.cache_ops.prompt_dest_groups(req.group_pages))
-                self._cache = self.cache_ops.set_page_table(
-                    self._cache, slot, dest)
-            else:
-                dest = jnp.asarray(self.cache_ops.prompt_dest(slot))
+            dest = (self.cache_ops.prompt_dest_groups(req.group_pages)
+                    if cfg.paged else self.cache_ops.prompt_dest(slot))
             exe = self._get_prefill_exe(bucket)
             # serving/prefill_ms starts here, as it always has: at the
             # transfers of the executable's own arguments
             t0 = time.perf_counter()
-            self._cache, first_tok, last_logits = exe(
-                self.params, self._cache, dest, jnp.asarray(prompt),
-                jnp.asarray(req.prompt_len, jnp.int32),
-                jnp.asarray(req.temperature, jnp.float32),
-                jnp.asarray(req.top_k, jnp.int32),
-                jnp.asarray(req.seed, jnp.int32))
-        with _span("serving/prefill.sync") as sync:
-            tok = int(np.asarray(first_tok))
-        t1 = sync.t1
+            first_tok, last_logits = self._admit_on_device(
+                exe, dest, prompt, *_request_scalars(req, slot))
+        t1, tok = self._first_token(first_tok)
         _trace.on_prefill(req, slot, bucket, t0, t1, cause="local")
         _sm.PREFILL_MS.observe((t1 - t0) * 1e3)
         _sm.PREFILL_COUNT.inc()
@@ -1071,45 +1112,33 @@ class ServingEngine:
 
     def _prefill_from_prefix(self, req: Request, slot: int, entry
                              ) -> Optional[Request]:
-        """Serve admission from a prefix-cache hit: point this slot's page
-        table at the request's pages, row-copy the cached prefix KV into
-        them, then run ONLY the prompt remainder through the resume
-        executable (teacher-forced decode over the model's own serving
+        """Serve admission from a prefix-cache hit: the resume executable
+        points this slot's page table at the request's pages, row-copies
+        the cached prefix KV into them, then runs ONLY the prompt remainder
+        (teacher-forced decode over the model's own serving
         contract — model-agnostic, no second prefill trace). The first
         sampled token is keyed (seed, prompt_len-1), identical to the cold
         prefill path, so hit and miss generate the same stream."""
-        ps = self.cfg.page_size
         n = entry.n_tokens
         npages = len(entry.pages)
         with _span("serving/prefill.launch"):
-            dest_np = self.cache_ops.prompt_dest(req.pages)
-            self._cache["pt"] = self._cache["pt"].at[slot].set(
-                jnp.asarray(dest_np))
-            rows = np.arange(ps, dtype=np.int32)
-            src = np.concatenate([p * ps + rows for p in entry.pages])
-            dst = np.concatenate([p * ps + rows for p in req.pages[:npages]])
+            dest = self.cache_ops.prompt_dest(req.pages)
+            # the cached pages onto the request's first ones; the pairs
+            # left over copy the request's next page onto itself (int8
+            # layout: per-page scales are fixed constants, rows copy 1:1)
+            src = np.full_like(dest, req.pages[npages])
+            src[:npages] = entry.pages
+            dst = src.copy()
+            dst[:npages] = dest[:npages]
             t0 = time.perf_counter()
-            self._cache["k"] = self._cache["k"].at[:, dst].set(
-                self._cache["k"][:, src])
-            self._cache["v"] = self._cache["v"].at[:, dst].set(
-                self._cache["v"][:, src])
-            # (int8 layout: per-page scales are fixed constants — rows copy
-            # 1:1)
             rbucket = self._bucket_for(req.prompt_len - n)
             remainder = np.full((rbucket,), self.cfg.pad_id, np.int32)
             remainder[:req.prompt_len - n] = req.prompt[n:]
             exe = self._get_resume_exe(rbucket)
-            self._cache, first_tok, last_logits = exe(
-                self.params, self._cache, jnp.asarray(remainder),
-                jnp.asarray(n, jnp.int32),
-                jnp.asarray(req.prompt_len, jnp.int32),
-                jnp.asarray(slot, jnp.int32),
-                jnp.asarray(req.temperature, jnp.float32),
-                jnp.asarray(req.top_k, jnp.int32),
-                jnp.asarray(req.seed, jnp.int32))
-        with _span("serving/prefill.sync") as sync:
-            tok = int(np.asarray(first_tok))
-        t1 = sync.t1
+            first_tok, last_logits = self._admit_on_device(
+                exe, dest, remainder, *_request_scalars(req, slot, n), src,
+                dst)
+        t1, tok = self._first_token(first_tok)
         _trace.on_prefill(req, slot, rbucket, t0, t1, cause="resume")
         _sm.PREFILL_MS.observe((t1 - t0) * 1e3)
         # deliberately NOT PREFILL_COUNT: the bench's "reduced prefill
@@ -1117,18 +1146,46 @@ class ServingEngine:
         self._resumes += 1
         return self._finish_prefill(req, slot, tok, last_logits)
 
+    def _admit_on_device(self, exe, *args):
+        """Call an admission's executable (prefill or resume) on the cache
+        and the per-slot state as they stand (the outputs of the decode
+        dispatch in flight, read or not) and take both back armed. The
+        cache is donated; the eight state arrays are not, and are
+        reassigned only here, after the call returned: a call that raises
+        leaves them, and the page table, as they were. Returns the first
+        token and the last row's logits, still on the device."""
+        out = exe(self.params, self._cache, self._slot_state(), *args)
+        _sm.ADMISSION_PROGRAMS.inc()
+        self._cache, state, first_tok, last_logits = out
+        for name, x in zip(_SLOT_STATE, state):
+            setattr(self, name, x)
+        first_tok.copy_to_host_async()
+        return first_tok, last_logits
+
+    @staticmethod
+    def _first_token(first_tok):
+        """The admission's one read: ``(the instant it ended, the token)``.
+        It drains the pipe: the executable queued behind the decode
+        dispatch in flight."""
+        with _span("serving/prefill.sync") as sync:
+            tok = int(np.asarray(first_tok))
+        return sync.t1, tok
+
     def _finish_prefill(self, req: Request, slot: int, tok: int,
                         last_logits) -> Optional[Request]:
-        """Post-prefill bookkeeping shared by the cold and prefix-hit
-        paths: TTFT, first token, immediate retirement, slot arming."""
+        """What is the host's of an admission, shared by the cold and
+        prefix-hit paths: TTFT, the first token and its stamp, the slot's
+        draft width, immediate retirement. It launches nothing: the
+        executable armed the slot on the device (:func:`_arm_slot`), not
+        live where the request ends here."""
         cfg = self.cfg
         _sm.TOKENS_GENERATED.inc()
         now = time.perf_counter()
         req.first_token_t = now
         _sm.TTFT_MS.observe((now - req.submitted_t) * 1e3)
         req.tokens_out.append(tok)
-        # its own admission is open: what is left of it (the arming below)
-        # counts as its own stall, since its second token waits for it
+        # its own admission is open: what is left of it counts as its own
+        # stall, since its second token waits for it
         req.timeline.append((now, 1, self.prefill_clock(now)))
         if cfg.collect_logits:
             self._captured_logits.setdefault(req.id, []).append(
@@ -1136,14 +1193,6 @@ class ServingEngine:
         if (cfg.eos_id is not None and tok == cfg.eos_id) \
                 or req.max_new_tokens == 1:
             return self._retire(slot)
-        self._len = self._len.at[slot].set(req.prompt_len)
-        self._tok = self._tok.at[slot].set(tok)
-        self._active = self._active.at[slot].set(True)
-        self._gen = self._gen.at[slot].set(1)
-        self._maxnew = self._maxnew.at[slot].set(req.max_new_tokens)
-        self._temp = self._temp.at[slot].set(req.temperature)
-        self._topk = self._topk.at[slot].set(req.top_k)
-        self._seed = self._seed.at[slot].set(req.seed)
         k = self._request_spec_k(req)
         self._spec_k[slot] = k
         if k > 0:
@@ -1595,15 +1644,7 @@ class ServingEngine:
             req.error = self._last_error
             failed.append(self._retire(slot, state=FAILED,
                                        clear_slot=False))
-        b = self.cfg.slots
-        self._len = jnp.zeros((b,), jnp.int32)
-        self._tok = jnp.zeros((b,), jnp.int32)
-        self._active = jnp.zeros((b,), jnp.bool_)
-        self._gen = jnp.zeros((b,), jnp.int32)
-        self._maxnew = jnp.ones((b,), jnp.int32)
-        self._temp = jnp.zeros((b,), jnp.float32)
-        self._topk = jnp.zeros((b,), jnp.int32)
-        self._seed = jnp.zeros((b,), jnp.int32)
+        self._reset_slot_state()
         if self._cache_lost():
             self._cache = self.cache_ops.init_state()
             if self.prefix_cache is not None and self.pool is not None:
@@ -1649,7 +1690,10 @@ class ServingEngine:
 
         last_only = hasattr(model, "prefill_last")
 
-        def prefill(params, cache, dest, prompt, length, temp, topk, seed):
+        def prefill(params, cache, state, dest, prompt, ints, temp):
+            slot, length, maxnew, topk, seed, _ = ints
+            if cfg.paged:
+                cache = ops.set_page_table(cache, slot, dest)
             if last_only:
                 logits, kvs = model.prefill_last(params, prompt[None],
                                                  length[None])
@@ -1666,18 +1710,16 @@ class ServingEngine:
             # length+1, ... — the streams can't collide)
             tok = _sample_tokens(last[None], temp[None], topk[None],
                                  seed[None], (length - 1)[None])[0]
-            return cache, tok, last
+            state = _arm_slot(state, slot, length, tok, maxnew, temp, topk,
+                              seed, cfg.eos_id)
+            return cache, state, tok, last
 
         dest_abs = (jax.ShapeDtypeStruct((ops.page_table_len,), jnp.int32)
                     if cfg.paged else jax.ShapeDtypeStruct((), jnp.int32))
         exe = aot_compile(
             prefill,
-            (self.params, self._cache, dest_abs,
-             jax.ShapeDtypeStruct((bucket,), jnp.int32),
-             jax.ShapeDtypeStruct((), jnp.int32),
-             jax.ShapeDtypeStruct((), jnp.float32),
-             jax.ShapeDtypeStruct((), jnp.int32),
-             jax.ShapeDtypeStruct((), jnp.int32)),
+            (self.params, self._cache, self._slot_state(), dest_abs,
+             jax.ShapeDtypeStruct((bucket,), jnp.int32)) + _SCALARS_ABS,
             donate_argnums=(1,))
         self._prefill_exe[bucket] = exe
         return exe
@@ -1825,9 +1867,11 @@ class ServingEngine:
         model's own decode contract (each step writes KV at its absolute
         position), then sample the first generated token from the final
         step's logits, keyed (seed, prompt_len-1) — exactly the cold
-        prefill's keying, so the sampled stream is path-independent.
-        Compiled once per remainder bucket, cache donated like every other
-        step function."""
+        prefill's keying, so the sampled stream is path-independent. Before
+        that it points the slot's page table at the request's pages and
+        copies the cached prefix's pages onto them (``src`` onto ``dst``);
+        after it, it arms the slot as the prefill does. Compiled once per
+        remainder bucket, cache donated like every other step function."""
         exe = self._resume_exe.get(rbucket)
         if exe is not None:
             return exe
@@ -1835,8 +1879,10 @@ class ServingEngine:
         b = cfg.slots
         vocab = self.model.cfg.vocab_size
 
-        def resume(params, cache, toks, start, length, slot, temp, topk,
-                   seed):
+        def resume(params, cache, state, dest, toks, ints, temp, src, dst):
+            slot, length, maxnew, topk, seed, start = ints
+            cache = ops.copy_pages(ops.set_page_table(cache, slot, dest),
+                                   src, dst)
             slotmask = jnp.arange(b, dtype=jnp.int32) == slot
             tempv = jnp.where(slotmask, temp, 0.0).astype(jnp.float32)
             topkv = jnp.where(slotmask, topk, 0).astype(jnp.int32)
@@ -1865,18 +1911,16 @@ class ServingEngine:
                     jnp.zeros((vocab,), jnp.float32))
             (cache, tok, last), _ = jax.lax.scan(
                 body, init, jnp.arange(rbucket, dtype=jnp.int32))
-            return cache, tok, last
+            state = _arm_slot(state, slot, length, tok, maxnew, temp, topk,
+                              seed, cfg.eos_id)
+            return cache, state, tok, last
 
+        pages_abs = jax.ShapeDtypeStruct((ops.page_table_len,), jnp.int32)
         exe = aot_compile(
             resume,
-            (self.params, self._cache,
-             jax.ShapeDtypeStruct((rbucket,), jnp.int32),
-             jax.ShapeDtypeStruct((), jnp.int32),
-             jax.ShapeDtypeStruct((), jnp.int32),
-             jax.ShapeDtypeStruct((), jnp.int32),
-             jax.ShapeDtypeStruct((), jnp.float32),
-             jax.ShapeDtypeStruct((), jnp.int32),
-             jax.ShapeDtypeStruct((), jnp.int32)),
+            (self.params, self._cache, self._slot_state(), pages_abs,
+             jax.ShapeDtypeStruct((rbucket,), jnp.int32)) + _SCALARS_ABS
+            + (pages_abs, pages_abs),
             donate_argnums=(1,))
         self._resume_exe[rbucket] = exe
         return exe

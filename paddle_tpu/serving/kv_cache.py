@@ -262,6 +262,18 @@ class PagedKVCache(_KVCacheBase):
                 dest[self._pt_start[gi]:self._pt_start[gi + 1]])
         return out
 
+    def copy_pages(self, state: Cache, src, dst) -> Cache:
+        """Every layer's K and V rows of pool pages ``src`` written to pages
+        ``dst`` (int32 vectors of one length; a pair with ``src == dst``
+        leaves its page as it is, which is how a caller pads them)."""
+        self._single_group("page copy")
+        rows = jnp.arange(self.page_size, dtype=jnp.int32)
+        s = (src[:, None] * self.page_size + rows).reshape(-1)
+        d = (dst[:, None] * self.page_size + rows).reshape(-1)
+        return {**state,
+                "k": state["k"].at[:, d].set(state["k"][:, s]),
+                "v": state["v"].at[:, d].set(state["v"][:, s])}
+
     # -- decode (one token per slot) -----------------------------------------
     def write_token(self, state: Cache, layer: int, k_new, v_new, pos,
                     active) -> Cache:

@@ -19,6 +19,7 @@ __all__ = [
     "DECODE_LAUNCHED_AHEAD",
     "TOKENS_GENERATED", "CYCLES", "SAMPLER_DISPATCHES",
     "REQUEST_LATENCY_MS", "TTFT_MS", "DECODE_STEP_MS", "PREFILL_MS",
+    "ADMISSION_MS", "ADMISSION_PROGRAMS",
     "TPOT_MS", "PREFILL_STALL_MS_PER_TOKEN",
     "FAULTS", "RETRIES", "TIMEOUTS", "REQUESTS_FAILED",
     "DRAINS", "DRAINED_REQUESTS", "DRAIN_REJECTED",
@@ -92,6 +93,20 @@ DECODE_STEP_MS = _mx.histogram(
          "read (all its fused steps; one observation a dispatch read)")
 PREFILL_MS = _mx.histogram(
     "serving/prefill_ms", help="host wall time of one compiled prefill call")
+ADMISSION_MS = _mx.histogram(
+    "serving/admission_ms",
+    help="host wall time of one admission whole, the length of its "
+         "serving/prefill span (Request.prefill_s): the launch, the "
+         "executable and the read of its first token, and the host's "
+         "bookkeeping around them. Over serving/prefill_ms, which starts at "
+         "the executable's argument transfers and ends with the token on "
+         "the host: what an admission costs the host beyond its executable")
+ADMISSION_PROGRAMS = _mx.counter(
+    "serving/admission_programs",
+    help="device programs the engine launched inside serving/prefill "
+         "spans: over the admissions (serving/prefills and the prefix "
+         "resumes) it reads 1, the prefill or resume executable, which "
+         "writes the slot's page table and arms its per-slot state itself")
 TPOT_MS = _mx.histogram(
     "serving/tpot_ms",
     help="mean gap between a finished request's output tokens: the time "
@@ -103,7 +118,7 @@ PREFILL_STALL_MS_PER_TOKEN = _mx.histogram(
     help="the part of serving/tpot_ms the request spent behind admissions: "
          "the engine's prefill clock (seconds inside serving/prefill spans) "
          "at its last hand-over minus at its first token, over the tokens "
-         "between; the rest of its own arming included. An upper bound by "
+         "between; the rest of its own admission included. An upper bound by "
          "up to one decode step an admission (the prefill's sync drains "
          "the decode dispatch in flight too)")
 FAULTS = _mx.counter(

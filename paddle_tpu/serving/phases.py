@@ -12,7 +12,7 @@ Phase taxonomy — every microsecond of a request's life lands in one of:
   ``router``, first attempt) and the engine's admission queue (cause
   ``engine``); a drain shedding queued work closes with cause ``shed``.
 * ``admission`` — the scheduler gap between engine admission and the
-  prefill dispatch actually starting (slot arming, page reservation).
+  prefill dispatch actually starting (slot assignment, page reservation).
 * ``prefill``   — the prefill dispatch; ``cause`` distinguishes a cold
   local prefill (``local``) from a prefix-cache resume (``resume``) —
   the resume path is also how a remote-prefill replica's shipped pages

@@ -77,8 +77,11 @@ class Request:
     decode dispatch that brought at least one token, however many, at the
     end of the sync that read it. The difference of two entries' clocks is
     what the request lost behind admissions between them (the rest of its
-    own arming included: its second token waits for it). ``prefill_s`` is
-    the length of its own ``serving/prefill`` span, launch to slot armed.
+    own admission included: its second token waits for it). ``prefill_s``
+    is the length of its own ``serving/prefill`` span, launch to slot armed
+    (the prefill executable arms the slot itself, so what follows the read
+    of the first token is the host's bookkeeping alone);
+    ``serving/admission_ms`` observes it.
     """
 
     __slots__ = ("id", "prompt", "max_new_tokens", "state", "slot", "pages",

@@ -319,6 +319,17 @@ def _serve_case(name, chip, n_layer=None):
                             [x[None] for x in sampling],
                             (length - 1)[None])[0]
 
+    def prefill_armed(params, cache, state, dest, prompt, ints, temp):
+        # an admission whole, as the engine launches it: the slot's page
+        # table, the prompt's rows, the token, the slot's per-slot state
+        from paddle_tpu.serving.engine import _arm_slot
+
+        slot, length, maxnew, topk, seed, _ = ints
+        cache, tok = prefill(params, ops.set_page_table(cache, slot, dest),
+                             dest, prompt, length)
+        return cache, _arm_slot(state, slot, length, tok, maxnew, temp,
+                                topk, seed, None), tok
+
     def resume(params, cache, toks, start, length, slot):
         mask = jnp.arange(b, dtype=jnp.int32) == slot
 
@@ -343,14 +354,21 @@ def _serve_case(name, chip, n_layer=None):
         "verify": (verify, (params, cache, ints, sds((b, w), jnp.int32),
                             flags, sds((b, w), jnp.bool_))),
         "prefill": (prefill, prefill_args),
+        "prefill_armed": (prefill_armed, (
+            params, cache, (ints, ints, flags, ints, ints,
+                            sds((b,), jnp.float32), ints, ints),
+            *prefill_args[2:4], sds((6,), jnp.int32), sds((), jnp.float32))),
         "resume": (resume, (params, cache, sds((g["page_size"],), jnp.int32),
                             scalar, scalar, scalar)),
     }[name], ops.num_rows
 
 
-@pytest.mark.parametrize("exe", ["chunk", "verify", "prefill", "resume"])
+@pytest.mark.parametrize("exe", ["chunk", "verify", "prefill",
+                                 "prefill_armed", "resume"])
 def test_serving_executables_leave_the_pool_in_place(chip, monkeypatch, exe):
-    """The decode chunk, the verify window, a prefill bucket and the resume
+    """The decode chunk, the verify window, a prefill bucket (alone, and as
+    the admission the engine launches: with the slot's page table and its
+    per-slot state written in the same program) and the resume
     scan write the 1.2 GB pool where it lies and hand it to the kernel
     whole: no ``copy``, ``slice``, ``dynamic-slice`` or ``transpose`` with
     the pool's row count in its result or an operand, both pools aliased
@@ -366,7 +384,7 @@ def test_serving_executables_leave_the_pool_in_place(chip, monkeypatch, exe):
     (fn, args), rows = _serve_case(exe, chip)
     compiled = jax.jit(fn, donate_argnums=(1,)).lower(*args).compile()
     text = compiled.as_text()
-    if exe != "prefill":  # the prefill attends over its own K and V
+    if not exe.startswith("prefill"):  # it attends over its own K and V
         assert text.count("tpu_custom_call") == SERVE["n_layer"]
     instructions = list(_instructions(text))
     types = {name: rtype for name, rtype, _, _ in instructions}
@@ -382,6 +400,8 @@ def test_serving_executables_leave_the_pool_in_place(chip, monkeypatch, exe):
     aliased = {int(p) for p in re.findall(
         r"\((\d+), \{\}, (?:may|must)-alias\)", text.split("\n", 1)[0])}
     assert {n_params, n_params + 2} <= aliased
+    if exe == "prefill_armed":  # the page table is written where it lies
+        assert n_params + 1 in aliased
     assert compiled.memory_analysis().temp_size_in_bytes < 64 * 2 ** 20
 
 

@@ -706,3 +706,235 @@ def test_no_dispatch_is_left_unread_and_no_token_lost(how, rng):
         assert req.tokens_out == want
     assert eng.page_accounting_ok()
     eng.close()
+
+
+# -- an admission is one device program and one read -------------------------
+
+_ADMISSION_PATH = ("_prefill", "_prefill_cold", "_prefill_from_prefix",
+                   "_admit_on_device", "_first_token", "_finish_prefill")
+
+
+def _admission_engine(cache):
+    """``(engine, stream)`` over each kind of cache an admission writes:
+    ``stream`` a list of ``(prompt, max_new_tokens)`` whose admissions fall
+    in several cycles (two slots)."""
+    family = {"grouped": "smallthinker", "latent": "kimi"}.get(cache, "gpt2")
+    if family not in _TOYS:
+        _TOYS[family] = _toy(family)
+    model = _TOYS[family][0]
+    kw = {"contiguous": {"paged": False},
+          "resume": {"num_pages": 32, "prefix_cache_pages": 8}}.get(cache, {})
+    eng = serving.ServingEngine(model, serving.ServingConfig(
+        slots=2, page_size=8, max_seq=64, prompt_buckets=(8, 16, 32), **kw))
+    r = np.random.RandomState(7)
+    vocab = model.cfg.vocab_size
+    stream = [(list(r.randint(0, vocab, n)), m)
+              for n, m in ((5, 6), (19, 3), (11, 1), (27, 4), (4, 5))]
+    if cache == "resume":       # one prompt again and again: hits after one
+        stream = [(stream[1][0], m) for _, m in stream]
+    return eng, stream
+
+
+@pytest.mark.parametrize("cache", ["paged", "grouped", "latent",
+                                   "contiguous", "resume"])
+def test_an_admission_launches_one_program(cache):
+    """``serving/admission_programs`` moves by exactly one an admission on
+    every cache the engine writes at admission (one group, two groups, the
+    latent rows, the contiguous layout) and on a prefix-cache resume, whose
+    page-table write and row copies ride in the resume executable; and
+    ``serving/admission_ms`` observes each request's ``prefill_s``."""
+    from paddle_tpu.serving import metrics as sm
+
+    eng, stream = _admission_engine(cache)
+    assert len(eng.pools) == {"grouped": 2, "contiguous": 0}.get(cache, 1)
+    before = (sm.ADMISSION_PROGRAMS.value, sm.ADMISSION_MS.count,
+              sm.ADMISSION_MS.sum, sm.PREFILL_MS.count)
+    reqs = []
+    for prompt, m in stream:    # one at a time: each a cycle of its own
+        reqs.append(eng.submit(prompt, m))
+        n0 = sm.ADMISSION_PROGRAMS.value
+        eng.step()
+        assert sm.ADMISSION_PROGRAMS.value - n0 == 1
+        eng.run()
+    eng.close()
+    assert all(r.state == "finished" and len(r.tokens_out) == m
+               for r, (_, m) in zip(reqs, stream))
+    admissions = eng._prefills + eng._resumes
+    assert admissions == len(stream)
+    assert eng._resumes == (len(stream) - 1 if cache == "resume" else 0)
+    assert sm.ADMISSION_PROGRAMS.value - before[0] == admissions
+    assert sm.ADMISSION_MS.count - before[1] == admissions
+    assert sm.PREFILL_MS.count - before[3] == admissions
+    assert sm.ADMISSION_MS.sum - before[2] == pytest.approx(
+        sum(r.prefill_s for r in reqs) * 1e3)
+    if cache == "resume":       # a hit serves the cold prefill's stream
+        assert all(r.tokens_out == reqs[0].tokens_out[:len(r.tokens_out)]
+                   for r in reqs)
+
+
+def test_the_admission_path_holds_no_eager_device_call():
+    """Between a ``serving/prefill`` span's open and close the engine calls
+    one executable and reads one token: none of the path's functions
+    touches ``jnp`` or an ``.at[...]`` update, and the executable's
+    arguments are built with numpy. No option selects another path."""
+    import inspect
+
+    for name in _ADMISSION_PATH:
+        src = inspect.getsource(getattr(serving.ServingEngine, name))
+        assert "jnp." not in src and ".at[" not in src, name
+    both = "".join(inspect.getsource(getattr(serving.ServingEngine, name))
+                   for name in ("_prefill_cold", "_prefill_from_prefix"))
+    assert both.count("self._admit_on_device(") == 2
+    assert inspect.getsource(
+        serving.ServingEngine._admit_on_device).count("exe(") == 1
+    params = inspect.signature(serving.ServingConfig.__init__).parameters
+    assert not [p for p in params if "arm" in p or "admission" in p]
+
+
+# recorded on the parent of the PR that moved the arming into the prefill
+# executable (6fd5412), where ten programs wrote what one writes now:
+# RandomState(40) prompts of 3, 19, 5, 11, 8, 27, 4 tokens through two slots
+_RECORDED = [
+    ((9, 0.0, 0, None), [23] * 9),
+    ((5, 0.8, 0, 11), [10, 12, 57, 34, 11]),
+    ((14, 1.3, 5, 12),
+     [15, 57, 1, 3, 60, 42, 39, 31, 58, 5, 24, 41, 41, 40]),
+    ((2, 0.0, 0, None), [7, 7]),
+    ((1, 0.7, 3, 13), [55]),
+    ((11, 0.0, 0, None), [24] * 11),
+    ((7, 1.0, 8, 14), [47, 35, 54, 62, 62, 8, 52]),
+]
+
+
+@pytest.mark.parametrize("fuse", [1, 4])
+def test_greedy_and_sampled_streams_are_the_recorded_parents(fuse):
+    """Greedy and sampled (``temperature``, ``top_k``, ``seed``) requests,
+    admitted while a decode dispatch is unread: every token is the one the
+    parent served, at ``decode_fuse`` 1 and 4."""
+    r = np.random.RandomState(40)
+    prompts = [list(map(int, r.randint(0, 64, n)))
+               for n in (3, 19, 5, 11, 8, 27, 4)]
+    eng = serving.ServingEngine(get_model(), serving.ServingConfig(
+        slots=2, page_size=8, max_seq=64, prompt_buckets=(8, 16, 32),
+        decode_fuse=fuse))
+
+    def submit(i):
+        (m, temp, top_k, seed), _ = _RECORDED[i]
+        return eng.submit(prompts[i], m, temperature=temp, top_k=top_k,
+                          seed=seed)
+
+    reqs = [submit(i) for i in range(4)]
+    unread_at_admission = 0
+    for _ in range(3):
+        unread_at_admission += eng._unread is not None \
+            and eng.scheduler.queue_depth > 0
+        eng.step()
+    reqs += [submit(i) for i in range(4, 7)]
+    while not eng.scheduler.idle():
+        unread_at_admission += eng._unread is not None \
+            and eng.scheduler.queue_depth > 0
+        eng.step()
+    eng.close()
+    assert unread_at_admission >= 2
+    assert [q.tokens_out for q in reqs] == [want for _, want in _RECORDED]
+
+
+@pytest.mark.parametrize("ends", ["eos", "max_new_tokens"])
+def test_a_request_that_ends_at_its_first_token_is_armed_not_live(
+        ends, rng, attention_spy):
+    """The executable arms the slot of a request that ends at its first
+    token (EOS, or ``max_new_tokens == 1``) with ``active`` false: the
+    slot keeps the prompt's length (the attention is handed the context a
+    live slot would have, its own row included), every later dispatch is
+    given 0 rows for it and emits nothing there, and the request beside it
+    decodes its own stream."""
+    model = get_model()
+    ender = list(rng.randint(0, 64, 8))
+    stayer = list(rng.randint(0, 64, 5))
+    first, _ = decoder_lm.reference_decode(model.params, model.cfg, ender, 1)
+    eos = first[0] if ends == "eos" else None
+    eng = serving.ServingEngine(model, small_config(eos_id=eos))
+    gone = eng.submit(ender, 6 if ends == "eos" else 1)
+    # (with an EOS set the stayer's own stream must not hold it)
+    want, _ = decoder_lm.reference_decode(model.params, model.cfg, stayer, 12)
+    n = want.index(eos) + 1 if eos in want else 12
+    stays = eng.submit(stayer, n)
+    done = eng.step()
+    assert done == [gone] and gone.state == "finished"
+    assert gone.tokens_out == first
+    state = {name: np.asarray(getattr(eng, name))
+             for name in ("_active", "_len", "_gen")}
+    assert not state["_active"][0] and state["_active"][1] == (n > 1)
+    assert state["_len"][0] == len(ender) and state["_gen"][0] == 1
+    eng.run()
+    eng.close()
+    assert stays.tokens_out == want[:n] and len(gone.tokens_out) == 1
+    for active, kept, rows in attention_spy():
+        assert not active[0] and kept[0] == len(ender) + 1
+        assert not rows[0].any()
+    assert eng.page_accounting_ok() and eng.pool.num_used == 0
+
+
+def test_a_prefill_that_raises_leaves_the_slot_state_and_the_table(rng):
+    """The eight state arrays are reassigned only after the executable
+    returned, and the page table is written inside it: a call that raises
+    leaves all nine as they were, and the prefill that follows serves the
+    model's own stream."""
+    from paddle_tpu.serving import engine as eng_mod
+
+    model = get_model()
+    eng = serving.ServingEngine(model, small_config(slots=2))
+    first = eng.submit(list(rng.randint(0, 64, 9)), 12)
+    eng.step()
+    eng.step()
+    held = eng._slot_state()
+    table = np.asarray(eng._cache["pt"]).copy()
+    real = eng._get_prefill_exe(16)
+
+    def raises(*args):
+        raise RuntimeError("injected before the executable ran")
+
+    eng._prefill_exe[16] = raises
+    prompt = list(rng.randint(0, 64, 6))
+    late = eng.submit(prompt, 4)
+    with pytest.raises(RuntimeError, match="injected"):
+        eng.step()
+    assert late.slot == 1 and late.tokens_out == []
+    assert all(a is b for a, b in zip(eng._slot_state(), held))
+    assert len(held) == len(eng_mod._SLOT_STATE) == 8
+    assert np.array_equal(np.asarray(eng._cache["pt"]), table)
+    assert late.prefill_s is not None       # the span closed all the same
+    # the slot's tenant never ran: vacate it, then serve the prompt again
+    eng._retire(1, state="failed")
+    eng._prefill_exe[16] = real
+    again = eng.submit(prompt, 4)
+    eng.run()
+    eng.close()
+    want = [decoder_lm.reference_decode(model.params, model.cfg, p, m)[0]
+            for p, m in ((first.prompt, 12), (prompt, 4))]
+    assert [first.tokens_out, again.tokens_out] == want
+    assert eng.page_accounting_ok() and eng.pool.num_used == 0
+
+
+def test_a_dispatch_is_not_retried_across_an_arming(rng):
+    """``_roll_back``'s rule reads as before: from the state a dispatch
+    was launched on it can be launched again, unless a slot was armed
+    since (the snapshot knows nothing of the new tenant)."""
+    eng = serving.ServingEngine(get_model(), small_config(slots=2))
+    eng.submit(list(rng.randint(0, 64, 7)), 12)
+    eng.step()
+    eng.step()
+
+    def rolled_back():
+        d = eng._unread
+        live = (eng._cache, eng._len, eng._tok, eng._active, eng._gen)
+        return eng._roll_back(d._replace(snap=live))
+
+    assert rolled_back()
+    late = eng.submit(list(rng.randint(0, 64, 5)), 3)
+    assert eng._admit() == [] and late.slot == 1
+    assert np.asarray(eng._active).tolist() == [True, True]
+    assert not rolled_back()
+    eng.run()
+    eng.close()
+    assert late.state == "finished" and len(late.tokens_out) == 3
