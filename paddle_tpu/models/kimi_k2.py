@@ -58,7 +58,8 @@ class KimiK2Config:
                  rms_eps: float = 1e-6, max_seq: int = 16384,
                  dtype="float32",
                  experts_held: Optional[Sequence[int]] = None,
-                 bias_std: float = 0.001):
+                 bias_std: float = 0.001, n_group: int = 1,
+                 topk_group: int = 1):
         self.vocab_size = int(vocab_size)
         self.n_layer = int(n_layer)
         self.d_model = int(d_model)
@@ -68,6 +69,7 @@ class KimiK2Config:
         self.d_head = self.d_nope + self.d_rope      # a query's lanes
         self.d_dense, self.n_dense = int(d_dense), int(n_dense)
         self.n_expert, self.top_k = int(n_expert), int(top_k)
+        self.n_group, self.topk_group = int(n_group), int(topk_group)
         self.d_expert = int(d_expert)
         self.routed_scale = float(routed_scale)
         self.rope_theta = float(rope_theta)
@@ -178,9 +180,13 @@ def _swiglu(u, wg, wu, wd):
 def _latent(cfg, lp, h, pos):
     """What attention reads of the normed input ``h`` [..., d] at ``pos``
     [...]: the queries ``(q_nope, q_rope)`` [..., H, nope | rope], rotated,
-    and the cache row ``[c | kr']`` [..., rank + rope]."""
-    cq = _rms(h @ lp["wqa"], lp["gq"], cfg.rms_eps)
-    q = (cq @ lp["wqb"]).reshape(h.shape[:-1] + (cfg.n_head, cfg.d_head))
+    and the cache row ``[c | kr']`` [..., rank + rope]. A layer without a
+    query latent (``q_lora_rank`` null) projects ``h`` by ``wq``."""
+    if "wq" in lp:
+        q = h @ lp["wq"]
+    else:
+        q = _rms(h @ lp["wqa"], lp["gq"], cfg.rms_eps) @ lp["wqb"]
+    q = q.reshape(h.shape[:-1] + (cfg.n_head, cfg.d_head))
     kva = h @ lp["wkva"]
     c = _rms(kva[..., :cfg.kv_rank], lp["gkv"], cfg.rms_eps)
     kr = _rope(kva[..., cfg.kv_rank:], pos, cfg.inv_freq)
@@ -195,8 +201,10 @@ def _feed_forward(cfg, lp, x, row_valid):
     u = _rms(x, lp["g2"], cfg.rms_eps)
     if "wr" not in lp:
         return x + _swiglu(u, lp["wg"], lp["wu"], lp["wd"]), None
+    limited = ({} if cfg.n_group == 1 else
+               {"n_group": cfg.n_group, "topk_group": cfg.topk_group})
     idx, w = moe_ops.route_sigmoid_topk(u, lp["wr"], lp["br"], cfg.top_k,
-                                        cfg.routed_scale)
+                                        cfg.routed_scale, **limited)
     y, stats = moe_ops.expert_layer(
         u, idx, w, lp["wg"], lp["wu"], lp["wd"], n_expert=cfg.n_expert,
         held=(None if len(cfg.experts_held) == cfg.n_expert

@@ -23,8 +23,9 @@ run again, and nothing is dropped.
 The routing rule and the experts' activation are the caller's:
 :func:`route_topk` (softmax over the chosen logits) or
 :func:`route_sigmoid_topk` (sigmoid scores, a bias that selects and never
-weighs), and ``expert_layer(..., activation=)`` (ReLU by default: ReGLU
-experts; ``jax.nn.silu`` gives SwiGLU).
+weighs, group-limited where the model's router is), and
+``expert_layer(..., activation=)`` (ReLU by default: ReGLU experts;
+``jax.nn.silu`` gives SwiGLU).
 
 Precision: the router's logits accumulate in float32 and its softmax or
 sigmoid is float32; the experts' outputs are combined in float32.
@@ -52,16 +53,32 @@ def route_topk(h, wr, top_k: int) -> Tuple[jnp.ndarray, jnp.ndarray]:
         return idx.astype(jnp.int32), jax.nn.softmax(top, axis=-1)
 
 
-def route_sigmoid_topk(h, wr, bias, top_k: int, scale: float = 1.0
+def route_sigmoid_topk(h, wr, bias, top_k: int, scale: float = 1.0,
+                       n_group: int = 1, topk_group: int = 1
                        ) -> Tuple[jnp.ndarray, jnp.ndarray]:
-    """The DeepSeek-V3 family's router without group limits: ``s =
-    sigmoid(h wr)`` [N, E]; the ``top_k`` largest of ``s + bias`` are
-    CHOSEN, and weighed by ``s`` alone (the bias selects, never weighs),
-    normalised over the chosen ones and multiplied by ``scale``. Returns
-    ``(idx [N, k] int32, w [N, k] float32)``."""
+    """The DeepSeek-V3 family's router: ``s = sigmoid(h wr)`` [N, E]; the
+    ``top_k`` largest of ``s + bias`` are CHOSEN, and weighed by ``s``
+    alone (the bias selects, never weighs), normalised over the chosen
+    ones and multiplied by ``scale``. With ``n_group`` > 1 the choice is
+    group-limited: the E experts are ``n_group`` equal runs, a group's
+    score is the sum of its two largest ``s + bias``, only the
+    ``topk_group`` best groups stay, and the ``top_k`` are chosen among
+    their experts. ``n_group`` = ``topk_group`` = 1 is the plain top-k,
+    the same operations as before there were groups. Returns ``(idx [N,
+    k] int32, w [N, k] float32)``."""
     with jax.named_scope("moe/router"):
         s = jax.nn.sigmoid(jnp.dot(h, wr, preferred_element_type=jnp.float32))
-        _, idx = jax.lax.top_k(s + bias.astype(jnp.float32), top_k)
+        biased = s + bias.astype(jnp.float32)
+        if n_group > 1:
+            n, e = biased.shape
+            by_group = biased.reshape(n, n_group, e // n_group)
+            score = jnp.sum(jax.lax.top_k(by_group, 2)[0], axis=-1)
+            _, keep = jax.lax.top_k(score, topk_group)
+            kept = jnp.zeros((n, n_group), bool).at[
+                jnp.arange(n)[:, None], keep].set(True)
+            biased = jnp.where(jnp.repeat(kept, e // n_group, axis=1),
+                               biased, -jnp.inf)
+        _, idx = jax.lax.top_k(biased, top_k)
         chosen = jnp.take_along_axis(s, idx, axis=-1)
         w = chosen / (jnp.sum(chosen, axis=-1, keepdims=True) + 1e-20)
         return idx.astype(jnp.int32), w * scale

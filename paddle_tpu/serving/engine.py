@@ -38,8 +38,8 @@ from ..reliability import faults as _faults
 from . import metrics as _sm
 from . import speculative as _speculative
 from . import trace as _trace
-from .kv_cache import (CacheGroup, ContiguousKVCache, Int8PagedKVCache,
-                       LatentPagedCache, PagedKVCache)
+from .kv_cache import (KV, LATENT, STATE, CacheGroup, ContiguousKVCache,
+                       Int8PagedKVCache, LatentPagedCache, PagedKVCache)
 from .page_pool import PagePool, PagePoolExhausted
 from .request import (FAILED, FINISHED, REJECTED, TIMEOUT, DrainingError,
                       Request)
@@ -338,6 +338,18 @@ class ServingConfig:
         return tune.resolve_speculation_k(self.slots)
 
 
+def _layer_groups(mcfg):
+    """A model config's cache groups as ``(name, layers, window, kind)``:
+    ``cache_groups`` (an entry without a kind is K and V rows; with
+    ``latent_row`` and no ``cache_groups``, one latent group of every
+    layer), else one group of every layer that keeps every position."""
+    latent = getattr(mcfg, "latent_row", None)
+    groups = getattr(mcfg, "cache_groups", None) or [
+        ("latent" if latent else "global", tuple(range(mcfg.n_layer)), None,
+         LATENT if latent else KV)]
+    return [tuple(g) + (KV,) * (4 - len(g)) for g in groups]
+
+
 def _query_groups(mcfg, layer_groups):
     """``(n_kv, {group: G})`` of a model config: the KV heads, and for each
     cache group the query heads a KV head of its layers. ``n_head`` is one
@@ -349,7 +361,7 @@ def _query_groups(mcfg, layer_groups):
         heads = (heads,) * mcfg.n_layer
     n_kv = getattr(mcfg, "n_kv_head", heads[0])
     q_per_kv = {}
-    for name, layers, _window in layer_groups:
+    for name, layers, _window, _kind in layer_groups:
         of_group = sorted({int(heads[l]) for l in layers})
         if len(of_group) != 1 or of_group[0] % n_kv:
             raise ValueError(
@@ -368,17 +380,22 @@ class ServingEngine:
       the QUERY heads, one number or one a layer; optionally ``n_kv_head``
       (the heads of K and V, the same in every layer, which size the
       cache; default ``n_head``: the queries then are not grouped) and
-      ``cache_groups``, a list of ``(name, layers, window)`` (default: one
-      group of every layer that keeps every position; see
-      serving.kv_cache). The layers of a group have one ``n_head``, so
-      the query heads a KV head are the GROUP's; or
-      ``latent_row``, ``(rank, rope)``: the model keeps ONE ``[c | kr]``
-      row a token a layer (latent attention) and the cache is a
-      :class:`~.kv_cache.LatentPagedCache` sized from it,
+      ``cache_groups``, a list of ``(name, layers, window)`` or ``(name,
+      layers, window, kind)`` (default: one group of every layer that
+      keeps every position; see serving.kv_cache). The layers of a group
+      have one ``n_head``, so the query heads a KV head are the GROUP's;
+      or ``latent_row``, ``(rank, rope)``: the model's latent-attention
+      layers keep ONE ``[c | kr]`` row a token and the cache is a
+      :class:`~.kv_cache.LatentPagedCache` sized from it: over every
+      layer, or over the ``LATENT`` group of ``cache_groups`` beside a
+      ``STATE`` group whose layers keep ``slot_state`` ``(heads, dk, dv,
+      tail rows, tail width)`` a SLOT and no pages,
     * ``model.prefill(params, tokens[B,S], lengths[B]) -> (logits[B,S,V],
-      kvs)`` with ``kvs`` one ``(k, v)`` ``[B,S,H,D]`` pair per layer (H
-      the KV heads), or one ``(row,)`` ``[B,S,rank+rope]`` per layer of a
-      latent model: what the cache's ``write_prompt`` takes; a model with
+      kvs)`` with ``kvs``, a layer, what the cache's ``write_prompt``
+      takes with a leading batch axis: one ``(k, v)`` ``[B,S,H,D]`` pair
+      (H the KV heads), or one ``(row,)`` ``[B,S,rank+rope]`` of a latent
+      layer, or ``(state [B,H,dk,dv], tail [B,rows,width])`` of a state
+      layer: the state the prompt LEAVES, not rows; a model with
       ``prefill_last`` is asked for that
       instead: the same with ``logits[B,V]`` of each prompt's last row,
     * ``model.decode(params, cache, cache_ops, tokens[B], pos[B],
@@ -386,14 +403,18 @@ class ServingEngine:
       with ``stats`` a dict of small int arrays a step; the engine feeds
       ``moe_experts_touched``, ``moe_max_expert_rows`` and
       ``moe_held_pairs`` [n_layer] to the ``serving/*`` histograms of
-      those names, and ``attn_rows_read.<group>`` (what the cache's
-      ``rows_read`` gives) to ``serving/attn_rows_read.<group>``.
+      those names, ``state_slots_stepped`` to
+      ``serving/state_slots_stepped``, and ``attn_rows_read.<group>``
+      (what the cache's ``rows_read`` gives) to
+      ``serving/attn_rows_read.<group>``.
 
-    Over a cache of more than one group, and over a latent cache, the
-    engine refuses, at construction, what cannot work there: speculative
-    verify (a ring cannot be rolled back; a latent row is no K and V),
-    the int8 KV pool, the prefix cache and the contiguous layout; page
-    export/import raise when called.
+    Over a cache of more than one group, and over a latent cache (with or
+    without a state group), the engine refuses, at construction, what
+    cannot work there: speculative verify (a ring cannot be rolled back;
+    a latent row is no K and V; a state has no earlier value to return
+    to), the int8 KV pool, the prefix cache (a state has no snapshot at a
+    page boundary) and the contiguous layout; page export/import raise
+    when called.
     """
 
     def __init__(self, model, config: Optional[ServingConfig] = None,
@@ -406,34 +427,38 @@ class ServingEngine:
                 "model max_seq %d < serving max_seq %d (position table too "
                 "small for the context budget)" % (mcfg.max_seq, self.cfg.max_seq))
         self.params = params if params is not None else model.params
-        layer_groups = getattr(mcfg, "cache_groups", None) or [
-            ("global", tuple(range(mcfg.n_layer)), None)]
-        n_kv, q_per_kv = _query_groups(mcfg, layer_groups)
+        layer_groups = _layer_groups(mcfg)
         latent = getattr(mcfg, "latent_row", None)
         if len(layer_groups) > 1 or latent:
             self._refuse_over_groups(layer_groups, latent)
         self.pools: List[PagePool] = []
-        if latent:
-            pages = self.cfg.group_pages.get("latent", self.cfg.num_pages)
-            self.cache_ops = LatentPagedCache(
-                mcfg.n_layer, latent[0], latent[1], self.cfg.slots,
-                self.cfg.max_seq, self.cfg.page_size, pages,
-                dtype=mcfg.dtype)
-        elif self.cfg.paged:
+        groups = []
+        if self.cfg.paged:
             ps = self.cfg.page_size
-            groups = []
-            for gi, (name, layers, window) in enumerate(layer_groups):
+            for gi, (name, layers, window, kind) in enumerate(layer_groups):
                 rows = self.cfg.max_seq if window is None \
                     else min(int(window), self.cfg.max_seq)
-                pages = self.cfg.group_pages.get(
+                pages = 0 if kind == STATE else self.cfg.group_pages.get(
                     name, self.cfg.num_pages if gi == 0
                     else self.cfg.slots * (rows // ps))
-                groups.append(CacheGroup(name, tuple(layers), window, pages))
-            unknown = set(self.cfg.group_pages) - {g.name for g in groups}
+                groups.append(CacheGroup(name, tuple(layers), window, pages,
+                                         kind))
+            unknown = set(self.cfg.group_pages) - {
+                g.name for g in groups if g.kind != STATE}
             if unknown:
-                raise ValueError("group_pages names %s; the model's cache "
-                                 "groups are %s" % (sorted(unknown),
-                                                    [g.name for g in groups]))
+                raise ValueError("group_pages names %s; the model's paged "
+                                 "cache groups are %s"
+                                 % (sorted(unknown), [
+                                     g.name for g in groups
+                                     if g.kind != STATE]))
+        if latent:
+            self.cache_ops = LatentPagedCache(
+                mcfg.n_layer, latent[0], latent[1], self.cfg.slots,
+                self.cfg.max_seq, self.cfg.page_size, groups[0].num_pages,
+                dtype=mcfg.dtype, groups=groups,
+                slot_state=getattr(mcfg, "slot_state", None))
+        elif self.cfg.paged:
+            n_kv, q_per_kv = _query_groups(mcfg, layer_groups)
             kv_scales = None
             if self.cfg.kv_dtype == "int8":
                 kv_scales = self._calibrated_kv_scales(mcfg)
@@ -449,18 +474,23 @@ class ServingEngine:
                     mcfg.n_layer, n_kv, mcfg.d_head, self.cfg.slots,
                     self.cfg.max_seq, ps, groups[0].num_pages, **geometry)
         else:
+            n_kv, _ = _query_groups(mcfg, layer_groups)
             self.cache_ops = ContiguousKVCache(
                 mcfg.n_layer, n_kv, mcfg.d_head, self.cfg.slots,
                 self.cfg.max_seq, dtype=mcfg.dtype)
-        if self.cfg.paged:      # one free list a cache group
+        if self.cfg.paged:      # one free list a PAGED cache group
             self.pools = [PagePool(g.num_pages, self.cfg.page_size,
                                    name=g.name, primary=(gi == 0))
-                          for gi, g in enumerate(self.cache_ops.groups)]
+                          for gi, g in enumerate(self.cache_ops.groups)
+                          if g.kind != STATE]
         # the first group's pool, under the name a one-group engine's only
         # pool always had
         self.pool: Optional[PagePool] = self.pools[0] if self.pools else None
         self.scheduler = Scheduler(self.cfg.slots, self.cfg.max_queue)
         self._cache = self.cache_ops.init_state()
+        if self.cfg.paged:
+            _sm.STATE_POOL_BYTES.set(
+                self.cache_ops.state_bytes(self._cache))
         b = self.cfg.slots
         self._reset_slot_state()
         self._prefill_exe: Dict[int, Any] = {}   # bucket -> AOT executable
@@ -555,7 +585,9 @@ class ServingEngine:
         """What cannot work over a cache of more than one group, or over a
         latent one, said at construction rather than computed wrong."""
         cfg = self.cfg
-        over = ("a latent cache (one [c | kr] row a token, no V pool)"
+        over = ("a latent cache (one [c | kr] row a token, no V pool%s)"
+                % (", beside a state a slot that has no pages"
+                   if STATE in [g[3] for g in layer_groups] else "")
                 if latent else "a cache with %d groups %s"
                 % (len(layer_groups), [g[0] for g in layer_groups]))
         for on, what in (
@@ -1095,7 +1127,7 @@ class ServingEngine:
         with _span("serving/prefill.launch"):
             prompt = np.full((bucket,), cfg.pad_id, np.int32)
             prompt[:req.prompt_len] = req.prompt
-            dest = (self.cache_ops.prompt_dest_groups(req.group_pages)
+            dest = (self.cache_ops.prompt_dest_groups(req.group_pages, slot)
                     if cfg.paged else self.cache_ops.prompt_dest(slot))
             exe = self._get_prefill_exe(bucket)
             # serving/prefill_ms starts here, as it always has: at the

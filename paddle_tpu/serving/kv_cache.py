@@ -46,12 +46,29 @@ layers of different kinds may put different numbers of query heads over the
 same KV heads. ``q_per_kv`` names G, one value for every group or a mapping
 by group name, and the kernel's gate is asked for each.
 
-A LATENT group (:class:`LatentPagedCache`): a model with latent (MLA)
-attention keeps ONE row a token a layer, ``[c | kr]`` (the compressed KV
-latent and the one rotary key every head shares), and no V pool: decode
-attention, absorbed, scores every query head against that row and sums
-over its first ``rank`` lanes. Pages, page tables, the pool's free list and
-the drop scatter are the paged cache's own.
+A group has a KIND (``CacheGroup.kind``), and one cache may hold groups of
+different kinds side by side:
+
+* ``KV``: rows of K and V a token, as above.
+* ``LATENT`` (:class:`LatentPagedCache`): the layers of a model with latent
+  (MLA) attention keep ONE row a token a layer, ``[c | kr]`` (the
+  compressed KV latent and the one rotary key every head shares), and no V
+  pool: decode attention, absorbed, scores every query head against that
+  row and sums over its first ``rank`` lanes. Pages, page tables, the
+  pool's free list and the drop scatter are the paged cache's own. The
+  group covers the layers it names: all of them (Kimi-K2) or one in six
+  (a hybrid whose other layers keep a state).
+* ``STATE``: the layers of a linear-attention recurrence keep nothing a
+  token. What they keep belongs to the SLOT, has a fixed size and is
+  rewritten whole at every step: a ``[H, dk, dv]`` float32 state and the
+  last few inputs of a short causal convolution (``slot_state``). Such a
+  group has no pages, no page table and no pool, so the scheduler admits
+  by the other groups' pages alone; its one entry of a slot's ``dest`` row
+  is the slot's own index. ``set_page_table`` zeroes the slot's state (the
+  prefill executable arms the slot with it), ``write_prompt`` takes a
+  layer's final state and convolution tail, ``tail_step`` and
+  ``state_step`` advance them by one decode step for the slots that are
+  ``active`` and leave every other slot's unread and unwritten.
 
 Both write paths scatter with ``mode="drop"`` on out-of-bounds destination
 rows, so inactive slots / padding positions are dropped INSIDE the compiled
@@ -68,17 +85,22 @@ import jax.numpy as jnp
 import numpy as np
 
 __all__ = ["CacheGroup", "PagedKVCache", "Int8PagedKVCache",
-           "LatentPagedCache", "ContiguousKVCache"]
+           "LatentPagedCache", "ContiguousKVCache", "KV", "LATENT", "STATE"]
+
+KV, LATENT, STATE = "kv", "latent", "state"
 
 
 class CacheGroup(NamedTuple):
-    """Layers that keep the same positions: ``window`` None keeps every
-    position of a slot's context, W keeps the last W as a ring."""
+    """Layers that keep the same thing: ``kind`` ``KV`` or ``LATENT`` keeps
+    rows a token in pages (``window`` None keeps every position of a slot's
+    context, W keeps the last W as a ring); ``STATE`` keeps a fixed-size
+    state a slot and has no pages (``window`` None, ``num_pages`` 0)."""
 
     name: str
     layers: Tuple[int, ...]
     window: Optional[int]
     num_pages: int
+    kind: str = KV
 
 Cache = Dict[str, jnp.ndarray]
 
@@ -141,7 +163,8 @@ class PagedKVCache(_KVCacheBase):
                  max_ctx: int, page_size: int, num_pages: int,
                  dtype=jnp.float32,
                  groups: Optional[Sequence[CacheGroup]] = None,
-                 q_per_kv: Union[int, Mapping[str, int]] = 1):
+                 q_per_kv: Union[int, Mapping[str, int]] = 1,
+                 slot_state: Optional[Sequence[int]] = None):
         super().__init__(n_layer, n_head, d_head, slots, max_ctx, dtype)
         if max_ctx % page_size != 0:
             raise ValueError("max_ctx=%d must be a multiple of page_size=%d"
@@ -155,7 +178,19 @@ class PagedKVCache(_KVCacheBase):
             CacheGroup(g.name, tuple(g.layers),
                        None if g.window is None
                        else min(int(g.window), self.max_ctx),
-                       int(g.num_pages)) for g in groups]
+                       int(g.num_pages), g.kind) for g in groups]
+        # a STATE group's geometry: (heads, dk, dv, tail rows, tail width)
+        self.slot_state = (None if slot_state is None
+                           else tuple(int(n) for n in slot_state))
+        kinds = [g.kind for g in self.groups]
+        if STATE in kinds and (
+                self.slot_state is None
+                or STATE in kinds[:len(kinds) - kinds.count(STATE)]):
+            raise ValueError(
+                "a state group needs slot_state=(heads, dk, dv, tail rows, "
+                "tail width) and comes after every paged group (the "
+                "engine's pools are the paged groups', in order): %s"
+                % kinds)
         # layer -> (its group's index, its index inside that group's pool)
         self._where: Dict[int, Tuple[int, int]] = {}
         for gi, g in enumerate(self.groups):
@@ -199,6 +234,10 @@ class PagedKVCache(_KVCacheBase):
         return self.max_ctx if w is None else w
 
     def group_pages_per_slot(self, gi: int) -> int:
+        """Entries of group ``gi`` in a slot's page-table rows: its pages,
+        or for a state group the one entry that names the slot."""
+        if self.groups[gi].kind == STATE:
+            return 1
         return self.group_rows(gi) // self.page_size
 
     @property
@@ -224,23 +263,36 @@ class PagedKVCache(_KVCacheBase):
         return ctx_len if w is None else jnp.minimum(ctx_len, w)
 
     def _single_group(self, what: str) -> None:
-        if len(self.groups) > 1:
-            raise ValueError("%s is not supported over a cache with %d "
-                             "groups (%s)" % (what, len(self.groups),
-                                              [g.name for g in self.groups]))
+        """What needs ONE group of K and V rows (speculative verify, the
+        int8 pool, page copies, export and import) is refused elsewhere."""
+        if len(self.groups) > 1 or self.groups[0].kind != KV:
+            raise ValueError(
+                "%s is not supported over a cache with %d groups (%s)"
+                % (what, len(self.groups),
+                   ["%s: %s" % (g.name, g.kind) for g in self.groups]))
 
     def _storage_dtype(self):
         """What a pool row is stored as (``self.dtype`` is what ``context``
         returns)."""
         return self.dtype
 
+    _POOLS = ("k", "v")     # a paged group's pools, by state key
+
     def init_state(self) -> Cache:
         state = {}
         for gi, g in enumerate(self.groups):
+            if g.kind == STATE:
+                h, dk, dv, taps, width = self.slot_state
+                state[self._key(gi, "s")] = jnp.zeros(
+                    (len(g.layers), self.slots, h, dk, dv), jnp.float32)
+                state[self._key(gi, "tail")] = jnp.zeros(
+                    (len(g.layers), self.slots, taps, width), self.dtype)
+                continue
             shp = (len(g.layers), g.num_pages * self.page_size,
                    self.row_width)
-            state[self._key(gi, "k")] = jnp.zeros(shp, self._storage_dtype())
-            state[self._key(gi, "v")] = jnp.zeros(shp, self._storage_dtype())
+            for what in self._POOLS:
+                state[self._key(gi, what)] = jnp.zeros(
+                    shp, self._storage_dtype())
             # page table: slot -> ordered page ids; rows beyond a slot's
             # reservation are whatever the allocator last left (reads are
             # masked by length, writes by the drop scatter)
@@ -249,14 +301,27 @@ class PagedKVCache(_KVCacheBase):
         return state
 
     def cache_bytes(self, state: Cache) -> int:
-        return int(sum(state[self._key(gi, w)].nbytes
-                       for gi in range(len(self.groups)) for w in "kv"))
+        """Every pool and state as stored (a latent row's padding lanes
+        and an int8 pool's scales count); the page tables do not."""
+        return int(sum(x.nbytes for key, x in state.items()
+                       if key.partition(".")[0] != "pt"))
+
+    def state_bytes(self, state: Cache) -> int:
+        """The per-slot states and convolution tails of the state groups."""
+        return int(sum(x.nbytes for key, x in state.items()
+                       if key.partition(".")[0] in ("s", "tail")))
 
     def set_page_table(self, state: Cache, slot: int, dest) -> Cache:
         """Point ``slot`` at the pages of ``dest`` (what
-        :meth:`prompt_dest_groups` made) in every group."""
+        :meth:`prompt_dest_groups` made) in every paged group, and zero
+        its state in every state group: a request starts from nothing."""
         out = dict(state)
-        for gi in range(len(self.groups)):
+        for gi, g in enumerate(self.groups):
+            if g.kind == STATE:
+                for what in ("s", "tail"):
+                    key = self._key(gi, what)
+                    out[key] = state[key].at[:, slot].set(0)
+                continue
             key = self._key(gi, "pt")
             out[key] = state[key].at[slot].set(
                 dest[self._pt_start[gi]:self._pt_start[gi + 1]])
@@ -375,7 +440,7 @@ class PagedKVCache(_KVCacheBase):
         live = _live_len(ctx_len, active)
         return {"attn_rows_read." + g.name:
                 jnp.sum(self._group_len(gi, live)).astype(jnp.int32)
-                for gi, g in enumerate(self.groups)}
+                for gi, g in enumerate(self.groups) if g.kind != STATE}
 
     def decode_attention(self, state: Cache, layer: int, q, ctx_len,
                          active, sm_scale: float = 1.0) -> jnp.ndarray:
@@ -448,24 +513,82 @@ class PagedKVCache(_KVCacheBase):
         return attention_ops.verify_attention(q, ctx_k, ctx_v, live,
                                               sm_scale=sm_scale)
 
+    # -- a state group's decode step -----------------------------------------
+    def state_kernel_mode(self):
+        """:meth:`kernel_mode`'s twin for the state groups' decode step:
+        ``(mode, why_not)`` by the same flag and
+        ``pallas_kernels.kda.kda_state_step_gate`` over ``slot_state``."""
+        from ..ops import attention_ops
+        from ..ops.pallas_kernels.kda import kda_state_step_gate
+
+        mode = attention_ops.paged_kernel_mode()
+        if mode is None:
+            return None, "n/a"
+        why_not = kda_state_step_gate(*self.slot_state[:3],
+                                      interpret=(mode == "interpret"))
+        if why_not is not None:
+            return None, "gate: " + why_not
+        return mode, None
+
+    def tail_step(self, state: Cache, layer: int, u, active):
+        """One decode step of a state layer's convolution tail: ``u`` [B,
+        width], this step's inputs. Returns ``(window [B, rows + 1, width],
+        state)``: each slot's kept rows with ``u`` after them, oldest first
+        (what a causal convolution of ``rows + 1`` taps reads), and the
+        tails advanced by one row where ``active``; elsewhere as they
+        were."""
+        gi, li = self._where[layer]
+        key = self._key(gi, "tail")
+        tail = state[key][li]
+        window = jnp.concatenate([tail, u[:, None].astype(tail.dtype)],
+                                 axis=1)
+        new = jnp.where(active[:, None, None], window[:, 1:], tail)
+        return window, {**state, key: state[key].at[li].set(new)}
+
+    def state_step(self, state: Cache, layer: int, q, k, v, a, beta,
+                   active):
+        """One step of a state layer's recurrence
+        (ops/pallas_kernels/kda.py) for the slots that are ``active``:
+        ``q``/``k``/``a`` [B, H, dk], ``v`` [B, H, dv], ``beta`` [B, H].
+        Returns ``(o [B, H, dv] float32, state)``. By the kernel where
+        :meth:`state_kernel_mode` arms it (the group's whole state buffer
+        aliased in and out, an inactive slot's neither read nor written),
+        else in plain XLA (computed for all, kept where active)."""
+        from ..ops.pallas_kernels import kda
+
+        gi, li = self._where[layer]
+        key = self._key(gi, "s")
+        mode, _ = self.state_kernel_mode()
+        if mode is None:
+            o, s = kda.kda_state_step_xla(state[key], li, q, k, v, a, beta,
+                                          active)
+        else:
+            o, s = kda.kda_state_step(
+                state[key], li, q, k, v, a, beta, active,
+                interpret=(mode == "interpret"))
+        return o, {**state, key: s}
+
     # -- prefill (one sequence) ----------------------------------------------
     def prompt_dest(self, pages) -> np.ndarray:
         """:meth:`prompt_dest_groups` of a one-group cache."""
         return self.prompt_dest_groups([pages])
 
-    def prompt_dest_groups(self, group_pages) -> np.ndarray:
+    def prompt_dest_groups(self, group_pages, slot: int = 0) -> np.ndarray:
         """Host-side: the ``dest`` operand for ``write_prompt`` and
-        ``set_page_table`` — every group's full page-table row, one after
-        another (reserved pages first, rest parked on page 0; unused
-        entries are never read or written)."""
-        if len(group_pages) != len(self.groups):
-            raise ValueError("pages for %d groups, the cache has %d"
-                             % (len(group_pages), len(self.groups)))
+        ``set_page_table`` — every paged group's full page-table row, one
+        after another (reserved pages first, rest parked on page 0; unused
+        entries are never read or written), then ``slot`` for each state
+        group: what a state group has of a slot is the slot itself."""
+        paged = [gi for gi, g in enumerate(self.groups) if g.kind != STATE]
+        if len(group_pages) != len(paged):
+            raise ValueError("pages for %d groups, the cache has %d paged"
+                             % (len(group_pages), len(paged)))
         rows = []
-        for gi, pages in enumerate(group_pages):
+        for gi, pages in zip(paged, group_pages):
             row = np.zeros(self.group_pages_per_slot(gi), np.int32)
             row[:len(pages)] = np.asarray(pages, np.int32)
             rows.append(row)
+        rows.append(np.full(len(self.groups) - len(paged), slot, np.int32))
         return np.concatenate(rows)
 
     def write_prompt(self, state: Cache, layer: int, k_new, v_new, dest,
@@ -474,10 +597,20 @@ class PagedKVCache(_KVCacheBase):
         :meth:`prompt_dest_groups`'s row; positions >= length are dropped,
         and in a window group the positions that have already left the
         window (< length - window) too: the last ``min(length, window)``
-        land at their places in the ring."""
+        land at their places in the ring. For a layer of a state group
+        ``k_new`` is the state ``[H, dk, dv]`` the prompt leaves and
+        ``v_new`` its convolution tail ``[rows, width]``, written whole to
+        the slot ``dest`` names."""
         ps = self.page_size
-        gi, _ = self._where[layer]
+        gi, li = self._where[layer]
         off = self._pt_start[gi]
+        if self.groups[gi].kind == STATE:
+            sk, tk = self._key(gi, "s"), self._key(gi, "tail")
+            return {**state,
+                    sk: state[sk].at[li, dest[off]].set(
+                        k_new.astype(jnp.float32)),
+                    tk: state[tk].at[li, dest[off]].set(
+                        v_new.astype(state[tk].dtype))}
         s = k_new.shape[0]
         j = jnp.arange(s)
         keep = j < length
@@ -637,10 +770,6 @@ class Int8PagedKVCache(PagedKVCache):
     def kernel_mode(self):
         return None, "gate: int8 pool (the kernel has no dequant stage)"
 
-    def cache_bytes(self, state: Cache) -> int:
-        return int(state["k"].nbytes + state["v"].nbytes
-                   + state["ks"].nbytes + state["vs"].nbytes)
-
     # -- page migration ------------------------------------------------------
     def export_pages(self, state: Cache, pages):
         """int8 pages travel WITH their per-page fp32 scale columns
@@ -672,8 +801,11 @@ class Int8PagedKVCache(PagedKVCache):
 
 
 class LatentPagedCache(PagedKVCache):
-    """The paged layout with ONE pool of latent rows, ``"c"``
-    ``[n_layer, num_pages*page_size, row_width]``, and no V pool.
+    """The paged layout whose paged group keeps latent rows: ONE pool,
+    ``"c"`` ``[layers of the group, num_pages*page_size, row_width]``, and
+    no V pool. Beside it there may be a state group (``groups``,
+    ``slot_state``); without ``groups`` the latent group is every layer
+    (Kimi-K2: the one-group case).
 
     A token's row is ``[c (rank) | kr (rope) | 0...]``: ``rank + rope``
     values (512 + 64 at DeepSeek-V3's sizes, against 64 heads x (192 + 128)
@@ -685,34 +817,31 @@ class LatentPagedCache(PagedKVCache):
     ``decode_attention`` takes the ABSORBED query ``[B, H, rank + rope]``
     and returns ``[B, H, rank]`` (ops.attention_ops.mla_decode_attention or
     the kernel of ops/pallas_kernels/mla_attention.py, by the same flag
-    as the paged kernel). One global group. What needs a K and a V row
-    (speculative verify, the int8 pool, page export and import, with them
-    the prefix cache) is refused: nobody needs it yet."""
+    as the paged kernel). What needs a K and a V row (speculative verify,
+    the int8 pool, page export and import, with them the prefix cache) is
+    refused by the paged cache's own rule: nobody needs it yet."""
 
     layout = "paged-latent"
+    _POOLS = ("c",)
 
     def __init__(self, n_layer: int, rank: int, rope: int, slots: int,
                  max_ctx: int, page_size: int, num_pages: int,
-                 dtype=jnp.float32):
+                 dtype=jnp.float32,
+                 groups: Optional[Sequence[CacheGroup]] = None,
+                 slot_state: Optional[Sequence[int]] = None):
         self.rank, self.rope = int(rank), int(rope)
         self.row_values = self.rank + self.rope
         width = -(-self.row_values // 128) * 128
+        if groups is None:
+            groups = [CacheGroup("latent", tuple(range(int(n_layer))), None,
+                                 int(num_pages), LATENT)]
+        if [g.kind for g in groups if g.kind != STATE] != [LATENT]:
+            raise ValueError("a latent cache has ONE latent group (and "
+                             "state groups after it), got %s"
+                             % [(g.name, g.kind) for g in groups])
         super().__init__(n_layer, 1, width, slots, max_ctx, page_size,
-                         num_pages, dtype,
-                         groups=[CacheGroup("latent",
-                                            tuple(range(int(n_layer))), None,
-                                            int(num_pages))])
-
-    def init_state(self) -> Cache:
-        g = self.groups[0]
-        return {"c": jnp.zeros((len(g.layers), g.num_pages * self.page_size,
-                                self.row_width), self.dtype),
-                "pt": jnp.zeros((self.slots, self.pages_per_slot),
-                                jnp.int32)}
-
-    def cache_bytes(self, state: Cache) -> int:
-        """The pool as stored: the padding lanes count."""
-        return int(state["c"].nbytes)
+                         groups[0].num_pages, dtype, groups=groups,
+                         slot_state=slot_state)
 
     def write_token(self, state: Cache, layer: int, row_new, pos, active
                     ) -> Cache:
@@ -720,24 +849,30 @@ class LatentPagedCache(PagedKVCache):
         slot b; inactive slots dropped."""
         return super().write_token(state, layer, row_new, None, pos, active)
 
-    def write_prompt(self, state: Cache, layer: int, row_new, dest, length
+    def write_prompt(self, state: Cache, layer: int, *new_dest_length
                      ) -> Cache:
-        """``row_new`` [S, rank + rope] of ONE sequence; positions >=
-        ``length`` are dropped."""
-        return super().write_prompt(state, layer, row_new, None, dest, length)
+        """A latent layer: ``(row_new [S, rank + rope], dest, length)`` of
+        ONE sequence, positions >= ``length`` dropped. A state layer:
+        ``(state, tail, dest, length)``, the paged cache's."""
+        if len(new_dest_length) == 3:
+            row_new, dest, length = new_dest_length
+            new_dest_length = (row_new, None, dest, length)
+        return super().write_prompt(state, layer, *new_dest_length)
 
     def _write_rows(self, state: Cache, layer: int, dest, row_new, _v
                     ) -> Cache:
         """The paged cache's destinations, one padded row each."""
+        _, li = self._where[layer]
         rows = row_new.reshape(-1, self.row_values).astype(self.dtype)
         rows = jnp.pad(rows, ((0, 0), (0, self.row_width - self.row_values)))
         return {**state,
-                "c": state["c"].at[layer, dest].set(rows, mode="drop")}
+                "c": state["c"].at[li, dest].set(rows, mode="drop")}
 
     def context(self, state: Cache, layer: int) -> jnp.ndarray:
         """Every slot's rows of ``layer`` in page-table order: ``[slots,
         max_ctx, row_width]`` (the XLA-gather path)."""
-        return state["c"][layer, self._context_rows(state["pt"])]
+        _, li = self._where[layer]
+        return state["c"][li, self._context_rows(state["pt"])]
 
     def _kernel_gate(self, interpret: bool) -> Optional[str]:
         from ..ops.pallas_kernels.mla_attention import mla_decode_gate
@@ -751,6 +886,7 @@ class LatentPagedCache(PagedKVCache):
         slot's LIVE length (:func:`_live_len`)."""
         from ..ops import attention_ops
 
+        _, li = self._where[layer]
         length = _live_len(ctx_len, active)
         q = jnp.pad(q, ((0, 0), (0, 0), (0, self.row_width - q.shape[-1])))
         mode, _ = self.kernel_mode()
@@ -759,27 +895,11 @@ class LatentPagedCache(PagedKVCache):
 
             return _mla.mla_paged_decode(
                 q, state["c"], state["pt"], length,
-                page_size=self.page_size, rank=self.rank, layer=layer,
+                page_size=self.page_size, rank=self.rank, layer=li,
                 sm_scale=sm_scale, interpret=(mode == "interpret"))
         return attention_ops.mla_decode_attention(
             q, self.context(state, layer), length, self.rank,
             sm_scale=sm_scale)
-
-    def _no_kv_rows(self, what: str):
-        raise ValueError("%s is not supported over a latent cache (one "
-                         "[c | kr] row a token and no V pool)" % what)
-
-    def decode_verify(self, *args, **kwargs):
-        self._no_kv_rows("speculative verify")
-
-    def page_meta(self) -> dict:
-        self._no_kv_rows("page export")
-
-    def export_pages(self, state: Cache, pages):
-        self._no_kv_rows("page export")
-
-    def import_pages(self, state: Cache, pages, meta: dict, blobs) -> Cache:
-        self._no_kv_rows("page import")
 
 
 class ContiguousKVCache(_KVCacheBase):

@@ -26,6 +26,7 @@ __all__ = [
     "SPEC_PROPOSED", "SPEC_ACCEPTED", "SPEC_REJECTED", "SPEC_DRAFTS",
     "SPEC_VERIFY_DISPATCHES", "SPEC_ACCEPT_RATE",
     "MOE_EXPERTS_TOUCHED", "MOE_MAX_EXPERT_ROWS", "MOE_HELD_PAIRS",
+    "STATE_SLOTS_STEPPED", "STATE_POOL_BYTES",
     "pages_used", "attn_rows_read", "model_stat",
 ]
 
@@ -180,11 +181,20 @@ MOE_HELD_PAIRS = _mx.histogram(
     help="(token, expert) pairs routed to an expert this chip holds, one "
          "observation a layer a decode step: the load of a share of a "
          "wider expert-parallel deployment")
+STATE_SLOTS_STEPPED = _mx.histogram(
+    "serving/state_slots_stepped",
+    help="live slots whose recurrent state a decode step advanced, one "
+         "observation a step (a model with linear-attention layers)")
+STATE_POOL_BYTES = _mx.gauge(
+    "serving/state_pool_bytes",
+    help="bytes of the per-slot recurrent states and convolution tails "
+         "the cache holds (0 for a cache without a state group)")
 
 # a model's decode ``stats`` by name (:func:`model_stat`)
 _MODEL_STATS = {"moe_experts_touched": MOE_EXPERTS_TOUCHED,
                 "moe_max_expert_rows": MOE_MAX_EXPERT_ROWS,
-                "moe_held_pairs": MOE_HELD_PAIRS}
+                "moe_held_pairs": MOE_HELD_PAIRS,
+                "state_slots_stepped": STATE_SLOTS_STEPPED}
 
 
 def pages_used(group: str):
@@ -208,8 +218,8 @@ def attn_rows_read(group: str):
 def model_stat(name: str):
     """The histogram the engine feeds a model's decode ``stats[name]`` to
     (an observation a value a step), or None for a name it does not know:
-    the three ``moe_*`` above and ``attn_rows_read.<group>``, looked up
-    once a name."""
+    the three ``moe_*`` and ``state_slots_stepped`` above and
+    ``attn_rows_read.<group>``, looked up once a name."""
     hist = _MODEL_STATS.get(name)
     if hist is None and name.startswith("attn_rows_read."):
         hist = _MODEL_STATS[name] = attn_rows_read(name.partition(".")[2])

@@ -852,3 +852,114 @@ def test_mixed_query_groups_decoder_decode_step(chip, monkeypatch):
         r"\((\d+), \{\}, (?:may|must)-alias\)", text.split("\n", 1)[0])}
     # "k" "k.window" "pt" "pt.window" "v" "v.window" in key order
     assert {n_params, n_params + 1, n_params + 4, n_params + 5} <= aliased
+
+
+# -- the hybrid decoder: KDA layers beside an MLA layer (ling-3-flash-ep4-serve)
+
+HYBRID_SERVE = dict(slots=64, page_size=16, max_seq=16384,
+                    latent_pages=40960, vocab=39296)
+
+
+def test_state_step_kernel_at_the_served_geometry(chip):
+    """64 slots of 32 heads of a 128 x 128 float32 state, six layers in
+    one buffer, a layer in the middle: the kernel compiles under its own
+    name, the whole buffer is aliased from input to output, and nothing
+    outside the kernel touches it."""
+    from paddle_tpu.ops.pallas_kernels import kda
+
+    assert kda.kda_state_step_gate(32, 128, 128) is None
+    shape = (6, 64, 32, 128, 128)
+    args = [jax.ShapeDtypeStruct(s, d, sharding=chip) for s, d in (
+        (shape, jnp.float32), ((64, 32, 128), jnp.bfloat16),
+        ((64, 32, 128), jnp.bfloat16), ((64, 32, 128), jnp.bfloat16),
+        ((64, 32, 128), jnp.float32), ((64, 32), jnp.float32),
+        ((64,), jnp.bool_))]
+    text = jax.jit(
+        lambda s, q, k, v, a, b, live: kda.kda_state_step(s, 3, q, k, v, a,
+                                                          b, live),
+        donate_argnums=(0,)).lower(*args).compile().as_text()
+    kernel, = [ln for ln in text.split("\n") if "tpu_custom_call" in ln]
+    assert kernel.strip().startswith("%kda_state_step")
+    assert "f32[6,64,32,128,128]" in kernel.split(" custom-call(")[0]
+    assert [op for _, rtype, op, _ in _instructions(text)
+            if _has_dim(rtype, 6) and "128,128" in rtype
+            and op not in ("parameter", "custom-call", "get-tuple-element",
+                           "tuple")] == []
+    assert re.search(r"\(0, \{\}, (?:may|must)-alias\)",
+                     text.split("\n", 1)[0])
+
+
+def _hybrid_case(chip):
+    """The decode step of the hybrid decoder at its published widths, as
+    one chip of four holds it (128 of 512 experts, 39,296 rows of the
+    vocabulary): a dense KDA layer, a sparse KDA layer and the MLA layer
+    over the cell's latent pool and 64 slots' states."""
+    from paddle_tpu.models import ling3_flash as lf
+    from paddle_tpu.serving.kv_cache import CacheGroup, LatentPagedCache
+
+    g = HYBRID_SERVE
+    cfg = lf.Ling3FlashConfig(
+        vocab_size=g["vocab"], n_layer=3, d_model=2560, n_head=32,
+        d_state=128, layer_types=["kda", "kda", "mla"], kv_rank=512,
+        d_nope=128, d_rope=64, d_v=128, d_dense=6144, dense_layers=(0,),
+        n_expert=512, top_k=8, d_expert=768, n_group=8, topk_group=4,
+        routed_scale=2.5, max_seq=g["max_seq"], dtype="bfloat16",
+        experts_held=tuple(range(128)))
+    model = lf.Ling3FlashLM(cfg, params={})
+
+    def sds(x):
+        return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=chip)
+
+    params = jax.tree_util.tree_map(
+        sds, jax.eval_shape(lambda: lf.init_params(cfg, 0)))
+    groups = [CacheGroup(name, layers, window,
+                         g["latent_pages"] if kind == "latent" else 0, kind)
+              for name, layers, window, kind in cfg.cache_groups]
+    ops = LatentPagedCache(3, 512, 64, g["slots"], g["max_seq"],
+                           g["page_size"], g["latent_pages"],
+                           dtype="bfloat16", groups=groups,
+                           slot_state=cfg.slot_state)
+    cache = jax.tree_util.tree_map(sds, jax.eval_shape(ops.init_state))
+    ints = jax.ShapeDtypeStruct((g["slots"],), jnp.int32, sharding=chip)
+    flags = jax.ShapeDtypeStruct((g["slots"],), jnp.bool_, sharding=chip)
+
+    def chunk(params, cache, lengths, tokens, active):
+        logits, cache, stats = model.decode(params, cache, ops, tokens,
+                                            lengths, active)
+        return cache, jnp.argmax(logits, -1), stats
+
+    return chunk, (params, cache, ints, ints, flags), ops
+
+
+def test_hybrid_decoder_decode_step(chip, monkeypatch):
+    """The decode step runs the state kernel once a KDA layer and the
+    latent kernel once, and the compiler's grouped matmul three times an
+    expert layer; it does not copy, slice or transpose the states or the
+    latent pool, and both (and the tails) are aliased from input to
+    output."""
+    monkeypatch.setattr(attention_ops, "paged_kernel_mode",
+                        lambda: "compiled")
+    monkeypatch.setattr(attention_ops, "_on_tpu", lambda: True)
+    fn, args, ops = _hybrid_case(chip)
+    assert ops.kernel_mode() == ("compiled", None)
+    assert ops.state_kernel_mode() == ("compiled", None)
+    text = jax.jit(fn, donate_argnums=(1,)).lower(*args).compile().as_text()
+    kernels = [ln.strip().split(" = ")[0] for ln in text.split("\n")
+               if "tpu_custom_call" in ln]
+    assert sum(k.startswith("%kda_state_step") for k in kernels) == 2
+    assert sum(k.startswith("%mla_latent_decode") for k in kernels) == 1
+    assert len(re.findall(r"= \S+ custom-call\([^\n]*ragged-dot", text)) >= 3
+    instructions = list(_instructions(text))
+    types = {name: rtype for name, rtype, _, _ in instructions}
+    rows = ops.groups[0].num_pages * ops.page_size
+    moved = [(op, rtype) for _, rtype, op, operands in instructions
+             if op in ("copy", "copy-start", "slice", "dynamic-slice",
+                       "transpose")
+             and any(_has_dim(t, rows) or "f32[2,64,32,128,128]" in t
+                     for t in [rtype] + [types.get(o, "") for o in operands])]
+    assert moved == [], moved
+    n_params = len(jax.tree_util.tree_leaves(args[0]))
+    aliased = {int(p) for p in re.findall(
+        r"\((\d+), \{\}, (?:may|must)-alias\)", text.split("\n", 1)[0])}
+    # "c" "pt" "s.state" "tail.state" in key order
+    assert {n_params, n_params + 2, n_params + 3} <= aliased
