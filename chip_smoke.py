@@ -43,7 +43,16 @@ SIZES = {
     "ctr": dict(vocab=1_000_000, fields=26, width=10, batch=1024, steps=4),
     "kernels": dict(flash=(1, 8, 2048, 64), xent=(16384, 30000),
                     sparse_vocab=1_000_000, sparse_ids=26624,
-                    sparse_widths=(1, 10, 128)),
+                    sparse_widths=(1, 10, 128),
+                    # the fused expert kernel at two served decode passes'
+                    # published widths: (rows a pass, experts held, d, f,
+                    # experts touched, live rows): SmallThinker's 16 slots
+                    # x top-6 over all 64 experts (whole matrices, one
+                    # grid step an expert), Kimi-K2's 12-of-384 share
+                    # (29 MB matrices in row blocks, 248 of 256 rows dead)
+                    expert_stream=dict(
+                        smallthinker=(96, 64, 2560, 768, 45, 96),
+                        kimi_k2=(256, 12, 7168, 2048, 6, 8))),
     "serve": dict(vocab=50257, n_layer=12, d_model=768, n_head=12,
                   max_seq=1024, page_size=16, slots=8, requests=8,
                   prompt_min=16, prompt_max=512, new_tokens=32,
@@ -556,13 +565,77 @@ def _kernel_mla(seed, interpret):
             "ctx_len": [int(c) for c in ctx], "max_abs_err": errs}
 
 
+def _kernel_expert_stream(seed, interpret):
+    """The routed experts' fused feed-forward (gate, up and down in one
+    kernel over the touched experts' weights) in bfloat16, as served,
+    against the plain float32 statement of the same rows; and beside what
+    it replaces, ``ragged_dot`` x 3, which rounds gate and up before the
+    activation: the kernel may not lie farther from the statement."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from paddle_tpu.ops import moe_ops
+    from paddle_tpu.ops.pallas_kernels import expert_stream as es
+
+    out = {}
+    for name, (m, e, d, f, touched, live) in sorted(
+            SIZES["kernels"]["expert_stream"].items()):
+        why = es.expert_stream_gate(m, e, d, f, jnp.bfloat16,
+                                    interpret=interpret)
+        if why is not None:
+            out[name] = {"gate": why}
+            continue
+        rng = np.random.RandomState(seed)
+        sizes = np.zeros((e,), np.int32)
+        who = rng.choice(e, touched, replace=False)
+        sizes[who] = 1 + rng.multinomial(live - touched,
+                                         np.ones(touched) / touched)
+        kx, kg, ku, kd = jax.random.split(jax.random.PRNGKey(seed), 4)
+        xs = jax.random.normal(kx, (m, d), jnp.float32).astype(jnp.bfloat16)
+        wg, wu = ((jax.random.normal(k, (e, d, f), jnp.float32)
+                   / np.sqrt(d)).astype(jnp.bfloat16) for k in (kg, ku))
+        wd = (jax.random.normal(kd, (e, f, d), jnp.float32)
+              / np.sqrt(f)).astype(jnp.bfloat16)
+        sizes = jnp.asarray(sizes)
+        got = np.asarray(jax.jit(lambda *a: es.expert_stream_ffn(
+            *a, jax.nn.silu, interpret=interpret))(xs, wg, wu, wd, sizes),
+            np.float32)
+        want = np.asarray(jax.jit(lambda *a: es.expert_ffn_reference(
+            *a, jax.nn.silu))(xs, wg, wu, wd, sizes))
+        before = np.asarray(jax.jit(lambda *a: moe_ops._ragged_ffn(
+            *a, jax.nn.silu))(xs, wg, wu, wd, sizes), np.float32)[:live]
+        err, _ = _max_err(got[:live], want[:live])
+        scale = float(np.abs(want).max())
+        mean = float(np.abs(got[:live] - want[:live]).mean())
+        mean_before = float(np.abs(before - want[:live]).mean())
+        check(err <= 2e-2 * scale,
+              "expert stream kernel (%s) differs from the float32 "
+              "statement by %g of %g" % (name, err, scale))
+        check(mean <= 1.02 * mean_before,
+              "expert stream kernel (%s) lies farther from the float32 "
+              "statement (%g a value) than ragged_dot x 3 (%g)"
+              % (name, mean, mean_before))
+        check(not got[live:].any(),
+              "rows past the last group did not come back 0 (%s)" % name)
+        plan = es.expert_stream_plan(m, e, d, f, jnp.bfloat16)
+        out[name] = {"shape": {"rows": m, "experts": e, "d": d, "f": f,
+                               "touched": touched, "live": live},
+                     "blocks": [plan["nkd"], plan["nkf"]],
+                     "max_abs_err": err, "max_abs": scale,
+                     "mean_abs_err": mean,
+                     "mean_abs_err_ragged_dot": mean_before}
+    return out
+
+
 def phase_kernels(seed, meter):
     interpret = not on_tpu()
     out = {"flash_attention": _kernel_flash(seed, interpret),
            "softmax_xent": _kernel_xent(seed, interpret),
            "sparse_rows": _kernel_sparse(seed, interpret),
            "paged_attention": _kernel_paged(seed, interpret),
-           "mla_latent_decode": _kernel_mla(seed, interpret)}
+           "mla_latent_decode": _kernel_mla(seed, interpret),
+           "ragged_dot_stream": _kernel_expert_stream(seed, interpret)}
     return {"checked": "each Pallas kernel against its plain reference",
             "kernel_path": "interpreted" if interpret else "compiled",
             "kernels": out, "tune": tune_layers()}

@@ -3,11 +3,24 @@
 ``parallel/moe.py`` is top-1 Switch with a capacity factor, for training:
 a token over capacity is dropped. A served token cannot be: every routed
 (token, expert) pair is computed here, with no capacity and no drops, by
-sorting the pairs by expert and running ONE grouped matmul over the experts
-that received rows (``jax.lax.ragged_dot``; on the TPU the compiler lowers
-it to its own grouped-matmul kernel, which reads an expert's weights only
-where its group has rows). The same function serves the decode step (16
-rows x 6) and the prefill (8,192 rows x 6).
+sorting the pairs by expert and running ONE grouped feed-forward over the
+experts that received rows. The same function serves the decode step (16
+rows x 6) and the prefill (8,192 rows x 6), and :func:`_grouped_ffn` gives
+each pass the form of the grouped product that its rows call for:
+
+* a pass of at most ``STREAM_ROWS`` rows on a TPU (every decode pass of
+  the served cells: 96 to 256 rows, a few an expert) is bound by the
+  stream of the touched experts' weights, and takes
+  ``pallas_kernels/expert_stream.py``: gate, up and down in ONE kernel
+  that reads each touched expert's three matrices once and multiplies the
+  expert's own rows;
+* every larger pass (the prefills: from 1,024 rows up, bound by
+  arithmetic) and every other backend takes ``jax.lax.ragged_dot`` three
+  times; on the TPU the compiler lowers it to its own grouped-matmul
+  kernel, which reads an expert's weights only where its group has rows
+  but multiplies EVERY row of the pass by every touched expert (PERF.md,
+  PR 42): right where the rows are many, half the stream's rate where
+  they are few.
 
 The layer is told which experts it HOLDS and routes over all of them: a
 pair routed to an expert that lives on another chip contributes nothing
@@ -39,7 +52,17 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-__all__ = ["route_topk", "route_sigmoid_topk", "expert_layer", "held_pairs"]
+from . import attention_ops
+
+__all__ = ["route_topk", "route_sigmoid_topk", "expert_layer", "held_pairs",
+           "pass_rows", "matmul_form", "STREAM_ROWS"]
+
+# Rows of a pass up to which the experts' weights, not the arithmetic, bound
+# the grouped product: no served cell has a pass between 257 and 1,024 rows
+# (decode passes 96-256, prefill passes 1,024-81,920: PERF.md, PR 42), and
+# 512 rows over 12 or more experts are still far under the chip's ridge of
+# 240 rows an expert.
+STREAM_ROWS = 512
 
 
 def route_topk(h, wr, top_k: int) -> Tuple[jnp.ndarray, jnp.ndarray]:
@@ -104,6 +127,52 @@ def _share_rows(n_pairs: int, e_held: int, n_expert: int) -> int:
     return min(n_pairs, -(-max(2 * even, 1) // 256) * 256)
 
 
+def pass_rows(n_pairs: int, e_held: int, n_expert: int) -> int:
+    """Rows of one pass of the grouped product over ``n_pairs`` (token,
+    expert) pairs: all of them where every expert is held, a share's bound
+    else."""
+    return (n_pairs if e_held == n_expert
+            else _share_rows(n_pairs, e_held, n_expert))
+
+
+def _on_tpu() -> bool:
+    # asked through the module, so that what steers attention_ops' kernels
+    # onto a described chip (tests/test_chip_compile.py) steers this too
+    return attention_ops._on_tpu()
+
+
+def matmul_form(rows: int) -> str:
+    """Which grouped product a pass of ``rows`` rows takes: ``"stream"``
+    (the fused kernel) or ``"grouped"`` (``ragged_dot`` x 3). A function
+    of the pass's static row count and the backend alone."""
+    return "stream" if rows <= STREAM_ROWS and _on_tpu() else "grouped"
+
+
+def _ragged_ffn(xs, wg, wu, wd, sizes, activation):
+    """The grouped feed-forward as three of the compiler's grouped matmuls
+    (gate and up are rounded to ``xs``'s type before the activation)."""
+    gate = jax.lax.ragged_dot(xs, wg, sizes)
+    up = jax.lax.ragged_dot(xs, wu, sizes)
+    return jax.lax.ragged_dot(activation(gate) * up, wd, sizes)
+
+
+def _grouped_ffn(xs, wg, wu, wd, sizes, activation):
+    """``(act(xs Wg_e) * (xs Wu_e)) Wd_e`` for the rows of each group of
+    ``sizes`` (rows sorted by expert; the rows past the last group are
+    unspecified), in ``xs``'s type, in the form :func:`matmul_form` gives
+    the pass's rows; widths the kernel's gate refuses (no whole lane
+    tiles: no served model's) keep ``ragged_dot``."""
+    m, d = xs.shape
+    e, _, f = wg.shape
+    if matmul_form(m) == "stream":
+        from .pallas_kernels import expert_stream   # Pallas only where used
+
+        if expert_stream.expert_stream_gate(m, e, d, f, xs.dtype) is None:
+            return expert_stream.expert_stream_ffn(xs, wg, wu, wd, sizes,
+                                                   activation)
+    return _ragged_ffn(xs, wg, wu, wd, sizes, activation)
+
+
 def expert_layer(u, idx, w, wg, wu, wd, n_expert: Optional[int] = None,
                  held: Optional[Sequence[int]] = None, row_valid=None,
                  activation=jax.nn.relu
@@ -150,12 +219,9 @@ def expert_layer(u, idx, w, wg, wu, wd, n_expert: Optional[int] = None,
         sizes = jnp.zeros((e_held + 1,), jnp.int32).at[flat].add(1)[:e_held]
         if e_held < n_expert:
             return _share(u, w, wg, wu, wd, order, sizes, activation,
-                          _share_rows(n * k, e_held, n_expert)), \
+                          pass_rows(n * k, e_held, n_expert)), \
                 _group_stats(sizes)
-        xs = u[order // k]
-        gate = jax.lax.ragged_dot(xs, wg, sizes)
-        up = jax.lax.ragged_dot(xs, wu, sizes)
-        out = jax.lax.ragged_dot(activation(gate) * up, wd, sizes)
+        out = _grouped_ffn(u[order // k], wg, wu, wd, sizes, activation)
         # rows past the last group are whatever the grouped matmul left
         out = jnp.where((flat[order] < e_held)[:, None], out, 0)
         back = jnp.zeros((n * k,), jnp.int32).at[order].set(
@@ -190,10 +256,7 @@ def _share(u, w, wg, wu, wd, order, sizes, activation, rows: int):
         live = at < total
         part = (jnp.clip(ends, lo, lo + rows)
                 - jnp.clip(ends - sizes, lo, lo + rows))
-        xs = u[pair // k]
-        gate = jax.lax.ragged_dot(xs, wg, part)
-        up = jax.lax.ragged_dot(xs, wu, part)
-        out = jax.lax.ragged_dot(activation(gate) * up, wd, part)
+        out = _grouped_ffn(u[pair // k], wg, wu, wd, part, activation)
         # rows past the last group are whatever the grouped matmul left
         out = jnp.where(live[:, None],
                         out.astype(jnp.float32) * wf[pair][:, None], 0)

@@ -8,6 +8,9 @@ Kernels:
 - sparse_adam: row-wise sparse Adam/SGD update — batched dynamic-slice row
   DMA replacing the three ~30 GB/s XLA scatter fusions on SelectedRows
   embedding updates (benchmarks/SPARSE_PROFILE.md §1).
+- expert_stream: the routed experts' gate, up and down products of a decode
+  pass as ONE kernel that streams each touched expert's weights once
+  (``ragged_dot`` x 3 reads 47-84% of that stream at the served shapes).
 
 Each kernel has an XLA-composed reference implementation it is numerically
 tested against, and ``benchmarks/bench_softmax_xent.py`` /

@@ -1,0 +1,300 @@
+"""The routed experts' feed-forward over rows sorted by expert, fused three
+deep and bound by the weight stream: ``ragged_dot`` x 3 as ONE kernel.
+
+``ops/moe_ops.py`` sorts a pass's (token, expert) pairs by expert and
+computes, for the rows ``[start_e, start_e + sizes[e])`` of each expert,
+
+    out = (act(xs Wg_e) * (xs Wu_e)) Wd_e
+
+A decode pass holds a few rows an expert (2.8 in the served cells), so its
+time is the bytes of the TOUCHED experts' matrices over the HBM rate, and
+the arithmetic is a few percent of that. The kernel is built around the
+stream:
+
+* the touched experts are compacted in front by a scalar-prefetched list
+  (expert, first row, rows); the grid runs over all ``E`` experts and the
+  steps past the last touched one stay on the block the step before held
+  (no DMA) and skip their body, so an untouched expert costs a grid step
+  and no bytes;
+* the weights are read AS STORED, ``[E, d, f]`` and ``[E, f, d]``, in
+  blocks of whole rows of a matrix (long contiguous runs), double-buffered
+  by the pipeline against the block before: a matrix of at most
+  ``_BLOCK_BYTES`` whole, so that an expert is one grid step; a larger one
+  (Kimi-K2's 29 MB) in row blocks, ``[td, f]`` of ``Wg``/``Wu``
+  accumulating gate and up in float32 scratch, then ``[tf, d]`` of ``Wd``
+  accumulating the result; ``Wd``'s first block rides the last gate step,
+  and its index map holds the block before until then, so every step
+  fetches only what the next one needs;
+* an expert's rows are read as whole row tiles of ``xs`` on a lattice of
+  ``tile`` rows (32 or 64, from the rows an even router would send an
+  expert; a tile's products must end before the next block's DMA does,
+  and 128 rows do not), masked at both ends where the result is written;
+* bf16 operands, float32 accumulation; gate and up stay float32 until
+  ``act(gate) * up`` is rounded ONCE to the operands' type for the product
+  with ``Wd``; the result is rounded once more, as ``ragged_dot``'s is.
+
+Rows past the last group come back 0. The kernel's name in a device trace
+is ``ragged_dot_stream``.
+"""
+
+from __future__ import annotations
+
+import functools
+from typing import Optional
+
+import jax
+import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+__all__ = ["expert_stream_ffn", "expert_stream_gate", "expert_stream_plan",
+           "expert_ffn_reference", "KERNEL_NAME"]
+
+KERNEL_NAME = "ragged_dot_stream"
+_LANES = 128
+_BLOCK_BYTES = 8 << 20     # a matrix up to this is one block, whole
+_VMEM_CAP = 100 << 20      # of a v5e core's 128 MiB
+_VMEM_SLACK = 12 << 20     # the products' temporaries beside the buffers
+
+
+def _row_tile(m: int, e: int) -> int:
+    """Rows of a tile: 32, or 64 where an even router sends an expert over
+    16 rows. Never 128: at Kimi-K2's widths a tile of 128 rows was measured
+    2 to 3.7 times slower than one of 32 or 64, which read alike (its
+    products outlast the next block's DMA: PERF.md, PR 42)."""
+    return 32 if 2 * m <= 32 * e else 64
+
+
+def _split(rows: int, cols: int, itemsize: int, limit: int) -> Optional[int]:
+    """In how many row blocks a ``[rows, cols]`` matrix is read: the fewest
+    equal blocks of whole lane tiles of rows within ``limit`` bytes."""
+    for n in range(1, rows // _LANES + 1):
+        if rows % (n * _LANES) == 0 \
+                and rows // n * cols * itemsize <= limit:
+            return n
+    return None
+
+
+def expert_stream_plan(m: int, e: int, d: int, f: int, dtype) -> dict:
+    """The static choices for a geometry: ``tile`` rows, the padded row
+    count ``rows``, ``nkd`` blocks of ``Wg``/``Wu`` and ``nkf`` of ``Wd``
+    (blocks of at most ``_BLOCK_BYTES``, halved until the buffers fit)
+    and the ``vmem`` limit it asks for; ``fits`` says whether such blocks
+    exist."""
+    size = jnp.dtype(dtype).itemsize
+    tile = _row_tile(m, e)
+    rows = -(-m // tile) * tile
+    rows_io = 2 * 2 * rows * d * size
+    scratch = rows * (2 * f * 4 + f * size + d * 4)
+    limit = _BLOCK_BYTES
+    while True:
+        nkd, nkf = _split(d, f, size, limit), _split(f, d, size, limit)
+        split = nkd is not None and nkf is not None
+        nkd, nkf = nkd or 1, nkf or 1
+        need = (2 * (2 * (d // nkd) * f + (f // nkf) * d) * size
+                + rows_io + scratch)
+        fits = split and need + _VMEM_SLACK <= _VMEM_CAP
+        if fits or not split:
+            break
+        limit //= 2
+    return {"tile": tile, "rows": rows, "nkd": nkd, "nkf": nkf, "fits": fits,
+            "vmem": min(_VMEM_CAP, need + _VMEM_SLACK)}
+
+
+def expert_stream_gate(m: int, e: int, d: int, f: int, dtype,
+                       interpret: bool = False) -> Optional[str]:
+    """None when the compiled kernel takes this geometry, else the rule
+    that excludes it. The shape rules are the chip compiler's tiling and
+    do not bind the interpreter."""
+    dt = jnp.dtype(dtype)
+    if dt not in (jnp.dtype(jnp.float32), jnp.dtype(jnp.bfloat16)):
+        return "operand dtype %s is not float32/bfloat16" % dt.name
+    if interpret:
+        return None
+    if d % _LANES or f % _LANES:
+        return ("hidden size %d and expert width %d must be multiples of %d"
+                % (d, f, _LANES))
+    if not expert_stream_plan(m, e, d, f, dt)["fits"]:
+        return ("%d rows of [%d, %d] experts: no row blocks of whole lane "
+                "tiles whose buffers fit %d bytes of VMEM"
+                % (m, d, f, _VMEM_CAP))
+    return None
+
+
+def _kernel(ids_ref, start_ref, count_ref, n_ref, xs_ref, wg_ref, wu_ref,
+            wd_ref, out_ref, g_ref, u_ref, h_ref, y_ref, *, tile, nkd, nkf,
+            activation, precision):
+    i, j = pl.program_id(0), pl.program_id(1)   # the interpreter has none
+    td = xs_ref.shape[1] // nkd                 # in a branch
+    tf = h_ref.shape[1] // nkf
+    first, rows_e = start_ref[i], count_ref[i]
+    lo = first // tile
+    n_tiles = (first + rows_e + tile - 1) // tile - lo
+
+    def dot(a, b):
+        return jnp.dot(a, b, precision=precision,
+                       preferred_element_type=jnp.float32)
+
+    def over_tiles(body):
+        def one(t, carry):
+            at = pl.multiple_of((lo + t) * tile, tile)
+            body(at, pl.ds(at, tile))
+            return carry
+
+        jax.lax.fori_loop(0, n_tiles, one, 0)
+
+    def when(step):
+        # one step an expert: no branch at all
+        return (lambda fn: fn()) if nkd + nkf == 2 else pl.when(j == step)
+
+    @pl.when((i == 0) & (j == 0))
+    def _():
+        out_ref[...] = jnp.zeros_like(out_ref)
+
+    @pl.when(i < n_ref[0])
+    def _():
+        for kd in range(nkd):
+            def gate_up(at, rows, kd=kd):
+                x = xs_ref[rows, kd * td:(kd + 1) * td]
+                g, u = dot(x, wg_ref[0]), dot(x, wu_ref[0])
+                if kd:
+                    g, u = g + g_ref[rows, :], u + u_ref[rows, :]
+                if kd == nkd - 1:
+                    # the one rounding between the two products
+                    h_ref[rows, :] = (activation(g) * u).astype(h_ref.dtype)
+                else:
+                    g_ref[rows, :], u_ref[rows, :] = g, u
+
+            when(kd)(functools.partial(over_tiles, gate_up))
+
+        for kf in range(nkf):
+            def down(at, rows, kf=kf):
+                y = dot(h_ref[rows, kf * tf:(kf + 1) * tf], wd_ref[0])
+                if kf:
+                    y = y + y_ref[rows, :]
+                if kf < nkf - 1:
+                    y_ref[rows, :] = y
+                    return
+                # the tile's rows of OTHER experts keep what they hold
+                r = at + jax.lax.broadcasted_iota(jnp.int32, (tile, 1), 0)
+                mine = (r >= first) & (r < first + rows_e)
+                out_ref[rows, :] = jnp.where(
+                    mine, y, out_ref[rows, :].astype(jnp.float32)
+                ).astype(out_ref.dtype)
+
+            when(nkd - 1 + kf)(functools.partial(over_tiles, down))
+
+
+def _touched_first(sizes):
+    """The scalar-prefetched lists: the touched experts in front, in
+    expert order (``ids``, their first rows and row counts), the places
+    past the last of them repeating it with no rows, and how many there
+    are."""
+    e = sizes.shape[0]
+    touched = sizes > 0
+    n = jnp.sum(touched).astype(jnp.int32)
+    ids = jnp.argsort(jnp.logical_not(touched), stable=True).astype(jnp.int32)
+    place = jnp.arange(e, dtype=jnp.int32)
+    ids = jnp.where(place < n, ids, ids[jnp.maximum(n - 1, 0)])
+    starts = (jnp.cumsum(sizes) - sizes)[ids]
+    counts = jnp.where(place < n, sizes[ids], 0)
+    return ids, starts.astype(jnp.int32), counts.astype(jnp.int32), \
+        n.reshape(1)
+
+
+@functools.partial(jax.jit, static_argnames=("activation", "interpret"))
+def expert_stream_ffn(xs, wg, wu, wd, sizes, activation=jax.nn.relu, *,
+                      interpret: bool = False):
+    """``(act(xs Wg_e) * (xs Wu_e)) Wd_e`` for the rows of each group.
+
+    ``xs`` [M, d] sorted by expert; ``wg``/``wu`` [E, d, f], ``wd`` [E, f,
+    d]; ``sizes`` [E] int32, the rows of each group (``sum(sizes) <= M``;
+    the rows past the last group come back 0). Returns [M, d] in ``xs``'s
+    type: ``jax.lax.ragged_dot``'s contract, three products deep.
+
+    Jitted so that a model's layers share ONE trace and ONE lowering of
+    the kernel in their executable (32 call sites lower in 0.07 s for
+    1.6 s apart: a cell's set-up, PERF.md, PR 42)."""
+    m, d = xs.shape
+    e, _, f = wg.shape
+    if wg.shape != (e, d, f) or wu.shape != (e, d, f) \
+            or wd.shape != (e, f, d) or sizes.shape != (e,):
+        raise ValueError("xs %s, wg %s, wu %s, wd %s, sizes %s do not fit"
+                         % (xs.shape, wg.shape, wu.shape, wd.shape,
+                            sizes.shape))
+    why = expert_stream_gate(m, e, d, f, xs.dtype, interpret=interpret)
+    if why is not None:
+        raise ValueError("expert_stream_ffn: " + why)
+    plan = expert_stream_plan(m, e, d, f, xs.dtype)
+    tile, rows, nkd, nkf = (plan[k] for k in ("tile", "rows", "nkd", "nkf"))
+    steps = nkd + nkf - 1
+    td, tf = d // nkd, f // nkf
+    ids, starts, counts, n = _touched_first(sizes.astype(jnp.int32))
+
+    def step(i, j, n_ref):
+        # a place past the last touched expert stays where that one ended
+        return jnp.where(i < n_ref[0], j, steps - 1)
+
+    def up_block(i, j, ids_ref, s_ref, c_ref, n_ref):
+        return ids_ref[i], jnp.minimum(step(i, j, n_ref), nkd - 1), 0
+
+    def down_block(i, j, ids_ref, s_ref, c_ref, n_ref):
+        jj = step(i, j, n_ref)
+        early = jj < nkd - 1     # still the block of the expert before
+        who = jnp.where(early, ids_ref[jnp.maximum(i - 1, 0)], ids_ref[i])
+        blk = jnp.where(early, jnp.where(i > 0, nkf - 1, 0), jj - (nkd - 1))
+        return who, blk, 0
+
+    whole = lambda i, j, *_: (0, 0)
+    kernel = functools.partial(
+        _kernel, tile=tile, nkd=nkd, nkf=nkf, activation=activation,
+        precision=(jax.lax.Precision.HIGHEST
+                   if xs.dtype == jnp.float32 else None))
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=4,
+        grid=(e, steps),
+        in_specs=[pl.BlockSpec((rows, d), whole),
+                  pl.BlockSpec((1, td, f), up_block),
+                  pl.BlockSpec((1, td, f), up_block),
+                  pl.BlockSpec((1, tf, d), down_block)],
+        out_specs=pl.BlockSpec((rows, d), whole),
+        scratch_shapes=[pltpu.VMEM((rows, f), jnp.float32),
+                        pltpu.VMEM((rows, f), jnp.float32),
+                        pltpu.VMEM((rows, f), xs.dtype),
+                        pltpu.VMEM((rows, d), jnp.float32)])
+    out = pl.pallas_call(
+        kernel, grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((rows, d), xs.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary"),
+            vmem_limit_bytes=plan["vmem"]),
+        cost_estimate=pl.CostEstimate(
+            flops=6 * m * d * f, transcendentals=0,
+            bytes_accessed=(3 * min(e, m) * d * f + 2 * m * d)
+            * xs.dtype.itemsize),
+        interpret=interpret, name=KERNEL_NAME,
+    )(ids, starts, counts, n,
+      jnp.pad(xs, ((0, rows - m), (0, 0))) if rows > m else xs, wg, wu, wd)
+    return out[:m] if rows > m else out
+
+
+def expert_ffn_reference(xs, wg, wu, wd, sizes, activation=jax.nn.relu):
+    """The plain statement in float32, an expert at a time: every row
+    through that expert's three matrices, nothing rounded between, kept
+    where the row is the expert's; the rows past the last group 0."""
+    f32 = jnp.float32
+    hi = jax.lax.Precision.HIGHEST
+    x = xs.astype(f32)
+    ends = jnp.cumsum(sizes)
+    row = jnp.arange(xs.shape[0])[:, None]
+
+    def one(y, ew):
+        lo, hi_, g_w, u_w, d_w = ew
+        g = jnp.dot(x, g_w.astype(f32), precision=hi)
+        u = jnp.dot(x, u_w.astype(f32), precision=hi)
+        mine = jnp.dot(activation(g) * u, d_w.astype(f32), precision=hi)
+        return jnp.where((row >= lo) & (row < hi_), mine, y), None
+
+    y, _ = jax.lax.scan(one, jnp.zeros(xs.shape, f32),
+                        (ends - sizes, ends, wg, wu, wd))
+    return y

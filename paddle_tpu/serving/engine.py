@@ -138,6 +138,20 @@ def _sampler_tier(requests) -> int:
     return tier
 
 
+def _expert_matmul_form(mcfg, n_tokens: int) -> Optional[str]:
+    """The form of the experts' grouped product (``ops.moe_ops.matmul_form``)
+    that an executable over ``n_tokens`` rows a forward selects, told on
+    the host from the model's static geometry as the expert layer tells it
+    from its pass's rows; None for a model with no expert layer."""
+    held = getattr(mcfg, "experts_held", None)
+    if held is None:
+        return None
+    from ..ops import moe_ops
+
+    return moe_ops.matmul_form(moe_ops.pass_rows(
+        n_tokens * mcfg.top_k, len(held), mcfg.n_expert))
+
+
 # the per-slot state every decode executable carries from step to step, in
 # the order the executables take it
 _SLOT_STATE = ("_len", "_tok", "_active", "_gen", "_maxnew", "_temp",
@@ -1100,6 +1114,8 @@ class ServingEngine:
         if self.prefix_cache is not None:
             entry = self.prefix_cache.lookup(req.prompt)
         _sm.SAMPLER_DISPATCHES[_sampler_tier((req,))].inc()
+        # a resume runs the remainder through the decode contract: a row a slot
+        self._count_expert_matmul(bucket if entry is None else self.cfg.slots)
         admission = _span("serving/prefill", trace_id=req.trace_id, slot=slot,
                           bucket=bucket,
                           cause="local" if entry is None else "resume")
@@ -1375,7 +1391,15 @@ class ServingEngine:
             for x in jax.tree_util.tree_leaves(outs):
                 x.copy_to_host_async()
         _sm.SAMPLER_DISPATCHES[_sampler_tier(tenants)].inc()
+        # a verify window is ONE forward over every slot's window
+        self._count_expert_matmul(
+            self.cfg.slots * (1 if dlen is None else steps))
         return _Dispatch(snap, tenants, steps, dlen, outs, launch.t0)
+
+    def _count_expert_matmul(self, n_tokens: int) -> None:
+        form = _expert_matmul_form(self.model.cfg, n_tokens)
+        if form is not None:
+            _sm.EXPERT_MATMUL_DISPATCHES[form].inc()
 
     def _sync(self, d: _Dispatch):
         """``d``'s outputs on the host: ``(toks, emitted, fin, logits or

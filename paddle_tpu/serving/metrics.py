@@ -81,6 +81,16 @@ SAMPLER_DISPATCHES = tuple(
         ("sort", "decode dispatches and prefills launched with a request of "
                  "temperature > 0 and top_k > 0: the sampler sorts the "
                  "vocabulary")))
+# keyed by what ops.moe_ops.matmul_form returns
+EXPERT_MATMUL_DISPATCHES = {
+    form: _mx.counter("serving/expert_matmul_dispatches.%s" % form, help=what)
+    for form, what in (
+        ("stream", "decode dispatches and prefills of a model with an expert "
+                   "layer whose passes of the grouped product take the fused "
+                   "stream kernel (few rows an expert: the weights bound it)"),
+        ("grouped", "decode dispatches and prefills of a model with an "
+                    "expert layer whose passes take the compiler's grouped "
+                    "matmul (ragged_dot x 3: many rows, or no TPU)"))}
 REQUEST_LATENCY_MS = _mx.histogram(
     "serving/request_latency_ms",
     help="submit -> finish wall time per retired request")

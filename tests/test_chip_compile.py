@@ -54,6 +54,21 @@ def compiled_text(chip, fn, *shapes):
     return jax.jit(fn).lower(*args).compile().as_text()
 
 
+def _expert_products(text, dims):
+    """What an executable makes of its expert layers: the lines of the
+    fused stream kernel's calls, each of which must name the experts'
+    ``[E, d, f]`` operand (what the grid's readers tell an expert operation
+    by, beside the name), and how many grouped matmuls the compiler made of
+    ``ragged_dot``."""
+    e, d, f = dims
+    stream = [ln for ln in text.split("\n")
+              if "tpu_custom_call" in ln and "%ragged_dot_stream" in ln]
+    for ln in stream:
+        assert "[%d,%d,%d]" % (e, d, f) in ln and "[%d,%d,%d]" % (e, f, d) in ln
+    return stream, len(re.findall(
+        r"= \S+ custom-call\([^\n]*ragged-dot", text))
+
+
 @pytest.mark.parametrize("seq", [2048, 8192])
 def test_flash_attention_fwd_bwd(chip, seq):
     """bf16 causal at the tiles the tune table hands out for this length."""
@@ -620,10 +635,11 @@ def test_cache_attention_over_live_lengths_at_the_cells_shapes(
 @pytest.mark.parametrize("exe", ["chunk", "prefill"])
 def test_sparse_decoder_executables(chip, monkeypatch, exe):
     """The decode chunk runs the grouped-query kernel once a layer and the
-    compiler's grouped matmul three times a layer, and neither it nor an
-    8,192-token prefill (two windows: the banded blocks) copies, slices or
-    transposes a pool of either group; every pool is aliased from input to
-    output."""
+    fused expert-stream kernel once a layer (96 rows a pass: no grouped
+    matmul of the compiler's is left in it), an 8,192-token prefill keeps
+    the compiler's grouped matmul three times a layer (49,152 rows a pass),
+    and neither copies, slices or transposes a pool of either group; every
+    pool is aliased from input to output."""
     # the default backend here is the CPU; only the compile's target is the
     # chip, so the two choices made by asking the backend are made here
     monkeypatch.setattr(attention_ops, "paged_kernel_mode",
@@ -632,10 +648,12 @@ def test_sparse_decoder_executables(chip, monkeypatch, exe):
     (fn, args), ops = _moe_case(exe, chip)
     compiled = jax.jit(fn, donate_argnums=(1,)).lower(*args).compile()
     text = compiled.as_text()
+    stream, grouped = _expert_products(text, (64, 2560, 768))
     if exe == "chunk":
         assert text.count("%paged_attention") >= 4
-        assert len(re.findall(r"= \S+ custom-call\([^\n]*ragged-dot", text)) \
-            >= 3 * 4
+        assert (len(stream), grouped) == (4, 0)
+    else:
+        assert stream == [] and grouped >= 3 * 4
     instructions = list(_instructions(text))
     types = {name: rtype for name, rtype, _, _ in instructions}
     for gi, grp in enumerate(ops.groups):
@@ -651,6 +669,44 @@ def test_sparse_decoder_executables(chip, monkeypatch, exe):
         r"\((\d+), \{\}, (?:may|must)-alias\)", text.split("\n", 1)[0])}
     # "k" "k.window" "pt" "pt.window" "v" "v.window" in key order
     assert {n_params, n_params + 1, n_params + 4, n_params + 5} <= aliased
+
+
+@pytest.mark.parametrize("cell,m,e,d,f", [
+    ("smallthinker-mixed-sat", 96, 64, 2560, 768),
+    ("ling3-flash-reason-sat", 256, 128, 2560, 768),
+    ("kimi-k2-longctx-sat", 256, 12, 7168, 2048),
+    ("laguna-s-code-sat", 160, 128, 3072, 1024)])
+def test_expert_stream_kernel_at_the_served_decode_geometries(
+        chip, cell, m, e, d, f):
+    """The fused expert kernel at each sparse cell's decode pass (rows a
+    pass, experts held, hidden size, expert width), bf16: the chip's
+    compiler takes it, the weights go in AS STORED (no operand of their
+    size is copied or transposed on the way), and the VMEM the compiler
+    reports using fits the limit the kernel asks for, which is the plan's."""
+    from paddle_tpu.ops.pallas_kernels import expert_stream as es
+
+    assert es.expert_stream_gate(m, e, d, f, jnp.bfloat16) is None
+    plan = es.expert_stream_plan(m, e, d, f, jnp.bfloat16)
+    assert (plan["nkd"], plan["nkf"]) == (
+        (4, 4) if cell.startswith("kimi") else (1, 1))
+    text = compiled_text(
+        chip, functools.partial(es.expert_stream_ffn,
+                                activation=jax.nn.silu),
+        ((m, d), jnp.bfloat16), ((e, d, f), jnp.bfloat16),
+        ((e, d, f), jnp.bfloat16), ((e, f, d), jnp.bfloat16),
+        ((e,), jnp.int32))
+    (kernel,), grouped = _expert_products(text, (e, d, f))
+    assert grouped == 0
+    asked, = re.findall(
+        r'"scoped_memory_configs":\[\{"memory_space":"1","offset":"0",'
+        r'"size":"(\d+)"\}\]', kernel)
+    used, = re.findall(
+        r'"used_scoped_memory_configs":\[\{"memory_space":"1","offset":"0",'
+        r'"size":"(\d+)"\}\]', kernel)
+    assert int(asked) == plan["vmem"] and 0 < int(used) <= int(asked)
+    assert [(op, rtype) for _, rtype, op, _ in _instructions(text)
+            if op != "parameter" and (_has_dim(rtype, d) or _has_dim(rtype, f))
+            and _has_dim(rtype, e)] == []
 
 
 # -- the latent (MLA) decoder ---------------------------------------------------
@@ -717,17 +773,18 @@ def _mla_case(chip, n_layer=2):
 
 
 def test_latent_decoder_decode_step(chip, monkeypatch):
-    """The decode step runs the latent kernel once a layer and the
-    compiler's grouped matmul three times an expert layer (inside the
-    share's loop), and does not copy, slice or transpose the latent pool,
-    which is aliased from input to output."""
+    """The decode step runs the latent kernel once a layer and the fused
+    expert-stream kernel once an expert layer (inside the share's loop; no
+    grouped matmul of the compiler's is left), and does not copy, slice or
+    transpose the latent pool, which is aliased from input to output."""
     monkeypatch.setattr(attention_ops, "paged_kernel_mode",
                         lambda: "compiled")
     monkeypatch.setattr(attention_ops, "_on_tpu", lambda: True)
     fn, args, ops = _mla_case(chip)
     text = jax.jit(fn, donate_argnums=(1,)).lower(*args).compile().as_text()
     assert text.count("%mla_latent_decode") >= 2
-    assert len(re.findall(r"= \S+ custom-call\([^\n]*ragged-dot", text)) >= 3
+    stream, grouped = _expert_products(text, (12, 7168, 2048))
+    assert (len(stream), grouped) == (1, 0)
     instructions = list(_instructions(text))
     types = {name: rtype for name, rtype, _, _ in instructions}
     rows = ops.groups[0].num_pages * ops.page_size
@@ -822,8 +879,9 @@ def _mixed_case(chip):
 def test_mixed_query_groups_decoder_decode_step(chip, monkeypatch):
     """The decode step runs the paged kernel once a layer, at the full
     layers' query shape twice and at the window layers' three times, and
-    the compiler's grouped matmul three times an expert layer (inside the
-    share's loop); it does not copy, slice or transpose a pool of either
+    the fused expert-stream kernel once an expert layer (inside the
+    share's loop; no grouped matmul of the compiler's is left); it does
+    not copy, slice or transpose a pool of either
     group, and every pool is aliased from input to output."""
     monkeypatch.setattr(attention_ops, "paged_kernel_mode",
                         lambda: "compiled")
@@ -835,8 +893,8 @@ def test_mixed_query_groups_decoder_decode_step(chip, monkeypatch):
                if "tpu_custom_call" in ln and "%paged_attention" in ln]
     assert sum("bf16[16,8,1024]" in k for k in kernels) == 2
     assert sum("bf16[16,16,1024]" in k for k in kernels) == 3
-    assert len(re.findall(r"= \S+ custom-call\([^\n]*ragged-dot", text)) \
-        >= 3 * 4
+    stream, grouped = _expert_products(text, (128, 3072, 1024))
+    assert (len(stream), grouped) == (4, 0)
     instructions = list(_instructions(text))
     types = {name: rtype for name, rtype, _, _ in instructions}
     for grp in ops.groups:
@@ -933,8 +991,8 @@ def _hybrid_case(chip):
 
 def test_hybrid_decoder_decode_step(chip, monkeypatch):
     """The decode step runs the state kernel once a KDA layer and the
-    latent kernel once, and the compiler's grouped matmul three times an
-    expert layer; it does not copy, slice or transpose the states or the
+    latent kernel once, and the fused expert-stream kernel once an expert
+    layer (no grouped matmul of the compiler's is left); it does not copy, slice or transpose the states or the
     latent pool, and both (and the tails) are aliased from input to
     output."""
     monkeypatch.setattr(attention_ops, "paged_kernel_mode",
@@ -948,7 +1006,8 @@ def test_hybrid_decoder_decode_step(chip, monkeypatch):
                if "tpu_custom_call" in ln]
     assert sum(k.startswith("%kda_state_step") for k in kernels) == 2
     assert sum(k.startswith("%mla_latent_decode") for k in kernels) == 1
-    assert len(re.findall(r"= \S+ custom-call\([^\n]*ragged-dot", text)) >= 3
+    stream, grouped = _expert_products(text, (128, 2560, 768))
+    assert len(stream) >= 1 and grouped == 0
     instructions = list(_instructions(text))
     types = {name: rtype for name, rtype, _, _ in instructions}
     rows = ops.groups[0].num_pages * ops.page_size

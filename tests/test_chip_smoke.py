@@ -21,7 +21,9 @@ TOY = {
                   batch=8, seq=16, steps=2),
     "ctr": dict(vocab=1000, fields=4, width=10, batch=32, steps=2),
     "kernels": dict(flash=(1, 1, 128, 64), xent=(64, 256), sparse_vocab=512,
-                    sparse_ids=64, sparse_widths=(10, 128)),
+                    sparse_ids=64, sparse_widths=(10, 128),
+                    expert_stream=dict(smallthinker=(24, 4, 32, 16, 3, 24),
+                                       kimi_k2=(40, 6, 64, 48, 2, 5))),
     "serve": dict(vocab=64, n_layer=1, d_model=32, n_head=2, max_seq=64,
                   page_size=8, slots=4, requests=3, prompt_min=4,
                   prompt_max=12, new_tokens=3, buckets=(16,),
@@ -86,6 +88,10 @@ def test_one_chip_runs_six_phases_and_ends_with_the_contract_line(
     assert by_phase["serve_mla"]["reference_margin"] < 1e-3
     assert "xla scatter" in by_phase["ctr"]["kernel_path"]["sparse_emb"]
     assert by_phase["kernels"]["kernel_path"] == "interpreted"
+    stream = by_phase["kernels"]["kernels"]["ragged_dot_stream"]
+    assert sorted(stream) == ["kimi_k2", "smallthinker"]
+    for doc in stream.values():
+        assert doc["mean_abs_err"] <= 1.02 * doc["mean_abs_err_ragged_dot"]
 
 
 def test_four_chips_runs_only_the_data_parallel_phase(
