@@ -416,7 +416,7 @@ def gqa_causal_attention(q, k, v, sm_scale=1.0):
         p = jax.nn.softmax(sc, axis=-1)
         o = jnp.einsum("hgqk,khd->qhgd", p.astype(v.dtype), v,
                        preferred_element_type=jnp.float32)
-        return o.astype(q.dtype).reshape(s, hq, d)
+        return o.astype(q.dtype).reshape(s, hq, v.shape[-1])
 
 
 def windowed_causal_attention(q, k, v, window: int, sm_scale=1.0,
@@ -428,7 +428,8 @@ def windowed_causal_attention(q, k, v, window: int, sm_scale=1.0,
     blocks of ``block_q`` rows, each against the ``window + block_q`` keys
     that can reach it: O(S x window) work, and the largest score tensor is
     [Hkv, G * block_q, window + block_q], never S x S. Softmax in
-    float32. Returns [S, Hq, D]."""
+    float32. ``v`` may be narrower than ``q`` and ``k`` (latent attention's
+    expanded heads: 128 against 192). Returns [S, Hq, Dv]."""
     s, hq, d = q.shape
     hkv = k.shape[1]
     g = hq // hkv
@@ -461,7 +462,7 @@ def windowed_causal_attention(q, k, v, window: int, sm_scale=1.0,
                               ).astype(q.dtype)
 
         out = jax.lax.map(block, (jnp.arange(s // bq), qb))
-        return out.reshape(s, hq, d)
+        return out.reshape(s, hq, v.shape[-1])
 
 
 def mla_causal_attention(q, k_nope, k_rope, v, sm_scale=1.0):
@@ -520,3 +521,22 @@ def mla_decode_attention(q, ctx_rows, ctx_len, rank: int, sm_scale=1.0):
     o = jnp.einsum("bhl,blr->bhr", p.astype(ctx_rows.dtype),
                    ctx_rows[..., :rank], preferred_element_type=jnp.float32)
     return o.astype(q.dtype)
+
+
+def differential_combine(o, lam, n_kv: int):
+    """Grouped differential attention's subtraction, after attention
+    (Differential Transformer V2's form): ``o`` [..., H, D] holds the
+    outputs of ``H = n_kv * G`` heads, the ``G`` heads of a KV head side
+    by side, of which the first ``G - 1`` are SIGNAL heads and the last is
+    the group's NOISE head; ``lam`` [..., n_kv * (G - 1)] a weight a signal
+    head. Returns ``o_s - lam_s * o_noise(group of s)`` [..., n_kv * (G -
+    1), D] in ``o``'s type, the subtraction in float32. ``D`` is whatever
+    the heads' outputs are: the values after the up-projection, or the
+    latent before it (a group's heads share one up-projection, which is
+    linear, so the two orders agree)."""
+    lead, (h, d) = o.shape[:-2], o.shape[-2:]
+    g = h // n_kv
+    of = o.astype(jnp.float32).reshape(lead + (n_kv, g, d))
+    lf = lam.astype(jnp.float32).reshape(lead + (n_kv, g - 1, 1))
+    y = of[..., :g - 1, :] - lf * of[..., g - 1:, :]
+    return y.reshape(lead + (n_kv * (g - 1), d)).astype(o.dtype)

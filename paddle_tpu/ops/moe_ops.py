@@ -38,7 +38,11 @@ The routing rule and the experts' activation are the caller's:
 :func:`route_sigmoid_topk` (sigmoid scores, a bias that selects and never
 weighs, group-limited where the model's router is), and
 ``expert_layer(..., activation=)`` (ReLU by default: ReGLU experts;
-``jax.nn.silu`` gives SwiGLU).
+``jax.nn.silu`` gives SwiGLU). An activation with numbers of its own for
+every expert (PolyNorm's three weights and bias) comes with ``act_params``
+[E_held, P] and is called ``activation(gate, p)`` with ``p`` the P numbers
+of the expert the rows belong to; it sees an expert's WHOLE width, so it
+may reduce over it.
 
 Precision: the router's logits accumulate in float32 and its softmax or
 sigmoid is float32; the experts' outputs are combined in float32.
@@ -148,38 +152,55 @@ def matmul_form(rows: int) -> str:
     return "stream" if rows <= STREAM_ROWS and _on_tpu() else "grouped"
 
 
-def _ragged_ffn(xs, wg, wu, wd, sizes, activation):
+def _ragged_ffn(xs, wg, wu, wd, sizes, activation, act_params=None):
     """The grouped feed-forward as three of the compiler's grouped matmuls
-    (gate and up are rounded to ``xs``'s type before the activation)."""
+    (gate and up are rounded to ``xs``'s type before the activation). With
+    ``act_params`` [E, P] each row's activation is given the P numbers of
+    the expert whose group the row lies in, as P columns ``[M, 1]``."""
     gate = jax.lax.ragged_dot(xs, wg, sizes)
     up = jax.lax.ragged_dot(xs, wu, sizes)
-    return jax.lax.ragged_dot(activation(gate) * up, wd, sizes)
+    if act_params is None:
+        return jax.lax.ragged_dot(activation(gate) * up, wd, sizes)
+    e = sizes.shape[0]
+    of_row = jnp.minimum(jnp.searchsorted(
+        jnp.cumsum(sizes), jnp.arange(xs.shape[0]), side="right"), e - 1)
+    p = act_params.astype(jnp.float32)[of_row]
+    act = activation(gate, [p[:, j:j + 1] for j in range(p.shape[1])])
+    return jax.lax.ragged_dot((act * up).astype(xs.dtype), wd, sizes)
 
 
-def _grouped_ffn(xs, wg, wu, wd, sizes, activation):
+def _grouped_ffn(xs, wg, wu, wd, sizes, activation, act_params=None):
     """``(act(xs Wg_e) * (xs Wu_e)) Wd_e`` for the rows of each group of
     ``sizes`` (rows sorted by expert; the rows past the last group are
     unspecified), in ``xs``'s type, in the form :func:`matmul_form` gives
     the pass's rows; widths the kernel's gate refuses (no whole lane
-    tiles: no served model's) keep ``ragged_dot``."""
+    tiles: no served model's) keep ``ragged_dot``. ``act_params`` [E, P]:
+    the activation's own numbers for each expert."""
     m, d = xs.shape
     e, _, f = wg.shape
     if matmul_form(m) == "stream":
         from .pallas_kernels import expert_stream   # Pallas only where used
 
         if expert_stream.expert_stream_gate(m, e, d, f, xs.dtype) is None:
-            return expert_stream.expert_stream_ffn(xs, wg, wu, wd, sizes,
-                                                   activation)
-    return _ragged_ffn(xs, wg, wu, wd, sizes, activation)
+            return expert_stream.expert_stream_ffn(
+                xs, wg, wu, wd, sizes, activation, act_params=act_params)
+    return _ragged_ffn(xs, wg, wu, wd, sizes, activation, act_params)
 
 
 def expert_layer(u, idx, w, wg, wu, wd, n_expert: Optional[int] = None,
                  held: Optional[Sequence[int]] = None, row_valid=None,
-                 activation=jax.nn.relu
+                 activation=jax.nn.relu, act_params=None
                  ) -> Tuple[jnp.ndarray, Dict[str, jnp.ndarray]]:
     """``sum_k w[n, k] * (act(u Wg_e) * (u Wu_e)) Wd_e`` with ``e = idx[n,
-    k]``, for the experts in ``held``; ``activation`` is ``act`` (ReLU:
-    ReGLU experts; ``jax.nn.silu``: SwiGLU).
+    k]``, for the experts in ``held``; ``activation`` is ``act``: a
+    callable of the gate's rows ``[R, f]`` (ReLU: ReGLU experts;
+    ``jax.nn.silu``: SwiGLU), which sees an expert's whole width ``f`` in
+    every path. Where the activation has numbers of its own for every
+    expert, ``act_params`` [E_held, P] holds them in the order of the
+    weights and the callable is ``act(gate, p)`` with ``p`` a list of P
+    values that broadcast against ``[R, 1]``: the numbers of the expert
+    the rows belong to (columns in the ``ragged_dot`` path, scalars in the
+    fused kernel). Without ``act_params`` every path is as it was.
 
     ``u`` [N, d]; ``idx``/``w`` [N, k] from :func:`route_topk` or
     :func:`route_sigmoid_topk`; ``wg``/
@@ -219,9 +240,10 @@ def expert_layer(u, idx, w, wg, wu, wd, n_expert: Optional[int] = None,
         sizes = jnp.zeros((e_held + 1,), jnp.int32).at[flat].add(1)[:e_held]
         if e_held < n_expert:
             return _share(u, w, wg, wu, wd, order, sizes, activation,
-                          pass_rows(n * k, e_held, n_expert)), \
+                          pass_rows(n * k, e_held, n_expert), act_params), \
                 _group_stats(sizes)
-        out = _grouped_ffn(u[order // k], wg, wu, wd, sizes, activation)
+        out = _grouped_ffn(u[order // k], wg, wu, wd, sizes, activation,
+                           act_params)
         # rows past the last group are whatever the grouped matmul left
         out = jnp.where((flat[order] < e_held)[:, None], out, 0)
         back = jnp.zeros((n * k,), jnp.int32).at[order].set(
@@ -237,7 +259,8 @@ def _group_stats(sizes) -> Dict[str, jnp.ndarray]:
             "max_expert_rows": jnp.max(sizes).astype(jnp.int32)}
 
 
-def _share(u, w, wg, wu, wd, order, sizes, activation, rows: int):
+def _share(u, w, wg, wu, wd, order, sizes, activation, rows: int,
+           act_params=None):
     """A share's part of the layer: the sorted pairs of the HELD experts
     (the first ``sum(sizes)`` of ``order``), ``rows`` of them a pass, each
     pass one grouped matmul over its own slice of every group, its rows
@@ -256,7 +279,8 @@ def _share(u, w, wg, wu, wd, order, sizes, activation, rows: int):
         live = at < total
         part = (jnp.clip(ends, lo, lo + rows)
                 - jnp.clip(ends - sizes, lo, lo + rows))
-        out = _grouped_ffn(u[pair // k], wg, wu, wd, part, activation)
+        out = _grouped_ffn(u[pair // k], wg, wu, wd, part, activation,
+                           act_params)
         # rows past the last group are whatever the grouped matmul left
         out = jnp.where(live[:, None],
                         out.astype(jnp.float32) * wf[pair][:, None], 0)

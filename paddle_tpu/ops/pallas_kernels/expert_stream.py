@@ -33,6 +33,12 @@ stream:
   ``act(gate) * up`` is rounded ONCE to the operands' type for the product
   with ``Wd``; the result is rounded once more, as ``ragged_dot``'s is.
 
+* an activation with numbers of its own for every expert (``act_params``
+  [E, P]: PolyNorm's weights and bias) reads them as scalars from SMEM by
+  the expert's id, beside the prefetched lists; it is applied to full-
+  width float32 rows ``[tile, f]`` after the last gate block, so one that
+  reduces over the expert's width needs no tiling of its own.
+
 Rows past the last group come back 0. The kernel's name in a device trace
 is ``ragged_dot_stream``.
 """
@@ -122,8 +128,14 @@ def expert_stream_gate(m: int, e: int, d: int, f: int, dtype,
 
 
 def _kernel(ids_ref, start_ref, count_ref, n_ref, xs_ref, wg_ref, wu_ref,
-            wd_ref, out_ref, g_ref, u_ref, h_ref, y_ref, *, tile, nkd, nkf,
-            activation, precision):
+            wd_ref, *rest, tile, nkd, nkf, activation, precision, n_params):
+    if n_params:        # the activation's numbers of expert ids[i], in SMEM
+        p_ref, *rest = rest
+        act = lambda g: activation(
+            g, [p_ref[ids_ref[i] * n_params + k] for k in range(n_params)])
+    else:
+        act = activation
+    out_ref, g_ref, u_ref, h_ref, y_ref = rest
     i, j = pl.program_id(0), pl.program_id(1)   # the interpreter has none
     td = xs_ref.shape[1] // nkd                 # in a branch
     tf = h_ref.shape[1] // nkf
@@ -161,7 +173,7 @@ def _kernel(ids_ref, start_ref, count_ref, n_ref, xs_ref, wg_ref, wu_ref,
                     g, u = g + g_ref[rows, :], u + u_ref[rows, :]
                 if kd == nkd - 1:
                     # the one rounding between the two products
-                    h_ref[rows, :] = (activation(g) * u).astype(h_ref.dtype)
+                    h_ref[rows, :] = (act(g) * u).astype(h_ref.dtype)
                 else:
                     g_ref[rows, :], u_ref[rows, :] = g, u
 
@@ -204,13 +216,15 @@ def _touched_first(sizes):
 
 @functools.partial(jax.jit, static_argnames=("activation", "interpret"))
 def expert_stream_ffn(xs, wg, wu, wd, sizes, activation=jax.nn.relu, *,
-                      interpret: bool = False):
+                      act_params=None, interpret: bool = False):
     """``(act(xs Wg_e) * (xs Wu_e)) Wd_e`` for the rows of each group.
 
     ``xs`` [M, d] sorted by expert; ``wg``/``wu`` [E, d, f], ``wd`` [E, f,
     d]; ``sizes`` [E] int32, the rows of each group (``sum(sizes) <= M``;
-    the rows past the last group come back 0). Returns [M, d] in ``xs``'s
-    type: ``jax.lax.ragged_dot``'s contract, three products deep.
+    the rows past the last group come back 0). ``act_params`` [E, P]: the
+    activation is then ``act(gate [tile, f] float32, p)`` with ``p`` the P
+    scalars of the rows' expert. Returns [M, d] in ``xs``'s type:
+    ``jax.lax.ragged_dot``'s contract, three products deep.
 
     Jitted so that a model's layers share ONE trace and ONE lowering of
     the kernel in their executable (32 call sites lower in 0.07 s for
@@ -246,17 +260,25 @@ def expert_stream_ffn(xs, wg, wu, wd, sizes, activation=jax.nn.relu, *,
         return who, blk, 0
 
     whole = lambda i, j, *_: (0, 0)
+    n_params = 0 if act_params is None else act_params.shape[1]
+    if n_params and act_params.shape[0] != e:
+        raise ValueError("act_params %s names other than the %d experts"
+                         % (act_params.shape, e))
     kernel = functools.partial(
         _kernel, tile=tile, nkd=nkd, nkf=nkf, activation=activation,
+        n_params=n_params,
         precision=(jax.lax.Precision.HIGHEST
                    if xs.dtype == jnp.float32 else None))
+    smem = ([pl.BlockSpec(memory_space=pltpu.SMEM)] if n_params else [])
+    numbers = ([act_params.astype(jnp.float32).reshape(-1)]
+               if n_params else [])
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=4,
         grid=(e, steps),
         in_specs=[pl.BlockSpec((rows, d), whole),
                   pl.BlockSpec((1, td, f), up_block),
                   pl.BlockSpec((1, td, f), up_block),
-                  pl.BlockSpec((1, tf, d), down_block)],
+                  pl.BlockSpec((1, tf, d), down_block)] + smem,
         out_specs=pl.BlockSpec((rows, d), whole),
         scratch_shapes=[pltpu.VMEM((rows, f), jnp.float32),
                         pltpu.VMEM((rows, f), jnp.float32),
@@ -274,27 +296,36 @@ def expert_stream_ffn(xs, wg, wu, wd, sizes, activation=jax.nn.relu, *,
             * xs.dtype.itemsize),
         interpret=interpret, name=KERNEL_NAME,
     )(ids, starts, counts, n,
-      jnp.pad(xs, ((0, rows - m), (0, 0))) if rows > m else xs, wg, wu, wd)
+      jnp.pad(xs, ((0, rows - m), (0, 0))) if rows > m else xs, wg, wu, wd,
+      *numbers)
     return out[:m] if rows > m else out
 
 
-def expert_ffn_reference(xs, wg, wu, wd, sizes, activation=jax.nn.relu):
+def expert_ffn_reference(xs, wg, wu, wd, sizes, activation=jax.nn.relu,
+                         act_params=None):
     """The plain statement in float32, an expert at a time: every row
-    through that expert's three matrices, nothing rounded between, kept
-    where the row is the expert's; the rows past the last group 0."""
+    through that expert's three matrices (and, with ``act_params`` [E, P],
+    the activation given that expert's P numbers), nothing rounded
+    between, kept where the row is the expert's; the rows past the last
+    group 0."""
     f32 = jnp.float32
     hi = jax.lax.Precision.HIGHEST
     x = xs.astype(f32)
     ends = jnp.cumsum(sizes)
     row = jnp.arange(xs.shape[0])[:, None]
 
+    numbers = (jnp.zeros((sizes.shape[0], 0), f32) if act_params is None
+               else act_params.astype(f32))
+
     def one(y, ew):
-        lo, hi_, g_w, u_w, d_w = ew
+        lo, hi_, g_w, u_w, d_w, p = ew
         g = jnp.dot(x, g_w.astype(f32), precision=hi)
         u = jnp.dot(x, u_w.astype(f32), precision=hi)
-        mine = jnp.dot(activation(g) * u, d_w.astype(f32), precision=hi)
+        a = (activation(g) if act_params is None
+             else activation(g, [p[k] for k in range(p.shape[0])]))
+        mine = jnp.dot(a * u, d_w.astype(f32), precision=hi)
         return jnp.where((row >= lo) & (row < hi_), mine, y), None
 
     y, _ = jax.lax.scan(one, jnp.zeros(xs.shape, f32),
-                        (ends - sizes, ends, wg, wu, wd))
+                        (ends - sizes, ends, wg, wu, wd, numbers))
     return y

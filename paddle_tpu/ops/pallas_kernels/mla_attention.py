@@ -13,11 +13,13 @@ runs over the first ``rank`` lanes of the SAME row:
     o[h, :] = sum_j softmax_j(s[h, :]) row[j, :rank]
 
 so a page is DMA'd once and feeds both products: about ``2 H (W + rank) /
-(2 W)`` operations a cache byte (121 at H = 64, 576 + 512 lanes in bf16),
-near the chip's ridge, where the grouped kernel of ``paged_attention.py``
-(a few operations a byte) is far under it. Hence the differences from that
-kernel, whose page-table, scalar-prefetch and ragged-length skeleton this
-one shares:
+(2 W)`` operations a cache byte (60 at H = 32, 121 at H = 64, 151 at H =
+80, over 576 + 512 lanes in bf16: the served models' head counts), near
+the chip's ridge, where the grouped kernel of ``paged_attention.py`` (a
+few operations a byte) is far under it. ``H`` is any number: the heads are
+padded to whole sublanes (8) and the score tile is ``[H, 512]`` float32.
+Hence the differences from that kernel, whose page-table, scalar-prefetch
+and ragged-length skeleton this one shares:
 
 - both products ride the MXU in the POOL's type with float32 accumulation
   (bf16 rows are not widened first; a float32 pool, as in the CPU tests,
@@ -30,7 +32,10 @@ one shares:
 A slot of length 0 writes zeros and ends its grid step there; rows at or
 beyond the length are zeroed before use and masked with the package's one
 masking constant, so stale rows contribute exactly 0.0. The kernel's name
-in a device trace is ``mla_latent_decode``.
+in a device trace is ``mla_latent_decode``; a caller whose page table is a
+RING of a few pages (a window layer: one wave a slot, a call whose cost is
+its launches and DMA waits and not its bytes) names its calls
+``mla_latent_decode_ring`` so that a trace's reader can tell the two.
 """
 
 from __future__ import annotations
@@ -45,9 +50,10 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 __all__ = ["mla_paged_decode", "mla_gather_reference", "mla_decode_gate",
-           "KERNEL_NAME"]
+           "KERNEL_NAME", "RING_KERNEL_NAME"]
 
 KERNEL_NAME = "mla_latent_decode"
+RING_KERNEL_NAME = "mla_latent_decode_ring"
 _LANES = 128
 _WAVE_ROWS = 512     # context rows a wave folds: [H, 512] f32 scores
 
@@ -55,8 +61,11 @@ _WAVE_ROWS = 512     # context rows a wave folds: [H, 512] f32 scores
 def mla_decode_gate(dtype, width: int, rank: int, page_size: int,
                     interpret: bool = False) -> Optional[str]:
     """None when the compiled kernel takes this latent geometry, else the
-    rule that excludes it. The shape rules are the chip compiler's tiling
-    and do not bind the interpreter."""
+    rule that excludes it: a row or a latent that is not whole lane tiles,
+    a page that is not whole sublane tiles of the pool's type, a type that
+    is neither float32 nor bfloat16. The number of heads is not among the
+    rules (32, 64 and 80 are served). The shape rules are the chip
+    compiler's tiling and do not bind the interpreter."""
     dt = jnp.dtype(dtype)
     if dt not in (jnp.dtype(jnp.float32), jnp.dtype(jnp.bfloat16)):
         return "latent dtype %s is not float32/bfloat16" % dt.name
@@ -150,7 +159,7 @@ def _mla_kernel(pt_ref, len_ref, layer_ref, q_ref, pool, o_ref, scr, sems, *,
 
 def mla_paged_decode(q, pool, page_table, ctx_len, *, page_size, rank,
                      layer=None, sm_scale=1.0, block_pages=None,
-                     interpret: bool = False):
+                     interpret: bool = False, name: str = KERNEL_NAME):
     """Absorbed latent decode attention over a paged pool.
 
     ``q`` [B, H, W]: each head's absorbed query over the row's lanes (its
@@ -159,7 +168,8 @@ def mla_paged_decode(q, pool, page_table, ctx_len, *, page_size, rank,
     layer ``layer`` is read), or one layer [rows, W] with ``layer`` None.
     ``page_table`` [B, pages_per_slot] int32; ``ctx_len`` [B] valid leading
     rows a slot (0: the slot holds nothing, its output is exactly 0.0 and
-    it moves no page). Returns [B, H, rank] in ``q``'s type, matching
+    it moves no page). ``name`` is the call's name in a device trace.
+    Returns [B, H, rank] in ``q``'s type, matching
     :func:`mla_gather_reference` to the products' round-off."""
     b, h, width = q.shape
     if pool.ndim == 2 and layer is None:
@@ -203,7 +213,7 @@ def mla_paged_decode(q, pool, page_table, ctx_len, *, page_size, rank,
     out = pl.pallas_call(
         kernel, grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, hp, rank), q.dtype),
-        interpret=interpret, name=KERNEL_NAME,
+        interpret=interpret, name=name,
     )(page_table.reshape(-1).astype(jnp.int32), ctx_len.astype(jnp.int32),
       jnp.asarray(layer, jnp.int32).reshape(1), qk, pool)
     return out[:, :h]

@@ -401,9 +401,11 @@ class ServingEngine:
       or ``latent_row``, ``(rank, rope)``: the model's latent-attention
       layers keep ONE ``[c | kr]`` row a token and the cache is a
       :class:`~.kv_cache.LatentPagedCache` sized from it: over every
-      layer, or over the ``LATENT`` group of ``cache_groups`` beside a
-      ``STATE`` group whose layers keep ``slot_state`` ``(heads, dk, dv,
-      tail rows, tail width)`` a SLOT and no pages,
+      layer, or over the ``LATENT`` groups of ``cache_groups`` (one of
+      pages, or one of pages beside one with a ``window`` whose slots keep
+      rings) with, after them, a ``STATE`` group whose layers keep
+      ``slot_state`` ``(heads, dk, dv, tail rows, tail width)`` a SLOT and
+      no pages,
     * ``model.prefill(params, tokens[B,S], lengths[B]) -> (logits[B,S,V],
       kvs)`` with ``kvs``, a layer, what the cache's ``write_prompt``
       takes with a leading batch axis: one ``(k, v)`` ``[B,S,H,D]`` pair
@@ -505,6 +507,9 @@ class ServingEngine:
         if self.cfg.paged:
             _sm.STATE_POOL_BYTES.set(
                 self.cache_ops.state_bytes(self._cache))
+            if latent:
+                _sm.LATENT_RING_BYTES.set(
+                    self.cache_ops.ring_bytes(self._cache))
         b = self.cfg.slots
         self._reset_slot_state()
         self._prefill_exe: Dict[int, Any] = {}   # bucket -> AOT executable
@@ -599,9 +604,14 @@ class ServingEngine:
         """What cannot work over a cache of more than one group, or over a
         latent one, said at construction rather than computed wrong."""
         cfg = self.cfg
-        over = ("a latent cache (one [c | kr] row a token, no V pool%s)"
-                % (", beside a state a slot that has no pages"
-                   if STATE in [g[3] for g in layer_groups] else "")
+        kinds = [g[3] for g in layer_groups]
+        over = ("a latent cache (one [c | kr] row a token, no V pool%s%s)"
+                % (", %d latent groups %s of which the windowed keep rings"
+                   % (kinds.count(LATENT),
+                      [g[0] for g in layer_groups if g[3] == LATENT])
+                   if kinds.count(LATENT) > 1 else "",
+                   ", beside a state a slot that has no pages"
+                   if STATE in kinds else "")
                 if latent else "a cache with %d groups %s"
                 % (len(layer_groups), [g[0] for g in layer_groups]))
         for on, what in (

@@ -55,9 +55,13 @@ different kinds side by side:
   compressed KV latent and the one rotary key every head shares), and no V
   pool: decode attention, absorbed, scores every query head against that
   row and sums over its first ``rank`` lanes. Pages, page tables, the
-  pool's free list and the drop scatter are the paged cache's own. The
-  group covers the layers it names: all of them (Kimi-K2) or one in six
-  (a hybrid whose other layers keep a state).
+  pool's free list and the drop scatter are the paged cache's own. A
+  group covers the layers it names: all of them (Kimi-K2), one in six (a
+  hybrid whose other layers keep a state), or one in four beside a second
+  LATENT group with a ``window`` (a model whose other latent layers see
+  the last W positions only): that group's slots keep their rows in a
+  RING by the rule above, the row being stored with its rotary key
+  already rotated.
 * ``STATE``: the layers of a linear-attention recurrence keep nothing a
   token. What they keep belongs to the SLOT, has a fixed size and is
   rewritten whole at every step: a ``[H, dk, dv]`` float32 state and the
@@ -801,11 +805,14 @@ class Int8PagedKVCache(PagedKVCache):
 
 
 class LatentPagedCache(PagedKVCache):
-    """The paged layout whose paged group keeps latent rows: ONE pool,
-    ``"c"`` ``[layers of the group, num_pages*page_size, row_width]``, and
-    no V pool. Beside it there may be a state group (``groups``,
-    ``slot_state``); without ``groups`` the latent group is every layer
-    (Kimi-K2: the one-group case).
+    """The paged layout whose paged groups keep latent rows: a pool a
+    group, ``"c"`` ``[layers of the group, num_pages*page_size,
+    row_width]``, and no V pool. ONE LATENT group a ``window`` (None: pages
+    for every position; W: a ring of the last W, the paged cache's own
+    rule), each with its page table and its free list; after them there
+    may be a state group (``groups``, ``slot_state``). Without
+    ``groups`` the one latent group is every layer (Kimi-K2: the one-group
+    case).
 
     A token's row is ``[c (rank) | kr (rope) | 0...]``: ``rank + rope``
     values (512 + 64 at DeepSeek-V3's sizes, against 64 heads x (192 + 128)
@@ -817,9 +824,11 @@ class LatentPagedCache(PagedKVCache):
     ``decode_attention`` takes the ABSORBED query ``[B, H, rank + rope]``
     and returns ``[B, H, rank]`` (ops.attention_ops.mla_decode_attention or
     the kernel of ops/pallas_kernels/mla_attention.py, by the same flag
-    as the paged kernel). What needs a K and a V row (speculative verify,
-    the int8 pool, page export and import, with them the prefix cache) is
-    refused by the paged cache's own rule: nobody needs it yet."""
+    as the paged kernel; a window group's call carries the kernel name
+    ``mla_latent_decode_ring`` in a device trace). What needs a K and a V
+    row (speculative verify, the int8 pool, page export and import, with
+    them the prefix cache) is refused by the paged cache's own rule:
+    nobody needs it yet."""
 
     layout = "paged-latent"
     _POOLS = ("c",)
@@ -835,10 +844,14 @@ class LatentPagedCache(PagedKVCache):
         if groups is None:
             groups = [CacheGroup("latent", tuple(range(int(n_layer))), None,
                                  int(num_pages), LATENT)]
-        if [g.kind for g in groups if g.kind != STATE] != [LATENT]:
-            raise ValueError("a latent cache has ONE latent group (and "
-                             "state groups after it), got %s"
-                             % [(g.name, g.kind) for g in groups])
+        paged = [g for g in groups if g.kind != STATE]
+        if {g.kind for g in paged} != {LATENT} \
+                or len({g.window for g in paged}) != len(paged):
+            raise ValueError(
+                "a latent cache has ONE latent group a window (None: pages "
+                "for every position; W: a ring of the last W), and state "
+                "groups after them, got %s"
+                % [(g.name, g.kind, g.window) for g in groups])
         super().__init__(n_layer, 1, width, slots, max_ctx, page_size,
                          groups[0].num_pages, dtype, groups=groups,
                          slot_state=slot_state)
@@ -862,17 +875,27 @@ class LatentPagedCache(PagedKVCache):
     def _write_rows(self, state: Cache, layer: int, dest, row_new, _v
                     ) -> Cache:
         """The paged cache's destinations, one padded row each."""
-        _, li = self._where[layer]
+        gi, li = self._where[layer]
+        key = self._key(gi, "c")
         rows = row_new.reshape(-1, self.row_values).astype(self.dtype)
         rows = jnp.pad(rows, ((0, 0), (0, self.row_width - self.row_values)))
         return {**state,
-                "c": state["c"].at[li, dest].set(rows, mode="drop")}
+                key: state[key].at[li, dest].set(rows, mode="drop")}
 
     def context(self, state: Cache, layer: int) -> jnp.ndarray:
         """Every slot's rows of ``layer`` in page-table order: ``[slots,
-        max_ctx, row_width]`` (the XLA-gather path)."""
-        _, li = self._where[layer]
-        return state["c"][li, self._context_rows(state["pt"])]
+        rows, row_width]`` with ``rows`` = ``max_ctx``, or the ring's
+        length in a window group (the XLA-gather path)."""
+        gi, li = self._where[layer]
+        return state[self._key(gi, "c")][
+            li, self._context_rows(state[self._key(gi, "pt")])]
+
+    def ring_bytes(self, state: Cache) -> int:
+        """The pools of the window groups as stored: what the rings hold
+        whatever the contexts' lengths."""
+        return int(sum(state[self._key(gi, "c")].nbytes
+                       for gi, g in enumerate(self.groups)
+                       if g.kind == LATENT and g.window is not None))
 
     def _kernel_gate(self, interpret: bool) -> Optional[str]:
         from ..ops.pallas_kernels.mla_attention import mla_decode_gate
@@ -883,20 +906,23 @@ class LatentPagedCache(PagedKVCache):
     def decode_attention(self, state: Cache, layer: int, q, ctx_len,
                          active, sm_scale: float = 1.0) -> jnp.ndarray:
         """``q`` [B, H, rank + rope], absorbed; [B, H, rank] out, over each
-        slot's LIVE length (:func:`_live_len`)."""
+        slot's LIVE length (:func:`_live_len`), in a window group over
+        ``min(that, window)`` rows of its ring."""
         from ..ops import attention_ops
 
-        _, li = self._where[layer]
-        length = _live_len(ctx_len, active)
+        gi, li = self._where[layer]
+        length = self._group_len(gi, _live_len(ctx_len, active))
         q = jnp.pad(q, ((0, 0), (0, 0), (0, self.row_width - q.shape[-1])))
         mode, _ = self.kernel_mode()
         if mode is not None:
             from ..ops.pallas_kernels import mla_attention as _mla
 
             return _mla.mla_paged_decode(
-                q, state["c"], state["pt"], length,
-                page_size=self.page_size, rank=self.rank, layer=li,
-                sm_scale=sm_scale, interpret=(mode == "interpret"))
+                q, state[self._key(gi, "c")], state[self._key(gi, "pt")],
+                length, page_size=self.page_size, rank=self.rank, layer=li,
+                sm_scale=sm_scale, interpret=(mode == "interpret"),
+                name=(_mla.KERNEL_NAME if self.groups[gi].window is None
+                      else _mla.RING_KERNEL_NAME))
         return attention_ops.mla_decode_attention(
             q, self.context(state, layer), length, self.rank,
             sm_scale=sm_scale)

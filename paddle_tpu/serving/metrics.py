@@ -26,7 +26,7 @@ __all__ = [
     "SPEC_PROPOSED", "SPEC_ACCEPTED", "SPEC_REJECTED", "SPEC_DRAFTS",
     "SPEC_VERIFY_DISPATCHES", "SPEC_ACCEPT_RATE",
     "MOE_EXPERTS_TOUCHED", "MOE_MAX_EXPERT_ROWS", "MOE_HELD_PAIRS",
-    "STATE_SLOTS_STEPPED", "STATE_POOL_BYTES",
+    "STATE_SLOTS_STEPPED", "STATE_POOL_BYTES", "LATENT_RING_BYTES",
     "pages_used", "attn_rows_read", "model_stat",
 ]
 
@@ -199,6 +199,11 @@ STATE_POOL_BYTES = _mx.gauge(
     "serving/state_pool_bytes",
     help="bytes of the per-slot recurrent states and convolution tails "
          "the cache holds (0 for a cache without a state group)")
+LATENT_RING_BYTES = _mx.gauge(
+    "serving/latent_ring_bytes",
+    help="bytes of the latent pools whose slots keep the last W rows as a "
+         "ring (0 for a latent cache without a window group): what those "
+         "layers hold whatever the contexts' lengths")
 
 # a model's decode ``stats`` by name (:func:`model_stat`)
 _MODEL_STATS = {"moe_experts_touched": MOE_EXPERTS_TOUCHED,

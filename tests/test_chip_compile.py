@@ -1022,3 +1022,116 @@ def test_hybrid_decoder_decode_step(chip, monkeypatch):
         r"\((\d+), \{\}, (?:may|must)-alias\)", text.split("\n", 1)[0])}
     # "c" "pt" "s.state" "tail.state" in key order
     assert {n_params, n_params + 2, n_params + 3} <= aliased
+
+
+# -- the grouped-differential latent decoder (motif-3-beta-ep16-serve) -----------
+
+
+def test_expert_stream_kernel_with_an_experts_own_numbers(chip):
+    """The fused expert kernel at the cell's decode pass (256 rows, 24 of
+    384 held, 4096 x 1280, bf16) with PolyNorm's four numbers an expert as
+    an SMEM operand: the chip's compiler takes it, a matrix goes in two
+    row blocks (10.5 MB each), and no operand of the weights' size is
+    copied on the way."""
+    from paddle_tpu.models import motif3 as mf
+    from paddle_tpu.ops.pallas_kernels import expert_stream as es
+
+    m, e, d, f = 256, 24, 4096, 1280
+    assert es.expert_stream_gate(m, e, d, f, jnp.bfloat16) is None
+    plan = es.expert_stream_plan(m, e, d, f, jnp.bfloat16)
+    assert (plan["nkd"], plan["nkf"]) == (2, 2)
+
+    def ffn(xs, wg, wu, wd, sizes, pn):
+        return es.expert_stream_ffn(xs, wg, wu, wd, sizes,
+                                    mf._activation(0.5, 0.5), act_params=pn)
+
+    text = compiled_text(
+        chip, ffn, ((m, d), jnp.bfloat16), ((e, d, f), jnp.bfloat16),
+        ((e, d, f), jnp.bfloat16), ((e, f, d), jnp.bfloat16),
+        ((e,), jnp.int32), ((e, 4), jnp.float32))
+    (kernel,), grouped = _expert_products(text, (e, d, f))
+    assert grouped == 0 and "f32[%d]" % (4 * e) in kernel
+    assert [(op, rtype) for _, rtype, op, _ in _instructions(text)
+            if op != "parameter" and (_has_dim(rtype, d) or _has_dim(rtype, f))
+            and _has_dim(rtype, e)] == []
+
+
+def _gdla_case(chip):
+    """The decode step of the grouped-differential latent decoder at its
+    published widths, as one chip of sixteen holds it (24 of 384 experts,
+    27,520 rows of the vocabulary): a dense window layer, a sparse window
+    layer and a sparse full layer over the cell's page pool and 64 slots'
+    rings."""
+    from paddle_tpu.models import motif3 as mf
+    from paddle_tpu.serving.kv_cache import CacheGroup, LatentPagedCache
+
+    yarn = {"original_max_position_embeddings": 4096, "factor": 64,
+            "mscale": 1, "rope_type": "yarn", "rope_theta": 10000,
+            "beta_fast": 32, "beta_slow": 1, "apply_yarn_scaling": False}
+    cfg = mf.Motif3Config(
+        vocab_size=27520, n_layer=3, d_model=4096, n_head=80, n_kv_head=16,
+        q_rank=1024, kv_rank=512, d_nope=128, d_rope=64, d_v=128,
+        layer_types=["window", "window", "full"], window=128, d_dense=12288,
+        dense_layers=(0,), n_expert=384, top_k=8, d_expert=1280,
+        routed_scale=2.0, rope_scaling=yarn, max_seq=16384,
+        dtype="bfloat16", experts_held=tuple(range(24)))
+    model = mf.Motif3LM(cfg, params={})
+
+    def sds(x):
+        return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=chip)
+
+    params = jax.tree_util.tree_map(
+        sds, jax.eval_shape(lambda: mf.init_params(cfg, 0)))
+    pages = {"latent_full": 36864, "latent_ring": 64 * 8}
+    groups = [CacheGroup(name, layers, window, pages[name], kind)
+              for name, layers, window, kind in cfg.cache_groups]
+    ops = LatentPagedCache(3, 512, 64, 64, 16384, 16, 36864,
+                           dtype="bfloat16", groups=groups)
+    cache = jax.tree_util.tree_map(sds, jax.eval_shape(ops.init_state))
+    ints = jax.ShapeDtypeStruct((64,), jnp.int32, sharding=chip)
+    flags = jax.ShapeDtypeStruct((64,), jnp.bool_, sharding=chip)
+
+    def chunk(params, cache, lengths, tokens, active):
+        logits, cache, stats = model.decode(params, cache, ops, tokens,
+                                            lengths, active)
+        return cache, jnp.argmax(logits, -1), stats
+
+    return chunk, (params, cache, ints, ints, flags), ops
+
+
+def test_gdla_decoder_decode_step(chip, monkeypatch):
+    """The decode step runs the latent kernel at 80 heads once a layer,
+    under its ring name over the window layers' rings and its own over the
+    full layer's pages, and the fused expert-stream kernel once an expert
+    layer (no grouped matmul of the compiler's is left); it does not copy,
+    slice or transpose either pool, and both are aliased from input to
+    output."""
+    monkeypatch.setattr(attention_ops, "paged_kernel_mode",
+                        lambda: "compiled")
+    monkeypatch.setattr(attention_ops, "_on_tpu", lambda: True)
+    fn, args, ops = _gdla_case(chip)
+    assert ops.kernel_mode() == ("compiled", None)
+    text = jax.jit(fn, donate_argnums=(1,)).lower(*args).compile().as_text()
+    kernels = [ln.strip().split(" = ")[0] for ln in text.split("\n")
+               if "tpu_custom_call" in ln]
+    assert sum(k.startswith("%mla_latent_decode_ring") for k in kernels) == 2
+    assert sum(k.startswith("%mla_latent_decode") for k in kernels) == 3
+    assert all("bf16[64,80,512]" in ln for ln in text.split("\n")
+               if "tpu_custom_call" in ln and "%mla_latent_decode" in ln)
+    stream, grouped = _expert_products(text, (24, 4096, 1280))
+    assert len(stream) >= 1 and grouped == 0
+    instructions = list(_instructions(text))
+    types = {name: rtype for name, rtype, _, _ in instructions}
+    pools = [g.num_pages * ops.page_size for g in ops.groups]
+    moved = [(op, rtype) for _, rtype, op, operands in instructions
+             if op in ("copy", "copy-start", "slice", "dynamic-slice",
+                       "transpose")
+             and any(_has_dim(t, rows) and _has_dim(t, 640)
+                     for rows in pools
+                     for t in [rtype] + [types.get(o, "") for o in operands])]
+    assert moved == [], moved
+    n_params = len(jax.tree_util.tree_leaves(args[0]))
+    aliased = {int(p) for p in re.findall(
+        r"\((\d+), \{\}, (?:may|must)-alias\)", text.split("\n", 1)[0])}
+    # "c" "c.latent_ring" "pt" "pt.latent_ring" in key order
+    assert {n_params, n_params + 1} <= aliased
