@@ -24,17 +24,52 @@ and ragged-length skeleton this one shares:
 - both products ride the MXU in the POOL's type with float32 accumulation
   (bf16 rows are not widened first; a float32 pool, as in the CPU tests,
   multiplies at full precision); the softmax state is float32;
-- the waves are double-buffered: wave ``w + 1``'s pages are in flight
-  while wave ``w`` is folded, because here the fold is not free beside the
-  DMA;
 - no head-membership matmuls and no V pool.
 
-A slot of length 0 writes zeros and ends its grid step there; rows at or
-beyond the length are zeroed before use and masked with the package's one
-masking constant, so stale rows contribute exactly 0.0. The kernel's name
-in a device trace is ``mla_latent_decode``; a caller whose page table is a
-RING of a few pages (a window layer: one wave a slot, a call whose cost is
-its launches and DMA waits and not its bytes) names its calls
+A wave's order of work (PR 44; what each part cost before and after is in
+PERF.md section 6 and ``benchmarks/diag_latent_ring.py``). The waves are
+double-buffered over the WHOLE sequential grid: two wave buffers, one DMA
+semaphore each, and two SMEM words that one slot leaves the next (which
+buffer the next live slot's wave 0 lands in, and whether it is already
+started). For wave ``w`` of a slot, in buffer ``buf``, ONE loop body:
+
+1. start what comes next into the other buffer: this slot's wave ``w + 1``
+   or, behind the slot's last wave, wave 0 of the NEXT slot that holds a
+   row (rowless slots are skipped over; the last live slot starts
+   nothing), so that a slot's first wave, and the ONE wave of a ring call,
+   is in flight while the slot before is folded;
+2. wait for wave ``w``;
+3. fold it into the online-softmax state ``(m, l, acc)``.
+
+A wave is FULL when every one of its rows is below the slot's length and
+every one of its pages is in the table. Its ``block_pages`` copies start
+with no predicate, in unrolled runs of four, one loop a buffer (so a
+copy's buffer and semaphore are constants of its descriptor); they are
+waited for ONCE (all signal the buffer's one semaphore; the wait is for
+their sum) and the buffer is folded as it lies. Only a slot's last wave
+can be partial: it copies and awaits just the pages that hold a row below
+the length (a loop of that many trips) and ZEROES the rows at or beyond
+the length in the buffer, where the exactly-0.0 probabilities would meet
+them. The scores of such rows are replaced with the package's one masking
+constant; that select runs on every wave (all true in a full one, at a
+cost no chip run could read) so that the kernel holds one fold. So stale
+rows (whatever the buffer last held, Inf and NaN included) contribute
+exactly 0.0. A slot of length 0 writes zeros, moves no page and leaves the
+two words as they are; a length past the table is read as the table's.
+
+The kernel's TEXT is part of its cost: a decode executable holds one copy
+a layer and the serve cells compile theirs at every start (0.2 s a copy
+before PR 44). Every copy of a wave unrolled with a constant destination
+folds a 512-row wave in 1.21 us (56% of the stream at 80 heads) and
+compiles in 1.0-1.9 s a copy, 22 s more set-up in Kimi's cell; the runs of
+four read 1.5 us (45%) and compile in 0.3 s. On a v5e at 80 heads the fold
+alone is 0.80 us a wave, the copies alone 0.85, and a copy's descriptor
+costs the scalar unit 13 ns (20 with a destination computed at run time)
+that it does NOT overlap with the fold: what bounds a wave now is its 32
+descriptors, not its 655 KB (0.80 us at the HBM rate). The
+kernel's name in a device trace is ``mla_latent_decode``; a caller whose
+page table is a RING of a few pages (a window layer: one wave a slot, a
+call whose cost is each slot's own chain and not its bytes) names its calls
 ``mla_latent_decode_ring`` so that a trace's reader can tell the two.
 """
 
@@ -55,7 +90,11 @@ __all__ = ["mla_paged_decode", "mla_gather_reference", "mla_decode_gate",
 KERNEL_NAME = "mla_latent_decode"
 RING_KERNEL_NAME = "mla_latent_decode_ring"
 _LANES = 128
-_WAVE_ROWS = 512     # context rows a wave folds: [H, 512] f32 scores
+# context rows a wave folds: [H, 512] f32 scores. Read on the chip at 256,
+# 512, 1,024 and 2,048 rows (PERF.md, PR 44): 1,024 folds 2 to 8% faster at
+# the served lengths and costs each of a decode executable's kernels a
+# tenth of a second more to compile, which every start pays
+_WAVE_ROWS = 512
 
 
 def mla_decode_gate(dtype, width: int, rank: int, page_size: int,
@@ -81,58 +120,131 @@ def mla_decode_gate(dtype, width: int, rank: int, page_size: int,
     return None
 
 
-def _mla_kernel(pt_ref, len_ref, layer_ref, q_ref, pool, o_ref, scr, sems, *,
-                block_pages, page_size, pages_per_slot, num_pages, rank,
-                sm_scale, mask_value, precision):
+def _mla_kernel(pt_ref, len_ref, layer_ref, q_ref, pool, o_ref, scr, sems,
+                ahead, *, block_pages, page_size, pages_per_slot, num_pages,
+                rank, sm_scale, mask_value, precision):
     b = pl.program_id(0)  # out here: the interpreter has none in a branch
-    ctx = len_ref[b]
-    live = ctx > 0
+    slots = pl.num_programs(0)
     ps = page_size
     rows = block_pages * ps
     layer = layer_ref[0]
+    n_waves = -(-pages_per_slot // block_pages)
+    whole = pages_per_slot // block_pages   # waves with every page tabled
+    # a full wave's copies start in unrolled runs of this many (2 reads 6%
+    # slower than 4 or 8 on the chip; a copy more in a run is 8 ms more of
+    # compilation for each of an executable's kernels, at every start)
+    run = max(d for d in range(1, 5) if block_pages % d == 0)
 
-    def dma(w, i, buf):
-        """The copy of wave ``w``'s page ``i`` into buffer ``buf``."""
-        pidx = jnp.minimum(w * block_pages + i, pages_per_slot - 1)
-        page = jnp.clip(pt_ref[b * pages_per_slot + pidx], 0, num_pages - 1)
+    def length(slot):
+        """A slot's rows, as many as its table can hold."""
+        return jnp.minimum(len_ref[slot], pages_per_slot * ps)
+
+    ctx = length(b)
+
+    def page(slot, w, i, buf):
+        """The copy of ``slot``'s wave ``w``, page ``i``, into ``buf``. A
+        table entry is clamped: a corrupt one reads a wrong page, never
+        out of bounds."""
+        entry = pt_ref[slot * pages_per_slot + w * block_pages + i]
         return pltpu.make_async_copy(
-            pool.at[layer, pl.ds(page * ps, ps)],
-            scr.at[buf, pl.ds(i * ps, ps)], sems.at[buf, i])
+            pool.at[layer, pl.ds(jnp.clip(entry, 0, num_pages - 1) * ps, ps)],
+            scr.at[buf, pl.ds(pl.multiple_of(i * ps, ps), ps)], sems.at[buf])
 
-    def each_page(w, buf, act):
+    def each_live_page(slot, length, w, buf, act):
+        """``act`` on the copy of each page of a wave that holds a row
+        below the length, one at a time; a page wholly at or past the
+        length is not in the loop."""
+        live = jnp.minimum((length + ps - 1) // ps, pages_per_slot)
+
         def body(i, _):
-            pidx = w * block_pages + i
-
-            @pl.when((pidx < pages_per_slot) & (pidx * ps < ctx))
-            def _():
-                act(dma(w, i, buf))
-
+            act(page(slot, w, i, buf))
             return 0
 
-        jax.lax.fori_loop(0, block_pages, body, 0)
+        jax.lax.fori_loop(
+            0, jnp.clip(live - w * block_pages, 0, block_pages), body, 0)
 
-    @pl.when(live)
+    def start(slot, length, w, buf):
+        """Wave ``w`` of ``slot`` on its way into ``buf``. A FULL wave (no
+        row at or past the length, no page past the table) is a straight
+        run with no predicate, one a buffer, so that a copy's buffer and
+        semaphore are constants of its descriptor."""
+        full = ((w + 1) * rows <= length) & (w < whole)
+
+        for const in (0, 1):
+            @pl.when(full & (buf == const))
+            def _(const=const):
+                def some(g, _):
+                    for j in range(run):
+                        page(slot, w, g * run + j, const).start()
+                    return 0
+
+                jax.lax.fori_loop(0, block_pages // run, some, 0)
+
+        @pl.when(jnp.logical_not(full))
+        def _():
+            each_live_page(slot, length, w, buf, lambda c: c.start())
+
+    @pl.when(b == 0)
+    def _():
+        ahead[0] = 0    # the buffer this slot's wave 0 lands in
+        ahead[1] = 0    # 1: an earlier slot has started that wave
+
+    @pl.when(ctx > 0)
     def _():
         q = q_ref[0]                                  # [H, W], pool's type
-        n_waves = -(-pages_per_slot // block_pages)
+        first = ahead[0]
         live_waves = jnp.minimum((ctx + rows - 1) // rows, n_waves)
-        each_page(0, 0, lambda c: c.start())
+        full_waves = jnp.minimum(ctx // rows, whole)  # live_waves or 1 less
+        # the next slot that holds a row: its wave 0 follows this slot's last
+        nxt = jax.lax.while_loop(
+            lambda s: (s < slots) & (len_ref[jnp.minimum(s, slots - 1)] <= 0),
+            lambda s: s + 1, b + 1)
+        follows = nxt < slots
+        nxt = jnp.minimum(nxt, slots - 1)
+
+        @pl.when(ahead[1] == 0)     # the call's first live slot: its own
+        def _():
+            each_live_page(b, ctx, 0, first, lambda c: c.start())
 
         def wave(w, carry):
             m, l, acc = carry
-            buf = w % 2
+            buf = (first + w) % 2
+            # in flight while this wave is folded, in the buffer the fold
+            # does not read: this slot's wave w + 1 or, behind its last,
+            # the next live slot's wave 0
+            more = w + 1 < live_waves
 
-            @pl.when(w + 1 < live_waves)
+            @pl.when(more | follows)
             def _():
-                each_page(w + 1, 1 - buf, lambda c: c.start())
+                start(jnp.where(more, b, nxt),
+                      jnp.where(more, ctx, length(nxt)),
+                      jnp.where(more, w + 1, 0), 1 - buf)
 
-            each_page(w, buf, lambda c: c.wait())
-            col = w * rows + jax.lax.broadcasted_iota(jnp.int32, (rows, 1), 0)
-            # rows past the length hold whatever the buffer last held
-            kb = jnp.where(col < ctx, scr[buf], 0)    # [R, W]
+            @pl.when(w < full_waves)
+            def _():
+                # the wave's copies signal ONE semaphore: one wait of
+                # their sum, and the buffer is folded as it lies
+                pltpu.make_async_copy(scr.at[buf], scr.at[buf],
+                                      sems.at[buf]).wait()
+
+            @pl.when(w >= full_waves)
+            def _():
+                # the slot's last wave, partial: rows at or past the
+                # length hold whatever the buffer last held and are
+                # zeroed where the exactly-0 probabilities meet them
+                # (Inf/NaN * 0)
+                each_live_page(b, ctx, w, buf, lambda c: c.wait())
+                col = w * rows + jax.lax.broadcasted_iota(
+                    jnp.int32, (rows, 1), 0)
+                scr[buf] = jnp.where(col < ctx, scr[buf], 0)
+
+            kb = scr[buf]                             # [R, W]
             s = jax.lax.dot_general(
                 q, kb, (((1,), (1,)), ((), ())), precision=precision,
                 preferred_element_type=jnp.float32) * sm_scale   # [H, R]
+            # all true in a full wave (which costs nothing a chip run
+            # could read); a second fold without it doubles the kernel's
+            # text, and its compilation is paid at every start
             pos = w * rows + jax.lax.broadcasted_iota(jnp.int32, (1, rows), 1)
             s = jnp.where(pos < ctx, s, mask_value)
             m_new = jnp.maximum(m, jnp.max(s, axis=1, keepdims=True))
@@ -151,8 +263,10 @@ def _mla_kernel(pt_ref, len_ref, layer_ref, q_ref, pool, o_ref, scr, sems, *,
         # ctx >= 1 here, so every state has folded a valid row: l >= 1
         _, l, acc = jax.lax.fori_loop(0, live_waves, wave, init)
         o_ref[0] = (acc / l).astype(o_ref.dtype)
+        ahead[0] = (first + live_waves) % 2
+        ahead[1] = follows.astype(jnp.int32)
 
-    @pl.when(jnp.logical_not(live))
+    @pl.when(ctx <= 0)
     def _():
         o_ref[...] = jnp.zeros_like(o_ref)
 
@@ -209,10 +323,13 @@ def mla_paged_decode(q, pool, page_table, ctx_len, *, page_size, rank,
                   pl.BlockSpec(memory_space=pl.ANY)],
         out_specs=pl.BlockSpec((1, hp, rank), lambda i, *_: (i, 0, 0)),
         scratch_shapes=[pltpu.VMEM((2, bp * ps, width), pool.dtype),
-                        pltpu.SemaphoreType.DMA((2, bp))])
+                        pltpu.SemaphoreType.DMA((2,)),
+                        pltpu.SMEM((2,), jnp.int32)])
     out = pl.pallas_call(
         kernel, grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, hp, rank), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),  # a slot leaves the next
         interpret=interpret, name=name,
     )(page_table.reshape(-1).astype(jnp.int32), ctx_len.astype(jnp.int32),
       jnp.asarray(layer, jnp.int32).reshape(1), qk, pool)

@@ -60,6 +60,38 @@ def rng():
 
 
 @pytest.fixture
+def poisoned_latent_pool():
+    """Builds a latent pool for the kernel's tests: ``(q, poisoned, clean,
+    page_table)`` for slots of ``lens`` rows. ``poisoned`` [2, rows, width]
+    holds random rows below each slot's length in layer 1 and Inf or NaN
+    in every other row of both layers; ``clean`` [rows, width] is layer 1
+    with zeros where the poison is (what a reference may read); the page
+    table is scrambled."""
+    import jax.numpy as jnp
+
+    def build(rng, lens, h, rank, rope, ps, pps, width=128):
+        lens = np.asarray(lens, np.int32)
+        slots = len(lens)
+        pages = slots * pps + 3
+        clean = np.zeros((pages * ps, width), np.float32)
+        pt = rng.permutation(pages)[:slots * pps].reshape(slots, pps)
+        live = np.zeros(pages * ps, bool)
+        for s in range(slots):
+            flat = pt[s].repeat(ps) * ps + np.tile(np.arange(ps), pps)
+            live[flat[:lens[s]]] = True
+        clean[live, :rank + rope] = rng.randn(int(live.sum()), rank + rope)
+        poisoned = np.stack([np.full_like(clean, np.nan), clean])
+        poisoned[1, ~live] = np.where(np.arange((~live).sum()) % 2,
+                                      np.inf, np.nan)[:, None]
+        q = np.zeros((slots, h, width), np.float32)
+        q[..., :rank + rope] = rng.randn(slots, h, rank + rope)
+        return (jnp.asarray(q), jnp.asarray(poisoned), jnp.asarray(clean),
+                jnp.asarray(pt.astype(np.int32)))
+
+    return build
+
+
+@pytest.fixture
 def attention_spy(monkeypatch):
     """What the attention of every decode step is given, for engines built
     inside the test: wraps the caches' ``decode_attention`` /

@@ -712,24 +712,34 @@ def test_expert_stream_kernel_at_the_served_decode_geometries(
 # -- the latent (MLA) decoder ---------------------------------------------------
 
 
-def test_latent_decode_kernel_at_the_served_geometry(chip):
-    """64 heads over one 640-lane row (512 latent + 64 rotary + padding),
-    page 16, bf16, the cell's whole pool, a layer in the middle: the kernel
-    compiles under its own name and nothing outside it touches the pool."""
+@pytest.mark.parametrize("slots,heads,pages,layers,ring", [
+    (32, 64, 1024, 7, False),   # kimi-k2-longctx-sat
+    (64, 32, 1024, 1, False),   # ling3-flash-reason-sat: one layer in seven
+    (64, 80, 1024, 2, False),   # motif3-docreason-sat's two full layers
+    (64, 80, 8, 7, True),       # ... and its seven rings of 128 rows
+], ids=["64_heads", "32_heads", "80_heads", "80_heads_ring"])
+def test_latent_decode_kernel_at_the_served_geometry(chip, slots, heads,
+                                                     pages, layers, ring):
+    """32, 64 and 80 heads over one 640-lane row (512 latent + 64 rotary +
+    padding), page 16, bf16, a cell's whole pool, its last layer, at the
+    wave the kernel ships with; and the window layers' call over a ring of
+    eight pages a slot: the kernel compiles under the call's name, as ONE
+    call, and nothing outside it touches the pool."""
     from paddle_tpu.ops.pallas_kernels import mla_attention as mla
 
     assert mla.mla_decode_gate(jnp.bfloat16, 640, 512, 16) is None
-    rows = 18432 * 16
+    name = mla.RING_KERNEL_NAME if ring else mla.KERNEL_NAME
+    rows = (slots * pages if ring else 18432) * 16
     text = compiled_text(
         chip,
         functools.partial(mla.mla_paged_decode, page_size=16, rank=512,
-                          layer=3, sm_scale=0.1309),
-        ((32, 64, 640), jnp.bfloat16), ((7, rows, 640), jnp.bfloat16),
-        ((32, 1024), jnp.int32), ((32,), jnp.int32))
+                          layer=layers - 1, sm_scale=0.1309, name=name),
+        ((slots, heads, 640), jnp.bfloat16),
+        ((layers, rows, 640), jnp.bfloat16),
+        ((slots, pages), jnp.int32), ((slots,), jnp.int32))
     kernel, = [ln for ln in text.split("\n") if "tpu_custom_call" in ln]
-    assert kernel.strip().startswith(("%mla_latent_decode",
-                                      "ROOT %mla_latent_decode"))
-    assert "bf16[32,64,512]" in kernel
+    assert kernel.strip().startswith(("%" + name + ".", "ROOT %" + name + "."))
+    assert "bf16[%d,%d,512]" % (slots, heads) in kernel
     assert [op for _, rtype, op, _ in _instructions(text)
             if _has_dim(rtype, rows) and op != "parameter"] == []
 

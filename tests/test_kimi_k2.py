@@ -243,6 +243,48 @@ def test_latent_kernel_equals_the_gather(block, rng):
     np.testing.assert_array_equal(run(poisoned)[0], got)
 
 
+# a wave is 2 pages of 8 rows here; a slot's table holds 6 pages (3 waves)
+WAVE_LENGTHS = {
+    "nothing_one_row_and_a_row_short_of_a_wave": [0, 1, 15],
+    "one_wave_a_row_past_it_and_two_waves": [16, 17, 32],
+    "a_rowless_slot_between_two_live_ones": [5, 0, 40],
+    "two_rowless_slots_between_two_full_waves": [16, 0, 0, 32],
+    "the_last_slot_rowless": [33, 16, 0],
+    "only_the_last_slot_live": [0, 0, 7],
+    "every_slot_rowless": [0, 0, 0],
+    "the_whole_table": [48, 48],
+    "odd_and_even_waves_in_turn": [16, 32, 16, 48, 9, 31],
+    "a_length_past_the_table": [16, 60, 3],
+}
+
+
+@pytest.mark.parametrize("block", [2, 4], ids=["2_pages_a_wave",
+                                                "4_pages_a_wave"])
+@pytest.mark.parametrize("case", sorted(WAVE_LENGTHS))
+def test_every_branch_of_the_wave_loop_equals_the_gather(
+        case, block, rng, poisoned_latent_pool):
+    """Lengths that land on each branch of the kernel's wave loop (a full
+    wave folded as it lies, the boundary wave zeroed and masked, the next
+    live slot's first wave started behind this slot's last, over rowless
+    slots; with 4 pages a wave the table's 6 pages end in a wave that is
+    never full): every row past a length is Inf or NaN and the outputs
+    are finite and the gather's."""
+    ps, pps, rank = 8, 6, 16
+    lens = np.minimum(WAVE_LENGTHS[case], ps * pps)
+    q, poisoned, clean, pt = poisoned_latent_pool(rng, lens, 4, rank, 8, ps,
+                                                  pps)
+    # the kernel is handed the lengths as they come; the table bounds them
+    got = np.asarray(mla.mla_paged_decode(
+        q, poisoned, pt, jnp.asarray(WAVE_LENGTHS[case], jnp.int32),
+        page_size=ps, rank=rank, layer=1, sm_scale=0.3, block_pages=block,
+        interpret=True))
+    want = np.asarray(mla.mla_gather_reference(
+        q, clean, pt, jnp.asarray(lens), ps, rank, sm_scale=0.3))
+    assert np.all(np.isfinite(got))
+    np.testing.assert_allclose(got, want, atol=2e-6, rtol=0)
+    assert np.all(got[lens == 0] == 0)
+
+
 def test_the_gate_knows_the_latent_row():
     assert mla.mla_decode_gate(jnp.bfloat16, 640, 512, 16) is None
     assert "multiples of 128" in mla.mla_decode_gate(jnp.bfloat16, 576, 512,

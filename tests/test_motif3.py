@@ -637,6 +637,35 @@ def test_the_ring_call_has_a_name_of_its_own_and_the_gate_says_what_it_refuses()
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=1e-5)
 
 
+RING_LENGTHS = {
+    "one_row_a_row_short_and_the_whole_ring": [1, 127, 128],
+    "whole_rings_in_a_row": [128, 128, 128, 128],
+    "a_rowless_slot_between_two_whole_rings": [128, 0, 128, 5],
+    "the_last_slot_rowless": [127, 128, 0],
+    "only_the_last_slot_live": [0, 0, 128],
+}
+
+
+@pytest.mark.parametrize("case", sorted(RING_LENGTHS))
+def test_a_ring_call_of_eight_pages_equals_the_gather(case,
+                                                      poisoned_latent_pool):
+    """The window layers' call: ONE wave of eight pages a slot, so what
+    overlaps a slot's fold is the NEXT live slot's copy. 80 heads, every
+    row past a length Inf or NaN: finite and the gather's."""
+    lens = np.asarray(RING_LENGTHS[case], np.int32)
+    ps, rank = 16, 32
+    q, poisoned, clean, pt = poisoned_latent_pool(
+        np.random.RandomState(7), lens, 80, rank, 16, ps, 8)
+    got = np.asarray(mla.mla_paged_decode(
+        q, poisoned, pt, jnp.asarray(lens), page_size=ps, rank=rank, layer=1,
+        sm_scale=0.1, interpret=True, name=mla.RING_KERNEL_NAME))
+    want = np.asarray(mla.mla_gather_reference(
+        q, clean, pt, jnp.asarray(lens), ps, rank, 0.1))
+    assert np.all(np.isfinite(got))
+    np.testing.assert_allclose(got, want, atol=1e-5, rtol=0)
+    assert np.all(got[lens == 0] == 0)
+
+
 def test_the_benchmark_holds_a_copy_of_the_reference():
     """``grid/reference/motif3.py`` (the benchmark's, which a later PR may
     not edit) and ``models/motif3_reference.py`` (the program's) are one
