@@ -56,6 +56,9 @@ def infer_op_shapes(op, block) -> None:
     class _Trace:
         is_test = False
         current_op_idx = 0
+        # an op whose result's shape needs none of its body may ask, and
+        # skip a body that is dear to trace (a Pallas kernel's)
+        shapes_only = True
 
         def __init__(self):
             self.base_rng = None

@@ -20,12 +20,19 @@ _DEFAULTS: Dict[str, Any] = {
     "benchmark": False,              # block_until_ready every step (operator.cc:942)
     "strict_fused_attention": False, # raise (not warn+fallback) if the Pallas
                                      # flash-attention call fails on TPU
-    "flash_attention_min_seq": 2048, # perf crossover: with v5e-tuned
-                                     # BlockSizes (r4 sweep) flash beats
-                                     # composed 1.6x at S=2048 up to 4.2x at
-                                     # S=8192; composed wins below (its single
-                                     # fused HLO beats the kernel's fixed
-                                     # grid overhead at short S)
+    "flash_attention_min_seq": 2048, # perf crossover of the LONG path's
+                                     # kernel: with v5e-tuned BlockSizes (r4
+                                     # sweep) flash beats composed 1.6x at
+                                     # S=2048 up to 4.2x at S=8192; composed
+                                     # wins below (its single fused HLO beats
+                                     # that kernel's fixed grid overhead at
+                                     # short S). That sweep had no dropout, no
+                                     # segment ids, block_b 1 and the long
+                                     # kernel only: a key length of ONE tile
+                                     # (S <= 512) has its own kernels and its
+                                     # own predicate of shapes, which this
+                                     # flag does not move
+                                     # (attention_ops._single_tile_ok)
     "unfused_attention": False,      # layers.attention emits the reference-
                                      # style primitive composition (matmul/
                                      # scale/softmax/dropout/matmul) instead

@@ -144,16 +144,25 @@ def _tuned_block_sizes(sq: int, sk: int):
 
 
 def _flash_ok(q, k, causal) -> bool:
-    """Gate for the Pallas kernel: blocking constraints (seq multiples of
-    128) AND a measured perf crossover. With the v5e-tuned BlockSizes (see
-    _tuned_block_sizes) the round-4 sweep (benchmarks/sweep_flash_crossover.py,
-    b* h8 d64 causal bf16 fwd+bwd, loop-difference timing) measured flash
-    speedup over composed: S=1024 0.80x, S=2048 1.61x, S=4096 3.46x,
-    S=8192 4.15x, S=16384 3.25x. The crossover is ~S=2048, which is the
+    """Gate for the LONG path's Pallas kernel (online softmax over key
+    blocks): blocking constraints (seq multiples of 128) AND a measured
+    perf crossover. With the v5e-tuned BlockSizes (see _tuned_block_sizes)
+    the round-4 sweep (benchmarks/sweep_flash_crossover.py, b* h8 d64
+    causal bf16 fwd+bwd, loop-difference timing) measured flash speedup
+    over composed: S=1024 0.80x, S=2048 1.61x, S=4096 3.46x, S=8192 4.15x,
+    S=16384 3.25x. The crossover is ~S=2048, which is the
     FLAGS_flash_attention_min_seq default; below it the composed path's
-    single fused HLO beats the kernel's fixed grid overhead, above it the
+    single fused HLO beats that kernel's fixed grid overhead, above it the
     O(S) memory AND the tiling win compound. (The composed path OOMs around
-    S~24k single-chip, so flash is also the only viable path there.)"""
+    S~24k single-chip, so flash is also the only viable path there.)
+
+    What that sweep did NOT cover: dropout (the composed side drew no
+    threefry mask and kept none for the backward), segment ids, more than
+    one batch row a grid step (``block_b`` 1), and any kernel but the long
+    one. A key length that is one tile has its own kernels and its own
+    predicate, :func:`_single_tile_ok`, which :func:`sdpa` alone asks;
+    this gate and its callers (:func:`gqa_causal_attention`,
+    :func:`mla_causal_attention`) read as they did."""
     if not _on_tpu():
         return False
     b, h, sq, d = q.shape
@@ -222,9 +231,92 @@ def _flash_dropout_bwd(causal, sm_scale, rate, res, do):
 _flash_dropout.defvjp(_flash_dropout_fwd, _flash_dropout_bwd)
 
 
+# The fewest (row, head) pairs for which a key length of one tile takes the
+# single-tile kernels. Below it a call is a few microseconds of chip work
+# either way (GPT-2 small's prefill holds 12 pairs) and the composed lines
+# stay; the table behind the number is in PERF.md section 6 under PR 45
+# (benchmarks/diag_short_attention.py).
+SINGLE_TILE_MIN_PAIRS = 64
+
+
+def _single_tile_ok(q, k, causal, bias=None) -> bool:
+    """Whether :func:`sdpa` computes this call in the single-tile kernels
+    (ops/pallas_kernels/short_attention.py): the chip, no additive bias,
+    S_q and S_k multiples of 128 and at most 512 (the whole key length is
+    one tile), heads of 64 or 128 that fill whole lane tiles, and enough
+    (row, head) pairs to be worth a kernel. Dropout and segment ids do not
+    enter: the kernels take both."""
+    if (not _on_tpu() or bias is not None or q.ndim != 4
+            or q.shape[0] * q.shape[1] < SINGLE_TILE_MIN_PAIRS):
+        return False
+    from .pallas_kernels import short_attention as sa  # Pallas: a second
+
+    return sa.supported(q.shape, k.shape, q.dtype, causal)
+
+
+def _single_tile(q, k, v, seg_q, seg_kv, causal, sm_scale, rate, rng, mesh):
+    """The single-tile kernels over the whole batch, or over each chip's
+    rows where the batch is split over ``mesh``'s ``data`` axis: the
+    compiler does not partition a ``tpu_custom_call`` (it would gather
+    every row to every chip), so the call is mapped over the shards, and
+    each shard moves the hash's batch coordinate by its first row so that
+    two chips do not drop the same elements of their rows."""
+    from .pallas_kernels import short_attention as sa
+
+    seed = None
+    if rate > 0.0:
+        seed = jax.lax.bitcast_convert_type(
+            jax.random.bits(rng, (1,), jnp.uint32), jnp.int32)
+
+    def call(q, k, v, seg_q, seg_kv, seed):
+        return sa.single_tile_attention(q, k, v, seg_q, seg_kv, seed, causal,
+                                        float(sm_scale), float(rate))
+
+    n = mesh.shape.get("data", 1) if mesh is not None else 1
+    if n == 1 or q.shape[0] % n:
+        return call(q, k, v, seg_q, seg_kv, seed)
+    from jax.sharding import PartitionSpec as P
+
+    rows = q.shape[0] // n
+
+    def shard(q, k, v, seg_q, seg_kv, seed):
+        if seed is not None:
+            seed = sa.shard_seed(seed, jax.lax.axis_index("data") * rows)
+        return call(q, k, v, seg_q, seg_kv, seed)
+
+    row, seg = P("data"), None if seg_q is None else P("data")
+    return jax.shard_map(
+        shard, mesh=mesh, in_specs=(row, row, row, seg, seg, P()),
+        out_specs=row, check_vma=False)(q, k, v, seg_q, seg_kv, seed)
+
+
+def _count(path: str) -> None:
+    """One more ``sdpa`` call traced into ``path``: trace-time counters
+    (an executable's calls count once, when it is traced)."""
+    from ..monitor import metrics
+
+    metrics.counter(
+        "attention/sdpa_calls." + path,
+        help="sdpa calls traced into the %s path (counted where sdpa "
+             "chooses: once a call of a traced program, not once a run)"
+             % path).inc()
+
+
 def sdpa(q, k, v, bias=None, segment_ids_q=None, segment_ids_kv=None,
-         causal=False, sm_scale=1.0, dropout_rate=0.0, dropout_rng=None):
-    """Scaled dot-product attention over [B, H, S, D] tensors."""
+         causal=False, sm_scale=1.0, dropout_rate=0.0, dropout_rng=None,
+         mesh=None):
+    """Scaled dot-product attention over [B, H, S, D] tensors. Three
+    paths, chosen on the arguments' shapes alone: the single-tile kernels
+    where the whole key length is one tile (:func:`_single_tile_ok`), the
+    long path's flash kernels from ``FLAGS_flash_attention_min_seq`` on
+    (:func:`_flash_ok`), else the composed lines. ``mesh`` is the device
+    mesh of the trace, if any: the single-tile call is mapped over the
+    shards of its ``data`` axis."""
+    if _single_tile_ok(q, k, causal, bias):
+        _count("single_tile")
+        rate = dropout_rate if dropout_rng is not None else 0.0
+        return _single_tile(q, k, v, segment_ids_q, segment_ids_kv, causal,
+                            sm_scale, rate, dropout_rng, mesh)
     use_flash = dropout_rate == 0.0 and _flash_ok(q, k, causal)
     if (dropout_rate > 0.0 and dropout_rng is not None and bias is None
             and segment_ids_q is None and segment_ids_kv is None
@@ -234,8 +326,10 @@ def sdpa(q, k, v, bias=None, segment_ids_q=None, segment_ids_kv=None,
         seed = jax.lax.bitcast_convert_type(
             jax.random.bits(dropout_rng, (1,), jnp.uint32), jnp.int32)
         try:
-            return _flash_dropout(q, k, v, seed, causal, float(sm_scale),
-                                  float(dropout_rate))
+            out = _flash_dropout(q, k, v, seed, causal, float(sm_scale),
+                                 float(dropout_rate))
+            _count("flash")
+            return out
         except Exception as e:
             # honor the same never-hide contract as the no-dropout path:
             # falling back means an ~S^2 memory/perf cliff (note the try
@@ -260,8 +354,10 @@ def sdpa(q, k, v, bias=None, segment_ids_q=None, segment_ids_kv=None,
             seg = SegmentIds(q=segment_ids_q, kv=segment_ids_kv)
         try:
             bs = _tuned_block_sizes(q.shape[2], k.shape[2])
-            return flash(q, k, v, ab=bias, segment_ids=seg, causal=causal,
-                         sm_scale=sm_scale, block_sizes=bs)
+            out = flash(q, k, v, ab=bias, segment_ids=seg, causal=causal,
+                        sm_scale=sm_scale, block_sizes=bs)
+            _count("flash")
+            return out
         except Exception as e:
             # A failed flash call means a ~S² perf regression — never hide it.
             from ..flags import get_flag
@@ -277,6 +373,15 @@ def sdpa(q, k, v, bias=None, segment_ids_q=None, segment_ids_kv=None,
                 "composed O(S^2) attention. Set FLAGS_strict_fused_attention=1 "
                 "to make this an error." % (type(e).__name__, e),
                 RuntimeWarning, stacklevel=2)
+    _count("composed")
+    return _composed(q, k, v, bias, segment_ids_q, segment_ids_kv, causal,
+                     sm_scale, dropout_rate, dropout_rng)
+
+
+def _composed(q, k, v, bias, segment_ids_q, segment_ids_kv, causal, sm_scale,
+              dropout_rate, dropout_rng):
+    """The composed path: every [B, H, S_q, S_k] tensor is the compiler's
+    to fuse, write and re-read."""
     scores = jnp.einsum("bhqd,bhkd->bhqk", q, k) * sm_scale
     if bias is not None:
         scores = scores + bias
@@ -381,6 +486,12 @@ def verify_attention(q, ctx_k, ctx_v, ctx_len, sm_scale=1.0):
 @register_op("scaled_dot_product_attention")
 def sdpa_op(ctx: OpContext):
     q, k, v = ctx.input("Q"), ctx.input("K"), ctx.input("V")
+    if getattr(ctx.trace, "shapes_only", False):
+        # shape inference while the program is built (at a placeholder
+        # batch): tracing a kernel's body there is seconds of every start
+        ctx.set_output("Out", jax.ShapeDtypeStruct(
+            q.shape[:3] + v.shape[3:], q.dtype))
+        return
     bias = ctx.input("Bias")
     seg_q = ctx.input("SegmentIdsQ")
     seg_kv = ctx.input("SegmentIdsKV")
@@ -388,7 +499,13 @@ def sdpa_op(ctx: OpContext):
     sm_scale = ctx.attr("sm_scale", 1.0)
     p = 0.0 if ctx.is_test else ctx.attr("dropout_rate", 0.0)
     rng = ctx.rng() if p > 0.0 else None
-    ctx.set_output("Out", sdpa(q, k, v, bias, seg_q, seg_kv, causal, sm_scale, p, rng))
+    mesh = getattr(ctx.trace, "mesh", None)
+    if mesh is None:
+        from ..parallel.mesh import get_mesh
+
+        mesh = get_mesh()
+    ctx.set_output("Out", sdpa(q, k, v, bias, seg_q, seg_kv, causal, sm_scale,
+                               p, rng, mesh=mesh))
 
 
 def gqa_causal_attention(q, k, v, sm_scale=1.0):

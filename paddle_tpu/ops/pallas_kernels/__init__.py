@@ -11,6 +11,9 @@ Kernels:
 - expert_stream: the routed experts' gate, up and down products of a decode
   pass as ONE kernel that streams each touched expert's weights once
   (``ragged_dot`` x 3 reads 47-84% of that stream at the served shapes).
+- short_attention: attention whose whole key length is one tile (S <= 512),
+  forward and ONE backward kernel, dropout and segment ids inside: no
+  ``[B, H, S, S]`` tensor reaches HBM (Transformer-base at S = 256).
 
 Each kernel has an XLA-composed reference implementation it is numerically
 tested against, and ``benchmarks/bench_softmax_xent.py`` /

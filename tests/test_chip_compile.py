@@ -85,6 +85,59 @@ def test_flash_attention_fwd_bwd(chip, seq):
     assert text.count("tpu_custom_call") >= 3  # fwd, dq, dkv
 
 
+@pytest.mark.parametrize("case", ["encoder", "decoder_self", "cross"])
+def test_single_tile_attention_at_transformer_base(chip, monkeypatch, case):
+    """Through ``sdpa`` at the training cell's shape, dropout 0.1 and
+    segment ids, forward and backward: two kernels and no ``[B, H, S, S]``
+    tensor anywhere in the executable."""
+    monkeypatch.setattr(attention_ops, "_on_tpu", lambda: True)
+
+    def loss(q, k, v, seg_q, seg_kv, key):
+        o = attention_ops.sdpa(
+            q, k, v, None, seg_q, seg_kv if case == "cross" else seg_q,
+            case == "decoder_self", 0.125, 0.1, key)
+        return (o.astype(jnp.float32) ** 2).sum()
+
+    act, seg = ((96, 8, 256, 64), jnp.bfloat16), ((96, 256), jnp.int32)
+    text = compiled_text(chip, jax.grad(loss, argnums=(0, 1, 2)),
+                         act, act, act, seg, seg, ((2,), jnp.uint32))
+    assert text.count("tpu_custom_call") == 2
+    assert "single_tile_attention_fwd" in text
+    assert "single_tile_attention_bwd" in text
+    assert "[96,8,256,256]" not in text
+
+
+def test_single_tile_attention_runs_a_shard_on_four_chips(chip, monkeypatch):
+    """384 rows split over a ``data`` axis of four described chips: each
+    chip's kernel holds its 96 rows, and no collective gathers q, k or v."""
+    import numpy as np
+    from jax.experimental import topologies
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    monkeypatch.setattr(attention_ops, "_on_tpu", lambda: True)
+    # the fixture has described the topology once: this process may again
+    topo = topologies.get_topology_desc(platform="tpu",
+                                        topology_name="v5e:2x2")
+    mesh = Mesh(np.array(topo.devices), ("data",))
+    rows, repl = NamedSharding(mesh, P("data")), NamedSharding(mesh, P())
+
+    def loss(q, k, v, seg, key):
+        o = attention_ops.sdpa(q, k, v, None, seg, seg, True, 0.125, 0.1,
+                               key, mesh=mesh)
+        return (o.astype(jnp.float32) ** 2).sum()
+
+    act = jax.ShapeDtypeStruct((384, 8, 256, 64), jnp.bfloat16, sharding=rows)
+    seg = jax.ShapeDtypeStruct((384, 256), jnp.int32, sharding=rows)
+    key = jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=repl)
+    text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        act, act, act, seg, key).compile().as_text()
+    calls = [ln for ln in text.split("\n") if "tpu_custom_call" in ln]
+    assert len(calls) == 2
+    assert all("[96,256,512]" in ln and "[384," not in ln for ln in calls)
+    assert "all-gather" not in text
+    assert "[96,8,256,256]" not in text
+
+
 def test_softmax_xent_fwd_bwd(chip):
     def loss(logits, labels):
         return fused_softmax_xent(logits, labels).sum()
