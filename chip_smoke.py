@@ -719,7 +719,7 @@ def phase_serve_moe(seed, meter):
     import jax
     import numpy as np
 
-    from paddle_tpu.models import smallthinker_reference as reference
+    from grid.reference import smallthinker as reference
     from paddle_tpu.models.smallthinker import (
         SmallThinkerConfig, SmallThinkerLM)
     from paddle_tpu.serving import ServingConfig, ServingEngine
@@ -784,7 +784,7 @@ def phase_serve_moe(seed, meter):
     return {"checked": "%d requests (prompts %s, %d tokens each, the longest "
                        "context %d past the window of %d) through two cache "
                        "groups: greedy tokens == the float32 reference's "
-                       "(models/smallthinker_reference.py); the window group "
+                       "(grid/reference/smallthinker.py); the window group "
                        "never over %d pages a slot; both pools balance"
                        % (len(reqs), list(cfg["prompts"]), cfg["new_tokens"],
                           longest, cfg["window"], ring),
@@ -804,7 +804,7 @@ def phase_serve_mla(seed, meter):
     margin of its float32 reference given the same share."""
     import numpy as np
 
-    from paddle_tpu.models import kimi_k2_reference as reference
+    from grid.reference import kimi_k2 as reference
     from paddle_tpu.models.kimi_k2 import KimiK2Config, KimiK2LM
     from paddle_tpu.serving import ServingConfig, ServingEngine
 
@@ -861,7 +861,7 @@ def phase_serve_mla(seed, meter):
                        "decode steps through a latent cache of one [c | kr] "
                        "row a token; logits finite; the served tokens rank "
                        "%.4f below the float32 reference's best at worst "
-                       "(models/kimi_k2_reference.py given experts 0-%d, "
+                       "(grid/reference/kimi_k2.py given experts 0-%d, "
                        "margin %.2f); the pool balances"
                        % (cfg["prompt"], cfg["new_tokens"] - 1, worst,
                           cfg["held"] - 1, reference.LOGIT_MARGIN),

@@ -37,6 +37,7 @@ from .monitor import GRAD_NORM_VAR, device as _dev, metrics as _mx, tracer as _t
 from .monitor import numerics as _num
 from .monitor.numerics import NUM_STATS as _NUM_STATS, \
     STATS_ENV_KEY as _STATS_ENV_KEY
+from .parallel.mesh import valid_sharding
 from .reliability import faults as _faults
 
 __all__ = ["Executor", "FeedError", "FetchHandle", "TraceContext",
@@ -305,13 +306,6 @@ def _mesh_batch_spec(mesh, leading_step_axis=False):
     if "data" not in mesh.axis_names:
         return P()
     return P(None, "data") if leading_step_axis else P("data")
-
-
-def _valid_sharding(spec, mesh):
-    """A Variable.sharding annotation applies iff every named axis exists on
-    this mesh — the one predicate all sharding consumers share."""
-    return spec is not None and all(
-        a is None or a in mesh.axis_names for a in spec)
 
 
 def _abstractify(tree):
@@ -709,7 +703,7 @@ class _CompiledStep:
             for n in state_names:
                 v = program.global_block._find_var_recursive(n)
                 spec = getattr(v, "sharding", None) if v is not None else None
-                if _valid_sharding(spec, mesh):
+                if valid_sharding(spec, mesh):
                     out_state_sh[n] = NamedSharding(mesh, P(*spec))
                 else:
                     out_state_sh[n] = repl
@@ -1260,7 +1254,7 @@ class Executor:
             put_specs = {}
             for v in program.list_vars():
                 spec = getattr(v, "sharding", None)
-                if _valid_sharding(spec, mesh):
+                if valid_sharding(spec, mesh):
                     put_specs[v.name] = NamedSharding(mesh, P(*spec))
             batch_sh = NamedSharding(mesh, _mesh_batch_spec(mesh))
 

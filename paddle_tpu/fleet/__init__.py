@@ -23,7 +23,7 @@ from . import metrics  # registers every fleet/* instrument
 from .autopsy import (BreachAutopsy, autopsy_breaches, build_ledgers,
                       phase_stats, run_autopsy)
 from .events import FleetEventLog, read_events
-from .prefix_cache import PrefixCache, PrefixEntry, prefix_key
+from ..serving.prefix_cache import PrefixCache, PrefixEntry, prefix_key
 from .protocol import FrameReader, read_frame, send_frame
 from .replica import (InProcessReplica, ProcessReplica, SimConfig,
                       SimEngine, sim_token)

@@ -1,10 +1,14 @@
 """fleet/* instruments: the monitor-registry face of the fleet router.
 
-One module owns every ``fleet/*`` name so the router, replicas and the
-prefix cache never race a get-or-create, and tools
+One module owns every ``fleet/*`` name the fleet bumps, so the router and
+the replicas never race a get-or-create, and tools
 (``tools/dump_metrics --selftest``) can assert the full set exists by
-importing this module alone. Same hot-path contract as serving.metrics:
-module-level handles, a single disabled-branch per call.
+importing this module alone. The eight ``fleet/prefix_cache/*`` instruments
+of an engine's OWN prefix cache are ``serving.metrics``'s (the engine and
+``serving/prefix_cache.py`` bump them; importing this module imports
+``serving`` and so registers them too); the ``remote_*`` three below are
+the router's. Same hot-path contract as serving.metrics: module-level
+handles, a single disabled-branch per call.
 """
 
 from __future__ import annotations
@@ -18,9 +22,6 @@ __all__ = [
     "DUPLICATE_RESULTS", "QUEUE_DEPTH", "REPLICAS_ALIVE",
     "REPLICA_RESTARTS", "ROLLING_RESTARTS", "NO_HEALTHY_REPLICA",
     "REROUTED",
-    "PREFIX_HITS", "PREFIX_MISSES", "PREFIX_INSERTS", "PREFIX_EVICTIONS",
-    "PREFIX_ENTRIES", "PREFIX_PAGES", "PREFIX_TOKENS_REUSED",
-    "PREFIX_POISONED_SKIPPED",
     "MIGRATIONS_STARTED", "MIGRATIONS_COMPLETED", "MIGRATIONS_FAILED",
     "MIGRATED_PAGES", "MIGRATION_MS",
     "REMOTE_HITS", "REMOTE_MISSES", "REMOTE_SHIPS",
@@ -66,35 +67,6 @@ REROUTED = _mx.counter(
     help="requests re-routed to a peer after a replica-side typed "
          "rejection (draining/backpressure) — never surfaced as a "
          "terminal rejection")
-
-PREFIX_HITS = _mx.counter(
-    "fleet/prefix_cache/hits",
-    help="prefill requests served from cached prefix KV pages (prefill "
-         "compute skipped for the shared prefix)")
-PREFIX_MISSES = _mx.counter(
-    "fleet/prefix_cache/misses",
-    help="prefill lookups that found no cached prefix")
-PREFIX_INSERTS = _mx.counter(
-    "fleet/prefix_cache/inserts",
-    help="prefix entries inserted (pages donated by a FINISHED request)")
-PREFIX_EVICTIONS = _mx.counter(
-    "fleet/prefix_cache/evictions",
-    help="LRU evictions under page-budget pressure")
-PREFIX_ENTRIES = _mx.gauge(
-    "fleet/prefix_cache/entries", help="live prefix entries")
-PREFIX_PAGES = _mx.gauge(
-    "fleet/prefix_cache/pages_held",
-    help="KV pages owned by the prefix cache (counted by the engine's "
-         "page-accounting invariant)")
-PREFIX_TOKENS_REUSED = _mx.counter(
-    "fleet/prefix_cache/tokens_reused",
-    help="prompt tokens whose prefill compute was skipped via a cached "
-         "prefix")
-PREFIX_POISONED_SKIPPED = _mx.counter(
-    "fleet/prefix_cache/poisoned_skipped",
-    help="cacheable prefixes NOT inserted because their request did not "
-         "FINISH (failed/timed-out pages are never served to a later "
-         "request)")
 
 MIGRATIONS_STARTED = _mx.counter(
     "fleet/migrations_started",

@@ -146,7 +146,7 @@ class SimEngine:
         req.tokens_out.append(sim_token(req.seed, pos, self.cfg.vocab))
 
     def _cacheable_len(self, n: int) -> int:
-        # same alignment rule as fleet.prefix_cache: longest page-aligned
+        # same alignment rule as serving.prefix_cache: longest page-aligned
         # prefix STRICTLY shorter than the prompt
         return ((int(n) - 1) // self.cfg.page_size) * self.cfg.page_size
 

@@ -58,12 +58,12 @@ from typing import Callable, Deque, Dict, List, Optional, Sequence
 
 from ..monitor import runlog as _runlog
 from ..monitor import tracer as _tr
+from ..serving.prefix_cache import prefix_key
 from ..serving.request import FAILED, FINISHED, REJECTED, TIMEOUT
 from . import autopsy as _autopsy
 from . import metrics as _fm
 from . import trace as _ftr
 from .events import KIND_BREACH_AUTOPSY, FleetEventLog
-from .prefix_cache import prefix_key
 from .replica import InProcessReplica, ProcessReplica
 from .slo import FleetSLO, fleet_slos_from_env
 

@@ -1,0 +1,356 @@
+"""What the served decoders share: the blocks more than one of them is made
+of, the host-side tables their configs are built from, and the ONE class
+through which ``serving.ServingEngine`` drives any of them
+(:class:`ServedLM`, whose docstring is the contract).
+
+``smallthinker.py``, ``kimi_k2.py``, ``laguna.py``, ``ling3_flash.py`` and
+``motif3.py`` take their blocks from here and keep what only they have. A
+block two models need is written HERE under a public name; no model module
+imports another's underscore names. Two forms of a block are one function
+only where the merged one needs no argument that says who calls it and the
+models' executables come out as they were: the rotary block stays two
+(:func:`rope` over a whole last axis, :func:`rope_lanes` over its first
+lanes with an attention factor). ``decoder_lm.py`` (GPT-2: LayerNorm,
+learned positions, a ``verify``) keeps its own blocks.
+
+The plain float32 statement each model is compared with is the benchmark's
+(``grid/reference/<model>.py``). Nothing here or in a model module imports
+it: the tables below (:func:`yarn_inv_freq`, :func:`mla_softmax_scale`,
+:func:`rope_table`, :func:`l2_normalize`, :func:`log_decay`) are the
+program's own text, and ``tests/test_model_blocks.py`` holds each against
+the reference's at the published configurations' values.
+"""
+
+from __future__ import annotations
+
+import math
+from typing import Any, Callable, Dict, Optional, Tuple
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+from ..ops import moe_ops
+
+__all__ = ["ServedLM", "absorbed_output", "absorbed_query", "gated",
+           "head", "held_experts", "l2_normalize", "latent", "log_decay",
+           "mla_softmax_scale", "moe_stats", "rms_norm", "rope", "rope_lanes",
+           "rope_table", "routed_feed_forward", "seeded_params", "swiglu",
+           "yarn_inv_freq"]
+
+
+# -- host-side tables a config is built from ----------------------------------
+
+def yarn_inv_freq(rot: int, theta: float,
+                  scaling: Optional[Dict[str, Any]] = None) -> np.ndarray:
+    """The ``rot / 2`` rotary frequencies of ``rot`` lanes at base
+    ``theta``, float64. Plain (``scaling`` empty): pair i runs at
+    ``theta^(-2i / rot)``. Under YaRN (``factor``, ``beta_fast``,
+    ``beta_slow``, ``original_max_position_embeddings``): a pair that turns
+    more than ``beta_fast`` times over the original context keeps that
+    frequency, one that turns fewer than ``beta_slow`` times runs at it
+    over ``factor``, and the pairs between ramp linearly from one to the
+    other."""
+    pair = np.arange(rot // 2, dtype=np.float64)
+    freq = theta ** (-pair * 2.0 / rot)
+    if not scaling:
+        return freq
+    span = float(scaling["original_max_position_embeddings"])
+
+    def pair_turning(turns: float) -> float:
+        return (rot * math.log(span / (turns * 2 * math.pi))
+                / (2 * math.log(theta)))
+
+    low = max(math.floor(pair_turning(float(scaling["beta_fast"]))), 0)
+    high = min(math.ceil(pair_turning(float(scaling["beta_slow"]))), rot - 1)
+    if low == high:
+        high += 0.001
+    ramp = np.clip((pair - low) / (high - low), 0.0, 1.0)
+    return freq * (1.0 - ramp) + freq / float(scaling["factor"]) * ramp
+
+
+def mla_softmax_scale(d_head: int,
+                      scaling: Optional[Dict[str, Any]] = None) -> float:
+    """A latent-attention layer's score scale: ``d_head^-0.5`` over a
+    query's ``nope + rope`` lanes, times YaRN's ``mscale^2`` where the
+    scaling names ``mscale_all_dim``."""
+    scale = d_head ** -0.5
+    if scaling and scaling.get("mscale_all_dim"):
+        m = (0.1 * float(scaling["mscale_all_dim"])
+             * math.log(scaling["factor"]) + 1.0)
+        scale *= m * m
+    return scale
+
+
+def rope_table(d_head: int, rope: Dict[str, Any]) -> Tuple[np.ndarray, float]:
+    """``(inv_freq, attention factor)`` of one published ``rope_parameters``
+    entry, what :func:`rope_lanes` takes: the frequencies of the first
+    ``d_head * partial_rotary_factor`` lanes (``rope_type`` ``default``:
+    plain, factor 1; ``yarn``: :func:`yarn_inv_freq`'s, and cos and sin
+    times ``attention_factor``, by default ``0.1 ln(factor) + 1``)."""
+    rot = int(round(d_head * float(rope.get("partial_rotary_factor", 1.0))))
+    theta = float(rope["rope_theta"])
+    kind = rope.get("rope_type", "default")
+    if kind == "default":
+        return yarn_inv_freq(rot, theta), 1.0
+    if kind != "yarn":
+        raise ValueError("rope_type %r" % kind)
+    att = rope.get("attention_factor")
+    return (yarn_inv_freq(rot, theta, rope),
+            float(att) if att is not None
+            else 0.1 * math.log(float(rope["factor"])) + 1.0)
+
+
+# -- blocks -------------------------------------------------------------------
+
+def rms_norm(x, g, eps):
+    """RMSNorm over the last axis, computed in float32, in ``x``'s type."""
+    xf = x.astype(jnp.float32)
+    y = xf * jax.lax.rsqrt(jnp.mean(xf * xf, axis=-1, keepdims=True) + eps)
+    return (y * g.astype(jnp.float32)).astype(x.dtype)
+
+
+def l2_normalize(x):
+    """``x`` over its last axis' length (1e-6 under the root)."""
+    return x / jnp.sqrt(jnp.sum(x * x, axis=-1, keepdims=True) + 1e-6)
+
+
+def log_decay(z, a_log, lower_bound):
+    """A recurrence step's log-decay ``lower_bound sigmoid(exp(A_log) z)``
+    from the gate's pre-activation ``z`` [..., H, dk] and ``a_log`` [H]:
+    inside (``lower_bound``, 0) whatever ``z`` is."""
+    return lower_bound * jax.nn.sigmoid(jnp.exp(a_log)[:, None] * z)
+
+
+def rope(x, pos, inv_freq):
+    """Rotate-half over the WHOLE last axis of ``x`` [..., rope] at
+    positions ``pos`` (the leading axes of ``x``; any axes between them and
+    the last turn alike) and frequencies ``inv_freq`` [rope / 2]."""
+    half = x.shape[-1] // 2
+    ang = pos.astype(jnp.float32).reshape(
+        pos.shape + (1,) * (x.ndim - pos.ndim)) \
+        * jnp.asarray(inv_freq, jnp.float32)
+    cos, sin = jnp.cos(ang), jnp.sin(ang)
+    x1 = x[..., :half].astype(jnp.float32)
+    x2 = x[..., half:].astype(jnp.float32)
+    return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin],
+                           axis=-1).astype(x.dtype)
+
+
+def rope_lanes(x, pos, table):
+    """Rotate-half over the FIRST ``2 * len(inv_freq)`` lanes of ``x`` [...,
+    H, D] at positions ``pos`` [...] (one a row of heads), cos and sin times
+    the attention factor; the other lanes pass. ``table`` is
+    :func:`rope_table`'s ``(inv_freq, factor)``."""
+    inv_freq, factor = table
+    half = len(inv_freq)
+    ang = pos.astype(jnp.float32)[..., None, None] \
+        * jnp.asarray(inv_freq, jnp.float32)
+    cos, sin = jnp.cos(ang) * factor, jnp.sin(ang) * factor
+    x1 = x[..., :half].astype(jnp.float32)
+    x2 = x[..., half:2 * half].astype(jnp.float32)
+    return jnp.concatenate(
+        [(x1 * cos - x2 * sin).astype(x.dtype),
+         (x2 * cos + x1 * sin).astype(x.dtype), x[..., 2 * half:]], axis=-1)
+
+
+def swiglu(u, wg, wu, wd):
+    return (jax.nn.silu(u @ wg) * (u @ wu)) @ wd
+
+
+def head(params, cfg, x):
+    """The final RMSNorm and the output head (not tied to the embedding)."""
+    return rms_norm(x, params["gf"], cfg.rms_eps) @ params["head"]
+
+
+def gated(lp, h, o):
+    """``gamma_n a_n``: attention's output ``o`` [..., H, D] under the
+    head-wise gate ``sigmoid(h Wgamma)`` of the same normed input ``h``
+    [..., d], in float32, flattened to [..., H * D] for the output
+    projection."""
+    with jax.named_scope("attn/gate"):
+        gamma = jax.nn.sigmoid(jnp.dot(h, lp["wgam"],
+                                       preferred_element_type=jnp.float32))
+        o = (o.astype(jnp.float32) * gamma[..., None]).astype(o.dtype)
+        return o.reshape(o.shape[:-2] + (-1,))
+
+
+def latent(cfg, lp, h, pos):
+    """What latent attention reads of the normed input ``h`` [..., d] at
+    ``pos`` [...]: the queries ``(q_nope, q_rope)`` [..., H, nope | rope],
+    rotated, and the cache row ``[c | kr']`` [..., rank + rope]. A layer
+    without a query latent (``q_lora_rank`` null) projects ``h`` by ``wq``.
+    ``cfg`` gives ``n_head``, ``d_head``, ``d_nope``, ``kv_rank``,
+    ``rms_eps`` and ``inv_freq``."""
+    if "wq" in lp:
+        q = h @ lp["wq"]
+    else:
+        q = rms_norm(h @ lp["wqa"], lp["gq"], cfg.rms_eps) @ lp["wqb"]
+    q = q.reshape(h.shape[:-1] + (cfg.n_head, cfg.d_head))
+    kva = h @ lp["wkva"]
+    c = rms_norm(kva[..., :cfg.kv_rank], lp["gkv"], cfg.rms_eps)
+    kr = rope(kva[..., cfg.kv_rank:], pos, cfg.inv_freq)
+    q_r = rope(q[..., cfg.d_nope:], pos, cfg.inv_freq)
+    return q[..., :cfg.d_nope], q_r, jnp.concatenate([c, kr], axis=-1)
+
+
+def absorbed_query(cfg, wkvb, q_n, q_r):
+    """``[q_nope_n Wuk_n^T | q_rope_n]`` [B, H, rank + rope]: the query of
+    head n over the cache row's lanes, every head with an up-projection of
+    its own."""
+    w = wkvb.reshape(cfg.kv_rank, cfg.n_head, cfg.d_nope + cfg.d_v)
+    q_lat = jnp.einsum("bhn,chn->bhc", q_n, w[..., :cfg.d_nope],
+                       preferred_element_type=jnp.float32)
+    return jnp.concatenate([q_lat.astype(q_n.dtype), q_r], axis=-1)
+
+
+def absorbed_output(cfg, wkvb, o_lat):
+    """``o_lat_n Wuv_n`` [B, H * d_v] of ``o_lat`` [B, H, rank]."""
+    w = wkvb.reshape(cfg.kv_rank, cfg.n_head, cfg.d_nope + cfg.d_v)
+    a = jnp.einsum("bhc,chv->bhv", o_lat, w[..., cfg.d_nope:],
+                   preferred_element_type=jnp.float32)
+    return a.astype(o_lat.dtype).reshape(o_lat.shape[0], -1)
+
+
+def held_experts(cfg):
+    """``ops.moe_ops.expert_layer``'s ``held``: None where every expert is
+    here, else the global ids of the share in ``wg``/``wu``/``wd``."""
+    return (None if len(cfg.experts_held) == cfg.n_expert
+            else cfg.experts_held)
+
+
+def routed_feed_forward(cfg, lp, x, row_valid):
+    """The DeepSeek-V3 layer's second half over rows ``x`` [N, d]: the
+    dense SwiGLU, or the sigmoid-routed SwiGLU experts held here (the
+    router group-limited where ``cfg.n_group`` > 1) plus the shared
+    expert. Returns ``(x, stats or None)``."""
+    u = rms_norm(x, lp["g2"], cfg.rms_eps)
+    if "wr" not in lp:
+        return x + swiglu(u, lp["wg"], lp["wu"], lp["wd"]), None
+    limited = ({} if cfg.n_group == 1 else
+               {"n_group": cfg.n_group, "topk_group": cfg.topk_group})
+    idx, w = moe_ops.route_sigmoid_topk(u, lp["wr"], lp["br"], cfg.top_k,
+                                        cfg.routed_scale, **limited)
+    y, stats = moe_ops.expert_layer(
+        u, idx, w, lp["wg"], lp["wu"], lp["wd"], n_expert=cfg.n_expert,
+        held=held_experts(cfg), row_valid=row_valid, activation=jax.nn.silu)
+    stats = dict(stats, held_pairs=moe_ops.held_pairs(
+        idx, cfg.experts_held, cfg.n_expert, row_valid))
+    with jax.named_scope("moe/shared"):
+        shared = swiglu(u, lp["sg"], lp["su"], lp["sd"])
+    return x + (y + shared.astype(jnp.float32)).astype(x.dtype), stats
+
+
+def moe_stats(stats) -> Dict:
+    """A decode step's expert counts from its EXPERT layers'
+    ``expert_layer`` stats (with ``held_pairs``), stacked a layer: what
+    the engine feeds the ``serving/moe_*`` histograms."""
+    return {"moe_experts_touched": jnp.stack(
+                [s["experts_touched"] for s in stats]),
+            "moe_max_expert_rows": jnp.stack(
+                [s["max_expert_rows"] for s in stats]),
+            "moe_held_pairs": jnp.stack([s["held_pairs"] for s in stats])}
+
+
+def seeded_params(cfg, seed, init_layer: Callable, layer_args: Callable
+                  ) -> Dict:
+    """Seeded random weights, made where JAX computes (the device), in
+    ``cfg.dtype``, one layer a call: the largest temporary is one layer.
+    ``init_layer(cfg, key, *layer_args(i))`` makes layer i; its arguments
+    after the key are static (one compile a kind of layer)."""
+    keys = jax.random.split(jax.random.PRNGKey(seed), cfg.n_layer + 2)
+    n_static = len(layer_args(0))
+    layer = jax.jit(lambda k, *static: init_layer(cfg, k, *static),
+                    static_argnums=tuple(range(1, 1 + n_static)))
+    emb = jax.jit(lambda k, shape: 0.02 * jax.random.normal(
+        k, shape, cfg.dtype), static_argnums=1)
+    return {"tok_emb": emb(keys[0], (cfg.vocab_size, cfg.d_model)),
+            "head": emb(keys[1], (cfg.d_model, cfg.vocab_size)),
+            "gf": jnp.ones((cfg.d_model,), cfg.dtype),
+            "layers": [layer(keys[2 + i], *layer_args(i))
+                       for i in range(cfg.n_layer)]}
+
+
+# -- the contract -------------------------------------------------------------
+
+class ServedLM:
+    """THE SERVING CONTRACT: what ``serving.ServingEngine`` may ask of a
+    model and of its config. ``SmallThinkerLM``, ``KimiK2LM``, ``LagunaLM``,
+    ``Ling3FlashLM`` and ``Motif3LM`` are this class over their module's
+    ``init_params``, ``prefill_forward`` and ``decode_forward``;
+    ``decoder_lm.DecoderLM`` meets it with methods of its own.
+
+    The engine reads ``model.cfg``, ``model.params`` and calls:
+
+    * ``prefill(params, tokens [B, S], lengths [B]) -> (logits [B, S, V],
+      kept)``: causal over bucket-padded prompts. ``kept`` is, a layer,
+      what the cache's ``write_prompt`` takes with a leading batch axis:
+      ``(k, v)`` [B, S, Hkv, D] of a K-and-V layer, ``(row,)`` [B, S, rank +
+      rope] of a latent layer, ``(state [B, H, dk, dv], tail [B, rows,
+      width])`` of a state layer (the state the prompt LEAVES, not rows);
+    * ``prefill_last`` (optional; the engine asks ``hasattr``): the same
+      with ``logits [B, V]`` of each prompt's LAST row only ([B, S, V] at S
+      = 8,192 and V = 151,936 would be 5 GB). Absent: the engine calls
+      ``prefill`` and takes the row itself;
+    * ``decode(params, cache, cache_ops, tokens [B], pos [B], active [B])
+      -> (logits [B, V], cache)`` or ``(logits, cache, stats)``: one
+      position a slot through ``cache_ops``, which owns the cache's groups,
+      rings and the gather-or-kernel choice. ``stats`` is a dict of small
+      int arrays a step that the engine feeds to the ``serving/*``
+      histograms of the same names: ``moe_experts_touched``,
+      ``moe_max_expert_rows``, ``moe_held_pairs`` [expert layers],
+      ``state_slots_stepped``, ``attn_rows_read.<group>``;
+    * ``verify`` (optional; ``hasattr``): scores a window of drafted tokens
+      for speculative decoding. Absent, as on every model of this class,
+      every speculation setting resolves off: a ring, a latent row and a
+      recurrent state cannot be rolled back.
+
+    Of ``model.cfg`` the engine reads ``n_layer``, ``n_head`` (the QUERY
+    heads: one number, or one a layer), ``d_head``, ``max_seq``, ``dtype``,
+    and, by ``getattr``, each with what its absence means:
+
+    * ``n_kv_head``: the heads of K and V, the same in every layer, which
+      size the cache. Absent: ``n_head`` (queries are not grouped);
+    * ``cache_groups``: a list of ``(name, layers, window)`` or ``(name,
+      layers, window, kind)``; the layers of a group share one ``n_head``
+      (a group's decode attention is one kernel shape), ``window`` rows a
+      slot are kept as a ring (None: every position, in pages), and the
+      first group is the one admission counts pages of. Absent: one group
+      of every layer that keeps every position. ``kind`` is one of
+      ``KV``, ``LATENT``, ``STATE``, names that ``serving/kv_cache.py``
+      OWNS (``serving`` lies below ``models``, which imports them at
+      module top); absent: ``KV``;
+    * ``latent_row``: ``(rank, rope)``; the cache is then a
+      ``LatentPagedCache`` of ONE ``[c | kr']`` row a token a layer, over
+      every layer or over the ``LATENT`` groups. Absent: K and V rows;
+    * ``slot_state``: ``(heads, dk, dv, tail rows, tail width)`` that each
+      layer of a ``STATE`` group keeps a SLOT, and no pages. Absent: the
+      model has no state group;
+    * ``experts_held`` (with ``n_expert``, ``top_k``): the global ids of
+      the routed experts held here, from which the engine tells the form
+      of an executable's grouped product. Absent: no expert layer.
+    """
+
+    # a subclass's module functions, bound as static methods
+    init_params: Callable
+    prefill_forward: Callable
+    decode_forward: Callable
+
+    def __init__(self, cfg, params: Dict = None, seed: int = 0):
+        self.cfg = cfg
+        self.params = (params if params is not None
+                       else self.init_params(cfg, seed))
+
+    def prefill(self, params, tokens, lengths):
+        x, kept = self.prefill_forward(params, self.cfg, tokens, lengths)
+        return head(params, self.cfg, x), kept
+
+    def prefill_last(self, params, tokens, lengths):
+        x, kept = self.prefill_forward(params, self.cfg, tokens, lengths)
+        last = jnp.take_along_axis(
+            x, (lengths - 1)[:, None, None], axis=1)[:, 0]
+        return head(params, self.cfg, last), kept
+
+    def decode(self, params, cache, cache_ops, tokens, pos, active):
+        return self.decode_forward(params, self.cfg, cache, cache_ops, tokens,
+                                   pos, active)

@@ -201,8 +201,9 @@ def verify_forward(params: Dict, cfg: DecoderConfig, cache, cache_ops,
 
 
 class DecoderLM:
-    """The serving contract (serving.engine.ServingEngine's ``model``):
-    bundles a config + params pytree with the two step functions."""
+    """Meets the serving contract (``models.blocks.ServedLM``'s docstring)
+    with methods of its own: a config + params pytree with ``prefill``,
+    ``decode`` and, alone among the served models, ``verify``."""
 
     def __init__(self, cfg: DecoderConfig, params: Dict = None, seed: int = 0):
         self.cfg = cfg

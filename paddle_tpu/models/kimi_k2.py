@@ -1,10 +1,11 @@
 """Kimi-K2-Instruct's decoder block (the DeepSeek-V3 block: latent
 attention, a sigmoid-routed expert layer with a shared expert) as pure JAX
-functions, with ``models.decoder_lm.DecoderLM``'s serving contract
-(``cfg``, ``params``, ``prefill_last``, ``decode``), so the same
-``ServingEngine``, scheduler and page pool serve it. The plain float32
-statement of the same equations is ``models/kimi_k2_reference.py``; read
-the layer there.
+functions under the serving contract (``models.blocks.ServedLM``), so the
+same ``ServingEngine``, scheduler and page pool serve it. The plain float32
+statement of the same equations, which the tests and the benchmark compare
+this with, is ``grid/reference/kimi_k2.py``; read the layer there. The
+latent projection, the absorbed pair and the feed-forward half are
+``models/blocks.py``'s: Ling-3 and Motif-3 are made of them too.
 
 What is particular to serving it:
 
@@ -37,8 +38,10 @@ from typing import Any, Dict, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 
-from ..ops import attention_ops, moe_ops
-from . import kimi_k2_reference as _ref
+from ..ops import attention_ops
+from .blocks import (ServedLM, absorbed_output, absorbed_query, head, latent,
+                     mla_softmax_scale, moe_stats, rms_norm,
+                     routed_feed_forward, seeded_params, yarn_inv_freq)
 
 __all__ = ["KimiK2Config", "KimiK2LM", "init_params"]
 
@@ -81,11 +84,9 @@ class KimiK2Config:
         self.experts_held = (tuple(range(self.n_expert))
                              if experts_held is None
                              else tuple(int(e) for e in experts_held))
-        self.inv_freq = _ref.yarn_inv_freq(self.d_rope, self.rope_theta,
-                                           self.rope_scaling)
-        self.sm_scale = _ref.softmax_scale(
-            {"qk_nope_head_dim": self.d_nope, "qk_rope_head_dim": self.d_rope,
-             "rope_scaling": self.rope_scaling})
+        self.inv_freq = yarn_inv_freq(self.d_rope, self.rope_theta,
+                                      self.rope_scaling)
+        self.sm_scale = mla_softmax_scale(self.d_head, self.rope_scaling)
 
     @property
     def latent_row(self) -> Tuple[int, int]:
@@ -135,86 +136,13 @@ def _init_layer(cfg: KimiK2Config, key, dense: bool) -> Dict:
 
 
 def init_params(cfg: KimiK2Config, seed) -> Dict:
-    """Seeded random weights, made where JAX computes (the device), in
-    ``cfg.dtype``, one layer a call: the largest temporary is one layer.
-    The selection bias ``br`` is drawn with ``cfg.bias_std``: of the size of
-    the gaps between the largest sigmoid scores, so that the selection by
-    ``s + b`` differs from the selection by ``s`` without the bias alone
-    choosing the experts."""
-    keys = jax.random.split(jax.random.PRNGKey(seed), cfg.n_layer + 2)
-    layer = jax.jit(lambda k, dense: _init_layer(cfg, k, dense),
-                    static_argnums=1)
-    emb = jax.jit(lambda k, shape: 0.02 * jax.random.normal(
-        k, shape, cfg.dtype), static_argnums=1)
-    return {"tok_emb": emb(keys[0], (cfg.vocab_size, cfg.d_model)),
-            "head": emb(keys[1], (cfg.d_model, cfg.vocab_size)),
-            "gf": jnp.ones((cfg.d_model,), cfg.dtype),
-            "layers": [layer(keys[2 + i], i < cfg.n_dense)
-                       for i in range(cfg.n_layer)]}
-
-
-def _rms(x, g, eps):
-    xf = x.astype(jnp.float32)
-    y = xf * jax.lax.rsqrt(jnp.mean(xf * xf, axis=-1, keepdims=True) + eps)
-    return (y * g.astype(jnp.float32)).astype(x.dtype)
-
-
-def _rope(x, pos, inv_freq):
-    """Rotate-half over the last axis: ``x`` [..., rope] (any axes between
-    the leading position axes and the last), ``pos`` the leading axes."""
-    half = x.shape[-1] // 2
-    ang = pos.astype(jnp.float32).reshape(
-        pos.shape + (1,) * (x.ndim - pos.ndim)) \
-        * jnp.asarray(inv_freq, jnp.float32)
-    cos, sin = jnp.cos(ang), jnp.sin(ang)
-    x1 = x[..., :half].astype(jnp.float32)
-    x2 = x[..., half:].astype(jnp.float32)
-    return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin],
-                           axis=-1).astype(x.dtype)
-
-
-def _swiglu(u, wg, wu, wd):
-    return (jax.nn.silu(u @ wg) * (u @ wu)) @ wd
-
-
-def _latent(cfg, lp, h, pos):
-    """What attention reads of the normed input ``h`` [..., d] at ``pos``
-    [...]: the queries ``(q_nope, q_rope)`` [..., H, nope | rope], rotated,
-    and the cache row ``[c | kr']`` [..., rank + rope]. A layer without a
-    query latent (``q_lora_rank`` null) projects ``h`` by ``wq``."""
-    if "wq" in lp:
-        q = h @ lp["wq"]
-    else:
-        q = _rms(h @ lp["wqa"], lp["gq"], cfg.rms_eps) @ lp["wqb"]
-    q = q.reshape(h.shape[:-1] + (cfg.n_head, cfg.d_head))
-    kva = h @ lp["wkva"]
-    c = _rms(kva[..., :cfg.kv_rank], lp["gkv"], cfg.rms_eps)
-    kr = _rope(kva[..., cfg.kv_rank:], pos, cfg.inv_freq)
-    q_r = _rope(q[..., cfg.d_nope:], pos, cfg.inv_freq)
-    return q[..., :cfg.d_nope], q_r, jnp.concatenate([c, kr], axis=-1)
-
-
-def _feed_forward(cfg, lp, x, row_valid):
-    """The layer's second half over rows ``x`` [N, d]: the dense SwiGLU,
-    or the routed experts held here plus the shared expert. Returns ``(x,
-    stats or None)``."""
-    u = _rms(x, lp["g2"], cfg.rms_eps)
-    if "wr" not in lp:
-        return x + _swiglu(u, lp["wg"], lp["wu"], lp["wd"]), None
-    limited = ({} if cfg.n_group == 1 else
-               {"n_group": cfg.n_group, "topk_group": cfg.topk_group})
-    idx, w = moe_ops.route_sigmoid_topk(u, lp["wr"], lp["br"], cfg.top_k,
-                                        cfg.routed_scale, **limited)
-    y, stats = moe_ops.expert_layer(
-        u, idx, w, lp["wg"], lp["wu"], lp["wd"], n_expert=cfg.n_expert,
-        held=(None if len(cfg.experts_held) == cfg.n_expert
-              else cfg.experts_held), row_valid=row_valid,
-        activation=jax.nn.silu)
-    stats = dict(stats, held_pairs=moe_ops.held_pairs(
-        idx, cfg.experts_held, cfg.n_expert, row_valid))
-    with jax.named_scope("moe/shared"):
-        shared = _swiglu(u, lp["sg"], lp["su"], lp["sd"])
-    return x + (y + shared.astype(jnp.float32)).astype(x.dtype), stats
+    """Seeded random weights (``blocks.seeded_params``). The selection bias
+    ``br`` is drawn with ``cfg.bias_std``: of the size of the gaps between
+    the largest sigmoid scores, so that the selection by ``s + b`` differs
+    from the selection by ``s`` without the bias alone choosing the
+    experts."""
+    return seeded_params(cfg, seed, _init_layer,
+                         lambda i: (i < cfg.n_dense,))
 
 
 def prefill_forward(params: Dict, cfg: KimiK2Config, tokens, lengths):
@@ -230,8 +158,8 @@ def prefill_forward(params: Dict, cfg: KimiK2Config, tokens, lengths):
     valid = (pos < lengths[:, None]).reshape(b * s)
     rows = []
     for lp in params["layers"]:
-        h = _rms(x, lp["g1"], cfg.rms_eps)
-        q_n, q_r, row = _latent(cfg, lp, h, pos)
+        h = rms_norm(x, lp["g1"], cfg.rms_eps)
+        q_n, q_r, row = latent(cfg, lp, h, pos)
         rows.append((row,))
         kv = (row[..., :cfg.kv_rank] @ lp["wkvb"]).reshape(
             b, s, cfg.n_head, cfg.d_nope + cfg.d_v)
@@ -241,30 +169,9 @@ def prefill_forward(params: Dict, cfg: KimiK2Config, tokens, lengths):
             kv[j, ..., cfg.d_nope:], cfg.sm_scale) for j in range(b)]
         o = jnp.stack(att).reshape(b, s, cfg.n_head * cfg.d_v)
         x = x + o @ lp["wo"]
-        x, _ = _feed_forward(cfg, lp, x.reshape(b * s, -1), valid)
+        x, _ = routed_feed_forward(cfg, lp, x.reshape(b * s, -1), valid)
         x = x.reshape(b, s, -1)
     return x, rows
-
-
-def _head(params, cfg, x):
-    return _rms(x, params["gf"], cfg.rms_eps) @ params["head"]
-
-
-def absorbed_query(cfg: KimiK2Config, wkvb, q_n, q_r):
-    """``[q_nope_n Wuk_n^T | q_rope_n]`` [B, H, rank + rope]: the query of
-    head n over the cache row's lanes."""
-    w = wkvb.reshape(cfg.kv_rank, cfg.n_head, cfg.d_nope + cfg.d_v)
-    q_lat = jnp.einsum("bhn,chn->bhc", q_n, w[..., :cfg.d_nope],
-                       preferred_element_type=jnp.float32)
-    return jnp.concatenate([q_lat.astype(q_n.dtype), q_r], axis=-1)
-
-
-def absorbed_output(cfg: KimiK2Config, wkvb, o_lat):
-    """``o_lat_n Wuv_n`` [B, H * d_v] of ``o_lat`` [B, H, rank]."""
-    w = wkvb.reshape(cfg.kv_rank, cfg.n_head, cfg.d_nope + cfg.d_v)
-    a = jnp.einsum("bhc,chv->bhv", o_lat, w[..., cfg.d_nope:],
-                   preferred_element_type=jnp.float32)
-    return a.astype(o_lat.dtype).reshape(o_lat.shape[0], -1)
 
 
 def decode_forward(params: Dict, cfg: KimiK2Config, cache, cache_ops,
@@ -278,46 +185,23 @@ def decode_forward(params: Dict, cfg: KimiK2Config, cache, cache_ops,
     x = params["tok_emb"][tokens]
     stats = []
     for i, lp in enumerate(params["layers"]):
-        h = _rms(x, lp["g1"], cfg.rms_eps)
-        q_n, q_r, row = _latent(cfg, lp, h, pos)
+        h = rms_norm(x, lp["g1"], cfg.rms_eps)
+        q_n, q_r, row = latent(cfg, lp, h, pos)
         cache = cache_ops.write_token(cache, i, row, pos, active)
         with jax.named_scope("attn/mla"):
             o_lat = cache_ops.decode_attention(
                 cache, i, absorbed_query(cfg, lp["wkvb"], q_n, q_r),
                 pos + 1, active, sm_scale=cfg.sm_scale)
             x = x + absorbed_output(cfg, lp["wkvb"], o_lat) @ lp["wo"]
-        x, st = _feed_forward(cfg, lp, x, active)
+        x, st = routed_feed_forward(cfg, lp, x, active)
         if st is not None:
             stats.append(st)
-    return _head(params, cfg, x), cache, {
-        "moe_experts_touched": jnp.stack(
-            [s["experts_touched"] for s in stats]),
-        "moe_max_expert_rows": jnp.stack(
-            [s["max_expert_rows"] for s in stats]),
-        "moe_held_pairs": jnp.stack([s["held_pairs"] for s in stats])}
+    return head(params, cfg, x), cache, moe_stats(stats)
 
 
-class KimiK2LM:
-    """The serving contract over :class:`KimiK2Config`. No ``verify``
-    method: speculation resolves off for this model."""
+class KimiK2LM(ServedLM):
+    """The serving contract over :class:`KimiK2Config`."""
 
-    def __init__(self, cfg: KimiK2Config, params: Dict = None,
-                 seed: int = 0):
-        self.cfg = cfg
-        self.params = params if params is not None else init_params(cfg, seed)
-
-    def prefill(self, params, tokens, lengths):
-        x, rows = prefill_forward(params, self.cfg, tokens, lengths)
-        return _head(params, self.cfg, x), rows
-
-    def prefill_last(self, params, tokens, lengths):
-        """The head for each prompt's LAST row only: ``(logits [B, V],
-        rows)``."""
-        x, rows = prefill_forward(params, self.cfg, tokens, lengths)
-        last = jnp.take_along_axis(
-            x, (lengths - 1)[:, None, None], axis=1)[:, 0]
-        return _head(params, self.cfg, last), rows
-
-    def decode(self, params, cache, cache_ops, tokens, pos, active):
-        return decode_forward(params, self.cfg, cache, cache_ops, tokens,
-                              pos, active)
+    init_params = staticmethod(init_params)
+    prefill_forward = staticmethod(prefill_forward)
+    decode_forward = staticmethod(decode_forward)

@@ -1,9 +1,9 @@
-"""Laguna-S-2.1's decoder block as pure JAX functions, with
-``models.decoder_lm.DecoderLM``'s serving contract (``cfg``, ``params``,
-``prefill``/``prefill_last``, ``decode``), so the same ``ServingEngine``,
+"""Laguna-S-2.1's decoder block as pure JAX functions under the serving
+contract (``models.blocks.ServedLM``), so the same ``ServingEngine``,
 scheduler, page pools, paged cache and paged-attention kernel serve it.
-The plain float32 statement of the same equations is
-``models/laguna_reference.py``; read the layer there.
+The plain float32 statement of the same equations, which the tests and the
+benchmark compare this with, is ``grid/reference/laguna.py``; read the
+layer there.
 
 What is particular to serving it:
 
@@ -34,7 +34,8 @@ import jax
 import jax.numpy as jnp
 
 from ..ops import attention_ops, moe_ops
-from . import laguna_reference as _ref
+from .blocks import (ServedLM, gated, head, held_experts, moe_stats, rms_norm,
+                     rope_lanes, rope_table, seeded_params, swiglu)
 
 __all__ = ["LagunaConfig", "LagunaLM", "init_params"]
 
@@ -86,7 +87,7 @@ class LagunaConfig:
                              if experts_held is None
                              else tuple(int(e) for e in experts_held))
         # (inv_freq [rot / 2], attention factor) of each kind of layer
-        self.rope = {kind: _ref.rope_table(self.d_head, rope[kind])
+        self.rope = {kind: rope_table(self.d_head, rope[kind])
                      for kind in set(self.layer_types)}
 
     @property
@@ -138,43 +139,9 @@ def _init_layer(cfg: LagunaConfig, key, n_head: int, dense: bool) -> Dict:
 
 
 def init_params(cfg: LagunaConfig, seed) -> Dict:
-    """Seeded random weights, made where JAX computes (the device), in
-    ``cfg.dtype``, one layer a call: the largest temporary is one layer."""
-    keys = jax.random.split(jax.random.PRNGKey(seed), cfg.n_layer + 2)
-    layer = jax.jit(lambda k, n_head, dense: _init_layer(cfg, k, n_head,
-                                                         dense),
-                    static_argnums=(1, 2))
-    emb = jax.jit(lambda k, shape: 0.02 * jax.random.normal(
-        k, shape, cfg.dtype), static_argnums=1)
-    return {"tok_emb": emb(keys[0], (cfg.vocab_size, cfg.d_model)),
-            "head": emb(keys[1], (cfg.d_model, cfg.vocab_size)),
-            "gf": jnp.ones((cfg.d_model,), cfg.dtype),
-            "layers": [layer(keys[2 + i], cfg.n_head[i],
-                             i in cfg.dense_layers)
-                       for i in range(cfg.n_layer)]}
-
-
-def _rms(x, g, eps):
-    xf = x.astype(jnp.float32)
-    y = xf * jax.lax.rsqrt(jnp.mean(xf * xf, axis=-1, keepdims=True) + eps)
-    return (y * g.astype(jnp.float32)).astype(x.dtype)
-
-
-def _rope(x, pos, table):
-    """Rotate-half over the first ``2 * len(inv_freq)`` lanes of ``x`` [...,
-    H, D] at positions ``pos`` [...] (one a row of heads), cos and sin times
-    the attention factor; the other lanes pass. ``table`` is one entry of
-    ``cfg.rope``."""
-    inv_freq, factor = table
-    half = len(inv_freq)
-    ang = pos.astype(jnp.float32)[..., None, None] \
-        * jnp.asarray(inv_freq, jnp.float32)
-    cos, sin = jnp.cos(ang) * factor, jnp.sin(ang) * factor
-    x1 = x[..., :half].astype(jnp.float32)
-    x2 = x[..., half:2 * half].astype(jnp.float32)
-    return jnp.concatenate(
-        [(x1 * cos - x2 * sin).astype(x.dtype),
-         (x2 * cos + x1 * sin).astype(x.dtype), x[..., 2 * half:]], axis=-1)
+    """Seeded random weights (``blocks.seeded_params``)."""
+    return seeded_params(cfg, seed, _init_layer,
+                         lambda i: (cfg.n_head[i], i in cfg.dense_layers))
 
 
 def _qkv(cfg, lp, i: int, h, pos):
@@ -185,31 +152,16 @@ def _qkv(cfg, lp, i: int, h, pos):
     k = (h @ lp["wk"]).reshape(lead + (cfg.n_kv_head, cfg.d_head))
     v = (h @ lp["wv"]).reshape(lead + (cfg.n_kv_head, cfg.d_head))
     table = cfg.rope[cfg.layer_types[i]]
-    return _rope(q, pos, table), _rope(k, pos, table), v
-
-
-def _gated(lp, h, o):
-    """``gamma_n a_n``: attention's output ``o`` [..., H, D] under the
-    head-wise gate of the same normed input ``h`` [..., d], flattened to
-    [..., H * D] for the output projection."""
-    with jax.named_scope("attn/gate"):
-        gamma = jax.nn.sigmoid(jnp.dot(h, lp["wgam"],
-                                       preferred_element_type=jnp.float32))
-        o = (o.astype(jnp.float32) * gamma[..., None]).astype(o.dtype)
-        return o.reshape(o.shape[:-2] + (-1,))
-
-
-def _swiglu(u, wg, wu, wd):
-    return (jax.nn.silu(u @ wg) * (u @ wu)) @ wd
+    return rope_lanes(q, pos, table), rope_lanes(k, pos, table), v
 
 
 def _feed_forward(cfg, lp, x, row_valid):
     """The layer's second half over rows ``x`` [N, d]: the dense SwiGLU,
     or the routed experts held here plus the shared expert. Returns ``(x,
     stats or None)``."""
-    u = _rms(x, lp["g2"], cfg.rms_eps)
+    u = rms_norm(x, lp["g2"], cfg.rms_eps)
     if "wr" not in lp:
-        return x + _swiglu(u, lp["wg"], lp["wu"], lp["wd"]), None
+        return x + swiglu(u, lp["wg"], lp["wu"], lp["wd"]), None
     with jax.named_scope("moe/route"):
         # the softmax over the chosen logits IS the softmax over all the
         # experts kept at the chosen ones and renormalised
@@ -218,13 +170,12 @@ def _feed_forward(cfg, lp, x, row_valid):
     with jax.named_scope("moe/routed"):
         y, stats = moe_ops.expert_layer(
             u, idx, w, lp["wg"], lp["wu"], lp["wd"], n_expert=cfg.n_expert,
-            held=(None if len(cfg.experts_held) == cfg.n_expert
-                  else cfg.experts_held), row_valid=row_valid,
+            held=held_experts(cfg), row_valid=row_valid,
             activation=jax.nn.silu)
     stats = dict(stats, held_pairs=moe_ops.held_pairs(
         idx, cfg.experts_held, cfg.n_expert, row_valid))
     with jax.named_scope("moe/shared"):
-        shared = _swiglu(u, lp["sg"], lp["su"], lp["sd"])
+        shared = swiglu(u, lp["sg"], lp["su"], lp["sd"])
     return x + (y + shared.astype(jnp.float32)).astype(x.dtype), stats
 
 
@@ -240,7 +191,7 @@ def prefill_forward(params: Dict, cfg: LagunaConfig, tokens, lengths):
     valid = (pos < lengths[:, None]).reshape(b * s)
     kvs = []
     for i, lp in enumerate(params["layers"]):
-        h = _rms(x, lp["g1"], cfg.rms_eps)
+        h = rms_norm(x, lp["g1"], cfg.rms_eps)
         q, k, v = _qkv(cfg, lp, i, h, pos)
         kvs.append((k, v))
         if cfg.layer_types[i] == SLIDING:
@@ -249,14 +200,10 @@ def prefill_forward(params: Dict, cfg: LagunaConfig, tokens, lengths):
         else:
             att = [attention_ops.gqa_causal_attention(
                 q[j], k[j], v[j], cfg.sm_scale) for j in range(b)]
-        x = x + _gated(lp, h, jnp.stack(att)) @ lp["wo"]
+        x = x + gated(lp, h, jnp.stack(att)) @ lp["wo"]
         x, _ = _feed_forward(cfg, lp, x.reshape(b * s, -1), valid)
         x = x.reshape(b, s, -1)
     return x, kvs
-
-
-def _head(params, cfg, x):
-    return _rms(x, params["gf"], cfg.rms_eps) @ params["head"]
 
 
 def decode_forward(params: Dict, cfg: LagunaConfig, cache, cache_ops,
@@ -271,46 +218,24 @@ def decode_forward(params: Dict, cfg: LagunaConfig, cache, cache_ops,
     x = params["tok_emb"][tokens]
     stats = []
     for i, lp in enumerate(params["layers"]):
-        h = _rms(x, lp["g1"], cfg.rms_eps)
+        h = rms_norm(x, lp["g1"], cfg.rms_eps)
         q, k, v = _qkv(cfg, lp, i, h, pos)
         cache = cache_ops.write_token(cache, i, k, v, pos, active)
         with jax.named_scope("attn/window" if cfg.layer_types[i] == SLIDING
                              else "attn/global"):
             o = cache_ops.decode_attention(cache, i, q, pos + 1, active,
                                            sm_scale=cfg.sm_scale)
-        x = x + _gated(lp, h, o) @ lp["wo"]
+        x = x + gated(lp, h, o) @ lp["wo"]
         x, st = _feed_forward(cfg, lp, x, active)
         if st is not None:
             stats.append(st)
-    return _head(params, cfg, x), cache, {
-        "moe_experts_touched": jnp.stack(
-            [s["experts_touched"] for s in stats]),
-        "moe_max_expert_rows": jnp.stack(
-            [s["max_expert_rows"] for s in stats]),
-        "moe_held_pairs": jnp.stack([s["held_pairs"] for s in stats]),
-        **cache_ops.rows_read(pos + 1, active)}
+    return head(params, cfg, x), cache, {
+        **moe_stats(stats), **cache_ops.rows_read(pos + 1, active)}
 
 
-class LagunaLM:
-    """The serving contract over :class:`LagunaConfig`. No ``verify``
-    method: speculation resolves off for this model."""
+class LagunaLM(ServedLM):
+    """The serving contract over :class:`LagunaConfig`."""
 
-    def __init__(self, cfg: LagunaConfig, params: Dict = None, seed: int = 0):
-        self.cfg = cfg
-        self.params = params if params is not None else init_params(cfg, seed)
-
-    def prefill(self, params, tokens, lengths):
-        x, kvs = prefill_forward(params, self.cfg, tokens, lengths)
-        return _head(params, self.cfg, x), kvs
-
-    def prefill_last(self, params, tokens, lengths):
-        """The head for each prompt's LAST row only: ``(logits [B, V],
-        kvs)``."""
-        x, kvs = prefill_forward(params, self.cfg, tokens, lengths)
-        last = jnp.take_along_axis(
-            x, (lengths - 1)[:, None, None], axis=1)[:, 0]
-        return _head(params, self.cfg, last), kvs
-
-    def decode(self, params, cache, cache_ops, tokens, pos, active):
-        return decode_forward(params, self.cfg, cache, cache_ops, tokens,
-                              pos, active)
+    init_params = staticmethod(init_params)
+    prefill_forward = staticmethod(prefill_forward)
+    decode_forward = staticmethod(decode_forward)

@@ -1,8 +1,8 @@
-"""Ling-3.0-flash-VL's language model as pure JAX functions, with
-``models.decoder_lm.DecoderLM``'s serving contract (``cfg``, ``params``,
-``prefill``/``prefill_last``, ``decode``), so the same ``ServingEngine``,
-scheduler and page pool serve it. The plain float32 statement of the same
-equations is ``models/ling3_flash_reference.py``; read the layers there.
+"""Ling-3.0-flash-VL's language model as pure JAX functions under the
+serving contract (``models.blocks.ServedLM``), so the same
+``ServingEngine``, scheduler and page pool serve it. The plain float32
+statement of the same equations, which the tests and the benchmark compare
+this with, is ``grid/reference/ling3_flash.py``; read the layers there.
 
 A HYBRID: five layers in six are Kimi Delta Attention (KDA), a
 linear-attention recurrence, and each sixth is latent attention (MLA).
@@ -22,13 +22,13 @@ What is particular to serving it:
 * its DECODE step reads the slot's tail, then streams the slot's state
   through ``cache_ops.state_step`` (the ``kda_state_step`` kernel) once a
   layer; the decay is computed and the state kept in float32;
-* the MLA layer is ``models/kimi_k2.py``'s, with no query latent
-  (``q_lora_rank`` null: ``wq``) and ``models/laguna.py``'s head-wise
-  gate on the heads' outputs: expanded prefill, absorbed decode over the
-  latent kernel;
-* the feed-forward half is ``models/kimi_k2.py``'s, the router
-  group-limited (``ops/moe_ops.route_sigmoid_topk``); the routed experts
-  may be a SHARE (``cfg.experts_held``).
+* the MLA layer is Kimi-K2's (``blocks.latent`` and the absorbed pair),
+  with no query latent (``q_lora_rank`` null: ``wq``) and Laguna's
+  head-wise gate (``blocks.gated``) on the heads' outputs: expanded
+  prefill, absorbed decode over the latent kernel;
+* the feed-forward half is Kimi-K2's (``blocks.routed_feed_forward``), the
+  router group-limited (``ops/moe_ops.route_sigmoid_topk``); the routed
+  experts may be a SHARE (``cfg.experts_held``).
 """
 
 from __future__ import annotations
@@ -42,14 +42,13 @@ import jax.numpy as jnp
 from ..ops import attention_ops
 from ..ops.pallas_kernels import kda as kda_ops
 from ..serving.kv_cache import LATENT, STATE
-from . import ling3_flash_reference as _ref
-from .kimi_k2 import (_feed_forward, _head, _latent, _rms, absorbed_output,
-                      absorbed_query)
-from .laguna import _gated
+from .blocks import (ServedLM, absorbed_output, absorbed_query, gated, head,
+                     l2_normalize, latent, log_decay, moe_stats, rms_norm,
+                     routed_feed_forward, seeded_params)
 
 __all__ = ["Ling3FlashConfig", "Ling3FlashLM", "init_params"]
 
-KDA, MLA = _ref.KDA, _ref.MLA
+KDA, MLA = "kda", "mla"                # ``layer_types``' two names
 
 
 class Ling3FlashConfig:
@@ -197,26 +196,17 @@ def _init_layer(cfg: Ling3FlashConfig, key, kind: str, dense: bool) -> Dict:
 
 
 def init_params(cfg: Ling3FlashConfig, seed) -> Dict:
-    """Seeded random weights, made where JAX computes (the device), in
-    ``cfg.dtype``, one layer a call. The convolution's taps are drawn at
-    0.5 (four of them over inputs of deviation 1: an output of deviation
-    1, as a trained short convolution gives), the selection bias at
-    ``cfg.bias_std`` (``models/kimi_k2.py`` says why), and ``dt_bias`` so
-    that the channels' half-lives spread log-uniformly over
-    ``cfg.half_life`` tokens at a zero pre-activation: a state that holds
-    something of a long context, so that a comparison can see an error in
-    it."""
-    keys = jax.random.split(jax.random.PRNGKey(seed), cfg.n_layer + 2)
-    layer = jax.jit(lambda k, kind, dense: _init_layer(cfg, k, kind, dense),
-                    static_argnums=(1, 2))
-    emb = jax.jit(lambda k, shape: 0.02 * jax.random.normal(
-        k, shape, cfg.dtype), static_argnums=1)
-    return {"tok_emb": emb(keys[0], (cfg.vocab_size, cfg.d_model)),
-            "head": emb(keys[1], (cfg.d_model, cfg.vocab_size)),
-            "gf": jnp.ones((cfg.d_model,), cfg.dtype),
-            "layers": [layer(keys[2 + i], cfg.layer_types[i],
-                             i in cfg.dense_layers)
-                       for i in range(cfg.n_layer)]}
+    """Seeded random weights (``blocks.seeded_params``). The convolution's
+    taps are drawn at 0.5 (four of them over inputs of deviation 1: an
+    output of deviation 1, as a trained short convolution gives), the
+    selection bias at ``cfg.bias_std`` (``models/kimi_k2.py`` says why),
+    and ``dt_bias`` so that the channels' half-lives spread log-uniformly
+    over ``cfg.half_life`` tokens at a zero pre-activation: a state that
+    holds something of a long context, so that a comparison can see an
+    error in it."""
+    return seeded_params(
+        cfg, seed, _init_layer,
+        lambda i: (cfg.layer_types[i], i in cfg.dense_layers))
 
 
 def _kda_inputs(cfg, lp, h, taps):
@@ -230,12 +220,12 @@ def _kda_inputs(cfg, lp, h, taps):
     c = jax.nn.silu(sum(x.astype(f32) * cw[j] for j, x in enumerate(taps)))
     q, k, v = (t.reshape(lead + (cfg.n_head, cfg.d_state))
                for t in jnp.split(c, 3, axis=-1))
-    q = _ref._l2(q) * cfg.d_state ** -0.5
+    q = l2_normalize(q) * cfg.d_state ** -0.5
     z = (jnp.dot(h, lp["wa"], preferred_element_type=f32) + lp["dt_bias"]
          ).reshape(lead + (cfg.n_head, cfg.d_state))
-    a = _ref.log_decay(z, lp["a_log"], cfg.lower_bound)
+    a = log_decay(z, lp["a_log"], cfg.lower_bound)
     beta = jax.nn.sigmoid(jnp.dot(h, lp["wb"], preferred_element_type=f32))
-    return (q.astype(h.dtype), _ref._l2(k).astype(h.dtype),
+    return (q.astype(h.dtype), l2_normalize(k).astype(h.dtype),
             v.astype(h.dtype), a, beta)
 
 
@@ -243,7 +233,8 @@ def _kda_output(cfg, lp, h, o):
     """``(RMSNorm_head(o; gn) * gate_head) Wo`` of ``o`` [..., H, dv]
     float32."""
     gn = lp["gn"].reshape(cfg.n_head, cfg.d_state)
-    return _gated(lp, h, _rms(o, gn, cfg.rms_eps).astype(h.dtype)) @ lp["wo"]
+    return gated(lp, h, rms_norm(o, gn, cfg.rms_eps).astype(h.dtype)
+                 ) @ lp["wo"]
 
 
 def _kda_prefill(cfg, lp, h, length):
@@ -269,13 +260,13 @@ def _mla_prefill(cfg, lp, h, pos):
     """One sequence's MLA half, K and V EXPANDED from the latent: ``(y [S,
     d], row [S, rank + rope])``."""
     s = h.shape[0]
-    q_n, q_r, row = _latent(cfg, lp, h, pos)
+    q_n, q_r, row = latent(cfg, lp, h, pos)
     kv = (row[..., :cfg.kv_rank] @ lp["wkvb"]).reshape(
         s, cfg.n_head, cfg.d_nope + cfg.d_v)
     o = attention_ops.mla_causal_attention(
         jnp.concatenate([q_n, q_r], axis=-1), kv[..., :cfg.d_nope],
         row[:, cfg.kv_rank:], kv[..., cfg.d_nope:], cfg.sm_scale)
-    return _gated(lp, h, o) @ lp["wo"], row
+    return gated(lp, h, o) @ lp["wo"], row
 
 
 def prefill_forward(params: Dict, cfg: Ling3FlashConfig, tokens, lengths):
@@ -290,7 +281,7 @@ def prefill_forward(params: Dict, cfg: Ling3FlashConfig, tokens, lengths):
     valid = (pos[None] < lengths[:, None]).reshape(b * s)
     kept = []
     for lp, kind in zip(params["layers"], cfg.layer_types):
-        h = _rms(x, lp["g1"], cfg.rms_eps)
+        h = rms_norm(x, lp["g1"], cfg.rms_eps)
         if kind == KDA:
             with jax.named_scope("attn/kda"):
                 ys, *keep = zip(*(_kda_prefill(cfg, lp, h[j], lengths[j])
@@ -300,7 +291,7 @@ def prefill_forward(params: Dict, cfg: Ling3FlashConfig, tokens, lengths):
                               for j in range(b)))
         kept.append(tuple(jnp.stack(t) for t in keep))
         x = x + jnp.stack(ys)
-        x, _ = _feed_forward(cfg, lp, x.reshape(b * s, -1), valid)
+        x, _ = routed_feed_forward(cfg, lp, x.reshape(b * s, -1), valid)
         x = x.reshape(b, s, -1)
     return x, kept
 
@@ -316,7 +307,7 @@ def decode_forward(params: Dict, cfg: Ling3FlashConfig, cache, cache_ops,
     x = params["tok_emb"][tokens]
     stats = []
     for i, (lp, kind) in enumerate(zip(params["layers"], cfg.layer_types)):
-        h = _rms(x, lp["g1"], cfg.rms_eps)
+        h = rms_norm(x, lp["g1"], cfg.rms_eps)
         if kind == KDA:
             with jax.named_scope("attn/kda"):
                 window, cache = cache_ops.tail_step(cache, i, h @ lp["wqkv"],
@@ -328,50 +319,27 @@ def decode_forward(params: Dict, cfg: Ling3FlashConfig, cache, cache_ops,
                     active)
                 x = x + _kda_output(cfg, lp, h, o)
         else:
-            q_n, q_r, row = _latent(cfg, lp, h, pos)
+            q_n, q_r, row = latent(cfg, lp, h, pos)
             cache = cache_ops.write_token(cache, i, row, pos, active)
             with jax.named_scope("attn/mla"):
                 o_lat = cache_ops.decode_attention(
                     cache, i, absorbed_query(cfg, lp["wkvb"], q_n, q_r),
                     pos + 1, active, sm_scale=cfg.sm_scale)
                 a = absorbed_output(cfg, lp["wkvb"], o_lat)
-                x = x + _gated(lp, h, a.reshape(-1, cfg.n_head, cfg.d_v)
+                x = x + gated(lp, h, a.reshape(-1, cfg.n_head, cfg.d_v)
                                ) @ lp["wo"]
-        x, st = _feed_forward(cfg, lp, x, active)
+        x, st = routed_feed_forward(cfg, lp, x, active)
         if st is not None:
             stats.append(st)
-    return _head(params, cfg, x), cache, {
-        "moe_experts_touched": jnp.stack(
-            [s["experts_touched"] for s in stats]),
-        "moe_max_expert_rows": jnp.stack(
-            [s["max_expert_rows"] for s in stats]),
-        "moe_held_pairs": jnp.stack([s["held_pairs"] for s in stats]),
+    return head(params, cfg, x), cache, {
+        **moe_stats(stats),
         "state_slots_stepped": jnp.sum(active).astype(jnp.int32),
         **cache_ops.rows_read(pos + 1, active)}
 
 
-class Ling3FlashLM:
-    """The serving contract over :class:`Ling3FlashConfig`. No ``verify``
-    method: speculation resolves off for this model (a state cannot be
-    rolled back)."""
+class Ling3FlashLM(ServedLM):
+    """The serving contract over :class:`Ling3FlashConfig`."""
 
-    def __init__(self, cfg: Ling3FlashConfig, params: Dict = None,
-                 seed: int = 0):
-        self.cfg = cfg
-        self.params = params if params is not None else init_params(cfg, seed)
-
-    def prefill(self, params, tokens, lengths):
-        x, kept = prefill_forward(params, self.cfg, tokens, lengths)
-        return _head(params, self.cfg, x), kept
-
-    def prefill_last(self, params, tokens, lengths):
-        """The head for each prompt's LAST row only: ``(logits [B, V],
-        kept)``."""
-        x, kept = prefill_forward(params, self.cfg, tokens, lengths)
-        last = jnp.take_along_axis(
-            x, (lengths - 1)[:, None, None], axis=1)[:, 0]
-        return _head(params, self.cfg, last), kept
-
-    def decode(self, params, cache, cache_ops, tokens, pos, active):
-        return decode_forward(params, self.cfg, cache, cache_ops, tokens,
-                              pos, active)
+    init_params = staticmethod(init_params)
+    prefill_forward = staticmethod(prefill_forward)
+    decode_forward = staticmethod(decode_forward)

@@ -1,8 +1,8 @@
-"""Motif-3-Beta as pure JAX functions, with ``models.decoder_lm
-.DecoderLM``'s serving contract (``cfg``, ``params``, ``prefill``/
-``prefill_last``, ``decode``), so the same ``ServingEngine``, scheduler and
-page pool serve it. The plain float32 statement of the same equations is
-``models/motif3_reference.py``; read the layers there.
+"""Motif-3-Beta as pure JAX functions under the serving contract
+(``models.blocks.ServedLM``), so the same ``ServingEngine``, scheduler and
+page pool serve it. The plain float32 statement of the same equations,
+which the tests and the benchmark compare this with, is
+``grid/reference/motif3.py``; read the layers there.
 
 What is particular to serving it:
 
@@ -25,7 +25,7 @@ What is particular to serving it:
   for every token and signal head, from each of the other four
   (``ops.attention_ops.differential_combine``). PREFILL EXPANDS K and V at
   16 heads (the flash kernel in a full layer, the banded form in a window
-  layer); DECODE ABSORBS, ``models/kimi_k2.py``'s way, over either group,
+  layer); DECODE ABSORBS, Kimi-K2's way, over either group,
   and because a group's heads share one value up-projection the
   subtraction is taken on the LATENT outputs: 64 up-projections, not 80;
 * every MLP's activation is PolyNorm with four numbers of its own, so a
@@ -45,8 +45,8 @@ import jax.numpy as jnp
 
 from ..ops import attention_ops, moe_ops
 from ..serving.kv_cache import LATENT
-from .kimi_k2 import _head, _latent, _rms
-from .kimi_k2_reference import yarn_inv_freq
+from .blocks import (ServedLM, head, held_experts, latent, moe_stats,
+                     rms_norm, seeded_params, yarn_inv_freq)
 
 __all__ = ["Motif3Config", "Motif3LM", "init_params", "poly_norm"]
 
@@ -61,9 +61,8 @@ def poly_norm(v, p, scale: float = 0.5, clamp: float = 0.5,
     all times ``scale``; in float32. ``p`` is four numbers that broadcast
     against ``v``'s rows: an MLP's own, or in the expert paths each row's
     expert's (scalars from SMEM in the fused kernel). Written here on its
-    own: ``models/motif3_reference.py`` states the same mathematics
-    plainly, and ``tests/test_motif3.py`` holds the two against each
-    other."""
+    own: ``grid/reference/motif3.py`` states the same mathematics plainly,
+    and ``tests/test_motif3.py`` holds the two against each other."""
     v = v.astype(jnp.float32)
     acc, power = None, v
     for k in range(3):
@@ -145,14 +144,13 @@ class Motif3Config:
         self.activation = _activation(float(act_scale),
                                       float(act_bias_clamp))
         self.sm_scale = self.d_head ** -0.5
-        # the rotary tables are Kimi-K2's (a full layer YaRN's, no
-        # temperature factor; a window layer plain at its own base), not
-        # this model's reference's: the tests hold the two texts together
+        # a full layer's frequencies are YaRN's (no temperature factor), a
+        # window layer's plain at its own base
         freqs = {FULL: yarn_inv_freq(self.d_rope, float(rope_theta),
                                      rope_scaling),
                  RING: yarn_inv_freq(self.d_rope, float(window_rope_theta),
                                      None)}
-        # what models/kimi_k2._latent reads of a config, a layer kind: the
+        # what blocks.latent reads of a config, a layer kind: the
         # same sizes under the kind's own rotary frequencies
         self.latent_of = {kind: types.SimpleNamespace(
             n_head=self.n_head, d_head=self.d_head, d_nope=self.d_nope,
@@ -239,23 +237,14 @@ def _init_layer(cfg: Motif3Config, key, dense: bool) -> Dict:
 
 
 def init_params(cfg: Motif3Config, seed) -> Dict:
-    """Seeded random weights, made where JAX computes (the device), in
-    ``cfg.dtype``, one layer a call. The residual maps' ``alpha`` are 0.1
-    and ``b_res`` normal(0, 1) (at the paper's small alpha the dynamic
-    part is a hundredth of the static and no comparison could see an error
-    in it), PolyNorm's weights 1/3 +- 0.1 and its bias inside the clamp,
-    drawn for each MLP and each expert (at one value for all, the wrong
-    expert's numbers would pass); both kept float32."""
-    keys = jax.random.split(jax.random.PRNGKey(seed), cfg.n_layer + 2)
-    layer = jax.jit(lambda k, dense: _init_layer(cfg, k, dense),
-                    static_argnums=1)
-    emb = jax.jit(lambda k, shape: 0.02 * jax.random.normal(
-        k, shape, cfg.dtype), static_argnums=1)
-    return {"tok_emb": emb(keys[0], (cfg.vocab_size, cfg.d_model)),
-            "head": emb(keys[1], (cfg.d_model, cfg.vocab_size)),
-            "gf": jnp.ones((cfg.d_model,), cfg.dtype),
-            "layers": [layer(keys[2 + i], i in cfg.dense_layers)
-                       for i in range(cfg.n_layer)]}
+    """Seeded random weights (``blocks.seeded_params``). The residual maps'
+    ``alpha`` are 0.1 and ``b_res`` normal(0, 1) (at the paper's small
+    alpha the dynamic part is a hundredth of the static and no comparison
+    could see an error in it), PolyNorm's weights 1/3 +- 0.1 and its bias
+    inside the clamp, drawn for each MLP and each expert (at one value for
+    all, the wrong expert's numbers would pass); both kept float32."""
+    return seeded_params(cfg, seed, _init_layer,
+                         lambda i: (i in cfg.dense_layers,))
 
 
 def _lower(cfg, x):
@@ -299,7 +288,7 @@ def _mix_in(cfg, lp, which: str, x, g):
             mat = _lower(cfg, mat / jnp.sum(mat, axis=-1, keepdims=True))
             mat = _lower(cfg, mat / jnp.sum(mat, axis=-2, keepdims=True))
         u = sum(h_pre[..., j, None] * t for j, t in enumerate(xf))
-        return _rms(u.astype(x.dtype), g, cfg.rms_eps), h_post, mat
+        return rms_norm(u.astype(x.dtype), g, cfg.rms_eps), h_post, mat
 
 
 def _mix_out(cfg, x, y, h_post, h_res):
@@ -324,8 +313,8 @@ def _kv_weights(cfg, wkvb):
 
 def absorbed_query(cfg: Motif3Config, wkvb, q_n, q_r):
     """``[q_nope_n Wuk_g^T | q_rope_n]`` [B, H, rank + rope] with ``g = n
-    // G``: ``models/kimi_k2.absorbed_query`` where ``G`` query heads share
-    a KV head's up-projection."""
+    // G``: ``blocks.absorbed_query`` where ``G`` query heads share a KV
+    head's up-projection."""
     b, h, n = q_n.shape
     w = _kv_weights(cfg, wkvb)[..., :cfg.d_nope]
     q_lat = jnp.einsum("bgjn,cgn->bgjc",
@@ -369,7 +358,7 @@ def _gdla_prefill(cfg, lp, kind, h, pos):
     d], row [S, rank + rope])``."""
     s = h.shape[0]
     g = cfg.n_head // cfg.n_kv_head
-    q_n, q_r, row = _latent(cfg.latent_of[kind], lp, h, pos)
+    q_n, q_r, row = latent(cfg.latent_of[kind], lp, h, pos)
     q = jnp.concatenate([q_n, q_r], axis=-1)
     kv = (row[..., :cfg.kv_rank] @ lp["wkvb"]).reshape(
         s, cfg.n_kv_head, cfg.d_nope + cfg.d_v)
@@ -406,8 +395,7 @@ def _feed_forward(cfg, lp, u, row_valid):
         cfg.routed_scale)
     y, stats = moe_ops.expert_layer(
         u, idx, w, lp["wg"], lp["wu"], lp["wd"], n_expert=cfg.n_expert,
-        held=(None if len(cfg.experts_held) == cfg.n_expert
-              else cfg.experts_held), row_valid=row_valid,
+        held=held_experts(cfg), row_valid=row_valid,
         activation=cfg.activation, act_params=lp["pn"])
     stats = dict(stats, held_pairs=moe_ops.held_pairs(
         idx, cfg.experts_held, cfg.n_expert, row_valid))
@@ -454,7 +442,7 @@ def decode_forward(params: Dict, cfg: Motif3Config, cache, cache_ops,
     stats = []
     for i, (lp, kind) in enumerate(zip(params["layers"], cfg.layer_types)):
         h, h_post, h_res = _mix_in(cfg, lp, "a", x, lp["g1"])
-        q_n, q_r, row = _latent(cfg.latent_of[kind], lp, h, pos)
+        q_n, q_r, row = latent(cfg.latent_of[kind], lp, h, pos)
         cache = cache_ops.write_token(cache, i, row, pos, active)
         with jax.named_scope("attn/gdla_full" if kind == FULL
                              else "attn/gdla_ring"):
@@ -470,37 +458,14 @@ def decode_forward(params: Dict, cfg: Motif3Config, cache, cache_ops,
         if st is not None:
             stats.append(st)
     x = jnp.sum(x.astype(jnp.float32), axis=0).astype(x.dtype)
-    return _head(params, cfg, x), cache, {
-        "moe_experts_touched": jnp.stack(
-            [s["experts_touched"] for s in stats]),
-        "moe_max_expert_rows": jnp.stack(
-            [s["max_expert_rows"] for s in stats]),
-        "moe_held_pairs": jnp.stack([s["held_pairs"] for s in stats]),
-        **cache_ops.rows_read(pos + 1, active)}
+    return head(params, cfg, x), cache, {
+        **moe_stats(stats), **cache_ops.rows_read(pos + 1, active)}
 
 
-class Motif3LM:
-    """The serving contract over :class:`Motif3Config`. No ``verify``
-    method: speculation resolves off for this model (a ring cannot be
-    rolled back; the published draft layer is not served)."""
+class Motif3LM(ServedLM):
+    """The serving contract over :class:`Motif3Config` (the published
+    draft layer is not served)."""
 
-    def __init__(self, cfg: Motif3Config, params: Dict = None,
-                 seed: int = 0):
-        self.cfg = cfg
-        self.params = params if params is not None else init_params(cfg, seed)
-
-    def prefill(self, params, tokens, lengths):
-        x, rows = prefill_forward(params, self.cfg, tokens, lengths)
-        return _head(params, self.cfg, x), rows
-
-    def prefill_last(self, params, tokens, lengths):
-        """The head for each prompt's LAST row only: ``(logits [B, V],
-        rows)``."""
-        x, rows = prefill_forward(params, self.cfg, tokens, lengths)
-        last = jnp.take_along_axis(
-            x, (lengths - 1)[:, None, None], axis=1)[:, 0]
-        return _head(params, self.cfg, last), rows
-
-    def decode(self, params, cache, cache_ops, tokens, pos, active):
-        return decode_forward(params, self.cfg, cache, cache_ops, tokens,
-                              pos, active)
+    init_params = staticmethod(init_params)
+    prefill_forward = staticmethod(prefill_forward)
+    decode_forward = staticmethod(decode_forward)

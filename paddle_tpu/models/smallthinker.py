@@ -1,11 +1,10 @@
-"""SmallThinker's decoder block as pure JAX functions, with
-``models.decoder_lm.DecoderLM``'s serving contract (``cfg``, ``params``,
-``prefill``/``prefill_last``, ``decode``), so the same ``ServingEngine``,
+"""SmallThinker's decoder block as pure JAX functions under the serving
+contract (``models.blocks.ServedLM``), so the same ``ServingEngine``,
 scheduler, page pool, paged cache and paged-attention kernel serve it.
 
 The layer (PowerInfer/SmallThinker-21BA3B-Instruct ``config.json``; the
-plain float32 statement of the same equations is
-``models/smallthinker_reference.py``):
+plain float32 statement of the same equations, which the tests and the
+benchmark compare this with, is ``grid/reference/smallthinker.py``):
 
 * RMSNorm; Q of ``n_head`` heads and K, V of ``n_kv_head`` heads of
   ``d_head`` (grouped queries: query head n reads KV head ``n // G``), no
@@ -34,6 +33,8 @@ import jax
 import jax.numpy as jnp
 
 from ..ops import attention_ops, moe_ops
+from .blocks import (ServedLM, head, held_experts, rms_norm, rope,
+                     seeded_params)
 
 __all__ = ["SmallThinkerConfig", "SmallThinkerLM", "init_params"]
 
@@ -115,43 +116,23 @@ def _init_layer(cfg: SmallThinkerConfig, key) -> Dict:
 
 
 def init_params(cfg: SmallThinkerConfig, seed) -> Dict:
-    """Seeded random weights, made where JAX computes (the device), in
-    ``cfg.dtype``, one layer a call: the largest temporary is one layer."""
-    keys = jax.random.split(jax.random.PRNGKey(seed), cfg.n_layer + 2)
-    layer = jax.jit(lambda k: _init_layer(cfg, k))
-    emb = jax.jit(lambda k, shape: 0.02 * jax.random.normal(
-        k, shape, cfg.dtype), static_argnums=1)
-    return {"tok_emb": emb(keys[0], (cfg.vocab_size, cfg.d_model)),
-            "head": emb(keys[1], (cfg.d_model, cfg.vocab_size)),
-            "gf": jnp.ones((cfg.d_model,), cfg.dtype),
-            "layers": [layer(keys[2 + i]) for i in range(cfg.n_layer)]}
+    """Seeded random weights (``blocks.seeded_params``)."""
+    return seeded_params(cfg, seed, _init_layer, lambda i: ())
 
 
-def _rms(x, g, eps):
-    xf = x.astype(jnp.float32)
-    y = xf * jax.lax.rsqrt(jnp.mean(xf * xf, axis=-1, keepdims=True) + eps)
-    return (y * g.astype(jnp.float32)).astype(x.dtype)
-
-
-def _rope(x, pos, theta: float):
-    """Rotate-half over the whole head: ``x`` [..., H, D] at positions
-    ``pos`` [...] (one a row of heads)."""
+def _rope(cfg, x, pos):
+    """Rotate-half over the whole head of ``x`` [..., H, D] at ``pos``
+    [...], the frequencies made in the program from ``cfg.rope_theta``."""
     half = x.shape[-1] // 2
-    freq = theta ** (-jnp.arange(half, dtype=jnp.float32) / half)
-    ang = pos.astype(jnp.float32)[..., None, None] * freq
-    cos, sin = jnp.cos(ang), jnp.sin(ang)
-    x1 = x[..., :half].astype(jnp.float32)
-    x2 = x[..., half:].astype(jnp.float32)
-    return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin],
-                           axis=-1).astype(x.dtype)
+    return rope(x, pos, cfg.rope_theta ** (
+        -jnp.arange(half, dtype=jnp.float32) / half))
 
 
 def _experts(cfg, lp, x, idx, w, row_valid):
-    u = _rms(x, lp["g2"], cfg.rms_eps)
+    u = rms_norm(x, lp["g2"], cfg.rms_eps)
     y, stats = moe_ops.expert_layer(
         u, idx, w, lp["wg"], lp["wu"], lp["wd"], n_expert=cfg.n_expert,
-        held=(None if len(cfg.experts_held) == cfg.n_expert
-              else cfg.experts_held), row_valid=row_valid)
+        held=held_experts(cfg), row_valid=row_valid)
     return x + y.astype(x.dtype), stats
 
 
@@ -167,13 +148,13 @@ def prefill_forward(params: Dict, cfg: SmallThinkerConfig, tokens, lengths):
     valid = (pos[None, :] < lengths[:, None]).reshape(b * s)
     kvs = []
     for i, lp in enumerate(params["layers"]):
-        h = _rms(x, lp["g1"], cfg.rms_eps)
+        h = rms_norm(x, lp["g1"], cfg.rms_eps)
         q = (h @ lp["wq"]).reshape(b, s, cfg.n_head, cfg.d_head)
         k = (h @ lp["wk"]).reshape(b, s, cfg.n_kv_head, cfg.d_head)
         v = (h @ lp["wv"]).reshape(b, s, cfg.n_kv_head, cfg.d_head)
         if cfg.rope_layout[i]:
-            q = _rope(q, pos[None], cfg.rope_theta)
-            k = _rope(k, pos[None], cfg.rope_theta)
+            q = _rope(cfg, q, pos[None])
+            k = _rope(cfg, k, pos[None])
         kvs.append((k, v))
         if cfg.window_layout[i]:
             att = [attention_ops.windowed_causal_attention(
@@ -189,10 +170,6 @@ def prefill_forward(params: Dict, cfg: SmallThinkerConfig, tokens, lengths):
     return x, kvs
 
 
-def _head(params, cfg, x):
-    return _rms(x, params["gf"], cfg.rms_eps) @ params["head"]
-
-
 def decode_forward(params: Dict, cfg: SmallThinkerConfig, cache, cache_ops,
                    tokens, pos, active):
     """One decode position a slot, through ``cache_ops`` (the cache owns
@@ -204,13 +181,13 @@ def decode_forward(params: Dict, cfg: SmallThinkerConfig, cache, cache_ops,
     x = params["tok_emb"][tokens]
     touched, biggest = [], []
     for i, lp in enumerate(params["layers"]):
-        h = _rms(x, lp["g1"], cfg.rms_eps)
+        h = rms_norm(x, lp["g1"], cfg.rms_eps)
         q = (h @ lp["wq"]).reshape(b, cfg.n_head, cfg.d_head)
         k = (h @ lp["wk"]).reshape(b, cfg.n_kv_head, cfg.d_head)
         v = (h @ lp["wv"]).reshape(b, cfg.n_kv_head, cfg.d_head)
         if cfg.rope_layout[i]:
-            q = _rope(q, pos, cfg.rope_theta)
-            k = _rope(k, pos, cfg.rope_theta)
+            q = _rope(cfg, q, pos)
+            k = _rope(cfg, k, pos)
         cache = cache_ops.write_token(cache, i, k, v, pos, active)
         with jax.named_scope("attn/window" if cfg.window_layout[i]
                              else "attn/global"):
@@ -221,32 +198,14 @@ def decode_forward(params: Dict, cfg: SmallThinkerConfig, cache, cache_ops,
         x, stats = _experts(cfg, lp, x, idx, w, active)
         touched.append(stats["experts_touched"])
         biggest.append(stats["max_expert_rows"])
-    return _head(params, cfg, x), cache, {
+    return head(params, cfg, x), cache, {
         "moe_experts_touched": jnp.stack(touched),
         "moe_max_expert_rows": jnp.stack(biggest)}
 
 
-class SmallThinkerLM:
-    """The serving contract over :class:`SmallThinkerConfig`. No ``verify``
-    method: speculation resolves off for this model."""
+class SmallThinkerLM(ServedLM):
+    """The serving contract over :class:`SmallThinkerConfig`."""
 
-    def __init__(self, cfg: SmallThinkerConfig, params: Dict = None,
-                 seed: int = 0):
-        self.cfg = cfg
-        self.params = params if params is not None else init_params(cfg, seed)
-
-    def prefill(self, params, tokens, lengths):
-        x, kvs = prefill_forward(params, self.cfg, tokens, lengths)
-        return _head(params, self.cfg, x), kvs
-
-    def prefill_last(self, params, tokens, lengths):
-        """The head for each prompt's LAST row only: ``(logits [B, V],
-        kvs)``. [B, S, V] at S = 8,192 and V = 151,936 would be 5 GB."""
-        x, kvs = prefill_forward(params, self.cfg, tokens, lengths)
-        last = jnp.take_along_axis(
-            x, (lengths - 1)[:, None, None], axis=1)[:, 0]
-        return _head(params, self.cfg, last), kvs
-
-    def decode(self, params, cache, cache_ops, tokens, pos, active):
-        return decode_forward(params, self.cfg, cache, cache_ops, tokens,
-                              pos, active)
+    init_params = staticmethod(init_params)
+    prefill_forward = staticmethod(prefill_forward)
+    decode_forward = staticmethod(decode_forward)

@@ -65,9 +65,9 @@ def _table_mesh_sharding(ctx, param):
     except Exception:
         return None
     spec = getattr(var, "sharding", None)
-    from ..executor import _valid_sharding
+    from ..parallel.mesh import valid_sharding
 
-    if not spec or spec[0] is None or not _valid_sharding(spec, mesh):
+    if not spec or spec[0] is None or not valid_sharding(spec, mesh):
         return None
     axis = spec[0]
     n = mesh.shape[axis]

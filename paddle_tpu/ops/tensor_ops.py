@@ -314,15 +314,11 @@ def _init_out_sharding(ctx: OpContext):
     if not spec or all(a is None for a in spec):
         return None
     mesh = getattr(ctx.trace, "mesh", None)
-    if mesh is None:
-        from ..parallel.mesh import get_mesh
+    from ..parallel.mesh import get_mesh, valid_sharding
 
+    if mesh is None:
         mesh = get_mesh()
-    if mesh is None:
-        return None
-    from ..executor import _valid_sharding
-
-    if not _valid_sharding(spec, mesh):
+    if mesh is None or not valid_sharding(spec, mesh):
         return None
     from jax.sharding import NamedSharding, PartitionSpec
 

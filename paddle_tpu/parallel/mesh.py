@@ -16,7 +16,8 @@ import jax
 import numpy as np
 from jax.sharding import Mesh
 
-__all__ = ["create_mesh", "get_mesh", "mesh_guard"]
+__all__ = ["create_mesh", "get_mesh", "mesh_guard",
+           "valid_sharding"]
 
 _current_mesh: Optional[Mesh] = None
 
@@ -42,6 +43,14 @@ def create_mesh(axes: Dict[str, int], devices=None) -> Mesh:
 
 def get_mesh() -> Optional[Mesh]:
     return _current_mesh
+
+
+def valid_sharding(spec, mesh) -> bool:
+    """A Variable.sharding annotation applies iff every named axis exists on
+    this mesh — the one predicate all sharding consumers share (the
+    executor's placement, the init ops, the sparse optimizer ops)."""
+    return spec is not None and all(
+        a is None or a in mesh.axis_names for a in spec)
 
 
 @contextlib.contextmanager

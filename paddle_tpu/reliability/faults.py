@@ -322,13 +322,10 @@ def classify(exc: BaseException) -> str:
         return "transient"
     if isinstance(exc, InjectedFault):  # resource / fatal
         return "fatal"
-    try:  # lazy: serving must stay importable without reliability and v.v.
-        from ..serving.request import BackpressureError
-
-        if isinstance(exc, BackpressureError):
-            return "backpressure"
-    except Exception:
-        pass
+    # told by what the exception carries (serving.request.BackpressureError
+    # and its subclasses): reliability lies below serving, imports none of it
+    if getattr(exc, "fault_class", None) == "backpressure":
+        return "backpressure"
     if _TRANSIENT_MSG.search(str(exc)):
         return "transient"
     return "fatal"

@@ -41,6 +41,7 @@ from . import trace as _trace
 from .kv_cache import (KV, LATENT, STATE, CacheGroup, ContiguousKVCache,
                        Int8PagedKVCache, LatentPagedCache, PagedKVCache)
 from .page_pool import PagePool, PagePoolExhausted
+from .prefix_cache import PrefixCache
 from .request import (FAILED, FINISHED, REJECTED, TIMEOUT, DrainingError,
                       Request)
 from .scheduler import Scheduler
@@ -143,7 +144,7 @@ def _expert_matmul_form(mcfg, n_tokens: int) -> Optional[str]:
     that an executable over ``n_tokens`` rows a forward selects, told on
     the host from the model's static geometry as the expert layer tells it
     from its pass's rows; None for a model with no expert layer."""
-    held = getattr(mcfg, "experts_held", None)
+    held = getattr(mcfg, "experts_held", None)    # the contract's
     if held is None:
         return None
     from ..ops import moe_ops
@@ -356,7 +357,8 @@ def _layer_groups(mcfg):
     """A model config's cache groups as ``(name, layers, window, kind)``:
     ``cache_groups`` (an entry without a kind is K and V rows; with
     ``latent_row`` and no ``cache_groups``, one latent group of every
-    layer), else one group of every layer that keeps every position."""
+    layer), else one group of every layer that keeps every position (the
+    contract's defaults: ``models.blocks.ServedLM``)."""
     latent = getattr(mcfg, "latent_row", None)
     groups = getattr(mcfg, "cache_groups", None) or [
         ("latent" if latent else "global", tuple(range(mcfg.n_layer)), None,
@@ -387,42 +389,17 @@ def _query_groups(mcfg, layer_groups):
 
 
 class ServingEngine:
-    """Drives a model implementing the serving contract:
-
-    * ``model.cfg`` — exposes ``n_layer``/``n_head``/``d_head``/``max_seq``
-      /``dtype`` (models.decoder_lm.DecoderConfig shape); ``n_head`` counts
-      the QUERY heads, one number or one a layer; optionally ``n_kv_head``
-      (the heads of K and V, the same in every layer, which size the
-      cache; default ``n_head``: the queries then are not grouped) and
-      ``cache_groups``, a list of ``(name, layers, window)`` or ``(name,
-      layers, window, kind)`` (default: one group of every layer that
-      keeps every position; see serving.kv_cache). The layers of a group
-      have one ``n_head``, so the query heads a KV head are the GROUP's;
-      or ``latent_row``, ``(rank, rope)``: the model's latent-attention
-      layers keep ONE ``[c | kr]`` row a token and the cache is a
-      :class:`~.kv_cache.LatentPagedCache` sized from it: over every
-      layer, or over the ``LATENT`` groups of ``cache_groups`` (one of
-      pages, or one of pages beside one with a ``window`` whose slots keep
-      rings) with, after them, a ``STATE`` group whose layers keep
-      ``slot_state`` ``(heads, dk, dv, tail rows, tail width)`` a SLOT and
-      no pages,
-    * ``model.prefill(params, tokens[B,S], lengths[B]) -> (logits[B,S,V],
-      kvs)`` with ``kvs``, a layer, what the cache's ``write_prompt``
-      takes with a leading batch axis: one ``(k, v)`` ``[B,S,H,D]`` pair
-      (H the KV heads), or one ``(row,)`` ``[B,S,rank+rope]`` of a latent
-      layer, or ``(state [B,H,dk,dv], tail [B,rows,width])`` of a state
-      layer: the state the prompt LEAVES, not rows; a model with
-      ``prefill_last`` is asked for that
-      instead: the same with ``logits[B,V]`` of each prompt's last row,
-    * ``model.decode(params, cache, cache_ops, tokens[B], pos[B],
-      active[B]) -> (logits[B,V], cache)`` or ``(logits, cache, stats)``
-      with ``stats`` a dict of small int arrays a step; the engine feeds
-      ``moe_experts_touched``, ``moe_max_expert_rows`` and
-      ``moe_held_pairs`` [n_layer] to the ``serving/*`` histograms of
-      those names, ``state_slots_stepped`` to
-      ``serving/state_slots_stepped``, and ``attn_rows_read.<group>``
-      (what the cache's ``rows_read`` gives) to
-      ``serving/attn_rows_read.<group>``.
+    """Drives a model under the serving contract, which is written ONCE,
+    in ``models.blocks.ServedLM``'s docstring: the methods the engine calls
+    (``prefill`` or, where the model has it, ``prefill_last``; ``decode``;
+    ``verify`` where it has it) and what it reads of ``model.cfg``
+    (``n_layer``, ``n_head``, ``d_head``, ``max_seq``, ``dtype`` and, by
+    ``getattr``, ``n_kv_head``, ``cache_groups``, ``latent_row``,
+    ``slot_state``, ``experts_held``), each with what its absence means.
+    Every ``getattr``/``hasattr`` on a model or its config in this module
+    is one of those. The cache groups' kinds (``KV``, ``LATENT``,
+    ``STATE``) are ``serving.kv_cache``'s; a model's decode ``stats`` go to
+    the ``serving/*`` histograms of their names (``metrics.model_stat``).
 
     Over a cache of more than one group, and over a latent cache (with or
     without a state group), the engine refuses, at construction, what
@@ -516,7 +493,7 @@ class ServingEngine:
         self._decode_exe: Dict[int, Any] = {}    # fuse length -> executable
         self._resume_exe: Dict[int, Any] = {}    # remainder bucket -> exe
         self._verify_exe: Dict[int, Any] = {}    # window width -> executable
-        # speculative decoding: needs the model's ``verify`` contract
+        # speculative decoding: needs the contract's optional ``verify``
         # method; without it every speculation knob silently resolves off
         # (serving must come up on a decode-only model)
         self._spec_capable = hasattr(model, "verify")
@@ -526,12 +503,10 @@ class ServingEngine:
         self._spec_k = np.zeros((b,), np.int32)  # per-slot resolved draft k
         self._spec_auto: Optional[tuple] = None  # cached "auto" resolution
         self._spec_enabled = False  # any slot ever armed with k > 0
-        # fleet prefix cache: host-side index of donated prompt-prefix KV
-        # pages (paged layout only; see paddle_tpu.fleet.prefix_cache)
+        # prefix cache: host-side index of donated prompt-prefix KV pages
+        # (paged layout only; see serving/prefix_cache.py)
         self.prefix_cache = None
         if self.cfg.paged and self.cfg.prefix_cache_pages > 0:
-            from ..fleet.prefix_cache import PrefixCache
-
             self.prefix_cache = PrefixCache(self.cfg.prefix_cache_pages,
                                             self.cfg.page_size)
         self._captured_logits: Dict[int, List[np.ndarray]] = {}
@@ -1663,9 +1638,7 @@ class ServingEngine:
         if n <= 0:
             return 0
         if state != FINISHED:
-            from ..fleet import metrics as _fm
-
-            _fm.PREFIX_POISONED_SKIPPED.inc()
+            _sm.PREFIX_POISONED_SKIPPED.inc()
             return 0
         tokens = req.prompt[:n]
         if cache.contains(tokens):
@@ -1754,7 +1727,7 @@ class ServingEngine:
             return exe
         model, ops, cfg = self.model, self.cache_ops, self.cfg
 
-        last_only = hasattr(model, "prefill_last")
+        last_only = hasattr(model, "prefill_last")   # optional: contract
 
         def prefill(params, cache, state, dest, prompt, ints, temp):
             slot, length, maxnew, topk, seed, _ = ints

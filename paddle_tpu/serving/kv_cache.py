@@ -461,10 +461,14 @@ class PagedKVCache(_KVCacheBase):
         ever materializes, and a slot of length 0 costs a grid step and
         nothing else; otherwise the XLA gather +
         ops.attention_ops.decode_attention path runs. Both mask rows >= the
-        length with the SAME neg_inf constant, so the paths agree to float
-        round-off (tier-1 parity tests pin it). A slot of length 0 comes
-        back finite from both and is nobody's to read: exactly 0.0 from
-        the kernel, the mean of its table's V rows from the gather."""
+        length with the SAME neg_inf constant, so at one query head a KV
+        head over a float32 pool the paths agree to float round-off
+        (tier-1 parity tests pin it); with grouped queries or a bfloat16
+        pool they differ by the rounding of what each keeps between its
+        steps, inside the margins the models' tests state. A slot of
+        length 0 comes back finite from both and is nobody's to read:
+        exactly 0.0 from the kernel, the mean of its table's V rows from
+        the gather."""
         from ..ops import attention_ops
 
         gi, li = self._where[layer]

@@ -27,6 +27,9 @@ __all__ = [
     "SPEC_VERIFY_DISPATCHES", "SPEC_ACCEPT_RATE",
     "MOE_EXPERTS_TOUCHED", "MOE_MAX_EXPERT_ROWS", "MOE_HELD_PAIRS",
     "STATE_SLOTS_STEPPED", "STATE_POOL_BYTES", "LATENT_RING_BYTES",
+    "PREFIX_HITS", "PREFIX_MISSES", "PREFIX_INSERTS", "PREFIX_EVICTIONS",
+    "PREFIX_ENTRIES", "PREFIX_PAGES", "PREFIX_TOKENS_REUSED",
+    "PREFIX_POISONED_SKIPPED",
     "pages_used", "attn_rows_read", "model_stat",
 ]
 
@@ -204,6 +207,38 @@ LATENT_RING_BYTES = _mx.gauge(
     help="bytes of the latent pools whose slots keep the last W rows as a "
          "ring (0 for a latent cache without a window group): what those "
          "layers hold whatever the contexts' lengths")
+
+# the engine's prefix cache (serving/prefix_cache.py). The names say
+# ``fleet/``: the cache was the fleet's before it was the engine's, and
+# operators and tools/dump_metrics.py read these strings
+PREFIX_HITS = _mx.counter(
+    "fleet/prefix_cache/hits",
+    help="prefill requests served from cached prefix KV pages (prefill "
+         "compute skipped for the shared prefix)")
+PREFIX_MISSES = _mx.counter(
+    "fleet/prefix_cache/misses",
+    help="prefill lookups that found no cached prefix")
+PREFIX_INSERTS = _mx.counter(
+    "fleet/prefix_cache/inserts",
+    help="prefix entries inserted (pages donated by a FINISHED request)")
+PREFIX_EVICTIONS = _mx.counter(
+    "fleet/prefix_cache/evictions",
+    help="LRU evictions under page-budget pressure")
+PREFIX_ENTRIES = _mx.gauge(
+    "fleet/prefix_cache/entries", help="live prefix entries")
+PREFIX_PAGES = _mx.gauge(
+    "fleet/prefix_cache/pages_held",
+    help="KV pages owned by the prefix cache (counted by the engine's "
+         "page-accounting invariant)")
+PREFIX_TOKENS_REUSED = _mx.counter(
+    "fleet/prefix_cache/tokens_reused",
+    help="prompt tokens whose prefill compute was skipped via a cached "
+         "prefix")
+PREFIX_POISONED_SKIPPED = _mx.counter(
+    "fleet/prefix_cache/poisoned_skipped",
+    help="cacheable prefixes NOT inserted because their request did not "
+         "FINISH (failed/timed-out pages are never served to a later "
+         "request)")
 
 # a model's decode ``stats`` by name (:func:`model_stat`)
 _MODEL_STATS = {"moe_experts_touched": MOE_EXPERTS_TOUCHED,

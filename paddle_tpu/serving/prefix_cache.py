@@ -30,7 +30,7 @@ import hashlib
 from collections import OrderedDict
 from typing import Dict, List, Optional, Sequence, Tuple
 
-from . import metrics as _fm
+from . import metrics as _sm
 
 __all__ = ["PrefixCache", "PrefixEntry", "prefix_key"]
 
@@ -114,7 +114,7 @@ class PrefixCache:
             return []
         del self._entries[e.key]
         self.pages_held -= len(e.pages)
-        _fm.PREFIX_EVICTIONS.inc()
+        _sm.PREFIX_EVICTIONS.inc()
         self._export_gauges()
         return e.pages
 
@@ -131,10 +131,10 @@ class PrefixCache:
             if entry is not None and entry.tokens == tuple(prompt[:n]):
                 self._entries.move_to_end(key)
                 entry.hits += 1
-                _fm.PREFIX_HITS.inc()
-                _fm.PREFIX_TOKENS_REUSED.inc(entry.n_tokens)
+                _sm.PREFIX_HITS.inc()
+                _sm.PREFIX_TOKENS_REUSED.inc(entry.n_tokens)
                 return entry
-        _fm.PREFIX_MISSES.inc()
+        _sm.PREFIX_MISSES.inc()
         return None
 
     def insert(self, tokens: Sequence[int], pages: Sequence[int]
@@ -161,14 +161,14 @@ class PrefixCache:
             evicted.extend(self._evict_lru())
         self._entries[key] = PrefixEntry(key, tokens, pages)
         self.pages_held += len(pages)
-        _fm.PREFIX_INSERTS.inc()
+        _sm.PREFIX_INSERTS.inc()
         self._export_gauges()
         return True, evicted
 
     def _evict_lru(self) -> List[int]:
         _key, entry = self._entries.popitem(last=False)
         self.pages_held -= len(entry.pages)
-        _fm.PREFIX_EVICTIONS.inc()
+        _sm.PREFIX_EVICTIONS.inc()
         return entry.pages
 
     def flush(self) -> List[int]:
@@ -185,8 +185,8 @@ class PrefixCache:
         return pages
 
     def _export_gauges(self) -> None:
-        _fm.PREFIX_ENTRIES.set(len(self._entries))
-        _fm.PREFIX_PAGES.set(self.pages_held)
+        _sm.PREFIX_ENTRIES.set(len(self._entries))
+        _sm.PREFIX_PAGES.set(self.pages_held)
 
     def stats(self) -> Dict[str, int]:
         return {"entries": len(self._entries),

@@ -32,7 +32,10 @@ class BackpressureError(RuntimeError):
     """The serving stack cannot take more work RIGHT NOW (bounded queue
     full, or — via the :class:`~.page_pool.PagePoolExhausted` subclass — no
     KV pages left). Deliberately a distinct type: callers shed or retry;
-    it never signals a crash."""
+    it never signals a crash. ``fault_class`` is what
+    ``reliability.faults.classify`` tells it by."""
+
+    fault_class = "backpressure"
 
 
 class DrainingError(BackpressureError):

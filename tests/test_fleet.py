@@ -21,6 +21,7 @@ from paddle_tpu.fleet import metrics as fm
 from paddle_tpu.fleet.protocol import (MAX_FRAME, Binary, FrameReader,
                                        pack_pages, read_frame, send_frame,
                                        send_binary_frame, unpack_pages)
+from paddle_tpu.serving import metrics as sm
 
 _REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -43,7 +44,7 @@ class TestPrefixKey:
         toks = list(range(40, 72))
         out = subprocess.run(
             [sys.executable, "-c",
-             "from paddle_tpu.fleet.prefix_cache import prefix_key;"
+             "from paddle_tpu.serving.prefix_cache import prefix_key;"
              "print(prefix_key(range(40, 72)))"],
             cwd=_REPO, env=dict(os.environ, JAX_PLATFORMS="cpu",
                                 PYTHONHASHSEED="12345"),
@@ -108,17 +109,17 @@ class TestPrefixCache:
         assert c.pages_held == 0 and len(c) == 0 and c.flush() == []
 
     def test_counters_tick(self):
-        h0, m0 = fm.PREFIX_HITS.value, fm.PREFIX_MISSES.value
-        i0, e0 = fm.PREFIX_INSERTS.value, fm.PREFIX_EVICTIONS.value
+        h0, m0 = sm.PREFIX_HITS.value, sm.PREFIX_MISSES.value
+        i0, e0 = sm.PREFIX_INSERTS.value, sm.PREFIX_EVICTIONS.value
         c = PrefixCache(page_budget=1, page_size=4)
         c.insert([1, 2, 3, 4], [0])
         assert c.lookup([1, 2, 3, 4, 5]) is not None
         assert c.lookup([9, 9, 9, 9, 9]) is None
         c.insert([5, 6, 7, 8], [1])  # evicts the first
-        assert fm.PREFIX_HITS.value == h0 + 1
-        assert fm.PREFIX_MISSES.value == m0 + 1
-        assert fm.PREFIX_INSERTS.value == i0 + 2
-        assert fm.PREFIX_EVICTIONS.value == e0 + 1
+        assert sm.PREFIX_HITS.value == h0 + 1
+        assert sm.PREFIX_MISSES.value == m0 + 1
+        assert sm.PREFIX_INSERTS.value == i0 + 2
+        assert sm.PREFIX_EVICTIONS.value == e0 + 1
 
 
 # -- frame protocol -----------------------------------------------------------
@@ -748,12 +749,10 @@ class TestEnginePrefixCache:
                                   num_pages=16, prefix_cache_pages=16)
 
     def test_hit_skips_prefill_and_matches_cold_stream(self, tiny_model):
-        from paddle_tpu.serving import metrics as sm
-
         sys_prompt = list(range(1, 18))  # 17 tokens: 2 full pages cached
         eng = _prefix_engine(tiny_model)
         p0 = sm.PREFILL_COUNT.value
-        h0 = fm.PREFIX_HITS.value
+        h0 = sm.PREFIX_HITS.value
         r1 = eng.submit(sys_prompt + [30], 5, temperature=0.8, seed=11)
         eng.run()
         r2 = eng.submit(sys_prompt + [30], 5, temperature=0.8, seed=11)
@@ -761,7 +760,7 @@ class TestEnginePrefixCache:
         assert r1.state == r2.state == "finished"
         assert list(r2.tokens_out) == list(r1.tokens_out), \
             "a prefix hit changed the sampled stream"
-        assert fm.PREFIX_HITS.value == h0 + 1
+        assert sm.PREFIX_HITS.value == h0 + 1
         assert sm.PREFILL_COUNT.value == p0 + 1, \
             "the warm request still dispatched a full prefill"
         assert eng.page_accounting_ok()
@@ -772,23 +771,23 @@ class TestEnginePrefixCache:
         from paddle_tpu.reliability import FaultPlan, faults
 
         eng = _prefix_engine(tiny_model)
-        pk0 = fm.PREFIX_POISONED_SKIPPED.value
+        pk0 = sm.PREFIX_POISONED_SKIPPED.value
         plan = FaultPlan([faults.FaultSpec("serving.decode", "fatal",
                                            at=1, times=1)])
         with plan:
             bad = eng.submit(list(range(1, 18)), 5)
             eng.run(max_steps=50)
         assert bad.state == "failed"
-        assert fm.PREFIX_POISONED_SKIPPED.value > pk0
+        assert sm.PREFIX_POISONED_SKIPPED.value > pk0
         assert len(eng.prefix_cache) == 0, \
             "a FAILED request's pages entered the prefix cache"
         assert eng.page_accounting_ok() and eng.pool.num_used == 0
         # the poisoned prefix is structurally unservable: a fresh request
         # with the same prompt misses and re-prefills cleanly
-        h0 = fm.PREFIX_HITS.value
+        h0 = sm.PREFIX_HITS.value
         good = eng.submit(list(range(1, 18)), 3, seed=5)
         eng.run()
-        assert good.state == "finished" and fm.PREFIX_HITS.value == h0
+        assert good.state == "finished" and sm.PREFIX_HITS.value == h0
         eng.drain(10.0)
 
     def test_accounting_includes_cache_owned_pages(self, tiny_model):
@@ -956,8 +955,6 @@ class TestPagePayloadLayouts:
         shipped as (meta, blobs), and ingested by engine B serves the
         same request on B as a RESUME — zero prefill dispatches, the
         sampled stream bit-identical, page accounting intact on both."""
-        from paddle_tpu.serving import metrics as sm
-
         prompt = list(range(1, 18))  # 16 cached tokens = 2 pages of 8
         eng_a = _prefix_engine(tiny_model)
         eng_b = _prefix_engine(tiny_model)

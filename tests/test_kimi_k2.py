@@ -1,6 +1,6 @@
 """Kimi-K2's block (latent attention, a sigmoid-routed expert layer with a
 shared expert) through the serving stack, against its plain float32
-reference (``models/kimi_k2_reference.py``), at a toy size on the CPU: one
+reference (``grid/reference/kimi_k2.py``), at a toy size on the CPU: one
 dense and two expert layers, d 64, 4 heads, ``q_lora_rank`` 32, latent 16
 + 8 rotary, nope 16, v 16, 16 experts top-4 of width 32 and one shared,
 YaRN factor 32 over 64 positions, page 8. LOGITS are compared, never
@@ -17,17 +17,17 @@ the attention softmax by 2.1e-2 (``test_a_lower_precision_fails`` asks for
 ten times ``TOL`` of each), so none of them can hide inside it.
 """
 
-import os
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from grid.reference import kimi_k2 as ref
 from paddle_tpu import serving
 from paddle_tpu.flags import set_flag
+from paddle_tpu.models import blocks
 from paddle_tpu.models import kimi_k2 as kk
-from paddle_tpu.models import kimi_k2_reference as ref
 from paddle_tpu.ops import attention_ops, moe_ops
 from paddle_tpu.ops.pallas_kernels import mla_attention as mla
 from paddle_tpu.serving.kv_cache import LatentPagedCache
@@ -185,7 +185,7 @@ def test_absorbed_decode_equals_expanded_attention(toy, rng):
     cfg, lp = toy.cfg, toy.params["layers"][1]
     n = 12
     h = jnp.asarray(rng.randn(n, cfg.d_model).astype("float32"))
-    q_n, q_r, row = kk._latent(cfg, lp, h, jnp.arange(n))
+    q_n, q_r, row = blocks.latent(cfg, lp, h, jnp.arange(n))
     kv = (row[:, :cfg.kv_rank] @ lp["wkvb"]).reshape(n, cfg.n_head, -1)
     want = attention_ops.mla_causal_attention(
         jnp.concatenate([q_n, q_r], -1), kv[..., :cfg.d_nope],
@@ -359,15 +359,15 @@ def test_four_shares_and_one_shared_expert_add_up_to_the_whole_layer(toy,
     cfg, lp = toy.cfg, toy.params["layers"][2]
     x = jnp.asarray(rng.randn(9, cfg.d_model).astype("float32"))
     whole = np.asarray(ref._sparse(lp, x, 4, 2.827, 1e-6, tuple(range(16))))
-    shared = np.asarray(kk._swiglu(kk._rms(x, lp["g2"], 1e-6), lp["sg"],
-                                   lp["su"], lp["sd"]))
+    shared = np.asarray(blocks.swiglu(blocks.rms_norm(x, lp["g2"], 1e-6),
+                                      lp["sg"], lp["su"], lp["sd"]))
     total = np.asarray(x) + shared
     for c in range(4):
         held = tuple(range(4 * c, 4 * c + 4))
         part = {**lp, **{k: lp[k][np.asarray(held)] for k in ("wg", "wu",
                                                               "wd")}}
-        out, stats = kk._feed_forward(toy_cfg(experts_held=held), part, x,
-                                      None)
+        out, stats = blocks.routed_feed_forward(
+            toy_cfg(experts_held=held), part, x, None)
         assert int(stats["experts_touched"]) <= 4
         total += np.asarray(out) - np.asarray(x) - shared
         # the reference, given the same share, agrees with the program
@@ -492,15 +492,3 @@ def test_page_export_and_verify_are_refused_over_a_latent_cache(toy):
                  "speculative verify")):
             with pytest.raises(ValueError, match=what):
                 call()
-
-
-def test_the_benchmark_holds_a_copy_of_the_reference():
-    """``grid/reference/kimi_k2.py`` (the benchmark's, which a later PR may
-    not edit) and ``models/kimi_k2_reference.py`` (the program's, which
-    ``chip_smoke.py`` reads) are one text."""
-    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    with open(os.path.join(root, "grid", "reference", "kimi_k2.py")) as f:
-        grid_copy = f.read()
-    with open(os.path.join(root, "paddle_tpu", "models",
-                           "kimi_k2_reference.py")) as f:
-        assert f.read() == grid_copy

@@ -2,7 +2,7 @@
 a head-wise gate, a YaRN table with a partial rotation beside a plain one,
 a dense first layer, a scaled softmax router with a shared expert) through
 the serving stack, against its plain float32 reference
-(``models/laguna_reference.py``), at a toy size on the CPU: layers full
+(``grid/reference/laguna.py``), at a toy size on the CPU: layers full
 (dense), sliding, sliding, sliding, full; d 64, two KV heads of 16 lanes
 under 4 query heads on full layers and 6 on sliding ones (two query groups:
 2 and 3 a KV head), window 8, 8 experts top-3 of width 32 and one shared,
@@ -22,17 +22,17 @@ each), so none of them can hide inside it.
 """
 
 import math
-import os
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from grid.reference import laguna as ref
 from paddle_tpu import serving
 from paddle_tpu.flags import set_flag
+from paddle_tpu.models import blocks
 from paddle_tpu.models import laguna as lg
-from paddle_tpu.models import laguna_reference as ref
 from paddle_tpu.ops import attention_ops, moe_ops
 from paddle_tpu.ops.pallas_kernels import paged_attention as pa
 
@@ -290,8 +290,8 @@ def test_two_shares_and_one_shared_expert_add_up_to_the_whole_layer(toy, rng):
     cfg, lp = toy.cfg, toy.params["layers"][2]
     x = jnp.asarray(rng.randn(9, cfg.d_model).astype("float32"))
     whole = np.asarray(ref._sparse(lp, x, 3, 2.5, 1e-6, tuple(range(8))))
-    shared = np.asarray(lg._swiglu(lg._rms(x, lp["g2"], 1e-6), lp["sg"],
-                                   lp["su"], lp["sd"]))
+    shared = np.asarray(blocks.swiglu(blocks.rms_norm(x, lp["g2"], 1e-6),
+                                      lp["sg"], lp["su"], lp["sd"]))
     total = np.asarray(x) + shared
     pairs = 0
     for held in ((0, 1, 2, 3), (4, 5, 6, 7)):
@@ -398,7 +398,8 @@ def test_rotation_by_hand(toy, kind, rng):
             sn = math.sin(p * inv_freq[i]) * factor
             want[s, :, i] = x[s, :, i] * c - x[s, :, i + half] * sn
             want[s, :, i + half] = x[s, :, i + half] * c + x[s, :, i] * sn
-    got = np.asarray(lg._rope(jnp.asarray(x), jnp.asarray(pos), table))
+    got = np.asarray(blocks.rope_lanes(jnp.asarray(x), jnp.asarray(pos),
+                                       table))
     np.testing.assert_allclose(got, want, atol=1e-5, rtol=0)
     np.testing.assert_allclose(
         np.asarray(ref._rope(jnp.asarray(x), jnp.asarray(pos),
@@ -417,12 +418,12 @@ def test_a_closed_gate_halves_attention(toy, rng):
     lp = toy.params["layers"][1]
     h = jnp.asarray(rng.randn(5, 64).astype("float32"))
     o = jnp.asarray(rng.randn(5, 6, 16).astype("float32"))
-    half = lg._gated({**lp, "wgam": jnp.zeros_like(lp["wgam"])}, h, o)
+    half = blocks.gated({**lp, "wgam": jnp.zeros_like(lp["wgam"])}, h, o)
     np.testing.assert_allclose(np.asarray(half),
                                0.5 * np.asarray(o).reshape(5, -1), atol=1e-7)
     gamma = 1 / (1 + np.exp(-(np.asarray(h) @ np.asarray(lp["wgam"]))))
     np.testing.assert_allclose(
-        np.asarray(lg._gated(lp, h, o)),
+        np.asarray(blocks.gated(lp, h, o)),
         (np.asarray(o) * gamma[:, :, None]).reshape(5, -1), atol=1e-6)
     # through the whole model: the reference, given the same zero gate,
     # still agrees, and differs from the gated one
@@ -437,7 +438,7 @@ def test_a_closed_gate_halves_attention(toy, rng):
     assert np.abs(got - reference_rows(toy, seq, np.arange(12))).max() > 0.01
 
 
-# -- (f) what the groups cannot do, and the benchmark's copy --------------------
+# -- (f) what the groups cannot do ----------------------------------------------
 
 
 @pytest.mark.parametrize("kw,what", [
@@ -454,15 +455,3 @@ def test_what_two_groups_cannot_do_is_refused_at_construction(toy, kw, what):
         return
     with pytest.raises(ValueError, match=what):
         _engine(toy, **kw)
-
-
-def test_the_benchmark_holds_a_copy_of_the_reference():
-    """``grid/reference/laguna.py`` (the benchmark's, which a later PR may
-    not edit) and ``models/laguna_reference.py`` (the program's) are one
-    text."""
-    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    with open(os.path.join(root, "grid", "reference", "laguna.py")) as f:
-        grid_copy = f.read()
-    with open(os.path.join(root, "paddle_tpu", "models",
-                           "laguna_reference.py")) as f:
-        assert f.read() == grid_copy

@@ -1,7 +1,7 @@
 """Ling-3.0-flash-VL's language model (five KDA layers to each MLA layer,
 a group-limited sigmoid router with a shared expert) through the serving
 stack, against its plain float32 reference
-(``models/ling3_flash_reference.py``), at a toy size on the CPU: layers
+(``grid/reference/ling3_flash.py``), at a toy size on the CPU: layers
 KDA (dense), KDA, MLA, KDA; d 64, 4 heads, a 16 x 16 state a head, latent
 16 + 8 rotary, nope 16, v 16, 16 experts in 4 groups (2 stay) top-4 of
 width 32 and one shared, page 8. LOGITS are compared, never sampled
@@ -18,17 +18,17 @@ decay computed in bfloat16 by 1e-3 (``test_a_lower_precision_fails`` asks
 for ten times ``TOL`` of each), so neither can hide inside it.
 """
 
-import os
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from grid.reference import ling3_flash as ref
 from paddle_tpu import serving
 from paddle_tpu.flags import set_flag
+from paddle_tpu.models import blocks
 from paddle_tpu.models import ling3_flash as lf
-from paddle_tpu.models import ling3_flash_reference as ref
 from paddle_tpu.ops import moe_ops
 from paddle_tpu.ops.pallas_kernels import kda
 from paddle_tpu.serving.kv_cache import (LATENT, STATE, CacheGroup,
@@ -112,7 +112,7 @@ def test_prefill_equals_the_reference(toy, n, rng):
     assert kept[0][1].shape == (1, 3, 3 * 64)
     assert kept[2][0].shape == (1, 32, 16 + 8)
     # the tail is the last three inputs of the convolution BELOW the length
-    u = lf._rms(toy.params["tok_emb"][jnp.asarray(seq)],
+    u = blocks.rms_norm(toy.params["tok_emb"][jnp.asarray(seq)],
                 toy.params["layers"][0]["g1"], 1e-6) \
         @ toy.params["layers"][0]["wqkv"]
     np.testing.assert_allclose(np.asarray(kept[0][1][0]),
@@ -125,7 +125,7 @@ def test_each_layer_kind_alone_equals_the_reference(toy, rng):
     x = jnp.asarray(rng.randn(23, 64).astype("float32"))
     for i, kind in ((1, "kda"), (2, "mla")):
         lp = toy.params["layers"][i]
-        h = lf._rms(x, lp["g1"], cfg.rms_eps)
+        h = blocks.rms_norm(x, lp["g1"], cfg.rms_eps)
         if kind == "kda":
             y, _, _ = lf._kda_prefill(cfg, lp, h, 23)
             want = ref._kda(lp, x, 4, -5.0, 1e-6)
@@ -157,9 +157,9 @@ def test_a_lower_precision_fails(toy, what, rng, monkeypatch):
         monkeypatch.setattr(kda, "kda_chunk_scan",
                             lambda *a, **kw: scan(*a, chunk=8, **kw))
     else:
-        real = ref.log_decay
+        real = lf.log_decay
         monkeypatch.setattr(
-            ref, "log_decay",
+            lf, "log_decay",
             lambda z, a_log, lb: bf16(real(bf16(z), a_log, lb)))
     seq = rng.randint(0, 96, 23)
     logits, _ = _prefill(toy, seq)
@@ -434,20 +434,19 @@ def test_four_shares_and_one_shared_expert_add_up_to_the_whole_layer(toy,
     group of four experts each, every chip has the router and the shared
     expert. The routed parts of the four shares, with the shared expert
     counted ONCE, add up to the uncut reference's whole layer."""
-    from paddle_tpu.models.kimi_k2 import _feed_forward, _swiglu
-
     lp = toy.params["layers"][1]
     x = jnp.asarray(rng.randn(9, 64).astype("float32"))
     whole = np.asarray(ref._sparse(lp, x, 4, 4, 2, 2.5, 1e-6,
                                    tuple(range(16))))
-    shared = np.asarray(_swiglu(lf._rms(x, lp["g2"], 1e-6), lp["sg"],
-                                lp["su"], lp["sd"]))
+    shared = np.asarray(blocks.swiglu(blocks.rms_norm(x, lp["g2"], 1e-6),
+                                      lp["sg"], lp["su"], lp["sd"]))
     total = np.asarray(x) + shared
     for c in range(4):
         held = tuple(range(4 * c, 4 * c + 4))
         part = {**lp, **{k: lp[k][np.asarray(held)] for k in ("wg", "wu",
                                                               "wd")}}
-        out, stats = _feed_forward(toy_cfg(experts_held=held), part, x, None)
+        out, stats = blocks.routed_feed_forward(
+            toy_cfg(experts_held=held), part, x, None)
         assert int(stats["experts_touched"]) <= 4
         total += np.asarray(out) - np.asarray(x) - shared
         np.testing.assert_allclose(
@@ -571,16 +570,3 @@ def test_page_export_and_verify_are_refused_over_this_cache(toy):
                  "speculative verify")):
             with pytest.raises(ValueError, match=what + ".*state: state"):
                 call()
-
-
-def test_the_benchmark_holds_a_copy_of_the_reference():
-    """``grid/reference/ling3_flash.py`` (the benchmark's, which a later PR
-    may not edit) and ``models/ling3_flash_reference.py`` (the program's)
-    are one text."""
-    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    with open(os.path.join(root, "grid", "reference",
-                           "ling3_flash.py")) as f:
-        grid_copy = f.read()
-    with open(os.path.join(root, "paddle_tpu", "models",
-                           "ling3_flash_reference.py")) as f:
-        assert f.read() == grid_copy

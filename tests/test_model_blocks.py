@@ -1,0 +1,156 @@
+"""``models/blocks.py``: the tables a served model's config is built from,
+held against the benchmark's references at the PUBLISHED configurations'
+values, and the one contract class under each of the five served models.
+
+The tables were the references' own functions until PR 46 (the program
+imported them from its referee, so an error in one was on both sides of
+every comparison). They are now two texts, and this file is where the two
+meet: at the numbers of ``grid/configs/*.json``, which are the ones served.
+"""
+
+import importlib
+import json
+import os
+
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from grid.reference import kimi_k2 as kimi_ref
+from grid.reference import laguna as laguna_ref
+from grid.reference import ling3_flash as ling3_ref
+from grid.reference import motif3 as motif3_ref
+from paddle_tpu.models import blocks, ling3_flash
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+
+def published(name):
+    with open(os.path.join(ROOT, "grid", "configs", name + ".json")) as f:
+        return json.load(f)
+
+
+KIMI = published("kimi-k2-ep32-serve")
+MOTIF = published("motif-3-beta-ep16-serve")
+LAGUNA = published("laguna-s-ep2-serve")
+LING = published("ling-3-flash-ep4-serve")
+
+
+@pytest.mark.parametrize("want,rot,theta,scaling", [
+    (kimi_ref.yarn_inv_freq(KIMI["qk_rope_head_dim"], KIMI["rope_theta"],
+                            KIMI["rope_scaling"]),
+     KIMI["qk_rope_head_dim"], KIMI["rope_theta"], KIMI["rope_scaling"]),
+    (motif3_ref.rotary(MOTIF)[motif3_ref.FULL], MOTIF["qk_rope_head_dim"],
+     MOTIF["rope_theta"], MOTIF["rope_scaling"]),
+    (motif3_ref.rotary(MOTIF)[motif3_ref.RING], MOTIF["qk_rope_head_dim"],
+     MOTIF["swa_rope_theta"], None),
+], ids=["kimi-k2", "motif-3 full", "motif-3 window"])
+def test_yarn_inv_freq_is_the_references(want, rot, theta, scaling):
+    got = blocks.yarn_inv_freq(int(rot), float(theta), scaling)
+    assert got.dtype == np.float64 and got.shape == (rot // 2,)
+    np.testing.assert_allclose(got, np.asarray(want), rtol=1e-14, atol=0)
+    if scaling:      # the ramp lies inside the table: both ends are there
+        plain = float(theta) ** (-np.arange(rot // 2) * 2.0 / rot)
+        assert got[0] == plain[0]
+        assert abs(got[-1] * scaling["factor"] / plain[-1] - 1) < 1e-12
+    assert blocks.yarn_inv_freq.__code__ is not \
+        kimi_ref.yarn_inv_freq.__code__
+
+
+@pytest.mark.parametrize("model", [
+    KIMI, dict(MOTIF, qk_nope_head_dim=MOTIF["head_dim"]
+               - MOTIF["qk_rope_head_dim"])], ids=["kimi-k2", "motif-3"])
+def test_softmax_scale_is_the_references(model):
+    d_head = model["qk_nope_head_dim"] + model["qk_rope_head_dim"]
+    got = blocks.mla_softmax_scale(d_head, model["rope_scaling"])
+    assert got == pytest.approx(kimi_ref.softmax_scale(model), rel=1e-15)
+    # Kimi-K2 scales all dimensions (mscale^2 on the score), Motif-3 not
+    assert (got > d_head ** -0.5) == bool(
+        model["rope_scaling"].get("mscale_all_dim"))
+
+
+@pytest.mark.parametrize("kind", sorted(LAGUNA["rope_parameters"]))
+def test_rope_table_is_the_references(kind):
+    rope = LAGUNA["rope_parameters"][kind]
+    freq, factor = blocks.rope_table(LAGUNA["head_dim"], rope)
+    want_freq, want_factor = laguna_ref.rope_table(LAGUNA["head_dim"], rope)
+    np.testing.assert_allclose(freq, want_freq, rtol=1e-14, atol=0)
+    assert factor == pytest.approx(want_factor, rel=1e-15)
+    assert len(freq) == LAGUNA["head_dim"] * rope["partial_rotary_factor"] / 2
+    assert (factor == 1.0) == (rope["rope_type"] == "default")
+
+
+def test_rope_table_refuses_a_type_it_does_not_know():
+    with pytest.raises(ValueError, match="rope_type"):
+        blocks.rope_table(128, {"rope_type": "llama3", "rope_theta": 1e4})
+
+
+@pytest.mark.parametrize("what", ["log_decay", "l2"])
+def test_the_recurrence_helpers_are_the_references(what):
+    """Ling-3's gate and its q/k normalisation at the published lower
+    bound and head width, over pre-activations far out on both sides."""
+    rng = np.random.RandomState(7)
+    h, dk = 4, LING["head_dim"]
+    z = jnp.asarray(rng.randn(5, h, dk).astype("float32") * 8.0)
+    if what == "log_decay":
+        a_log = jnp.asarray(rng.randn(h).astype("float32"))
+        got = blocks.log_decay(z, a_log, float(LING["kda_lower_bound"]))
+        want = ling3_ref.log_decay(z, a_log, float(LING["kda_lower_bound"]))
+        assert float(got.max()) <= 0.0
+        assert float(got.min()) >= LING["kda_lower_bound"]
+    else:
+        got, want = blocks.l2_normalize(z), ling3_ref._l2(z)
+        np.testing.assert_allclose(np.asarray(jnp.sum(got * got, -1)), 1.0,
+                                   atol=1e-5)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=1e-6,
+                               atol=0)
+
+
+def test_the_layer_kinds_are_the_references_names():
+    assert (ling3_flash.KDA, ling3_flash.MLA) == (ling3_ref.KDA,
+                                                  ling3_ref.MLA)
+    assert set(LING["layer_types"]) == {ling3_flash.KDA, ling3_flash.MLA}
+
+
+SERVED = [("smallthinker", "SmallThinkerLM"), ("kimi_k2", "KimiK2LM"),
+          ("laguna", "LagunaLM"), ("ling3_flash", "Ling3FlashLM"),
+          ("motif3", "Motif3LM")]
+
+
+@pytest.mark.parametrize("module,cls", SERVED, ids=[m for m, _ in SERVED])
+def test_prefill_last_is_the_last_row_of_prefill(module, cls):
+    """The contract class, once, under each model at its tests' toy widths:
+    two prompts of different lengths in one bucket."""
+    model = importlib.import_module("test_" + module).toy_model()
+    served = getattr(importlib.import_module("paddle_tpu.models." + module),
+                     cls)
+    assert type(model) is served and issubclass(served, blocks.ServedLM)
+    for name in ("prefill", "prefill_last", "decode", "__init__"):
+        assert name not in vars(served), name      # written once, in blocks
+    assert not hasattr(model, "verify")            # speculation resolves off
+    rng = np.random.RandomState(3)
+    tokens = jnp.asarray(rng.randint(0, model.cfg.vocab_size, (2, 16)),
+                         jnp.int32)
+    lengths = jnp.asarray([5, 11], jnp.int32)
+    logits, kept = model.prefill(model.params, tokens, lengths)
+    last, kept_last = model.prefill_last(model.params, tokens, lengths)
+    assert logits.shape == (2, 16, model.cfg.vocab_size)
+    assert last.shape == (2, model.cfg.vocab_size)
+    assert len(kept) == len(kept_last) == model.cfg.n_layer
+    want = np.stack([np.asarray(logits[0, 4]), np.asarray(logits[1, 10])])
+    np.testing.assert_allclose(np.asarray(last), want, atol=1e-4, rtol=1e-5)
+
+
+def test_a_model_without_weights_draws_them_from_its_seed():
+    from test_smallthinker import toy_cfg
+
+    from paddle_tpu.models import smallthinker as st
+
+    cfg = toy_cfg(n_layer=1, rope_layout=[1], window_layout=[0])
+    got = st.SmallThinkerLM(cfg, seed=5).params
+    want = st.init_params(cfg, 5)
+    assert sorted(got) == ["gf", "head", "layers", "tok_emb"]
+    np.testing.assert_array_equal(np.asarray(got["layers"][0]["wq"]),
+                                  np.asarray(want["layers"][0]["wq"]))
+    assert np.any(np.asarray(got["head"]) != np.asarray(
+        st.init_params(cfg, 6)["head"]))

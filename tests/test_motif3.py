@@ -2,7 +2,7 @@
 the last W rows on three layers in four beside one page pool, four
 residual streams mixed by Sinkhorn matrices, PolyNorm experts) through the
 serving stack, against its plain float32 reference
-(``models/motif3_reference.py``), at a toy size on the CPU: layers window
+(``grid/reference/motif3.py``), at a toy size on the CPU: layers window
 (dense), window, full, window; d 64 x 4 streams, 10 query heads over 2 KV
 heads (8 signal, 2 noise), latent 16 + 8 rotary, nope 16, v 16, window 16,
 16 experts top-4 of width 32 and one shared, page 8. LOGITS are compared,
@@ -19,17 +19,16 @@ times that and far under what a lower precision gives
 """
 
 import functools
-import os
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from grid.reference import motif3 as ref
 from paddle_tpu import serving
 from paddle_tpu.flags import set_flag
 from paddle_tpu.models import motif3 as mf
-from paddle_tpu.models import motif3_reference as ref
 from paddle_tpu.ops import attention_ops, moe_ops
 from paddle_tpu.ops.pallas_kernels import expert_stream as es
 from paddle_tpu.ops.pallas_kernels import mla_attention as mla
@@ -404,7 +403,7 @@ def test_the_programs_polynorm_is_the_references(rng, departure):
     "original_max_position_embeddings": 4096, "factor": 64, "mscale": 1,
     "beta_fast": 32, "beta_slow": 1, "apply_yarn_scaling": False}])
 def test_the_programs_rotary_tables_are_the_references(scaling):
-    """The program takes its frequencies from ``models/kimi_k2_reference``
+    """The program makes its frequencies with ``blocks.yarn_inv_freq``
     and this model's reference has its own statement: a full layer YaRN's
     over ``rope_theta`` (its ramp inside the table at the published
     numbers), a window layer plain at ``swa_rope_theta``."""
@@ -664,15 +663,3 @@ def test_a_ring_call_of_eight_pages_equals_the_gather(case,
     assert np.all(np.isfinite(got))
     np.testing.assert_allclose(got, want, atol=1e-5, rtol=0)
     assert np.all(got[lens == 0] == 0)
-
-
-def test_the_benchmark_holds_a_copy_of_the_reference():
-    """``grid/reference/motif3.py`` (the benchmark's, which a later PR may
-    not edit) and ``models/motif3_reference.py`` (the program's) are one
-    text."""
-    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    with open(os.path.join(root, "grid", "reference", "motif3.py")) as f:
-        grid_copy = f.read()
-    with open(os.path.join(root, "paddle_tpu", "models",
-                           "motif3_reference.py")) as f:
-        assert f.read() == grid_copy

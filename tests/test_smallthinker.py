@@ -429,17 +429,3 @@ def test_one_group_is_the_same_cache(rng):
         from paddle_tpu.serving.kv_cache import CacheGroup
         PagedKVCache(3, 2, 8, 2, 32, 4, 9,
                      groups=[CacheGroup("global", (0, 2), None, 9)])
-
-
-def test_the_benchmark_holds_a_copy_of_the_reference():
-    """``grid/reference/smallthinker.py`` (the benchmark's, which a later
-    PR may not edit) and ``models/smallthinker_reference.py`` (the
-    program's, which ``chip_smoke.py`` reads) are one text."""
-    import os
-
-    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    with open(os.path.join(root, "grid", "reference", "smallthinker.py")) as f:
-        grid_copy = f.read()
-    with open(os.path.join(root, "paddle_tpu", "models",
-                           "smallthinker_reference.py")) as f:
-        assert f.read() == grid_copy

@@ -52,9 +52,9 @@ MODULES = [
     "paddle_tpu.inference",
     "paddle_tpu.serving",
     "paddle_tpu.serving.phases",
+    "paddle_tpu.serving.prefix_cache",
     "paddle_tpu.fleet",
     "paddle_tpu.fleet.autopsy",
-    "paddle_tpu.fleet.prefix_cache",
     "paddle_tpu.fleet.protocol",
     "paddle_tpu.fleet.replica",
     "paddle_tpu.fleet.router",
