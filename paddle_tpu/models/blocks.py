@@ -31,12 +31,15 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..ops import moe_ops
+from ..ops.pallas_kernels import kda as kda_ops
 
-__all__ = ["ServedLM", "absorbed_output", "absorbed_query", "gated",
-           "head", "held_experts", "l2_normalize", "latent", "log_decay",
-           "mla_softmax_scale", "moe_stats", "rms_norm", "rope", "rope_lanes",
-           "rope_table", "routed_feed_forward", "seeded_params", "swiglu",
-           "yarn_inv_freq"]
+__all__ = ["ServedLM", "absorbed_output", "absorbed_query", "at_precision",
+           "gated", "head", "held_experts", "kda_inputs", "kda_output",
+           "kda_prefill",
+           "l2_normalize", "latent", "log_decay", "maps_precision", "mix_in",
+           "mix_out", "mla_softmax_scale", "moe_stats", "rms_norm", "rope",
+           "rope_lanes", "rope_table", "routed_feed_forward", "seeded_params",
+           "swiglu", "yarn_inv_freq"]
 
 
 # -- host-side tables a config is built from ----------------------------------
@@ -212,6 +215,134 @@ def absorbed_output(cfg, wkvb, o_lat):
     return a.astype(o_lat.dtype).reshape(o_lat.shape[0], -1)
 
 
+# -- a KDA layer's half (Ling-3.0-flash, GLM-5.3-Flash) ------------------------
+
+def kda_inputs(cfg, lp, h, taps):
+    """What a KDA layer's recurrence reads at each position (``cfg`` gives
+    ``n_head``, ``d_state``, ``lower_bound``): ``h`` [..., d] the
+    normed input, ``taps`` the convolution's inputs there, oldest first,
+    each [..., 3C]. Returns ``(q, k [..., H, dk], v [..., H, dv], a [...,
+    H, dk] float32, beta [..., H] float32)``."""
+    f32 = jnp.float32
+    lead = h.shape[:-1]
+    cw = lp["cw"].astype(f32)
+    c = jax.nn.silu(sum(x.astype(f32) * cw[j] for j, x in enumerate(taps)))
+    q, k, v = (t.reshape(lead + (cfg.n_head, cfg.d_state))
+               for t in jnp.split(c, 3, axis=-1))
+    q = l2_normalize(q) * cfg.d_state ** -0.5
+    # the decay's projection whole (``wa``) or through a low rank
+    z = jnp.dot(h, lp["wa"], preferred_element_type=f32) if "wa" in lp \
+        else jnp.dot(h @ lp["wa1"], lp["wa2"], preferred_element_type=f32)
+    z = (z + lp["dt_bias"]).reshape(lead + (cfg.n_head, cfg.d_state))
+    a = log_decay(z, lp["a_log"], cfg.lower_bound)
+    beta = jax.nn.sigmoid(jnp.dot(h, lp["wb"], preferred_element_type=f32))
+    return (q.astype(h.dtype), l2_normalize(k).astype(h.dtype),
+            v.astype(h.dtype), a, beta)
+
+
+def kda_output(cfg, lp, h, o):
+    """``(RMSNorm_head(o; gn) * gate_head) Wo`` of ``o`` [..., H, dv]
+    float32."""
+    gn = lp["gn"].reshape(cfg.n_head, cfg.d_state)
+    return gated(lp, h, rms_norm(o, gn, cfg.rms_eps).astype(h.dtype)
+                 ) @ lp["wo"]
+
+
+def kda_prefill(cfg, lp, h, length):
+    """One sequence's KDA half: ``h`` [S, d] normed, ``length`` its valid
+    rows. Returns ``(y [S, d], state [H, dk, dv] float32, tail [taps - 1,
+    3C])``: the state and the convolution inputs the first ``length``
+    tokens leave."""
+    s = h.shape[0]
+    rows = cfg.conv_taps - 1
+    u = h @ lp["wqkv"]
+    up = jnp.pad(u, ((rows, 0), (0, 0)))
+    q, k, v, a, beta = kda_inputs(
+        cfg, lp, h, [up[j:j + s] for j in range(cfg.conv_taps)])
+    valid = jnp.arange(s) < length
+    a = jnp.where(valid[:, None, None], a, 0.0)
+    beta = jnp.where(valid[:, None], beta, 0.0)
+    o, state = kda_ops.kda_chunk_scan(q, k, v, a, beta)
+    tail = jax.lax.dynamic_slice_in_dim(up, length, rows, axis=0)
+    return kda_output(cfg, lp, h, o), state, tail
+
+
+# -- four residual streams (Motif-3, GLM-5.3-Flash) ---------------------------
+
+def at_precision(x, dtype):
+    """``x`` at ``dtype``'s precision, in its own type (itself where they
+    are one): ``reduce_precision``, because the chip's compiler elides a
+    pair of converts. What a precision CONTROL lowers (a configuration's
+    ``maps_dtype``, ``row_dtype``, ``index_dtype``); the configurations
+    as stated pass through."""
+    if jnp.dtype(dtype) == x.dtype:
+        return x
+    info = jnp.finfo(dtype)
+    return jax.lax.reduce_precision(x, info.nexp, info.nmant)
+
+
+def maps_precision(cfg, x):
+    """``x`` (float32) at ``cfg.maps_dtype``'s precision."""
+    return at_precision(x, cfg.maps_dtype)
+
+
+def mix_in(cfg, lp, which: str, x, g):
+    """A half's input from the streams ``x`` [n, ..., d]: ``(RMSNorm(H_pre
+    X; g) [..., d], H_post [..., n], H_res [..., n, n])``, the maps in
+    float32 whatever the streams' type. The streams lie stream-major, so
+    that each is whole lane tiles of its own (four rows of a ``[.., 4,
+    d]`` array fill a quarter of a bfloat16 tile's sixteen) and ``z Phi``
+    is the sum of the streams' own products: no ``[.., 4 d]`` row is
+    built."""
+    with jax.named_scope("residual/mhc"):
+        f32 = jnp.float32
+        n, d = cfg.n_stream, x.shape[-1]
+        xf = [x[m].astype(f32) for m in range(n)]
+        inv = jax.lax.rsqrt(
+            sum(jnp.sum(t * t, axis=-1, keepdims=True) for t in xf)
+            / (n * d) + cfg.rms_eps)
+        phi = lp["p" + which].astype(f32).reshape(n, d, -1)
+        m = maps_precision(cfg, sum(
+            jnp.dot(maps_precision(cfg, t * inv), phi[j],
+                    precision=jax.lax.Precision.HIGHEST)
+            for j, t in enumerate(xf)))
+        alpha, bias = lp["a" + which], lp["b" + which]
+        h_pre = maps_precision(cfg, jax.nn.sigmoid(alpha[0] * m[..., :n] + bias[:n]))
+        h_post = 2.0 * jax.nn.sigmoid(alpha[1] * m[..., n:2 * n]
+                                      + bias[n:2 * n])
+        r = (alpha[2] * m[..., 2 * n:] + bias[2 * n:]).reshape(
+            m.shape[:-1] + (n, n))
+        mat = maps_precision(cfg, jnp.exp(maps_precision(cfg, r)))
+        def total(t, axis):
+            # ``hc_eps`` in the divisions where the configuration has one
+            t = jnp.sum(t, axis=axis, keepdims=True)
+            return t + cfg.sinkhorn_eps if cfg.sinkhorn_eps else t
+
+        for _ in range(cfg.sinkhorn_iters):
+            mat = maps_precision(cfg, mat / total(mat, -1))
+            mat = maps_precision(cfg, mat / total(mat, -2))
+        u = sum(h_pre[..., j, None] * t for j, t in enumerate(xf))
+        return rms_norm(u.astype(x.dtype), g, cfg.rms_eps), h_post, mat
+
+
+def mix_out(cfg, x, y, h_post, h_res):
+    """``H_res X + H_post^T y`` [n, ..., d] in float32, each stream
+    rounded once to the streams' type; ``y`` [..., d] clamped where the
+    configuration publishes a clamp (``cfg.hidden_clamp``)."""
+    with jax.named_scope("residual/mhc"):
+        f32 = jnp.float32
+        n = cfg.n_stream
+        y = y.astype(f32)
+        if cfg.hidden_clamp is not None:
+            y = jnp.clip(y, -cfg.hidden_clamp, cfg.hidden_clamp)
+        xf = [x[m].astype(f32) for m in range(n)]
+        h_post = maps_precision(cfg, h_post)
+        return jnp.stack([
+            (sum(h_res[..., i, j, None] * xf[j] for j in range(n))
+             + h_post[..., i, None] * y).astype(x.dtype)
+            for i in range(n)])
+
+
 def held_experts(cfg):
     """``ops.moe_ops.expert_layer``'s ``held``: None where every expert is
     here, else the global ids of the share in ``wg``/``wu``/``wd``."""
@@ -299,7 +430,10 @@ class ServedLM:
       int arrays a step that the engine feeds to the ``serving/*``
       histograms of the same names: ``moe_experts_touched``,
       ``moe_max_expert_rows``, ``moe_held_pairs`` [expert layers],
-      ``state_slots_stepped``, ``attn_rows_read.<group>``;
+      ``state_slots_stepped``, ``attn_rows_read.<group>``,
+      ``attn_rows_context.<group>``, ``index_blocks_scored``; a name
+      without a histogram (a probe) rides to ``engine.last_decode_stats``
+      only;
     * ``verify`` (optional; ``hasattr``): scores a window of drafted tokens
       for speculative decoding. Absent, as on every model of this class,
       every speculation setting resolves off: a ring, a latent row and a
@@ -326,6 +460,11 @@ class ServedLM:
     * ``slot_state``: ``(heads, dk, dv, tail rows, tail width)`` that each
       layer of a ``STATE`` group keeps a SLOT, and no pages. Absent: the
       model has no state group;
+    * ``index_row``: ``(rows a block, lanes of an index key, blocks a
+      query reads)`` of a latent cache whose layers choose the rows a
+      query reads: the cache then keeps a pooled index key a block beside
+      the rows, through the same page table, and the open block's raw
+      keys a slot (``LatentPagedCache(index=)``). Absent: no index;
     * ``experts_held`` (with ``n_expert``, ``top_k``): the global ids of
       the routed experts held here, from which the engine tells the form
       of an executable's grouped product. Absent: no expert layer.

@@ -43,8 +43,8 @@ from ..ops import attention_ops
 from ..ops.pallas_kernels import kda as kda_ops
 from ..serving.kv_cache import LATENT, STATE
 from .blocks import (ServedLM, absorbed_output, absorbed_query, gated, head,
-                     l2_normalize, latent, log_decay, moe_stats, rms_norm,
-                     routed_feed_forward, seeded_params)
+                     kda_inputs, kda_output, kda_prefill, latent, moe_stats,
+                     rms_norm, routed_feed_forward, seeded_params)
 
 __all__ = ["Ling3FlashConfig", "Ling3FlashLM", "init_params"]
 
@@ -209,53 +209,6 @@ def init_params(cfg: Ling3FlashConfig, seed) -> Dict:
         lambda i: (cfg.layer_types[i], i in cfg.dense_layers))
 
 
-def _kda_inputs(cfg, lp, h, taps):
-    """What the recurrence reads at each position: ``h`` [..., d] the
-    normed input, ``taps`` the convolution's inputs there, oldest first,
-    each [..., 3C]. Returns ``(q, k [..., H, dk], v [..., H, dv], a [...,
-    H, dk] float32, beta [..., H] float32)``."""
-    f32 = jnp.float32
-    lead = h.shape[:-1]
-    cw = lp["cw"].astype(f32)
-    c = jax.nn.silu(sum(x.astype(f32) * cw[j] for j, x in enumerate(taps)))
-    q, k, v = (t.reshape(lead + (cfg.n_head, cfg.d_state))
-               for t in jnp.split(c, 3, axis=-1))
-    q = l2_normalize(q) * cfg.d_state ** -0.5
-    z = (jnp.dot(h, lp["wa"], preferred_element_type=f32) + lp["dt_bias"]
-         ).reshape(lead + (cfg.n_head, cfg.d_state))
-    a = log_decay(z, lp["a_log"], cfg.lower_bound)
-    beta = jax.nn.sigmoid(jnp.dot(h, lp["wb"], preferred_element_type=f32))
-    return (q.astype(h.dtype), l2_normalize(k).astype(h.dtype),
-            v.astype(h.dtype), a, beta)
-
-
-def _kda_output(cfg, lp, h, o):
-    """``(RMSNorm_head(o; gn) * gate_head) Wo`` of ``o`` [..., H, dv]
-    float32."""
-    gn = lp["gn"].reshape(cfg.n_head, cfg.d_state)
-    return gated(lp, h, rms_norm(o, gn, cfg.rms_eps).astype(h.dtype)
-                 ) @ lp["wo"]
-
-
-def _kda_prefill(cfg, lp, h, length):
-    """One sequence's KDA half: ``h`` [S, d] normed, ``length`` its valid
-    rows. Returns ``(y [S, d], state [H, dk, dv] float32, tail [taps - 1,
-    3C])``: the state and the convolution inputs the first ``length``
-    tokens leave."""
-    s = h.shape[0]
-    rows = cfg.conv_taps - 1
-    u = h @ lp["wqkv"]
-    up = jnp.pad(u, ((rows, 0), (0, 0)))
-    q, k, v, a, beta = _kda_inputs(
-        cfg, lp, h, [up[j:j + s] for j in range(cfg.conv_taps)])
-    valid = jnp.arange(s) < length
-    a = jnp.where(valid[:, None, None], a, 0.0)
-    beta = jnp.where(valid[:, None], beta, 0.0)
-    o, state = kda_ops.kda_chunk_scan(q, k, v, a, beta)
-    tail = jax.lax.dynamic_slice_in_dim(up, length, rows, axis=0)
-    return _kda_output(cfg, lp, h, o), state, tail
-
-
 def _mla_prefill(cfg, lp, h, pos):
     """One sequence's MLA half, K and V EXPANDED from the latent: ``(y [S,
     d], row [S, rank + rope])``."""
@@ -284,7 +237,7 @@ def prefill_forward(params: Dict, cfg: Ling3FlashConfig, tokens, lengths):
         h = rms_norm(x, lp["g1"], cfg.rms_eps)
         if kind == KDA:
             with jax.named_scope("attn/kda"):
-                ys, *keep = zip(*(_kda_prefill(cfg, lp, h[j], lengths[j])
+                ys, *keep = zip(*(kda_prefill(cfg, lp, h[j], lengths[j])
                                   for j in range(b)))
         else:
             ys, *keep = zip(*(_mla_prefill(cfg, lp, h[j], pos)
@@ -313,11 +266,11 @@ def decode_forward(params: Dict, cfg: Ling3FlashConfig, cache, cache_ops,
                 window, cache = cache_ops.tail_step(cache, i, h @ lp["wqkv"],
                                                     active)
                 o, cache = cache_ops.state_step(
-                    cache, i, *_kda_inputs(
+                    cache, i, *kda_inputs(
                         cfg, lp, h,
                         [window[:, j] for j in range(cfg.conv_taps)]),
                     active)
-                x = x + _kda_output(cfg, lp, h, o)
+                x = x + kda_output(cfg, lp, h, o)
         else:
             q_n, q_r, row = latent(cfg, lp, h, pos)
             cache = cache_ops.write_token(cache, i, row, pos, active)

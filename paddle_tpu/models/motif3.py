@@ -45,8 +45,9 @@ import jax.numpy as jnp
 
 from ..ops import attention_ops, moe_ops
 from ..serving.kv_cache import LATENT
-from .blocks import (ServedLM, head, held_experts, latent, moe_stats,
-                     rms_norm, seeded_params, yarn_inv_freq)
+from .blocks import (ServedLM, head, held_experts, latent, maps_precision,
+                     mix_in, mix_out, moe_stats, rms_norm, seeded_params,
+                     yarn_inv_freq)
 
 __all__ = ["Motif3Config", "Motif3LM", "init_params", "poly_norm"]
 
@@ -134,6 +135,7 @@ class Motif3Config:
         self.n_stream = int(n_stream)
         self.sinkhorn_iters = int(sinkhorn_iters)
         self.hidden_clamp = float(hidden_clamp)
+        self.sinkhorn_eps = 0.0     # the iterations divide by the sums alone
         self.rms_eps = float(rms_eps)
         self.max_seq = int(max_seq)
         self.dtype = jnp.dtype(dtype)
@@ -247,66 +249,6 @@ def init_params(cfg: Motif3Config, seed) -> Dict:
                          lambda i: (i in cfg.dense_layers,))
 
 
-def _lower(cfg, x):
-    """``x`` (float32) at ``cfg.maps_dtype``'s precision: itself where that
-    is float32. ``reduce_precision``, because the chip's compiler elides a
-    pair of converts."""
-    if cfg.maps_dtype == jnp.float32:
-        return x
-    info = jnp.finfo(cfg.maps_dtype)
-    return jax.lax.reduce_precision(x, info.nexp, info.nmant)
-
-
-def _mix_in(cfg, lp, which: str, x, g):
-    """A half's input from the streams ``x`` [n, ..., d]: ``(RMSNorm(H_pre
-    X; g) [..., d], H_post [..., n], H_res [..., n, n])``, the maps in
-    float32 whatever the streams' type. The streams lie stream-major, so
-    that each is whole lane tiles of its own (four rows of a ``[.., 4,
-    d]`` array fill a quarter of a bfloat16 tile's sixteen) and ``z Phi``
-    is the sum of the streams' own products: no ``[.., 4 d]`` row is
-    built."""
-    with jax.named_scope("residual/mhc"):
-        f32 = jnp.float32
-        n, d = cfg.n_stream, x.shape[-1]
-        xf = [x[m].astype(f32) for m in range(n)]
-        inv = jax.lax.rsqrt(
-            sum(jnp.sum(t * t, axis=-1, keepdims=True) for t in xf)
-            / (n * d) + cfg.rms_eps)
-        phi = lp["p" + which].astype(f32).reshape(n, d, -1)
-        m = _lower(cfg, sum(
-            jnp.dot(_lower(cfg, t * inv), phi[j],
-                    precision=jax.lax.Precision.HIGHEST)
-            for j, t in enumerate(xf)))
-        alpha, bias = lp["a" + which], lp["b" + which]
-        h_pre = _lower(cfg, jax.nn.sigmoid(alpha[0] * m[..., :n] + bias[:n]))
-        h_post = 2.0 * jax.nn.sigmoid(alpha[1] * m[..., n:2 * n]
-                                      + bias[n:2 * n])
-        r = (alpha[2] * m[..., 2 * n:] + bias[2 * n:]).reshape(
-            m.shape[:-1] + (n, n))
-        mat = _lower(cfg, jnp.exp(_lower(cfg, r)))
-        for _ in range(cfg.sinkhorn_iters):
-            mat = _lower(cfg, mat / jnp.sum(mat, axis=-1, keepdims=True))
-            mat = _lower(cfg, mat / jnp.sum(mat, axis=-2, keepdims=True))
-        u = sum(h_pre[..., j, None] * t for j, t in enumerate(xf))
-        return rms_norm(u.astype(x.dtype), g, cfg.rms_eps), h_post, mat
-
-
-def _mix_out(cfg, x, y, h_post, h_res):
-    """``H_res X + H_post^T y`` [n, ..., d] in float32, each stream
-    rounded once to the streams' type; ``y`` [..., d] clamped as
-    published."""
-    with jax.named_scope("residual/mhc"):
-        f32 = jnp.float32
-        n = cfg.n_stream
-        y = jnp.clip(y.astype(f32), -cfg.hidden_clamp, cfg.hidden_clamp)
-        xf = [x[m].astype(f32) for m in range(n)]
-        h_post = _lower(cfg, h_post)
-        return jnp.stack([
-            (sum(h_res[..., i, j, None] * xf[j] for j in range(n))
-             + h_post[..., i, None] * y).astype(x.dtype)
-            for i in range(n)])
-
-
 def _kv_weights(cfg, wkvb):
     return wkvb.reshape(cfg.kv_rank, cfg.n_kv_head, cfg.d_nope + cfg.d_v)
 
@@ -341,7 +283,7 @@ def _combine(cfg, lp, h, o):
     with jax.named_scope("attn/diff_combine"):
         lam = jax.nn.sigmoid(jnp.dot(h, lp["wlam"],
                                      preferred_element_type=jnp.float32))
-        return attention_ops.differential_combine(o, _lower(cfg, lam),
+        return attention_ops.differential_combine(o, maps_precision(cfg, lam),
                                                   cfg.n_kv_head)
 
 
@@ -421,14 +363,14 @@ def prefill_forward(params: Dict, cfg: Motif3Config, tokens, lengths):
     valid = (pos[None] < lengths[:, None]).reshape(b * s)
     rows = []
     for lp, kind in zip(params["layers"], cfg.layer_types):
-        h, h_post, h_res = _mix_in(cfg, lp, "a", x, lp["g1"])
+        h, h_post, h_res = mix_in(cfg, lp, "a", x, lp["g1"])
         ys, row = zip(*(_gdla_prefill(cfg, lp, kind, h[j], pos)
                         for j in range(b)))
         rows.append((jnp.stack(row),))
-        x = _mix_out(cfg, x, jnp.stack(ys), h_post, h_res)
-        u, h_post, h_res = _mix_in(cfg, lp, "m", x, lp["g2"])
+        x = mix_out(cfg, x, jnp.stack(ys), h_post, h_res)
+        u, h_post, h_res = mix_in(cfg, lp, "m", x, lp["g2"])
         y, _ = _feed_forward(cfg, lp, u.reshape(b * s, -1), valid)
-        x = _mix_out(cfg, x, y.reshape(b, s, -1), h_post, h_res)
+        x = mix_out(cfg, x, y.reshape(b, s, -1), h_post, h_res)
     return jnp.sum(x.astype(jnp.float32), axis=0).astype(x.dtype), rows
 
 
@@ -441,7 +383,7 @@ def decode_forward(params: Dict, cfg: Motif3Config, cache, cache_ops,
     x = _streams(params, cfg, tokens)
     stats = []
     for i, (lp, kind) in enumerate(zip(params["layers"], cfg.layer_types)):
-        h, h_post, h_res = _mix_in(cfg, lp, "a", x, lp["g1"])
+        h, h_post, h_res = mix_in(cfg, lp, "a", x, lp["g1"])
         q_n, q_r, row = latent(cfg.latent_of[kind], lp, h, pos)
         cache = cache_ops.write_token(cache, i, row, pos, active)
         with jax.named_scope("attn/gdla_full" if kind == FULL
@@ -451,10 +393,10 @@ def decode_forward(params: Dict, cfg: Motif3Config, cache, cache_ops,
                 pos + 1, active, sm_scale=cfg.sm_scale)
         y_lat = _combine(cfg, lp, h, o_lat)
         y = _attn_out(lp, h, absorbed_output(cfg, lp["wkvb"], y_lat))
-        x = _mix_out(cfg, x, y, h_post, h_res)
-        u, h_post, h_res = _mix_in(cfg, lp, "m", x, lp["g2"])
+        x = mix_out(cfg, x, y, h_post, h_res)
+        u, h_post, h_res = mix_in(cfg, lp, "m", x, lp["g2"])
         y, st = _feed_forward(cfg, lp, u, active)
-        x = _mix_out(cfg, x, y, h_post, h_res)
+        x = mix_out(cfg, x, y, h_post, h_res)
         if st is not None:
             stats.append(st)
     x = jnp.sum(x.astype(jnp.float32), axis=0).astype(x.dtype)

@@ -621,23 +621,137 @@ def mla_causal_attention(q, k_nope, k_rope, v, sm_scale=1.0):
         return o.astype(q.dtype)
 
 
-def mla_decode_attention(q, ctx_rows, ctx_len, rank: int, sm_scale=1.0):
+def mla_decode_attention(q, ctx_rows, ctx_len, rank: int, sm_scale=1.0,
+                         row_valid=None):
     """Single-position latent attention, ABSORBED, over gathered rows:
     ``q`` [B, H, W] (each head's absorbed query over the row's lanes),
     ``ctx_rows`` [B, L, W] (every head reads the same rows), ``ctx_len``
     [B]. Scores over all W lanes, the weighted sum over the first ``rank``
     (the latent): [B, H, rank]. The XLA path the latent paged kernel
     (ops/pallas_kernels/mla_attention.py) replaces, with the same masking
-    constant and a float32 softmax."""
+    constant and a float32 softmax. ``row_valid`` [B, L] bool (the sparse
+    read): of the rows below the length, those that count."""
     sc = jnp.einsum("bhw,blw->bhl", q, ctx_rows,
                     preferred_element_type=jnp.float32) * sm_scale
     mask = jnp.arange(ctx_rows.shape[1])[None, None, :] \
         < ctx_len[:, None, None]
+    if row_valid is not None:
+        mask = mask & row_valid[:, None, :]
     sc = jnp.where(mask, sc, neg_inf(jnp.float32))
     p = jax.nn.softmax(sc, axis=-1)
     o = jnp.einsum("bhl,blr->bhr", p.astype(ctx_rows.dtype),
                    ctx_rows[..., :rank], preferred_element_type=jnp.float32)
     return o.astype(q.dtype)
+
+
+# -- learned sparse attention (DeepSeek's lightning indexer over pooled keys) --
+
+def dsa_index_scores(q_idx, w_idx, keys, closed):
+    """The index scores of one position a slot: ``q_idx`` [B, Hi, L] the
+    index queries, ``w_idx`` [B, Hi] float32 their weights, ``keys`` one
+    pooled index key of L lanes a block: [N, L] (the same for every
+    query), [B, N, L], or PACKED [B, P, G * L] (a cache page's G keys side
+    by side in one row, block ``p G + g`` in lanes ``g L..``: scored a
+    lane slice at a time, so that the gathered rows are never re-laid),
+    ``closed`` [B] the blocks that may be scored (those before it are
+    closed). ``I(b) = sum_j w_j ReLU(q_j . K_b)`` [B, N] float32, the
+    masking constant at and past ``closed``."""
+    with jax.named_scope("attn/dsa_index"):
+        lanes = q_idx.shape[-1]
+        w = w_idx.astype(jnp.float32)
+
+        def scored(k):
+            sc = jnp.einsum("bhl,nl->bhn" if k.ndim == 2 else "bhl,bnl->bhn",
+                            q_idx, k, preferred_element_type=jnp.float32)
+            return jnp.einsum("bh,bhn->bn", w, jax.nn.relu(sc))
+
+        packed = keys.shape[-1] // lanes
+        if packed == 1:
+            score = scored(keys)
+        else:
+            score = jnp.stack(
+                [scored(keys[..., g * lanes:(g + 1) * lanes])
+                 for g in range(packed)], axis=-1).reshape(keys.shape[0], -1)
+        live = jnp.arange(score.shape[-1])[None, :] < closed[:, None]
+        return jnp.where(live, score, neg_inf(jnp.float32))
+
+
+def dsa_select(scores, own_block, top_blocks: int):
+    """The blocks a query reads: ``scores`` [B, N] (:func:`dsa_index_scores`:
+    the masking constant where a block may not be chosen), ``own_block``
+    [B] the block the query's position lies in, always read. The
+    ``top_blocks - 1`` scored blocks of highest score join it (every one
+    where there are fewer), a tie going to the lower block
+    (``lax.top_k``'s order). Returns ``(chosen [B, N] bool, picked [B,
+    top_blocks - 1] int32)``: ``picked`` the scored blocks chosen,
+    ascending, -1 where there were fewer."""
+    with jax.named_scope("attn/dsa_select"):
+        b, n = scores.shape
+        k = min(int(top_blocks) - 1, n)
+        low = neg_inf(jnp.float32)
+        vals, idx = jax.lax.top_k(scores, k)
+        # no scatter of the k indices (the chip takes them one at a time):
+        # a block is chosen where it scores above the k-th, and of those
+        # that score the same as the k-th, the lower ones that still fit
+        kth = vals[:, -1:]
+        above = scores > kth
+        tie = (scores == kth) & (scores > low)
+        room = k - jnp.sum(above, axis=-1, keepdims=True)
+        chosen = above | (tie & (jnp.cumsum(tie, axis=-1) <= room))
+        blocks = jnp.arange(n, dtype=jnp.int32)[None, :]
+        chosen = chosen | (blocks == own_block[:, None])
+        picked = jnp.sort(jnp.where(vals > low, idx.astype(jnp.int32), n),
+                          axis=-1)
+        return chosen, jnp.where(picked < n, picked, -1)
+
+
+def dsa_causal_attention(q, k, v, q_idx, w_idx, k_pool, kpool: int,
+                         top_blocks: int, sm_scale=1.0, block_q: int = 256):
+    """Causal attention of ONE sequence in which every query row reads the
+    rows its indexer chose: ``q``/``k`` [S, H, D], ``v`` [S, H, Dv]
+    EXPANDED; ``q_idx`` [S, Hi, L], ``w_idx`` [S, Hi] the rows' index
+    queries and weights, ``k_pool`` [S / kpool, L] one pooled index key a
+    block of ``kpool`` rows. Row t reads the rows <= t of its own block and
+    the ``top_blocks - 1`` blocks of highest index score among those CLOSED
+    before its own (all of them where there are fewer; :func:`dsa_select`'s
+    rule). A selection depends on its query, so the mask is a ROW's own:
+    the scores of ``block_q`` query rows against the whole sequence are
+    held at a time, never the [S, S] of all (on a v5e at S = 8,192 and 64
+    heads of 256: 131, 106 and 93 ms a layer at 128, 256 and 512 rows a
+    block; PERF.md, PR 47). Returns [S, H, Dv]."""
+    s = q.shape[0]
+    bq = _divisor_block(block_q, s, s)
+    cols = jnp.arange(s)[None, :]
+
+    def rows_of(args):
+        i, qb, qib, wib = args
+        rows = i * bq + jnp.arange(bq)
+        own = rows // kpool
+        chosen, _ = dsa_select(
+            dsa_index_scores(qib, wib, k_pool, own), own, top_blocks)
+        with jax.named_scope("attn/dsa_sparse"):
+            mask = jnp.repeat(chosen, kpool, axis=1) & (cols <= rows[:, None])
+            sc = jnp.einsum("qhd,khd->hqk", qb, k,
+                            preferred_element_type=jnp.float32) * sm_scale
+            sc = jnp.where(mask[None], sc, neg_inf(jnp.float32))
+            # the softmax by hand, its maximum behind a barrier: left to
+            # the compiler, the maximum and its broadcast over the S keys
+            # become ONE reduce-window of 2 S - 1 taps a score (23 ms a
+            # block of 128 rows at S = 8,192 on a v5e, 1.5 s a layer)
+            top = jax.lax.optimization_barrier(
+                jnp.max(sc, axis=-1, keepdims=True))
+            p = jnp.exp(sc - top)
+            total = jnp.sum(p, axis=-1)                         # [H, bq]
+            o = jnp.einsum("hqk,khd->qhd", p.astype(v.dtype), v,
+                           preferred_element_type=jnp.float32)
+            return (o / total.T[:, :, None]).astype(q.dtype)
+
+    def split(x):
+        return x.reshape((s // bq, bq) + x.shape[1:])
+
+    out = jax.lax.map(rows_of, (jnp.arange(s // bq), split(q), split(q_idx),
+                                split(w_idx)))
+    return out.reshape((s,) + out.shape[2:])
 
 
 def differential_combine(o, lam, n_kv: int):

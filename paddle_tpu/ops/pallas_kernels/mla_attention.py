@@ -71,6 +71,19 @@ kernel's name in a device trace is ``mla_latent_decode``; a caller whose
 page table is a RING of a few pages (a window layer: one wave a slot, a
 call whose cost is each slot's own chain and not its bytes) names its calls
 ``mla_latent_decode_ring`` so that a trace's reader can tell the two.
+
+The SPARSE read (``dsa_sparse_decode``; a layer whose indexer chooses the
+blocks of rows a query reads, ``kv_cache.LatentPagedCache
+.sparse_decode_attention``) is the same kernel over a second, shorter table
+a step: the "pages" are the 8-row TILES that hold a chosen block, and a
+``row_valid`` operand [B, table rows] keeps the chosen blocks' rows, since
+a tile may hold a block that was not chosen. Eight rows, not a block's
+four: a bfloat16 pool lies in HBM in tiles of (8, 128) 32-bit words' worth
+of rows and the chip's compiler refuses a copy that is not whole tiles
+("Slice shape along dimension 1 must be aligned to tiling (8), but is 4"),
+so two neighbouring blocks are ONE copy and ONE descriptor whether one of
+them or both were chosen; the fold then runs over the tile's eight rows
+and the mask drops the four that were not.
 """
 
 from __future__ import annotations
@@ -85,10 +98,13 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 __all__ = ["mla_paged_decode", "mla_gather_reference", "mla_decode_gate",
-           "KERNEL_NAME", "RING_KERNEL_NAME"]
+           "KERNEL_NAME", "RING_KERNEL_NAME", "SPARSE_KERNEL_NAME",
+           "SPARSE_TILE"]
 
 KERNEL_NAME = "mla_latent_decode"
 RING_KERNEL_NAME = "mla_latent_decode_ring"
+SPARSE_KERNEL_NAME = "dsa_sparse_decode"
+SPARSE_TILE = 8     # rows the sparse read copies at a time: an HBM tile's
 _LANES = 128
 # context rows a wave folds: [H, 512] f32 scores. Read on the chip at 256,
 # 512, 1,024 and 2,048 rows (PERF.md, PR 44): 1,024 folds 2 to 8% faster at
@@ -98,13 +114,16 @@ _WAVE_ROWS = 512
 
 
 def mla_decode_gate(dtype, width: int, rank: int, page_size: int,
-                    interpret: bool = False) -> Optional[str]:
+                    interpret: bool = False, sparse: bool = False
+                    ) -> Optional[str]:
     """None when the compiled kernel takes this latent geometry, else the
     rule that excludes it: a row or a latent that is not whole lane tiles,
     a page that is not whole sublane tiles of the pool's type, a type that
     is neither float32 nor bfloat16. The number of heads is not among the
     rules (32, 64 and 80 are served). The shape rules are the chip
-    compiler's tiling and do not bind the interpreter."""
+    compiler's tiling and do not bind the interpreter. ``sparse``: the
+    "page" is the sparse read's tile, held to the 8 rows of an HBM tile
+    (what the compiler takes of a copy), not to the pool type's sublanes."""
     dt = jnp.dtype(dtype)
     if dt not in (jnp.dtype(jnp.float32), jnp.dtype(jnp.bfloat16)):
         return "latent dtype %s is not float32/bfloat16" % dt.name
@@ -113,16 +132,19 @@ def mla_decode_gate(dtype, width: int, rank: int, page_size: int,
     if width % _LANES or rank % _LANES:
         return ("row width %d and latent rank %d must be multiples of %d"
                 % (width, rank, _LANES))
-    sublanes = 32 // dt.itemsize
+    sublanes = SPARSE_TILE if sparse else 32 // dt.itemsize
     if page_size % sublanes:
         return ("page_size=%d is not a multiple of the %s tile's %d rows"
                 % (page_size, dt.name, sublanes))
     return None
 
 
-def _mla_kernel(pt_ref, len_ref, layer_ref, q_ref, pool, o_ref, scr, sems,
-                ahead, *, block_pages, page_size, pages_per_slot, num_pages,
-                rank, sm_scale, mask_value, precision):
+def _mla_kernel(pt_ref, len_ref, layer_ref, q_ref, pool, *rest, block_pages,
+                page_size, pages_per_slot, num_pages, rank, sm_scale,
+                mask_value, precision):
+    # the sparse read's row mask rides between the pool and the output
+    valid_ref = rest[0] if len(rest) == 5 else None
+    o_ref, scr, sems, ahead = rest[-4:]
     b = pl.program_id(0)  # out here: the interpreter has none in a branch
     slots = pl.num_programs(0)
     ps = page_size
@@ -246,7 +268,11 @@ def _mla_kernel(pt_ref, len_ref, layer_ref, q_ref, pool, o_ref, scr, sems,
             # could read); a second fold without it doubles the kernel's
             # text, and its compilation is paid at every start
             pos = w * rows + jax.lax.broadcasted_iota(jnp.int32, (1, rows), 1)
-            s = jnp.where(pos < ctx, s, mask_value)
+            keep = pos < ctx
+            if valid_ref is not None:
+                keep = keep & (valid_ref[0, :, pl.ds(
+                    pl.multiple_of(w * rows, rows), rows)] > 0)
+            s = jnp.where(keep, s, mask_value)
             m_new = jnp.maximum(m, jnp.max(s, axis=1, keepdims=True))
             alpha = jnp.exp(m - m_new)
             p = jnp.exp(s - m_new)    # masked rows underflow to exactly 0.0
@@ -273,7 +299,8 @@ def _mla_kernel(pt_ref, len_ref, layer_ref, q_ref, pool, o_ref, scr, sems,
 
 def mla_paged_decode(q, pool, page_table, ctx_len, *, page_size, rank,
                      layer=None, sm_scale=1.0, block_pages=None,
-                     interpret: bool = False, name: str = KERNEL_NAME):
+                     interpret: bool = False, name: str = KERNEL_NAME,
+                     row_valid=None):
     """Absorbed latent decode attention over a paged pool.
 
     ``q`` [B, H, W]: each head's absorbed query over the row's lanes (its
@@ -283,7 +310,9 @@ def mla_paged_decode(q, pool, page_table, ctx_len, *, page_size, rank,
     ``page_table`` [B, pages_per_slot] int32; ``ctx_len`` [B] valid leading
     rows a slot (0: the slot holds nothing, its output is exactly 0.0 and
     it moves no page). ``name`` is the call's name in a device trace.
-    Returns [B, H, rank] in ``q``'s type, matching
+    ``row_valid`` [B, pages_per_slot * page_size] bool (the sparse read):
+    of the rows below the length, those that count; a slot with a length
+    has one at least. Returns [B, H, rank] in ``q``'s type, matching
     :func:`mla_gather_reference` to the products' round-off."""
     b, h, width = q.shape
     if pool.ndim == 2 and layer is None:
@@ -316,11 +345,21 @@ def mla_paged_decode(q, pool, page_table, ctx_len, *, page_size, rank,
         rank=int(rank), sm_scale=float(sm_scale),
         mask_value=neg_inf_value(jnp.float32),
         precision=jax.lax.Precision.HIGHEST if f32 else None)
+    masks = ()
+    if row_valid is not None:
+        table_rows = pages_per_slot * ps
+        if row_valid.shape != (b, table_rows) or table_rows % (bp * ps):
+            raise ValueError("row_valid must be [%d, %d], whole waves of %d "
+                             "rows: got %s" % (b, table_rows, bp * ps,
+                                               row_valid.shape))
+        masks = (row_valid.astype(jnp.int32).reshape(b, 1, table_rows),)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
         grid=(b,),
         in_specs=[pl.BlockSpec((1, hp, width), lambda i, *_: (i, 0, 0)),
-                  pl.BlockSpec(memory_space=pl.ANY)],
+                  pl.BlockSpec(memory_space=pl.ANY)]
+        + [pl.BlockSpec((1, 1, m.shape[-1]), lambda i, *_: (i, 0, 0))
+           for m in masks],
         out_specs=pl.BlockSpec((1, hp, rank), lambda i, *_: (i, 0, 0)),
         scratch_shapes=[pltpu.VMEM((2, bp * ps, width), pool.dtype),
                         pltpu.SemaphoreType.DMA((2,)),
@@ -332,7 +371,7 @@ def mla_paged_decode(q, pool, page_table, ctx_len, *, page_size, rank,
             dimension_semantics=("arbitrary",)),  # a slot leaves the next
         interpret=interpret, name=name,
     )(page_table.reshape(-1).astype(jnp.int32), ctx_len.astype(jnp.int32),
-      jnp.asarray(layer, jnp.int32).reshape(1), qk, pool)
+      jnp.asarray(layer, jnp.int32).reshape(1), qk, pool, *masks)
     return out[:, :h]
 
 

@@ -395,7 +395,8 @@ class ServingEngine:
     ``verify`` where it has it) and what it reads of ``model.cfg``
     (``n_layer``, ``n_head``, ``d_head``, ``max_seq``, ``dtype`` and, by
     ``getattr``, ``n_kv_head``, ``cache_groups``, ``latent_row``,
-    ``slot_state``, ``experts_held``), each with what its absence means.
+    ``slot_state``, ``index_row``, ``experts_held``), each with what its
+    absence means.
     Every ``getattr``/``hasattr`` on a model or its config in this module
     is one of those. The cache groups' kinds (``KV``, ``LATENT``,
     ``STATE``) are ``serving.kv_cache``'s; a model's decode ``stats`` go to
@@ -449,7 +450,8 @@ class ServingEngine:
                 mcfg.n_layer, latent[0], latent[1], self.cfg.slots,
                 self.cfg.max_seq, self.cfg.page_size, groups[0].num_pages,
                 dtype=mcfg.dtype, groups=groups,
-                slot_state=getattr(mcfg, "slot_state", None))
+                slot_state=getattr(mcfg, "slot_state", None),
+                index=getattr(mcfg, "index_row", None))
         elif self.cfg.paged:
             n_kv, q_per_kv = _query_groups(mcfg, layer_groups)
             kv_scales = None
@@ -487,6 +489,8 @@ class ServingEngine:
             if latent:
                 _sm.LATENT_RING_BYTES.set(
                     self.cache_ops.ring_bytes(self._cache))
+                _sm.INDEX_POOL_BYTES.set(
+                    self.cache_ops.index_bytes(self._cache))
         b = self.cfg.slots
         self._reset_slot_state()
         self._prefill_exe: Dict[int, Any] = {}   # bucket -> AOT executable
@@ -497,6 +501,7 @@ class ServingEngine:
         # method; without it every speculation knob silently resolves off
         # (serving must come up on a decode-only model)
         self._spec_capable = hasattr(model, "verify")
+        self.last_decode_stats = None   # (tenants, stats) of the newest read
         from .speculative import make_drafter
 
         self._drafter = make_drafter(self.cfg.spec_drafter)
@@ -1545,6 +1550,10 @@ class ServingEngine:
         # tokens/steps > 1 is exactly the speculative win
         _sm.DECODE_STEPS.inc(1 if d.dlen is not None else steps)
         if stats is not None:
+            # the newest dispatch's stats as read, with who held its slots:
+            # what a caller may look at beside the histograms (a name the
+            # histograms do not know, such as a probe, is only here)
+            self.last_decode_stats = (d.tenants, stats)
             for name, xs in stats.items():
                 hist = _sm.model_stat(name)
                 if hist is not None:

@@ -61,7 +61,13 @@ different kinds side by side:
   LATENT group with a ``window`` (a model whose other latent layers see
   the last W positions only): that group's slots keep their rows in a
   RING by the rule above, the row being stored with its rotary key
-  already rotated.
+  already rotated. A latent cache may keep an INDEX beside its rows
+  (``index``; a model whose latent layers choose the rows a query reads):
+  one pooled index key a block of ``kpool`` rows, in a second pool ``"ik"``
+  addressed through the group's own page table (a page of 16 rows owns 4
+  of them: no second allocator, no second free list), and, bound to the
+  SLOT as a state group's convolution tail is, the raw keys of the block
+  still open (``"it"``).
 * ``STATE``: the layers of a linear-attention recurrence keep nothing a
   token. What they keep belongs to the SLOT, has a fixed size and is
   rewritten whole at every step: a ``[H, dk, dv]`` float32 state and the
@@ -841,8 +847,11 @@ class LatentPagedCache(PagedKVCache):
                  max_ctx: int, page_size: int, num_pages: int,
                  dtype=jnp.float32,
                  groups: Optional[Sequence[CacheGroup]] = None,
-                 slot_state: Optional[Sequence[int]] = None):
+                 slot_state: Optional[Sequence[int]] = None,
+                 index: Optional[Sequence[int]] = None):
         self.rank, self.rope = int(rank), int(rope)
+        # (rows a block, lanes of an index key, blocks a query reads)
+        self.index = None if index is None else tuple(int(n) for n in index)
         self.row_values = self.rank + self.rope
         width = -(-self.row_values // 128) * 128
         if groups is None:
@@ -859,6 +868,195 @@ class LatentPagedCache(PagedKVCache):
         super().__init__(n_layer, 1, width, slots, max_ctx, page_size,
                          groups[0].num_pages, dtype, groups=groups,
                          slot_state=slot_state)
+        if self.index is not None:
+            kpool = self.index[0]
+            if len(paged) != 1 or paged[0].window is not None \
+                    or self.page_size % (2 * kpool) or 8 % kpool:
+                raise ValueError(
+                    "an index is kept beside ONE latent group of pages, "
+                    "whole blocks a page and whole blocks an 8-row tile "
+                    "(the least the chip copies), pairs of tiles a page: "
+                    "got groups %s, page_size=%d, blocks of %d rows"
+                    % ([(g.name, g.window) for g in paged], self.page_size,
+                       kpool))
+
+    # -- the index beside a latent group's rows -------------------------------
+    @property
+    def page_table_len(self) -> int:
+        """With an index, a slot's ``dest`` row ends with the slot itself:
+        the open block's keys are the slot's, not a page's."""
+        return self._pt_start[-1] + (self.index is not None)
+
+    def prompt_dest_groups(self, group_pages, slot: int = 0) -> np.ndarray:
+        dest = super().prompt_dest_groups(group_pages, slot)
+        if self.index is None:
+            return dest
+        return np.concatenate([dest, np.full(1, slot, np.int32)])
+
+    def init_state(self) -> Cache:
+        state = super().init_state()
+        if self.index is not None:
+            kpool, lanes, _ = self.index
+            g = self.groups[0]
+            # a PAGE's pooled keys side by side in one row (4 x 128 lanes
+            # at a page of 16 rows): the page table gathers them as it
+            # stands, a row a page
+            state["ik"] = jnp.zeros(
+                (len(g.layers), g.num_pages,
+                 self.page_size // kpool * lanes), self.dtype)
+            state["it"] = jnp.zeros(
+                (len(g.layers), self.slots, kpool - 1, lanes), self.dtype)
+        return state
+
+    def index_bytes(self, state: Cache) -> int:
+        """The pooled index keys and the open blocks' raw keys as stored."""
+        return int(sum(state[k].nbytes for k in ("ik", "it") if k in state))
+
+    def write_index(self, state: Cache, layer: int, key_new, pos, active
+                    ) -> Cache:
+        """One decode step of a layer's index: ``key_new`` [B, lanes], the
+        index key of position ``pos[b]``. A row that is not its block's
+        last joins the slot's open block (``"it"``); the block's LAST row
+        closes it: the mean of the block's keys, in float32, is written to
+        the block's lanes of its page's row in ``"ik"`` (through the page
+        table) and the raw keys are dropped (overwritten as the next block
+        opens). Inactive slots write nothing."""
+        kpool, lanes, _ = self.index
+        _, li = self._where[layer]
+        pt = state["pt"]
+        b_idx = jnp.arange(pt.shape[0])
+        j = pos % kpool
+        tail = state["it"][li]                           # [B, kpool - 1, L]
+        pooled = ((jnp.sum(tail.astype(jnp.float32), axis=1)
+                   + key_new.astype(jnp.float32)) / kpool).astype(self.dtype)
+        per_page = self.page_size // kpool
+        page = pt[b_idx, pos // self.page_size]
+        row = state["ik"][li, page]                      # [B, per_page * L]
+        mine = (jnp.arange(per_page * lanes) // lanes)[None, :] \
+            == ((pos % self.page_size) // kpool)[:, None]
+        row = jnp.where(mine, jnp.tile(pooled, (1, per_page)), row)
+        dest = jnp.where(active & (j == kpool - 1), page,
+                         state["ik"].shape[1])
+        opened = jnp.where(
+            (active & (j < kpool - 1))[:, None, None]
+            & (jnp.arange(kpool - 1)[None, :, None] == j[:, None, None]),
+            key_new[:, None].astype(tail.dtype), tail)
+        return {**state,
+                "ik": state["ik"].at[li, dest].set(row, mode="drop"),
+                "it": state["it"].at[li].set(opened)}
+
+    def _write_index_prompt(self, state: Cache, layer: int, pooled, tail,
+                            dest, length) -> Cache:
+        """A prompt's index: ``pooled`` [S / kpool, lanes], a key a block,
+        written a page's row at a time to every page that holds a closed
+        block (a block of such a page that is still open holds whatever
+        the bucket's padding pooled to: it is not scored before it closes,
+        and closing writes it), and ``tail`` [kpool - 1, lanes], the raw
+        keys of the block ``length`` leaves open, written whole to the
+        slot ``dest`` ends with."""
+        kpool, lanes, _ = self.index
+        _, li = self._where[layer]
+        per_page = self.page_size // kpool
+        rows = pooled.astype(self.dtype).reshape(-1, per_page * lanes)
+        p = jnp.arange(rows.shape[0])
+        flat = jnp.where(p * self.page_size + kpool <= length, dest[p],
+                         state["ik"].shape[1])
+        return {**state,
+                "ik": state["ik"].at[li, flat].set(rows, mode="drop"),
+                "it": state["it"].at[li, dest[-1]].set(
+                    tail.astype(self.dtype))}
+
+    def index_scores(self, state: Cache, layer: int, q_idx, w_idx, ctx_len,
+                     active):
+        """The index scores of one decode step: ``q_idx`` [B, Hi, lanes]
+        the index queries, ``w_idx`` [B, Hi] float32 their weights.
+        Returns ``(scores [B, blocks a slot] float32, closed [B])``: ``I(t,
+        b) = sum_j w_j ReLU(q_j . K_b)`` for each of the slot's CLOSED
+        blocks before the one position ``ctx_len - 1`` lies in, the
+        masking constant elsewhere (and everywhere in a slot that is not
+        ``active``); ``closed`` counts them. The keys are gathered a PAGE's
+        row at a time, by the page table as it stands."""
+        from ..ops import attention_ops
+
+        kpool = self.index[0]
+        _, li = self._where[layer]
+        closed = jnp.where(active, (ctx_len - 1) // kpool, 0)
+        return attention_ops.dsa_index_scores(
+            q_idx, w_idx, state["ik"][li, state["pt"]], closed), closed
+
+    def sparse_kernel_mode(self):
+        """:meth:`kernel_mode`'s twin for the sparse read: the same flag,
+        the latent kernel's gate at the 8-row tile it copies."""
+        from ..ops import attention_ops
+        from ..ops.pallas_kernels.mla_attention import (SPARSE_TILE,
+                                                        mla_decode_gate)
+
+        mode = attention_ops.paged_kernel_mode()
+        if mode is None:
+            return None, "n/a"
+        why_not = mla_decode_gate(self.dtype, self.row_width, self.rank,
+                                  SPARSE_TILE, interpret=(mode == "interpret"),
+                                  sparse=True)
+        if why_not is not None:
+            return None, "gate: " + why_not
+        return mode, None
+
+    def sparse_decode_attention(self, state: Cache, layer: int, q, chosen,
+                                ctx_len, active, sm_scale: float = 1.0):
+        """Decode attention over the CHOSEN blocks only: ``q`` [B, H,
+        rank + rope] absorbed, ``chosen`` [B, blocks a slot] bool (the
+        selection: closed blocks, and the block position ``ctx_len - 1``
+        lies in). Returns ``(o [B, H, rank], rows_read [B])``. The chip
+        copies 8 rows at the least (a bfloat16 tile's rows in HBM), so the
+        read goes by 8-row TILES: the tiles that hold a chosen block, in
+        ascending order, through a second, shorter table a step, and a row
+        mask that keeps the chosen blocks' rows at or before the position.
+        By the latent kernel under the name ``dsa_sparse_decode`` where
+        :meth:`sparse_kernel_mode` arms it, else by an XLA gather of the
+        same tiles."""
+        from ..ops import attention_ops
+        from ..ops.pallas_kernels import mla_attention as _mla
+
+        kpool, _, topk = self.index
+        _, li = self._where[layer]
+        pt = state["pt"]
+        b = pt.shape[0]
+        tile = _mla.SPARSE_TILE
+        per_tile, per_page = tile // kpool, self.page_size // tile
+        chosen = chosen & active[:, None]
+        by_tile = chosen.reshape(b, -1, per_tile)
+        n_tiles = by_tile.shape[1]
+        big = jnp.int32(n_tiles)
+        # a query reads at most ``topk`` blocks: so many tiles at the most
+        order = jnp.sort(jnp.where(jnp.any(by_tile, axis=-1),
+                                   jnp.arange(n_tiles, dtype=jnp.int32),
+                                   big), axis=-1)[:, :topk]
+        held = order < big
+        tiles = jnp.where(held, order, 0)
+        table = jnp.where(
+            held, pt[jnp.arange(b)[:, None], tiles // per_page] * per_page
+            + tiles % per_page, 0)
+        rows = tiles[:, :, None] * tile + jnp.arange(tile)
+        valid = jnp.repeat(
+            jnp.take_along_axis(by_tile, tiles[:, :, None], axis=1),
+            kpool, axis=-1) & held[:, :, None] \
+            & (rows < ctx_len[:, None, None])
+        valid = valid.reshape(b, -1)
+        length = jnp.sum(held, axis=-1).astype(jnp.int32) * tile
+        q = jnp.pad(q, ((0, 0), (0, 0), (0, self.row_width - q.shape[-1])))
+        mode, _ = self.sparse_kernel_mode()
+        read = jnp.sum(valid, axis=-1).astype(jnp.int32)
+        if mode is not None:
+            return _mla.mla_paged_decode(
+                q, state["c"], table, length, page_size=tile, rank=self.rank,
+                layer=li, sm_scale=sm_scale, row_valid=valid,
+                interpret=(mode == "interpret"),
+                name=_mla.SPARSE_KERNEL_NAME), read
+        pool_rows = (table[:, :, None] * tile + jnp.arange(tile)
+                     ).reshape(b, -1)
+        return attention_ops.mla_decode_attention(
+            q, state["c"][li, pool_rows], length, self.rank,
+            sm_scale=sm_scale, row_valid=valid), read
 
     def write_token(self, state: Cache, layer: int, row_new, pos, active
                     ) -> Cache:
@@ -869,8 +1067,15 @@ class LatentPagedCache(PagedKVCache):
     def write_prompt(self, state: Cache, layer: int, *new_dest_length
                      ) -> Cache:
         """A latent layer: ``(row_new [S, rank + rope], dest, length)`` of
-        ONE sequence, positions >= ``length`` dropped. A state layer:
-        ``(state, tail, dest, length)``, the paged cache's."""
+        ONE sequence, positions >= ``length`` dropped; with an index,
+        ``(row_new, pooled keys [S / kpool, lanes], open block's keys
+        [kpool - 1, lanes], dest, length)``. A state layer: ``(state,
+        tail, dest, length)``, the paged cache's."""
+        if len(new_dest_length) == 5:    # a latent layer with an index
+            row_new, pooled, tail, dest, length = new_dest_length
+            state = self._write_index_prompt(state, layer, pooled, tail,
+                                             dest, length)
+            new_dest_length = (row_new, dest, length)
         if len(new_dest_length) == 3:
             row_new, dest, length = new_dest_length
             new_dest_length = (row_new, None, dest, length)

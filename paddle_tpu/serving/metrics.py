@@ -208,6 +208,17 @@ LATENT_RING_BYTES = _mx.gauge(
          "ring (0 for a latent cache without a window group): what those "
          "layers hold whatever the contexts' lengths")
 
+INDEX_BLOCKS_SCORED = _mx.histogram(
+    "serving/index_blocks_scored",
+    help="closed blocks of index keys a decode step scored in one sparse "
+         "latent layer, over the live slots, one observation a step (a "
+         "model whose indexer chooses the rows a query reads)")
+INDEX_POOL_BYTES = _mx.gauge(
+    "serving/index_pool_bytes",
+    help="bytes of the pooled index keys and the open blocks' raw keys a "
+         "latent cache keeps beside its rows (0 for a cache without an "
+         "index)")
+
 # the engine's prefix cache (serving/prefix_cache.py). The names say
 # ``fleet/``: the cache was the fleet's before it was the engine's, and
 # operators and tools/dump_metrics.py read these strings
@@ -244,7 +255,8 @@ PREFIX_POISONED_SKIPPED = _mx.counter(
 _MODEL_STATS = {"moe_experts_touched": MOE_EXPERTS_TOUCHED,
                 "moe_max_expert_rows": MOE_MAX_EXPERT_ROWS,
                 "moe_held_pairs": MOE_HELD_PAIRS,
-                "state_slots_stepped": STATE_SLOTS_STEPPED}
+                "state_slots_stepped": STATE_SLOTS_STEPPED,
+                "index_blocks_scored": INDEX_BLOCKS_SCORED}
 
 
 def pages_used(group: str):
@@ -265,12 +277,28 @@ def attn_rows_read(group: str):
              "the cache's rows_read)" % group)
 
 
+def attn_rows_context(group: str):
+    """``serving/attn_rows_context.<group>``: the whole contexts of the
+    slots whose rows :func:`attn_rows_read` counts, where a layer reads
+    only the rows it chose: what a dense layer would have read."""
+    return _mx.histogram(
+        "serving/attn_rows_context.%s" % group,
+        help="context rows the live slots HOLD in cache group %r, of which "
+             "a sparse layer read serving/attn_rows_read.%s, one "
+             "observation a decode step" % (group, group))
+
+
 def model_stat(name: str):
     """The histogram the engine feeds a model's decode ``stats[name]`` to
     (an observation a value a step), or None for a name it does not know:
     the three ``moe_*`` and ``state_slots_stepped`` above and
-    ``attn_rows_read.<group>``, looked up once a name."""
+    ``attn_rows_read.<group>``, ``index_blocks_scored`` and
+    ``attn_rows_context.<group>``, looked up once a name."""
     hist = _MODEL_STATS.get(name)
-    if hist is None and name.startswith("attn_rows_read."):
-        hist = _MODEL_STATS[name] = attn_rows_read(name.partition(".")[2])
+    if hist is None:
+        kind, _, group = name.partition(".")
+        by_group = {"attn_rows_read": attn_rows_read,
+                    "attn_rows_context": attn_rows_context}.get(kind)
+        if by_group is not None and group:
+            hist = _MODEL_STATS[name] = by_group(group)
     return hist

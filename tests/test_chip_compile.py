@@ -1198,3 +1198,105 @@ def test_gdla_decoder_decode_step(chip, monkeypatch):
         r"\((\d+), \{\}, (?:may|must)-alias\)", text.split("\n", 1)[0])}
     # "c" "c.latent_ring" "pt" "pt.latent_ring" in key order
     assert {n_params, n_params + 1} <= aliased
+
+
+# -- the sparse latent hybrid (GLM-5.3-Flash as one chip of EP8) ---------------
+
+
+def test_sparse_latent_kernel_at_the_served_geometry(chip):
+    """64 heads over a 512-lane row with no rotary lane, bf16, the cell's
+    whole pool, a table of 512 eight-row tiles a slot and the row mask:
+    the kernel compiles under the sparse call's name, as ONE call, and
+    nothing outside it touches the pool. A four-row copy (a block alone)
+    is what the chip's compiler refuses; the gate says so first."""
+    from paddle_tpu.ops.pallas_kernels import mla_attention as mla
+
+    assert mla.mla_decode_gate(jnp.bfloat16, 512, 512, mla.SPARSE_TILE,
+                               sparse=True) is None
+    assert mla.mla_decode_gate(jnp.bfloat16, 512, 512, 4,
+                               sparse=True) is not None
+    rows = 40960 * 16
+    text = compiled_text(
+        chip,
+        lambda q, pool, table, length, valid: mla.mla_paged_decode(
+            q, pool, table, length, page_size=mla.SPARSE_TILE, rank=512,
+            layer=0, sm_scale=0.0625, name=mla.SPARSE_KERNEL_NAME,
+            row_valid=valid),
+        ((64, 64, 512), jnp.bfloat16), ((1, rows, 512), jnp.bfloat16),
+        ((64, 512), jnp.int32), ((64,), jnp.int32), ((64, 4096), jnp.bool_))
+    kernel, = [ln for ln in text.split("\n") if "tpu_custom_call" in ln]
+    assert kernel.strip().startswith(("%dsa_sparse_decode.",
+                                      "ROOT %dsa_sparse_decode."))
+    assert "bf16[64,64,512]" in kernel
+    assert [op for _, rtype, op, _ in _instructions(text)
+            if _has_dim(rtype, rows) and op != "parameter"] == []
+
+
+def test_sparse_hybrid_decoder_decode_step(chip, monkeypatch):
+    """The decode step of the sparse latent hybrid at its published
+    widths, as one chip of eight holds it (36 of 288 experts, 19,360 rows
+    of the vocabulary), a dense KDA layer and the sparse DSA layer over the
+    cell's pool, index and states: the state kernel once, the sparse read
+    once under its own name (and no dense latent call), the fused expert
+    kernel; the latent pool is neither copied nor sliced, and pool, index
+    and states are aliased from input to output."""
+    from paddle_tpu.models import glm5_flash as gf
+    from paddle_tpu.serving.kv_cache import CacheGroup, LatentPagedCache
+
+    monkeypatch.setattr(attention_ops, "paged_kernel_mode",
+                        lambda: "compiled")
+    monkeypatch.setattr(attention_ops, "_on_tpu", lambda: True)
+    cfg = gf.Glm5FlashConfig(
+        vocab_size=19360, n_layer=2, d_model=4096, n_head=64, d_state=128,
+        layer_types=[gf.KDA, gf.DSA], q_rank=1536, kv_rank=512, d_nope=256,
+        d_v=256, index_heads=32, index_dim=128, index_topk=2048,
+        index_kpool=4, d_dense=12288, dense_layers=(0,), n_expert=288,
+        top_k=8, d_expert=2048, routed_scale=2.5, max_seq=16384,
+        dtype="bfloat16", experts_held=tuple(range(36)))
+    model = gf.Glm5FlashLM(cfg, params={})
+
+    def sds(x):
+        return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=chip)
+
+    params = jax.tree_util.tree_map(
+        sds, jax.eval_shape(lambda: gf.init_params(cfg, 0)))
+    groups = [CacheGroup(name, layers, window,
+                         40960 if kind == "latent" else 0, kind)
+              for name, layers, window, kind in cfg.cache_groups]
+    ops = LatentPagedCache(2, 512, 0, 64, 16384, 16, 40960, dtype="bfloat16",
+                           groups=groups, slot_state=cfg.slot_state,
+                           index=cfg.index_row)
+    assert ops.sparse_kernel_mode() == ("compiled", None)
+    assert ops.state_kernel_mode() == ("compiled", None)
+    cache = jax.tree_util.tree_map(sds, jax.eval_shape(ops.init_state))
+    ints = jax.ShapeDtypeStruct((64,), jnp.int32, sharding=chip)
+    flags = jax.ShapeDtypeStruct((64,), jnp.bool_, sharding=chip)
+
+    def chunk(params, cache, lengths, tokens, active):
+        logits, cache, stats = model.decode(params, cache, ops, tokens,
+                                            lengths, active)
+        return cache, jnp.argmax(logits, -1), stats
+
+    text = jax.jit(chunk, donate_argnums=(1,)).lower(
+        params, cache, ints, ints, flags).compile().as_text()
+    kernels = [ln.strip().split(" = ")[0] for ln in text.split("\n")
+               if "tpu_custom_call" in ln]
+    assert sum(k.startswith("%kda_state_step") for k in kernels) == 1
+    assert sum(k.startswith("%dsa_sparse_decode") for k in kernels) == 1
+    assert not any(k.startswith("%mla_latent_decode") for k in kernels)
+    stream, grouped = _expert_products(text, (36, 4096, 2048))
+    assert len(stream) >= 1 and grouped == 0
+    instructions = list(_instructions(text))
+    types = {name: rtype for name, rtype, _, _ in instructions}
+    rows = 40960 * 16
+    moved = [(op, rtype) for _, rtype, op, operands in instructions
+             if op in ("copy", "copy-start", "slice", "dynamic-slice",
+                       "transpose")
+             and any(_has_dim(t, rows) and _has_dim(t, 512)
+                     for t in [rtype] + [types.get(o, "") for o in operands])]
+    assert moved == [], moved
+    n_params = len(jax.tree_util.tree_leaves(params))
+    aliased = {int(p) for p in re.findall(
+        r"\((\d+), \{\}, (?:may|must)-alias\)", text.split("\n", 1)[0])}
+    # "c" "ik" "it" "pt" "s.state" "tail.state" in key order
+    assert {n_params, n_params + 1, n_params + 4} <= aliased

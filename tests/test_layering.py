@@ -46,7 +46,8 @@ LEFT = [
     ("ops/pallas_kernels/sparse_adam.py", "tune", (226,)),
 ]
 
-SERVED = ["smallthinker", "kimi_k2", "laguna", "ling3_flash", "motif3"]
+SERVED = ["smallthinker", "kimi_k2", "laguna", "ling3_flash", "motif3",
+          "glm5_flash"]
 
 
 def _files(package):

@@ -127,7 +127,7 @@ def test_each_layer_kind_alone_equals_the_reference(toy, rng):
         lp = toy.params["layers"][i]
         h = blocks.rms_norm(x, lp["g1"], cfg.rms_eps)
         if kind == "kda":
-            y, _, _ = lf._kda_prefill(cfg, lp, h, 23)
+            y, _, _ = blocks.kda_prefill(cfg, lp, h, 23)
             want = ref._kda(lp, x, 4, -5.0, 1e-6)
         else:
             y, _ = lf._mla_prefill(cfg, lp, h, jnp.arange(23))
@@ -157,9 +157,9 @@ def test_a_lower_precision_fails(toy, what, rng, monkeypatch):
         monkeypatch.setattr(kda, "kda_chunk_scan",
                             lambda *a, **kw: scan(*a, chunk=8, **kw))
     else:
-        real = lf.log_decay
+        real = blocks.log_decay
         monkeypatch.setattr(
-            lf, "log_decay",
+            blocks, "log_decay",
             lambda z, a_log, lb: bf16(real(bf16(z), a_log, lb)))
     seq = rng.randint(0, 96, 23)
     logits, _ = _prefill(toy, seq)

@@ -16,11 +16,12 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from grid.reference import glm5_flash as glm5_ref
 from grid.reference import kimi_k2 as kimi_ref
 from grid.reference import laguna as laguna_ref
 from grid.reference import ling3_flash as ling3_ref
 from grid.reference import motif3 as motif3_ref
-from paddle_tpu.models import blocks, ling3_flash
+from paddle_tpu.models import blocks, glm5_flash, ling3_flash
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -34,6 +35,7 @@ KIMI = published("kimi-k2-ep32-serve")
 MOTIF = published("motif-3-beta-ep16-serve")
 LAGUNA = published("laguna-s-ep2-serve")
 LING = published("ling-3-flash-ep4-serve")
+GLM = published("glm-5.3-flash-ep8-serve")
 
 
 @pytest.mark.parametrize("want,rot,theta,scaling", [
@@ -112,9 +114,89 @@ def test_the_layer_kinds_are_the_references_names():
     assert set(LING["layer_types"]) == {ling3_flash.KDA, ling3_flash.MLA}
 
 
+@pytest.mark.parametrize("what", ["index_rope", "log_decay", "sinkhorn",
+                                  "pooled", "swiglu", "kinds"])
+def test_the_sparse_hybrids_helpers_are_the_references(what):
+    """GLM-5.3-Flash's helpers that the program owns, each against the
+    reference's at the configuration's values: the indexer's interleaved
+    rotation at 64 of 128 lanes and a base of 8e6 out to position 16,000;
+    the KDA gate at ``gate_lower_bound``; the residual path's Sinkhorn
+    with ``hc_eps`` in its divisions; the pooled key as the mean of a
+    block's four; SwiGLU under ``swiglu_limit``; the layer kinds' names."""
+    from grid.drivers import serve_dsa
+
+    rng = np.random.RandomState(11)
+    cfg = serve_dsa.model_config(GLM)
+    m = GLM["model"]
+    if what == "index_rope":
+        x = jnp.asarray(rng.randn(6, 32, GLM["index_head_dim"])
+                        .astype("float32"))
+        pos = jnp.asarray([0, 1, 7, 2047, 8191, 16000])
+        got = glm5_flash.index_rope(x, pos, cfg.index_inv_freq)
+        want = glm5_ref.index_rope(x, pos, float(m["index_rope_theta"]),
+                                   int(m["index_rope_dim"]))
+        np.testing.assert_array_equal(np.asarray(got[..., 64:]),
+                                      np.asarray(x[..., 64:]))
+        # the pairs are neighbours: lane 0 turns with lane 1, not lane 32
+        one = glm5_flash.index_rope(
+            jnp.zeros((1, 128)).at[0, 0].set(1.0), jnp.asarray([1]),
+            cfg.index_inv_freq)
+        assert abs(float(one[0, 1]) - np.sin(1.0)) < 1e-6
+        assert float(jnp.abs(one[0, 2:]).max()) == 0.0
+    elif what == "log_decay":
+        lin = GLM["linear_attn_config"]
+        z = jnp.asarray(rng.randn(5, 4, lin["head_dim"]).astype("float32")
+                        * 8.0)
+        a_log = jnp.asarray(rng.randn(4).astype("float32"))
+        got = blocks.log_decay(z, a_log, float(lin["gate_lower_bound"]))
+        want = glm5_ref.log_decay(z, a_log, float(lin["gate_lower_bound"]))
+        assert cfg.lower_bound == lin["gate_lower_bound"] == -5
+    elif what == "sinkhorn":
+        n = GLM["hc_mult"]
+        lp = {"pa": jnp.asarray(rng.randn(n * 8, 2 * n + n * n)
+                                .astype("float32")),
+              "aa": jnp.full((3,), 0.1), "ba": jnp.asarray(
+                  rng.randn(2 * n + n * n).astype("float32"))}
+        x = jnp.asarray(rng.randn(n, 3, 8).astype("float32"))
+        _, _, got = blocks.mix_in(cfg, lp, "a", x, jnp.ones((8,)))
+        _, _, want = glm5_ref.mhc_maps(
+            lp["pa"], lp["aa"], lp["ba"], jnp.moveaxis(x, 0, 1), n,
+            GLM["hc_sinkhorn_iters"], GLM["rms_norm_eps"], GLM["hc_eps"])
+        assert cfg.sinkhorn_eps == GLM["hc_eps"] == 1e-6
+        # the divisions carry hc_eps: without it the two differ
+        _, _, bare = glm5_ref.mhc_maps(
+            lp["pa"], lp["aa"], lp["ba"], jnp.moveaxis(x, 0, 1), n,
+            GLM["hc_sinkhorn_iters"], GLM["rms_norm_eps"], 0.0)
+        assert float(jnp.abs(bare - want).max()) > 1e-8
+    elif what == "pooled":
+        k = jnp.asarray(rng.randn(16, 128).astype("float32"))
+        want = glm5_ref.pooled_keys(k, GLM["index_kpool"])
+        got = jnp.stack([k[4 * b:4 * b + 4].mean(0) for b in range(4)])
+        assert cfg.index_row == (4, 128, 512)
+    elif what == "swiglu":
+        u = jnp.asarray(rng.randn(3, 8).astype("float32") * 40.0)
+        wg, wu = (jnp.asarray(rng.randn(8, 8).astype("float32"))
+                  for _ in range(2))
+        wd = jnp.eye(8)
+        got = glm5_flash._mlp(cfg, u, wg, wu, wd)
+        want = glm5_ref._mlp(u, wg, wu, wd, float(GLM["swiglu_limit"]))
+        plain = blocks.swiglu(u, wg, wu, wd)       # the clamps bind here
+        assert float(jnp.abs(plain - want).max()) > 1.0
+    else:
+        assert (glm5_flash.KDA, glm5_flash.DSA) == (glm5_ref.KDA,
+                                                    glm5_ref.DSA)
+        assert set(GLM["layer_types"]) == {glm5_flash.KDA, glm5_flash.DSA}
+        assert glm5_ref.layer_kinds(GLM) == GLM["layer_types_held"] == [
+            GLM["layer_types"][i] for i in GLM["published_layer_indices"]]
+        assert cfg.layer_types == tuple(GLM["layer_types_held"])
+        return
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=2e-5,
+                               atol=2e-6)
+
+
 SERVED = [("smallthinker", "SmallThinkerLM"), ("kimi_k2", "KimiK2LM"),
           ("laguna", "LagunaLM"), ("ling3_flash", "Ling3FlashLM"),
-          ("motif3", "Motif3LM")]
+          ("motif3", "Motif3LM"), ("glm5_flash", "Glm5FlashLM")]
 
 
 @pytest.mark.parametrize("module,cls", SERVED, ids=[m for m, _ in SERVED])

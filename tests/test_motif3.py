@@ -28,6 +28,7 @@ import pytest
 from grid.reference import motif3 as ref
 from paddle_tpu import serving
 from paddle_tpu.flags import set_flag
+from paddle_tpu.models import blocks
 from paddle_tpu.models import motif3 as mf
 from paddle_tpu.ops import attention_ops, moe_ops
 from paddle_tpu.ops.pallas_kernels import expert_stream as es
@@ -172,14 +173,14 @@ def test_h_res_is_doubly_stochastic_and_two_iterations_are_not(toy, rng):
     lp = toy.params["layers"][1]
     x = jnp.asarray(rng.randn(7, 4, 64).astype("float32"))
     major = jnp.moveaxis(x, -2, 0)          # the served streams: [n, B, d]
-    _, _, h_res = mf._mix_in(toy.cfg, lp, "a", major, lp["g1"])
+    _, _, h_res = blocks.mix_in(toy.cfg, lp, "a", major, lp["g1"])
     for axis in (-1, -2):
         np.testing.assert_allclose(np.asarray(h_res.sum(axis)), 1.0,
                                    atol=1e-5)
     _, _, want = ref.mhc_maps(lp["pa"], lp["aa"], lp["ba"], x, 4, 20, 1e-5)
     np.testing.assert_allclose(np.asarray(h_res), np.asarray(want),
                                atol=1e-6)
-    _, _, two = mf._mix_in(toy_cfg(sinkhorn_iters=2), lp, "a", major,
+    _, _, two = blocks.mix_in(toy_cfg(sinkhorn_iters=2), lp, "a", major,
                            lp["g1"])
     assert np.abs(np.asarray(two.sum(-1)) - 1.0).max() > 1e-3
 
@@ -191,8 +192,8 @@ def test_the_streams_are_mixed_as_the_equations_say(toy, rng):
     x = rng.randn(5, 4, 64).astype("float32")
     y = rng.randn(5, 64).astype("float32")
     major = jnp.moveaxis(jnp.asarray(x), -2, 0)     # [n, B, d] as served
-    u, h_post, h_res = mf._mix_in(toy.cfg, lp, "m", major, lp["g2"])
-    out = np.moveaxis(np.asarray(mf._mix_out(
+    u, h_post, h_res = blocks.mix_in(toy.cfg, lp, "m", major, lp["g2"])
+    out = np.moveaxis(np.asarray(blocks.mix_out(
         toy.cfg, major, jnp.asarray(y), h_post, h_res)), 0, -2)
     h_pre, hp, hr = (np.asarray(t) for t in ref.mhc_maps(
         lp["pm"], lp["am"], lp["bm"], jnp.asarray(x), 4, 20, 1e-5))

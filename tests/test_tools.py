@@ -63,3 +63,32 @@ def test_documents_name_only_what_exists():
                 or name.endswith("_")
                 and any(r.startswith(name) for r in read)))
     assert not unread, "README.md names variables nothing reads: %r" % unread
+
+
+def test_compare_lowering_tells_an_executable_that_changed(monkeypatch,
+                                                           tmp_path, capsys):
+    """``tools/compare_lowering.py``: a real child traces the smallest
+    served configuration's executables (the chunk and every bucket's
+    prefill of both its cells, a digest each), and the comparison names
+    exactly the executable whose text differs between two trees, writes
+    both texts for ``diff`` and says so by its exit code."""
+    from tools import compare_lowering as cl
+
+    traced = cl._child(REPO, ["gpt2-small-serve"], None)
+    exes = traced["gpt2-small-serve"]
+    assert sum(name.startswith("chunk.") for name in exes) == 1
+    assert sum(name.startswith("prefill.") for name in exes) >= 3
+    assert all(re.fullmatch(r"[0-9a-f]{64}", d) for d in exes.values())
+
+    def child(root, names, keep_text):
+        return {"cfg": {"chunk.a": "same", "prefill.b": "text of " + root}}
+
+    monkeypatch.setattr(cl, "_child", child)
+    differ = cl.compare("/parent", None, str(tmp_path))
+    assert differ == ["cfg/prefill.b"]
+    assert "DIFFERS" in capsys.readouterr().out
+    assert sorted(os.listdir(tmp_path)) == [
+        "cfg.prefill.b.change.txt", "cfg.prefill.b.parent.txt"]
+    monkeypatch.setattr(cl, "_child", lambda *a: {"cfg": {"chunk.a": "x"}})
+    assert cl.compare("/parent", None, str(tmp_path)) == []
+    assert cl.main(["--parent", "/parent"]) == 0
