@@ -1,10 +1,16 @@
 """Where a traced training step's device time goes by TENSOR SHAPE: the
 summed time a step of every operation of ``jit_step`` with an operand or
 a result of a given shape (``--shape 96,8,256,256``: the attention's score
-tensors), read from the trace a ``grid.run --trace 1`` left behind, and of
-those the part whose fusion draws random bits (threefry: told by the
-fusion's own instructions in the executable's text, where a dump of it is
-given).
+tensors), read from the trace a ``grid.run --trace 1`` left behind, and,
+where a dump of the executable's text is given, WHAT ITS FUSIONS HOLD
+(told by a fused computation's own instructions): the fusions that draw a
+dropout mask, by generator (a threefry chain; the coordinate hash of
+``paddle_tpu/ops/keep_hash.py``, told by its two multipliers), their time
+a step by label, and for each of the ten largest labels of the step
+whether its fusions hold a matrix product (``convolution``), a ``divide``
+that comes from ``optimizer_ops.py`` (the Adam update:
+``diag_adam_fusion.py``'s test) and a draw. A label is a fusion's FIRST
+result, so the label alone does not say.
 
     XLA_FLAGS="--xla_dump_to=<dir> --xla_dump_hlo_as_text \\
         --xla_dump_hlo_module_re=jit_step" \\
@@ -12,11 +18,14 @@ given).
     python benchmarks/diag_train_split.py --trace grid_out/tfbase-train-1chip/trace \\
         --hlo <dir> --shape 96,8,256,256
 
-An event of the trace is named by its instruction's whole text, operands'
-shapes included, so the shapes need no executable; what a fusion holds
-inside does. One JSON document: steps traced, milliseconds a step of the
-whole step, of the operations that touch the shape (by label, the largest
-first), of those that draw bits, and of every Pallas kernel.
+(a step loaded from the compile cache is not dumped: give that run an
+empty ``JAX_COMPILATION_CACHE_DIR``.) An event of the trace is named by its
+instruction's whole text, operands' shapes included, so the shapes need no
+executable; what a fusion holds inside does. One JSON document: steps
+traced, milliseconds a step of the whole step, of the operations that touch
+the shape (by label, the largest first), of those that draw, by generator,
+and of every Pallas kernel. Two traced runs, a parent's and a change's,
+give the before and after of the drawing fusions.
 """
 
 from __future__ import annotations
@@ -31,34 +40,102 @@ import sys
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 from grid import reduce  # noqa: E402
+from paddle_tpu.ops import keep_hash  # noqa: E402
 
 MODULE = "jit_step"
-_COMPUTATION = re.compile(r"^%?([\w.\-]+) \(.*\) -> .* \{$")
+_COMPUTATION = re.compile(r"^(?:ENTRY )?%?([\w.\-]+) \(.*\) -> .* \{$")
 _FUSION = re.compile(r"^\s*(?:ROOT )?%([\w.\-]+) = .* fusion\(.*calls=%([\w.\-]+)")
 # a threefry round is a rotate (two shifts and an or) and an xor on u32:
-# 20 rounds a draw, so a fusion that draws holds dozens of each
+# 20 rounds a draw, so a fusion that draws holds dozens of each. The hash
+# has no shift-left: three sites' hashes in one fusion are not a chain
 DRAWS_AT = 16
+# what is counted a computation: a name and the text an instruction has
+_COUNTED = {
+    "xor": " xor(", "shift_right": " shift-right-logical(",
+    "shift_left": " shift-left(", "product": " convolution(",
+    "mul_a": " constant(%d)" % keep_hash.MUL_A,
+    "mul_b": " constant(%d)" % keep_hash.MUL_B}
+_ADAM_FILE = "optimizer_ops.py"
+_FRAME = re.compile(r"stack_frame_id=(\d+)")
 
 
-def drawing_fusions(hlo_text: str):
-    """Names of the fusion instructions whose computation holds at least
-    ``DRAWS_AT`` ``xor`` and as many ``shift-right-logical``."""
-    counts, comp = {}, None
-    calls = {}
+def frames_of(hlo_text: str, file_name: str):
+    """The ``stack_frame_id``s of the text's own tables (``FileNames``,
+    ``FileLocations``, ``StackFrames``) with a frame in ``file_name``: an
+    instruction's metadata names a frame, not a file."""
+    tables, name = {}, None
+    for line in hlo_text.split("\n"):
+        if line in ("FileNames", "FunctionNames", "FileLocations",
+                    "StackFrames"):
+            name = line
+        elif name and line[:1].isdigit():
+            key, _, rest = line.partition(" ")
+            tables.setdefault(name, {})[int(key)] = rest
+        elif line.startswith(("ENTRY", "%")):
+            break
+    files = {k for k, v in tables.get("FileNames", {}).items()
+             if v.strip('"').endswith(file_name)}
+    field = lambda v, f: int(re.search(f + r"=(\d+)", v).group(1))  # noqa: E731
+    places = {k for k, v in tables.get("FileLocations", {}).items()
+              if field(v, "file_name_id") in files}
+    frames = {k: (field(v, "file_location_id"), field(v, "parent_frame_id"))
+              for k, v in tables.get("StackFrames", {}).items()}
+    inside = set()
+    for k in sorted(frames):        # a parent's id is below its child's
+        place, parent = frames[k]
+        if place in places or parent in inside:
+            inside.add(k)
+    return inside
+
+
+def fusion_contents(hlo_text: str):
+    """``{fusion instruction: set of what its computation holds}``, what
+    the fusions nested in it hold included, out of ``threefry`` (at least
+    ``DRAWS_AT`` each of ``xor``, ``shift-right-logical`` and ``shift-left``
+    in one computation), ``hash`` (both of the mixer's multipliers),
+    ``product`` (a ``convolution``) and ``adam`` (a ``divide`` from
+    ``optimizer_ops.py``)."""
+    adam_frames = frames_of(hlo_text, _ADAM_FILE)
+    counts, nested, comp, calls = {}, {}, None, {}
     for line in hlo_text.split("\n"):
         m = _COMPUTATION.match(line.strip())
         if m:
             comp = m.group(1)
-            counts[comp] = [0, 0]
+            counts[comp], nested[comp] = dict.fromkeys(_COUNTED, 0), []
+            counts[comp]["adam"] = 0
+            continue
+        if comp is None:
             continue
         m = _FUSION.match(line)
         if m:
             calls[m.group(1)] = m.group(2)
-        if comp is not None:
-            counts[comp][0] += " xor(" in line
-            counts[comp][1] += " shift-right-logical(" in line
-    return {name for name, c in calls.items()
-            if min(counts.get(c, (0, 0))) >= DRAWS_AT}
+            nested[comp].append(m.group(2))
+        for name, text in _COUNTED.items():
+            counts[comp][name] += text in line
+        if " divide(" in line:
+            frame = _FRAME.search(line)
+            counts[comp]["adam"] += _ADAM_FILE in line or (
+                frame is not None and int(frame.group(1)) in adam_frames)
+
+    def held(called, seen=()):
+        c = counts.get(called)
+        kinds = set() if c is None else {kind for kind, has in (
+            ("threefry", min(c["xor"], c["shift_right"],
+                             c["shift_left"]) >= DRAWS_AT),
+            ("hash", c["mul_a"] and c["mul_b"]),
+            ("product", c["product"]), ("adam", c["adam"])) if has}
+        for inner in nested.get(called, ()):
+            if inner not in seen:
+                kinds |= held(inner, seen + (called,))
+        return kinds
+
+    return {name: held(called) for name, called in calls.items()}
+
+
+def drawing_fusions(hlo_text: str):
+    """Names of the fusion instructions that hold a threefry chain."""
+    return {name for name, held in fusion_contents(hlo_text).items()
+            if "threefry" in held}
 
 
 def main(argv=None) -> int:
@@ -107,11 +184,44 @@ def main(argv=None) -> int:
         args.hlo, "*%s*after_optimizations.txt" % MODULE))) if args.hlo else []
     if texts:
         with open(max(texts, key=os.path.getsize)) as f:
-            draws = drawing_fusions(f.read())
-        doc["drawing_fusions"] = len(draws)
-        doc["drawing_ms"] = ms(lambda o: o.name in draws)
-        doc["drawing_and_touching_ms"] = ms(
-            lambda o: o.name in draws and touches(o))
+            holds = fusion_contents(f.read())
+        step_ops = [o for o in trace.ops[chip] if o.module == MODULE
+                    and whole[0] <= o.start and o.end <= whole[1]]
+
+        def per_step(ops):
+            return round(sum(o.end - o.start for o in ops) * 1e3 / n, 3)
+
+        def largest(ops, top):
+            """(label, its ops) of the ``top`` labels with most time."""
+            labels = {}
+            for o in ops:
+                labels.setdefault(reduce.op_label(o), []).append(o)
+            return sorted(labels.items(),
+                          key=lambda kv: -per_step(kv[1]))[:top]
+
+        def holding(kind):
+            return lambda o: kind in holds.get(o.name, ())
+
+        for kind in ("threefry", "hash"):
+            drawn = list(filter(holding(kind), step_ops))
+            doc["drawing_" + kind] = {
+                "fusions": len({o.name for o in drawn}),
+                "ms": ms(holding(kind)),
+                "touching_shape_ms": ms(
+                    lambda o: holding(kind)(o) and touches(o)),
+                "with_a_product_ms": per_step(
+                    filter(holding("product"), drawn)),
+                "by_label_ms": {lab: per_step(ops)
+                                for lab, ops in largest(drawn, args.top)},
+            }
+        doc["largest_labels"] = {
+            lab: dict(
+                {"ms": per_step(ops),
+                 "instructions": len({o.name for o in ops})},
+                **{"with_" + kind: len({
+                    o.name for o in filter(holding(kind), ops)})
+                   for kind in ("product", "adam", "threefry", "hash")})
+            for lab, ops in largest(step_ops, 10)}
     print(json.dumps(doc, indent=1))
     return 0
 

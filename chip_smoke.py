@@ -226,7 +226,10 @@ def phase_train(seed, meter):
         # the model's own loss: label smoothing 0.1 (models/transformer.py)
         xent_why_not = fused_xent_gate((n_rows, cfg["vocab"]), "bfloat16",
                                        smooth=0.1)
-        fused_xent = "tpu_custom_call" in hlo
+        # by the kernels' own names: the attention's single-tile kernels are
+        # tpu_custom_calls of this step too
+        fused_xent = "softmax_xent_fwd" in hlo
+        single_tile = "single_tile_attention_fwd" in hlo
         check(all(np.isfinite(losses)), "a loss is not finite: %s" % losses)
         check(losses[-1] < losses[0],
               "loss did not fall on a fixed batch: %s" % losses)
@@ -237,7 +240,7 @@ def phase_train(seed, meter):
               and steady["step_specializations"] == 0,
               "compiled again after the first step: %s" % steady)
         check(fused_xent == (xent_why_not is None),
-              "the compiled step %s a tpu_custom_call, but the "
+              "the compiled step %s the softmax_xent kernel, but the "
               "cross-entropy gate says: %s"
               % ("holds" if fused_xent else "lacks",
                  xent_why_not or "fused kernel"))
@@ -249,11 +252,12 @@ def phase_train(seed, meter):
             "kernel_path": {
                 "cross_entropy": ("pallas fused_softmax_xent [%d x %d]"
                                   % (n_rows, cfg["vocab"]) if fused_xent
-                                  else "xla, no tpu_custom_call (gate: %s)"
+                                  else "xla, no softmax_xent kernel (gate: %s)"
                                   % xent_why_not),
-                "attention": "composed (seq %d < flash_attention_min_seq %d)"
-                             % (cfg["seq"],
-                                fluid.get_flag("flash_attention_min_seq"))},
+                "attention": "single_tile_attention_fwd|bwd" if single_tile
+                             else "composed (seq %d < flash_attention_min_seq "
+                                  "%d)" % (cfg["seq"], fluid.get_flag(
+                                      "flash_attention_min_seq"))},
             "tune": tune_layers()}
 
 

@@ -48,10 +48,9 @@ def encoder_layer(x, attn_bias, n_head, d_key, d_value, d_model, d_inner,
     BERT arrangement (LN after each residual add). ``inner_dropout`` is the
     relu_dropout INSIDE the FFN — present in the translation model, absent
     in BERT (whose FFN is gelu with dropout only on sublayer outputs); an
-    extraneous inner dropout also forces XLA to rematerialize a threefry
-    chain inside both fc dw-grad fusions (~0.8 ms/layer/step measured,
-    benchmarks/diag_adam_fusion.py). Defaults preserve the translation
-    model; BERT passes gelu/0/True.
+    extraneous inner dropout also makes XLA draw its mask again inside
+    both fc dw-grad fusions (ops/keep_hash.py: the mask is never stored).
+    Defaults preserve the translation model; BERT passes gelu/0/True.
     """
     if inner_dropout is None:
         inner_dropout = dropout_rate

@@ -33,6 +33,8 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 import jax.numpy as jnp
 
+from .. import keep_hash  # paddle_tpu: the mixer's one home
+
 DEFAULT_MASK_VALUE = -0.7 * float(jnp.finfo(jnp.dtype("float32")).max)
 
 # paddle_tpu: when True, every pallas_call runs in interpret mode so the
@@ -45,11 +47,12 @@ INTERPRET = False
 #
 # The keep-mask is a pure function of the ABSOLUTE (batch, head, q, k)
 # element coordinates and a seed — a counter-based splitmix32-style hash in
-# plain jnp u32 ops (pltpu.prng_* has no interpret-mode lowering in this
-# JAX). Purity over coordinates means the forward kernel and BOTH backward
-# kernels regenerate bit-identical masks regardless of their tile
-# partitioning, and the composed reference can reproduce the mask outside
-# the kernel for parity tests (tests/test_flash_dropout.py).
+# plain u32 ops (ops/keep_hash.py, which tensor_ops.dropout_op shares;
+# pltpu.prng_* has no interpret-mode lowering in this JAX). Purity over
+# coordinates means the forward kernel and BOTH backward kernels regenerate
+# bit-identical masks regardless of their tile partitioning, and the
+# composed reference can reproduce the mask outside the kernel for parity
+# tests (tests/test_flash_dropout.py).
 #
 # Dropout applies to the NORMALIZED probabilities: o = (mask*p/(1-r)) @ v
 # with the softmax stats (l, m) computed dropout-free; in the backward,
@@ -67,24 +70,11 @@ def _dropout_coords(q_offset, k_offset, shape):
 
 
 def _dropout_keep_at(coords, dropout_rate, seed, b_idx, h_idx):
-  # lax operations on the tile, not jnp's: the same arithmetic, a quarter of
-  # the time to trace, and a kernel that unrolls its heads traces this once
-  # a head at every start of the program
   key = (jnp.uint32(seed)
          + jnp.uint32(b_idx) * jnp.uint32(0x9E3779B9)
          + jnp.uint32(h_idx) * jnp.uint32(0xC2B2AE35))
-
-  def tile(c):
-    return lax.full_like(coords, c)
-
-  x = lax.bitwise_xor(coords, lax.broadcast(key, coords.shape))
-  x = lax.mul(lax.bitwise_xor(x, lax.shift_right_logical(x, tile(16))),
-              tile(0x7FEB352D))
-  x = lax.mul(lax.bitwise_xor(x, lax.shift_right_logical(x, tile(15))),
-              tile(0x846CA68B))
-  x = lax.bitwise_xor(x, lax.shift_right_logical(x, tile(16)))
-  threshold = min(int(float(dropout_rate) * 4294967296.0), 4294967295)
-  return lax.ge(x, tile(threshold))
+  return keep_hash.keep(
+      lax.bitwise_xor(coords, lax.broadcast(key, coords.shape)), dropout_rate)
 
 
 def _dropout_keep_tile(dropout_rate, seed, b_idx, h_idx, q_offset, k_offset,

@@ -14,6 +14,7 @@ import numpy as np
 
 from ..core.dtypes import to_jnp_dtype
 from ..core.registry import OpContext, register_op
+from . import keep_hash
 
 
 def _resolve_shape(shape, x=None):
@@ -440,6 +441,18 @@ def randint_op(ctx: OpContext):
     ctx.set_output("Out", out)
 
 
+def _count_draw() -> None:
+    """One more dropout traced (trace-time: an executable's sites count
+    once, when it is traced, as ``attention/sdpa_calls.*`` do)."""
+    from ..monitor import metrics
+
+    metrics.counter(
+        "dropout/draws.hash",
+        help="dropout ops traced with the coordinate-hash mask "
+             "(ops/keep_hash.py; once a site of a traced program, not "
+             "once a run)").inc()
+
+
 @register_op("dropout")
 def dropout_op(ctx: OpContext):
     """Reference: operators/dropout_op.cc. Two impl modes:
@@ -459,10 +472,14 @@ def dropout_op(ctx: OpContext):
         ctx.set_output("Out", x)
         ctx.set_output("Mask", jnp.ones_like(x))
         return
-    # keep the mask as PRED through the where: the backward residual is then
-    # the 1-byte bool, not an x.dtype mask — one byte/element less HBM
-    # traffic per dropout site (matters at [B,H,S,S] attention sites)
-    keep = jax.random.bernoulli(ctx.rng(), 1.0 - p, x.shape)
+    # The mask is the attention kernels' coordinate hash (keep_hash.py) over
+    # the element's position in the whole array and the op's key. XLA stores
+    # no such mask: it draws it again in every fusion that consumes the
+    # dropped tensor (forward, backward where, weight-gradient products), so
+    # the draw has to be cheap. It stays PRED through the where, so a mask
+    # that IS saved is 1 byte an element.
+    keep = keep_hash.keep_mask(ctx.rng(), x.shape, p)
+    _count_draw()
     if impl == "upscale_in_train":
         out = jnp.where(keep, x * jnp.asarray(1.0 / (1.0 - p), x.dtype),
                         jnp.zeros((), x.dtype))

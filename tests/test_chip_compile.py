@@ -138,6 +138,65 @@ def test_single_tile_attention_runs_a_shard_on_four_chips(chip, monkeypatch):
     assert "[96,8,256,256]" not in text
 
 
+def _ffn_with_its_dropouts(x, w1, w2):
+    """An FFN with its inner dropout and the residual dropout behind it,
+    through the registered ``dropout`` op (a constant key, as the training
+    cell's seeded program gives it)."""
+    from paddle_tpu.testing.op_test import run_op
+
+    def drop(v, seed):
+        return run_op("dropout", {"X": v}, ["Out"], attrs={
+            "dropout_prob": 0.1, "seed": seed,
+            "dropout_implementation": "upscale_in_train"})["Out"]
+
+    h = drop(jax.nn.relu(jnp.einsum("bsd,df->bsf", x, w1)), 3)
+    y = jnp.einsum("bsf,fd->bsd", h, w2)
+    return ((x + drop(y, 4)).astype(jnp.float32) ** 2).mean()
+
+
+@pytest.mark.parametrize("chips", [1, 4])
+def test_dropout_draws_no_threefry_chain_at_transformer_base(chip, chips):
+    """Forward and backward at ``[96, 256, 512]`` / 2048 in bf16 (a chip's
+    rows; 384 over a ``data`` axis of four): no fused computation holds a
+    threefry chain, the mixer's two multipliers stand where a mask is used
+    (the two forward fusions, the backward ``where``s and the weight-gradient
+    products that read a dropped tensor), and on four chips each hashes its
+    own rows' global positions: no collective carries a mask."""
+    import numpy as np
+    from jax.experimental import topologies
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from benchmarks import diag_train_split
+
+    w1, w2 = (512, 2048), (2048, 512)
+    if chips == 1:
+        shard = [chip] * 3
+        outs = None
+    else:
+        # the fixture has described the topology once: this process may again
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+        mesh = Mesh(np.array(topo.devices), ("data",))
+        rows, repl = NamedSharding(mesh, P("data")), NamedSharding(mesh, P())
+        shard = outs = (rows, repl, repl)
+    args = [jax.ShapeDtypeStruct(s, jnp.bfloat16, sharding=sh) for s, sh in
+            zip(((96 * chips, 256, 512), w1, w2), shard)]
+    text = jax.jit(jax.grad(_ffn_with_its_dropouts, argnums=(0, 1, 2)),
+                   out_shardings=outs).lower(*args).compile().as_text()
+    holds = diag_train_split.fusion_contents(text)
+    assert not diag_train_split.drawing_fusions(text)
+    hashing = [held for held in holds.values() if "hash" in held]
+    assert len(hashing) >= 4
+    assert any("product" in held for held in hashing)
+    assert "[%d,256," % (96 * chips) not in text or chips == 1
+    collectives = [ln for ln in text.split("\n") if re.search(
+        r" (all-gather|all-reduce|all-to-all|collective-permute)"
+        r"(-start)?\(", ln)]
+    assert len(collectives) == (chips == 4)    # the weight gradients' sum
+    assert all("pred[" not in ln and "u32[" not in ln and "all-reduce" in ln
+               for ln in collectives)
+
+
 def test_softmax_xent_fwd_bwd(chip):
     def loss(logits, labels):
         return fused_softmax_xent(logits, labels).sum()
