@@ -1146,6 +1146,83 @@ def test_hybrid_decoder_decode_step(chip, monkeypatch):
     assert {n_params, n_params + 2, n_params + 3} <= aliased
 
 
+def _scan_loops(text, heads):
+    """The ``while`` instructions of an HLO module whose carried tuple
+    names the chunk scan's float32 state ``[heads, 128, 128]`` (what
+    ``grid/readers/hybrid.py`` ``_is_scan`` asks of a traced event), each
+    with the names of the computations it runs."""
+    state = "f32[%d,128,128]" % heads
+    return [(rtype, re.findall(r"(?:condition|body)=%([\w.\-]+)", line))
+            for line in text.split("\n")
+            for _, rtype, op, _ in _instructions(line)
+            if op == "while" and state in rtype]
+
+
+def _calls_the_scan_kernel(text, computations):
+    comps, _ = _computations(text)
+    return any("tpu_custom_call" in ln and ln.strip().startswith(
+        "%kda_chunk_scan") for name in computations for ln in comps[name])
+
+
+@pytest.mark.parametrize("heads", [32, 64])
+def test_chunk_scan_kernel_at_the_served_geometries(chip, heads):
+    """A 4,096-row bucket of 32 and of 64 heads of 128 x 128, chunks of
+    64: the kernel compiles under its own name, ONE text for the loop's
+    turns, inside a ``while`` that carries the float32 state (the chunk
+    scan's roofline is that loop's time); q, k, v, the log-decay and the
+    outputs (what has 128 lanes a head) are neither copied nor turned on
+    their way in and out."""
+    from paddle_tpu.ops.pallas_kernels import kda
+
+    assert kda.kda_chunk_scan_gate(heads, 128, 128) is None
+    rows = 4096
+    text = compiled_text(
+        chip, kda.kda_chunk_scan_kernel,
+        *[((rows, heads, 128), jnp.bfloat16)] * 3,
+        ((rows, heads, 128), jnp.float32), ((rows, heads), jnp.float32))
+    kernel, = [ln for ln in text.split("\n") if "tpu_custom_call" in ln]
+    assert kernel.strip().startswith("%kda_chunk_scan")
+    loop, = _scan_loops(text, heads)
+    assert _calls_the_scan_kernel(text, loop[1])
+    moved = [(op, rtype) for _, rtype, op, _ in _instructions(text)
+             if op in ("copy", "transpose", "fusion")
+             and _has_dim(rtype, heads) and (_has_dim(rtype, rows)
+                                             or _has_dim(rtype, rows // 64))
+             and _has_dim(rtype, 128) and "128,128]" not in rtype]
+    assert moved == [], moved
+    assert "reduce-window" not in text
+
+
+def test_hybrid_decoder_prefill_holds_the_scan_kernel_in_its_loop(
+        chip, monkeypatch):
+    """The hybrid decoder's prefill executable (a dense KDA layer, a
+    sparse KDA layer and the MLA layer at the published widths, a 2,048-row
+    bucket): a ``while`` a KDA layer carries ``f32[32,128,128]`` and runs
+    the ``kda_chunk_scan`` kernel; no running sum of floats (a softmax's,
+    a decay's) became a ``reduce-window``."""
+    from paddle_tpu.models import ling3_flash as lf
+
+    monkeypatch.setattr(attention_ops, "_on_tpu", lambda: True)
+    _, (params, _, _, _, _), _ = _hybrid_case(chip)
+    cfg = lf.Ling3FlashConfig(
+        vocab_size=HYBRID_SERVE["vocab"], n_layer=3, d_model=2560,
+        n_head=32, d_state=128, layer_types=["kda", "kda", "mla"],
+        kv_rank=512, d_nope=128, d_rope=64, d_v=128, d_dense=6144,
+        dense_layers=(0,), n_expert=512, top_k=8, d_expert=768, n_group=8,
+        topk_group=4, routed_scale=2.5, max_seq=HYBRID_SERVE["max_seq"],
+        dtype="bfloat16", experts_held=tuple(range(128)))
+    tokens = jax.ShapeDtypeStruct((1, 2048), jnp.int32, sharding=chip)
+    lengths = jax.ShapeDtypeStruct((1,), jnp.int32, sharding=chip)
+    text = jax.jit(lambda p, t, n: lf.prefill_forward(p, cfg, t, n)).lower(
+        params, tokens, lengths).compile().as_text()
+    loops = _scan_loops(text, 32)
+    assert len(loops) == 2
+    assert all(_calls_the_scan_kernel(text, comps) for _, comps in loops)
+    # the routing's integer running counts are reduce-windows; no float is
+    assert [rtype for _, rtype, op, _ in _instructions(text)
+            if op == "reduce-window" and rtype[:3] != "s32"] == []
+
+
 # -- the grouped-differential latent decoder (motif-3-beta-ep16-serve) -----------
 
 

@@ -19,6 +19,8 @@ for ten times ``TOL`` of each), so neither can hide inside it.
 """
 
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -29,6 +31,7 @@ from paddle_tpu import serving
 from paddle_tpu.flags import set_flag
 from paddle_tpu.models import blocks
 from paddle_tpu.models import ling3_flash as lf
+from paddle_tpu.monitor import metrics as mx
 from paddle_tpu.ops import moe_ops
 from paddle_tpu.ops.pallas_kernels import kda
 from paddle_tpu.serving.kv_cache import (LATENT, STATE, CacheGroup,
@@ -257,21 +260,54 @@ def _kda_case(rng, t, h=3, dk=16, dv=8):
     return [jnp.asarray(x) for x in (q, k, v, a, beta)]
 
 
+SCAN_FORMS = {
+    "xla": kda.kda_chunk_scan_xla,
+    "kernel": functools.partial(kda.kda_chunk_scan_kernel, interpret=True)}
+
+
+@pytest.fixture(params=sorted(SCAN_FORMS))
+def scan(request):
+    """The chunk scan in each of its forms: the blocked ``jax.numpy`` loop,
+    and the ``kda_chunk_scan`` kernel in the interpreter."""
+    return SCAN_FORMS[request.param]
+
+
 @pytest.mark.parametrize("t", [1, 50, 64, 130, 256])
-def test_the_chunk_scan_equals_the_recurrence(t, rng):
+def test_the_chunk_scan_equals_the_recurrence(t, scan, rng):
     """Lengths that are and are not multiples of 64, decays anywhere in
     (-5, 0): within 2e-5 of the token-by-token recurrence on outputs of
     order 1, and the final state within the same."""
     x = _kda_case(rng, t)
     o1, s1 = kda.kda_recurrence(*x)
-    o2, s2 = kda.kda_chunk_scan(*x)
+    o2, s2 = scan(*x)
     assert o2.shape == o1.shape and s2.shape == s1.shape
     np.testing.assert_allclose(np.asarray(o2), np.asarray(o1), atol=2e-5)
     np.testing.assert_allclose(np.asarray(s2), np.asarray(s1), atol=2e-5)
 
 
+@pytest.mark.parametrize("heads", [32, 64])
+def test_the_chunk_scan_at_the_served_head_counts_from_a_given_state(
+        heads, scan, rng):
+    """32 and 64 heads (four and eight blocks of the kernel's grid), 100
+    tokens (a chunk and a padded tail) and a state to start from: outputs
+    and final state within 2e-5 of the recurrence's, in the bfloat16 the
+    served model hands the scan too."""
+    x = _kda_case(rng, 100, heads)
+    s0 = jnp.asarray(rng.randn(heads, 16, 8).astype("float32"))
+    o1, s1 = kda.kda_recurrence(*x, s0=s0)
+    o2, s2 = scan(*x, s0=s0)
+    np.testing.assert_allclose(np.asarray(o2), np.asarray(o1), atol=2e-5)
+    np.testing.assert_allclose(np.asarray(s2), np.asarray(s1), atol=2e-5)
+    q, k, v = (t.astype(jnp.bfloat16) for t in x[:3])
+    o1, s1 = kda.kda_recurrence(q, k, v, *x[3:], s0=s0)
+    o2, s2 = scan(q, k, v, *x[3:], s0=s0)
+    assert o2.dtype == s2.dtype == jnp.float32
+    np.testing.assert_allclose(np.asarray(o2), np.asarray(o1), atol=2e-5)
+    np.testing.assert_allclose(np.asarray(s2), np.asarray(s1), atol=2e-5)
+
+
 def test_the_chunk_scan_is_exact_where_a_chunks_keys_resemble_each_other(
-        rng):
+        scan, rng):
     """Keys that are nearly ONE direction with little decay and strong
     writes (what a served model's hidden states give: the chip's first run
     read 5 row deviations from the series form of ``(I + A)^-1``, whose
@@ -285,30 +321,96 @@ def test_the_chunk_scan_is_exact_where_a_chunks_keys_resemble_each_other(
     a = jnp.full((t, h, dk), -1e-3, jnp.float32)
     beta = jnp.full((t, h), 0.95, jnp.float32)
     o1, s1 = kda.kda_recurrence(q, jnp.asarray(k), v, a, beta)
-    o2, s2 = kda.kda_chunk_scan(q, jnp.asarray(k), v, a, beta)
+    o2, s2 = scan(q, jnp.asarray(k), v, a, beta)
     np.testing.assert_allclose(np.asarray(o2), np.asarray(o1), atol=2e-5)
     np.testing.assert_allclose(np.asarray(s2), np.asarray(s1), atol=2e-5)
 
 
-def test_the_scan_is_safe_at_the_decay_bound_and_carries_a_state(rng):
+def test_the_scan_is_safe_at_the_decay_bound_and_carries_a_state(scan, rng):
     """Every step at the lower bound (e^-320 over a chunk) neither
     overflows nor loses the near pairs; and a scan continued from a state
     is the scan of the whole."""
     q, k, v, a, beta = _kda_case(rng, 128)
     hard = jnp.full_like(a, -4.999)
     o1, s1 = kda.kda_recurrence(q, k, v, hard, beta)
-    o2, s2 = kda.kda_chunk_scan(q, k, v, hard, beta)
+    o2, s2 = scan(q, k, v, hard, beta)
     assert np.isfinite(np.asarray(o2)).all()
     np.testing.assert_allclose(np.asarray(o2), np.asarray(o1), atol=2e-5)
-    whole, s_whole = kda.kda_chunk_scan(q, k, v, a, beta)
-    head, s_head = kda.kda_chunk_scan(q[:70], k[:70], v[:70], a[:70],
-                                      beta[:70])
-    tail, s_tail = kda.kda_chunk_scan(q[70:], k[70:], v[70:], a[70:],
-                                      beta[70:], s0=s_head)
+    whole, s_whole = scan(q, k, v, a, beta)
+    head, s_head = scan(q[:70], k[:70], v[:70], a[:70], beta[:70])
+    tail, s_tail = scan(q[70:], k[70:], v[70:], a[70:], beta[70:],
+                        s0=s_head)
     np.testing.assert_allclose(np.asarray(tail), np.asarray(whole[70:]),
                                atol=2e-5)
     np.testing.assert_allclose(np.asarray(s_tail), np.asarray(s_whole),
                                atol=2e-5)
+
+
+def test_the_scan_gate_names_what_it_refuses_and_chooses_the_form(
+        monkeypatch):
+    """The kernel takes chunks of 64 of heads in blocks of 8 whose q, k and
+    v are whole lane tiles, and ``kda_chunk_scan`` asks it only on a TPU:
+    here, and where the gate refuses, the blocked ``jax.numpy`` runs."""
+    assert kda.kda_chunk_scan_gate(32, 128, 128) is None
+    assert kda.kda_chunk_scan_gate(64, 128, 128) is None
+    assert "128-lane" in kda.kda_chunk_scan_gate(32, 128, 64)
+    assert "128-lane" in kda.kda_chunk_scan_gate(32, 64, 128)
+    assert "blocks of 8" in kda.kda_chunk_scan_gate(12, 128, 128)
+    assert "KiB of VMEM" in kda.kda_chunk_scan_gate(32, 256, 256)
+    assert "chunks of 64" in kda.kda_chunk_scan_gate(32, 128, 128, chunk=32)
+    assert "chunks of 64" in kda.kda_chunk_scan_gate(4, 16, 8, chunk=8,
+                                                     interpret=True)
+    assert kda.kda_chunk_scan_gate(3, 16, 8, interpret=True) is None
+    with pytest.raises(ValueError, match="128-lane"):
+        kda.kda_chunk_scan_kernel(*_kda_case(np.random.RandomState(0), 8))
+    took = []
+    monkeypatch.setattr(kda, "kda_chunk_scan_kernel",
+                        lambda *a, **kw: took.append("kernel"))
+    monkeypatch.setattr(kda, "kda_chunk_scan_xla",
+                        lambda *a, **kw: took.append("xla"))
+    small = _kda_case(np.random.RandomState(0), 8)
+    served = [jnp.zeros((8, 8, 128))] * 4 + [jnp.zeros((8, 8))]
+    kda.kda_chunk_scan(*served)
+    monkeypatch.setattr(kda, "_on_tpu", lambda: True)
+    kda.kda_chunk_scan(*small)
+    kda.kda_chunk_scan(*served)
+    kda.kda_chunk_scan(*served, chunk=32)
+    assert took == ["xla", "xla", "kernel", "xla"]
+
+
+def _scan_calls():
+    snap = mx.snapshot()
+    return {f: snap.get("kda/scan_calls." + f, {"value": 0})["value"]
+            for f in ("kernel", "blocked")}
+
+
+@pytest.mark.parametrize("form", ["blocked", "kernel"])
+def test_the_scan_counts_the_form_it_chose_once_a_traced_call(
+        form, monkeypatch):
+    """Two layers' scans in one program, at a geometry the gate takes:
+    ``kda/scan_calls.<form>`` rises by two when the program is traced
+    (here the blocked form, on a chip that is pretended the kernel) and
+    not again when the traced program runs or is asked for again."""
+    x = [jnp.ones((64, 8, 128))] * 4 + [jnp.ones((64, 8))]
+    if form == "kernel":
+        monkeypatch.setattr(kda, "_on_tpu", lambda: True)
+
+    def two_layers(*x):
+        o, s = kda.kda_chunk_scan(*x)
+        return kda.kda_chunk_scan(*x, s0=s)[0] + o
+
+    program = jax.jit(two_layers)
+    before = _scan_calls()
+    text = str(program.trace(*x).jaxpr)
+    after = _scan_calls()
+    assert {f: after[f] - before[f] for f in after} == {
+        f: 2.0 * (f == form) for f in after}
+    assert ("pallas_call" in text) is (form == "kernel")
+    if form == "blocked":
+        program(*x), program(*x)
+    else:
+        program.trace(*x)
+    assert _scan_calls() == after
 
 
 @pytest.mark.parametrize("live", [[1, 0, 1, 1, 0], [0] * 5, [1] * 5,
