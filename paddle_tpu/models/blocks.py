@@ -3,8 +3,9 @@ of, the host-side tables their configs are built from, and the ONE class
 through which ``serving.ServingEngine`` drives any of them
 (:class:`ServedLM`, whose docstring is the contract).
 
-``smallthinker.py``, ``kimi_k2.py``, ``laguna.py``, ``ling3_flash.py`` and
-``motif3.py`` take their blocks from here and keep what only they have. A
+``smallthinker.py``, ``kimi_k2.py``, ``laguna.py``, ``ling3_flash.py``,
+``motif3.py``, ``glm5_flash.py`` and ``falcon_h1.py`` take their blocks from
+here and keep what only they have. A
 block two models need is written HERE under a public name; no model module
 imports another's underscore names. Two forms of a block are one function
 only where the merged one needs no argument that says who calls it and the
@@ -34,8 +35,9 @@ from ..ops import moe_ops
 from ..ops.pallas_kernels import kda as kda_ops
 
 __all__ = ["ServedLM", "absorbed_output", "absorbed_query", "at_precision",
-           "gated", "head", "held_experts", "kda_inputs", "kda_output",
-           "kda_prefill",
+           "causal_conv_prefill", "causal_conv_step", "gated",
+           "gated_group_norm", "head", "held_experts", "kda_inputs",
+           "kda_output", "kda_prefill",
            "l2_normalize", "latent", "log_decay", "maps_precision", "mix_in",
            "mix_out", "mla_softmax_scale", "moe_stats", "rms_norm", "rope",
            "rope_lanes", "rope_table", "routed_feed_forward", "seeded_params",
@@ -267,6 +269,43 @@ def kda_prefill(cfg, lp, h, length):
     return kda_output(cfg, lp, h, o), state, tail
 
 
+# -- a state-space mixer's ends (Falcon-H1's Mamba-2 branch) -------------------
+
+def causal_conv_prefill(u, cw, cb, length):
+    """A depth-wise causal convolution over ONE sequence, with the tail it
+    leaves: ``u`` [S, C] the inputs (zeros stand before the request's
+    start), ``cw`` [taps, C] the taps oldest first, ``cb`` [C] the bias or
+    None, ``length`` the valid rows. Returns ``(silu(conv) [S, C] float32,
+    tail [taps - 1, C])``: the tail is the last ``taps - 1`` inputs before
+    position ``length``, what :func:`causal_conv_step` reads next."""
+    s, rows = u.shape[0], cw.shape[0] - 1
+    up = jnp.pad(u, ((rows, 0), (0, 0)))
+    out = causal_conv_step(
+        jnp.stack([up[j:j + s] for j in range(rows + 1)], axis=1), cw, cb)
+    return out, jax.lax.dynamic_slice_in_dim(up, length, rows, axis=0)
+
+
+def causal_conv_step(window, cw, cb):
+    """``silu(sum_j cw_j window[:, j] + cb)`` float32 of ``window`` [B,
+    taps, C], each row's last ``taps`` inputs oldest first (the cache's
+    ``tail_step`` hands a decode step that)."""
+    f32 = jnp.float32
+    y = jnp.sum(window.astype(f32) * cw.astype(f32), axis=1)
+    return jax.nn.silu(y if cb is None else y + cb.astype(f32))
+
+
+def gated_group_norm(y, z, g, groups: int, eps):
+    """``RMSNorm_group(y * silu(z); g)``: the gate FIRST, then an RMS norm
+    over each of ``groups`` equal runs of the last axis (Mamba-2's gated
+    norm with ``norm_before_gate`` false). ``y``, ``z`` [..., C]; float32
+    inside, ``z``'s type out."""
+    f32 = jnp.float32
+    v = y.astype(f32) * jax.nn.silu(z.astype(f32))
+    vg = v.reshape(v.shape[:-1] + (groups, -1))
+    vg = vg * jax.lax.rsqrt(jnp.mean(vg * vg, axis=-1, keepdims=True) + eps)
+    return (vg.reshape(v.shape) * g.astype(f32)).astype(z.dtype)
+
+
 # -- four residual streams (Motif-3, GLM-5.3-Flash) ---------------------------
 
 def at_precision(x, dtype):
@@ -407,8 +446,9 @@ def seeded_params(cfg, seed, init_layer: Callable, layer_args: Callable
 class ServedLM:
     """THE SERVING CONTRACT: what ``serving.ServingEngine`` may ask of a
     model and of its config. ``SmallThinkerLM``, ``KimiK2LM``, ``LagunaLM``,
-    ``Ling3FlashLM`` and ``Motif3LM`` are this class over their module's
-    ``init_params``, ``prefill_forward`` and ``decode_forward``;
+    ``Ling3FlashLM``, ``Motif3LM``, ``Glm5FlashLM`` and ``FalconH1LM`` are
+    this class over their module's ``init_params``, ``prefill_forward`` and
+    ``decode_forward`` (and, where the head is not the plain one, ``head``);
     ``decoder_lm.DecoderLM`` meets it with methods of its own.
 
     The engine reads ``model.cfg``, ``model.params`` and calls:
@@ -449,7 +489,12 @@ class ServedLM:
       layers, window, kind)``; the layers of a group share one ``n_head``
       (a group's decode attention is one kernel shape), ``window`` rows a
       slot are kept as a ring (None: every position, in pages), and the
-      first group is the one admission counts pages of. Absent: one group
+      first group is the one admission counts pages of. A layer is named
+      once a KIND of group: in one paged group (``KV`` or ``LATENT``), in
+      one ``STATE`` group, or in one of each (a block whose two mixers, one
+      over pages and one over a recurrent state, read the same input);
+      the state groups come after the paged ones, and the engine's page
+      accounting is the paged groups' alone. Absent: one group
       of every layer that keeps every position. ``kind`` is one of
       ``KV``, ``LATENT``, ``STATE``, names that ``serving/kv_cache.py``
       OWNS (``serving`` lies below ``models``, which imports them at
@@ -460,6 +505,9 @@ class ServedLM:
     * ``slot_state``: ``(heads, dk, dv, tail rows, tail width)`` that each
       layer of a ``STATE`` group keeps a SLOT, and no pages. Absent: the
       model has no state group;
+    * ``state_recurrence``: ``"kda"`` or ``"ssd"``, the recurrence whose
+      step ``cache_ops.state_step`` runs over those states (and so what
+      its inputs are: ``serving/kv_cache.py``). Absent: ``"kda"``;
     * ``index_row``: ``(rows a block, lanes of an index key, blocks a
       query reads)`` of a latent cache whose layers choose the rows a
       query reads: the cache then keeps a pooled index key a block beside
@@ -474,6 +522,7 @@ class ServedLM:
     init_params: Callable
     prefill_forward: Callable
     decode_forward: Callable
+    head = staticmethod(head)   # (params, cfg, x) -> logits: the plain one
 
     def __init__(self, cfg, params: Dict = None, seed: int = 0):
         self.cfg = cfg
@@ -482,13 +531,13 @@ class ServedLM:
 
     def prefill(self, params, tokens, lengths):
         x, kept = self.prefill_forward(params, self.cfg, tokens, lengths)
-        return head(params, self.cfg, x), kept
+        return self.head(params, self.cfg, x), kept
 
     def prefill_last(self, params, tokens, lengths):
         x, kept = self.prefill_forward(params, self.cfg, tokens, lengths)
         last = jnp.take_along_axis(
             x, (lengths - 1)[:, None, None], axis=1)[:, 0]
-        return head(params, self.cfg, last), kept
+        return self.head(params, self.cfg, last), kept
 
     def decode(self, params, cache, cache_ops, tokens, pos, active):
         return self.decode_forward(params, self.cfg, cache, cache_ops, tokens,

@@ -395,7 +395,8 @@ class ServingEngine:
     ``verify`` where it has it) and what it reads of ``model.cfg``
     (``n_layer``, ``n_head``, ``d_head``, ``max_seq``, ``dtype`` and, by
     ``getattr``, ``n_kv_head``, ``cache_groups``, ``latent_row``,
-    ``slot_state``, ``index_row``, ``experts_held``), each with what its
+    ``slot_state``, ``state_recurrence``, ``index_row``, ``experts_held``),
+    each with what its
     absence means.
     Every ``getattr``/``hasattr`` on a model or its config in this module
     is one of those. The cache groups' kinds (``KV``, ``LATENT``,
@@ -459,6 +460,11 @@ class ServingEngine:
                 kv_scales = self._calibrated_kv_scales(mcfg)
             geometry = dict(dtype=mcfg.dtype, groups=groups,
                             q_per_kv=q_per_kv)
+            slot_state = getattr(mcfg, "slot_state", None)
+            if slot_state is not None:   # K and V pages BESIDE a state
+                geometry.update(
+                    slot_state=slot_state,
+                    recurrence=getattr(mcfg, "state_recurrence", "kda"))
             if kv_scales is not None:
                 self.cache_ops = Int8PagedKVCache(
                     mcfg.n_layer, n_kv, mcfg.d_head, self.cfg.slots,
@@ -1751,6 +1757,12 @@ class ServingEngine:
                                             length[None])
                 last = logits[0, length - 1]
             for i, kv in enumerate(kvs):
+                if isinstance(kv[0], tuple):
+                    # a layer in a paged AND a state group: its rows, then
+                    # what the prompt leaves in its slot
+                    kv, left = kv
+                    cache = ops.write_slot_state(
+                        cache, i, *(t[0] for t in left), dest)
                 cache = ops.write_prompt(cache, i, *(t[0] for t in kv), dest,
                                          length)
             # first generated token: same sampler as the decode scan, keyed

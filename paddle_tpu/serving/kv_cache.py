@@ -78,7 +78,18 @@ different kinds side by side:
   prefill executable arms the slot with it), ``write_prompt`` takes a
   layer's final state and convolution tail, ``tail_step`` and
   ``state_step`` advance them by one decode step for the slots that are
-  ``active`` and leave every other slot's unread and unwritten.
+  ``active`` and leave every other slot's unread and unwritten. The
+  group's RECURRENCE (``recurrence``: ``"kda"``, the delta rule of
+  ops/pallas_kernels/kda.py, or ``"ssd"``, Mamba-2's of ssd.py) says
+  which step ``state_step`` runs and what its inputs are.
+
+A layer stands in ONE group of a kind, and may stand in one PAGED group
+(``KV`` or ``LATENT``) and one ``STATE`` group at once: a block whose two
+mixers read the same input, one over a page pool's rows and one over a
+recurrent state, keeps both. ``write_token``, ``context`` and
+``decode_attention`` then mean the layer's pages, ``tail_step``,
+``state_step`` and ``write_slot_state`` its state, and ``write_prompt``
+its pages (its state where it has no pages).
 
 Both write paths scatter with ``mode="drop"`` on out-of-bounds destination
 rows, so inactive slots / padding positions are dropped INSIDE the compiled
@@ -88,6 +99,7 @@ layouts, which is what makes the gathered contexts bit-identical.
 
 from __future__ import annotations
 
+import functools
 from typing import (Dict, List, Mapping, NamedTuple, Optional, Sequence,
                     Tuple, Union)
 
@@ -98,6 +110,10 @@ __all__ = ["CacheGroup", "PagedKVCache", "Int8PagedKVCache",
            "LatentPagedCache", "ContiguousKVCache", "KV", "LATENT", "STATE"]
 
 KV, LATENT, STATE = "kv", "latent", "state"
+# a state group's recurrence: a module of that name under
+# ops/pallas_kernels has ``<name>_state_step``, ``<name>_state_step_xla``
+# and ``<name>_state_step_gate``
+_RECURRENCES = ("kda", "ssd")
 
 
 class CacheGroup(NamedTuple):
@@ -174,7 +190,8 @@ class PagedKVCache(_KVCacheBase):
                  dtype=jnp.float32,
                  groups: Optional[Sequence[CacheGroup]] = None,
                  q_per_kv: Union[int, Mapping[str, int]] = 1,
-                 slot_state: Optional[Sequence[int]] = None):
+                 slot_state: Optional[Sequence[int]] = None,
+                 recurrence: str = "kda"):
         super().__init__(n_layer, n_head, d_head, slots, max_ctx, dtype)
         if max_ctx % page_size != 0:
             raise ValueError("max_ctx=%d must be a multiple of page_size=%d"
@@ -201,21 +218,32 @@ class PagedKVCache(_KVCacheBase):
                 "tail width) and comes after every paged group (the "
                 "engine's pools are the paged groups', in order): %s"
                 % kinds)
-        # layer -> (its group's index, its index inside that group's pool)
+        if recurrence not in _RECURRENCES:
+            raise ValueError("recurrence=%r is not one of %s"
+                             % (recurrence, sorted(_RECURRENCES)))
+        self.recurrence = recurrence
+        # layer -> (its group's index, its index inside that group's pool
+        # or state buffer), a map a KIND of group: the paged groups', the
+        # state groups'. A layer may be in one of each
         self._where: Dict[int, Tuple[int, int]] = {}
+        self._where_state: Dict[int, Tuple[int, int]] = {}
         for gi, g in enumerate(self.groups):
             if g.window is not None and g.window % self.page_size:
                 raise ValueError("group %r: window=%d must be a multiple of "
                                  "page_size=%d" % (g.name, g.window,
                                                    self.page_size))
+            where = self._where_state if g.kind == STATE else self._where
             for li, layer in enumerate(g.layers):
-                if layer in self._where:
-                    raise ValueError("layer %d is in two cache groups" % layer)
-                self._where[layer] = (gi, li)
-        if sorted(self._where) != list(range(self.n_layer)):
-            raise ValueError("the cache groups must cover layers 0..%d once "
-                             "each, got %s" % (self.n_layer - 1,
-                                               sorted(self._where)))
+                if layer in where:
+                    raise ValueError(
+                        "layer %d is in two %s cache groups"
+                        % (layer, "state" if g.kind == STATE else "paged"))
+                where[layer] = (gi, li)
+        covered = sorted(set(self._where) | set(self._where_state))
+        if covered != list(range(self.n_layer)):
+            raise ValueError("the cache groups must cover layers 0..%d, each "
+                             "once a kind, got %s" % (self.n_layer - 1,
+                                                      covered))
         # query heads a KV head, by group name
         if not isinstance(q_per_kv, Mapping):
             q_per_kv = {g.name: q_per_kv for g in self.groups}
@@ -530,19 +558,36 @@ class PagedKVCache(_KVCacheBase):
     # -- a state group's decode step -----------------------------------------
     def state_kernel_mode(self):
         """:meth:`kernel_mode`'s twin for the state groups' decode step:
-        ``(mode, why_not)`` by the same flag and
-        ``pallas_kernels.kda.kda_state_step_gate`` over ``slot_state``."""
+        ``(mode, why_not)`` by the same flag and the recurrence's own
+        ``*_state_step_gate`` over ``slot_state``."""
         from ..ops import attention_ops
-        from ..ops.pallas_kernels.kda import kda_state_step_gate
 
         mode = attention_ops.paged_kernel_mode()
         if mode is None:
             return None, "n/a"
-        why_not = kda_state_step_gate(*self.slot_state[:3],
-                                      interpret=(mode == "interpret"))
+        gate, _, _ = self._state_step_forms()
+        why_not = gate(interpret=(mode == "interpret"))
         if why_not is not None:
             return None, "gate: " + why_not
         return mode, None
+
+    def _state_step_forms(self):
+        """``(gate, XLA form, kernel)`` of the state groups' recurrence.
+        The gate takes ``interpret`` alone: Mamba-2's also needs the groups
+        that share ``B`` and ``C``, which the tail's width tells (``heads x
+        dv`` channels of ``x`` and ``2 x groups x dk`` of ``B`` and
+        ``C``)."""
+        h, dk, dv, _, width = self.slot_state
+        if self.recurrence == "ssd":
+            from ..ops.pallas_kernels import ssd
+
+            return (functools.partial(ssd.ssd_state_step_gate, h, dk, dv,
+                                      (width - h * dv) // (2 * dk)),
+                    ssd.ssd_state_step_xla, ssd.ssd_state_step)
+        from ..ops.pallas_kernels import kda
+
+        return (functools.partial(kda.kda_state_step_gate, h, dk, dv),
+                kda.kda_state_step_xla, kda.kda_state_step)
 
     def tail_step(self, state: Cache, layer: int, u, active):
         """One decode step of a state layer's convolution tail: ``u`` [B,
@@ -551,7 +596,7 @@ class PagedKVCache(_KVCacheBase):
         (what a causal convolution of ``rows + 1`` taps reads), and the
         tails advanced by one row where ``active``; elsewhere as they
         were."""
-        gi, li = self._where[layer]
+        gi, li = self._where_state[layer]
         key = self._key(gi, "tail")
         tail = state[key][li]
         window = jnp.concatenate([tail, u[:, None].astype(tail.dtype)],
@@ -559,27 +604,25 @@ class PagedKVCache(_KVCacheBase):
         new = jnp.where(active[:, None, None], window[:, 1:], tail)
         return window, {**state, key: state[key].at[li].set(new)}
 
-    def state_step(self, state: Cache, layer: int, q, k, v, a, beta,
-                   active):
-        """One step of a state layer's recurrence
-        (ops/pallas_kernels/kda.py) for the slots that are ``active``:
-        ``q``/``k``/``a`` [B, H, dk], ``v`` [B, H, dv], ``beta`` [B, H].
-        Returns ``(o [B, H, dv] float32, state)``. By the kernel where
-        :meth:`state_kernel_mode` arms it (the group's whole state buffer
-        aliased in and out, an inactive slot's neither read nor written),
-        else in plain XLA (computed for all, kept where active)."""
-        from ..ops.pallas_kernels import kda
-
-        gi, li = self._where[layer]
+    def state_step(self, state: Cache, layer: int, *inputs_active):
+        """One step of a state layer's recurrence for the slots that are
+        ``active``, the last argument. ``"kda"``
+        (ops/pallas_kernels/kda.py): ``q``/``k``/``a`` [B, H, dk], ``v``
+        [B, H, dv], ``beta`` [B, H]. ``"ssd"`` (ssd.py): ``x`` [B, H, dv],
+        ``b``/``c`` [B, G, dk], ``a`` [B, H]. Returns ``(o [B, H, dv]
+        float32, state)``. By the kernel where :meth:`state_kernel_mode`
+        arms it (the group's whole state buffer aliased in and out, an
+        inactive slot's neither read nor written), else in plain XLA
+        (computed for all, kept where active)."""
+        gi, li = self._where_state[layer]
         key = self._key(gi, "s")
         mode, _ = self.state_kernel_mode()
+        _, step_xla, step_kernel = self._state_step_forms()
         if mode is None:
-            o, s = kda.kda_state_step_xla(state[key], li, q, k, v, a, beta,
-                                          active)
+            o, s = step_xla(state[key], li, *inputs_active)
         else:
-            o, s = kda.kda_state_step(
-                state[key], li, q, k, v, a, beta, active,
-                interpret=(mode == "interpret"))
+            o, s = step_kernel(state[key], li, *inputs_active,
+                               interpret=(mode == "interpret"))
         return o, {**state, key: s}
 
     # -- prefill (one sequence) ----------------------------------------------
@@ -611,20 +654,14 @@ class PagedKVCache(_KVCacheBase):
         :meth:`prompt_dest_groups`'s row; positions >= length are dropped,
         and in a window group the positions that have already left the
         window (< length - window) too: the last ``min(length, window)``
-        land at their places in the ring. For a layer of a state group
-        ``k_new`` is the state ``[H, dk, dv]`` the prompt leaves and
-        ``v_new`` its convolution tail ``[rows, width]``, written whole to
-        the slot ``dest`` names."""
+        land at their places in the ring. For a layer that has a state
+        and NO pages ``k_new`` is the state and ``v_new`` the tail:
+        :meth:`write_slot_state`'s."""
         ps = self.page_size
+        if layer not in self._where:
+            return self.write_slot_state(state, layer, k_new, v_new, dest)
         gi, li = self._where[layer]
         off = self._pt_start[gi]
-        if self.groups[gi].kind == STATE:
-            sk, tk = self._key(gi, "s"), self._key(gi, "tail")
-            return {**state,
-                    sk: state[sk].at[li, dest[off]].set(
-                        k_new.astype(jnp.float32)),
-                    tk: state[tk].at[li, dest[off]].set(
-                        v_new.astype(state[tk].dtype))}
         s = k_new.shape[0]
         j = jnp.arange(s)
         keep = j < length
@@ -635,6 +672,21 @@ class PagedKVCache(_KVCacheBase):
         flat = dest[off + idx] * ps + j % ps
         flat = jnp.where(keep, flat, self._drop_row(gi))
         return self._write_rows(state, layer, flat, k_new, v_new)
+
+    def write_slot_state(self, state: Cache, layer: int, s_new, tail_new,
+                         dest) -> Cache:
+        """What a prompt LEAVES in a layer of a state group: its state
+        ``s_new`` [H, dk, dv] and its convolution tail ``tail_new`` [rows,
+        width], written whole to the slot ``dest``
+        (:meth:`prompt_dest_groups`'s row) names."""
+        gi, li = self._where_state[layer]
+        off = self._pt_start[gi]
+        sk, tk = self._key(gi, "s"), self._key(gi, "tail")
+        return {**state,
+                sk: state[sk].at[li, dest[off]].set(
+                    s_new.astype(jnp.float32)),
+                tk: state[tk].at[li, dest[off]].set(
+                    tail_new.astype(state[tk].dtype))}
 
     # -- page migration ------------------------------------------------------
     def _page_rows(self, pages) -> np.ndarray:

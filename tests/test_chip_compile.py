@@ -1436,3 +1436,155 @@ def test_sparse_hybrid_decoder_decode_step(chip, monkeypatch):
         r"\((\d+), \{\}, (?:may|must)-alias\)", text.split("\n", 1)[0])}
     # "c" "ik" "it" "pt" "s.state" "tail.state" in key order
     assert {n_params, n_params + 1, n_params + 4} <= aliased
+
+
+# -- Falcon-H1: a Mamba-2 state beside a GQA page pool in every layer -----------
+
+def test_ssd_state_step_kernel_at_the_served_geometry(chip):
+    """64 slots of 32 heads of a 256 x 128 float32 state (2 groups of 16
+    heads, a group a grid step), five layers in one buffer, a layer in the
+    middle: the kernel compiles under its own name, the whole buffer is
+    aliased from input to output, and nothing outside the kernel touches
+    it."""
+    from paddle_tpu.ops.pallas_kernels import ssd
+
+    assert ssd.ssd_state_step_gate(32, 256, 128, 2) is None
+    shape = (5, 64, 32, 256, 128)
+    args = [jax.ShapeDtypeStruct(s, d, sharding=chip) for s, d in (
+        (shape, jnp.float32), ((64, 32, 128), jnp.float32),
+        ((64, 2, 256), jnp.float32), ((64, 2, 256), jnp.float32),
+        ((64, 32), jnp.float32), ((64,), jnp.bool_))]
+    text = jax.jit(
+        lambda s, x, b, c, a, live: ssd.ssd_state_step(s, 2, x, b, c, a,
+                                                       live),
+        donate_argnums=(0,)).lower(*args).compile().as_text()
+    kernel, = [ln for ln in text.split("\n") if "tpu_custom_call" in ln]
+    assert kernel.strip().startswith("%ssd_state_step")
+    assert "f32[5,64,32,256,128]" in kernel.split(" custom-call(")[0]
+    assert [op for _, rtype, op, _ in _instructions(text)
+            if _has_dim(rtype, 5) and "256,128" in rtype
+            and op not in ("parameter", "custom-call", "get-tuple-element",
+                           "tuple")] == []
+    assert re.search(r"\(0, \{\}, (?:may|must)-alias\)",
+                     text.split("\n", 1)[0])
+
+
+@pytest.mark.parametrize("rows", [1024, 4096])
+def test_ssd_chunk_scan_kernel_at_the_served_geometry(chip, monkeypatch,
+                                                      rows):
+    """A bucket's rows of 32 heads of 128 channels, 2 groups of 256 state
+    lanes, chunks of 128: on a TPU ``ssd_chunk_scan`` takes the kernel
+    form, ONE call whose grid holds the chunks."""
+    from paddle_tpu.ops.pallas_kernels import ssd
+
+    monkeypatch.setattr(attention_ops, "_on_tpu", lambda: True)
+    assert ssd.ssd_chunk_scan_gate(32, 2, 256, 128) is None
+    f32 = jnp.float32
+    text = compiled_text(
+        chip, lambda x, b, c, a: ssd.ssd_chunk_scan(x, b, c, a),
+        ((rows, 32, 128), f32), ((rows, 2, 256), f32), ((rows, 2, 256), f32),
+        ((rows, 32), f32))
+    kernel, = [ln for ln in text.split("\n") if "tpu_custom_call" in ln]
+    assert kernel.strip().startswith("%ssd_chunk_scan")
+
+
+FALCON_SERVE = dict(slots=64, page_size=16, max_seq=8192, pages=12288)
+
+
+def _falcon_case(chip, n_layer=2):
+    """Falcon-H1-34B at its published widths, ``n_layer`` whole layers and
+    the whole vocabulary, over the cell's K/V pool and 64 slots' states:
+    ``(model, params, ops, cache)`` as shapes on the described chip."""
+    import json
+
+    from grid.drivers import serve_ssm
+    from paddle_tpu.models import falcon_h1 as fh
+    from paddle_tpu.serving.kv_cache import CacheGroup, PagedKVCache
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "grid", "configs",
+                           "falcon-h1-34b-serve.json")) as f:
+        cfg = serve_ssm.model_config(dict(json.load(f),
+                                          num_hidden_layers=n_layer))
+    model = fh.FalconH1LM(cfg, params={})
+
+    def sds(x):
+        return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=chip)
+
+    params = jax.tree_util.tree_map(
+        sds, jax.eval_shape(lambda: fh.init_params(cfg, 0)))
+    g = FALCON_SERVE
+    groups = [CacheGroup(name, layers, window,
+                         g["pages"] if kind == "kv" else 0, kind)
+              for name, layers, window, kind in cfg.cache_groups]
+    ops = PagedKVCache(n_layer, 4, 128, g["slots"], g["max_seq"],
+                       g["page_size"], g["pages"], dtype="bfloat16",
+                       groups=groups, q_per_kv=5, slot_state=cfg.slot_state,
+                       recurrence=cfg.state_recurrence)
+    cache = jax.tree_util.tree_map(sds, jax.eval_shape(ops.init_state))
+    return model, params, ops, cache
+
+
+def test_parallel_hybrid_decoder_decode_step(chip, monkeypatch):
+    """A decode step of two whole layers: EACH layer calls the state-step
+    kernel over the group's whole float32 state buffer AND the paged
+    kernel at 5 query heads a KV head (8 rows a KV head: its result is
+    ``[64, 8, 512]``); neither the K and V pools nor the states are
+    copied, sliced or turned, and every one is aliased from input to
+    output."""
+    monkeypatch.setattr(attention_ops, "paged_kernel_mode",
+                        lambda: "compiled")
+    monkeypatch.setattr(attention_ops, "_on_tpu", lambda: True)
+    model, params, ops, cache = _falcon_case(chip)
+    assert ops.kernel_mode() == ("compiled", None)
+    assert ops.state_kernel_mode() == ("compiled", None)
+    ints = jax.ShapeDtypeStruct((64,), jnp.int32, sharding=chip)
+    flags = jax.ShapeDtypeStruct((64,), jnp.bool_, sharding=chip)
+
+    def chunk(params, cache, lengths, tokens, active):
+        logits, cache, stats = model.decode(params, cache, ops, tokens,
+                                            lengths, active)
+        return cache, jnp.argmax(logits, -1), stats
+
+    text = jax.jit(chunk, donate_argnums=(1,)).lower(
+        params, cache, ints, ints, flags).compile().as_text()
+    kernels = [ln.strip().split(" custom-call(")[0]
+               for ln in text.split("\n") if "tpu_custom_call" in ln]
+    steps = [k for k in kernels if k.startswith("%ssd_state_step")]
+    paged = [k for k in kernels if k.startswith("%paged_attention")]
+    assert len(steps) == 2 and len(paged) == 2
+    assert all("f32[2,64,32,256,128]" in k for k in steps)
+    assert all("bf16[64,8,512]" in k for k in paged)
+    instructions = list(_instructions(text))
+    types = {name: rtype for name, rtype, _, _ in instructions}
+    rows = FALCON_SERVE["pages"] * 16
+    moved = [(op, rtype) for _, rtype, op, operands in instructions
+             if op in ("copy", "copy-start", "slice", "dynamic-slice",
+                       "transpose")
+             and any(_has_dim(t, rows) or "32,256,128" in t
+                     for t in [rtype] + [types.get(o, "") for o in operands])]
+    assert moved == [], moved
+    n_params = len(jax.tree_util.tree_leaves(params))
+    aliased = {int(p) for p in re.findall(
+        r"\((\d+), \{\}, (?:may|must)-alias\)", text.split("\n", 1)[0])}
+    # "k" "pt" "s.ssm" "tail.ssm" "v" in key order
+    assert {n_params, n_params + 2, n_params + 3, n_params + 4} <= aliased
+
+
+def test_parallel_hybrid_decoder_prefill_holds_the_scan_kernel(chip,
+                                                               monkeypatch):
+    """The 1,024-row bucket's prefill of two whole layers: each layer's
+    chunk scan is ONE ``ssd_chunk_scan`` kernel call (no loop of the
+    compiler's carries the state), and causal attention composes its
+    scores below the flash kernel's length."""
+    monkeypatch.setattr(attention_ops, "_on_tpu", lambda: True)
+    model, params, _, _ = _falcon_case(chip)
+    toks = jax.ShapeDtypeStruct((1, 1024), jnp.int32, sharding=chip)
+    lens = jax.ShapeDtypeStruct((1,), jnp.int32, sharding=chip)
+    text = jax.jit(model.prefill_last).lower(params, toks,
+                                             lens).compile().as_text()
+    kernels = [ln.strip().split(" = ")[0] for ln in text.split("\n")
+               if "tpu_custom_call" in ln]
+    assert sum(k.startswith("%ssd_chunk_scan") for k in kernels) == 2
+    assert not [rtype for _, rtype, op, _ in _instructions(text)
+                if op == "while" and "f32[32,256,128]" in rtype]

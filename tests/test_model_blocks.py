@@ -12,16 +12,18 @@ import importlib
 import json
 import os
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from grid.reference import falcon_h1 as falcon_ref
 from grid.reference import glm5_flash as glm5_ref
 from grid.reference import kimi_k2 as kimi_ref
 from grid.reference import laguna as laguna_ref
 from grid.reference import ling3_flash as ling3_ref
 from grid.reference import motif3 as motif3_ref
-from paddle_tpu.models import blocks, glm5_flash, ling3_flash
+from paddle_tpu.models import blocks, falcon_h1, glm5_flash, ling3_flash
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -36,6 +38,7 @@ MOTIF = published("motif-3-beta-ep16-serve")
 LAGUNA = published("laguna-s-ep2-serve")
 LING = published("ling-3-flash-ep4-serve")
 GLM = published("glm-5.3-flash-ep8-serve")
+FALCON = published("falcon-h1-34b-serve")
 
 
 @pytest.mark.parametrize("want,rot,theta,scaling", [
@@ -194,9 +197,84 @@ def test_the_sparse_hybrids_helpers_are_the_references(what):
                                atol=2e-6)
 
 
+def _falcon_cfg():
+    from grid.drivers import serve_ssm
+
+    return serve_ssm.model_config(FALCON)
+
+
+@pytest.mark.parametrize("what", ["rope", "mup", "sizes", "gated_norm",
+                                  "convolution", "seeds"])
+def test_the_parallel_hybrids_tables_are_the_references(what):
+    """Falcon-H1's tables in the program against the reference's, at the
+    published configuration's values: the rotary frequencies at theta
+    1e11, the multipliers laid over the SSM projection's five segments,
+    the sizes both derive, the gated grouped norm, the convolution, and
+    the seeds the configuration's ``assumed`` states."""
+    cfg = _falcon_cfg()
+    rng = np.random.RandomState(5)
+    z = falcon_ref.sizes(FALCON)
+    if what == "rope":
+        x = jnp.asarray(rng.randn(6, 2, 128).astype("float32"))
+        pos = jnp.asarray([0, 1, 7, 1000, 100000, 262143])
+        got = blocks.rope(x, pos, cfg.inv_freq)
+        want = falcon_ref._rope(x, pos, float(FALCON["rope_theta"]))
+        assert cfg.rope_theta == 1e11 and cfg.inv_freq.shape == (64,)
+    elif what == "mup":
+        got = falcon_h1._mup_vector(cfg)
+        want = np.concatenate([
+            np.full(w, FALCON["ssm_in_multiplier"] * m, "float32")
+            for w, m in zip((4096, 4096, 512, 512, 32),
+                            FALCON["ssm_multipliers"])])
+        assert got.shape == (9248,) and cfg.in_segments == (
+            4096, 4096, 512, 512, 32)
+        assert {k: FALCON[k] for k in falcon_h1.MUP_KEYS} == {
+            k: (list(v) if isinstance(v, tuple) else v)
+            for k, v in cfg.mup.items()}
+    elif what == "sizes":
+        assert (z["H"], z["N"], z["P"], z["G"]) == (32, 256, 128, 2)
+        assert cfg.slot_state == (z["H"], z["N"], z["P"], z["taps"] - 1,
+                                  z["d_ssm"] + 2 * z["G"] * z["N"])
+        assert cfg.slot_state == (32, 256, 128, 3, 5120)
+        assert (cfg.n_head, cfg.n_kv_head, cfg.d_head) == (
+            z["n_head"], z["n_kv"], z["d_head"]) == (20, 4, 128)
+        assert cfg.chunk == FALCON["mamba_chunk_size"] == 128
+        assert cfg.vocab_size == 261120 and cfg.d_ff == 21504
+        assert cfg.n_layer == 5 and FALCON["published"] == {
+            "num_hidden_layers": 72}
+        return
+    elif what == "gated_norm":
+        y, gate = (jnp.asarray(rng.randn(3, 4096).astype("float32"))
+                   for _ in range(2))
+        g = jnp.asarray(rng.rand(4096).astype("float32") + 0.5)
+        got = blocks.gated_group_norm(y, gate, g, cfg.ssm_groups,
+                                      cfg.rms_eps)
+        v = (y * jax.nn.silu(gate)).reshape(3, 2, 2048)
+        want = (v / jnp.sqrt(jnp.mean(v * v, axis=-1, keepdims=True)
+                             + FALCON["rms_norm_eps"])).reshape(3, 4096) * g
+    elif what == "convolution":
+        u = jnp.asarray(rng.randn(9, 16).astype("float32"))
+        cw = jnp.asarray(rng.randn(4, 16).astype("float32"))
+        cb = jnp.asarray(rng.randn(16).astype("float32"))
+        got, _ = blocks.causal_conv_prefill(u, cw, cb, 9)
+        up = jnp.pad(u, ((3, 0), (0, 0)))
+        want = jax.nn.silu(sum(cw[j] * up[j:j + 9] for j in range(4)) + cb)
+        assert cfg.conv_taps == FALCON["mamba_d_conv"] == 4
+    else:
+        m = FALCON["model"]
+        assert cfg.seed_rms == m["seed_rms"] == falcon_h1.SEED_RMS
+        assert cfg.dt_range == tuple(m["dt_range"]) == (0.001, 0.1)
+        assert cfg.a_range == tuple(m["a_range"]) == (1.0, 16.0)
+        assert cfg.dtype == jnp.bfloat16 and m["state_dtype"] == "float32"
+        return
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=2e-5,
+                               atol=2e-6)
+
+
 SERVED = [("smallthinker", "SmallThinkerLM"), ("kimi_k2", "KimiK2LM"),
           ("laguna", "LagunaLM"), ("ling3_flash", "Ling3FlashLM"),
-          ("motif3", "Motif3LM"), ("glm5_flash", "Glm5FlashLM")]
+          ("motif3", "Motif3LM"), ("glm5_flash", "Glm5FlashLM"),
+          ("falcon_h1", "FalconH1LM")]
 
 
 @pytest.mark.parametrize("module,cls", SERVED, ids=[m for m, _ in SERVED])
