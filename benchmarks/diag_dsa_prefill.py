@@ -1,7 +1,11 @@
 """Times the parts of GLM-5.3-Flash's prefill alone on the chip, at the
 served widths and a bucket of ``--rows``: the DSA layer's masked attention
-by query blocks (index scores, selection, attention: each alone and
-together, at several ``block_q``), and one KDA layer's chunk scan at 64
+in both its forms (the blocked XLA at several ``block_q`` and the
+``dsa_prefill_attention`` kernel: their times, the largest gap between
+their results, the seconds one copy of the kernel takes to lower and to
+compile, the kernel alone under a mask made before, and with ``--sweep``
+the kernel at other tiles), index scores and
+selection each alone, and one KDA layer's chunk scan at 64
 and at 32 heads in both its forms (the XLA loop and the ``kda_chunk_scan``
 kernel: their times, the largest gap between their results, the seconds
 one copy of the kernel takes to lower and to compile, and a KDA layer's
@@ -34,12 +38,14 @@ def best_ms(fn, *args, reps):
     return min(times)
 
 
-def dsa_part(out, s, reps, trace_dir):
+def dsa_part(out, s, reps, trace_dir, sweep=()):
     """The DSA layer's masked attention at GLM's widths, ``s`` rows."""
     import jax
     import jax.numpy as jnp
 
+    from paddle_tpu.monitor import metrics
     from paddle_tpu.ops import attention_ops as ao
+    from paddle_tpu.ops.pallas_kernels import dsa_prefill as dp
 
     h, d, hi, li, kpool, top = 64, 256, 32, 128, 4, 512
     bf = jnp.bfloat16
@@ -62,11 +68,52 @@ def dsa_part(out, s, reps, trace_dir):
         out["device_ops_ms"] = [
             [name, round(t * 1e3, 2)] for name, t in
             reduce.breakdown(trace, None, top=14)["device_ops"]]
-    for bq in (128, 256, 512):
-        fn = jax.jit(lambda *a, bq=bq: ao.dsa_causal_attention(
-            *a, kpool, top, 0.0625, block_q=bq))
-        out["dsa_causal_attention_ms.block_q_%d" % bq] = best_ms(
-            fn, q, k, v, qi, wi, kp, reps=reps)
+    # the blocked form: what dsa_causal_attention takes where no chip is
+    on_tpu = ao._on_tpu
+    ao._on_tpu = lambda: False
+    try:
+        for bq in (128, 256, 512):
+            fn = jax.jit(lambda *a, bq=bq: ao.dsa_causal_attention(
+                *a, kpool, top, 0.0625, block_q=bq))
+            out["dsa_causal_attention_ms.blocked.block_q_%d" % bq] = best_ms(
+                fn, q, k, v, qi, wi, kp, reps=reps)
+            if bq == 256:
+                blocked = fn(q, k, v, qi, wi, kp)
+    finally:
+        ao._on_tpu = on_tpu
+    out["dsa_prefill_gate"] = dp.dsa_prefill_gate(h, d, d, s, kpool)
+    fn = jax.jit(lambda *a: ao.dsa_causal_attention(*a, kpool, top, 0.0625))
+    out["dsa_causal_attention_ms.kernel"] = best_ms(
+        fn, q, k, v, qi, wi, kp, reps=reps)
+    gap = jnp.abs(fn(q, k, v, qi, wi, kp).astype(jnp.float32)
+                  - blocked.astype(jnp.float32))
+    out["dsa_causal_attention_gap"] = float(gap.max())
+    out["dsa_causal_attention_gap_mean"] = float(gap.mean())
+    out["dsa_prefill_calls"] = {
+        form: int(metrics.counter("dsa/prefill_calls." + form).value)
+        for form in ("kernel", "blocked")}
+
+    # the kernel alone, under the mask of rows that choose nothing (the
+    # causal triangle: the work is the mask's shape's, not its content's)
+    mask = jnp.tril(jnp.ones((s, s), jnp.int8))
+    t0 = time.perf_counter()
+    lowered = jax.jit(lambda *a: dp.dsa_prefill_attention(
+        *a, sm_scale=0.0625)).lower(q, k, v, mask)
+    t1 = time.perf_counter()
+    alone = lowered.compile()
+    out["dsa_prefill_attention_lower_s"] = t1 - t0
+    out["dsa_prefill_attention_compile_s"] = time.perf_counter() - t1
+    out["dsa_prefill_attention_ms"] = best_ms(alone, q, k, v, mask,
+                                              reps=reps)
+    for tiles in sweep:
+        bq, bk, g = (int(x) for x in tiles.split("x"))
+        fn = jax.jit(lambda *a, bq=bq, bk=bk, g=g: dp.dsa_prefill_attention(
+            *a, sm_scale=0.0625, block_q=bq, block_k=bk, heads=g))
+        try:
+            out["dsa_prefill_attention_ms.%s" % tiles] = best_ms(
+                fn, q, k, v, mask, reps=reps)
+        except Exception as e:      # a tile the chip's compiler refuses
+            out["dsa_prefill_attention_ms.%s" % tiles] = repr(e)[:200]
 
     def index_only(qi, wi, kp):
         own = jnp.arange(s) // kpool
@@ -147,6 +194,9 @@ def main(argv=None) -> int:
     ap.add_argument("--reps", type=int, default=3)
     ap.add_argument("--parts", default="dsa,kda",
                     help="which layers' parts to time")
+    ap.add_argument("--sweep", default="",
+                    help="the kernel alone at other tiles too: a list of "
+                         "BLOCK_QxBLOCK_KxHEADS, such as 1024x512x4,256x256x8")
     ap.add_argument("--trace", default="",
                     help="a directory: profile one call of the DSA layer's "
                          "attention there and print its operations by time")
@@ -159,7 +209,8 @@ def main(argv=None) -> int:
     out = {"rows": args.rows, "device": jax.devices()[0].device_kind}
     parts = args.parts.split(",")
     if "dsa" in parts:
-        dsa_part(out, args.rows, args.reps, args.trace)
+        dsa_part(out, args.rows, args.reps, args.trace,
+                 [t for t in args.sweep.split(",") if t])
     if "kda" in parts:
         kda_part(out, args.rows, args.reps)
     print(json.dumps(out))

@@ -290,16 +290,17 @@ def _single_tile(q, k, v, seg_q, seg_kv, causal, sm_scale, rate, rng, mesh):
         out_specs=row, check_vma=False)(q, k, v, seg_q, seg_kv, seed)
 
 
-def _count(path: str) -> None:
-    """One more ``sdpa`` call traced into ``path``: trace-time counters
+def _count(path: str, calls: str = "attention/sdpa_calls",
+           of: str = "sdpa") -> None:
+    """One more call of ``of`` traced into ``path``: trace-time counters
     (an executable's calls count once, when it is traced)."""
     from ..monitor import metrics
 
     metrics.counter(
-        "attention/sdpa_calls." + path,
-        help="sdpa calls traced into the %s path (counted where sdpa "
+        "%s.%s" % (calls, path),
+        help="%s calls traced into the %s path (counted where %s "
              "chooses: once a call of a traced program, not once a run)"
-             % path).inc()
+             % (of, path, of)).inc()
 
 
 def sdpa(q, k, v, bias=None, segment_ids_q=None, segment_ids_kv=None,
@@ -714,23 +715,39 @@ def dsa_causal_attention(q, k, v, q_idx, w_idx, k_pool, kpool: int,
     block of ``kpool`` rows. Row t reads the rows <= t of its own block and
     the ``top_blocks - 1`` blocks of highest index score among those CLOSED
     before its own (all of them where there are fewer; :func:`dsa_select`'s
-    rule). A selection depends on its query, so the mask is a ROW's own:
-    the scores of ``block_q`` query rows against the whole sequence are
+    rule). A selection depends on its query, so the mask is a ROW's own,
+    made ``block_q`` query rows at a time. On a TPU, where
+    ``dsa_prefill_gate`` takes the shapes, the rows' masks are kept as
+    ``int8 [S, S]`` and the attention is ONE ``dsa_prefill_attention``
+    kernel call (pallas_kernels/dsa_prefill.py: no score reaches HBM, no
+    key tile past a query block is read). Elsewhere the BLOCKED form: the
+    float32 scores of ``block_q`` query rows against the whole sequence are
     held at a time, never the [S, S] of all (on a v5e at S = 8,192 and 64
     heads of 256: 131, 106 and 93 ms a layer at 128, 256 and 512 rows a
-    block; PERF.md, PR 47). Returns [S, H, Dv]."""
-    s = q.shape[0]
+    block; PERF.md, PR 47). ``dsa/prefill_calls.kernel`` and ``.blocked``
+    count which. Returns [S, H, Dv]."""
+    from .pallas_kernels import dsa_prefill
+
+    s, n_head, d = q.shape
     bq = _divisor_block(block_q, s, s)
     cols = jnp.arange(s)[None, :]
+    kernel = _on_tpu() and dsa_prefill.dsa_prefill_gate(
+        n_head, d, v.shape[-1], s, kpool, q.dtype.itemsize) is None
+    _count("kernel" if kernel else "blocked", "dsa/prefill_calls",
+           "dsa_causal_attention")
 
-    def rows_of(args):
-        i, qb, qib, wib = args
+    def mask_of(i, qib, wib):
         rows = i * bq + jnp.arange(bq)
         own = rows // kpool
         chosen, _ = dsa_select(
             dsa_index_scores(qib, wib, k_pool, own), own, top_blocks)
         with jax.named_scope("attn/dsa_sparse"):
-            mask = jnp.repeat(chosen, kpool, axis=1) & (cols <= rows[:, None])
+            return jnp.repeat(chosen, kpool, axis=1) & (cols <= rows[:, None])
+
+    def rows_of(args):
+        i, qb, qib, wib = args
+        mask = mask_of(i, qib, wib)
+        with jax.named_scope("attn/dsa_sparse"):
             sc = jnp.einsum("qhd,khd->hqk", qb, k,
                             preferred_element_type=jnp.float32) * sm_scale
             sc = jnp.where(mask[None], sc, neg_inf(jnp.float32))
@@ -749,7 +766,15 @@ def dsa_causal_attention(q, k, v, q_idx, w_idx, k_pool, kpool: int,
     def split(x):
         return x.reshape((s // bq, bq) + x.shape[1:])
 
-    out = jax.lax.map(rows_of, (jnp.arange(s // bq), split(q), split(q_idx),
+    blocks = jnp.arange(s // bq)
+    if kernel:
+        mask = jax.lax.map(
+            lambda a: mask_of(*a).astype(jnp.int8),
+            (blocks, split(q_idx), split(w_idx)))
+        with jax.named_scope("attn/dsa_sparse"):
+            return dsa_prefill.dsa_prefill_attention(
+                q, k, v, mask.reshape(s, s), sm_scale=float(sm_scale))
+    out = jax.lax.map(rows_of, (blocks, split(q), split(q_idx),
                                 split(w_idx)))
     return out.reshape((s,) + out.shape[2:])
 

@@ -1368,20 +1368,12 @@ def test_sparse_latent_kernel_at_the_served_geometry(chip):
             if _has_dim(rtype, rows) and op != "parameter"] == []
 
 
-def test_sparse_hybrid_decoder_decode_step(chip, monkeypatch):
-    """The decode step of the sparse latent hybrid at its published
-    widths, as one chip of eight holds it (36 of 288 experts, 19,360 rows
-    of the vocabulary), a dense KDA layer and the sparse DSA layer over the
-    cell's pool, index and states: the state kernel once, the sparse read
-    once under its own name (and no dense latent call), the fused expert
-    kernel; the latent pool is neither copied nor sliced, and pool, index
-    and states are aliased from input to output."""
+def _glm_case(chip):
+    """The sparse latent hybrid at its published widths, as one chip of
+    eight holds it (36 of 288 experts, 19,360 rows of the vocabulary): a
+    dense KDA layer and the sparse DSA layer, parameters as shapes."""
     from paddle_tpu.models import glm5_flash as gf
-    from paddle_tpu.serving.kv_cache import CacheGroup, LatentPagedCache
 
-    monkeypatch.setattr(attention_ops, "paged_kernel_mode",
-                        lambda: "compiled")
-    monkeypatch.setattr(attention_ops, "_on_tpu", lambda: True)
     cfg = gf.Glm5FlashConfig(
         vocab_size=19360, n_layer=2, d_model=4096, n_head=64, d_state=128,
         layer_types=[gf.KDA, gf.DSA], q_rank=1536, kv_rank=512, d_nope=256,
@@ -1396,6 +1388,22 @@ def test_sparse_hybrid_decoder_decode_step(chip, monkeypatch):
 
     params = jax.tree_util.tree_map(
         sds, jax.eval_shape(lambda: gf.init_params(cfg, 0)))
+    return cfg, model, params, sds
+
+
+def test_sparse_hybrid_decoder_decode_step(chip, monkeypatch):
+    """The decode step of the sparse latent hybrid at its published
+    widths, a dense KDA layer and the sparse DSA layer over the
+    cell's pool, index and states: the state kernel once, the sparse read
+    once under its own name (and no dense latent call), the fused expert
+    kernel; the latent pool is neither copied nor sliced, and pool, index
+    and states are aliased from input to output."""
+    from paddle_tpu.serving.kv_cache import CacheGroup, LatentPagedCache
+
+    monkeypatch.setattr(attention_ops, "paged_kernel_mode",
+                        lambda: "compiled")
+    monkeypatch.setattr(attention_ops, "_on_tpu", lambda: True)
+    cfg, model, params, sds = _glm_case(chip)
     groups = [CacheGroup(name, layers, window,
                          40960 if kind == "latent" else 0, kind)
               for name, layers, window, kind in cfg.cache_groups]
@@ -1436,6 +1444,36 @@ def test_sparse_hybrid_decoder_decode_step(chip, monkeypatch):
         r"\((\d+), \{\}, (?:may|must)-alias\)", text.split("\n", 1)[0])}
     # "c" "ik" "it" "pt" "s.state" "tail.state" in key order
     assert {n_params, n_params + 1, n_params + 4} <= aliased
+
+
+@pytest.mark.parametrize("bucket", [4096, 8192])
+def test_sparse_hybrid_decoder_prefill_attends_in_one_kernel(chip,
+                                                             monkeypatch,
+                                                             bucket):
+    """The cell's two buckets' prefills of a KDA and a DSA layer: the DSA
+    layer's attention under its rows' own masks is ONE
+    ``dsa_prefill_attention`` call, no score of 64 heads against the
+    bucket's keys is a float32 result of any instruction, and no softmax
+    became a ``reduce-window`` (the selection's and the experts' running
+    counts, int32, are the only ones)."""
+    from paddle_tpu.ops.pallas_kernels import dsa_prefill
+
+    monkeypatch.setattr(attention_ops, "_on_tpu", lambda: True)
+    _, model, params, _ = _glm_case(chip)
+    assert dsa_prefill.dsa_prefill_gate(64, 256, 256, bucket, 4) is None
+    toks = jax.ShapeDtypeStruct((1, bucket), jnp.int32, sharding=chip)
+    lens = jax.ShapeDtypeStruct((1,), jnp.int32, sharding=chip)
+    text = jax.jit(model.prefill_last).lower(params, toks,
+                                             lens).compile().as_text()
+    kernels = [ln.strip().split(" = ")[0] for ln in text.split("\n")
+               if "tpu_custom_call" in ln]
+    assert sum(k.startswith("%dsa_prefill_attention") for k in kernels) == 1
+    instructions = list(_instructions(text))
+    assert [rtype for _, rtype, op, _ in instructions
+            if op == "reduce-window" and not rtype.startswith("s32")] == []
+    scores = re.compile(r"f32\[(\d+,)*64,\d+,%d\]" % bucket)
+    assert [rtype for _, rtype, _, _ in instructions
+            if scores.search(rtype)] == []
 
 
 # -- Falcon-H1: a Mamba-2 state beside a GQA page pool in every layer -----------

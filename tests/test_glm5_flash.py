@@ -11,12 +11,15 @@ so that the selection bites at every row that matters:
 (c) the cache's index: a block closes exactly when its fourth row is
     written, the own block is always read, ties go to the lower block, a
     context under ``index_topk`` is dense attention;
-(d) the sparse read's kernel against the gather;
+(d) the sparse read's kernel against the gather, and the prefill's
+    attention kernel against the blocked form;
 (e) the share: eight shares and one shared expert add up to the whole
     layer, and a share through the engine equals the reference given it;
 (f) a lower precision anywhere the configuration states one fails;
 (g) what this cache cannot do is refused at construction.
 """
+
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -27,7 +30,9 @@ from grid.reference import glm5_flash as ref
 from paddle_tpu import serving
 from paddle_tpu.flags import set_flag
 from paddle_tpu.models import glm5_flash as gf
+from paddle_tpu.monitor import metrics as mx
 from paddle_tpu.ops import attention_ops
+from paddle_tpu.ops.pallas_kernels import dsa_prefill
 from paddle_tpu.ops.pallas_kernels import mla_attention as mla
 from paddle_tpu.serving.kv_cache import (LATENT, STATE, CacheGroup,
                                          LatentPagedCache)
@@ -111,13 +116,32 @@ def _served_against_reference(eng, req, **over):
 # -- (a) prefill ---------------------------------------------------------------
 
 
-def test_prefill_equals_the_reference(toy, rng):
+def _arm_the_prefill_kernel(monkeypatch, **tiles):
+    """``dsa_causal_attention`` as on a chip whose gate takes the shapes,
+    the kernel's interpreter standing in at ``tiles``."""
+    monkeypatch.setattr(attention_ops, "_on_tpu", lambda: True)
+    monkeypatch.setattr(dsa_prefill, "dsa_prefill_gate", functools.partial(
+        dsa_prefill.dsa_prefill_gate, interpret=True))
+    monkeypatch.setattr(
+        dsa_prefill, "dsa_prefill_attention", functools.partial(
+            dsa_prefill.dsa_prefill_attention, interpret=True, **tiles))
+
+
+@pytest.mark.parametrize("form", ["blocked", "kernel"])
+def test_prefill_equals_the_reference(toy, rng, form, monkeypatch):
     """Both kinds of layer under four streams: the chunk scan against the
     recurrence, and attention under each row's own mask against the
-    reference's selection row by row (150 rows: 37 blocks, 8 read)."""
+    reference's selection row by row (150 rows: 37 blocks, 8 read), by the
+    blocked form and by the ``dsa_prefill_attention`` kernel (interpreted:
+    four query blocks of eight key tiles, two heads a step)."""
+    if form == "kernel":
+        _arm_the_prefill_kernel(monkeypatch, block_q=64, block_k=32, heads=2)
+    counter = mx.counter("dsa/prefill_calls." + form)
+    before = counter.value
     n = 150
     seq = rng.randint(0, 96, n)
     logits, kept = _prefill(toy, seq)
+    assert counter.value == before + 2          # the toy's two DSA layers
     want = reference_rows(toy, seq, np.arange(n))
     np.testing.assert_allclose(np.asarray(logits[0, :n]), want, atol=TOL,
                                rtol=0)
@@ -337,6 +361,114 @@ def test_the_sparse_call_has_a_name_of_its_own_and_a_gate():
                                              sparse=True)
     # the dense call's page is still held to the pool type's sublanes
     assert "multiple" in mla.mla_decode_gate(jnp.bfloat16, 512, 512, 8)
+
+
+def _dsa_case(rng, s, h=4, d=16, hi=4, li=16, kpool=4):
+    def arr(*shape):
+        return jnp.asarray(rng.randn(*shape).astype("float32"))
+
+    return (arr(s, h, d), arr(s, h, d), arr(s, h, d), arr(s, hi, li),
+            arr(s, hi), arr(s // kpool, li))
+
+
+@pytest.mark.parametrize("rows,why", [
+    (24, "under index_topk: dense causal"),
+    (100, "past it: 17 of 25 blocks dropped at the last row"),
+    (88, "not whole query blocks of 16: tiles of 11 rows")])
+def test_the_prefill_kernel_equals_the_blocked_form(rng, monkeypatch, rows,
+                                                    why):
+    """``dsa_causal_attention`` at ``kpool`` 4 and 8 blocks read, by the
+    blocked form and by the kernel's interpreter at query blocks of 16
+    (where they divide the rows) and key tiles of 8: the same rows chosen,
+    the same float32 softmax, a row's sum gathered tile by tile."""
+    case = _dsa_case(rng, rows)
+    want = attention_ops.dsa_causal_attention(*case, 4, 8, 0.25, block_q=16)
+    _arm_the_prefill_kernel(monkeypatch, block_q=16, block_k=8, heads=2)
+    got = attention_ops.dsa_causal_attention(*case, 4, 8, 0.25, block_q=16)
+    assert got.shape == want.shape == (rows, 4, 16)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2e-6,
+                               rtol=0)
+
+
+def test_the_prefill_kernel_reads_no_key_tile_past_a_query_block(rng):
+    """The mask is the caller's, the causal edge the grid's: keys past a
+    query block's last row are not read even where the mask names them,
+    and the key tiles of a block that ends inside one are."""
+    q, k, v = _dsa_case(rng, 48)[:3]
+    tril = jnp.tril(jnp.ones((48, 48), jnp.int8))
+    run = functools.partial(dsa_prefill.dsa_prefill_attention, q, k, v,
+                            sm_scale=0.25, block_q=16, block_k=12, heads=4,
+                            interpret=True)
+    loose = tril.at[:16, 16:].set(1)      # the first block's rows name more
+    np.testing.assert_array_equal(np.asarray(run(loose)[16:]),
+                                  np.asarray(run(tril)[16:]))
+    np.testing.assert_array_equal(np.asarray(run(tril.at[:16, 24:].set(1))),
+                                  np.asarray(run(tril)))
+    sc = jnp.einsum("qhd,khd->hqk", q, k) * 0.25
+    sc = jnp.where(tril[None] != 0, sc, attention_ops.neg_inf(jnp.float32))
+    want = jnp.einsum("hqk,khd->qhd", jax.nn.softmax(sc, axis=-1), v)
+    np.testing.assert_allclose(np.asarray(run(tril)), np.asarray(want),
+                               atol=2e-6, rtol=0)
+
+
+def test_the_prefill_gate_names_what_it_refuses_and_chooses_the_form(
+        rng, monkeypatch):
+    """The kernel takes whole 128-lane heads and whole 128-row tiles of
+    the mask within its VMEM, and ``dsa_causal_attention`` asks it only on
+    a TPU: here, and where the gate refuses, the blocked form runs."""
+    gate = dsa_prefill.dsa_prefill_gate
+    for rows in (4096, 8192, 4096 + 128):         # the cell's buckets, and
+        assert gate(64, 256, 256, rows, 4) is None    # tiles of 128 rows
+    assert "128-lane" in gate(64, 192, 256, 8192, 4)
+    assert "128-lane" in gate(64, 256, 64, 8192, 4)
+    assert "128-row tiles" in gate(64, 256, 256, 8192 + 64, 4)
+    assert "blocks of 4" in gate(64, 256, 256, 8190, 4)
+    assert "KiB of VMEM" in gate(64, 1024, 1024, 8192, 4)
+    assert "KiB of VMEM" in gate(64, 512, 512, 8192, 4, itemsize=4)
+    assert gate(4, 16, 16, 88, 4, interpret=True) is None
+    assert "blocks of 4" in gate(4, 16, 16, 90, 4, interpret=True)
+    q, k, v = _dsa_case(rng, 128)[:3]
+    with pytest.raises(ValueError, match="128-lane"):
+        dsa_prefill.dsa_prefill_attention(q, k, v,
+                                          jnp.ones((128, 128), jnp.int8))
+    took = []
+    monkeypatch.setattr(
+        dsa_prefill, "dsa_prefill_attention",
+        lambda q, *a, **kw: took.append("kernel") or q)
+    monkeypatch.setattr(
+        jax.lax, "map", lambda f, xs: took.append("map") or jnp.zeros(
+            (xs[0].shape[0], xs[1].shape[1], 128), jnp.int8))
+    small = _dsa_case(rng, 128)
+    served = tuple(jnp.zeros(x, jnp.bfloat16) for x in (
+        (128, 2, 128), (128, 2, 128), (128, 2, 128), (128, 2, 16), (128, 2),
+        (32, 16)))
+    attention_ops.dsa_causal_attention(*served, 4, 8)
+    monkeypatch.setattr(attention_ops, "_on_tpu", lambda: True)
+    attention_ops.dsa_causal_attention(*small, 4, 8)
+    attention_ops.dsa_causal_attention(*served, 4, 8)
+    # blocked: ONE map (selection and attention together); the kernel's
+    # form: the map that makes the mask, then the call
+    assert took == ["map", "map", "map", "kernel"]
+
+
+@pytest.mark.parametrize("form", ["blocked", "kernel"])
+def test_the_prefill_attention_counts_the_form_it_chose_once_a_trace(
+        form, rng, monkeypatch):
+    """``dsa/prefill_calls.<form>`` rises by one when a program with one
+    layer's attention is traced, and not again when it runs."""
+    if form == "kernel":
+        _arm_the_prefill_kernel(monkeypatch, block_q=32, block_k=32)
+    counts = {f: mx.counter("dsa/prefill_calls." + f)
+              for f in ("blocked", "kernel")}
+    before = {f: c.value for f, c in counts.items()}
+    case = _dsa_case(rng, 64)
+    program = jax.jit(lambda *a: attention_ops.dsa_causal_attention(
+        *a, 4, 8, 0.25))
+    text = str(program.trace(*case).jaxpr)
+    program(*case), program(*case)
+    assert {f: c.value - before[f] for f, c in counts.items()} == {
+        f: 1.0 * (f == form) for f in counts}
+    assert ("pallas_call" in text) is (form == "kernel")
 
 
 # -- (e) the share -----------------------------------------------------------------
