@@ -17,11 +17,21 @@ Typical use mirrors Fluid:
     loss_val, = exe.run(feed={"x": xb, "y": yb}, fetch_list=[loss])
 """
 
+import time as _time
+
+_t_first_line = _time.perf_counter()
+
 # Place the persistent XLA compile cache BEFORE anything can trigger a
 # compile: JAX_COMPILATION_CACHE_DIR where set, else <checkout>/.jax_cache.
-from . import compile_cache as _compile_cache  # noqa: F401
+from . import compile_cache as _compile_cache  # noqa: E402,F401
 
 _compile_cache.setup_compile_cache()
+
+# startup/import, the first of the three start-up phases
+# (compile_cache.phases()): this file's first line to its last, so the
+# package's own imports and JAX's where nothing imported JAX before
+_import_phase = _compile_cache.phase("startup/import").__enter__()
+_import_phase.t0 = _t_first_line
 
 # Sharding-invariant RNG: with the legacy threefry lowering, random values
 # change when XLA partitions the generating computation — which would make a
@@ -98,3 +108,6 @@ __version__ = "0.1.0"
 from .async_executor import AsyncExecutor  # noqa: F401
 from .data_feed_desc import DataFeedDesc  # noqa: F401
 from .reader.py_reader import EOFException  # noqa: F401
+
+_import_phase.__exit__(None, None, None)
+del _import_phase, _t_first_line

@@ -24,6 +24,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from . import compile_cache as _cc
 from . import ops as _ops  # noqa: F401 — registers all op impls
 from .core.dtypes import to_jnp_dtype
 from .core.framework import (Program, Variable, default_main_program,
@@ -317,20 +318,29 @@ def _abstractify(tree):
         tree)
 
 
-def _timed_lower_compile(jitted_fn, args):
-    """(lowered, executable) with the compile wall time routed to the
+def _step_label(program) -> str:
+    """A Program's name in the compile log: ``step[<fingerprint, 8 hex>]``
+    of the program the caller handed over (not of its optimized clone)."""
+    return "step[%s]" % _dev.program_fingerprint(program)[:8]
+
+
+def _timed_lower_compile(jitted_fn, args, label):
+    """(lowered, executable) under ``label`` in the compile log
+    (compile_cache.log(): what JAX traces, lowers, compiles or loads here
+    is ONE entry of that name), the seam's own length routed to the
     executor/compile_time_ms histogram — the one AOT timing convention
     shared by Executor.prepare and aot_compile."""
     _faults.fire("executor.compile")  # chaos drills: injected compile failure
-    t0 = time.perf_counter()
-    lowered = jitted_fn.lower(*args)
-    aot = lowered.compile()
+    with _cc.label(label) as seam:
+        lowered = jitted_fn.lower(*args)
+        aot = lowered.compile()
     if _mx._enabled:
-        _m_compile_ms.observe((time.perf_counter() - t0) * 1e3)
+        _m_compile_ms.observe(seam.seconds * 1e3)
     return lowered, aot
 
 
-def aot_compile(fn, abstract_args, donate_argnums=(), static_argnums=()):
+def aot_compile(fn, abstract_args, donate_argnums=(), static_argnums=(),
+                label=None):
     """AOT lower + XLA-compile ``fn`` at abstract shapes WITHOUT running it
     — ``Executor.prepare``'s artifact path exposed for non-Program drivers
     (the serving decode engine compiles its per-bucket prefill fns and the
@@ -342,6 +352,10 @@ def aot_compile(fn, abstract_args, donate_argnums=(), static_argnums=()):
     processes in the compile cache (compile_cache.py), so a serving restart
     skips every prefill/decode compile. Returns the compiled executable
     (call it with concrete arrays; ``donate_argnums`` buffers are consumed).
+    ``label`` names the executable in the compile log
+    (``compile_cache.log()``; the serving engine passes ``prefill[<bucket>]``,
+    ``chunk[fuse=<k>]``, ``verify[<width>]``, ``resume[<bucket>]``); without
+    one it is the function's own name.
     """
     jitted = jax.jit(fn, donate_argnums=donate_argnums,
                      static_argnums=static_argnums)
@@ -351,7 +365,8 @@ def aot_compile(fn, abstract_args, donate_argnums=(), static_argnums=()):
     # structs — only the traced (dynamic) positions are abstractified
     args = tuple(a if i in static else _abstractify(a)
                  for i, a in enumerate(abstract_args))
-    _, aot = _timed_lower_compile(jitted, args)
+    _, aot = _timed_lower_compile(
+        jitted, args, label or getattr(fn, "__name__", "aot_compile"))
     return aot
 
 
@@ -958,6 +973,21 @@ class Executor:
                                   return_numpy, use_program_cache, mesh,
                                   accumulation_steps)
 
+    @staticmethod
+    def _first_call(compiled, src_program, state, feeds, rng_key,
+                    fetch_names):
+        """The miss path's call: trace, lower, compile (or load) and first
+        step, ONE ``step[..]`` entry of the compile log. A program that is
+        fed nothing and gives nothing back makes state from nothing (a
+        startup program: the weights made on the device), so its first
+        call is a ``startup/weights`` phase."""
+        with _cc.label(_step_label(src_program)), \
+                _tr.span("executor/compile_and_step", cat="executor"):
+            if feeds or fetch_names:
+                return compiled(state, feeds, rng_key)
+            with _cc.phase("startup/weights"):
+                return compiled(state, feeds, rng_key)
+
     def _run_step(self, program, feed, fetch_list, scope, return_numpy,
                   use_program_cache, mesh, accumulation_steps):
         if scope is None:
@@ -1015,9 +1045,13 @@ class Executor:
             spec = _faults.fire("executor.dispatch")
             if spec is not None and spec.kind == "nan":
                 feeds = _faults.poison_feeds(feeds)
-            with _tr.span("executor/compile_and_step" if was_miss
-                          else "executor/step", cat="executor"):
-                new_state, fetches = compiled(state, feeds, rng_key)
+            if was_miss:
+                new_state, fetches = self._first_call(
+                    compiled, src_program, state, feeds, rng_key,
+                    fetch_names)
+            else:
+                with _tr.span("executor/step", cat="executor"):
+                    new_state, fetches = compiled(state, feeds, rng_key)
             if mx_on:
                 # A cache-miss first call pays jit trace + XLA compile;
                 # report it separately so the steady-state step histogram
@@ -1706,6 +1740,7 @@ class Executor:
         # AOT-compile the OPTIMIZED program — the same object run() resolves,
         # so the warmed specialization (and persistent-cache entry) is the
         # one the real job hits
+        label = _step_label(program)
         program = self._maybe_optimize(program, fetch_names, scope)
         block = program.global_block
         abstract = {}
@@ -1752,7 +1787,8 @@ class Executor:
             abstract = laid_out(abstract, lambda k: at_dev)
         lowered, aot = _timed_lower_compile(
             compiled.fn, (abstract_state, abstract,
-                          jax.ShapeDtypeStruct((), np.dtype("uint32"))))
+                          jax.ShapeDtypeStruct((), np.dtype("uint32"))),
+            label)
         # the AOT artifacts are the attribution surface: the executable's
         # cost_analysis/memory_analysis feed the device_profile/* gauges
         # (memory_report, monitor.stepstats read them), and the lowered

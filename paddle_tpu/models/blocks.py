@@ -31,6 +31,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from .. import compile_cache as _cc
 from ..ops import moe_ops
 from ..ops.pallas_kernels import kda as kda_ops
 
@@ -422,6 +423,7 @@ def moe_stats(stats) -> Dict:
             "moe_held_pairs": jnp.stack([s["held_pairs"] for s in stats])}
 
 
+@_cc.in_phase("startup/weights")
 def seeded_params(cfg, seed, init_layer: Callable, layer_args: Callable
                   ) -> Dict:
     """Seeded random weights, made where JAX computes (the device), in

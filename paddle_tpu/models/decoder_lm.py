@@ -23,6 +23,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from .. import compile_cache as _cc
 from ..ops import attention_ops
 
 __all__ = ["DecoderConfig", "DecoderLM", "init_params", "prefill_forward",
@@ -54,6 +55,7 @@ class DecoderConfig:
                    self.max_seq, self.dtype))
 
 
+@_cc.in_phase("startup/weights")
 def init_params(cfg: DecoderConfig, seed: int = 0) -> Dict:
     key = jax.random.PRNGKey(seed)
     d, f = cfg.d_model, cfg.d_model * cfg.ffn_mult

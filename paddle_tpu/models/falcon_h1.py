@@ -41,6 +41,7 @@ from typing import Dict, Mapping, Tuple
 import jax
 import jax.numpy as jnp
 
+from .. import compile_cache as _cc
 from ..ops import attention_ops
 from ..ops.pallas_kernels import ssd as ssd_ops
 from ..serving.kv_cache import KV, STATE
@@ -231,6 +232,7 @@ def _vocab_matrix(key, rows: int, cols: int, std: float, dtype, by_rows: bool):
     return jax.lax.fori_loop(0, blocks, fill, jnp.zeros((rows, cols), dtype))
 
 
+@_cc.in_phase("startup/weights")
 def init_params(cfg: FalconH1Config, seed) -> Dict:
     """Seeded random weights, made where JAX computes (the device), in
     ``cfg.dtype``, one layer a call. Each projection's deviation follows

@@ -31,6 +31,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from .. import compile_cache as _cc
 from ..executor import _safe_flight_dump, aot_compile
 from ..monitor import (device as _dev, slo as _slo, telemetry as _telemetry,
                        tracer as _tr)
@@ -479,26 +480,30 @@ class ServingEngine:
             self.cache_ops = ContiguousKVCache(
                 mcfg.n_layer, n_kv, mcfg.d_head, self.cfg.slots,
                 self.cfg.max_seq, dtype=mcfg.dtype)
-        if self.cfg.paged:      # one free list a PAGED cache group
-            self.pools = [PagePool(g.num_pages, self.cfg.page_size,
-                                   name=g.name, primary=(gi == 0))
-                          for gi, g in enumerate(self.cache_ops.groups)
-                          if g.kind != STATE]
-        # the first group's pool, under the name a one-group engine's only
-        # pool always had
-        self.pool: Optional[PagePool] = self.pools[0] if self.pools else None
-        self.scheduler = Scheduler(self.cfg.slots, self.cfg.max_queue)
-        self._cache = self.cache_ops.init_state()
-        if self.cfg.paged:
-            _sm.STATE_POOL_BYTES.set(
-                self.cache_ops.state_bytes(self._cache))
-            if latent:
-                _sm.LATENT_RING_BYTES.set(
-                    self.cache_ops.ring_bytes(self._cache))
-                _sm.INDEX_POOL_BYTES.set(
-                    self.cache_ops.index_bytes(self._cache))
+        # the third start-up phase (compile_cache.phases()): first pool
+        # allocated to last, so pages, states and the per-slot tables
+        with _cc.phase("startup/pools"):
+            if self.cfg.paged:      # one free list a PAGED cache group
+                self.pools = [PagePool(g.num_pages, self.cfg.page_size,
+                                       name=g.name, primary=(gi == 0))
+                              for gi, g in enumerate(self.cache_ops.groups)
+                              if g.kind != STATE]
+            # the first group's pool, under the name a one-group engine's
+            # only pool always had
+            self.pool: Optional[PagePool] = \
+                self.pools[0] if self.pools else None
+            self.scheduler = Scheduler(self.cfg.slots, self.cfg.max_queue)
+            self._cache = self.cache_ops.init_state()
+            if self.cfg.paged:
+                _sm.STATE_POOL_BYTES.set(
+                    self.cache_ops.state_bytes(self._cache))
+                if latent:
+                    _sm.LATENT_RING_BYTES.set(
+                        self.cache_ops.ring_bytes(self._cache))
+                    _sm.INDEX_POOL_BYTES.set(
+                        self.cache_ops.index_bytes(self._cache))
+            self._reset_slot_state()
         b = self.cfg.slots
-        self._reset_slot_state()
         self._prefill_exe: Dict[int, Any] = {}   # bucket -> AOT executable
         self._decode_exe: Dict[int, Any] = {}    # fuse length -> executable
         self._resume_exe: Dict[int, Any] = {}    # remainder bucket -> exe
@@ -1780,7 +1785,7 @@ class ServingEngine:
             prefill,
             (self.params, self._cache, self._slot_state(), dest_abs,
              jax.ShapeDtypeStruct((bucket,), jnp.int32)) + _SCALARS_ABS,
-            donate_argnums=(1,))
+            donate_argnums=(1,), label="prefill[%d]" % bucket)
         self._prefill_exe[bucket] = exe
         return exe
 
@@ -1823,7 +1828,7 @@ class ServingEngine:
             chunk,
             (self.params, self._cache, self._len, self._tok, self._active,
              self._gen, self._maxnew, self._temp, self._topk, self._seed),
-            donate_argnums=(1,))
+            donate_argnums=(1,), label="chunk[fuse=%d]" % fuse)
         self._decode_exe[fuse] = exe
         return exe
 
@@ -1917,7 +1922,7 @@ class ServingEngine:
              self._gen, self._maxnew, self._temp, self._topk, self._seed,
              jax.ShapeDtypeStruct((cfg.slots, w - 1), jnp.int32),
              jax.ShapeDtypeStruct((cfg.slots,), jnp.int32)),
-            donate_argnums=(1,))
+            donate_argnums=(1,), label="verify[%d]" % width)
         self._verify_exe[width] = exe
         return exe
 
@@ -1981,7 +1986,7 @@ class ServingEngine:
             (self.params, self._cache, self._slot_state(), pages_abs,
              jax.ShapeDtypeStruct((rbucket,), jnp.int32)) + _SCALARS_ABS
             + (pages_abs, pages_abs),
-            donate_argnums=(1,))
+            donate_argnums=(1,), label="resume[%d]" % rbucket)
         self._resume_exe[rbucket] = exe
         return exe
 

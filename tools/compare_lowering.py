@@ -64,7 +64,7 @@ def trace_tree(names: Optional[List[str]], keep_text: bool) -> Dict[str, Dict]:
             setattr(kv_cache.LatentPagedCache, name, lambda self, state: 0)
     traced = {}
 
-    def record(fn, args, donate_argnums=()):
+    def record(fn, args, donate_argnums=(), label=None):
         text = str(jax.make_jaxpr(fn)(*args))
         traced[fn.__name__ + "." + hashlib.sha256(
             repr(jax.tree_util.tree_map(

@@ -17,6 +17,7 @@ import sys
 MODULES = [
     "paddle_tpu",
     "paddle_tpu.compile_cache",
+    "paddle_tpu.executor",
     "paddle_tpu.layers",
     "paddle_tpu.layers.detection",
     "paddle_tpu.layers.control_flow",

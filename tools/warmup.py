@@ -12,9 +12,10 @@ a cache hit instead of a multi-minute compile.
 
     python -m tools.warmup --model mlp          # CPU smoke (<5s)
 
-Exits 0 on success and prints the compile wall time plus the process's
-``compile_cache/hit|miss`` counters — run it twice to see the second
-invocation flip to a hit.
+Exits 0 on success and prints the process's compile log
+(``compile_cache.report()``: every executable it traced, lowered, compiled
+or loaded, by name, the step as ``step[<fingerprint>]``) — run it twice to
+see the second invocation's ``step[..]`` flip from ``miss`` to ``hit``.
 """
 
 from __future__ import annotations
@@ -22,7 +23,6 @@ from __future__ import annotations
 import argparse
 import os
 import sys
-import time
 
 _REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 if _REPO not in sys.path:
@@ -144,7 +144,7 @@ def main(argv=None) -> int:
     args = p.parse_args(argv)
 
     import paddle_tpu as fluid
-    from paddle_tpu import compile_cache, monitor
+    from paddle_tpu import compile_cache
 
     with fluid.unique_name.guard():
         with fluid.scope_guard(fluid.Scope()):
@@ -153,15 +153,11 @@ def main(argv=None) -> int:
                                  if fluid.is_compiled_with_tpu()
                                  else fluid.CPUPlace())
             exe.run(startup)
-            t0 = time.perf_counter()
             exe.prepare(main_prog, feed=feed_specs, fetch_list=[loss])
-            dt = time.perf_counter() - t0
 
-    snap = monitor.snapshot()
-    hits = int(snap["compile_cache/hit"]["value"])
-    misses = int(snap["compile_cache/miss"]["value"])
-    print("warmup[%s]: AOT compile %.2fs  compile_cache hit=%d miss=%d  (%s)"
-          % (args.model, dt, hits, misses, compile_cache.compile_cache_dir()))
+    print("warmup[%s]: the compile log (%s)"
+          % (args.model, compile_cache.compile_cache_dir()))
+    print(compile_cache.report())
     return 0
 
 
