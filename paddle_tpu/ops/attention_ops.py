@@ -547,13 +547,28 @@ def windowed_causal_attention(q, k, v, window: int, sm_scale=1.0,
     that can reach it: O(S x window) work, and the largest score tensor is
     [Hkv, G * block_q, window + block_q], never S x S. Softmax in
     float32. ``v`` may be narrower than ``q`` and ``k`` (latent attention's
-    expanded heads: 128 against 192). Returns [S, Hq, Dv]."""
+    expanded heads: 128 against 192). On a TPU, where
+    ``window_prefill_gate`` takes the shapes, the attention beyond the
+    window is ONE ``window_prefill_attention`` kernel call
+    (pallas_kernels/window_prefill.py: no score reaches HBM, a query block
+    reads the key tiles of its band alone, tiles that follow the window);
+    ``attn/window_prefill_calls.kernel`` and ``.blocked`` count which.
+    Returns [S, Hq, Dv]."""
+    from .pallas_kernels import window_prefill
+
     s, hq, d = q.shape
     hkv = k.shape[1]
     g = hq // hkv
     if s <= window:
         return gqa_causal_attention(q, k, v, sm_scale)
+    kernel = _on_tpu() and window_prefill.window_prefill_gate(
+        hq, hkv, d, v.shape[-1], s, window, q.dtype.itemsize) is None
+    _count("kernel" if kernel else "blocked", "attn/window_prefill_calls",
+           "windowed_causal_attention")
     with jax.named_scope("attn/window"):
+        if kernel:
+            return window_prefill.window_prefill_attention(
+                q, k, v, int(window), sm_scale=float(sm_scale))
         bq = block_q
         while s % bq:
             bq //= 2

@@ -951,13 +951,12 @@ def test_mixed_query_groups_paged_kernel_at_the_served_geometry(
     assert "bf16[16,%d,1024]" % rows in kernel.split(" custom-call(")[0]
 
 
-def _mixed_case(chip):
-    """The decode step of the decoder with query heads by layer type at
-    its published widths, as one chip of two holds it (128 of 256 experts,
-    50,176 rows of the vocabulary): layers full (dense), sliding, sliding,
-    sliding, full over the cell's two pools."""
+def _mixed_model(chip, n_layer=5):
+    """``(cfg, model, params as shapes, sds)`` of the decoder with query
+    heads by layer type at its published widths, as one chip of two holds
+    it (128 of 256 experts, 50,176 rows of the vocabulary): layers full
+    (dense), sliding, sliding, sliding, full, or the first ``n_layer``."""
     from paddle_tpu.models import laguna as lg
-    from paddle_tpu.serving.kv_cache import CacheGroup, PagedKVCache
 
     g = MIXED_SERVE
     rope = {lg.FULL: {"rope_theta": 500000, "rope_type": "yarn",
@@ -968,9 +967,10 @@ def _mixed_case(chip):
             lg.SLIDING: {"rope_type": "default", "rope_theta": 10000,
                          "partial_rotary_factor": 1}}
     cfg = lg.LagunaConfig(
-        vocab_size=g["vocab"], n_layer=5, d_model=3072,
-        n_head=[48, 72, 72, 72, 48], n_kv_head=8, d_head=128,
-        layer_types=[lg.FULL] + [lg.SLIDING] * 3 + [lg.FULL], window=512,
+        vocab_size=g["vocab"], n_layer=n_layer, d_model=3072,
+        n_head=[48, 72, 72, 72, 48][:n_layer], n_kv_head=8, d_head=128,
+        layer_types=([lg.FULL] + [lg.SLIDING] * 3 + [lg.FULL])[:n_layer],
+        window=512,
         rope=rope, d_dense=12288, dense_layers=[0], n_expert=256, top_k=10,
         d_expert=1024, d_shared=1024, routed_scale=2.5, max_seq=g["max_seq"],
         dtype="bfloat16", experts_held=tuple(range(128)))
@@ -981,6 +981,15 @@ def _mixed_case(chip):
 
     params = jax.tree_util.tree_map(
         sds, jax.eval_shape(lambda: lg.init_params(cfg, 0)))
+    return cfg, model, params, sds
+
+
+def _mixed_case(chip):
+    """The decode step of :func:`_mixed_model` over the cell's two pools."""
+    from paddle_tpu.serving.kv_cache import CacheGroup, PagedKVCache
+
+    g = MIXED_SERVE
+    cfg, model, params, sds = _mixed_model(chip)
     groups = [CacheGroup(n, l, w, g[n + "_pages"])
               for n, l, w in cfg.cache_groups]
     ops = PagedKVCache(5, 8, 128, g["slots"], g["max_seq"], g["page_size"],
@@ -1255,22 +1264,22 @@ def test_expert_stream_kernel_with_an_experts_own_numbers(chip):
             and _has_dim(rtype, e)] == []
 
 
-def _gdla_case(chip):
-    """The decode step of the grouped-differential latent decoder at its
-    published widths, as one chip of sixteen holds it (24 of 384 experts,
-    27,520 rows of the vocabulary): a dense window layer, a sparse window
-    layer and a sparse full layer over the cell's page pool and 64 slots'
-    rings."""
+def _gdla_model(chip, n_layer=3):
+    """``(cfg, model, params as shapes, sds)`` of the grouped-differential
+    latent decoder at its published widths, as one chip of sixteen holds
+    it (24 of 384 experts, 27,520 rows of the vocabulary): a dense window
+    layer, a sparse window layer and a sparse full layer, or the first
+    ``n_layer``."""
     from paddle_tpu.models import motif3 as mf
-    from paddle_tpu.serving.kv_cache import CacheGroup, LatentPagedCache
 
     yarn = {"original_max_position_embeddings": 4096, "factor": 64,
             "mscale": 1, "rope_type": "yarn", "rope_theta": 10000,
             "beta_fast": 32, "beta_slow": 1, "apply_yarn_scaling": False}
     cfg = mf.Motif3Config(
-        vocab_size=27520, n_layer=3, d_model=4096, n_head=80, n_kv_head=16,
-        q_rank=1024, kv_rank=512, d_nope=128, d_rope=64, d_v=128,
-        layer_types=["window", "window", "full"], window=128, d_dense=12288,
+        vocab_size=27520, n_layer=n_layer, d_model=4096, n_head=80,
+        n_kv_head=16, q_rank=1024, kv_rank=512, d_nope=128, d_rope=64,
+        d_v=128, layer_types=["window", "window", "full"][:n_layer],
+        window=128, d_dense=12288,
         dense_layers=(0,), n_expert=384, top_k=8, d_expert=1280,
         routed_scale=2.0, rope_scaling=yarn, max_seq=16384,
         dtype="bfloat16", experts_held=tuple(range(24)))
@@ -1281,6 +1290,15 @@ def _gdla_case(chip):
 
     params = jax.tree_util.tree_map(
         sds, jax.eval_shape(lambda: mf.init_params(cfg, 0)))
+    return cfg, model, params, sds
+
+
+def _gdla_case(chip):
+    """The decode step of :func:`_gdla_model` over the cell's page pool
+    and 64 slots' rings."""
+    from paddle_tpu.serving.kv_cache import CacheGroup, LatentPagedCache
+
+    cfg, model, params, sds = _gdla_model(chip)
     pages = {"latent_full": 36864, "latent_ring": 64 * 8}
     groups = [CacheGroup(name, layers, window, pages[name], kind)
               for name, layers, window, kind in cfg.cache_groups]
@@ -1474,6 +1492,59 @@ def test_sparse_hybrid_decoder_prefill_attends_in_one_kernel(chip,
     scores = re.compile(r"f32\[(\d+,)*64,\d+,%d\]" % bucket)
     assert [rtype for _, rtype, _, _ in instructions
             if scores.search(rtype)] == []
+
+
+def _prefill_last_of(build, n_layer):
+    """``(chip, bucket) -> (fn, abstract args)`` of ``prefill_last`` over
+    the first ``n_layer`` layers of ``build``'s model."""
+    def case(chip, bucket):
+        _, model, params, _ = build(chip, n_layer)
+        return model.prefill_last, (
+            params, jax.ShapeDtypeStruct((1, bucket), jnp.int32,
+                                         sharding=chip),
+            jax.ShapeDtypeStruct((1,), jnp.int32, sharding=chip))
+    return case
+
+
+# config: (its prefill over the leading layers up to the FIRST window layer
+# (a compile of the expert layers' 8,192 rows is 10-25 s a layer), query
+# heads, KV heads, D, Dv, window); SmallThinker's as the engine composes
+# it, over its two pools
+WINDOW_PREFILLS = {
+    "smallthinker": (lambda chip, bucket: _moe_case(
+        "prefill", chip, n_layer=2, bucket=bucket)[0], 28, 4, 128, 128, 4096),
+    "laguna": (_prefill_last_of(_mixed_model, 2), 72, 8, 128, 128, 512),
+    "motif3": (_prefill_last_of(_gdla_model, 1), 80, 16, 192, 128, 128),
+}
+
+
+@pytest.mark.parametrize("config,bucket", [
+    ("smallthinker", 8192), ("laguna", 4096), ("laguna", 8192),
+    ("motif3", 2048), ("motif3", 4096), ("motif3", 8192)])
+def test_window_layers_prefill_attends_in_one_kernel(chip, monkeypatch,
+                                                     config, bucket):
+    """The cells' own buckets past the window, at the published widths: the
+    gate takes the shapes, the window layer's attention is ONE
+    ``window_prefill_attention`` call, and no float32 instruction result
+    is a block of 512 query rows against ``window + 512`` keys or more
+    (the blocked form's scores, which went through HBM)."""
+    from paddle_tpu.ops.pallas_kernels import window_prefill
+
+    monkeypatch.setattr(attention_ops, "_on_tpu", lambda: True)
+    build, hq, hkv, d, d_v, window = WINDOW_PREFILLS[config]
+    assert window_prefill.window_prefill_gate(
+        hq, hkv, d, d_v, bucket, window) is None
+    fn, args = build(chip, bucket)
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    kernels = [ln.strip().split(" = ")[0] for ln in text.split("\n")
+               if "tpu_custom_call" in ln]
+    assert sum(k.startswith("%window_prefill_attention")
+               for k in kernels) == 1
+    scores = [rtype for _, rtype, _, _ in _instructions(text)
+              if rtype.startswith("f32[") and any(
+                  rows == 512 and keys >= window + 512
+                  for rows, keys in re.findall(r"(\d+),(\d+)\]", rtype))]
+    assert scores == []
 
 
 # -- Falcon-H1: a Mamba-2 state beside a GQA page pool in every layer -----------
