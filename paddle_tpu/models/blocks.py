@@ -4,8 +4,8 @@ through which ``serving.ServingEngine`` drives any of them
 (:class:`ServedLM`, whose docstring is the contract).
 
 ``smallthinker.py``, ``kimi_k2.py``, ``laguna.py``, ``ling3_flash.py``,
-``motif3.py``, ``glm5_flash.py`` and ``falcon_h1.py`` take their blocks from
-here and keep what only they have. A
+``motif3.py``, ``glm5_flash.py``, ``falcon_h1.py`` and ``ouro.py`` take their
+blocks from here and keep what only they have. A
 block two models need is written HERE under a public name; no model module
 imports another's underscore names. Two forms of a block are one function
 only where the merged one needs no argument that says who calls it and the
@@ -448,8 +448,8 @@ def seeded_params(cfg, seed, init_layer: Callable, layer_args: Callable
 class ServedLM:
     """THE SERVING CONTRACT: what ``serving.ServingEngine`` may ask of a
     model and of its config. ``SmallThinkerLM``, ``KimiK2LM``, ``LagunaLM``,
-    ``Ling3FlashLM``, ``Motif3LM``, ``Glm5FlashLM`` and ``FalconH1LM`` are
-    this class over their module's ``init_params``, ``prefill_forward`` and
+    ``Ling3FlashLM``, ``Motif3LM``, ``Glm5FlashLM``, ``FalconH1LM`` and
+    ``OuroLM`` are this class over their module's ``init_params``, ``prefill_forward`` and
     ``decode_forward`` (and, where the head is not the plain one, ``head``);
     ``decoder_lm.DecoderLM`` meets it with methods of its own.
 
@@ -461,6 +461,9 @@ class ServedLM:
       ``(k, v)`` [B, S, Hkv, D] of a K-and-V layer, ``(row,)`` [B, S, rank +
       rope] of a latent layer, ``(state [B, H, dk, dv], tail [B, rows,
       width])`` of a state layer (the state the prompt LEAVES, not rows);
+      under ``cache_steps`` T > 1, ``(k, v)`` [T, B, S, Hkv, D]: a leading
+      STEP axis, the rows each loop step made, which the engine writes a
+      step at a time (``write_prompt(..., step=t)``);
     * ``prefill_last`` (optional; the engine asks ``hasattr``): the same
       with ``logits [B, V]`` of each prompt's LAST row only ([B, S, V] at S
       = 8,192 and V = 151,936 would be 5 GB). Absent: the engine calls
@@ -468,12 +471,17 @@ class ServedLM:
     * ``decode(params, cache, cache_ops, tokens [B], pos [B], active [B])
       -> (logits [B, V], cache)`` or ``(logits, cache, stats)``: one
       position a slot through ``cache_ops``, which owns the cache's groups,
-      rings and the gather-or-kernel choice. ``stats`` is a dict of small
+      rings and the gather-or-kernel choice; a model with ``cache_steps``
+      names the loop step whose cache layer a call means by ``step=`` on
+      ``write_token`` and ``decode_attention`` (an int, or an int32 scalar
+      traced inside the model's own device loop, whose carry then holds
+      the cache). ``stats`` is a dict of small
       int arrays a step that the engine feeds to the ``serving/*``
       histograms of the same names: ``moe_experts_touched``,
       ``moe_max_expert_rows``, ``moe_held_pairs`` [expert layers],
       ``state_slots_stepped``, ``attn_rows_read.<group>``,
-      ``attn_rows_context.<group>``, ``index_blocks_scored``; a name
+      ``attn_rows_context.<group>``, ``index_blocks_scored``,
+      ``ut_expected_exit_step``; a name
       without a histogram (a probe) rides to ``engine.last_decode_stats``
       only;
     * ``verify`` (optional; ``hasattr``): scores a window of drafted tokens
@@ -501,6 +509,13 @@ class ServedLM:
       ``KV``, ``LATENT``, ``STATE``, names that ``serving/kv_cache.py``
       OWNS (``serving`` lies below ``models``, which imports them at
       module top); absent: ``KV``;
+    * ``cache_steps``: the cache layers EACH layer of a paged ``KV`` group
+      keeps, one a loop step, of a model that runs its layers that many
+      times over a token with the same weights (``n_layer`` stays the
+      weights' layers: pools, page bytes and a page payload are sized by
+      ``n_layer x cache_steps``; a group's one page table serves them all,
+      so admission counts the pages it counted). Absent: 1. Over it the
+      engine refuses a latent cache and the int8 pool;
     * ``latent_row``: ``(rank, rope)``; the cache is then a
       ``LatentPagedCache`` of ONE ``[c | kr']`` row a token a layer, over
       every layer or over the ``LATENT`` groups. Absent: K and V rows;

@@ -395,10 +395,9 @@ class ServingEngine:
     (``prefill`` or, where the model has it, ``prefill_last``; ``decode``;
     ``verify`` where it has it) and what it reads of ``model.cfg``
     (``n_layer``, ``n_head``, ``d_head``, ``max_seq``, ``dtype`` and, by
-    ``getattr``, ``n_kv_head``, ``cache_groups``, ``latent_row``,
-    ``slot_state``, ``state_recurrence``, ``index_row``, ``experts_held``),
-    each with what its
-    absence means.
+    ``getattr``, ``n_kv_head``, ``cache_groups``, ``cache_steps``,
+    ``latent_row``, ``slot_state``, ``state_recurrence``, ``index_row``,
+    ``experts_held``), each with what its absence means.
     Every ``getattr``/``hasattr`` on a model or its config in this module
     is one of those. The cache groups' kinds (``KV``, ``LATENT``,
     ``STATE``) are ``serving.kv_cache``'s; a model's decode ``stats`` go to
@@ -427,6 +426,14 @@ class ServingEngine:
         latent = getattr(mcfg, "latent_row", None)
         if len(layer_groups) > 1 or latent:
             self._refuse_over_groups(layer_groups, latent)
+        # cache layers a layer of a paged group (a looped model: one a step)
+        steps = int(getattr(mcfg, "cache_steps", 1))
+        if steps > 1 and (latent or self.cfg.kv_dtype == "int8"):
+            raise ValueError(
+                "%s is not supported over a cache of %d cache layers a "
+                "layer (cache_steps)" % (
+                    "a latent cache" if latent else "the int8 KV pool",
+                    steps))
         self.pools: List[PagePool] = []
         groups = []
         if self.cfg.paged:
@@ -461,6 +468,8 @@ class ServingEngine:
                 kv_scales = self._calibrated_kv_scales(mcfg)
             geometry = dict(dtype=mcfg.dtype, groups=groups,
                             q_per_kv=q_per_kv)
+            if steps > 1:
+                geometry["cache_steps"] = steps
             slot_state = getattr(mcfg, "slot_state", None)
             if slot_state is not None:   # K and V pages BESIDE a state
                 geometry.update(
@@ -479,7 +488,7 @@ class ServingEngine:
             n_kv, _ = _query_groups(mcfg, layer_groups)
             self.cache_ops = ContiguousKVCache(
                 mcfg.n_layer, n_kv, mcfg.d_head, self.cfg.slots,
-                self.cfg.max_seq, dtype=mcfg.dtype)
+                self.cfg.max_seq, dtype=mcfg.dtype, cache_steps=steps)
         # the third start-up phase (compile_cache.phases()): first pool
         # allocated to last, so pages, states and the per-slot tables
         with _cc.phase("startup/pools"):
@@ -1748,6 +1757,7 @@ class ServingEngine:
         model, ops, cfg = self.model, self.cache_ops, self.cfg
 
         last_only = hasattr(model, "prefill_last")   # optional: contract
+        steps = ops.cache_steps
 
         def prefill(params, cache, state, dest, prompt, ints, temp):
             slot, length, maxnew, topk, seed, _ = ints
@@ -1768,6 +1778,13 @@ class ServingEngine:
                     kv, left = kv
                     cache = ops.write_slot_state(
                         cache, i, *(t[0] for t in left), dest)
+                if steps > 1:
+                    # a looped model: K and V a step, [steps, B, S, H, D]
+                    for t in range(steps):
+                        cache = ops.write_prompt(
+                            cache, i, *(x[t, 0] for x in kv), dest, length,
+                            step=t)
+                    continue
                 cache = ops.write_prompt(cache, i, *(t[0] for t in kv), dest,
                                          length)
             # first generated token: same sampler as the decode scan, keyed

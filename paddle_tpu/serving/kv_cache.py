@@ -91,6 +91,21 @@ recurrent state, keeps both. ``write_token``, ``context`` and
 ``state_step`` and ``write_slot_state`` its state, and ``write_prompt``
 its pages (its state where it has no pages).
 
+Cache STEPS (``cache_steps``): a model that runs its layers T times over a
+token (a looped decoder: the same weights at every step) keeps, a layer,
+what EACH step wrote at every position, since step t of a layer attends
+over step t's rows alone. The pools then hold ``cache_steps`` layers a
+layer of the model, layer-major: layer ``li`` of a group at step ``t`` is
+the pool's layer ``li * cache_steps + t``. ``write_token``,
+``write_prompt``, ``context`` and ``decode_attention`` take ``step=`` beside
+``layer``: an int, or an int32 scalar traced inside the model's device loop
+over its steps (the layer stays static: the group and the place in it come
+from ``_where`` as ever). A group's ONE page table serves all its pool
+layers, so pages, admission and page sharing count what they counted and a
+page is ``cache_steps`` times the bytes. With ``cache_steps`` 1 (every other
+model) ``step`` is not passed and the pool's layer is the Python int it
+always was.
+
 Both write paths scatter with ``mode="drop"`` on out-of-bounds destination
 rows, so inactive slots / padding positions are dropped INSIDE the compiled
 step — no host-side branching, and unwritten rows stay zero in both
@@ -159,13 +174,26 @@ class _KVCacheBase:
     layout = "base"
 
     def __init__(self, n_layer: int, n_head: int, d_head: int, slots: int,
-                 max_ctx: int, dtype=jnp.float32):
+                 max_ctx: int, dtype=jnp.float32, cache_steps: int = 1):
         self.n_layer = int(n_layer)
         self.n_head = int(n_head)
         self.d_head = int(d_head)
         self.slots = int(slots)
         self.max_ctx = int(max_ctx)
         self.dtype = jnp.dtype(dtype)
+        self.cache_steps = int(cache_steps)
+        if self.cache_steps < 1:
+            raise ValueError("cache_steps=%d: a layer keeps at least one "
+                             "cache layer" % self.cache_steps)
+
+    def _pool_layer(self, li: int, step):
+        """The pool's layer of a model layer's place ``li`` at loop step
+        ``step`` (None: step 0), layer-major: ``li * cache_steps + step``.
+        ``step`` may be an int32 scalar traced in a device loop. With ONE
+        cache layer a layer it is ``li`` itself, a Python int."""
+        if self.cache_steps == 1:
+            return li
+        return li * self.cache_steps + (0 if step is None else step)
 
     def cache_bytes(self, state: Cache) -> int:
         return int(state["k"].nbytes + state["v"].nbytes)
@@ -191,8 +219,9 @@ class PagedKVCache(_KVCacheBase):
                  groups: Optional[Sequence[CacheGroup]] = None,
                  q_per_kv: Union[int, Mapping[str, int]] = 1,
                  slot_state: Optional[Sequence[int]] = None,
-                 recurrence: str = "kda"):
-        super().__init__(n_layer, n_head, d_head, slots, max_ctx, dtype)
+                 recurrence: str = "kda", cache_steps: int = 1):
+        super().__init__(n_layer, n_head, d_head, slots, max_ctx, dtype,
+                         cache_steps)
         if max_ctx % page_size != 0:
             raise ValueError("max_ctx=%d must be a multiple of page_size=%d"
                              % (max_ctx, page_size))
@@ -326,8 +355,8 @@ class PagedKVCache(_KVCacheBase):
                 state[self._key(gi, "tail")] = jnp.zeros(
                     (len(g.layers), self.slots, taps, width), self.dtype)
                 continue
-            shp = (len(g.layers), g.num_pages * self.page_size,
-                   self.row_width)
+            shp = (len(g.layers) * self.cache_steps,
+                   g.num_pages * self.page_size, self.row_width)
             for what in self._POOLS:
                 state[self._key(gi, what)] = jnp.zeros(
                     shp, self._storage_dtype())
@@ -379,10 +408,11 @@ class PagedKVCache(_KVCacheBase):
 
     # -- decode (one token per slot) -----------------------------------------
     def write_token(self, state: Cache, layer: int, k_new, v_new, pos,
-                    active) -> Cache:
+                    active, step=None) -> Cache:
         """k_new/v_new [B,H,D] written at logical position ``pos[b]`` of
         slot b (in a window group: at its place in the ring); inactive
-        slots dropped via an OOB destination row."""
+        slots dropped via an OOB destination row. ``step``: the loop step
+        whose cache layer of ``layer`` is written (:meth:`_pool_layer`)."""
         ps = self.page_size
         gi, _ = self._where[layer]
         pt = state[self._key(gi, "pt")]
@@ -392,18 +422,19 @@ class PagedKVCache(_KVCacheBase):
             idx = idx % self.group_pages_per_slot(gi)
         dest = pt[b_idx, idx] * ps + pos % ps
         dest = jnp.where(active, dest, self._drop_row(gi))
-        return self._write_rows(state, layer, dest, k_new, v_new)
+        return self._write_rows(state, layer, dest, k_new, v_new, step)
 
     def _drop_row(self, gi: int) -> int:
         """One past group ``gi``'s last pool row: a scatter to it drops."""
         return self.groups[gi].num_pages * self.page_size
 
-    def _write_rows(self, state: Cache, layer: int, dest, k_new, v_new
-                    ) -> Cache:
+    def _write_rows(self, state: Cache, layer: int, dest, k_new, v_new,
+                    step=None) -> Cache:
         """Scatter ``[N, H, D]`` updates into rows ``dest`` [N] of
-        ``layer`` in its group's pool; rows at :meth:`_drop_row` are
-        dropped."""
+        ``layer`` (at ``step``) in its group's pool; rows at
+        :meth:`_drop_row` are dropped."""
         gi, li = self._where[layer]
+        li = self._pool_layer(li, step)
         kk, vk = self._key(gi, "k"), self._key(gi, "v")
         return {
             **state,
@@ -413,12 +444,15 @@ class PagedKVCache(_KVCacheBase):
                 v_new.reshape(-1, self.row_width), mode="drop"),
         }
 
-    def context(self, state: Cache, layer: int) -> Tuple[jnp.ndarray, jnp.ndarray]:
-        """Gather every slot's pages of ``layer``'s group back into page-
-        table order: ``[slots, rows, H, D]`` with ``rows`` = ``max_ctx``, or
-        the ring's length in a window group (ring order, not position
-        order) — the XLA-gather paged-attention path."""
+    def context(self, state: Cache, layer: int, step=None
+                ) -> Tuple[jnp.ndarray, jnp.ndarray]:
+        """Gather every slot's pages of ``layer``'s group (the cache layer
+        of ``step``) back into page-table order: ``[slots, rows, H, D]``
+        with ``rows`` = ``max_ctx``, or the ring's length in a window group
+        (ring order, not position order) — the XLA-gather paged-attention
+        path."""
         gi, li = self._where[layer]
+        li = self._pool_layer(li, step)
         rows = self._context_rows(state[self._key(gi, "pt")])
         return (self._gather(state[self._key(gi, "k")], li, rows),
                 self._gather(state[self._key(gi, "v")], li, rows))
@@ -430,7 +464,7 @@ class PagedKVCache(_KVCacheBase):
         rows = (pt * ps)[:, :, None] + jnp.arange(ps)[None, None, :]
         return rows.reshape(pt.shape[0], pt.shape[1] * ps)
 
-    def _gather(self, pool, li: int, rows) -> jnp.ndarray:
+    def _gather(self, pool, li, rows) -> jnp.ndarray:
         """``pool[li, rows]`` with the heads split AFTER the gather:
         ``[slots, rows, H, D]``."""
         return pool[li, rows].reshape(rows.shape + (self.n_head,
@@ -481,8 +515,10 @@ class PagedKVCache(_KVCacheBase):
                 for gi, g in enumerate(self.groups) if g.kind != STATE}
 
     def decode_attention(self, state: Cache, layer: int, q, ctx_len,
-                         active, sm_scale: float = 1.0) -> jnp.ndarray:
-        """One decode-attention step over this layer's ragged contexts:
+                         active, sm_scale: float = 1.0, step=None
+                         ) -> jnp.ndarray:
+        """One decode-attention step over this layer's ragged contexts (at
+        loop step ``step``: the rows that step wrote, no other's):
         ``q`` [B, G*H, D] (G = 1: as many query heads as KV heads) in,
         [B, G*H, D] out. A slot attends over its LIVE length
         (:func:`_live_len`: 0 where ``active`` [B] is false, so a slot that
@@ -514,9 +550,9 @@ class PagedKVCache(_KVCacheBase):
             return _pa.paged_decode_attention(
                 q, state[self._key(gi, "k")], state[self._key(gi, "v")],
                 state[self._key(gi, "pt")], length,
-                page_size=self.page_size, layer=li, sm_scale=sm_scale,
-                interpret=(mode == "interpret"))
-        ctx_k, ctx_v = self.context(state, layer)
+                page_size=self.page_size, layer=self._pool_layer(li, step),
+                sm_scale=sm_scale, interpret=(mode == "interpret"))
+        ctx_k, ctx_v = self.context(state, layer, step)
         return attention_ops.decode_attention(q, ctx_k, ctx_v, length,
                                               sm_scale=sm_scale)
 
@@ -649,13 +685,15 @@ class PagedKVCache(_KVCacheBase):
         return np.concatenate(rows)
 
     def write_prompt(self, state: Cache, layer: int, k_new, v_new, dest,
-                     length) -> Cache:
+                     length, step=None) -> Cache:
         """k_new/v_new [S,H,D] for ONE sequence; ``dest`` is
         :meth:`prompt_dest_groups`'s row; positions >= length are dropped,
         and in a window group the positions that have already left the
         window (< length - window) too: the last ``min(length, window)``
-        land at their places in the ring. For a layer that has a state
-        and NO pages ``k_new`` is the state and ``v_new`` the tail:
+        land at their places in the ring. ``step``: the loop step that
+        made these rows (a model with ``cache_steps`` hands a prompt's K
+        and V a step, and the engine writes each). For a layer that has a
+        state and NO pages ``k_new`` is the state and ``v_new`` the tail:
         :meth:`write_slot_state`'s."""
         ps = self.page_size
         if layer not in self._where:
@@ -671,7 +709,7 @@ class PagedKVCache(_KVCacheBase):
             idx = idx % self.group_pages_per_slot(gi)
         flat = dest[off + idx] * ps + j % ps
         flat = jnp.where(keep, flat, self._drop_row(gi))
-        return self._write_rows(state, layer, flat, k_new, v_new)
+        return self._write_rows(state, layer, flat, k_new, v_new, step)
 
     def write_slot_state(self, state: Cache, layer: int, s_new, tail_new,
                          dest) -> Cache:
@@ -698,6 +736,7 @@ class PagedKVCache(_KVCacheBase):
         """Geometry a page payload must match to be importable here —
         embedded in every export, checked on every import."""
         return {"layout": self.layout, "n_layer": self.n_layer,
+                "cache_steps": self.cache_steps,
                 "n_head": self.n_head, "d_head": self.d_head,
                 "page_size": self.page_size,
                 "kv_dtype": jnp.dtype(self._storage_dtype()).name}
@@ -705,6 +744,8 @@ class PagedKVCache(_KVCacheBase):
     def _check_meta(self, meta: dict, n_blobs: int, blobs) -> None:
         want = self.page_meta()
         got = {k: meta.get(k) for k in want}
+        # a payload from before ``cache_steps`` has one cache layer a layer
+        got["cache_steps"] = meta.get("cache_steps", 1)
         if got != want:
             raise ValueError("page payload geometry mismatch: %r != %r"
                              % (got, want))
@@ -714,9 +755,10 @@ class PagedKVCache(_KVCacheBase):
 
     def export_pages(self, state: Cache, pages):
         """Serialize ``pages`` (pool page ids) to ``(meta, blobs)``: raw
-        C-order bytes of the K rows then the V rows, ``[n_layer,
-        n_pages*page_size, H, D]`` each (which the ``[.., H*D]`` pool's
-        rows are, byte for byte) — bit-exact, no float formatting."""
+        C-order bytes of the K rows then the V rows, ``[n_layer *
+        cache_steps, n_pages*page_size, H, D]`` each (which the ``[..,
+        H*D]`` pool's rows are, byte for byte) — bit-exact, no float
+        formatting."""
         self._single_group("page export")
         rows = self._page_rows(pages)
         k = np.ascontiguousarray(np.asarray(state["k"][:, rows]))
@@ -738,7 +780,7 @@ class PagedKVCache(_KVCacheBase):
                              % (n, len(pages)))
         rows = self._page_rows(pages)
         dt = _dtype_by_name(meta["kv_dtype"])
-        shp = (self.n_layer, len(rows), self.row_width)
+        shp = (self.n_layer * self.cache_steps, len(rows), self.row_width)
         want = int(np.prod(shp)) * dt.itemsize
         if len(blobs[0]) != want or len(blobs[1]) != want:
             raise ValueError("page payload blob bytes %d/%d != %d"
@@ -825,7 +867,9 @@ class Int8PagedKVCache(PagedKVCache):
                                     self._quant(v_new, self.v_scale),
                                     dest, length)
 
-    def context(self, state: Cache, layer: int) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    def context(self, state: Cache, layer: int, step=None
+                ) -> Tuple[jnp.ndarray, jnp.ndarray]:
+        # one cache layer a layer: the scales are [n_layer, num_pages]
         rows = self._context_rows(state["pt"])
         pages = rows // self.page_size  # page id per logical position
         ks = state["ks"][layer][pages][:, :, None, None].astype(self.dtype)
@@ -1133,8 +1177,8 @@ class LatentPagedCache(PagedKVCache):
             new_dest_length = (row_new, None, dest, length)
         return super().write_prompt(state, layer, *new_dest_length)
 
-    def _write_rows(self, state: Cache, layer: int, dest, row_new, _v
-                    ) -> Cache:
+    def _write_rows(self, state: Cache, layer: int, dest, row_new, _v,
+                    _step=None) -> Cache:
         """The paged cache's destinations, one padded row each."""
         gi, li = self._where[layer]
         key = self._key(gi, "c")
@@ -1193,33 +1237,44 @@ class ContiguousKVCache(_KVCacheBase):
     layout = "contiguous"
 
     def init_state(self) -> Cache:
-        shp = (self.n_layer, self.slots, self.max_ctx, self.n_head, self.d_head)
+        shp = (self.n_layer * self.cache_steps, self.slots, self.max_ctx,
+               self.n_head, self.d_head)
         return {"k": jnp.zeros(shp, self.dtype),
                 "v": jnp.zeros(shp, self.dtype)}
 
     def write_token(self, state: Cache, layer: int, k_new, v_new, pos,
-                    active) -> Cache:
+                    active, step=None) -> Cache:
         b_idx = jnp.arange(pos.shape[0])
         pos_c = jnp.where(active, pos, self.max_ctx)  # OOB -> dropped
+        layer = self._pool_layer(layer, step)
         return {
             **state,
             "k": state["k"].at[layer, b_idx, pos_c].set(k_new, mode="drop"),
             "v": state["v"].at[layer, b_idx, pos_c].set(v_new, mode="drop"),
         }
 
-    def context(self, state: Cache, layer: int) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    def context(self, state: Cache, layer: int, step=None
+                ) -> Tuple[jnp.ndarray, jnp.ndarray]:
+        layer = self._pool_layer(layer, step)
         return state["k"][layer], state["v"][layer]
 
     def decode_attention(self, state: Cache, layer: int, q, ctx_len,
-                         active, sm_scale: float = 1.0) -> jnp.ndarray:
+                         active, sm_scale: float = 1.0, step=None
+                         ) -> jnp.ndarray:
         """Dense layout has no gather to fuse away — always the XLA path
         (the parity yardstick the paged kernel is measured against), over
         the same live lengths as the paged layout."""
         from ..ops import attention_ops
 
-        ctx_k, ctx_v = self.context(state, layer)
+        ctx_k, ctx_v = self.context(state, layer, step)
         return attention_ops.decode_attention(
             q, ctx_k, ctx_v, _live_len(ctx_len, active), sm_scale=sm_scale)
+
+    def rows_read(self, ctx_len, active) -> Dict[str, jnp.ndarray]:
+        """The paged layout's count, for the one group of every position
+        that this layout is."""
+        return {"attn_rows_read.global": jnp.sum(
+            _live_len(ctx_len, active)).astype(jnp.int32)}
 
     def decode_verify(self, state: Cache, layer: int, q, ctx_len,
                       active, sm_scale: float = 1.0) -> jnp.ndarray:
@@ -1234,10 +1289,11 @@ class ContiguousKVCache(_KVCacheBase):
         return np.int32(slot)
 
     def write_prompt(self, state: Cache, layer: int, k_new, v_new, dest,
-                     length) -> Cache:
+                     length, step=None) -> Cache:
         s = k_new.shape[0]
         j = jnp.arange(s)
         pos_c = jnp.where(j < length, j, self.max_ctx)
+        layer = self._pool_layer(layer, step)
         return {
             **state,
             "k": state["k"].at[layer, dest, pos_c].set(k_new, mode="drop"),

@@ -198,6 +198,13 @@ STATE_SLOTS_STEPPED = _mx.histogram(
     "serving/state_slots_stepped",
     help="live slots whose recurrent state a decode step advanced, one "
          "observation a step (a model with linear-attention layers)")
+UT_EXPECTED_EXIT_STEP = _mx.histogram(
+    "serving/ut_expected_exit_step",
+    help="the loop step a looped model's exit gate expects to leave at, "
+         "sum_t (t + 1) p_t x 100, mean over the live slots, one "
+         "observation a decode step (a model that runs its layers several "
+         "times a token; with a threshold of 1 every step runs whatever "
+         "this reads)")
 STATE_POOL_BYTES = _mx.gauge(
     "serving/state_pool_bytes",
     help="bytes of the per-slot recurrent states and convolution tails "
@@ -256,7 +263,8 @@ _MODEL_STATS = {"moe_experts_touched": MOE_EXPERTS_TOUCHED,
                 "moe_max_expert_rows": MOE_MAX_EXPERT_ROWS,
                 "moe_held_pairs": MOE_HELD_PAIRS,
                 "state_slots_stepped": STATE_SLOTS_STEPPED,
-                "index_blocks_scored": INDEX_BLOCKS_SCORED}
+                "index_blocks_scored": INDEX_BLOCKS_SCORED,
+                "ut_expected_exit_step": UT_EXPECTED_EXIT_STEP}
 
 
 def pages_used(group: str):
@@ -291,7 +299,8 @@ def attn_rows_context(group: str):
 def model_stat(name: str):
     """The histogram the engine feeds a model's decode ``stats[name]`` to
     (an observation a value a step), or None for a name it does not know:
-    the three ``moe_*`` and ``state_slots_stepped`` above and
+    the three ``moe_*``, ``state_slots_stepped`` and
+    ``ut_expected_exit_step`` above and
     ``attn_rows_read.<group>``, ``index_blocks_scored`` and
     ``attn_rows_context.<group>``, looked up once a name."""
     hist = _MODEL_STATS.get(name)

@@ -329,13 +329,18 @@ CELL_KERNELS = {
     "smallthinker_window": (16, 28, 4, 128, 9, 4096, 256, "bf16[16,8,512]"),
     "laguna_global": (16, 48, 8, 128, 2, 9216, 1024, "bf16[16,8,1024]"),
     "laguna_window": (16, 72, 8, 128, 3, 512, 32, "bf16[16,16,1024]"),
+    # 48 layers x 4 steps in one pool, the layer a TRACED scalar (the step
+    # of a device loop): no sweep has visited this bucket
+    "ouro_loop": (12, 16, 16, 128, 192, 288, 64, "bf16[12,1,2048]"),
 }
 
 
 @pytest.mark.parametrize("cell", sorted(CELL_KERNELS))
 def test_paged_kernel_at_the_cells_shapes_and_shipped_waves(chip, cell):
     """bf16 pools, the ``block_pages`` the shipped v5e table holds for the
-    call's bucket (a measured entry, not the wildcard): ONE custom call
+    call's bucket (a measured entry, not the wildcard, but for the looped
+    model's 2,048-lane rows, which take the wildcard clamped to a wave's
+    budget, under a layer that is traced): ONE custom call
     named ``paged_attention`` with the result the trace readers tell the
     calls by, two K and two V wave buffers in the pool's type within the
     wave budget, and the pool handed over untouched."""
@@ -346,15 +351,27 @@ def test_paged_kernel_at_the_cells_shapes_and_shipped_waves(chip, cell):
     bucket = tune.bucket_ctx(pps * ps, hd)
     cfg, src = tune.lookup("paged_attention", bucket, device="tpu-v5e")
     shipped = tune.table.read_entries(tune.table.shipped_path())
-    assert src == "shipped" and tune.table.entry_key(
-        "paged_attention", bucket, "tpu-v5e") in shipped, (bucket, src)
+    measured = tune.table.entry_key("paged_attention", bucket,
+                                    "tpu-v5e") in shipped
+    assert src == "shipped" and measured == (cell != "ouro_loop"), (bucket,
+                                                                     src)
     bp = pa._block_pages(cfg["block_pages"], ps, pps, pps * ps, hd, 2)
-    assert bp == cfg["block_pages"], "the shipped wave is clamped"
-    fn = functools.partial(pa.paged_decode_attention, page_size=ps, layer=1,
-                           sm_scale=d ** -0.5, block_pages=bp)
+    if measured:
+        assert bp == cfg["block_pages"], "the shipped wave is clamped"
+    else:       # the wildcard's 16 pages, clamped to what a wave may hold
+        assert bp == pa._wave_fits(ps, hd, 2) == 8
+    kw = dict(page_size=ps, sm_scale=d ** -0.5, block_pages=bp)
     pool = ((n_layer, pages * ps, hd), jnp.bfloat16)
     shapes = (((b, hq, d), jnp.bfloat16), pool, pool, ((b, pps), jnp.int32),
               ((b,), jnp.int32))
+    if cell == "ouro_loop":
+        shapes += (((), jnp.int32),)
+
+        def fn(q, k, v, pt, ctx, layer):
+            return pa.paged_decode_attention(q, k, v, pt, ctx, layer=layer,
+                                             **kw)
+    else:
+        fn = functools.partial(pa.paged_decode_attention, layer=1, **kw)
     text = compiled_text(chip, fn, *shapes)
     kernel, = [ln for ln in text.split("\n") if "tpu_custom_call" in ln]
     assert kernel.strip().startswith("%paged_attention")
@@ -490,8 +507,71 @@ def _serve_case(name, chip, n_layer=None):
     }[name], ops.num_rows
 
 
+OURO_SERVE = dict(slots=12, page_size=16, num_pages=288, max_seq=1024,
+                  n_layer=4, steps=4, bucket=512)
+
+
+def _ouro_case(name, chip):
+    """``_serve_case``'s twin for the looped model at Ouro-2.6B's published
+    widths over the cell's pool, four of its 48 layers (16 cache layers:
+    the loop and its carry are what is looked at, and they are the same):
+    the decode chunk, whose steps are a device loop that CARRIES the pool
+    under a traced cache step, and the 512-row prefill, whose scan hands
+    the engine K and V a step."""
+    from paddle_tpu.models import ouro
+    from paddle_tpu.serving.kv_cache import PagedKVCache
+
+    def sds(x):
+        return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=chip)
+
+    g = OURO_SERVE
+    cfg = ouro.OuroConfig(49152, g["n_layer"], 2048, 16, 16, 128, 5632,
+                          ut_steps=g["steps"], max_seq=g["max_seq"],
+                          dtype="bfloat16")
+    model = ouro.OuroLM(cfg, params={})
+    ops = PagedKVCache(cfg.n_layer, 16, 128, g["slots"], g["max_seq"],
+                       g["page_size"], g["num_pages"], dtype="bfloat16",
+                       cache_steps=cfg.cache_steps)
+    params = jax.tree_util.tree_map(
+        sds, jax.eval_shape(lambda: ouro.init_params(cfg, 0)))
+    cache = jax.tree_util.tree_map(sds, jax.eval_shape(ops.init_state))
+    b = g["slots"]
+    ints = jax.ShapeDtypeStruct((b,), jnp.int32, sharding=chip)
+    flags = jax.ShapeDtypeStruct((b,), jnp.bool_, sharding=chip)
+    scalar = jax.ShapeDtypeStruct((), jnp.int32, sharding=chip)
+
+    def chunk(params, cache, lengths, tokens, active):
+        def body(carry, _):
+            cache, ln, tk, ac = carry
+            logits, cache, stats = model.decode(params, cache, ops, tk, ln,
+                                                ac)
+            nxt = jnp.where(ac, jnp.argmax(logits, -1).astype(jnp.int32), tk)
+            return (cache, ln + ac, nxt, ac), (nxt, stats)
+
+        return jax.lax.scan(body, (cache, lengths, tokens, active), None,
+                            length=1)
+
+    def prefill(params, cache, dest, prompt, length):
+        logits, kvs = model.prefill_last(params, prompt[None], length[None])
+        for i, kv in enumerate(kvs):
+            for t in range(g["steps"]):
+                cache = ops.write_prompt(cache, i, *(x[t, 0] for x in kv),
+                                         dest, length, step=t)
+        return cache, jnp.argmax(logits[0])
+
+    return {
+        "ouro_chunk": (chunk, (params, cache, ints, ints, flags)),
+        "ouro_prefill": (prefill, (
+            params, cache, jax.ShapeDtypeStruct((ops.pages_per_slot,),
+                                                jnp.int32, sharding=chip),
+            jax.ShapeDtypeStruct((g["bucket"],), jnp.int32, sharding=chip),
+            scalar)),
+    }[name], ops.num_rows
+
+
 @pytest.mark.parametrize("exe", ["chunk", "verify", "prefill",
-                                 "prefill_armed", "resume"])
+                                 "prefill_armed", "resume", "ouro_chunk",
+                                 "ouro_prefill"])
 def test_serving_executables_leave_the_pool_in_place(chip, monkeypatch, exe):
     """The decode chunk, the verify window, a prefill bucket (alone, and as
     the admission the engine launches: with the slot's page table and its
@@ -508,11 +588,17 @@ def test_serving_executables_leave_the_pool_in_place(chip, monkeypatch, exe):
     # the backend is the CPU and only the compile's target is the chip
     monkeypatch.setattr(attention_ops, "paged_kernel_mode",
                         lambda: "compiled")
-    (fn, args), rows = _serve_case(exe, chip)
+    looped = exe.startswith("ouro")
+    if looped:
+        monkeypatch.setattr(attention_ops, "_on_tpu", lambda: True)
+    (fn, args), rows = (_ouro_case if looped else _serve_case)(exe, chip)
     compiled = jax.jit(fn, donate_argnums=(1,)).lower(*args).compile()
     text = compiled.as_text()
-    if not exe.startswith("prefill"):  # it attends over its own K and V
-        assert text.count("tpu_custom_call") == SERVE["n_layer"]
+    if "prefill" not in exe:  # a prefill attends over its own K and V
+        assert text.count("tpu_custom_call") == (
+            OURO_SERVE if looped else SERVE)["n_layer"]
+    if looped:      # the steps stayed ONE loop: nothing unrolled them
+        assert len(re.findall(r" while\(", text)) == 1
     instructions = list(_instructions(text))
     types = {name: rtype for name, rtype, _, _ in instructions}
     moved = [(op, rtype) for _, rtype, op, operands in instructions
@@ -524,12 +610,18 @@ def test_serving_executables_leave_the_pool_in_place(chip, monkeypatch, exe):
     # cache is argument 1 of every executable: its leaves follow the
     # params' in the flattened parameter list, "k" "pt" "v" in key order
     n_params = len(jax.tree_util.tree_leaves(args[0]))
+    if exe == "ouro_prefill":   # a prefill reads no gate: ``w_e``, ``b_e``
+        n_params -= 2           # are pruned from the executable's arguments
     aliased = {int(p) for p in re.findall(
         r"\((\d+), \{\}, (?:may|must)-alias\)", text.split("\n", 1)[0])}
     assert {n_params, n_params + 2} <= aliased
     if exe == "prefill_armed":  # the page table is written where it lies
         assert n_params + 1 in aliased
-    assert compiled.memory_analysis().temp_size_in_bytes < 64 * 2 ** 20
+    limit = 64 * 2 ** 20
+    if exe == "ouro_prefill":   # K and V of 4 steps x 4 layers x 512 rows
+        g = OURO_SERVE
+        limit = g["n_layer"] * g["steps"] * rows * 2048 * 2     # one pool
+    assert compiled.memory_analysis().temp_size_in_bytes < limit
 
 
 def _computations(text):

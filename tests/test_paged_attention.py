@@ -264,6 +264,57 @@ def test_wave_buffers_fit_their_budget_at_every_served_row_width():
     assert pa._block_pages(64, 16, 4, 64, 128, 2) == 4  # the slot's pages
 
 
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+def test_kernel_and_gather_agree_under_a_traced_cache_step(rng, dtype):
+    """A looped model's cache: three cache layers a layer, the step a
+    TRACED scalar inside a device loop whose carry is the pool. Every
+    (layer, step) call of ``PagedKVCache.decode_attention`` by the kernel
+    (interpreted) equals the gather path's, each over its own pool layer:
+    the others hold other rows."""
+    import jax
+
+    from paddle_tpu.serving.kv_cache import PagedKVCache
+
+    ops = PagedKVCache(2, 2, 64, 3, 32, 8, 12, dtype=dtype, cache_steps=3)
+    state = ops.init_state()
+    for key in ("k", "v"):
+        state[key] = jnp.asarray(rng.randn(*state[key].shape)).astype(dtype)
+    state["pt"] = jnp.asarray(rng.permutation(12).reshape(3, 4), jnp.int32)
+    q = jnp.asarray(rng.randn(3, 2, 64)).astype(dtype)
+    ctx = jnp.asarray([5, 32, 17], jnp.int32)
+    active = jnp.asarray([True, True, False])
+
+    def looped(state):
+        def body(t, out):
+            return out.at[t].set(jnp.stack([
+                ops.decode_attention(state, layer, q, ctx, active,
+                                     sm_scale=0.125, step=t)
+                for layer in range(2)]))
+
+        return jax.lax.fori_loop(0, 3, body,
+                                 jnp.zeros((3, 2, 3, 2, 64), dtype))
+
+    got = {}
+    for mode in ("off", "interpret"):
+        set_flag("paged_attention_kernel", mode)
+        assert (ops.kernel_mode()[0] is None) == (mode == "off")
+        got[mode] = np.asarray(jax.jit(looped)(state), np.float32)
+    live = got["off"][:, :, :2]
+    tol = dict(rtol=1e-6, atol=1e-6) if dtype == "float32" \
+        else dict(rtol=0, atol=2.0 ** -6 * np.abs(live).max())   # two ulps
+    np.testing.assert_allclose(got["interpret"][:, :, :2], live, **tol)
+    # each (layer, step) read a pool layer of its own
+    flat = live.reshape(6, -1)
+    assert np.abs(flat[:, None] - flat[None]).max(axis=-1)[
+        ~np.eye(6, dtype=bool)].min() > 0.01
+    # and it is the one the int names: layer-major, ``layer * 3 + step``
+    want = pa.gather_reference(q, state["k"][1 * 3 + 2], state["v"][1 * 3 + 2],
+                               state["pt"], jnp.where(active, ctx, 0), 8,
+                               sm_scale=0.125)
+    np.testing.assert_allclose(live[2, 1], np.asarray(want, np.float32)[:2],
+                               **tol)
+
+
 def test_pool_shape_errors_are_typed(rng):
     """A pool without a layer, a layer outside the pool, and a ``[rows, H,
     D]`` layer (the layout this kernel no longer takes) all raise before

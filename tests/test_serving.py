@@ -938,3 +938,115 @@ def test_a_dispatch_is_not_retried_across_an_arming(rng):
     eng.run()
     eng.close()
     assert late.state == "finished" and len(late.tokens_out) == 3
+
+
+# -- cache layers a layer: a looped model's steps ----------------------------
+
+def _stepped_cache(layout):
+    from paddle_tpu.serving.kv_cache import ContiguousKVCache, PagedKVCache
+
+    if layout == "paged":
+        ops = PagedKVCache(2, 2, 8, 2, 32, 8, 8, cache_steps=3)
+        state = ops.set_page_table(ops.init_state(), 0,
+                                   ops.prompt_dest([5, 2, 7, 0]))
+        state = ops.set_page_table(state, 1, ops.prompt_dest([1, 3, 4, 6]))
+        import jax.numpy as jnp
+
+        return ops, state, [jnp.asarray(ops.prompt_dest([5, 2, 7, 0])),
+                            jnp.asarray(ops.prompt_dest([1, 3, 4, 6]))]
+    ops = ContiguousKVCache(2, 2, 8, 2, 32, cache_steps=3)
+    return ops, ops.init_state(), [ops.prompt_dest(0), ops.prompt_dest(1)]
+
+
+@pytest.mark.parametrize("layout", ["paged", "contiguous"])
+def test_a_cache_step_as_an_int_and_as_a_traced_scalar_are_the_same_rows(
+        rng, layout):
+    """``step=`` says which of a layer's cache layers a call means: pool
+    layer ``layer * cache_steps + step``. Written a step at a time, by
+    Python ints and under ``jax.jit`` by a traced int32 scalar, the pools
+    are bit-equal, each step's rows sit in its own pool layer, and
+    ``context`` and ``decode_attention`` read exactly them."""
+    import jax
+    import jax.numpy as jnp
+
+    ops, state, dests = _stepped_cache(layout)
+    assert state["k"].shape[0] == 2 * 3
+    rows = {(layer, t): [jnp.asarray(rng.randn(11, 2, 8), jnp.float32)
+                         for _ in range(2)]
+            for layer in range(2) for t in range(3)}
+    new = {key: [jnp.asarray(rng.randn(2, 2, 8), jnp.float32)
+                 for _ in range(2)] for key in rows}
+    pos, active = jnp.asarray([11, 3]), jnp.asarray([True, False])
+
+    def fill(state, step_of):
+        for (layer, t), (k, v) in rows.items():
+            state = ops.write_prompt(state, layer, k, v, dests[0], 11,
+                                     step=step_of(t))
+            state = ops.write_token(state, layer, *new[layer, t], pos,
+                                    active, step=step_of(t))
+        return state
+
+    by_int = fill(state, lambda t: t)
+    by_traced = state
+    for (layer, t), (k, v) in rows.items():
+        by_traced = jax.jit(
+            lambda s, t, layer=layer, k=k, v=v, kn=new[layer, t]:
+            ops.write_token(ops.write_prompt(s, layer, k, v, dests[0], 11,
+                                             step=t),
+                            layer, *kn, pos, active, step=t))(
+                by_traced, jnp.int32(t))
+    for key in ("k", "v"):
+        np.testing.assert_array_equal(np.asarray(by_int[key]),
+                                      np.asarray(by_traced[key]))
+    q = jnp.asarray(rng.randn(2, 2, 8), jnp.float32)
+    for (layer, t), (k, v) in rows.items():
+        ctx_k, _ = ops.context(by_int, layer, step=t)
+        np.testing.assert_array_equal(np.asarray(ctx_k[0, :11]),
+                                      np.asarray(k))
+        np.testing.assert_array_equal(np.asarray(ctx_k[0, 11]),
+                                      np.asarray(new[layer, t][0][0]))
+        want = ops.decode_attention(by_int, layer, q, pos + 1, active,
+                                    step=t)
+        got = jax.jit(lambda s, t, layer=layer: ops.decode_attention(
+            s, layer, q, pos + 1, active, step=t))(by_int, jnp.int32(t))
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                   rtol=1e-6, atol=1e-6)
+    # one cache layer a layer: no step to name, and the layer stays an int
+    plain = type(ops)(2, 2, 8, 2, 32, *((8, 8) if layout == "paged" else ()))
+    assert plain._pool_layer(1, None) == 1
+
+
+def test_a_snapshot_of_a_stepped_pool_round_trips_and_an_old_one_loads_as_one(
+        rng):
+    """``export_pages``/``import_pages`` carry every cache layer of a page
+    (``n_layer x cache_steps``) and say so in the payload's ``cache_steps``;
+    a payload from before the key is one cache layer a layer, and one of
+    another number of steps is refused."""
+    import jax.numpy as jnp
+
+    from paddle_tpu.serving.kv_cache import PagedKVCache
+
+    src = PagedKVCache(2, 2, 8, 2, 32, 8, 8, cache_steps=2)
+    state = src.init_state()
+    state = {**state,
+             "k": jnp.asarray(rng.randn(*state["k"].shape), jnp.float32),
+             "v": jnp.asarray(rng.randn(*state["v"].shape), jnp.float32)}
+    meta, blobs = src.export_pages(state, [1, 3])
+    assert meta["cache_steps"] == 2 and meta["n_layer"] == 2
+    assert len(blobs[0]) == 4 * 16 * 16 * 4     # 2 x 2 layers of 2 pages
+    dst = PagedKVCache(2, 2, 8, 2, 32, 8, 8, cache_steps=2)
+    landed = dst.import_pages(dst.init_state(), [4, 2], meta, blobs)
+    assert dst.export_pages(landed, [4, 2])[1] == blobs
+    rows = np.r_[8:16, 24:32]
+    np.testing.assert_array_equal(
+        np.asarray(landed["k"])[:, np.r_[32:40, 16:24]],
+        np.asarray(state["k"])[:, rows])
+    one = PagedKVCache(2, 2, 8, 2, 32, 8, 8)
+    with pytest.raises(ValueError, match="geometry mismatch"):
+        one.import_pages(one.init_state(), [4, 2], meta, blobs)
+    # a payload written before the key existed: one cache layer a layer
+    old_meta, old_blobs = one.export_pages(one.init_state(), [0])
+    assert old_meta.pop("cache_steps") == 1
+    one.import_pages(one.init_state(), [5], old_meta, old_blobs)
+    with pytest.raises(ValueError, match="geometry mismatch"):
+        dst.import_pages(dst.init_state(), [5], old_meta, old_blobs)
