@@ -29,31 +29,39 @@ Design:
   its page DMA (``k_hbm.at[layer, pl.ds(row, ps)]``): nothing outside the
   kernel slices or reshapes a pool-sized array. The layer rides in SMEM
   beside the page table, so every layer's call shares one kernel body;
-- per-head reductions over the flat row ride the MXU: ``(k * q) @ seg``
-  sums each head's D lanes (``seg`` is the 0/1 head-membership matrix
-  ``[H*D, 128]``), and ``p @ seg.T`` spreads each head's probability back
-  over its lanes. That fold widens the wave to float32 and multiplies at
-  full f32 precision, so with one query head a KV head the kernel agrees
-  with the gather path to float round-off whatever the pool's type;
-- GROUPED QUERIES: ``q`` may carry ``G`` query heads for each KV head
-  (query head n reads KV head ``n // G``). It arrives in the kernel as ``[B,
-  G, H*D]`` (row g holds, for every KV head, its g-th query head), each
-  wave of pages is DMA'd ONCE and folded into all G online-softmax states.
-  With G > 1 the per-head sums go straight to the MXU: one ``[G, D] x [D,
+- HEADS THAT ARE NOT WHOLE LANE TILES (one query head a KV head, ``D %
+  128 != 0``: GPT-2 small's heads of 64) cannot be sliced out of the flat
+  row, so their per-head reductions over it ride the MXU whole: ``(k * q)
+  @ seg`` sums each head's D lanes (``seg`` is the 0/1 head-membership
+  matrix ``[H*D, 128]``), and ``p @ seg.T`` spreads each head's
+  probability back over its lanes. That fold (``per_lane``) widens the
+  wave to float32 and multiplies at full f32 precision, so for such heads
+  the kernel agrees with the gather path to float round-off whatever the
+  pool's type;
+- HEADS OF WHOLE LANE TILES AND GROUPED QUERIES: ``q`` may carry ``G``
+  query heads for each KV head (query head n reads KV head ``n // G``). It
+  arrives in the kernel as ``[B, G, H*D]`` (row g holds, for every KV head,
+  its g-th query head; G padded to whole sublanes with zero queries, one
+  query head to 8 rows like any other count), each wave of pages is DMA'd
+  ONCE and folded into all G online-softmax states. The per-head sums go
+  straight to the MXU (the ``grouped`` fold): one ``[G, D] x [D,
   rows]`` product a KV head for the scores and one ``[G, rows] x [rows, D]``
   for the weighted sum, over the head's own lane slice of the row, which on
-  the chip has to be whole lane tiles (``D % 128 == 0``; the gate says so).
+  the chip has to be whole lane tiles (``D % 128 == 0``; the gate says so
+  for G > 1, and G = 1 takes this fold only there).
   Both products take the pool's rows in the POOL's type and accumulate in
   float32: a bf16 pool is not widened (bf16 x bf16 products are exact in
   the accumulator, so the scores are the float32 ones up to the order of
   a sum), its probabilities go in as their bf16 high and low halves (16
   bits), and scale, mask and the softmax state ``(m, l, acc)`` are float32;
-  a float32 pool multiplies at full precision. So with G > 1 "to float
+  a float32 pool multiplies at full precision. So in this fold "to float
   round-off" holds for float32 pools only: a bf16 pool agrees with a
   float32 reference over the same bf16 values to 2**-16 of the largest V.
-  G = 1 keeps the head-membership matmuls above, which take any ``H*D %
-  128 == 0`` (GPT-2 small's heads of 64). One kernel, one DMA loop, the
-  fold chosen by the geometry and its precision by the pool's type;
+  One query head a KV head over heads that are not whole lane tiles keeps
+  the head-membership matmuls above, which take any ``H*D % 128 == 0``.
+  One kernel, one DMA loop, the fold chosen by the geometry
+  (:func:`paged_attention_fold`: the head's width and G, nothing else)
+  and its precision by the pool's type;
 - grid is ``(slots,)``; the page table (flattened) and per-slot ``ctx_len``
   ride in SMEM via ``PrefetchScalarGridSpec`` scalar prefetch, so page
   addresses are known before the body runs;
@@ -72,7 +80,7 @@ Design:
   is not waited for, and the position mask uses attention_ops.neg_inf — the
   SAME masking constant as the gather path — with the rows beyond
   ``ctx_len`` zeroed before use where a product could see them (K and V in
-  the G = 1 fold; V in the grouped one, whose scores of such rows are
+  the per-lane fold; V in the grouped one, whose scores of such rows are
   REPLACED by the mask), so stale rows (retired requests, unreserved
   pages, whatever either buffer last held, Inf and NaN included)
   contribute exactly 0.0;
@@ -104,6 +112,7 @@ __all__ = [
     "paged_decode_attention",
     "gather_reference",
     "paged_attention_gate",
+    "paged_attention_fold",
     "paged_attention_supported",
 ]
 
@@ -139,6 +148,18 @@ def paged_attention_gate(dtype, n_head: int, d_head: int, page_size: int,
         return ("page_size=%d is not a multiple of the %s tile's %d rows"
                 % (page_size, dt.name, sublanes))
     return None
+
+
+def paged_attention_fold(q_per_kv: int, d_head: int) -> str:
+    """Which fold a geometry takes, from the head's width: ``"grouped"``
+    (a head's lanes sliced out of the flat row, its sums small MXU
+    products) where heads are whole lane tiles, and wherever several query
+    heads share a KV head (which the gate holds to such heads on the chip);
+    ``"per_lane"`` (the head-membership matmuls over the whole row) for
+    one query head a KV head of any other width."""
+    if int(q_per_kv) > 1 or int(d_head) % _LANES == 0:
+        return "grouped"
+    return "per_lane"
 
 
 def paged_attention_supported(dtype, n_head: int, d_head: int,
@@ -197,7 +218,7 @@ def _paged_attn_kernel(pt_ref, len_ref, layer_ref, q_ref, seg_ref, segt_ref,
                        k_hbm, v_hbm, o_ref, k_scr, v_scr, sems, **static):
     """One grid step, one slot. A slot that holds nothing (``ctx_len`` <= 0)
     writes a zero block and is done: no ``q`` cast, no wave, no epilogue
-    (whose G = 1 form is a matmul that ~30 rowless slots a layer would
+    (whose per-lane form is a matmul that ~30 rowless slots a layer would
     pay for nobody)."""
     b = pl.program_id(0)  # out here: the interpreter has none in a branch
     live = len_ref[b] > 0
@@ -276,9 +297,9 @@ def _attend_slot(b, pt_ref, len_ref, layer_ref, q_ref, seg_ref, segt_ref,
         return over_lanes(jnp.broadcast_to(x, (8, hp)))[0:1]
 
     def fold_per_lane(w, buf, carry):
-        """G = 1, any head width: one state a head over the flat row, the
-        per-head sums through the head-membership matrices, in float32 at
-        full precision whatever the pool's type."""
+        """One query head a KV head of any width: one state a head over
+        the flat row, the per-head sums through the head-membership
+        matrices, in float32 at full precision whatever the pool's type."""
         valid = below_ctx(w, 0)
         # zeroed so the exactly-0 probabilities below cannot meet an
         # Inf/NaN residue
@@ -381,7 +402,8 @@ def paged_decode_attention(q, k_pages, v_pages, page_table, ctx_len, *,
 
     ``q`` [B,Hq,D] — current position's query per slot, ``Hq`` = ``G * H``
     query heads over the pool's ``H`` KV heads (G = 1: one each; G > 1:
-    grouped queries, query head n on KV head ``n // G``). ``k_pages``/
+    grouped queries, query head n on KV head ``n // G``); the fold is
+    :func:`paged_attention_fold`'s for (G, D). ``k_pages``/
     ``v_pages`` [n_layer, num_pages*page_size, H*D] — the WHOLE paged KV
     pool (serving.kv_cache.PagedKVCache state), of which the kernel reads
     layer ``layer`` (an int or an int32 scalar); or ONE layer as
@@ -393,10 +415,13 @@ def paged_decode_attention(q, k_pages, v_pages, page_table, ctx_len, *,
     ``block_pages=None`` = tuned-table lookup with the
     analytic VMEM-budget fallback (see ``_block_pages``). Returns [B,Hq,D],
     matching ``gather_reference`` (the XLA gather + decode_attention path)
-    on live rows to float32 round-off (G > 1 over a bf16 pool: to 2**-16 of
-    the largest V, against that path in float32 over the same bf16 values)
-    and EXACTLY ignoring garbage beyond ``ctx_len``. Compiled (``interpret=False``) it takes the shapes
-    :func:`paged_attention_gate` admits; callers gate on it.
+    on live rows to float32 round-off (the grouped fold, G > 1 or heads of
+    whole lane tiles, over a bf16 pool: to 2**-16 of the largest V, against
+    that path in float32 over the same bf16 values; one query head a KV
+    head over heads that are not whole lane tiles: round-off whatever the
+    pool's type) and EXACTLY ignoring garbage beyond ``ctx_len``. Compiled
+    (``interpret=False``) it takes the shapes :func:`paged_attention_gate`
+    admits; callers gate on it.
     """
     b, hq, d = q.shape
     slots, pages_per_slot = page_table.shape
@@ -413,7 +438,6 @@ def paged_decode_attention(q, k_pages, v_pages, page_table, ctx_len, *,
             "pool must be [n_layer, rows, H*%d] with a layer, or one layer "
             "[rows, H*%d] without, H dividing q's %d heads: got k %s v %s "
             "layer=%r" % (d, d, hq, k_pages.shape, v_pages.shape, layer))
-    g = hq // h
     n_layer, num_rows = k_pages.shape[:2]
     if isinstance(layer, (int, np.integer)) and not 0 <= layer < n_layer:
         raise ValueError("layer %d outside a pool of %d" % (layer, n_layer))
@@ -424,27 +448,53 @@ def paged_decode_attention(q, k_pages, v_pages, page_table, ctx_len, *,
     max_ctx = pages_per_slot * ps
     bp = _block_pages(block_pages, ps, pages_per_slot, max_ctx, hd,
                       jnp.dtype(k_pages.dtype).itemsize)
-    from ..attention_ops import neg_inf_value
-
     # head-membership of the flat row's lanes, padded to a full lane tile
     # of heads: the padded heads own no lane, so they never reach the output
     hp = -(-h // _LANES) * _LANES
     seg = (np.arange(hd)[:, None] // d
            == np.arange(hp)[None, :]).astype(np.float32)
-    if g == 1:
-        gp, qk = 1, q.reshape(b, 1, hd)
-    else:
-        # row j of a slot: the j-th query head of every KV head, padded to
-        # whole sublanes with zero queries (uniform weights, sliced away)
+    return _kernel_call(
+        q, k_pages, v_pages, page_table, ctx_len,
+        jnp.asarray(layer, jnp.int32), jnp.asarray(seg), jnp.asarray(seg.T),
+        page_size=ps, sm_scale=float(sm_scale), block_pages=bp,
+        interpret=bool(interpret))
+
+
+@functools.partial(jax.jit, inline=True, static_argnames=(
+    "page_size", "sm_scale", "block_pages", "interpret"))
+def _kernel_call(q, k_pages, v_pages, page_table, ctx_len, layer, seg,
+                 segt, *, page_size, sm_scale, block_pages, interpret):
+    """:func:`paged_decode_attention` past its checks: the call itself over
+    a whole pool, ``layer`` an int32 scalar, the head maps ``[H*D, 128]``
+    and ``[128, H*D]`` the caller's constants. Under ``jit`` with
+    ``inline=True`` so that a program's calls at ONE geometry (a model's
+    layers: 48 in the looped model's decode step) share one trace of the
+    kernel's body and one lowering, which the grouped fold's unrolled heads
+    made seconds of a start-up (PERF.md, PR 57); inlined, the caller's
+    program holds the same operations as if they were written there."""
+    from ..attention_ops import neg_inf_value
+
+    b, hq, d = q.shape
+    pages_per_slot = page_table.shape[1]
+    num_rows, hd = k_pages.shape[1:]
+    h = hd // d
+    g = hq // h
+    ps, bp, hp = page_size, block_pages, seg.shape[1]
+    grouped = paged_attention_fold(g, d) == "grouped"
+    # row j of a slot: the j-th query head of every KV head
+    qk = q.reshape(b, h, g, d).transpose(0, 2, 1, 3).reshape(b, g, hd) \
+        if g > 1 else q.reshape(b, 1, hd)
+    gp = g
+    if grouped:
+        # padded to whole sublanes with zero queries (uniform weights,
+        # sliced away), in the pool's type: the fold's MXU operand
         gp = -(-g // 8) * 8
-        qk = q.reshape(b, h, g, d).transpose(0, 2, 1, 3).reshape(b, g, hd)
-        qk = jnp.pad(qk, ((0, 0), (0, gp - g), (0, 0)))
-        qk = qk.astype(k_pages.dtype)  # the grouped fold's MXU operand
+        qk = jnp.pad(qk, ((0, 0), (0, gp - g), (0, 0))).astype(k_pages.dtype)
     kernel = functools.partial(
         _paged_attn_kernel, block_pages=bp, page_size=ps,
         pages_per_slot=pages_per_slot, num_pages=num_rows // ps,
-        sm_scale=float(sm_scale), mask_value=neg_inf_value(jnp.float32),
-        d_head=d, grouped=g > 1)
+        sm_scale=sm_scale, mask_value=neg_inf_value(jnp.float32),
+        d_head=d, grouped=grouped)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
         grid=(b,),
@@ -469,12 +519,13 @@ def paged_decode_attention(q, k_pages, v_pages, page_table, ctx_len, *,
         interpret=interpret,
         name="paged_attention",
     )(page_table.reshape(-1).astype(jnp.int32),
-      ctx_len.astype(jnp.int32), jnp.asarray(layer, jnp.int32).reshape(1),
-      qk, jnp.asarray(seg), jnp.asarray(seg.T), k_pages, v_pages)
+      ctx_len.astype(jnp.int32), layer.reshape(1),
+      qk, seg, segt, k_pages, v_pages)
+    if gp != g:
+        out = out[:, :g]
     if g == 1:
         return out.reshape(b, hq, d)
-    return out[:, :g].reshape(b, g, h, d).transpose(0, 2, 1, 3).reshape(
-        b, hq, d)
+    return out.reshape(b, g, h, d).transpose(0, 2, 1, 3).reshape(b, hq, d)
 
 
 def gather_reference(q, k_pages, v_pages, page_table, ctx_len, page_size,
@@ -608,11 +659,12 @@ def _selftest() -> int:
     np.testing.assert_array_equal(got_r[~dead], clean[~dead])
 
     # a bf16 pool, 1 and 7 query heads a KV head, against the gather path
-    # in float32 over the SAME bf16 values: the G = 1 fold widens the pool
-    # (1e-6), the grouped fold gives the MXU p's two bf16 halves (2**-16 of
-    # the largest V). Waves of one page: an odd count (ctx 33: five), an
-    # even one (64: eight) and a single one, the two buffers alternating
-    # and each slot starting on what the slot before left in them
+    # in float32 over the SAME bf16 values: the per-lane fold (one query
+    # head of 16 lanes) widens the pool (1e-6), the grouped fold gives the
+    # MXU p's two bf16 halves (2**-16 of the largest V). Waves of one
+    # page: an odd count (ctx 33: five), an even one (64: eight) and a
+    # single one, the two buffers alternating and each slot starting on
+    # what the slot before left in them
     def stored(x):
         x = jnp.asarray(x).astype(jnp.bfloat16)
         return x, x.astype(jnp.float32)

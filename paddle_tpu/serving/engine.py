@@ -837,14 +837,17 @@ class ServingEngine:
 
     def decode_kernel_info(self) -> tuple:
         """``(kernel, source)`` of the decode-attention inner loop as THIS
-        engine resolves it: ``("paged", <tuned|shipped|default>)`` when the
-        ragged paged-attention Pallas kernel is armed
-        (``FLAGS_paged_attention_kernel``, paged layout, geometry inside
-        the kernel's static gate) — source is the tune-table layer
-        answering its ``block_pages`` lookup, i.e. the provenance the
-        compiled trace saw — else ``("gather", why)``: "n/a" with the flag
-        off or a dense layout, ``"gate: <rule>"`` for a cache geometry or
-        dtype the kernel's gate excludes."""
+        engine resolves it: ``("paged", "<tuned|shipped|default>; fold:
+        <grouped|per_lane>")`` when the ragged paged-attention Pallas
+        kernel is armed (``FLAGS_paged_attention_kernel``, paged layout,
+        geometry inside the kernel's static gate) — source is the
+        tune-table layer answering its ``block_pages`` lookup, i.e. the
+        provenance the compiled trace saw, and the fold the one the cache
+        groups' geometry takes (``PagedKVCache.kernel_folds`` says it a
+        group) — else
+        ``("gather", why)``: "n/a" with the flag off or a dense layout,
+        ``"gate: <rule>"`` for a cache geometry or dtype the kernel's gate
+        excludes."""
         if not self.cfg.paged:
             return "gather", "n/a"
         mode, why_not = self.cache_ops.kernel_mode()
@@ -860,7 +863,8 @@ class ServingEngine:
                 tune.bucket_ctx(self.cfg.max_seq, self.cache_ops.row_width))
         except Exception:
             src = "default"
-        return "paged", src
+        folds = sorted(set(self.cache_ops.kernel_folds().values()))
+        return "paged", "%s; fold: %s" % (src, "/".join(folds))
 
     def speculation_info(self) -> tuple:
         """``(k, drafter_kind, source)`` of the speculative fast path as

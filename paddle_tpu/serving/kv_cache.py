@@ -503,6 +503,20 @@ class PagedKVCache(_KVCacheBase):
                 return why_not
         return None
 
+    def kernel_folds(self) -> Dict[str, str]:
+        """``{group: "grouped" | "per_lane"}``: the fold the Pallas kernel
+        takes for each paged group's geometry (its query heads a KV head
+        and the head's width: paged_attention.paged_attention_fold, what
+        the call itself asks); empty where :meth:`kernel_mode` keeps the
+        gather path."""
+        from ..ops.pallas_kernels.paged_attention import paged_attention_fold
+
+        if self.kernel_mode()[0] is None:
+            return {}
+        return {g.name: paged_attention_fold(self.q_per_kv[g.name],
+                                             self.d_head)
+                for g in self.groups if g.kind != STATE}
+
     def rows_read(self, ctx_len, active) -> Dict[str, jnp.ndarray]:
         """``{"attn_rows_read.<group>": rows}``: the context rows ONE layer
         of each group reads in a decode step at ``ctx_len`` [B], summed
@@ -531,14 +545,17 @@ class PagedKVCache(_KVCacheBase):
         ever materializes, and a slot of length 0 costs a grid step and
         nothing else; otherwise the XLA gather +
         ops.attention_ops.decode_attention path runs. Both mask rows >= the
-        length with the SAME neg_inf constant, so at one query head a KV
-        head over a float32 pool the paths agree to float round-off
-        (tier-1 parity tests pin it); with grouped queries or a bfloat16
-        pool they differ by the rounding of what each keeps between its
-        steps, inside the margins the models' tests state. A slot of
-        length 0 comes back finite from both and is nobody's to read:
-        exactly 0.0 from the kernel, the mean of its table's V rows from
-        the gather."""
+        length with the SAME neg_inf constant, so over a float32 pool the
+        paths agree to float round-off (tier-1 parity tests pin it, at one
+        query head a KV head for heads that are not whole lane tiles and
+        for heads that are); over a bfloat16 pool they differ by the
+        rounding of what each keeps between its steps (the kernel's
+        ``grouped`` fold, :meth:`kernel_folds`, meets V with 16 bits of
+        each probability; its fold for heads that are not whole lane tiles
+        widens the pool to float32), inside the margins the models' tests
+        state. A slot of length 0 comes back finite from both and is
+        nobody's to read: exactly 0.0 from the kernel, the mean of its
+        table's V rows from the gather."""
         from ..ops import attention_ops
 
         gi, li = self._where[layer]
@@ -1207,6 +1224,9 @@ class LatentPagedCache(PagedKVCache):
 
         return mla_decode_gate(self.dtype, self.row_width, self.rank,
                                self.page_size, interpret=interpret)
+
+    def kernel_folds(self) -> Dict[str, str]:
+        return {}   # the latent kernels have one fold
 
     def decode_attention(self, state: Cache, layer: int, q, ctx_len,
                          active, sm_scale: float = 1.0) -> jnp.ndarray:

@@ -358,6 +358,10 @@ class PagedAttentionTunable(Tunable):
                 # gpt2-small-serve, as a loaded server would run it
                 dict(cell, slots=32, max_ctx=1024, n_head=12, d_head=64,
                      q_per_kv=1, live=(100, 900, 24)),
+                # ouro-2.6b-serve: one query head a KV head of a whole lane
+                # tile, 2,048-lane rows, 7 of 12 slots live
+                dict(cell, slots=12, max_ctx=1024, n_head=16, q_per_kv=1,
+                     live=(190, 662, 7)),
             ]
         # interpret-mode mechanism shape: seconds on CPU
         return [dict(slots=4, max_ctx=64, page_size=8, n_head=2, d_head=16)]

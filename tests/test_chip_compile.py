@@ -330,20 +330,23 @@ CELL_KERNELS = {
     "laguna_global": (16, 48, 8, 128, 2, 9216, 1024, "bf16[16,8,1024]"),
     "laguna_window": (16, 72, 8, 128, 3, 512, 32, "bf16[16,16,1024]"),
     # 48 layers x 4 steps in one pool, the layer a TRACED scalar (the step
-    # of a device loop): no sweep has visited this bucket
-    "ouro_loop": (12, 16, 16, 128, 192, 288, 64, "bf16[12,1,2048]"),
+    # of a device loop); one query head a KV head of a whole lane tile: the
+    # grouped fold, the query head padded to 8 rows
+    "ouro_loop": (12, 16, 16, 128, 192, 288, 64, "bf16[12,8,2048]"),
 }
 
 
 @pytest.mark.parametrize("cell", sorted(CELL_KERNELS))
 def test_paged_kernel_at_the_cells_shapes_and_shipped_waves(chip, cell):
     """bf16 pools, the ``block_pages`` the shipped v5e table holds for the
-    call's bucket (a measured entry, not the wildcard, but for the looped
-    model's 2,048-lane rows, which take the wildcard clamped to a wave's
-    budget, under a layer that is traced): ONE custom call
-    named ``paged_attention`` with the result the trace readers tell the
-    calls by, two K and two V wave buffers in the pool's type within the
-    wave budget, and the pool handed over untouched."""
+    call's bucket (a measured entry, not the wildcard; the looped model's
+    under a layer that is traced): ONE custom call named
+    ``paged_attention`` with the result the trace readers tell the calls
+    by, two K and two V wave buffers in the pool's type within the wave
+    budget, and the pool handed over untouched. At the looped model's
+    shape, whose fold the head's width chose, nothing is copied or
+    transposed around the call that the head-membership fold did not
+    copy: the traced layer's scalar and the result's split into heads."""
     from paddle_tpu import tune
 
     b, hq, h, d, n_layer, pages, pps, result = CELL_KERNELS[cell]
@@ -353,13 +356,9 @@ def test_paged_kernel_at_the_cells_shapes_and_shipped_waves(chip, cell):
     shipped = tune.table.read_entries(tune.table.shipped_path())
     measured = tune.table.entry_key("paged_attention", bucket,
                                     "tpu-v5e") in shipped
-    assert src == "shipped" and measured == (cell != "ouro_loop"), (bucket,
-                                                                     src)
+    assert src == "shipped" and measured, (bucket, src)
     bp = pa._block_pages(cfg["block_pages"], ps, pps, pps * ps, hd, 2)
-    if measured:
-        assert bp == cfg["block_pages"], "the shipped wave is clamped"
-    else:       # the wildcard's 16 pages, clamped to what a wave may hold
-        assert bp == pa._wave_fits(ps, hd, 2) == 8
+    assert bp == cfg["block_pages"], "the shipped wave is clamped"
     kw = dict(page_size=ps, sm_scale=d ** -0.5, block_pages=bp)
     pool = ((n_layer, pages * ps, hd), jnp.bfloat16)
     shapes = (((b, hq, d), jnp.bfloat16), pool, pool, ((b, pps), jnp.int32),
@@ -378,6 +377,12 @@ def test_paged_kernel_at_the_cells_shapes_and_shipped_waves(chip, cell):
     assert result in kernel.split(" custom-call(")[0]
     assert [op for _, rtype, op, _ in _instructions(text)
             if _has_dim(rtype, pages * ps) and op != "parameter"] == []
+    if cell == "ouro_loop":
+        assert pa.paged_attention_fold(hq // h, d) == "grouped"
+        moved = sorted(rtype.split("{")[0]
+                       for _, rtype, op, _ in _instructions(text)
+                       if op in ("copy", "transpose"))
+        assert moved == ["bf16[2,8,16,128]", "s32[]"], moved
     # what the kernel keeps in fast memory, from its own scratch operands
     jaxpr = jax.make_jaxpr(fn)(*[jax.ShapeDtypeStruct(*x) for x in shapes])
     call, = [e for e in jaxpr.eqns if e.primitive.name == "pallas_call"]

@@ -5,9 +5,10 @@ Kernel parity runs in Pallas interpret mode on CPU against the XLA
 page-gather + ``decode_attention`` reference — same tolerance discipline
 as the sparse_adam kernel tests (rtol/atol 1e-6 on live rows, BIT-exact
 indifference to garbage beyond ``ctx_len``). A bf16 pool's grouped fold
-hands the MXU its probabilities as bf16 high and low halves: against a
-float32 reference over the SAME bf16 values it is held to that rounding's
-bound (``P_ROUNDOFF``).
+(several query heads a KV head, or heads of whole lane tiles: ``d_head %
+128 == 0``) hands the MXU its probabilities as bf16 high and low halves:
+against a float32 reference over the SAME bf16 values it is held to that
+rounding's bound (``P_ROUNDOFF``).
 Engine-level tests arm
 ``FLAGS_paged_attention_kernel=interpret`` and assert the full serving
 stack emits the same token streams either way, that ``temperature=0`` is
@@ -65,41 +66,49 @@ def as_pool(x, dtype):
 # its bf16 high and low halves: 16 bits, so ``p`` is off by at most 2**-17
 # of itself and the output, a weighted mean of V rows under weights that sum
 # to l within the same bound, by at most 2**-16 * max|v| (float32 pools and
-# the G = 1 fold, which round nothing, keep 1e-6).
+# the fold over the head-membership matrices, which round nothing, keep
+# 1e-6).
 P_ROUNDOFF = 2.0 ** -16
 
 
-def tolerance(dtype, g, v):
-    if jnp.dtype(dtype) == jnp.float32 or g == 1:
+def tolerance(dtype, g, v, d=16):
+    """By the geometry, as the kernel chooses its fold: one query head a KV
+    head of ``d`` lanes that are NOT whole lane tiles goes through the
+    head-membership matrices in float32 whatever the pool's type."""
+    per_lane = g == 1 and d % 128 != 0
+    if jnp.dtype(dtype) == jnp.float32 or per_lane:
         return dict(rtol=1e-6, atol=1e-6)
     return dict(rtol=1e-6, atol=P_ROUNDOFF * float(jnp.max(jnp.abs(v))))
 
 
 # -- kernel parity (interpret mode) ------------------------------------------
 
-def test_kernel_matches_gather_at_ragged_lengths(rng):
-    slots, h, d, ps, pps = 5, 2, 16, 8, 8
+@pytest.mark.parametrize("d", [16, 128], ids=["d16", "lane_tile_heads"])
+def test_kernel_matches_gather_at_ragged_lengths(rng, d):
+    slots, h, ps, pps = 5, 2, 8, 8
     q, k, v, pt = make_pool(rng, slots, pps, 24, ps, h, d)
     ctx = jnp.asarray([1, 7, 8, 33, 64], jnp.int32)  # ragged, page-straddling
-    want = pa.gather_reference(q, k, v, pt, ctx, ps, sm_scale=0.25)
+    want = pa.gather_reference(q, k, v, pt, ctx, ps, sm_scale=d ** -0.5)
     for bp in (1, 3, 4, None):  # incl. non-divisor + tuned-table default
         got = pa.paged_decode_attention(q, k, v, pt, ctx, page_size=ps,
-                                        sm_scale=0.25, block_pages=bp,
+                                        sm_scale=d ** -0.5, block_pages=bp,
                                         interpret=True)
         np.testing.assert_allclose(got, want, rtol=1e-6, atol=1e-6,
                                    err_msg="block_pages=%r" % (bp,))
 
 
-@pytest.mark.parametrize("g", [1, 6], ids=["g1", "g6"])
+@pytest.mark.parametrize("g,d", [(1, 8), (6, 8), (1, 128)],
+                         ids=["g1", "g6", "g1_lane_tile_heads"])
 @pytest.mark.parametrize("dtype,junk", [("float32", (1e4, -1e4)),
                                         ("bfloat16", (np.inf, np.nan))],
                          ids=["float32_huge", "bfloat16_inf_nan"])
-def test_garbage_pages_move_no_output_bit(rng, dtype, junk, g):
+def test_garbage_pages_move_no_output_bit(rng, dtype, junk, g, d):
     """Pages beyond ctx_len belong to OTHER requests (or are stale) — the
     kernel must ignore them EXACTLY, not approximately: trashing every
     invalid row (large finite values; Inf and NaN in a bf16 pool, for both
-    folds) moves no output bit."""
-    slots, h, d, ps, pps = 4, 2, 8, 8, 4
+    folds, the grouped one at one query head of a whole lane tile too)
+    moves no output bit."""
+    slots, h, ps, pps = 4, 2, 8, 4
     _, k, v, pt = make_pool(rng, slots, pps, 12, ps, h, d)
     q = jnp.asarray(rng.randn(slots, g * h, d).astype(np.float32))
     ctx = jnp.asarray([3, 8, 17, 29], jnp.int32)
@@ -156,14 +165,16 @@ def test_layer_of_a_pool_is_bit_equal_to_the_layer_alone(rng, layer):
 
 
 @pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
-@pytest.mark.parametrize("g", [1, 7], ids=["one_query_head_a_kv_head",
-                                           "seven_padded_to_eight"])
-def test_rowless_slots_cost_no_read_and_return_zero(rng, g, dtype):
+@pytest.mark.parametrize("g,d", [(1, 16), (7, 16), (1, 128)],
+                         ids=["one_query_head_a_kv_head",
+                              "seven_padded_to_eight",
+                              "one_of_a_lane_tile_padded_to_eight"])
+def test_rowless_slots_cost_no_read_and_return_zero(rng, g, d, dtype):
     """``ctx_len`` 0 = the slot holds nothing (the cache hands the kernel
     LIVE lengths). Such slots sit among live ones with their page-table
     rows on pages of Inf and NaN: they come back exactly 0.0, and the live
     slots are bit-equal to the same call without them."""
-    slots, h, d, ps, pps = 6, 2, 16, 8, 4
+    slots, h, ps, pps = 6, 2, 8, 4
     live_pages = 16
     _, k, v, pt = make_pool(rng, slots, pps, live_pages, ps, h, d)
     (k, k32), (v, v32) = as_pool(k, dtype), as_pool(v, dtype)
@@ -178,7 +189,7 @@ def test_rowless_slots_cost_no_read_and_return_zero(rng, g, dtype):
     v = jnp.concatenate([v, jnp.asarray(-poison).astype(dtype)])
     pt = np.asarray(pt).copy()
     pt[dead] = live_pages + np.arange(4)
-    kw = dict(page_size=ps, sm_scale=0.25, block_pages=2, interpret=True)
+    kw = dict(page_size=ps, sm_scale=d ** -0.5, block_pages=2, interpret=True)
     got = np.asarray(pa.paged_decode_attention(
         q, k, v, jnp.asarray(pt), jnp.asarray(ctx), **kw))
     np.testing.assert_array_equal(got[dead], np.zeros_like(got[dead]))
@@ -187,32 +198,35 @@ def test_rowless_slots_cost_no_read_and_return_zero(rng, g, dtype):
         **kw))
     np.testing.assert_array_equal(got[~dead], alone)
     want = pa.gather_reference(q[~dead], k32, v32, jnp.asarray(pt[~dead]),
-                               jnp.asarray(ctx[~dead]), ps, sm_scale=0.25)
-    np.testing.assert_allclose(alone, want, **tolerance(dtype, g, v32))
+                               jnp.asarray(ctx[~dead]), ps, sm_scale=d ** -0.5)
+    np.testing.assert_allclose(alone, want, **tolerance(dtype, g, v32, d))
 
 
-@pytest.mark.parametrize("g", [1, 6, 7, 9])
-def test_bf16_pool_against_float32_reference_over_the_same_values(rng, g):
+@pytest.mark.parametrize("g,d", [(1, 16), (6, 16), (7, 16), (9, 16),
+                                 (1, 128)],
+                         ids=["1", "6", "7", "9", "1_lane_tile_heads"])
+def test_bf16_pool_against_float32_reference_over_the_same_values(rng, g, d):
     """A bf16 pool under 1, 6, 7 and 9 query heads a KV head (GPT-2's,
-    Laguna's full layers', SmallThinker's, Laguna's rings'): the grouped
-    fold multiplies in bf16 with a float32 accumulator and keeps its
-    softmax state in float32, so against a float32 reference over the same
-    bf16 values it is off by the rounding of ``p`` to 16 bits alone; G = 1
-    widens the pool and keeps 1e-6."""
-    slots, h, d, ps, pps = 5, 2, 16, 8, 8
+    Laguna's full layers', SmallThinker's, Laguna's rings') and under one
+    query head of a whole lane tile (Ouro's): the grouped fold multiplies
+    in bf16 with a float32 accumulator and keeps its softmax state in
+    float32, so against a float32 reference over the same bf16 values it is
+    off by the rounding of ``p`` to 16 bits alone; G = 1 over heads that
+    are not whole lane tiles widens the pool and keeps 1e-6."""
+    slots, h, ps, pps = 5, 2, 8, 8
     q, k, v, pt = make_pool(rng, slots, pps, 40, ps, h, d)
     (k, k32), (v, v32) = as_pool(k, jnp.bfloat16), as_pool(v, jnp.bfloat16)
     q = as_pool(rng.randn(slots, g * h, d).astype(np.float32),
                 jnp.bfloat16)[1]  # float32-typed: the result is not rounded
     ctx = jnp.asarray([1, 7, 16, 33, 64], jnp.int32)
-    want = pa.gather_reference(q, k32, v32, pt, ctx, ps, sm_scale=0.25)
+    want = pa.gather_reference(q, k32, v32, pt, ctx, ps, sm_scale=d ** -0.5)
     for bp in (1, 3, None):
         got = pa.paged_decode_attention(q, k, v, pt, ctx, page_size=ps,
-                                        sm_scale=0.25, block_pages=bp,
+                                        sm_scale=d ** -0.5, block_pages=bp,
                                         interpret=True)
         assert got.dtype == jnp.float32
         np.testing.assert_allclose(got, want, err_msg="block_pages=%r" % bp,
-                                   **tolerance(jnp.bfloat16, g, v32))
+                                   **tolerance(jnp.bfloat16, g, v32, d))
 
 
 # ps = 8 and two pages a wave: a wave is 16 rows, a slot has 8 pages
@@ -229,26 +243,30 @@ WAVE_EDGES = {
 }
 
 
-@pytest.mark.parametrize("dtype,g", [("float32", 1), ("float32", 7),
-                                     ("bfloat16", 1), ("bfloat16", 6)])
+@pytest.mark.parametrize("dtype,g,d", [
+    ("float32", 1, 16), ("float32", 7, 16), ("bfloat16", 1, 16),
+    ("bfloat16", 6, 16), ("float32", 1, 128), ("bfloat16", 1, 128)],
+    ids=["float32-1", "float32-7", "bfloat16-1", "bfloat16-6",
+         "float32-1-lane_tile_heads", "bfloat16-1-lane_tile_heads"])
 @pytest.mark.parametrize("edge", sorted(WAVE_EDGES))
-def test_double_buffered_waves_at_their_edges(rng, edge, dtype, g):
+def test_double_buffered_waves_at_their_edges(rng, edge, dtype, g, d):
     """Wave ``w + 1`` is copied into the other buffer while wave ``w`` is
     folded, and both buffers keep what the slot before left in them: the
     lengths at which a start, a wait or a mask could slip by one."""
     ctx, bp = WAVE_EDGES[edge]
-    slots, h, d, ps, pps = len(ctx), 2, 16, 8, 8
+    slots, h, ps, pps = len(ctx), 2, 8, 8
     _, k, v, pt = make_pool(rng, slots, pps, slots * pps, ps, h, d)
     (k, k32), (v, v32) = as_pool(k, dtype), as_pool(v, dtype)
     q = as_pool(rng.randn(slots, g * h, d).astype(np.float32), dtype)[1]
     ctx = jnp.asarray(ctx, jnp.int32)
     got = np.asarray(pa.paged_decode_attention(
-        q, k, v, pt, ctx, page_size=ps, sm_scale=0.25, block_pages=bp,
+        q, k, v, pt, ctx, page_size=ps, sm_scale=d ** -0.5, block_pages=bp,
         interpret=True))
     live = np.asarray(ctx) > 0
     want = pa.gather_reference(q[live], k32, v32, pt[live], ctx[live], ps,
-                               sm_scale=0.25)
-    np.testing.assert_allclose(got[live], want, **tolerance(dtype, g, v32))
+                               sm_scale=d ** -0.5)
+    np.testing.assert_allclose(got[live], want,
+                               **tolerance(dtype, g, v32, d))
     np.testing.assert_array_equal(got[~live], 0.0)
 
 
@@ -264,23 +282,24 @@ def test_wave_buffers_fit_their_budget_at_every_served_row_width():
     assert pa._block_pages(64, 16, 4, 64, 128, 2) == 4  # the slot's pages
 
 
+@pytest.mark.parametrize("d", [64, 128], ids=["d64", "lane_tile_heads"])
 @pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
-def test_kernel_and_gather_agree_under_a_traced_cache_step(rng, dtype):
+def test_kernel_and_gather_agree_under_a_traced_cache_step(rng, dtype, d):
     """A looped model's cache: three cache layers a layer, the step a
     TRACED scalar inside a device loop whose carry is the pool. Every
     (layer, step) call of ``PagedKVCache.decode_attention`` by the kernel
-    (interpreted) equals the gather path's, each over its own pool layer:
-    the others hold other rows."""
+    (interpreted, in the fold the head's width chooses) equals the gather
+    path's, each over its own pool layer: the others hold other rows."""
     import jax
 
     from paddle_tpu.serving.kv_cache import PagedKVCache
 
-    ops = PagedKVCache(2, 2, 64, 3, 32, 8, 12, dtype=dtype, cache_steps=3)
+    ops = PagedKVCache(2, 2, d, 3, 32, 8, 12, dtype=dtype, cache_steps=3)
     state = ops.init_state()
     for key in ("k", "v"):
         state[key] = jnp.asarray(rng.randn(*state[key].shape)).astype(dtype)
     state["pt"] = jnp.asarray(rng.permutation(12).reshape(3, 4), jnp.int32)
-    q = jnp.asarray(rng.randn(3, 2, 64)).astype(dtype)
+    q = jnp.asarray(rng.randn(3, 2, d)).astype(dtype)
     ctx = jnp.asarray([5, 32, 17], jnp.int32)
     active = jnp.asarray([True, True, False])
 
@@ -288,16 +307,18 @@ def test_kernel_and_gather_agree_under_a_traced_cache_step(rng, dtype):
         def body(t, out):
             return out.at[t].set(jnp.stack([
                 ops.decode_attention(state, layer, q, ctx, active,
-                                     sm_scale=0.125, step=t)
+                                     sm_scale=d ** -0.5, step=t)
                 for layer in range(2)]))
 
         return jax.lax.fori_loop(0, 3, body,
-                                 jnp.zeros((3, 2, 3, 2, 64), dtype))
+                                 jnp.zeros((3, 2, 3, 2, d), dtype))
 
     got = {}
     for mode in ("off", "interpret"):
         set_flag("paged_attention_kernel", mode)
         assert (ops.kernel_mode()[0] is None) == (mode == "off")
+        assert ops.kernel_folds() == ({} if mode == "off" else {
+            "global": "grouped" if d == 128 else "per_lane"})
         got[mode] = np.asarray(jax.jit(looped)(state), np.float32)
     live = got["off"][:, :, :2]
     tol = dict(rtol=1e-6, atol=1e-6) if dtype == "float32" \
@@ -310,9 +331,41 @@ def test_kernel_and_gather_agree_under_a_traced_cache_step(rng, dtype):
     # and it is the one the int names: layer-major, ``layer * 3 + step``
     want = pa.gather_reference(q, state["k"][1 * 3 + 2], state["v"][1 * 3 + 2],
                                state["pt"], jnp.where(active, ctx, 0), 8,
-                               sm_scale=0.125)
+                               sm_scale=d ** -0.5)
     np.testing.assert_allclose(live[2, 1], np.asarray(want, np.float32)[:2],
                                **tol)
+
+
+@pytest.mark.parametrize("h,g,d,fold,rows", [
+    (4, 1, 64, "per_lane", 1),     # GPT-2's: half a lane tile a head
+    (2, 1, 128, "grouped", 8),     # Ouro's: one query head, a whole tile
+    (1, 1, 256, "grouped", 8),
+    (2, 6, 128, "grouped", 8),     # Laguna's full layers
+    (2, 9, 128, "grouped", 16),
+    (2, 1, 192, "per_lane", 1),    # a tile and a half cannot be sliced out
+], ids=["1x64", "1x128", "1x256", "6x128", "9x128", "1x192"])
+def test_the_fold_is_chosen_by_the_heads_width_not_by_g_alone(rng, h, g, d,
+                                                              fold, rows):
+    """One query head a KV head takes the grouped fold exactly where a head
+    is whole lane tiles: the call's ``q`` and result carry the query heads
+    of a KV head padded to whole sublanes there (the trace readers' label)
+    and one row for every other width, and what the cache reports is what
+    the call was traced with."""
+    import jax
+
+    from paddle_tpu.serving.kv_cache import PagedKVCache
+
+    assert pa.paged_attention_fold(g, d) == fold
+    _, k, v, pt = make_pool(rng, 2, 2, 4, 8, h, d)
+    q = jnp.asarray(rng.randn(2, g * h, d).astype(np.float32))
+    ctx = jnp.asarray([3, 9], jnp.int32)
+    jaxpr = jax.make_jaxpr(lambda *a: pa.paged_decode_attention(
+        *a, page_size=8, interpret=True))(q, k, v, pt, ctx)
+    call, = [e for e in jaxpr.eqns if e.primitive.name == "pallas_call"]
+    assert call.outvars[0].aval.shape == (2, rows, h * d)
+    set_flag("paged_attention_kernel", "interpret")
+    ops = PagedKVCache(1, h, d, 2, 16, 8, 4, q_per_kv=g)
+    assert ops.kernel_folds() == {"global": fold}
 
 
 def test_pool_shape_errors_are_typed(rng):
@@ -357,7 +410,11 @@ def test_engine_kernel_vs_gather_token_parity(rng):
     want, base_stats = _serve(stream, "off")
     assert got == want, "kernel decode diverged from the gather path"
     assert stats["decode_kernel"] == "paged"
-    assert stats["decode_kernel_source"] in ("tuned", "shipped", "default")
+    # which table answered the wave's width, and the fold the geometry
+    # takes: two heads of 16 lanes are no lane tile
+    source, fold = stats["decode_kernel_source"].split("; ")
+    assert source in ("tuned", "shipped", "default")
+    assert fold == "fold: per_lane"
     assert base_stats["decode_kernel"] == "gather"
     assert base_stats["decode_kernel_source"] == "n/a"
 

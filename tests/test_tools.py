@@ -65,6 +65,39 @@ def test_documents_name_only_what_exists():
     assert not unread, "README.md names variables nothing reads: %r" % unread
 
 
+def test_compare_lowering_reads_a_shared_trace_as_the_program_it_is():
+    """``canonical_text``: three calls that share ONE traced sub-program
+    (``jit``'s cache) read as the same program as three that traced their
+    own, which JAX's own printer tells apart (it binds a shared
+    sub-program to a name once); a different operation still reads
+    different."""
+    import jax
+    import jax.numpy as jnp
+
+    from tools.compare_lowering import canonical_text
+
+    def body(x, scale):
+        return jax.lax.fori_loop(0, 3, lambda i, y: y * scale + i, x)
+
+    shared = jax.jit(body, static_argnums=1, inline=True)
+
+    def own(x):
+        return body(body(body(x, 2.0), 2.0), 2.0)
+
+    def cached(x):
+        return shared(shared(shared(x, 2.0), 2.0), 2.0)
+
+    def other(x):
+        return shared(shared(shared(x, 2.0), 2.0), 3.0)
+
+    x = jnp.ones((4,), jnp.float32)
+    texts = {f.__name__: jax.make_jaxpr(f)(x) for f in (own, cached, other)}
+    assert str(texts["own"]) != str(texts["cached"])
+    canon = {k: canonical_text(v.jaxpr) for k, v in texts.items()}
+    assert canon["own"] == canon["cached"] != canon["other"]
+    assert " at 0x" not in canon["own"]
+
+
 def test_compare_lowering_tells_an_executable_that_changed(monkeypatch,
                                                            tmp_path, capsys):
     """``tools/compare_lowering.py``: a real child traces the smallest

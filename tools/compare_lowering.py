@@ -10,7 +10,9 @@ chip would run them (the kernels armed: ``attention_ops._on_tpu``,
 ``moe_ops._on_tpu`` and ``paged_kernel_mode`` say "the chip"), and prints
 one digest an executable of the traced program's text: the jaxpr, which
 holds every operation, shape, constant and each Pallas kernel's own body,
-and no source location. Equal digests: a change to shared code (a block
+and no source location, written out by :func:`canonical_text` (a
+sub-program in full at each use: whether calls share one trace is not the
+program). Equal digests: a change to shared code (a block
 moved, a function generalised behind an argument nobody else passes) left
 that configuration's arithmetic as it was. Unequal: the two texts are
 written under ``--out`` for ``diff``.
@@ -65,7 +67,7 @@ def trace_tree(names: Optional[List[str]], keep_text: bool) -> Dict[str, Dict]:
     traced = {}
 
     def record(fn, args, donate_argnums=(), label=None):
-        text = str(jax.make_jaxpr(fn)(*args))
+        text = canonical_text(jax.make_jaxpr(fn)(*args).jaxpr)
         traced[fn.__name__ + "." + hashlib.sha256(
             repr(jax.tree_util.tree_map(
                 lambda a: (tuple(a.shape), str(a.dtype)), args)
@@ -97,6 +99,52 @@ def trace_tree(names: Optional[List[str]], keep_text: bool) -> Dict[str, Dict]:
             else hashlib.sha256(text.encode()).hexdigest()
             for name, text in sorted(traced.items())}
     return out
+
+
+def canonical_text(jaxpr) -> str:
+    """A traced program written out with every sub-program in full where
+    it is used and each one's variables numbered from its own start, so
+    that two programs holding the same operations read the same whether or
+    not their calls share one traced sub-program: ``jit``'s cache hands
+    every call at one geometry the SAME kernel body, which JAX's own
+    printer then binds to a name once and numbers by object."""
+    import re
+
+    from jax._src import core
+
+    lines = []
+
+    def walk(jaxpr, pad):
+        names = {}
+
+        def ref(v):
+            kind = v.aval.str_short()
+            if isinstance(v, core.Literal):
+                return "%r:%s" % (v.val, kind)
+            return "%s:%s" % (names.setdefault(v, "v%d" % len(names)), kind)
+
+        lines.append("%s{ lambda %s ; %s . let" % (
+            pad, " ".join(map(ref, jaxpr.constvars)),
+            " ".join(map(ref, jaxpr.invars))))
+        for eqn in jaxpr.eqns:
+            plain, subs = [], []
+            for key, val in sorted(eqn.params.items()):
+                held = list(core.jaxprs_in_params({key: val}))
+                if held:
+                    subs += [(key, sub) for sub in held]
+                else:
+                    plain.append("%s=%s" % (key, val))
+            ins = " ".join(map(ref, eqn.invars))
+            lines.append("%s  %s = %s[%s] %s" % (
+                pad, " ".join(map(ref, eqn.outvars)), eqn.primitive.name,
+                " ".join(plain), ins))
+            for key, sub in subs:
+                lines.append("%s    %s:" % (pad, key))
+                walk(sub, pad + "      ")
+        lines.append("%s  in %s }" % (pad, " ".join(map(ref, jaxpr.outvars))))
+
+    walk(jaxpr, "")
+    return re.sub(r" at 0x[0-9a-f]+", "", "\n".join(lines))
 
 
 def _abstract(fn):
