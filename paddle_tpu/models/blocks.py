@@ -4,8 +4,8 @@ through which ``serving.ServingEngine`` drives any of them
 (:class:`ServedLM`, whose docstring is the contract).
 
 ``smallthinker.py``, ``kimi_k2.py``, ``laguna.py``, ``ling3_flash.py``,
-``motif3.py``, ``glm5_flash.py``, ``falcon_h1.py`` and ``ouro.py`` take their
-blocks from here and keep what only they have. A
+``motif3.py``, ``glm5_flash.py``, ``falcon_h1.py``, ``ouro.py`` and
+``evabyte.py`` take their blocks from here and keep what only they have. A
 block two models need is written HERE under a public name; no model module
 imports another's underscore names. Two forms of a block are one function
 only where the merged one needs no argument that says who calls it and the
@@ -448,9 +448,10 @@ def seeded_params(cfg, seed, init_layer: Callable, layer_args: Callable
 class ServedLM:
     """THE SERVING CONTRACT: what ``serving.ServingEngine`` may ask of a
     model and of its config. ``SmallThinkerLM``, ``KimiK2LM``, ``LagunaLM``,
-    ``Ling3FlashLM``, ``Motif3LM``, ``Glm5FlashLM``, ``FalconH1LM`` and
-    ``OuroLM`` are this class over their module's ``init_params``, ``prefill_forward`` and
-    ``decode_forward`` (and, where the head is not the plain one, ``head``);
+    ``Ling3FlashLM``, ``Motif3LM``, ``Glm5FlashLM``, ``FalconH1LM``,
+    ``OuroLM`` and ``EvaByteLM`` are this class over their module's
+    ``init_params``, ``prefill_forward`` and ``decode_forward`` (and, where
+    the head is not the plain one, ``head``);
     ``decoder_lm.DecoderLM`` meets it with methods of its own.
 
     The engine reads ``model.cfg``, ``model.params`` and calls:
@@ -463,7 +464,16 @@ class ServedLM:
       width])`` of a state layer (the state the prompt LEAVES, not rows);
       under ``cache_steps`` T > 1, ``(k, v)`` [T, B, S, Hkv, D]: a leading
       STEP axis, the rows each loop step made, which the engine writes a
-      step at a time (``write_prompt(..., step=t)``);
+      step at a time (``write_prompt(..., step=t)``); of a layer in a
+      COMPACTING group (``serving/kv_cache.py``: a window's rows are
+      replaced by one summary a chunk when the window closes) ``(k_open,
+      v_open, k_sum, v_sum)``: the ``min(S, window)`` rows [B, .., Hkv, D]
+      from ``kv_cache.open_window_start(length, S, window)`` on, which
+      hold the window the prompt leaves open, and a summary a chunk of the
+      bucket [B, S / chunk, Hkv, D], the model's own pooling
+      (the cache's ``write_prompt`` unpacks a layer's ``kept`` by the
+      layer's group: it puts the closed windows' summaries where
+      attention reads them and the open window's where they wait);
     * ``prefill_last`` (optional; the engine asks ``hasattr``): the same
       with ``logits [B, V]`` of each prompt's LAST row only ([B, S, V] at S
       = 8,192 and V = 151,936 would be 5 GB). Absent: the engine calls
@@ -481,13 +491,16 @@ class ServedLM:
       ``moe_max_expert_rows``, ``moe_held_pairs`` [expert layers],
       ``state_slots_stepped``, ``attn_rows_read.<group>``,
       ``attn_rows_context.<group>``, ``index_blocks_scored``,
-      ``ut_expected_exit_step``; a name
+      ``ut_expected_exit_step``, ``eva_chunks_closed``,
+      ``eva_windows_closed``; a name
       without a histogram (a probe) rides to ``engine.last_decode_stats``
-      only;
+      only. A model over a compacting group calls, a layer,
+      ``write_token``, ``open_chunk``, ``write_summary`` and
+      ``decode_attention``, and ``close_windows`` after its last layer;
     * ``verify`` (optional; ``hasattr``): scores a window of drafted tokens
       for speculative decoding. Absent, as on every model of this class,
-      every speculation setting resolves off: a ring, a latent row and a
-      recurrent state cannot be rolled back.
+      every speculation setting resolves off: a ring, a latent row, a
+      recurrent state and a compacted window cannot be rolled back.
 
     Of ``model.cfg`` the engine reads ``n_layer``, ``n_head`` (the QUERY
     heads: one number, or one a layer), ``d_head``, ``max_seq``, ``dtype``,
@@ -495,8 +508,14 @@ class ServedLM:
 
     * ``n_kv_head``: the heads of K and V, the same in every layer, which
       size the cache. Absent: ``n_head`` (queries are not grouped);
-    * ``cache_groups``: a list of ``(name, layers, window)`` or ``(name,
-      layers, window, kind)``; the layers of a group share one ``n_head``
+    * ``cache_groups``: a list of ``(name, layers, window)``, ``(name,
+      layers, window, kind)`` or ``(name, layers, window, kind, chunk)``
+      (a ``chunk`` makes a ``KV`` group COMPACTING: ``window`` is then a
+      tumbling window, replaced by one summary a ``chunk`` positions when
+      it closes; over such a group the engine refuses speculative verify,
+      the prefix cache, the int8 pool and the contiguous layout, and page
+      export and import raise; absent: not compacting); the layers of a
+      group share one ``n_head``
       (a group's decode attention is one kernel shape), ``window`` rows a
       slot are kept as a ring (None: every position, in pages), and the
       first group is the one admission counts pages of. A layer is named

@@ -355,16 +355,17 @@ class ServingConfig:
 
 
 def _layer_groups(mcfg):
-    """A model config's cache groups as ``(name, layers, window, kind)``:
-    ``cache_groups`` (an entry without a kind is K and V rows; with
-    ``latent_row`` and no ``cache_groups``, one latent group of every
-    layer), else one group of every layer that keeps every position (the
-    contract's defaults: ``models.blocks.ServedLM``)."""
+    """A model config's cache groups as ``(name, layers, window, kind,
+    chunk)``: ``cache_groups`` (an entry without a kind is K and V rows,
+    one without a chunk is not compacting; with ``latent_row`` and no
+    ``cache_groups``, one latent group of every layer), else one group of
+    every layer that keeps every position (the contract's defaults:
+    ``models.blocks.ServedLM``)."""
     latent = getattr(mcfg, "latent_row", None)
     groups = getattr(mcfg, "cache_groups", None) or [
         ("latent" if latent else "global", tuple(range(mcfg.n_layer)), None,
          LATENT if latent else KV)]
-    return [tuple(g) + (KV,) * (4 - len(g)) for g in groups]
+    return [tuple(g) + (KV, None)[len(g) - 3:] for g in groups]
 
 
 def _query_groups(mcfg, layer_groups):
@@ -378,7 +379,7 @@ def _query_groups(mcfg, layer_groups):
         heads = (heads,) * mcfg.n_layer
     n_kv = getattr(mcfg, "n_kv_head", heads[0])
     q_per_kv = {}
-    for name, layers, _window, _kind in layer_groups:
+    for name, layers, _window, _kind, _chunk in layer_groups:
         of_group = sorted({int(heads[l]) for l in layers})
         if len(of_group) != 1 or of_group[0] % n_kv:
             raise ValueError(
@@ -403,13 +404,15 @@ class ServingEngine:
     ``STATE``) are ``serving.kv_cache``'s; a model's decode ``stats`` go to
     the ``serving/*`` histograms of their names (``metrics.model_stat``).
 
-    Over a cache of more than one group, and over a latent cache (with or
-    without a state group), the engine refuses, at construction, what
-    cannot work there: speculative verify (a ring cannot be rolled back;
-    a latent row is no K and V; a state has no earlier value to return
-    to), the int8 KV pool, the prefix cache (a state has no snapshot at a
-    page boundary) and the contiguous layout; page export/import raise
-    when called.
+    Over a cache of more than one group, over a latent cache (with or
+    without a state group) and over a COMPACTING group
+    (``serving.kv_cache``), the engine refuses, at construction, what
+    cannot work there: speculative verify (a ring and a compacted window
+    cannot be rolled back; a latent row is no K and V; a state has no
+    earlier value to return to), the int8 KV pool, the prefix cache (a
+    state has no snapshot at a page boundary; a compacted page no longer
+    holds the positions its place says) and the contiguous layout; page
+    export/import raise when called.
     """
 
     def __init__(self, model, config: Optional[ServingConfig] = None,
@@ -424,7 +427,8 @@ class ServingEngine:
         self.params = params if params is not None else model.params
         layer_groups = _layer_groups(mcfg)
         latent = getattr(mcfg, "latent_row", None)
-        if len(layer_groups) > 1 or latent:
+        if len(layer_groups) > 1 or latent \
+                or layer_groups[0][4] is not None:
             self._refuse_over_groups(layer_groups, latent)
         # cache layers a layer of a paged group (a looped model: one a step)
         steps = int(getattr(mcfg, "cache_steps", 1))
@@ -438,14 +442,18 @@ class ServingEngine:
         groups = []
         if self.cfg.paged:
             ps = self.cfg.page_size
-            for gi, (name, layers, window, kind) in enumerate(layer_groups):
-                rows = self.cfg.max_seq if window is None \
+            for gi, (name, layers, window, kind, chunk) in enumerate(
+                    layer_groups):
+                # a compacting group's window is no ring: a slot's rows
+                # follow max_seq through the cache's own map, and the
+                # worst case of every position a row covers it
+                rows = self.cfg.max_seq if window is None or chunk \
                     else min(int(window), self.cfg.max_seq)
                 pages = 0 if kind == STATE else self.cfg.group_pages.get(
                     name, self.cfg.num_pages if gi == 0
                     else self.cfg.slots * (rows // ps))
                 groups.append(CacheGroup(name, tuple(layers), window, pages,
-                                         kind))
+                                         kind, chunk))
             unknown = set(self.cfg.group_pages) - {
                 g.name for g in groups if g.kind != STATE}
             if unknown:
@@ -614,6 +622,12 @@ class ServingEngine:
                    if STATE in kinds else "")
                 if latent else "a cache with %d groups %s"
                 % (len(layer_groups), [g[0] for g in layer_groups]))
+        compacting = [g[0] for g in layer_groups if g[4] is not None]
+        if compacting:
+            over += (" of which %s compact: a closed window's rows are "
+                     "replaced by a summary a chunk, so nothing can be "
+                     "rolled back and a page no longer holds the positions "
+                     "its place says" % compacting)
         for on, what in (
                 (not cfg.paged, "the contiguous layout (paged=False)"),
                 (cfg.kv_dtype == "int8", "the int8 KV pool"),

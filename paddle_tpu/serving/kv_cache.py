@@ -106,6 +106,47 @@ page is ``cache_steps`` times the bytes. With ``cache_steps`` 1 (every other
 model) ``step`` is not passed and the pool's layer is the Python int it
 always was.
 
+COMPACTING groups (``CacheGroup.chunk``): a ``KV`` group with a ``window``
+W AND a ``chunk`` c keeps a position's row only while the position's
+TUMBLING window (positions ``[W w, W w + W)``) is open; when the window
+closes, its W rows are REPLACED, for good, by one pooled (key, value) pair
+a chunk of c positions, ``W / c`` summaries, which the MODEL computes (the
+cache owns where rows live, not what a summary is). A slot's view, in the
+order attention reads it, is::
+
+    [ summaries of windows 0..w-1 : (W/c) w rows ]
+    [ the open window's exact rows : up to W rows ]    <- the length ends here
+    [ the open window's finished summaries : W/c ]     <- unread until the close
+
+so position p lives at view row ``(W/c) (p // W) + p mod W`` and a query at
+p attends over that many rows plus one: neither the identity nor a ring. K
+is stored rotated and the summaries are pooled from rotated keys, so the
+order of a view's rows does not matter to the softmax and
+``decode_attention`` is the paged kernel given a length, as for a ring. The
+open window's summaries WAIT in the ``W / (c page_size)`` pages that follow
+the window's in the slot's page table; closing a window is a ROTATION of
+that stretch of the slot's page-table row (the summary pages come to stand
+where the window began, the window's pages, whose rows are dead from that
+instant, become the next window's and its summaries'): a few hundred int32
+a slot, no row of the pool is copied, and nothing is staged beside the
+pool. The table is the device's from admission on (the host keeps a
+request's pages as a set, to free them), so nothing else sees the
+rotation. A model calls, a decode step: ``write_token`` (the map above),
+``open_chunk`` (the c rows of the chunk p is in, out), ``write_summary``
+(the chunk's pair in; dropped unless ``(p + 1) % c == 0``),
+``decode_attention``, and, after its last layer, ``close_windows`` (the
+rotation where ``(p + 1) % W == 0``; every layer of the group shares the
+table). A prompt hands ``write_prompt`` the OPEN window's rows and a
+summary a chunk (``(k_open, v_open, k_sum, v_sum)``, the layer's ``kept``;
+:func:`open_window_start` says which rows).
+A request of n positions needs ``ceil(((W/c) (n // W) + min(n, W)) /
+page_size) + W / (c page_size)`` pages (:meth:`PagedKVCache.pages_needed`),
+not ``n / page_size``. What cannot work over such a group is refused as
+over a ring, and for one more reason: a compacted window cannot be rolled
+back, and a page no longer holds the positions its place says (speculative
+verify, the prefix cache, the int8 pool, the contiguous layout, page export
+and import).
+
 Both write paths scatter with ``mode="drop"`` on out-of-bounds destination
 rows, so inactive slots / padding positions are dropped INSIDE the compiled
 step — no host-side branching, and unwritten rows stay zero in both
@@ -118,11 +159,13 @@ import functools
 from typing import (Dict, List, Mapping, NamedTuple, Optional, Sequence,
                     Tuple, Union)
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
 __all__ = ["CacheGroup", "PagedKVCache", "Int8PagedKVCache",
-           "LatentPagedCache", "ContiguousKVCache", "KV", "LATENT", "STATE"]
+           "LatentPagedCache", "ContiguousKVCache", "KV", "LATENT", "STATE",
+           "open_window_start"]
 
 KV, LATENT, STATE = "kv", "latent", "state"
 # a state group's recurrence: a module of that name under
@@ -135,13 +178,17 @@ class CacheGroup(NamedTuple):
     """Layers that keep the same thing: ``kind`` ``KV`` or ``LATENT`` keeps
     rows a token in pages (``window`` None keeps every position of a slot's
     context, W keeps the last W as a ring); ``STATE`` keeps a fixed-size
-    state a slot and has no pages (``window`` None, ``num_pages`` 0)."""
+    state a slot and has no pages (``window`` None, ``num_pages`` 0). A
+    ``KV`` group with a ``chunk`` c is COMPACTING: ``window`` is then a
+    tumbling window whose rows are replaced by one summary a chunk when it
+    closes (the module docstring has the map)."""
 
     name: str
     layers: Tuple[int, ...]
     window: Optional[int]
     num_pages: int
     kind: str = KV
+    chunk: Optional[int] = None
 
 Cache = Dict[str, jnp.ndarray]
 
@@ -154,6 +201,16 @@ def _live_len(ctx_len, active, window: int = 1):
     slot's length where its request ended, so without this every layer of
     every step would stream that request's whole context for nobody."""
     return jnp.where(active, ctx_len, 1 - window)
+
+
+def open_window_start(length, rows: int, window: int):
+    """First position of the stretch of a prompt's rows that a compacting
+    group is handed: the ``min(rows, window)`` positions from here hold the
+    window that ``length`` positions leave open (the positions from
+    ``window (length // window)`` on), clipped to the bucket's ``rows``.
+    The model slices by it, ``write_prompt`` places by it."""
+    return jnp.clip(window * (length // window), 0,
+                    rows - min(rows, window))
 
 
 def _dtype_by_name(name: str) -> np.dtype:
@@ -233,8 +290,11 @@ class PagedKVCache(_KVCacheBase):
         self.groups: List[CacheGroup] = [
             CacheGroup(g.name, tuple(g.layers),
                        None if g.window is None
+                       else int(g.window) if g.chunk is not None
                        else min(int(g.window), self.max_ctx),
-                       int(g.num_pages), g.kind) for g in groups]
+                       int(g.num_pages), g.kind,
+                       None if g.chunk is None else int(g.chunk))
+            for g in groups]
         # a STATE group's geometry: (heads, dk, dv, tail rows, tail width)
         self.slot_state = (None if slot_state is None
                            else tuple(int(n) for n in slot_state))
@@ -261,6 +321,17 @@ class PagedKVCache(_KVCacheBase):
                 raise ValueError("group %r: window=%d must be a multiple of "
                                  "page_size=%d" % (g.name, g.window,
                                                    self.page_size))
+            if g.chunk is not None and (
+                    g.kind != KV or g.window is None or self.cache_steps > 1
+                    or self.page_size % g.chunk
+                    or g.window % (g.chunk * self.page_size)):
+                raise ValueError(
+                    "group %r: a compacting group is a KV group with a "
+                    "window, whole chunks a page and whole pages of "
+                    "summaries a window, one cache layer a layer; got kind "
+                    "%s, window=%s, chunk=%d, page_size=%d, cache_steps=%d"
+                    % (g.name, g.kind, g.window, g.chunk, self.page_size,
+                       self.cache_steps))
             where = self._where_state if g.kind == STATE else self._where
             for li, layer in enumerate(g.layers):
                 if layer in where:
@@ -297,14 +368,39 @@ class PagedKVCache(_KVCacheBase):
     # -- groups ---------------------------------------------------------------
     def group_rows(self, gi: int) -> int:
         """Context rows a slot can hold in group ``gi``."""
+        g = self.groups[gi]
+        if g.chunk is not None:
+            return self.pages_needed(gi, self.max_ctx) * self.page_size
+        return self.max_ctx if g.window is None else g.window
+
+    def _summaries(self, gi: int) -> int:
+        """Summaries a closed window of compacting group ``gi`` leaves:
+        view rows it keeps."""
+        return self.groups[gi].window // self.groups[gi].chunk
+
+    def _view_row(self, gi: int, pos):
+        """Where position ``pos`` lives in a slot's view of compacting
+        group ``gi`` while its window is open: after a summary a chunk of
+        every closed window."""
         w = self.groups[gi].window
-        return self.max_ctx if w is None else w
+        return self._summaries(gi) * (pos // w) + pos % w
+
+    def _pool_rows(self, table, view_rows):
+        """Pool rows of ``view_rows`` of a slot's view through its
+        page-table entries ``table`` (one table for all the rows, or one a
+        row)."""
+        ps = self.page_size
+        page = (table[view_rows // ps] if table.ndim == 1
+                else table[jnp.arange(table.shape[0]), view_rows // ps])
+        return page * ps + view_rows % ps
 
     def group_pages_per_slot(self, gi: int) -> int:
         """Entries of group ``gi`` in a slot's page-table rows: its pages,
         or for a state group the one entry that names the slot."""
         if self.groups[gi].kind == STATE:
             return 1
+        if self.groups[gi].chunk is not None:
+            return self.pages_needed(gi, self.max_ctx)
         return self.group_rows(gi) // self.page_size
 
     @property
@@ -315,8 +411,16 @@ class PagedKVCache(_KVCacheBase):
 
     def pages_needed(self, gi: int, total_tokens: int) -> int:
         """Pages group ``gi`` reserves for a request of ``total_tokens``
-        positions: all of them, or the whole ring where it is shorter."""
-        need = -(-int(total_tokens) // self.page_size)
+        positions: all of them, or the whole ring where it is shorter; in
+        a compacting group a summary a chunk of every window the request
+        can close, a whole window's rows (or the request's, if fewer) and
+        the pages where the open window's summaries wait."""
+        n, g = int(total_tokens), self.groups[gi]
+        if g.chunk is not None:
+            rows = self._summaries(gi) * (n // g.window) + min(n, g.window)
+            return (-(-rows // self.page_size)
+                    + self._summaries(gi) // self.page_size)
+        need = -(-n // self.page_size)
         return min(need, self.group_pages_per_slot(gi))
 
     def _key(self, gi: int, what: str) -> str:
@@ -325,13 +429,26 @@ class PagedKVCache(_KVCacheBase):
         return what if gi == 0 else "%s.%s" % (what, self.groups[gi].name)
 
     def _group_len(self, gi: int, ctx_len):
-        """Rows of a slot's context that group ``gi`` holds."""
-        w = self.groups[gi].window
-        return ctx_len if w is None else jnp.minimum(ctx_len, w)
+        """Rows of a slot's context that group ``gi`` holds: what a query
+        at position ``ctx_len - 1`` attends over."""
+        g = self.groups[gi]
+        if g.chunk is not None:
+            return jnp.where(ctx_len > 0,
+                             self._view_row(gi, ctx_len - 1) + 1, 0)
+        return ctx_len if g.window is None else jnp.minimum(ctx_len,
+                                                            g.window)
 
     def _single_group(self, what: str) -> None:
-        """What needs ONE group of K and V rows (speculative verify, the
-        int8 pool, page copies, export and import) is refused elsewhere."""
+        """What needs ONE group of K and V rows, a position's row where
+        the position says (speculative verify, the int8 pool, page copies,
+        export and import), is refused elsewhere."""
+        if self.groups[0].chunk is not None:
+            raise ValueError(
+                "%s is not supported over a compacting group (%s: a closed "
+                "window's rows are replaced by a summary a chunk of %d, so "
+                "a page no longer holds the positions its place says and "
+                "nothing can be rolled back)"
+                % (what, self.groups[0].name, self.groups[0].chunk))
         if len(self.groups) > 1 or self.groups[0].kind != KV:
             raise ValueError(
                 "%s is not supported over a cache with %d groups (%s)"
@@ -416,13 +533,81 @@ class PagedKVCache(_KVCacheBase):
         ps = self.page_size
         gi, _ = self._where[layer]
         pt = state[self._key(gi, "pt")]
-        b_idx = jnp.arange(pt.shape[0])
-        idx = pos // ps
-        if self.groups[gi].window is not None:
-            idx = idx % self.group_pages_per_slot(gi)
-        dest = pt[b_idx, idx] * ps + pos % ps
+        if self.groups[gi].chunk is not None:
+            dest = self._pool_rows(pt, self._view_row(gi, pos))
+        else:
+            b_idx = jnp.arange(pt.shape[0])
+            idx = pos // ps
+            if self.groups[gi].window is not None:
+                idx = idx % self.group_pages_per_slot(gi)
+            dest = pt[b_idx, idx] * ps + pos % ps
         dest = jnp.where(active, dest, self._drop_row(gi))
         return self._write_rows(state, layer, dest, k_new, v_new, step)
+
+    # -- a compacting group's decode step ------------------------------------
+    def open_chunk(self, state: Cache, layer: int, pos
+                   ) -> Tuple[jnp.ndarray, jnp.ndarray]:
+        """``(k, v)`` [B, chunk, H, D]: the rows of the chunk that position
+        ``pos[b]`` is in, as the pool keeps them (``write_token`` first:
+        the row at ``pos`` is among them; rows past it are whatever the
+        pages held, which a caller uses only once the chunk is whole). A
+        chunk's rows lie in ONE page, so this is a slice a slot of the
+        group's whole pool, no layer of it is copied."""
+        gi, li = self._where[layer]
+        c = self.groups[gi].chunk
+        row = self._view_row(gi, pos)
+        first = self._pool_rows(state[self._key(gi, "pt")],
+                                row - row % c)
+
+        def rows(pool):
+            out = jax.vmap(lambda f: jax.lax.dynamic_slice(
+                pool, (li, f, 0), (1, c, self.row_width))[0])(first)
+            return out.reshape(out.shape[:2] + (self.n_head, self.d_head))
+
+        return (rows(state[self._key(gi, "k")]),
+                rows(state[self._key(gi, "v")]))
+
+    def write_summary(self, state: Cache, layer: int, k_sum, v_sum, pos,
+                      active) -> Cache:
+        """``k_sum``/``v_sum`` [B, H, D], the summary of the chunk that
+        ``pos[b]`` ENDS, written where the open window's summaries wait
+        (after the window's rows in the slot's view: not read until
+        :meth:`close_windows` brings the pages forward). Dropped for a slot
+        that is not ``active`` or whose ``pos`` is not its chunk's last."""
+        gi, _ = self._where[layer]
+        g = self.groups[gi]
+        view = (self._summaries(gi) * (pos // g.window) + g.window
+                + (pos % g.window) // g.chunk)
+        dest = self._pool_rows(state[self._key(gi, "pt")], view)
+        dest = jnp.where(active & ((pos + 1) % g.chunk == 0), dest,
+                         self._drop_row(gi))
+        return self._write_rows(state, layer, dest, k_sum, v_sum)
+
+    def close_windows(self, state: Cache, pos, active) -> Cache:
+        """The compaction, after a decode step's LAST layer: in every
+        compacting group, a slot that is ``active`` and whose ``pos`` is
+        its window's last has the stretch of its page-table row that holds
+        the window and its summaries rotated by the summaries' pages, so
+        that they stand where the window began and the window's own pages
+        (their rows dead from now on) follow them, as the next window's and
+        its summaries'. No pool row moves."""
+        out = dict(state)
+        for gi, g in enumerate(self.groups):
+            if g.chunk is None:
+                continue
+            key = self._key(gi, "pt")
+            pt = state[key]
+            ps = self.page_size
+            kept = self._summaries(gi) // ps        # summary pages a window
+            span = g.window // ps + kept            # window + its summaries
+            entry = jnp.arange(pt.shape[1])[None, :]
+            rel = entry - (kept * (pos // g.window))[:, None]
+            src = jnp.where((rel >= 0) & (rel < span),
+                            entry - rel + (rel - kept) % span, entry)
+            closing = active & ((pos + 1) % g.window == 0)
+            out[key] = jnp.where(closing[:, None],
+                                 jnp.take_along_axis(pt, src, axis=1), pt)
+        return out
 
     def _drop_row(self, gi: int) -> int:
         """One past group ``gi``'s last pool row: a scatter to it drops."""
@@ -524,9 +709,21 @@ class PagedKVCache(_KVCacheBase):
         0 for a slot that is not ``active``). A model hands them back among
         its decode ``stats`` for ``serving/attn_rows_read.<group>``."""
         live = _live_len(ctx_len, active)
-        return {"attn_rows_read." + g.name:
-                jnp.sum(self._group_len(gi, live)).astype(jnp.int32)
-                for gi, g in enumerate(self.groups) if g.kind != STATE}
+        out = {}
+        for gi, g in enumerate(self.groups):
+            if g.kind == STATE:
+                continue
+            rows = jnp.sum(self._group_len(gi, live)).astype(jnp.int32)
+            if g.chunk is None:
+                out["attn_rows_read." + g.name] = rows
+                continue
+            # a compacting group reads two kinds of row: a summary a chunk
+            # of the closed windows, and the open window's exact rows
+            pooled = jnp.sum(self._summaries(gi) * (
+                jnp.maximum(live - 1, 0) // g.window)).astype(jnp.int32)
+            out["attn_rows_read.%s_summary" % g.name] = pooled
+            out["attn_rows_read.%s_exact" % g.name] = rows - pooled
+        return out
 
     def decode_attention(self, state: Cache, layer: int, q, ctx_len,
                          active, sm_scale: float = 1.0, step=None
@@ -697,26 +894,62 @@ class PagedKVCache(_KVCacheBase):
         for gi, pages in zip(paged, group_pages):
             row = np.zeros(self.group_pages_per_slot(gi), np.int32)
             row[:len(pages)] = np.asarray(pages, np.int32)
+            if self.groups[gi].chunk is not None:
+                # the first window's summaries wait after a WHOLE window's
+                # pages: a request shorter than a window holds fewer
+                kept = self._summaries(gi) // self.page_size
+                first = self.groups[gi].window // self.page_size
+                if len(pages) < first + kept:
+                    row[len(pages) - kept:len(pages)] = 0
+                    row[first:first + kept] = np.asarray(
+                        pages[len(pages) - kept:], np.int32)
             rows.append(row)
         rows.append(np.full(len(self.groups) - len(paged), slot, np.int32))
         return np.concatenate(rows)
 
-    def write_prompt(self, state: Cache, layer: int, k_new, v_new, dest,
-                     length, step=None) -> Cache:
-        """k_new/v_new [S,H,D] for ONE sequence; ``dest`` is
-        :meth:`prompt_dest_groups`'s row; positions >= length are dropped,
-        and in a window group the positions that have already left the
-        window (< length - window) too: the last ``min(length, window)``
-        land at their places in the ring. ``step``: the loop step that
-        made these rows (a model with ``cache_steps`` hands a prompt's K
-        and V a step, and the engine writes each). For a layer that has a
-        state and NO pages ``k_new`` is the state and ``v_new`` the tail:
-        :meth:`write_slot_state`'s."""
+    def write_prompt(self, state: Cache, layer: int, *new_dest_length,
+                     step=None) -> Cache:
+        """What a model's prefill ``kept`` of ONE sequence for ``layer``,
+        then ``dest`` (:meth:`prompt_dest_groups`'s row) and ``length``;
+        the layer's group says what was kept. ``(k_new, v_new)`` [S,H,D]:
+        positions >= length are dropped, and in a window group the
+        positions that have already left the window (< length - window)
+        too: the last ``min(length, window)`` land at their places in the
+        ring. ``step``: the loop step that made these rows (a model with
+        ``cache_steps`` hands a prompt's K and V a step, and the engine
+        writes each). For a layer that has a state and NO pages ``(state,
+        tail)``: :meth:`write_slot_state`'s. In a COMPACTING group
+        ``(k_open, v_open, k_sum, v_sum)``: the ``min(S, window)`` rows
+        from :func:`open_window_start` on (the window ``length`` leaves
+        open is among them) and a pair a chunk of the bucket [S / chunk,
+        H, D]: the closed windows' summaries go where attention reads
+        them, the open window's finished ones where they wait, the open
+        window's rows after the closed windows' summaries."""
+        *new, dest, length = new_dest_length
         ps = self.page_size
         if layer not in self._where:
-            return self.write_slot_state(state, layer, k_new, v_new, dest)
+            return self.write_slot_state(state, layer, *new, dest)
         gi, li = self._where[layer]
         off = self._pt_start[gi]
+        g = self.groups[gi]
+        if g.chunk is not None:
+            k_new, v_new, *summaries = new
+            table = dest[off:self._pt_start[gi + 1]]
+            kept, closed = self._summaries(gi), length // g.window
+            i = jnp.arange(summaries[0].shape[0])
+            view = jnp.where(i < kept * closed, i, i + g.window)
+            flat = jnp.where(i < length // g.chunk,
+                             self._pool_rows(table, view),
+                             self._drop_row(gi))
+            state = self._write_rows(state, layer, flat, *summaries)
+            j = jnp.arange(k_new.shape[0]) + open_window_start(
+                length, summaries[0].shape[0] * g.chunk, g.window)
+            flat = jnp.where(
+                (j >= closed * g.window) & (j < length),
+                self._pool_rows(table, self._view_row(gi, j)),
+                self._drop_row(gi))
+            return self._write_rows(state, layer, flat, k_new, v_new)
+        k_new, v_new = new
         s = k_new.shape[0]
         j = jnp.arange(s)
         keep = j < length

@@ -27,6 +27,7 @@ __all__ = [
     "SPEC_VERIFY_DISPATCHES", "SPEC_ACCEPT_RATE",
     "MOE_EXPERTS_TOUCHED", "MOE_MAX_EXPERT_ROWS", "MOE_HELD_PAIRS",
     "STATE_SLOTS_STEPPED", "STATE_POOL_BYTES", "LATENT_RING_BYTES",
+    "EVA_CHUNKS_CLOSED", "EVA_WINDOWS_CLOSED",
     "PREFIX_HITS", "PREFIX_MISSES", "PREFIX_INSERTS", "PREFIX_EVICTIONS",
     "PREFIX_ENTRIES", "PREFIX_PAGES", "PREFIX_TOKENS_REUSED",
     "PREFIX_POISONED_SKIPPED",
@@ -205,6 +206,16 @@ UT_EXPECTED_EXIT_STEP = _mx.histogram(
          "observation a decode step (a model that runs its layers several "
          "times a token; with a threshold of 1 every step runs whatever "
          "this reads)")
+EVA_CHUNKS_CLOSED = _mx.histogram(
+    "serving/eva_chunks_closed",
+    help="live slots whose decode step ended a chunk of a compacting cache "
+         "group (its summary written where the open window's wait), one "
+         "observation a step")
+EVA_WINDOWS_CLOSED = _mx.histogram(
+    "serving/eva_windows_closed",
+    help="live slots whose decode step closed a window of a compacting "
+         "cache group (the page-table rotation that puts a summary a chunk "
+         "in place of the window's rows), one observation a step")
 STATE_POOL_BYTES = _mx.gauge(
     "serving/state_pool_bytes",
     help="bytes of the per-slot recurrent states and convolution tails "
@@ -264,7 +275,9 @@ _MODEL_STATS = {"moe_experts_touched": MOE_EXPERTS_TOUCHED,
                 "moe_held_pairs": MOE_HELD_PAIRS,
                 "state_slots_stepped": STATE_SLOTS_STEPPED,
                 "index_blocks_scored": INDEX_BLOCKS_SCORED,
-                "ut_expected_exit_step": UT_EXPECTED_EXIT_STEP}
+                "ut_expected_exit_step": UT_EXPECTED_EXIT_STEP,
+                "eva_chunks_closed": EVA_CHUNKS_CLOSED,
+                "eva_windows_closed": EVA_WINDOWS_CLOSED}
 
 
 def pages_used(group: str):
@@ -299,8 +312,8 @@ def attn_rows_context(group: str):
 def model_stat(name: str):
     """The histogram the engine feeds a model's decode ``stats[name]`` to
     (an observation a value a step), or None for a name it does not know:
-    the three ``moe_*``, ``state_slots_stepped`` and
-    ``ut_expected_exit_step`` above and
+    the three ``moe_*``, ``state_slots_stepped``,
+    ``ut_expected_exit_step`` and the two ``eva_*_closed`` above and
     ``attn_rows_read.<group>``, ``index_blocks_scored`` and
     ``attn_rows_context.<group>``, looked up once a name."""
     hist = _MODEL_STATS.get(name)

@@ -574,9 +574,72 @@ def _ouro_case(name, chip):
     }[name], ops.num_rows
 
 
+EVA_SERVE = dict(slots=12, page_size=16, num_pages=2048, max_seq=18432,
+                 n_layer=2, bucket=4096)
+
+
+def _eva_case(name, chip):
+    """``_serve_case``'s twin for the model whose cache group COMPACTS, at
+    EvaByte-6.5B's published widths over the cell's pool, two of its
+    layers: the decode chunk (a row in, the open chunk's rows out, its
+    summary in, the paged kernel over the slot's view, the page-table
+    rotation after the last layer) and the 4,096-row prefill, which hands
+    the cache the open window's rows and a summary a chunk."""
+    from paddle_tpu.models import evabyte
+    from paddle_tpu.serving.kv_cache import KV, CacheGroup, PagedKVCache
+
+    def sds(x):
+        return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=chip)
+
+    g = EVA_SERVE
+    cfg = evabyte.EvaByteConfig(320, g["n_layer"], 4096, 32, 32, 11008,
+                                max_seq=g["max_seq"], dtype="bfloat16")
+    model = evabyte.EvaByteLM(cfg, params={})
+    ops = PagedKVCache(cfg.n_layer, 32, 128, g["slots"], g["max_seq"],
+                       g["page_size"], g["num_pages"], dtype="bfloat16",
+                       groups=[CacheGroup("eva", tuple(range(cfg.n_layer)),
+                                          cfg.window, g["num_pages"], KV,
+                                          cfg.chunk)])
+    params = jax.tree_util.tree_map(
+        sds, jax.eval_shape(lambda: evabyte.init_params(cfg, 0)))
+    cache = jax.tree_util.tree_map(sds, jax.eval_shape(ops.init_state))
+    b = g["slots"]
+    ints = jax.ShapeDtypeStruct((b,), jnp.int32, sharding=chip)
+    flags = jax.ShapeDtypeStruct((b,), jnp.bool_, sharding=chip)
+    scalar = jax.ShapeDtypeStruct((), jnp.int32, sharding=chip)
+
+    def chunk(params, cache, lengths, tokens, active):
+        def body(carry, _):
+            cache, ln, tk, ac = carry
+            logits, cache, stats = model.decode(params, cache, ops, tk, ln,
+                                                ac)
+            nxt = jnp.where(ac, jnp.argmax(logits, -1).astype(jnp.int32), tk)
+            return (cache, ln + ac, nxt, ac), (nxt, stats)
+
+        return jax.lax.scan(body, (cache, lengths, tokens, active), None,
+                            length=1)
+
+    def prefill(params, cache, dest, prompt, length):
+        logits, kvs = model.prefill_last(params, prompt[None], length[None])
+        for i, kv in enumerate(kvs):
+            cache = ops.write_prompt(cache, i, *(t[0] for t in kv), dest,
+                                     length)
+        return cache, jnp.argmax(logits[0])
+
+    return {
+        "eva_chunk": (chunk, (params, cache, ints, ints, flags)),
+        "eva_prefill": (prefill, (
+            params, cache, jax.ShapeDtypeStruct((ops.page_table_len,),
+                                                jnp.int32, sharding=chip),
+            jax.ShapeDtypeStruct((g["bucket"],), jnp.int32, sharding=chip),
+            scalar)),
+    }[name], ops.num_rows
+
+
 @pytest.mark.parametrize("exe", ["chunk", "verify", "prefill",
                                  "prefill_armed", "resume", "ouro_chunk",
-                                 "ouro_prefill"])
+                                 "ouro_prefill", "eva_chunk",
+                                 "eva_prefill"])
 def test_serving_executables_leave_the_pool_in_place(chip, monkeypatch, exe):
     """The decode chunk, the verify window, a prefill bucket (alone, and as
     the admission the engine launches: with the slot's page table and its
@@ -593,15 +656,17 @@ def test_serving_executables_leave_the_pool_in_place(chip, monkeypatch, exe):
     # the backend is the CPU and only the compile's target is the chip
     monkeypatch.setattr(attention_ops, "paged_kernel_mode",
                         lambda: "compiled")
-    looped = exe.startswith("ouro")
+    looped, compacting = exe.startswith("ouro"), exe.startswith("eva")
     if looped:
         monkeypatch.setattr(attention_ops, "_on_tpu", lambda: True)
-    (fn, args), rows = (_ouro_case if looped else _serve_case)(exe, chip)
+    (fn, args), rows = (_ouro_case if looped else _eva_case if compacting
+                        else _serve_case)(exe, chip)
     compiled = jax.jit(fn, donate_argnums=(1,)).lower(*args).compile()
     text = compiled.as_text()
     if "prefill" not in exe:  # a prefill attends over its own K and V
         assert text.count("tpu_custom_call") == (
-            OURO_SERVE if looped else SERVE)["n_layer"]
+            OURO_SERVE if looped else EVA_SERVE if compacting
+            else SERVE)["n_layer"]
     if looped:      # the steps stayed ONE loop: nothing unrolled them
         assert len(re.findall(r" while\(", text)) == 1
     instructions = list(_instructions(text))
@@ -611,6 +676,13 @@ def test_serving_executables_leave_the_pool_in_place(chip, monkeypatch, exe):
                        "transpose")
              and any(_has_dim(t, rows) for t in
                      [rtype] + [types.get(o, "") for o in operands])]
+    if exe == "eva_chunk":
+        # ``open_chunk``: the 16 rows of a slot's open chunk out of the
+        # pool, a slice a slot a pool a layer, is what the model asked for
+        chunks = [m for m in moved if m[0] == "dynamic-slice"
+                  and m[1].startswith("bf16[1,16,4096]")]
+        assert len(chunks) == 2 * EVA_SERVE["n_layer"]
+        moved = [m for m in moved if m not in chunks]
     assert moved == []
     # cache is argument 1 of every executable: its leaves follow the
     # params' in the flattened parameter list, "k" "pt" "v" in key order
@@ -626,6 +698,8 @@ def test_serving_executables_leave_the_pool_in_place(chip, monkeypatch, exe):
     if exe == "ouro_prefill":   # K and V of 4 steps x 4 layers x 512 rows
         g = OURO_SERVE
         limit = g["n_layer"] * g["steps"] * rows * 2048 * 2     # one pool
+    if exe == "eva_prefill":    # 4,096 rows' float32 residual, MLP halves
+        limit = 2 ** 30         # and a block's scores: half of ONE pool
     assert compiled.memory_analysis().temp_size_in_bytes < limit
 
 

@@ -47,7 +47,7 @@ LEFT = [
 ]
 
 SERVED = ["smallthinker", "kimi_k2", "laguna", "ling3_flash", "motif3",
-          "glm5_flash", "falcon_h1", "ouro"]
+          "glm5_flash", "falcon_h1", "ouro", "evabyte"]
 
 
 def _files(package):

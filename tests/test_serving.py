@@ -4,6 +4,7 @@ and flight-recorder capture (ISSUE 6 tentpole coverage)."""
 
 import json
 import os
+import re
 
 import numpy as np
 import pytest
@@ -1050,3 +1051,94 @@ def test_a_snapshot_of_a_stepped_pool_round_trips_and_an_old_one_loads_as_one(
     one.import_pages(one.init_state(), [5], old_meta, old_blobs)
     with pytest.raises(ValueError, match="geometry mismatch"):
         dst.import_pages(dst.init_state(), [5], old_meta, old_blobs)
+
+
+# -- a compacting cache group: windows replaced by a summary a chunk ----------
+
+def _compacting_model(seed=3):
+    from paddle_tpu.models import evabyte
+
+    cfg = evabyte.EvaByteConfig(
+        vocab_size=40, n_layer=2, d_model=32, n_head=4, n_kv_head=4, d_ff=48,
+        window=32, chunk=4, n_pred_heads=3, max_seq=160)
+    return evabyte.EvaByteLM(cfg, params=evabyte.init_params(cfg, seed))
+
+
+def test_a_compacting_group_is_served_end_to_end(rng):
+    """The toy byte-level model through ``submit``/``run``: admission
+    reserves the pages the cache's map says (not ``n / page_size``), they
+    return at retirement, ``serving/eva_windows_closed`` and
+    ``serving/eva_chunks_closed`` count what the lengths imply, the rows a
+    layer read are of both kinds, and every head's logits a decoded row
+    ride out as the probe."""
+    from paddle_tpu.serving import metrics as sm
+
+    model = _compacting_model()
+    cfg = serving.ServingConfig(slots=2, page_size=4, max_seq=160,
+                                prompt_buckets=(32, 64, 128),
+                                group_pages={"eva": 60})
+    # (prompt, new tokens): decode consumes positions [n, n + m - 1)
+    plan = [(20, 50), (70, 30), (100, 28), (5, 3)]
+    windows0 = sm.EVA_WINDOWS_CLOSED.sum
+    chunks0 = sm.EVA_CHUNKS_CLOSED.sum
+    summary = sm.attn_rows_read("eva_summary")
+    exact = sm.attn_rows_read("eva_exact")
+    context = sm.attn_rows_context("eva")
+    before = (summary.sum, exact.sum, context.sum)
+    with serving.ServingEngine(model, cfg) as eng:
+        ops = eng.cache_ops
+        assert [g.chunk for g in ops.groups] == [4]
+        assert eng.pool.name == "eva" and ops.pages_per_slot == 20
+        reqs = [eng.submit(rng.randint(0, 40, n).tolist(), m)
+                for n, m in plan]
+        eng.step()
+        # two slots admitted: 70 and 58 positions, by the map (the closed
+        # windows' 8 summaries, a window's 32 rows, 2 pages of waiting)
+        assert [len(r.pages) for r in reqs[:2]] == [
+            ops.pages_needed(0, 70), ops.pages_needed(0, 100)]
+        assert [len(r.pages) for r in reqs[:2]] == [14, 16]
+        assert eng.pool.num_used == 30
+        assert sm.pages_used("eva").value == 30
+        eng.run()
+        assert all(r.state == "finished" for r in reqs)
+        assert [len(r.tokens_out) for r in reqs] == [m for _, m in plan]
+        assert eng.pool.num_used == 0 and eng.page_accounting_ok()
+        tenants, stats = eng.last_decode_stats
+        assert stats["eva_head_logits"].shape[-2:] == (3, 40)
+    # a decode step at position p closes p's chunk where (p + 1) % 4 == 0
+    # and its window where (p + 1) % 32 == 0
+    consumed = [range(n, n + m - 1) for n, m in plan]
+    assert sm.EVA_WINDOWS_CLOSED.sum - windows0 == sum(
+        (p + 1) % 32 == 0 for r in consumed for p in r) == 3
+    assert sm.EVA_CHUNKS_CLOSED.sum - chunks0 == sum(
+        (p + 1) % 4 == 0 for r in consumed for p in r)
+    assert summary.sum - before[0] == sum(
+        8 * (p // 32) for r in consumed for p in r)
+    assert exact.sum - before[1] == sum(
+        p % 32 + 1 for r in consumed for p in r)
+    assert context.sum - before[2] == sum(p + 1 for r in consumed for p in r)
+
+
+@pytest.mark.parametrize("what,over", [
+    ("speculative verify", dict(speculation=2)),
+    ("the prefix cache", dict(prefix_cache_pages=4)),
+    ("the int8 KV pool", dict(kv_dtype="int8")),
+    ("the contiguous layout", dict(paged=False))])
+def test_what_a_compacting_group_refuses_says_why(what, over):
+    """At construction, with the reason: a compacted window cannot be
+    rolled back and a page no longer holds the positions its place says.
+    (The model has no ``verify``; one that had is refused the same way.)"""
+    model = _compacting_model()
+    if what == "speculative verify":
+        model.verify = lambda *a: None
+    with pytest.raises(ValueError, match="%s.* is not supported over .*"
+                       "compact" % re.escape(what)):
+        serving.ServingEngine(model, serving.ServingConfig(
+            slots=2, page_size=4, max_seq=160, **over))
+    with serving.ServingEngine(model, serving.ServingConfig(
+            slots=2, page_size=4, max_seq=160)) as eng:
+        for call in (lambda: eng.cache_ops.export_pages(eng._cache, [1]),
+                     lambda: eng.cache_ops.import_pages(eng._cache, [1], {},
+                                                        [])):
+            with pytest.raises(ValueError, match="compacting group"):
+                call()
