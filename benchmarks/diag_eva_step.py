@@ -3,11 +3,16 @@
 through ``grid.run`` (its command line is this script's), and after its
 last line one JSON line to ``chiprun_out/diag_eva_step.json`` and to
 standard error: for the decode and the prefill executables, the device
-seconds of the traced stretch under each of ``grid/readers/eva.SCOPES``,
+seconds of the traced stretch under each of ``grid/readers/eva.SCOPES``
+(``attn/eva_prefill`` is the prefill's attention, whichever form
+``attn/eva_prefill_calls.kernel|blocked``, printed beside, says it took),
 under none of them (and that time by the instructions' kind: the waits
 for asynchronous copies are among them), the whole module's, the decode
-steps and prefills in the stretch, and the twenty instructions that took
-most time with the scopes that claim them; and, from the decode
+steps and prefills in the stretch, every Pallas call and ``while`` by name
+and result (``eva_prefill_attention_bf16[4096,8192]``: the prefill's
+attention, too short for the ten labels of the run's own ``breakdown``), and
+the twenty instructions that took most time with the scopes that claim
+them; and, from the decode
 executable's own scheduled text, the asynchronous copies into fast memory
 (``S(1)``) with their bytes, by what they fetch, and which of them are in
 flight ACROSS a paged kernel's call (started before it, waited for after
@@ -118,8 +123,17 @@ def main(argv) -> int:
     if rc or trace is None:
         return rc
     win = tuple(record["trace_window"])
+    from paddle_tpu.monitor import metrics
+
+    buckets = eva._traced_buckets(record)
     out = {"decode_steps": eva._tail(record, "steps_n"),
-           "prefills": len(eva._traced_buckets(record)),
+           "prefills": len(buckets), "traced_buckets": buckets,
+           # which form each traced prefill attention took (a layer a
+           # prefill executable; an executable from the persistent cache
+           # is still traced in this process)
+           "eva_prefill_calls": {form: int(metrics.counter(
+               "attn/eva_prefill_calls." + form).value)
+               for form in ("kernel", "blocked")},
            "busy_s": reduce.busy_seconds(trace, win),
            "decode_async_copies": seen.get("copies")}
     for module, ops in record["scoped_ops"].items():
@@ -146,7 +160,13 @@ def main(argv) -> int:
                 kind = re.sub(r"\.\d+$", "", name)
                 kind = kind if "start" in kind or "done" in kind else op
                 unscoped[kind] = unscoped.get(kind, 0.0) + t
+        calls = {}      # the Pallas calls by kernel and result, the loops
+        for name, (t, op, shape) in by_name.items():
+            if op in ("custom-call", "while"):
+                label = "%s_%s" % (re.sub(r"\.\d+$", "", name), shape)
+                calls[label] = calls.get(label, 0.0) + t
         out[module] = {"whole_s": whole, "by_scope_s": by_scope,
+                       "kernels_and_loops_s": calls,
                        "unscoped_by_kind_s": dict(sorted(
                            unscoped.items(), key=lambda kv: -kv[1])[:12]),
                        "top": [[n, round(t, 5), op, shape, claimed.get(n, [])]

@@ -23,9 +23,15 @@ What is particular to serving it:
 * ``kept`` from prefill is ``(k_open, v_open, k_sum, v_sum)`` a layer: the
   ``min(S, window)`` rows from ``kv_cache.open_window_start`` on, which
   hold the window the prompt leaves open, and a summary a chunk of the
-  bucket. The prefill attention is, a block of queries, one product
-  against ``[every earlier window's summaries ++ its own window's rows]``
-  with the softmax written by hand over both parts (plain XLA);
+  bucket. The prefill attention is ONE softmax a query over ``[every
+  earlier window's summaries ++ its own window's rows up to itself]``: on
+  a TPU one ``eva_prefill_attention`` kernel call a layer
+  (``ops/pallas_kernels/eva_prefill.py``: an online softmax over the
+  closed windows' summary tiles and the own window's tiles, no score
+  through HBM), elsewhere blocked XLA with the softmax written by hand
+  over both parts (``_prefill_attention`` chooses from the backend and
+  ``eva_prefill_gate``'s shape rules and counts the choice,
+  ``attn/eva_prefill_calls.kernel|blocked``);
 * the residual stream is float32 (``fp32_skip_add``), the pooling and
   every softmax float32 (``mixedp_attn``), the products take the model
   type's operands with float32 accumulation, a norm applies ``1 + g``
@@ -43,7 +49,8 @@ from typing import Dict, Mapping
 import jax
 import jax.numpy as jnp
 
-from ..ops.attention_ops import neg_inf
+from ..ops import attention_ops
+from ..ops.pallas_kernels import eva_prefill
 from ..serving.kv_cache import KV, open_window_start
 from .blocks import ServedLM, rms_norm, rope, seeded_params
 
@@ -217,19 +224,44 @@ def summarize(cfg: EvaByteConfig, lp, k, v):
 
 def _prefill_attention(cfg: EvaByteConfig, q, k, v, ks, vs):
     """EVA attention of ONE bucket-padded sequence: ``q``/``k``/``v`` [S,
-    H, D], ``ks``/``vs`` [S / chunk, H, D] a summary a chunk. Queries go in
-    blocks of ``_PREFILL_BLOCK`` rows; a block of window w meets the
-    summaries of the windows before w and the rows of w up to each query,
-    and the softmax over both parts is written out (one max, one sum), in
-    float32. A summary of a chunk that holds padding is seen by padding
-    alone. Returns [S, H, D]."""
+    H, D], ``ks``/``vs`` [S / chunk, H, D] a summary a chunk (none at all
+    where ``S <= window``: no window has closed). A query of window w
+    meets the summaries of the windows before w and the rows of w up to
+    itself under ONE softmax in float32. On a TPU, where
+    ``eva_prefill_gate`` takes the shapes, that is ONE
+    ``eva_prefill_attention`` kernel call (pallas_kernels/eva_prefill.py:
+    no score reaches HBM, a query block reads the closed windows' summary
+    tiles and its own window's tiles up to its last row, and no others);
+    elsewhere the queries go in blocks of ``_PREFILL_BLOCK`` rows, each
+    against its WHOLE window and ALL the summaries, masked, the softmax
+    over both parts written out (one max, one sum).
+    ``attn/eva_prefill_calls.kernel`` and ``.blocked`` count which. A
+    summary of a chunk that holds padding is seen by padding alone.
+    Returns [S, H, D]."""
+    s, h, d = q.shape
+    wm = min(s, cfg.window)
+    kernel = attention_ops._on_tpu() and eva_prefill.eva_prefill_gate(
+        h, d, s, wm, cfg.chunk, q.dtype.itemsize) is None
+    attention_ops._count("kernel" if kernel else "blocked",
+                         "attn/eva_prefill_calls",
+                         "evabyte._prefill_attention")
+    with jax.named_scope("attn/eva_prefill"):
+        if kernel:
+            return eva_prefill.eva_prefill_attention(
+                q, k, v, ks, vs, wm, cfg.chunk, sm_scale=cfg.sm_scale)
+        return _blocked_prefill_attention(cfg, q, k, v, ks, vs)
+
+
+def _blocked_prefill_attention(cfg: EvaByteConfig, q, k, v, ks, vs):
+    """:func:`_prefill_attention` in plain XLA, a ``lax.map`` over query
+    blocks: the form off the TPU and what the kernel is held against."""
     s, h, d = q.shape
     wm = min(s, cfg.window)
     bq = _PREFILL_BLOCK if wm % _PREFILL_BLOCK == 0 else wm
     kept = cfg.window // cfg.chunk
     n_sum = kept * (s // wm - 1)        # what the last window's queries see
     ksum, vsum = ks[:n_sum], vs[:n_sum]
-    neg = neg_inf(jnp.float32)
+    neg = attention_ops.neg_inf(jnp.float32)
 
     def block(i):
         q0 = i * bq
@@ -259,8 +291,7 @@ def _prefill_attention(cfg: EvaByteConfig, q, k, v, ks, vs):
                                preferred_element_type=jnp.float32)
         return (o / total).transpose(1, 0, 2).astype(q.dtype)
 
-    with jax.named_scope("attn/eva_prefill"):
-        return jax.lax.map(block, jnp.arange(s // bq)).reshape(s, h, d)
+    return jax.lax.map(block, jnp.arange(s // bq)).reshape(s, h, d)
 
 
 def prefill_forward(params: Dict, cfg: EvaByteConfig, tokens, lengths):

@@ -127,6 +127,32 @@ def window_prefill_gate(n_head: int, n_kv_head: int, d: int, d_v: int,
     return None
 
 
+def _reset(low, m_scr, l_scr, acc_scr):
+    """Before a query block's first key tile: nothing seen yet."""
+    m_scr[...] = jnp.full(m_scr.shape, low, jnp.float32)
+    l_scr[...] = jnp.zeros(l_scr.shape, jnp.float32)
+    acc_scr[...] = jnp.zeros(acc_scr.shape, jnp.float32)
+
+
+def _fold(sc, v_ref, m_ref, l_ref, acc_ref):
+    """A key tile's (masked) float32 scores ``sc`` [bk, n], a (head, row)
+    a lane, into the running softmax of those lanes: ``m_ref``, ``l_ref``
+    [8, n] (the value in every sublane) and ``acc_ref`` [dv, n], with the
+    tile's values ``v_ref`` [dv, bk]. Maximum, exponent and sum in
+    float32, the weights cast to the values' type for the product.
+    ``eva_prefill.py`` folds its tiles by it too, a head at a time, after
+    the same :func:`_reset`."""
+    m_prev = m_ref[...]
+    m_next = jnp.maximum(m_prev, jnp.max(sc, axis=0, keepdims=True))
+    alpha = jnp.exp(m_prev - m_next)
+    p = jnp.exp(sc - m_next[:1])
+    l_ref[...] = alpha * l_ref[...] + jnp.sum(p, axis=0, keepdims=True)
+    acc_ref[...] = alpha[:1] * acc_ref[...] + jnp.dot(
+        v_ref[...], p.astype(v_ref.dtype),
+        preferred_element_type=jnp.float32)
+    m_ref[...] = m_next
+
+
 def _attn_kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
                  group, d, d_v, sm_scale, low, block_q, block_k, window):
     """One key tile of one query block of ONE KV head and its ``group``
@@ -142,11 +168,7 @@ def _attn_kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
     lo, hi = _band(i, block_q, block_k, window, jnp.maximum)
     row0, key0 = i * block_q, (lo + j) * block_k
 
-    @pl.when(j == 0)
-    def _():
-        m_scr[...] = jnp.full(m_scr.shape, low, f32)
-        l_scr[...] = jnp.zeros(l_scr.shape, f32)
-        acc_scr[...] = jnp.zeros(acc_scr.shape, f32)
+    pl.when(j == 0)(lambda: _reset(low, m_scr, l_scr, acc_scr))
 
     def tile(ok):
         """The band's tile ``lo + j`` into the group's running softmax,
@@ -159,14 +181,7 @@ def _attn_kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
                      preferred_element_type=f32) * sm_scale  # [bk, G bq]
         if ok is not None:
             sc = jnp.where(jnp.concatenate([ok] * group, axis=1), sc, low)
-        m_prev = m_scr[...]                                  # [8, G bq]
-        m_next = jnp.maximum(m_prev, jnp.max(sc, axis=0, keepdims=True))
-        alpha = jnp.exp(m_prev - m_next)
-        p = jnp.exp(sc - m_next[:1])
-        l_scr[...] = alpha * l_scr[...] + jnp.sum(p, axis=0, keepdims=True)
-        acc_scr[...] = alpha[:1] * acc_scr[...] + jnp.dot(
-            v_ref[...], p.astype(v_ref.dtype), preferred_element_type=f32)
-        m_scr[...] = m_next
+        _fold(sc, v_ref, m_scr, l_scr, acc_scr)
 
     # the band's two edges: the causal one crosses a tile that holds a key
     # past the block's first row, the window's one a tile whose first key

@@ -657,7 +657,7 @@ def test_serving_executables_leave_the_pool_in_place(chip, monkeypatch, exe):
     monkeypatch.setattr(attention_ops, "paged_kernel_mode",
                         lambda: "compiled")
     looped, compacting = exe.startswith("ouro"), exe.startswith("eva")
-    if looped:
+    if looped or compacting:
         monkeypatch.setattr(attention_ops, "_on_tpu", lambda: True)
     (fn, args), rows = (_ouro_case if looped else _eva_case if compacting
                         else _serve_case)(exe, chip)
@@ -698,8 +698,29 @@ def test_serving_executables_leave_the_pool_in_place(chip, monkeypatch, exe):
     if exe == "ouro_prefill":   # K and V of 4 steps x 4 layers x 512 rows
         g = OURO_SERVE
         limit = g["n_layer"] * g["steps"] * rows * 2048 * 2     # one pool
-    if exe == "eva_prefill":    # 4,096 rows' float32 residual, MLP halves
-        limit = 2 ** 30         # and a block's scores: half of ONE pool
+    if exe == "eva_prefill":
+        # the attention is ONE kernel call a layer under the scope the
+        # benchmark's readers tell it by, fed by the projections' and the
+        # rotation's own results: no ``copy`` or ``transpose`` turns q, k
+        # or v for it (row-major ``[S, H D]`` operands cost three)
+        made = {name: (op, operands)
+                for name, _, op, operands in instructions}
+        calls = [ln for ln in text.split("\n") if "tpu_custom_call" in ln]
+        assert len(calls) == EVA_SERVE["n_layer"]
+        for ln in calls:
+            name = ln.split(" = ")[0].strip().lstrip("%")
+            assert name.startswith("eva_prefill_attention")
+            assert "attn/eva_prefill" in ln
+            for operand in made[name][1][:3]:
+                while made[operand][0] in ("bitcast", "copy-done",
+                                           "copy-start"):  # views, prefetch
+                    operand = made[operand][1][0]
+                assert not re.match(r"(copy|transpose)", operand), ln
+                assert made[operand][0] not in ("copy", "transpose"), ln
+        # 4,096 rows' float32 residual (64 MiB), the MLP's two halves (86
+        # each), q, k, v and what is kept: 310 MiB read, where the blocked
+        # form's float32 scores of a block made it 359
+        limit = 336 * 2 ** 20
     assert compiled.memory_analysis().temp_size_in_bytes < limit
 
 

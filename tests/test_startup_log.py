@@ -92,6 +92,10 @@ def test_a_program_files_one_step_entry_and_nothing_on_its_second_run():
     exe = fluid.Executor(fluid.CPUPlace())
     feed = {"x": np.ones((2, 8), np.float32)}
     main, start, loss = _toy_program()
+    # a startup program runs op by op, and JAX keeps each such operation
+    # for the process: where an earlier test of this worker ran a twin
+    # (the same shapes), nothing would be built here and no entry filed
+    jax.clear_caches()
     t0 = time.perf_counter()
     exe.run(start)
     exe.run(main, feed=feed, fetch_list=[loss])
