@@ -354,7 +354,7 @@ def aot_compile(fn, abstract_args, donate_argnums=(), static_argnums=(),
     (call it with concrete arrays; ``donate_argnums`` buffers are consumed).
     ``label`` names the executable in the compile log
     (``compile_cache.log()``; the serving engine passes ``prefill[<bucket>]``,
-    ``chunk[fuse=<k>]``, ``verify[<width>]``, ``resume[<bucket>]``); without
+    ``chunk[fuse=<k>]``, ``resume[<bucket>]``); without
     one it is the function's own name.
     """
     jitted = jax.jit(fn, donate_argnums=donate_argnums,

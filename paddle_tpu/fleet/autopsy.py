@@ -55,9 +55,6 @@ _HINTS = {
                     "problem on the offending replica — look for "
                     "interference, injected faults, or an overloaded "
                     "host",
-    _phases.VERIFY: "speculation-bound: draft-verify windows dominate "
-                    "with low acceptance — lower draft k or disable "
-                    "speculation for this traffic",
     _phases.RETRY: "churn-bound: requeue gaps after replica loss — "
                    "check replica crash/restart history",
     _phases.TAIL: "tail-bound: drain/timeout tails past the last "

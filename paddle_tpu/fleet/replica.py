@@ -120,11 +120,7 @@ class SimEngine:
     # -- the engine contract --------------------------------------------------
     def submit(self, prompt, max_new_tokens, deadline_s=None,
                temperature=0.0, top_k=0, seed=None, trace_id=None,
-               attempt=0, speculation=None) -> Request:
-        # ``speculation`` is accepted for submit-surface parity with the
-        # real engine and ignored: the sim emits (seed, position)-keyed
-        # tokens directly, which is exactly the stream the speculative
-        # path would produce anyway
+               attempt=0) -> Request:
         if self._draining:
             raise DrainingError("sim engine is draining")
         if len(self._queue) >= self.cfg.max_queue:
@@ -406,8 +402,7 @@ class InProcessReplica:
                 temperature=rdoc.get("temperature", 0.0),
                 top_k=rdoc.get("top_k", 0), seed=rdoc.get("seed"),
                 trace_id=rdoc.get("trace_id"),
-                attempt=int(rdoc.get("attempt", 0)),
-                speculation=rdoc.get("speculation"))
+                attempt=int(rdoc.get("attempt", 0)))
         except DrainingError:
             self._events.append({"ev": "result", "id": rdoc["id"],
                                  "state": REJECTED, "kind": "draining"})

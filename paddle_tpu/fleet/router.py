@@ -90,8 +90,7 @@ class FleetRequest:
     identical sampled stream."""
 
     __slots__ = ("id", "prompt", "max_new_tokens", "deadline_s",
-                 "temperature", "top_k", "seed", "speculation", "state",
-                 "tokens", "error",
+                 "temperature", "top_k", "seed", "state", "tokens", "error",
                  "attempts", "last_replica", "submitted_t", "finished_t",
                  "trace_id", "dispatches", "dispatched_t", "queued_since",
                  "internal", "pin_replica", "no_migrate")
@@ -99,7 +98,7 @@ class FleetRequest:
     def __init__(self, rid: int, prompt: Sequence[int], max_new_tokens: int,
                  deadline_s: Optional[float] = None, temperature: float = 0.0,
                  top_k: int = 0, seed: Optional[int] = None,
-                 trace_id: Optional[str] = None, speculation=None):
+                 trace_id: Optional[str] = None):
         self.id = int(rid)
         self.prompt = [int(t) for t in prompt]
         self.max_new_tokens = int(max_new_tokens)
@@ -110,14 +109,6 @@ class FleetRequest:
         # ids differ between the first attempt and a requeued replay
         self.seed = (int(seed) if seed is not None
                      else (self.id * 1000003 + 0x5EED) & 0x7FFFFFFF)
-        # per-request speculative decoding override (None = inherit the
-        # replica engine's config; 0 = off; k; "auto" = tune table). The
-        # draft-verify path emits the same (seed, position)-keyed stream
-        # as plain decode, so a requeued replay stays bit-identical even
-        # if the respawned replica resolves a different k.
-        from ..serving.speculative import parse_speculation
-
-        self.speculation = parse_speculation(speculation)
         self.state = "queued"
         self.tokens: List[int] = []
         self.error: Optional[str] = None
@@ -159,8 +150,7 @@ class FleetRequest:
                 "max_new_tokens": self.max_new_tokens,
                 "deadline_s": self.deadline_s,
                 "temperature": self.temperature, "top_k": self.top_k,
-                "seed": self.seed, "speculation": self.speculation,
-                "trace_id": self.trace_id,
+                "seed": self.seed, "trace_id": self.trace_id,
                 "attempt": self.dispatches}
 
     def __repr__(self):
@@ -551,14 +541,10 @@ class Router:
     # -- submission -----------------------------------------------------------
     def submit(self, prompt: Sequence[int], max_new_tokens: int,
                deadline_s: Optional[float] = None, temperature: float = 0.0,
-               top_k: int = 0, seed: Optional[int] = None,
-               speculation=None) -> FleetRequest:
+               top_k: int = 0, seed: Optional[int] = None) -> FleetRequest:
         """Accept a request into the bounded queue. Raises
         :class:`FleetBackpressure` (typed, accounted) when full or
-        draining — the router never silently drops. ``speculation`` is
-        the per-request speculative-decoding override, carried on the
-        request doc to whichever replica (or replicas, across requeues)
-        serves it."""
+        draining — the router never silently drops."""
         if self._closed or self._draining:
             _fm.REJECTED.inc()
             raise FleetBackpressure("router is draining/closed")
@@ -568,7 +554,7 @@ class Router:
                 "fleet queue full (%d)" % self.cfg.max_queue)
         fr = FleetRequest(self._next_id, prompt, max_new_tokens,
                           deadline_s=deadline_s, temperature=temperature,
-                          top_k=top_k, seed=seed, speculation=speculation,
+                          top_k=top_k, seed=seed,
                           trace_id="fr%d-%d" % (self._seq, self._next_id))
         self._next_id += 1
         self._requests[fr.id] = fr

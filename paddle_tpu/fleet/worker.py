@@ -9,7 +9,7 @@ ops (router -> worker)::
     {"op": "spec", "spec": {...}}            # first frame only
     {"op": "submit", "id": <fleet id>, "prompt": [...],
      "max_new_tokens": n, "temperature": t, "top_k": k, "seed": s,
-     "deadline_s": d, "speculation": None|0|k|"auto"}
+     "deadline_s": d}
     {"op": "health"}                         # answered by a health event
     {"op": "clock"}                          # answered by a clock event
     {"op": "export_prefix", "xid", "tokens"}   # -> pages (binary) | miss
@@ -134,8 +134,7 @@ class _Worker:
                 temperature=op.get("temperature", 0.0),
                 top_k=op.get("top_k", 0), seed=op.get("seed"),
                 trace_id=op.get("trace_id"),
-                attempt=int(op.get("attempt", 0)),
-                speculation=op.get("speculation"))
+                attempt=int(op.get("attempt", 0)))
         except DrainingError:
             self.emit({"ev": "result", "id": op["id"], "state": REJECTED,
                        "kind": "draining"})

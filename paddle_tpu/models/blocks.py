@@ -12,7 +12,7 @@ only where the merged one needs no argument that says who calls it and the
 models' executables come out as they were: the rotary block stays two
 (:func:`rope` over a whole last axis, :func:`rope_lanes` over its first
 lanes with an attention factor). ``decoder_lm.py`` (GPT-2: LayerNorm,
-learned positions, a ``verify``) keeps its own blocks.
+learned positions) keeps its own blocks.
 
 The plain float32 statement each model is compared with is the benchmark's
 (``grid/reference/<model>.py``). Nothing here or in a model module imports
@@ -496,11 +496,7 @@ class ServedLM:
       without a histogram (a probe) rides to ``engine.last_decode_stats``
       only. A model over a compacting group calls, a layer,
       ``write_token``, ``open_chunk``, ``write_summary`` and
-      ``decode_attention``, and ``close_windows`` after its last layer;
-    * ``verify`` (optional; ``hasattr``): scores a window of drafted tokens
-      for speculative decoding. Absent, as on every model of this class,
-      every speculation setting resolves off: a ring, a latent row, a
-      recurrent state and a compacted window cannot be rolled back.
+      ``decode_attention``, and ``close_windows`` after its last layer.
 
     Of ``model.cfg`` the engine reads ``n_layer``, ``n_head`` (the QUERY
     heads: one number, or one a layer), ``d_head``, ``max_seq``, ``dtype``,
@@ -512,9 +508,9 @@ class ServedLM:
       layers, window, kind)`` or ``(name, layers, window, kind, chunk)``
       (a ``chunk`` makes a ``KV`` group COMPACTING: ``window`` is then a
       tumbling window, replaced by one summary a ``chunk`` positions when
-      it closes; over such a group the engine refuses speculative verify,
-      the prefix cache, the int8 pool and the contiguous layout, and page
-      export and import raise; absent: not compacting); the layers of a
+      it closes; over such a group the engine refuses the prefix cache,
+      the int8 pool and the contiguous layout, and page export and import
+      raise; absent: not compacting); the layers of a
       group share one ``n_head``
       (a group's decode attention is one kernel shape), ``window`` rows a
       slot are kept as a ring (None: every position, in pages), and the

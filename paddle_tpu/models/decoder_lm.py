@@ -27,7 +27,7 @@ from .. import compile_cache as _cc
 from ..ops import attention_ops
 
 __all__ = ["DecoderConfig", "DecoderLM", "init_params", "prefill_forward",
-           "decode_forward", "verify_forward", "reference_decode",
+           "decode_forward", "reference_decode",
            "reference_tokens"]
 
 
@@ -162,50 +162,10 @@ def decode_forward(params: Dict, cfg: DecoderConfig, cache, cache_ops,
     return x @ params["tok_emb"].T, cache
 
 
-def verify_forward(params: Dict, cfg: DecoderConfig, cache, cache_ops,
-                   tokens, pos, active, write_mask):
-    """Speculative verify window: ``decode_forward`` over W consecutive
-    positions per slot in ONE forward.
-
-    ``tokens`` [B,W] is each slot's window — position 0 its pending next
-    token, positions 1..W-1 the drafter's proposals; window position ``j``
-    sits at logical position ``pos[b] + j``. All W positions' K/V are
-    written BEFORE attention (the same write-then-attend order as decode),
-    gated per position by ``write_mask`` [B,W] — the engine masks writes
-    that would run past the slot's reservation (``gen + j >= max_new`` or
-    ``pos + j >= max_ctx``), because those positions' page-table entries
-    are unreserved and an unguarded scatter would land on another slot's
-    page. Attention dispatches through ``cache_ops.decode_verify`` (ragged
-    per-row lengths ``pos + 1 + j`` give in-window causality; 0 in every
-    row of a slot that is not ``active``), so the
-    layout again owns the gather-vs-fused-kernel choice. Returns (logits
-    [B,W,V], cache'). With W=1 and write_mask=active this is
-    ``decode_forward`` on the same math.
-    """
-    b, w = tokens.shape
-    posw = pos[:, None] + jnp.arange(w)[None, :]
-    pos_c = jnp.clip(posw, 0, cfg.max_seq - 1)
-    x = params["tok_emb"][tokens] + params["pos_emb"][pos_c]
-    for i, lp in enumerate(params["layers"]):
-        h = _ln(x, lp["ln1_g"], lp["ln1_b"])
-        q = (h @ lp["wq"]).reshape(b, w, cfg.n_head, cfg.d_head)
-        k = (h @ lp["wk"]).reshape(b, w, cfg.n_head, cfg.d_head)
-        v = (h @ lp["wv"]).reshape(b, w, cfg.n_head, cfg.d_head)
-        for jj in range(w):
-            cache = cache_ops.write_token(cache, i, k[:, jj], v[:, jj],
-                                          posw[:, jj], write_mask[:, jj])
-        o = cache_ops.decode_verify(cache, i, q, pos + 1, active,
-                                    sm_scale=cfg.sm_scale)
-        x = x + o.reshape(b, w, cfg.d_model) @ lp["wo"]
-        x = x + _ffn(_ln(x, lp["ln2_g"], lp["ln2_b"]), lp)
-    x = _ln(x, params["lnf_g"], params["lnf_b"])
-    return x @ params["tok_emb"].T, cache
-
-
 class DecoderLM:
     """Meets the serving contract (``models.blocks.ServedLM``'s docstring)
-    with methods of its own: a config + params pytree with ``prefill``,
-    ``decode`` and, alone among the served models, ``verify``."""
+    with methods of its own: a config + params pytree with ``prefill``
+    and ``decode``."""
 
     def __init__(self, cfg: DecoderConfig, params: Dict = None, seed: int = 0):
         self.cfg = cfg
@@ -217,11 +177,6 @@ class DecoderLM:
     def decode(self, params, cache, cache_ops, tokens, pos, active):
         return decode_forward(params, self.cfg, cache, cache_ops,
                               tokens, pos, active)
-
-    def verify(self, params, cache, cache_ops, tokens, pos, active,
-               write_mask):
-        return verify_forward(params, self.cfg, cache, cache_ops,
-                              tokens, pos, active, write_mask)
 
 
 def reference_decode(params: Dict, cfg: DecoderConfig, prompt,

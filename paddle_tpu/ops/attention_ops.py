@@ -456,34 +456,6 @@ def decode_attention(q, ctx_k, ctx_v, ctx_len, sm_scale=1.0):
     return jnp.einsum("bhl,blhd->bhd", probs, ctx_v)
 
 
-def verify_attention(q, ctx_k, ctx_v, ctx_len, sm_scale=1.0):
-    """Multi-position verify-window attention for speculative decode.
-
-    The k-token-window generalization of :func:`decode_attention`: ``q``
-    [B,W,H,D] holds the window's queries per slot (window position ``j``
-    is logical position ``ctx_len[b] - 1 + j`` — the caller wrote the
-    whole window's K/V first, exactly as decode writes before attending),
-    and ``ctx_len`` [B] counts valid positions INCLUDING window position
-    0 only. Causality inside the window falls out of per-row ragged
-    masking: row ``j`` sees ``ctx_len + j`` positions, i.e. everything up
-    to and including its own token, nothing after. Row 0 with W=1 is
-    :func:`decode_attention` (same masking, same softmax, same
-    :func:`neg_inf` constant — numerically equal; XLA batches the window
-    contraction differently, so low mantissa bits may move). The
-    speculative path's TOKEN bit-parity survives that: accept/sample
-    decisions are keyed draws over logit ranks, robust to contraction
-    order. Returns [B,W,H,D].
-    """
-    w = q.shape[1]
-    lens = ctx_len[:, None] + jnp.arange(w)[None, :]  # [B,W]
-    scores = jnp.einsum("bwhd,blhd->bwhl", q, ctx_k) * sm_scale
-    mask = (jnp.arange(ctx_k.shape[1])[None, None, None, :]
-            < lens[:, :, None, None])
-    scores = jnp.where(mask, scores, neg_inf(scores.dtype))
-    probs = jax.nn.softmax(scores, axis=-1)
-    return jnp.einsum("bwhl,blhd->bwhd", probs, ctx_v)
-
-
 @register_op("scaled_dot_product_attention")
 def sdpa_op(ctx: OpContext):
     q, k, v = ctx.input("Q"), ctx.input("K"), ctx.input("V")

@@ -37,7 +37,6 @@ from ..monitor import (device as _dev, slo as _slo, telemetry as _telemetry,
                        tracer as _tr)
 from ..reliability import faults as _faults
 from . import metrics as _sm
-from . import speculative as _speculative
 from . import trace as _trace
 from .kv_cache import (KV, LATENT, STATE, CacheGroup, ContiguousKVCache,
                        Int8PagedKVCache, LatentPagedCache, PagedKVCache)
@@ -68,8 +67,7 @@ def _pow2_buckets(lo: int, hi: int) -> tuple:
 
 def _sample_tokens(logits, temp, top_k, seed, position, live=None):
     """Device-side per-slot token selection, shared by the prefill
-    executable, the fused decode scan, the verify window and the resume
-    scan.
+    executable, the fused decode scan and the resume scan.
 
     ``logits`` [B,V]; ``temp``/``top_k``/``seed``/``position`` [B];
     ``live`` [B] bool, every row when None.
@@ -199,7 +197,6 @@ class _Dispatch(NamedTuple):
     tenants: list       # the requests that held the slots then: whom its
                         # tokens are for
     steps: int          # its fused steps
-    dlen: Any           # its draft lengths (a verify dispatch; else None)
     outs: list          # its outputs, still on the device
     t0: float           # the instant its launch began
 
@@ -233,19 +230,6 @@ class ServingConfig:
     falls back to the fp cache otherwise — serving must come up even with
     no calibration table on disk.
 
-    ``speculation`` arms speculative decoding (serving.speculative): the
-    engine-default draft k per scheduler tick — ``0`` off, a positive int
-    an explicit k (capped at ``speculative.SPEC_K_CAP``), ``"auto"`` the
-    autotuned k (tune table, kernel key ``serving.speculation_k``,
-    bucketed by slot count; ``speculation_source`` records which layer
-    answered — off/explicit/tuned/shipped/default — exactly like
-    ``decode_fuse_source``). ``None`` defers to the
-    ``PADDLE_TPU_SPECULATION`` env var (same grammar; unset means off).
-    ``spec_drafter`` names the drafter (``"ngram"`` — the zero-weight
-    prompt-lookup drafter). Per-request ``submit(speculation=...)``
-    overrides the default. Speculation silently disables when the model
-    lacks the ``verify`` contract method.
-
     Failure policy: ``decode_retries`` bounds in-place retries of a decode
     dispatch whose failure classifies as transient
     (:func:`paddle_tpu.reliability.faults.classify`); past the budget — or
@@ -275,7 +259,6 @@ class ServingConfig:
                  drain_timeout_s: float = 30.0,
                  kv_dtype: Optional[str] = None,
                  prefix_cache_pages: int = 0,
-                 speculation=None, spec_drafter: str = "ngram",
                  group_pages: Optional[Dict[str, int]] = None):
         if kv_dtype not in (None, "int8"):
             raise ValueError("kv_dtype must be None or 'int8', got %r"
@@ -322,19 +305,6 @@ class ServingConfig:
             raise ValueError(
                 "prefix_cache_pages=%d must leave serving pages free "
                 "(num_pages=%d)" % (self.prefix_cache_pages, self.num_pages))
-        from .speculative import parse_speculation
-
-        self.spec_drafter = str(spec_drafter)
-        if speculation is None:
-            speculation = os.environ.get("PADDLE_TPU_SPECULATION") or None
-        spec = parse_speculation(speculation)
-        if spec == "auto":
-            spec, self.speculation_source = self._tuned_speculation_k()
-        else:
-            self.speculation_source = "off" if not spec else "explicit"
-        self.speculation = max(0, int(spec or 0))
-        if self.speculation == 0:
-            self.speculation_source = "off"
 
     def _tuned_decode_fuse(self):
         """(value, source) from the autotuned config table; (1, "default")
@@ -343,15 +313,6 @@ class ServingConfig:
         from .. import tune
 
         return tune.resolve_decode_fuse(self.slots)
-
-    def _tuned_speculation_k(self):
-        """(value, source) for ``speculation="auto"`` from the autotuned
-        config table — same contract as :meth:`_tuned_decode_fuse`: a
-        missing/corrupt table yields the shipped-math default, never an
-        exception."""
-        from .. import tune
-
-        return tune.resolve_speculation_k(self.slots)
 
 
 def _layer_groups(mcfg):
@@ -393,12 +354,12 @@ def _query_groups(mcfg, layer_groups):
 class ServingEngine:
     """Drives a model under the serving contract, which is written ONCE,
     in ``models.blocks.ServedLM``'s docstring: the methods the engine calls
-    (``prefill`` or, where the model has it, ``prefill_last``; ``decode``;
-    ``verify`` where it has it) and what it reads of ``model.cfg``
-    (``n_layer``, ``n_head``, ``d_head``, ``max_seq``, ``dtype`` and, by
-    ``getattr``, ``n_kv_head``, ``cache_groups``, ``cache_steps``,
-    ``latent_row``, ``slot_state``, ``state_recurrence``, ``index_row``,
-    ``experts_held``), each with what its absence means.
+    (``prefill`` or, where the model has it, ``prefill_last``; ``decode``)
+    and what it reads of ``model.cfg`` (``n_layer``, ``n_head``,
+    ``d_head``, ``max_seq``, ``dtype`` and, by ``getattr``, ``n_kv_head``,
+    ``cache_groups``, ``cache_steps``, ``latent_row``, ``slot_state``,
+    ``state_recurrence``, ``index_row``, ``experts_held``), each with what
+    its absence means.
     Every ``getattr``/``hasattr`` on a model or its config in this module
     is one of those. The cache groups' kinds (``KV``, ``LATENT``,
     ``STATE``) are ``serving.kv_cache``'s; a model's decode ``stats`` go to
@@ -407,11 +368,9 @@ class ServingEngine:
     Over a cache of more than one group, over a latent cache (with or
     without a state group) and over a COMPACTING group
     (``serving.kv_cache``), the engine refuses, at construction, what
-    cannot work there: speculative verify (a ring and a compacted window
-    cannot be rolled back; a latent row is no K and V; a state has no
-    earlier value to return to), the int8 KV pool, the prefix cache (a
-    state has no snapshot at a page boundary; a compacted page no longer
-    holds the positions its place says) and the contiguous layout; page
+    cannot work there: the int8 KV pool, the prefix cache (a state has no
+    snapshot at a page boundary; a compacted page no longer holds the
+    positions its place says) and the contiguous layout; page
     export/import raise when called.
     """
 
@@ -520,22 +479,10 @@ class ServingEngine:
                     _sm.INDEX_POOL_BYTES.set(
                         self.cache_ops.index_bytes(self._cache))
             self._reset_slot_state()
-        b = self.cfg.slots
         self._prefill_exe: Dict[int, Any] = {}   # bucket -> AOT executable
         self._decode_exe: Dict[int, Any] = {}    # fuse length -> executable
         self._resume_exe: Dict[int, Any] = {}    # remainder bucket -> exe
-        self._verify_exe: Dict[int, Any] = {}    # window width -> executable
-        # speculative decoding: needs the contract's optional ``verify``
-        # method; without it every speculation knob silently resolves off
-        # (serving must come up on a decode-only model)
-        self._spec_capable = hasattr(model, "verify")
         self.last_decode_stats = None   # (tenants, stats) of the newest read
-        from .speculative import make_drafter
-
-        self._drafter = make_drafter(self.cfg.spec_drafter)
-        self._spec_k = np.zeros((b,), np.int32)  # per-slot resolved draft k
-        self._spec_auto: Optional[tuple] = None  # cached "auto" resolution
-        self._spec_enabled = False  # any slot ever armed with k > 0
         # prefix cache: host-side index of donated prompt-prefix KV pages
         # (paged layout only; see serving/prefix_cache.py)
         self.prefix_cache = None
@@ -631,9 +578,7 @@ class ServingEngine:
         for on, what in (
                 (not cfg.paged, "the contiguous layout (paged=False)"),
                 (cfg.kv_dtype == "int8", "the int8 KV pool"),
-                (cfg.prefix_cache_pages > 0, "the prefix cache"),
-                (cfg.speculation > 0 and hasattr(self.model, "verify"),
-                 "speculative verify")):
+                (cfg.prefix_cache_pages > 0, "the prefix cache")):
             if on:
                 raise ValueError("%s is not supported over %s" % (what, over))
 
@@ -693,8 +638,8 @@ class ServingEngine:
                deadline_s: Optional[float] = None,
                temperature: float = 0.0, top_k: int = 0,
                seed: Optional[int] = None,
-               trace_id: Optional[str] = None, attempt: int = 0,
-               speculation=None) -> Request:
+               trace_id: Optional[str] = None,
+               attempt: int = 0) -> Request:
         """Queue a request. Raises ``ValueError`` for a request that can
         NEVER be served at this geometry, and ``BackpressureError`` when
         the bounded queue is full (shed/retry — transient). ``deadline_s``
@@ -702,11 +647,7 @@ class ServingEngine:
         request is retired with TIMEOUT status (queued or running) so it
         stops pinning a slot and KV pages. ``temperature``/``top_k``/
         ``seed`` select device-side sampled decoding for THIS request (see
-        :class:`~.request.Request`); the default is exact greedy.
-        ``speculation`` overrides the engine's speculative-decoding
-        default for THIS request (``0`` off, int draft-k, ``"auto"`` the
-        tuned k, ``None`` inherit) — pure go-faster knob: the emitted
-        stream is bit-identical either way."""
+        :class:`~.request.Request`); the default is exact greedy."""
         if self._draining:
             _sm.DRAIN_REJECTED.inc()
             raise DrainingError(
@@ -714,8 +655,7 @@ class ServingEngine:
                 "requests — re-route to a peer")
         req = Request(prompt, max_new_tokens, deadline_s=deadline_s,
                       temperature=temperature, top_k=top_k, seed=seed,
-                      trace_id=trace_id, attempt=attempt,
-                      speculation=speculation)
+                      trace_id=trace_id, attempt=attempt)
         if req.prompt_len > self.cfg.prompt_buckets[-1]:
             raise ValueError(
                 "prompt length %d exceeds the largest prefill bucket %d"
@@ -880,22 +820,8 @@ class ServingEngine:
         folds = sorted(set(self.cache_ops.kernel_folds().values()))
         return "paged", "%s; fold: %s" % (src, "/".join(folds))
 
-    def speculation_info(self) -> tuple:
-        """``(k, drafter_kind, source)`` of the speculative fast path as
-        THIS engine resolves its default — the provenance twin of
-        :meth:`decode_kernel_info`: ``k`` is the engine-default draft
-        width (0 = off, including model-not-capable), ``drafter_kind``
-        names the proposer, ``source`` the answering layer
-        (off/explicit/tuned/shipped/default)."""
-        if not self._spec_capable:
-            return 0, "n/a", "off"
-        k = self.cfg.speculation
-        kind = self._drafter.kind if k > 0 else "off"
-        return k, kind, self.cfg.speculation_source
-
     def stats(self) -> dict:
         kern, kern_src = self.decode_kernel_info()
-        spec_k, spec_kind, spec_src = self.speculation_info()
         out = {
             "layout": self.cache_ops.layout,
             "queued": self.scheduler.queue_depth,
@@ -906,9 +832,6 @@ class ServingEngine:
                                           "explicit"),
             "decode_kernel": kern,
             "decode_kernel_source": kern_src,
-            "speculation": spec_k,
-            "spec_drafter": spec_kind,
-            "speculation_source": spec_src,
             # the layout actually serving (int8 requests silently fall back
             # to fp when uncalibrated — this is where that shows)
             "kv_layout": self.cache_ops.layout,
@@ -1250,10 +1173,10 @@ class ServingEngine:
     def _finish_prefill(self, req: Request, slot: int, tok: int,
                         last_logits) -> Optional[Request]:
         """What is the host's of an admission, shared by the cold and
-        prefix-hit paths: TTFT, the first token and its stamp, the slot's
-        draft width, immediate retirement. It launches nothing: the
-        executable armed the slot on the device (:func:`_arm_slot`), not
-        live where the request ends here."""
+        prefix-hit paths: TTFT, the first token and its stamp, immediate
+        retirement. It launches nothing: the executable armed the slot on
+        the device (:func:`_arm_slot`), not live where the request ends
+        here."""
         cfg = self.cfg
         _sm.TOKENS_GENERATED.inc()
         now = time.perf_counter()
@@ -1269,10 +1192,6 @@ class ServingEngine:
         if (cfg.eos_id is not None and tok == cfg.eos_id) \
                 or req.max_new_tokens == 1:
             return self._retire(slot)
-        k = self._request_spec_k(req)
-        self._spec_k[slot] = k
-        if k > 0:
-            self._spec_enabled = True
         return None
 
     # -- decode ---------------------------------------------------------------
@@ -1291,74 +1210,14 @@ class ServingEngine:
         jax.tree_util.tree_map(probe, self._cache)
         return lost
 
-    def _request_spec_k(self, req: Request) -> int:
-        """Resolve the draft k THIS request decodes with: per-request
-        override > engine default; ``"auto"`` goes through the tune table
-        once per engine (cached — admission must not pay a table read per
-        request). 0 when the model lacks the verify contract."""
-        if not self._spec_capable:
-            return 0
-        from .speculative import SPEC_K_CAP
-
-        s = req.speculation
-        if s is None:
-            return self.cfg.speculation
-        if s == "auto":
-            if self._spec_auto is None:
-                from .. import tune
-
-                self._spec_auto = tune.resolve_speculation_k(self.cfg.slots)
-            return min(max(0, int(self._spec_auto[0])), SPEC_K_CAP)
-        return min(max(0, int(s)), SPEC_K_CAP)
-
-    def _build_drafts(self):
-        """Host-side draft pass over the in-flight batch: ask the drafter
-        for up to k proposals per speculative slot (its full prompt +
-        generated history), capped at the slot's remaining emit budget —
-        a draft step past ``max_new``/``max_ctx`` could never be emitted.
-        Returns ``(draft [B,kmax], dlen [B], width)`` or None when no slot
-        proposed anything (the tick then takes the plain fused-decode
-        path — zero speculative overhead for non-speculative traffic)."""
-        if not self._spec_enabled:
-            return None
-        b = self.cfg.slots
-        props: Dict[int, List[int]] = {}
-        kmax = 0
-        for slot in range(b):
-            req = self.scheduler.slot_request(slot)
-            if req is None:
-                continue
-            k = int(self._spec_k[slot])
-            if k <= 0:
-                continue
-            gen = len(req.tokens_out)
-            ln = req.prompt_len + gen - 1
-            k = min(k, req.max_new_tokens - gen, self.cfg.max_seq - ln - 1)
-            if k <= 0:
-                continue
-            prop = self._drafter.propose(
-                list(req.prompt) + req.tokens_out, k)
-            if prop:
-                props[slot] = prop
-                kmax = max(kmax, len(prop))
-        if kmax == 0:
-            return None
-        draft = np.zeros((b, kmax), np.int32)
-        dlen = np.zeros((b,), np.int32)
-        for slot, prop in props.items():
-            draft[slot, :len(prop)] = prop
-            dlen[slot] = len(prop)
-        return draft, dlen, kmax + 1
-
     def _decode_dispatch(self) -> List[Request]:
-        """What one ``step()`` does about decoding. A plain dispatch
-        carries every per-slot state on the device and decides there who
-        finishes, so dispatch N+1 needs nothing of N that the host has to
-        read first: it is launched on N's outputs while N is unread, and
-        the host reads N's tokens while the device runs N+1
-        (:meth:`_decode_cycle`). N is read BEFORE anything is launched
-        where the engine can tell that N+1 would be built from N's tokens,
-        or would be empty (:meth:`_launches_ahead`)."""
+        """What one ``step()`` does about decoding. A dispatch carries
+        every per-slot state on the device and decides there who finishes,
+        so dispatch N+1 needs nothing of N that the host has to read first:
+        it is launched on N's outputs while N is unread, and the host reads
+        N's tokens while the device runs N+1 (:meth:`_decode_cycle`). N is
+        read BEFORE anything is launched where the engine can tell that
+        N+1 would be empty (:meth:`_launches_ahead`)."""
         finished: List[Request] = []
         if self._unread is not None and not self._launches_ahead(self._unread):
             finished = self._decode_cycle(launch=False)
@@ -1374,14 +1233,11 @@ class ServingEngine:
 
     def _launches_ahead(self, prev: _Dispatch) -> bool:
         """Whether the dispatch after ``prev`` is launched before ``prev``
-        is read. Not while a running slot drafts: a verify window is built
-        on the host from the tokens read so far. And not where, by the
-        host's own counts, every running request's budget
-        (``max_new_tokens``, ``max_seq``) ends in ``prev``: the next
-        dispatch would be empty. (An EOS the host cannot foresee may still
-        leave one dispatch with no live slot; its outputs are dropped.)"""
-        if self._a_slot_drafts():
-            return False
+        is read. Not where, by the host's own counts, every running
+        request's budget (``max_new_tokens``, ``max_seq``) ends in
+        ``prev``: the next dispatch would be empty. (An EOS the host cannot
+        foresee may still leave one dispatch with no live slot; its outputs
+        are dropped.)"""
         for slot, was in enumerate(prev.tenants):
             req = self.scheduler.slot_request(slot)
             if req is None:
@@ -1394,11 +1250,7 @@ class ServingEngine:
                 return True
         return False
 
-    def _a_slot_drafts(self) -> bool:
-        return self._spec_enabled and any(
-            self._spec_k[r.slot] > 0 for r in self.scheduler.running())
-
-    def _launch(self, exe, extra, steps: int, dlen) -> _Dispatch:
+    def _launch(self, exe, steps: int) -> _Dispatch:
         """Call ``exe`` on the per-slot state as it stands (the outputs of
         the dispatch before it, read or not; the cache stays donated) and
         start the host copies of its small outputs, so that a cycle later
@@ -1413,16 +1265,14 @@ class ServingEngine:
                     "injected pool exhaustion at serving.decode")
             out = exe(self.params, self._cache, self._len, self._tok,
                       self._active, self._gen, self._maxnew, self._temp,
-                      self._topk, self._seed, *extra)
+                      self._topk, self._seed)
             (self._cache, self._len, self._tok, self._active, self._gen,
              *outs) = out
             for x in jax.tree_util.tree_leaves(outs):
                 x.copy_to_host_async()
         _sm.SAMPLER_DISPATCHES[_sampler_tier(tenants)].inc()
-        # a verify window is ONE forward over every slot's window
-        self._count_expert_matmul(
-            self.cfg.slots * (1 if dlen is None else steps))
-        return _Dispatch(snap, tenants, steps, dlen, outs, launch.t0)
+        self._count_expert_matmul(self.cfg.slots)
+        return _Dispatch(snap, tenants, steps, outs, launch.t0)
 
     def _count_expert_matmul(self, n_tokens: int) -> None:
         form = _expert_matmul_form(self.model.cfg, n_tokens)
@@ -1461,10 +1311,8 @@ class ServingEngine:
         (``launch``; one more for each retry), then the sync that reads
         the dispatch launched a cycle ago, while the device runs the new
         one; ``serving/retire`` hands its tokens over. With nothing unread
-        the new dispatch is left unread and the span ends with the launch —
-        unless a slot drafts: the next window is then built from this
-        dispatch's tokens, so it is read here, as a verify dispatch always
-        is. With ``launch`` false the span is the sync alone.
+        the new dispatch is left unread and the span ends with the launch.
+        With ``launch`` false the span is the sync alone.
 
         The recovery ladder: transient failures retry in place (bounded by
         ``decode_retries``); a failure that exhausts the budget — or
@@ -1475,35 +1323,18 @@ class ServingEngine:
         was; one that surfaces at the sync rolls it back to what the
         dispatch being read was launched on (:meth:`_roll_back`), the
         dispatch launched ahead of it is abandoned unread, and a retry
-        launches the failed one again and reads it at once.
-
-        With speculation armed and the drafter proposing, the launch is
-        the verify executable instead: ONE windowed forward over each
-        slot's (pending token + draft) window, per-step accept/rollback
-        on device — up to k+1 tokens per dispatch, bit-identical stream
-        (serving.speculative). Rollback is free under the worst-case page
-        reservation: rejected positions sit beyond the rolled-back
-        ``ctx_len``, masked out of every later read until overwritten."""
+        launches the failed one again and reads it at once."""
         prev, self._unread = self._unread, None
-        drafts = self._build_drafts() if launch else None
-        if drafts is not None:
-            draft_np, dlen_np, steps = drafts
-            exe = self._get_verify_exe(steps)
-            extra = (jnp.asarray(draft_np), jnp.asarray(dlen_np))
-        else:
-            dlen_np = None
-            steps = self.cfg.decode_fuse
-            exe = self._get_decode_exe(steps)
-            extra = ()
-        read_now = prev is None and self._a_slot_drafts()
+        steps = self.cfg.decode_fuse
+        exe = self._get_decode_exe(steps)
+        read_now = False
         attempt = 0
-        with _span("serving/decode", steps=steps,
-                   kind="plain" if dlen_np is None else "verify") as window:
+        with _span("serving/decode", steps=steps) as window:
             while True:
                 cur = read = None
                 try:
                     if launch:
-                        cur = self._launch(exe, extra, steps, dlen_np)
+                        cur = self._launch(exe, steps)
                         if prev is not None:
                             _sm.DECODE_LAUNCHED_AHEAD.inc()
                     read = prev if prev is not None \
@@ -1567,26 +1398,14 @@ class ServingEngine:
         live = [req if req is not None
                 and self.scheduler.slot_request(slot) is req else None
                 for slot, req in enumerate(d.tenants)]
-        spec_args = None
-        if d.dlen is not None:
-            # accepted drafts per slot = its run-steps beyond the first
-            # (step 0 consumes the pending token, never a draft)
-            runs = emitted.sum(axis=0)
-            proposed = int(d.dlen.sum())
-            accepted = int(np.maximum(runs - 1, 0).sum())
-            spec_args = _speculative.verify_window_args(steps, proposed,
-                                                        accepted)
-        _trace.on_decode_chunk(live, steps, t0, t1, spec=spec_args)
+        _trace.on_decode_chunk(live, steps, t0, t1)
         # the dispatch's own latency, not the cycle's interval: a host-bound
         # loop launches in a fraction of its cycle, and what a caller paces
         # itself by (an SLO, a load generator's lateness) is how long a
         # dispatch takes to give its tokens
         _sm.DECODE_STEP_MS.observe((t1 - d.t0) * 1e3)
         _sm.DECODE_DISPATCHES.inc()
-        # a verify dispatch is ONE windowed model step however wide the
-        # window — DECODE_STEPS keeps meaning "model forwards", so
-        # tokens/steps > 1 is exactly the speculative win
-        _sm.DECODE_STEPS.inc(1 if d.dlen is not None else steps)
+        _sm.DECODE_STEPS.inc(steps)
         if stats is not None:
             # the newest dispatch's stats as read, with who held its slots:
             # what a caller may look at beside the histograms (a name the
@@ -1597,13 +1416,6 @@ class ServingEngine:
                 if hist is not None:
                     for x in xs.reshape(-1):
                         hist.observe(float(x))
-        if d.dlen is not None:
-            _sm.SPEC_PROPOSED.inc(proposed)
-            _sm.SPEC_ACCEPTED.inc(accepted)
-            _sm.SPEC_REJECTED.inc(proposed - accepted)
-            _sm.SPEC_DRAFTS.inc(int((d.dlen > 0).sum()))
-            _sm.SPEC_VERIFY_DISPATCHES.inc()
-            _sm.SPEC_ACCEPT_RATE.observe(accepted / max(1, proposed))
         finished: List[Request] = []
         handed = 0
         clock = self.prefill_clock(t1)
@@ -1752,20 +1564,15 @@ class ServingEngine:
                          "prompt_len": req.prompt_len,
                          "generated": len(req.tokens_out),
                          "max_new_tokens": req.max_new_tokens,
-                         "spec_k": int(self._spec_k[slot]),
                          "pages": list(req.pages)})
         kern, kern_src = self.decode_kernel_info()
-        spec_k, spec_kind, spec_src = self.speculation_info()
         return {"layout": self.cache_ops.layout, "slots": rows,
                 "queue_depth": self.scheduler.queue_depth,
                 "decode_fuse": self.cfg.decode_fuse,
                 "decode_fuse_source": getattr(self.cfg, "decode_fuse_source",
                                               "explicit"),
                 "decode_kernel": kern,
-                "decode_kernel_source": kern_src,
-                "speculation": spec_k,
-                "spec_drafter": spec_kind,
-                "speculation_source": spec_src}
+                "decode_kernel_source": kern_src}
 
     # -- AOT compilation ------------------------------------------------------
     def _get_prefill_exe(self, bucket: int):
@@ -1867,100 +1674,6 @@ class ServingEngine:
         self._decode_exe[fuse] = exe
         return exe
 
-    def _get_verify_exe(self, width: int):
-        """The speculative draft-verify step, compiled once per window
-        width (k+1 — the dict is bounded by ``speculative.SPEC_K_CAP``).
-
-        One windowed model forward scores every slot's window — position
-        0 its pending token, positions 1..k its draft — then a scan
-        replays the plain decode chunk's EXACT per-step state machine
-        over the window's target draws: step j emits
-        ``_sample_tokens(logits_j, ..., position=len+j)`` (the same
-        keying plain decode would use at that step), advances len/gen,
-        applies the same eos/max_new/max_ctx fin logic, and continues
-        speculatively only while the NEXT consumed token (the draft)
-        equals this step's emitted one. Equality-accept against the
-        target's own position-keyed draw is exact speculative sampling
-        for a deterministic drafter (serving.speculative), so both the
-        greedy and the seeded-sampled stream are bit-identical to plain
-        decode. A rejected tail simply never advances ``len`` — its
-        KV rows sit beyond every later read mask until overwritten —
-        and slots with an empty draft degrade to one plain step inside
-        the same dispatch. Output shape contract matches the decode
-        chunk (outs stacked [width, B]), so the host retire loop is
-        shared."""
-        exe = self._verify_exe.get(width)
-        if exe is not None:
-            return exe
-        model, ops, cfg = self.model, self.cache_ops, self.cfg
-        eos = -1 if cfg.eos_id is None else cfg.eos_id
-        max_ctx = cfg.max_seq
-        collect = cfg.collect_logits
-        w = width
-
-        def verify(params, cache, lengths, tokens, active, gen, maxnew,
-                   temp, topk, seed, draft, dlen):
-            b = tokens.shape[0]
-            steps = jnp.arange(w, dtype=jnp.int32)
-            cons = jnp.concatenate([tokens[:, None], draft], axis=1)
-            posw = lengths[:, None] + steps[None, :]
-            # guard every window write to the positions plain decode could
-            # itself reach (step j exists iff gen+j < max_new and
-            # len+j < max_ctx): beyond them the slot's page table holds
-            # UNRESERVED entries (parked on page 0) and an unguarded
-            # scatter would land on another slot's page
-            write_mask = (active[:, None]
-                          & (gen[:, None] + steps[None, :] < maxnew[:, None])
-                          & (posw < max_ctx))
-            logits, cache = model.verify(params, cache, ops, cons, lengths,
-                                         active, write_mask)
-            # the target's own draw at every window position, keyed by the
-            # SAME (seed, absolute position) as plain decode — [B,W] rows
-            # through the [B*W]-batched sampler are per-row identical
-            tt = _sample_tokens(
-                logits.reshape(b * w, -1), jnp.repeat(temp, w),
-                jnp.repeat(topk, w), jnp.repeat(seed, w),
-                posw.reshape(b * w), jnp.repeat(active, w)).reshape(b, w)
-            # token consumed by step j+1 (draft j); dummy past the window
-            nxt_cons = jnp.concatenate(
-                [draft, jnp.zeros((b, 1), jnp.int32)], axis=1)
-
-            def body(carry, xs):
-                ln, tk, ac, sp, gc = carry
-                if collect:
-                    tj, dj, j, lg = xs
-                else:
-                    tj, dj, j = xs
-                run = ac & sp
-                nxt = jnp.where(run, tj, tk)
-                emitted = run
-                gc = gc + run
-                ln = ln + run
-                fin = run & ((nxt == eos) | (gc >= maxnew) | (ln >= max_ctx))
-                ac = ac & ~fin
-                sp = sp & (j < dlen) & (nxt == dj) & ~fin
-                out = (nxt, emitted, fin, lg) if collect \
-                    else (nxt, emitted, fin)
-                return (ln, nxt, ac, sp, gc), out
-
-            xs = (tt.T, nxt_cons.T, steps)
-            if collect:
-                xs = xs + (logits.transpose(1, 0, 2),)
-            spec0 = jnp.ones((b,), jnp.bool_)
-            (lengths, tokens, active, _, gen), outs = jax.lax.scan(
-                body, (lengths, tokens, active, spec0, gen), xs)
-            return (cache, lengths, tokens, active, gen) + tuple(outs)
-
-        exe = aot_compile(
-            verify,
-            (self.params, self._cache, self._len, self._tok, self._active,
-             self._gen, self._maxnew, self._temp, self._topk, self._seed,
-             jax.ShapeDtypeStruct((cfg.slots, w - 1), jnp.int32),
-             jax.ShapeDtypeStruct((cfg.slots,), jnp.int32)),
-            donate_argnums=(1,), label="verify[%d]" % width)
-        self._verify_exe[width] = exe
-        return exe
-
     def _get_resume_exe(self, rbucket: int):
         """Teacher-forced prompt-remainder ingest for a prefix-cache hit:
         consume the uncached prompt tail token by token through the
@@ -2032,5 +1745,3 @@ class ServingEngine:
         for b in (buckets or self.cfg.prompt_buckets):
             self._get_prefill_exe(self._bucket_for(b))
         self._get_decode_exe(self.cfg.decode_fuse)
-        if self._spec_capable and self.cfg.speculation > 0:
-            self._get_verify_exe(self.cfg.speculation + 1)

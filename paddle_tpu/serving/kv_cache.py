@@ -143,9 +143,8 @@ A request of n positions needs ``ceil(((W/c) (n // W) + min(n, W)) /
 page_size) + W / (c page_size)`` pages (:meth:`PagedKVCache.pages_needed`),
 not ``n / page_size``. What cannot work over such a group is refused as
 over a ring, and for one more reason: a compacted window cannot be rolled
-back, and a page no longer holds the positions its place says (speculative
-verify, the prefix cache, the int8 pool, the contiguous layout, page export
-and import).
+back, and a page no longer holds the positions its place says (the prefix
+cache, the int8 pool, the contiguous layout, page export and import).
 
 Both write paths scatter with ``mode="drop"`` on out-of-bounds destination
 rows, so inactive slots / padding positions are dropped INSIDE the compiled
@@ -193,14 +192,12 @@ class CacheGroup(NamedTuple):
 Cache = Dict[str, jnp.ndarray]
 
 
-def _live_len(ctx_len, active, window: int = 1):
+def _live_len(ctx_len, active):
     """The lengths a decode step's attention is given: ``ctx_len`` [B]
-    where a slot is ``active``, and elsewhere the length at which none of
-    the slot's ``window`` rows (row j attends over ``length + j``) sees a
-    row: 0 for the one row of a plain step. The engine leaves a retired
+    where a slot is ``active``, and 0 elsewhere. The engine leaves a retired
     slot's length where its request ended, so without this every layer of
     every step would stream that request's whole context for nobody."""
-    return jnp.where(active, ctx_len, 1 - window)
+    return jnp.where(active, ctx_len, 0)
 
 
 def open_window_start(length, rows: int, window: int):
@@ -440,8 +437,8 @@ class PagedKVCache(_KVCacheBase):
 
     def _single_group(self, what: str) -> None:
         """What needs ONE group of K and V rows, a position's row where
-        the position says (speculative verify, the int8 pool, page copies,
-        export and import), is refused elsewhere."""
+        the position says (the int8 pool, page copies, export and import),
+        is refused elsewhere."""
         if self.groups[0].chunk is not None:
             raise ValueError(
                 "%s is not supported over a compacting group (%s: a closed "
@@ -770,41 +767,6 @@ class PagedKVCache(_KVCacheBase):
         return attention_ops.decode_attention(q, ctx_k, ctx_v, length,
                                               sm_scale=sm_scale)
 
-    def decode_verify(self, state: Cache, layer: int, q, ctx_len,
-                      active, sm_scale: float = 1.0) -> jnp.ndarray:
-        """Speculative verify-window attention [B,W,H,D] over this layer's
-        ragged contexts (window position j = logical position ctx_len-1+j;
-        the caller wrote all W positions' K/V first; every window row of a
-        slot that is not ``active`` has length 0). Rides the SAME ragged
-        Pallas kernel as ``decode_attention`` by flattening the window into
-        B*W pseudo-slots — each window row replays its slot's page table
-        with length ctx_len+j, which is exactly the per-slot raggedness the
-        kernel already handles; no kernel change, one dispatch. The XLA
-        gather + ops.attention_ops.verify_attention path stays the parity
-        reference (one ``context`` gather serves all W rows). One group
-        only: a ring cannot be rolled back."""
-        from ..ops import attention_ops
-
-        self._single_group("speculative verify")
-        b, w = q.shape[0], q.shape[1]
-        live = _live_len(ctx_len, active, w)
-        mode, _ = self.kernel_mode()
-        if mode is not None:
-            from ..ops.pallas_kernels import paged_attention as _pa
-
-            lens = live[:, None] + jnp.arange(w)[None, :]
-            lens = jnp.clip(lens.reshape(b * w), 0, self.max_ctx)
-            out = _pa.paged_decode_attention(
-                q.reshape(b * w, self.n_head, self.d_head),
-                state["k"], state["v"],
-                jnp.repeat(state["pt"], w, axis=0), lens,
-                page_size=self.page_size, layer=layer, sm_scale=sm_scale,
-                interpret=(mode == "interpret"))
-            return out.reshape(b, w, self.n_head, self.d_head)
-        ctx_k, ctx_v = self.context(state, layer)
-        return attention_ops.verify_attention(q, ctx_k, ctx_v, live,
-                                              sm_scale=sm_scale)
-
     # -- a state group's decode step -----------------------------------------
     def state_kernel_mode(self):
         """:meth:`kernel_mode`'s twin for the state groups' decode step:
@@ -1060,11 +1022,11 @@ class Int8PagedKVCache(PagedKVCache):
     fp32, which under the PagePool's unchanged reservation math doubles
     (resp. quadruples) the page capacity of the same byte budget.
 
-    ``decode_attention``/``decode_verify`` always take the gather path
-    (``kernel_mode`` says so): the ragged Pallas kernel reads raw pool rows
-    and has no dequant stage, so the kernel dispatch is bypassed rather
-    than fed garbage — both decode paths (fused decode scan and
-    prefill-side attention) dequantize through ``context``.
+    ``decode_attention`` always takes the gather path (``kernel_mode``
+    says so): the ragged Pallas kernel reads raw pool rows and has no
+    dequant stage, so the kernel dispatch is bypassed rather than fed
+    garbage — both decode paths (fused decode scan and prefill-side
+    attention) dequantize through ``context``.
     """
 
     layout = "paged-int8"
@@ -1182,8 +1144,8 @@ class LatentPagedCache(PagedKVCache):
     the kernel of ops/pallas_kernels/mla_attention.py, by the same flag
     as the paged kernel; a window group's call carries the kernel name
     ``mla_latent_decode_ring`` in a device trace). What needs a K and a V
-    row (speculative verify, the int8 pool, page export and import, with
-    them the prefix cache) is refused by the paged cache's own rule:
+    row (the int8 pool, page export and import, with them the prefix
+    cache) is refused by the paged cache's own rule:
     nobody needs it yet."""
 
     layout = "paged-latent"
@@ -1528,15 +1490,6 @@ class ContiguousKVCache(_KVCacheBase):
         that this layout is."""
         return {"attn_rows_read.global": jnp.sum(
             _live_len(ctx_len, active)).astype(jnp.int32)}
-
-    def decode_verify(self, state: Cache, layer: int, q, ctx_len,
-                      active, sm_scale: float = 1.0) -> jnp.ndarray:
-        from ..ops import attention_ops
-
-        ctx_k, ctx_v = self.context(state, layer)
-        return attention_ops.verify_attention(
-            q, ctx_k, ctx_v, _live_len(ctx_len, active, q.shape[1]),
-            sm_scale=sm_scale)
 
     def prompt_dest(self, slot: int) -> np.int32:
         return np.int32(slot)

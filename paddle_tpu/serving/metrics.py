@@ -23,8 +23,6 @@ __all__ = [
     "TPOT_MS", "PREFILL_STALL_MS_PER_TOKEN",
     "FAULTS", "RETRIES", "TIMEOUTS", "REQUESTS_FAILED",
     "DRAINS", "DRAINED_REQUESTS", "DRAIN_REJECTED",
-    "SPEC_PROPOSED", "SPEC_ACCEPTED", "SPEC_REJECTED", "SPEC_DRAFTS",
-    "SPEC_VERIFY_DISPATCHES", "SPEC_ACCEPT_RATE",
     "MOE_EXPERTS_TOUCHED", "MOE_MAX_EXPERT_ROWS", "MOE_HELD_PAIRS",
     "STATE_SLOTS_STEPPED", "STATE_POOL_BYTES", "LATENT_RING_BYTES",
     "EVA_CHUNKS_CLOSED", "EVA_WINDOWS_CLOSED",
@@ -163,26 +161,6 @@ DRAIN_REJECTED = _mx.counter(
     help="requests rejected because the engine was draining (typed "
          "DrainingError at submit, plus queued requests shed at drain "
          "start)")
-SPEC_PROPOSED = _mx.counter(
-    "serving/spec_proposed_tokens",
-    help="draft tokens proposed to speculative verify dispatches")
-SPEC_ACCEPTED = _mx.counter(
-    "serving/spec_accepted_tokens",
-    help="draft tokens accepted by the target model (each one is a decode "
-         "step the engine did not have to dispatch)")
-SPEC_REJECTED = _mx.counter(
-    "serving/spec_rejected_tokens",
-    help="draft tokens rejected (or cut by eos/budget) and rolled back — "
-         "their KV rows sit beyond ctx_len until overwritten")
-SPEC_DRAFTS = _mx.counter(
-    "serving/spec_drafts",
-    help="non-empty per-slot drafts submitted to verify dispatches")
-SPEC_VERIFY_DISPATCHES = _mx.counter(
-    "serving/spec_verify_dispatches",
-    help="decode dispatches that took the speculative verify-window path")
-SPEC_ACCEPT_RATE = _mx.histogram(
-    "serving/spec_accept_rate",
-    help="per-dispatch accepted/proposed draft-token ratio, 0..1")
 MOE_EXPERTS_TOUCHED = _mx.histogram(
     "serving/moe_experts_touched",
     help="experts that received at least one live row, one observation a "

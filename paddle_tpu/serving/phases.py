@@ -20,10 +20,7 @@ Phase taxonomy — every microsecond of a request's life lands in one of:
 * ``ship``      — KV-page migration windows (export → binary ship →
   ingest) attributed to the requests the migration served; ``cause`` is
   the migration purpose (``disagg``/``remote_hit``/``rebalance``/...).
-* ``decode``    — plain fused decode dispatches the request rode.
-* ``verify``    — speculative draft-verify windows (a decode dispatch
-  through the verify executable); args carry the accepted-k attribution
-  (``proposed``/``accepted``) the ledger accumulates per request.
+* ``decode``    — fused decode dispatches the request rode.
 * ``retry``     — requeue gaps: a replica died or rejected, the request
   sat re-queued until its next dispatch (fleet queued span, attempt>=2).
 * ``tail``      — the drain/timeout tail: time between the last dispatch
@@ -43,7 +40,7 @@ from __future__ import annotations
 from typing import Dict, List, Optional, Sequence
 
 __all__ = [
-    "QUEUE", "ADMISSION", "PREFILL", "SHIP", "DECODE", "VERIFY", "RETRY",
+    "QUEUE", "ADMISSION", "PREFILL", "SHIP", "DECODE", "RETRY",
     "TAIL", "PHASES",
     "PhaseInterval", "RequestLedger", "ledgers_from_spans",
 ]
@@ -53,11 +50,10 @@ ADMISSION = "admission"
 PREFILL = "prefill"
 SHIP = "ship"
 DECODE = "decode"
-VERIFY = "verify"
 RETRY = "retry"
 TAIL = "tail"
 
-PHASES = (QUEUE, ADMISSION, PREFILL, SHIP, DECODE, VERIFY, RETRY, TAIL)
+PHASES = (QUEUE, ADMISSION, PREFILL, SHIP, DECODE, RETRY, TAIL)
 
 _SERVING_TERMINALS = {"retired": "finished", "FAILED": "failed",
                       "TIMEOUT": "timeout", "rejected": "rejected"}
@@ -117,8 +113,6 @@ class RequestLedger:
         self.measured_ttft_ms: Optional[float] = None
         self.measured_latency_ms: Optional[float] = None
         self.attempts: int = 0
-        self.spec_proposed: int = 0
-        self.spec_accepted: int = 0
 
     def add(self, iv: PhaseInterval) -> None:
         self.intervals.append(iv)
@@ -182,9 +176,6 @@ class RequestLedger:
                           if self.e2e_ms() is not None else None),
                "ttft": self.ttft_decomposition(),
                "intervals": [iv.to_doc() for iv in self.intervals]}
-        if self.spec_proposed:
-            doc["speculation"] = {"proposed": self.spec_proposed,
-                                  "accepted": self.spec_accepted}
         return doc
 
 
@@ -233,12 +224,8 @@ def _build(trace_id: str, mine: Sequence[dict],
                     PREFILL, t0, t0 + dur, cause=args.get("cause", "local"),
                     replica=replica, attempt=attempt, src="serving"))
             elif name == "decode":
-                phase = VERIFY if args.get("phase") == VERIFY else DECODE
-                if phase == VERIFY:
-                    led.spec_proposed += int(args.get("proposed", 0) or 0)
-                    led.spec_accepted += int(args.get("accepted", 0) or 0)
                 led.add(PhaseInterval(
-                    phase, t0, t0 + dur, cause=args.get("cause"),
+                    DECODE, t0, t0 + dur, cause=args.get("cause"),
                     replica=replica, attempt=attempt, src="serving",
                     args=args))
             elif name.startswith("req "):
@@ -283,7 +270,7 @@ def _build(trace_id: str, mine: Sequence[dict],
         lo = int(life.get("ts_us", 0))
         hi = lo + int(life.get("dur_us", 0) or 0)
         last = max((iv.t1_us for iv in led.intervals
-                    if iv.phase in (PREFILL, DECODE, VERIFY)
+                    if iv.phase in (PREFILL, DECODE)
                     and lo <= iv.t0_us and iv.t1_us <= hi), default=lo)
         if hi > last:
             args = life.get("args") or {}

@@ -65,12 +65,6 @@ class Request:
     keyed by absolute context position, which makes replays reproducible
     across ``decode_fuse`` widths and slot re-admissions.
 
-    ``speculation`` overrides the engine's speculative-decoding default
-    for this request: ``None`` inherit, ``0`` off, a positive int the
-    draft k, ``"auto"`` the tune-table k (serving.speculative). A pure
-    scheduling knob — the emitted stream is bit-identical either way, so
-    replays (fleet requeues) need not pin it.
-
     ``timeline`` is the request's own record of when its tokens reached
     it: one entry a HAND-OVER, ``(t, n, prefill_clock_s)``: the
     ``time.perf_counter`` instant, ``len(tokens_out)`` after it, and the
@@ -91,15 +85,14 @@ class Request:
                  "group_pages",
                  "tokens_out", "submitted_t", "admitted_t", "first_token_t",
                  "finished_t", "deadline_s", "error", "trace_id", "attempt",
-                 "temperature", "top_k", "seed", "speculation",
+                 "temperature", "top_k", "seed",
                  "timeline", "prefill_s")
 
     def __init__(self, prompt: Sequence[int], max_new_tokens: int,
                  deadline_s: Optional[float] = None,
                  temperature: float = 0.0, top_k: int = 0,
                  seed: Optional[int] = None,
-                 trace_id: Optional[str] = None, attempt: int = 0,
-                 speculation=None):
+                 trace_id: Optional[str] = None, attempt: int = 0):
         if len(prompt) == 0:
             raise ValueError("Request needs a non-empty prompt")
         if max_new_tokens < 1:
@@ -142,9 +135,6 @@ class Request:
         # id-derived default: distinct per request, stable for replay when
         # the caller pins one explicitly
         self.seed = int(self.id if seed is None else seed) & 0x7FFFFFFF
-        from .speculative import parse_speculation
-
-        self.speculation = parse_speculation(speculation)
 
     @property
     def prompt_len(self) -> int:

@@ -104,25 +104,21 @@ def on_prefill(req, slot: int, bucket: int, t0_s: float, t1_s: float,
 
 
 def on_decode_chunk(reqs_by_slot: Sequence, fuse: int, t0_s: float,
-                    t1_s: float, spec: Optional[dict] = None) -> None:
+                    t1_s: float) -> None:
     """One fused decode dispatch: a ``decode`` span on EVERY occupied
     slot's track (same wall window — that is the point: Perfetto shows
     which requests shared the dispatch). ``reqs_by_slot[k]`` is the
-    request in slot k or None. A speculative verify dispatch passes
-    ``spec`` (``serving.speculative.verify_window_args``): the span is
-    tagged phase ``verify`` and carries the accepted-k attribution the
-    phase ledger accumulates per request."""
+    request in slot k or None."""
     if not _tr.active():
         return
     ts, dur = _us(t0_s), _us(t1_s) - _us(t0_s)
-    extra = dict(spec, phase="verify") if spec else {"phase": "decode"}
     for slot, req in enumerate(reqs_by_slot):
         if req is None:
             continue
         _tr.record_span(
             "decode", ts, dur, cat=CAT, track=slot_track(slot),
             args=_targs(req, steps=fuse, pages_held=len(req.pages),
-                        generated=len(req.tokens_out), **extra))
+                        generated=len(req.tokens_out), phase="decode"))
 
 
 def on_terminal(req, state: str, slot: Optional[int]) -> None:

@@ -40,7 +40,6 @@ from .table import (  # noqa: F401
     resolve_decode_fuse,
     resolve_fleet_roles,
     resolve_fleet_router,
-    resolve_speculation_k,
     shipped_path,
     table_path,
 )
@@ -51,7 +50,6 @@ __all__ = [
     "device_kind", "normalize_device_kind", "pow2_floor",
     "lookup", "record", "table_path", "shipped_path",
     "resolve_decode_fuse", "resolve_fleet_roles", "resolve_fleet_router",
-    "resolve_speculation_k",
     "provenance_snapshot", "reset_provenance",
     "SearchResult", "median_time_ms", "search",
     "Tunable", "register_tunable", "get_tunable", "registered_tunables",

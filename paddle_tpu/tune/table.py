@@ -52,7 +52,6 @@ __all__ = [
     "table_path", "shipped_path", "entry_key",
     "lookup", "record", "read_entries", "write_entries",
     "resolve_decode_fuse", "resolve_fleet_roles", "resolve_fleet_router",
-    "resolve_speculation_k",
     "provenance_snapshot", "reset_provenance",
 ]
 
@@ -344,23 +343,6 @@ def resolve_decode_fuse(slots: int) -> Tuple[int, str]:
     except Exception:
         pass
     return 1, "default"
-
-
-def resolve_speculation_k(slots: int) -> Tuple[int, str]:
-    """(draft k, source) for speculative decoding on a serving engine with
-    ``slots`` batch slots — the resolution behind
-    ``ServingConfig(speculation="auto")``, mirroring
-    :func:`resolve_decode_fuse`. The useful k trades verify-window compute
-    against acceptance decay, so the table keys it per (slot bucket,
-    device kind). (4, "default") on no entry or any table failure:
-    serving must come up even with a corrupt table."""
-    try:
-        cfg, src = lookup("serving.speculation_k", bucket_slots(slots))
-        if cfg and int(cfg.get("k", 0)) > 0:
-            return int(cfg["k"]), src
-    except Exception:
-        pass
-    return 4, "default"
 
 
 def resolve_fleet_router(cpus: Optional[int] = None

@@ -94,18 +94,16 @@ def poisoned_latent_pool():
 @pytest.fixture
 def attention_spy(monkeypatch):
     """What the attention of every decode step is given, for engines built
-    inside the test: wraps the caches' ``decode_attention`` /
-    ``decode_verify`` (which see each slot's length as the engine keeps it
-    and the ``active`` mask) and the three functions that consume a length
-    (the gather path's ``decode_attention`` and ``verify_attention``, the
-    kernel's entry). Call the fixture's value after driving an engine: a
-    list of ``(active [B], kept [B], rows [B, W])`` a call, ``kept`` the
-    engine's lengths and ``rows`` the rows each slot's W window positions
-    attend over, CHECKED: 0 rows for every slot that is not active, at
+    inside the test: wraps the caches' ``decode_attention`` (which sees
+    each slot's length as the engine keeps it and the ``active`` mask) and
+    the two functions that consume a length (the gather path's
+    ``decode_attention``, the kernel's entry). Call the fixture's value
+    after driving an engine: a list of ``(active [B], kept [B], rows [B, 1])``
+    a call, ``kept`` the engine's lengths and ``rows`` the rows each slot
+    attends over, CHECKED: 0 rows for every slot that is not active, at
     least its own token for every one that is, and some inactive slot did
     keep the length of a request that left (or the case shows nothing)."""
     import jax
-    import jax.numpy as jnp
 
     from paddle_tpu.ops import attention_ops
     from paddle_tpu.ops.pallas_kernels import paged_attention as pa
@@ -137,13 +135,9 @@ def attention_spy(monkeypatch):
         monkeypatch.setattr(mod, name, fn)
 
     for cls in (kv_cache.PagedKVCache, kv_cache.ContiguousKVCache):
-        for name in ("decode_attention", "decode_verify"):
-            spy_method(cls, name)
+        spy_method(cls, "decode_attention")
     spy_consumer(attention_ops, "decode_attention",
                  lambda q, k, v, n: n)
-    spy_consumer(attention_ops, "verify_attention",
-                 lambda q, k, v, n: jnp.maximum(
-                     n[:, None] + jnp.arange(q.shape[1])[None, :], 0))
     spy_consumer(pa, "paged_decode_attention",
                  lambda q, k, v, pt, n: n)
 

@@ -241,7 +241,6 @@ PAGED_SHAPES = {
     "gpt2_small_f32": (8, 12, 64, 16, 64, jnp.float32),
     "gpt2_small_bf16": (8, 12, 64, 16, 64, jnp.bfloat16),
     "heads4_d32": (8, 4, 32, 16, 16, jnp.float32),
-    "speculative_window": (8 * 5, 12, 64, 16, 64, jnp.float32),
     "tiny_test_model": (4, 2, 16, 8, 8, jnp.float32),
 }
 
@@ -397,8 +396,7 @@ def test_paged_kernel_at_the_cells_shapes_and_shipped_waves(chip, cell):
 # -- the engine's executables at gpt2-small-serve's geometry -------------------
 
 SERVE = dict(slots=32, page_size=16, num_pages=2048, max_seq=1024,
-             n_layer=12, n_head=12, d_model=768, vocab=50257, bucket=256,
-             window=5)
+             n_layer=12, n_head=12, d_model=768, vocab=50257, bucket=256)
 
 
 def _pick(logits, sampling, position, live=None):
@@ -442,7 +440,7 @@ def _serve_case(name, chip, n_layer=None):
 
     params = abstract(lambda: decoder_lm.init_params(cfg, 0))
     cache = abstract(ops.init_state)
-    b, w = g["slots"], g["window"]
+    b = g["slots"]
     ints, flags = sds((b,), jnp.int32), sds((b,), jnp.bool_)
 
     def chunk(params, cache, lengths, tokens, active, *sampling):
@@ -454,11 +452,6 @@ def _serve_case(name, chip, n_layer=None):
 
         return jax.lax.scan(body, (cache, lengths, tokens, active), None,
                             length=1)
-
-    def verify(params, cache, lengths, window, active, write_mask):
-        logits, cache = model.verify(params, cache, ops, window, lengths,
-                                     active, write_mask)
-        return cache, jnp.argmax(logits, -1)
 
     def prefill(params, cache, dest, prompt, length, *sampling):
         logits, kvs = model.prefill(params, prompt[None], length[None])
@@ -500,8 +493,6 @@ def _serve_case(name, chip, n_layer=None):
                                   sds((b,), jnp.float32), ints, ints)),
         "prefill_sampler": (prefill, prefill_args + (
             sds((), jnp.float32), scalar, scalar)),
-        "verify": (verify, (params, cache, ints, sds((b, w), jnp.int32),
-                            flags, sds((b, w), jnp.bool_))),
         "prefill": (prefill, prefill_args),
         "prefill_armed": (prefill_armed, (
             params, cache, (ints, ints, flags, ints, ints,
@@ -636,12 +627,11 @@ def _eva_case(name, chip):
     }[name], ops.num_rows
 
 
-@pytest.mark.parametrize("exe", ["chunk", "verify", "prefill",
-                                 "prefill_armed", "resume", "ouro_chunk",
-                                 "ouro_prefill", "eva_chunk",
-                                 "eva_prefill"])
+@pytest.mark.parametrize("exe", ["chunk", "prefill", "prefill_armed",
+                                 "resume", "ouro_chunk", "ouro_prefill",
+                                 "eva_chunk", "eva_prefill"])
 def test_serving_executables_leave_the_pool_in_place(chip, monkeypatch, exe):
-    """The decode chunk, the verify window, a prefill bucket (alone, and as
+    """The decode chunk, a prefill bucket (alone, and as
     the admission the engine launches: with the slot's page table and its
     per-slot state written in the same program) and the resume
     scan write the 1.2 GB pool where it lies and hand it to the kernel

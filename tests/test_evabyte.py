@@ -139,8 +139,7 @@ def test_a_compacting_group_states_its_geometry():
     state = ops.init_state()
     for call in (lambda: ops.export_pages(state, [1]),
                  lambda: ops.copy_pages(state, jnp.asarray([1]),
-                                        jnp.asarray([2])),
-                 lambda: ops.decode_verify(state, 0, None, None, None)):
+                                        jnp.asarray([2]))):
         with pytest.raises(ValueError, match="compacting group"):
             call()
 

@@ -364,22 +364,17 @@ def test_a_layer_stands_once_in_a_kind_of_group():
 
 
 @pytest.mark.parametrize("kw,what", [
-    (dict(speculation=2), None),          # no verify method: resolves off
     (dict(kv_dtype="int8"), "int8 KV pool"),
     (dict(prefix_cache_pages=4), "prefix cache"),
     (dict(paged=False), "contiguous layout"),
 ])
 def test_what_this_cache_cannot_do_is_refused_at_construction(toy, kw, what):
     """What the engine refuses over groups it refuses here too."""
-    if what is None:
-        with _engine(toy, **kw) as eng:
-            assert eng.speculation_info()[0] == 0
-        return
     with pytest.raises(ValueError, match=what + ".*cache with 2 groups"):
         _engine(toy, **kw)
 
 
-def test_page_export_and_verify_are_refused_over_this_cache(toy):
+def test_page_export_is_refused_over_this_cache(toy):
     with _engine(toy) as eng:
         for call, what in (
                 (lambda: eng.cache_ops.export_pages(eng._cache, [0]),
@@ -387,10 +382,7 @@ def test_page_export_and_verify_are_refused_over_this_cache(toy):
                 (lambda: eng.cache_ops.import_pages(eng._cache, [0], {}, []),
                  "page import"),
                 (lambda: eng.cache_ops.copy_pages(eng._cache, None, None),
-                 "page copy"),
-                (lambda: eng.cache_ops.decode_verify(eng._cache, 0, None,
-                                                     None, None),
-                 "speculative verify")):
+                 "page copy")):
             with pytest.raises(ValueError, match=what + ".*ssm: state"):
                 call()
 

@@ -375,35 +375,25 @@ class TestRouter:
 
         assert json.loads(json.dumps(d)) == d  # frame-protocol safe
 
-    def test_fleet_request_doc_carries_speculation(self):
-        """The per-request speculation override rides the wire frame —
-        parsed at the router (so a bad value fails at submit, not on a
-        replica), JSON-safe in every accepted form."""
-        import json
+    def test_a_frame_key_no_replica_reads_is_ignored(self):
+        """A submit frame from an older router may still carry
+        ``speculation``: the replica serves it as a frame without the key."""
+        from paddle_tpu.fleet.replica import InProcessReplica
 
-        assert FleetRequest(8, [1, 2], 4,
-                            speculation="auto").doc()["speculation"] == "auto"
-        assert FleetRequest(9, [1], 4).doc()["speculation"] is None
-        assert FleetRequest(10, [1], 4,
-                            speculation="off").doc()["speculation"] == 0
-        d = FleetRequest(11, [1], 4, speculation=64).doc()
-        assert isinstance(d["speculation"], int)  # capped, still an int
-        assert json.loads(json.dumps(d)) == d
-        with pytest.raises(ValueError):
-            FleetRequest(12, [1], 4, speculation=-3)
-
-    def test_sim_replica_accepts_speculative_submits(self):
-        """Sim engines ignore speculation but must accept the doc field —
-        a fleet mixing sim and real replicas routes the same wire form to
-        both."""
-        router = _sim_router(n=1)
-        fr = router.submit([5, 5, 5], 4, speculation="auto")
-        assert router.wait_all(20.0)
-        assert fr.state == "finished" and len(fr.tokens) == 4
-        router.close()
+        rep = InProcessReplica(SimEngine(SimConfig(slots=2)))
+        doc = FleetRequest(3, [5, 5, 5], 4, seed=9).doc()
+        rep.submit(dict(doc, speculation=4))
+        rep.submit(dict(doc, id=4))
+        results = {}
+        for _ in range(50):
+            results.update((e["id"], e) for e in rep.poll()
+                           if e["ev"] == "result")
+            if len(results) == 2:
+                break
+        assert results[3]["state"] == results[4]["state"] == "finished"
+        assert results[3]["tokens"] == results[4]["tokens"]
 
 
-# -- telemetry aggregation ----------------------------------------------------
 class TestAggregateTelemetry:
     def test_merges_replica_rings(self, tmp_path):
         from paddle_tpu.monitor import metrics as mx
@@ -601,125 +591,6 @@ class TestFleetTrace:
                    for d in digests.values())
         trace_ids = {f.trace_id for f in frs}
         assert set(digests) == trace_ids
-
-
-# -- speculative requests through the fleet (real engines) --------------------
-class TestFleetSpeculative:
-    @staticmethod
-    def _real_router(model, n=2):
-        from paddle_tpu import serving
-
-        def factory(i):
-            return serving.ServingEngine(model, serving.ServingConfig(
-                slots=2, page_size=8, max_seq=64))
-
-        return Router(FleetConfig(replicas=n, mode="inprocess",
-                                  affinity="round_robin",
-                                  engine_factory=factory))
-
-    def test_kill_replays_speculative_bit_identical(self, tiny_model):
-        """A speculative request stranded by a killed replica must
-        requeue and replay BIT-identically to an unkilled twin: greedy
-        draft-verify emits the same (seed, position)-keyed stream as
-        plain decode, so the fleet's replay invariant holds unchanged
-        even when the respawned replica re-runs the whole request."""
-        import numpy as np
-
-        rng = np.random.RandomState(5)
-        prompts = [list(rng.randint(0, 64, 3)) * 4 for _ in range(4)]
-        req0 = fm.REQUEUED.value
-        router = self._real_router(tiny_model)
-        frs = [router.submit(p, 6, speculation=4) for p in prompts]
-        for _ in range(2):
-            router.pump()
-        router._replicas[1].kill()
-        assert router.wait_all(120.0)
-        assert set(router.accounting().values()) == {"finished"}
-        assert fm.REQUEUED.value > req0, "the kill stranded nothing"
-        router.close()
-        twin = self._real_router(tiny_model, n=1)
-        frs_t = [twin.submit(p, 6, speculation=4) for p in prompts]
-        assert twin.wait_all(120.0)
-        twin.close()
-        assert [f.tokens for f in frs] == [f.tokens for f in frs_t], \
-            "a requeued speculative replay diverged from its unkilled twin"
-
-    def test_speculative_verify_spans_nest_in_decode_windows(
-            self, tiny_model, tmp_path):
-        """Trace-validator leg for the speculation/autopsy join: a traced
-        speculative fleet run must emit verify-tagged decode spans
-        (phase=verify, accepted <= proposed accounting) that nest inside
-        BOTH the request's serving lifetime span and the fleet attempt
-        (dispatch) window — the containment the phase ledger relies on to
-        attribute verify windows per request."""
-        import numpy as np
-
-        from paddle_tpu import serving
-        from paddle_tpu.fleet import trace as ftrace
-        from paddle_tpu.serving import trace as svtrace
-
-        trace_dir = str(tmp_path / "trace")
-
-        def factory(i):
-            return serving.ServingEngine(tiny_model, serving.ServingConfig(
-                slots=2, page_size=8, max_seq=64))
-
-        # ONE replica: two traced in-process engines would collide on the
-        # shared "serving slot <k>" virtual tracks
-        router = Router(FleetConfig(replicas=1, mode="inprocess",
-                                    affinity="round_robin",
-                                    engine_factory=factory,
-                                    trace_dir=trace_dir))
-        rng = np.random.RandomState(7)
-        prompts = [list(rng.randint(0, 64, 3)) * 4 for _ in range(3)]
-        frs = [router.submit(p, 6, speculation=4) for p in prompts]
-        assert router.wait_all(120.0)
-        router.close()
-
-        spans, manifest, problems = ftrace.load_fragments(trace_dir)
-        assert not problems and manifest.get("run_id")
-        digests = ftrace.validate_fleet_spans(spans)
-        assert digests.pop("_meta")["synthetic_closures"] == 0
-        # the serving-cat schedule is well-nested across the merged stream
-        svtrace.assert_well_nested(spans)
-
-        verify = [s for s in spans
-                  if s.get("cat") == "serving" and s["name"] == "decode"
-                  and (s.get("args") or {}).get("phase") == "verify"]
-        assert verify, "speculative run emitted no verify-tagged spans"
-        for s in verify:
-            a = s["args"]
-            assert a.get("verify") is True, a
-            assert 0 <= a["accepted"] <= a["proposed"], a
-            assert a.get("window", 0) >= 1, a
-        assert sum(s["args"]["proposed"] for s in verify) > 0
-
-        life = {(s.get("args") or {}).get("trace_id"):
-                (s["ts_us"], s["ts_us"] + s["dur_us"])
-                for s in spans
-                if s.get("cat") == "serving" and s["name"].startswith("req ")}
-        attempts = {((s.get("args") or {}).get("trace_id"),
-                     (s.get("args") or {}).get("attempt")):
-                    (s["ts_us"], s["ts_us"] + s["dur_us"])
-                    for s in spans
-                    if s.get("cat") == "fleet"
-                    and s["name"].startswith("attempt ")}
-        seen = set()
-        for s in verify:
-            a = s["args"]
-            tid = a["trace_id"]
-            seen.add(tid)
-            lo, hi = s["ts_us"], s["ts_us"] + s["dur_us"]
-            llo, lhi = life[tid]
-            assert llo <= lo and hi <= lhi, \
-                "verify span [%d,%d] escapes lifetime [%d,%d] of %s" \
-                % (lo, hi, llo, lhi, tid)
-            alo, ahi = attempts[(tid, a.get("attempt", 1))]
-            assert alo <= lo and hi <= ahi, \
-                "verify span [%d,%d] escapes attempt window [%d,%d] of %s" \
-                % (lo, hi, alo, ahi, tid)
-        assert seen == {f.trace_id for f in frs}, \
-            "some speculative request decoded without a verify window"
 
 
 # -- engine-level prefix cache (real model) -----------------------------------

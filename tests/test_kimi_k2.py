@@ -465,30 +465,22 @@ def test_the_cache_holds_one_latent_row_a_token_and_no_v_pool(toy):
 
 
 @pytest.mark.parametrize("kw,what", [
-    (dict(speculation=2), None),          # no verify method: resolves off
     (dict(kv_dtype="int8"), "int8 KV pool"),
     (dict(prefix_cache_pages=4), "prefix cache"),
     (dict(paged=False), "contiguous layout"),
 ])
 def test_what_a_latent_cache_cannot_do_is_refused_at_construction(toy, kw,
                                                                   what):
-    if what is None:
-        with _engine(toy, **kw) as eng:
-            assert eng.speculation_info()[0] == 0
-        return
     with pytest.raises(ValueError, match=what + ".*latent cache"):
         _engine(toy, **kw)
 
 
-def test_page_export_and_verify_are_refused_over_a_latent_cache(toy):
+def test_page_export_is_refused_over_a_latent_cache(toy):
     with _engine(toy) as eng:
         for call, what in (
                 (lambda: eng.cache_ops.export_pages(eng._cache, [0]),
                  "page export"),
                 (lambda: eng.cache_ops.import_pages(eng._cache, [0], {}, []),
-                 "page import"),
-                (lambda: eng.cache_ops.decode_verify(eng._cache, 0, None,
-                                                     None, None),
-                 "speculative verify")):
+                 "page import")):
             with pytest.raises(ValueError, match=what):
                 call()

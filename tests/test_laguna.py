@@ -442,16 +442,11 @@ def test_a_closed_gate_halves_attention(toy, rng):
 
 
 @pytest.mark.parametrize("kw,what", [
-    (dict(speculation=2), None),          # no verify method: resolves off
     (dict(kv_dtype="int8"), "int8 KV pool"),
     (dict(prefix_cache_pages=4), "prefix cache"),
     (dict(paged=False), "contiguous layout"),
     (dict(group_pages={"ring": 4}), "group_pages names"),
 ])
 def test_what_two_groups_cannot_do_is_refused_at_construction(toy, kw, what):
-    if what is None:
-        with _engine(toy, **kw) as eng:
-            assert eng.speculation_info()[0] == 0
-        return
     with pytest.raises(ValueError, match=what):
         _engine(toy, **kw)

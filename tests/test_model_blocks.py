@@ -287,7 +287,6 @@ def test_prefill_last_is_the_last_row_of_prefill(module, cls):
     assert type(model) is served and issubclass(served, blocks.ServedLM)
     for name in ("prefill", "prefill_last", "decode", "__init__"):
         assert name not in vars(served), name      # written once, in blocks
-    assert not hasattr(model, "verify")            # speculation resolves off
     rng = np.random.RandomState(3)
     tokens = jnp.asarray(rng.randint(0, model.cfg.vocab_size, (2, 16)),
                          jnp.int32)

@@ -141,7 +141,7 @@ def test_step_records_the_tree(case, host_tracer):
     assert by_name["serving/step"]["args"] == {
         "cycle": steps_before + 1, "occupancy": min(steps_before, 1),
         "queue": 0 if steps_before else 1}
-    assert by_name["serving/decode"]["args"]["kind"] == "plain"
+    assert by_name["serving/decode"]["args"] == {"steps": 1}
     if case == "one_admission":
         assert by_name["serving/prefill"]["args"]["trace_id"] == req.trace_id
         assert by_name["serving/prefill"]["args"]["cause"] == "local"

@@ -133,16 +133,14 @@ def test_paged_vs_contiguous_bit_parity(rng):
             assert np.array_equal(x, y), "paged logits diverged bitwise"
 
 
-@pytest.mark.parametrize("kernel,speculation", [
-    ("off", 0), ("interpret", 0), ("off", 3), ("interpret", 3)])
+@pytest.mark.parametrize("kernel", ["off", "interpret"])
 def test_a_retired_slot_streams_nothing_and_moves_no_live_token(
-        rng, attention_spy, kernel, speculation):
+        rng, attention_spy, kernel):
     """Requests with long contexts finish in slots 0 and 2 while two with
     short prompts decode on in slots 1 and 3. From then on the attention is
     given length 0 for the two retired slots (the engine's ``_len`` stays
     where their requests ended), by the gather path and by the interpreted
-    kernel, in plain decode steps and in the speculative verify window; and
-    the survivors' tokens and logits are those of a run in which no other
+    kernel; and the survivors' tokens and logits are those of a run in which no other
     slot was ever used."""
     from paddle_tpu.flags import set_flag
 
@@ -154,7 +152,7 @@ def test_a_retired_slot_streams_nothing_and_moves_no_live_token(
     def drive(stream):
         eng = serving.ServingEngine(get_model(),
                                     small_config(collect_logits=True))
-        reqs = [eng.submit(p, m, speculation=speculation) for p, m in stream]
+        reqs = [eng.submit(p, m) for p, m in stream]
         eng.run()
         out = [(r.tokens_out, eng.captured_logits(r)) for r in reqs]
         assert eng.page_accounting_ok()
@@ -164,13 +162,10 @@ def test_a_retired_slot_streams_nothing_and_moves_no_live_token(
     set_flag("paged_attention_kernel", kernel)
     try:
         mixed = drive([leavers[0], stayers[0], leavers[1], stayers[1]])
-        calls = attention_spy()
+        attention_spy()
         alone = drive(stayers)
     finally:
         set_flag("paged_attention_kernel", "auto")
-    # W > 1 only in the verify window: the case did go through it
-    assert (max(rows.shape[1] for _, _, rows in calls) > 1) == (
-        speculation > 0)
     for (toks, logits), (toks_alone, logits_alone) in zip(mixed[1::2], alone):
         assert toks == toks_alone
         for x, y in zip(logits, logits_alone):
@@ -652,34 +647,6 @@ def test_a_failure_that_surfaces_at_the_read_abandons_the_one_launched_ahead(
     assert reqs[1].tokens_out == want[1] and eng.health()["status"] == "ok"
 
 
-def test_nothing_is_launched_ahead_while_a_slot_drafts(rng):
-    """A verify window is built on the host from the tokens read so far:
-    while a speculative request runs, every dispatch is read in the cycle
-    that launches it. Once it has retired the plain request beside it is
-    launched ahead again; both streams are the non-speculative ones."""
-    motif = list(rng.randint(0, 64, 3))
-    stream = [(motif * 3, 10, 3), (list(rng.randint(0, 64, 9)), 30, 0)]
-
-    def drive(speculate):
-        eng = serving.ServingEngine(get_model(), small_config(slots=2))
-        reqs = [eng.submit(p, m, speculation=k if speculate else 0)
-                for p, m, k in stream]
-        during = None
-        base = _ahead_counts()
-        while not eng.scheduler.idle():
-            eng.step()
-            if during is None and reqs[0].state == "finished":
-                during = (_ahead_counts() - base)[0]
-        after = (_ahead_counts() - base)[0]
-        eng.close()
-        return [r.tokens_out for r in reqs], during, after
-
-    spec, during, after = drive(True)
-    plain, _, plain_ahead = drive(False)
-    assert spec == plain
-    assert during == 0 and after > 0 and plain_ahead > after
-
-
 @pytest.mark.parametrize("how", ["run", "drain", "close", "exit"])
 def test_no_dispatch_is_left_unread_and_no_token_lost(how, rng):
     model = get_model()
@@ -1120,17 +1087,13 @@ def test_a_compacting_group_is_served_end_to_end(rng):
 
 
 @pytest.mark.parametrize("what,over", [
-    ("speculative verify", dict(speculation=2)),
     ("the prefix cache", dict(prefix_cache_pages=4)),
     ("the int8 KV pool", dict(kv_dtype="int8")),
     ("the contiguous layout", dict(paged=False))])
 def test_what_a_compacting_group_refuses_says_why(what, over):
     """At construction, with the reason: a compacted window cannot be
-    rolled back and a page no longer holds the positions its place says.
-    (The model has no ``verify``; one that had is refused the same way.)"""
+    rolled back and a page no longer holds the positions its place says."""
     model = _compacting_model()
-    if what == "speculative verify":
-        model.verify = lambda *a: None
     with pytest.raises(ValueError, match="%s.* is not supported over .*"
                        "compact" % re.escape(what)):
         serving.ServingEngine(model, serving.ServingConfig(

@@ -390,17 +390,12 @@ def test_admission_waits_for_the_global_group_with_a_slot_free(toy):
 
 
 @pytest.mark.parametrize("kw,what", [
-    (dict(speculation=2), None),          # no verify method: resolves off
     (dict(kv_dtype="int8"), "int8 KV pool"),
     (dict(prefix_cache_pages=4), "prefix cache"),
     (dict(paged=False), "contiguous layout"),
     (dict(group_pages={"ring": 4}), "group_pages names"),
 ])
 def test_what_two_groups_cannot_do_is_refused_at_construction(toy, kw, what):
-    if what is None:
-        with _engine(toy, **kw) as eng:
-            assert eng.speculation_info()[0] == 0
-        return
     with pytest.raises(ValueError, match=what):
         _engine(toy, **kw)
 
@@ -409,8 +404,6 @@ def test_page_export_is_refused_over_two_groups(toy):
     with _engine(toy) as eng:
         with pytest.raises(ValueError, match="page export"):
             eng.cache_ops.export_pages(eng._cache, [0])
-        with pytest.raises(ValueError, match="speculative verify"):
-            eng.cache_ops.decode_verify(eng._cache, 0, None, None, None)
 
 
 def test_one_group_is_the_same_cache(rng):

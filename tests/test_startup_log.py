@@ -142,8 +142,8 @@ def test_aot_compile_counts_one_a_compile_and_names_it():
     t0 = time.perf_counter()
     args = (jax.ShapeDtypeStruct((4,), jnp.float32),)
     aot_compile(double, args)
-    aot_compile(double, args, label="verify[3]")
-    assert [e["name"] for e in _since(t0)] == ["double", "verify[3]"]
+    aot_compile(double, args, label="resume[8]")
+    assert [e["name"] for e in _since(t0)] == ["double", "resume[8]"]
     assert hist.count == count + 2
 
 

@@ -382,12 +382,7 @@ def selftest() -> int:
                  "reliability/feed_errors",
                  "serving/faults", "serving/retries", "serving/timeouts",
                  "serving/requests_failed", "serving/drains",
-                 "serving/drained_requests", "serving/drain_rejected",
-                 "serving/spec_proposed_tokens",
-                 "serving/spec_accepted_tokens",
-                 "serving/spec_rejected_tokens", "serving/spec_drafts",
-                 "serving/spec_verify_dispatches",
-                 "serving/spec_accept_rate"):
+                 "serving/drained_requests", "serving/drain_rejected"):
         assert name in snap, "missing instrument %s" % name
     metrics.reset()
 
