@@ -27,11 +27,28 @@ pair routed to an expert that lives on another chip contributes nothing
 here (that chip adds its part), so the one-chip share of a wider
 deployment is this function with a shorter ``held``. On one chip ``held``
 is every expert. A share computes its own pairs only: the sorted pairs go
-through the grouped matmul in chunks of a bound derived from the share
-(twice the rows an even router would send it), so a 12-of-384 share of an
-8,192-token prefill gathers 4,096 rows and not the 65,536 of which 31 in
-32 belong to absent experts; a router that sends it more makes the loop
-run again, and nothing is dropped.
+through the grouped matmul in passes of a bound derived from the share
+(:func:`pass_rows`), so a 12-of-384 share of a 4,096-token prefill gathers
+1,280 rows and not the 32,768 of which 31 in 32 belong to absent experts; a
+router that sends it more makes the loop run again, and nothing is dropped.
+A DECODE pass (at most ``STREAM_ROWS`` rows) holds twice an even router's
+load and adds its weighed rows to their tokens by a scatter-add. A PREFILL
+pass holds the even load and a quarter of it (``PASS_MARGIN``) and gives
+its rows back in the form :func:`combine_form` chooses from the part of
+all pairs it is:
+
+* ``gather`` where the pass is a quarter of the pairs or more (Laguna's
+  half share, Ling's quarter): the permutation is inverted once in front
+  of the loop and every pair fetches its row of the pass's result, in the
+  result's type; a token's ``k`` rows are then weighed and added in
+  float32 in ONE fusion over ``k`` slabs of ``[n, d]``. ``n k`` rows are
+  gathered a pass whatever it holds, and there is no float32 ``[rows,
+  d]`` and no scatter: the compiler makes a scatter-add of unsorted
+  indices a sort, a gather of the float32 updates into sorted order and a
+  sorted scatter, 13.3 of the 30.8 ms that a Laguna layer's 81,920-row
+  pass took on the chip (PERF.md section 6, PR 61);
+* ``scatter`` below that (GLM's eighth, Motif's sixteenth, Kimi's
+  thirty-second), the decode pass's form.
 
 The routing rule and the experts' activation are the caller's:
 :func:`route_topk` (softmax over the chosen logits) or
@@ -59,7 +76,7 @@ import numpy as np
 from . import attention_ops
 
 __all__ = ["route_topk", "route_sigmoid_topk", "expert_layer", "held_pairs",
-           "pass_rows", "matmul_form", "STREAM_ROWS"]
+           "pass_rows", "matmul_form", "combine_form", "STREAM_ROWS"]
 
 # Rows of a pass up to which the experts' weights, not the arithmetic, bound
 # the grouped product: no served cell has a pass between 257 and 1,024 rows
@@ -67,6 +84,33 @@ __all__ = ["route_topk", "route_sigmoid_topk", "expert_layer", "held_pairs",
 # 512 rows over 12 or more experts are still far under the chip's ridge of
 # 240 rows an expert.
 STREAM_ROWS = 512
+
+# What a prefill's pass holds over an even router's load. The grouped matmul
+# all but skips the tiles past its last group (a Laguna layer's gate and up
+# read 8.95 ms at 81,920 and at 51,200 rows of which 41,027 are live, the
+# down product 4.87 and 4.34), so a margin costs the gather of u, the
+# activation and the combine over its dead rows (from twice the load to
+# this: Motif 7.22 -> 5.89 ms, GLM 13.27 -> 11.98, Kimi 8.14 -> 6.90) and
+# their scratch; a second pass costs a whole combine again (Kimi 6.90 ->
+# 10.16 ms, Laguna 20.43 -> 25.58 where a full prompt needs two passes). The
+# cells' prompts fill 0.7 of their bucket on average: a pass of 1.25 even
+# loads runs twice only where the router is 1.3 times off even on a prompt
+# that fills its bucket. (benchmarks/diag_share_prefill.py, my chip runs,
+# PR 61.)
+PASS_MARGIN = 0.25
+
+# The part of all pairs a prefill's pass must be for the gather to return its
+# rows. Milliseconds a layer at each served share's largest bucket, scatter |
+# gather, by rows / (n k): Laguna 0.625: 24.50 | 20.43; Ling 0.3125: 14.19 |
+# 10.02; GLM 0.156: 11.98 | 11.57, where the gather holds 540 MB of scratch a
+# layer for the scatter's 254 (405 at twice the load); Motif 0.078: 5.89 |
+# 6.48. The gather costs n k rows a pass (0.5-0.8 ms where the pass's result
+# is under about 100 MB, 4.0 ms out of Laguna's 315 MB) and one float32
+# fusion over k slabs; the scatter a sort and a float32 [rows, d] written,
+# gathered into sorted order and scattered. Kimi (0.039: 6.90 | 5.29) is the
+# exception a ratio cannot see: a scatter-add into f32[4096, 7168] costs 3.4
+# ms whatever the pass holds (PERF.md section 7 (0-n)). (The same runs.)
+GATHER_SHARE = 0.25
 
 
 def route_topk(h, wr, top_k: int) -> Tuple[jnp.ndarray, jnp.ndarray]:
@@ -123,12 +167,22 @@ def held_pairs(idx, held: Sequence[int], n_expert: int, row_valid=None):
     return jnp.sum(on_share).astype(jnp.int32)
 
 
+def _tiles(rows: int) -> int:
+    return -(-max(rows, 1) // 256) * 256
+
+
 def _share_rows(n_pairs: int, e_held: int, n_expert: int) -> int:
-    """Rows of one pass of a share's grouped matmul: twice what an even
-    router sends ``e_held`` of ``n_expert`` experts, in whole tiles of 256,
-    and never more than there are pairs."""
+    """Rows of one pass of a share's grouped matmul, in whole tiles of 256
+    and never more than there are pairs. Twice what an even router sends
+    ``e_held`` of ``n_expert`` experts where that is a decode pass (at most
+    ``STREAM_ROWS`` rows); a larger one (a prefill's) holds the even load
+    and ``PASS_MARGIN`` of it, and stays over ``STREAM_ROWS``."""
     even = -(-n_pairs * e_held // n_expert)
-    return min(n_pairs, -(-max(2 * even, 1) // 256) * 256)
+    twice = min(n_pairs, _tiles(2 * even))
+    if twice <= STREAM_ROWS:
+        return twice
+    return min(n_pairs, max(_tiles(even + int(even * PASS_MARGIN)),
+                            STREAM_ROWS + 256))
 
 
 def pass_rows(n_pairs: int, e_held: int, n_expert: int) -> int:
@@ -150,6 +204,17 @@ def matmul_form(rows: int) -> str:
     (the fused kernel) or ``"grouped"`` (``ragged_dot`` x 3). A function
     of the pass's static row count and the backend alone."""
     return "stream" if rows <= STREAM_ROWS and _on_tpu() else "grouped"
+
+
+def combine_form(rows: int, n_pairs: int) -> str:
+    """How a pass of ``rows`` rows of a share gives its results back to the
+    tokens of ``n_pairs`` pairs: ``"gather"`` (every pair fetches its row
+    by the inverse permutation and a token's ``k`` are summed: ``n_pairs``
+    rows gathered a pass, no scatter) where the pass is a prefill's and at
+    least ``GATHER_SHARE`` of the pairs, else ``"scatter"`` (the pass's
+    rows added to their tokens). A function of static counts alone."""
+    return ("gather" if rows > STREAM_ROWS and rows >= GATHER_SHARE * n_pairs
+            else "scatter")
 
 
 def _ragged_ffn(xs, wg, wu, wd, sizes, activation, act_params=None):
@@ -264,13 +329,25 @@ def _share(u, w, wg, wu, wd, order, sizes, activation, rows: int,
     """A share's part of the layer: the sorted pairs of the HELD experts
     (the first ``sum(sizes)`` of ``order``), ``rows`` of them a pass, each
     pass one grouped matmul over its own slice of every group, its rows
-    weighed and added to their tokens in float32. An even router fills
-    half a pass; the loop runs until the last held pair is done."""
+    weighed and added to their tokens in float32 in the form
+    :func:`combine_form` gives the pass (``moe/share_combine.gather`` and
+    ``.scatter`` count which, once a traced layer). The loop runs until the
+    last held pair is done."""
     n, d = u.shape
     k = w.shape[1]
     ends = jnp.cumsum(sizes)
     total = ends[-1]
     wf = w.astype(jnp.float32).reshape(n * k)
+    combine = combine_form(rows, n * k)
+    attention_ops._count(combine, "moe/share_combine", "moe_ops._share")
+    if combine == "gather":
+        # where each pair lies among the sorted ones (the held pairs' below
+        # ``total``, in the order the passes take them), a token's k-th
+        # pair in row k: a token's sum is then over the LEADING axis, whole
+        # [n, d] slabs added, and no [n, k, d] tiling pads k to a sublane
+        back = jnp.zeros((n * k,), jnp.int32).at[order].set(
+            jnp.arange(n * k, dtype=jnp.int32)).reshape(n, k).T
+        wt = wf.reshape(n, k).T[:, :, None]
 
     def one_pass(i, y):
         lo = i * rows
@@ -282,6 +359,14 @@ def _share(u, w, wg, wu, wd, order, sizes, activation, rows: int,
         out = _grouped_ffn(u[pair // k], wg, wu, wd, part, activation,
                            act_params)
         # rows past the last group are whatever the grouped matmul left
+        if combine == "gather":
+            rel = back - lo
+            mine = (rel >= 0) & (rel < rows) & (back < total)
+            got = out[jnp.clip(rel, 0, rows - 1)]
+            for j in range(k):      # one fusion: y and the k slabs read once
+                y = y + jnp.where(mine[j][:, None], got[j], 0).astype(
+                    jnp.float32) * wt[j]
+            return y
         out = jnp.where(live[:, None],
                         out.astype(jnp.float32) * wf[pair][:, None], 0)
         return y.at[jnp.where(live, pair // k, n)].add(out, mode="drop")

@@ -1729,6 +1729,44 @@ def test_window_layers_prefill_attends_in_one_kernel(chip, monkeypatch,
     assert scores == []
 
 
+def test_half_share_prefill_returns_its_rows_without_a_scatter(chip,
+                                                               monkeypatch):
+    """The half share's 8,192-row prefill (the dense layer and the first
+    expert layer at the published widths): the share's loop holds the
+    three grouped matmuls of a 51,200-row pass and NO scatter, no sort (what
+    the compiler makes a scatter-add's unsorted indices into), no ``copy``
+    and no ``transpose`` (the parent's loop had neither, and 81,920-row
+    matmuls); the rows come back by a gather of ``[81920, 3072]`` in the
+    experts' type whose ``[10, 8192, 3072]`` view is a bitcast; and the
+    executable's scratch is under the 2,336,591,872 bytes the same two
+    layers took with the scatter-add (commit 1936beb, compiled here)."""
+    from paddle_tpu.monitor import metrics
+    from paddle_tpu.ops import moe_ops
+
+    monkeypatch.setattr(attention_ops, "_on_tpu", lambda: True)
+    traced = metrics.counter("moe/share_combine.gather").value
+    fn, args = _prefill_last_of(_mixed_model, 2)(chip, 8192)
+    compiled = jax.jit(fn).lower(*args).compile()
+    assert moe_ops.pass_rows(81920, 128, 256) == 51200
+    assert metrics.counter("moe/share_combine.gather").value == traced + 1
+    text = compiled.as_text()
+    comps, _ = _computations(text)
+    loops = [comps[body] for body in re.findall(
+        r" while\([^\n]*body=%([\w.\-]+)", text)
+        if any("moe/experts" in ln for ln in comps[body])]
+    assert len(loops) == 1
+    made = list(_instructions("\n".join(loops[0])))
+    assert not {"sort", "copy", "transpose"} & {op for _, _, op, _ in made}
+    assert not any("scatter" in ln for ln in loops[0])
+    assert sum(name.startswith("ragged-dot-none") and
+               rtype.startswith("bf16[51200,") for name, rtype, _, _ in made
+               ) == 3
+    assert any(rtype.startswith("bf16[81920,3072]") and op == "fusion"
+               for _, rtype, op, _ in made)
+    assert not any(rtype.startswith("f32[81920,") for _, rtype, _, _ in made)
+    assert compiled.memory_analysis().temp_size_in_bytes < 2336591872
+
+
 # -- Falcon-H1: a Mamba-2 state beside a GQA page pool in every layer -----------
 
 def test_ssd_state_step_kernel_at_the_served_geometry(chip):
