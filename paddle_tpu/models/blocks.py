@@ -4,8 +4,8 @@ through which ``serving.ServingEngine`` drives any of them
 (:class:`ServedLM`, whose docstring is the contract).
 
 ``smallthinker.py``, ``kimi_k2.py``, ``laguna.py``, ``ling3_flash.py``,
-``motif3.py``, ``glm5_flash.py``, ``falcon_h1.py``, ``ouro.py`` and
-``evabyte.py`` take their blocks from here and keep what only they have. A
+``motif3.py``, ``glm5_flash.py``, ``falcon_h1.py``, ``ouro.py``,
+``evabyte.py`` and ``deepseek_v32.py`` take their blocks from here and keep what only they have. A
 block two models need is written HERE under a public name; no model module
 imports another's underscore names. Two forms of a block are one function
 only where the merged one needs no argument that says who calls it and the
@@ -390,23 +390,38 @@ def held_experts(cfg):
             else cfg.experts_held)
 
 
-def routed_feed_forward(cfg, lp, x, row_valid):
+def routed_feed_forward(cfg, lp, x, row_valid, count_groups: bool = False):
     """The DeepSeek-V3 layer's second half over rows ``x`` [N, d]: the
     dense SwiGLU, or the sigmoid-routed SwiGLU experts held here (the
     router group-limited where ``cfg.n_group`` > 1) plus the shared
-    expert. Returns ``(x, stats or None)``."""
+    expert. Returns ``(x, stats or None)``. ``count_groups`` (a
+    group-limited router's share): ``stats`` also counts
+    ``groups_kept_with_held``, the valid rows of which a kept group holds
+    an expert held here."""
     u = rms_norm(x, lp["g2"], cfg.rms_eps)
     if "wr" not in lp:
         return x + swiglu(u, lp["wg"], lp["wu"], lp["wd"]), None
     limited = ({} if cfg.n_group == 1 else
                {"n_group": cfg.n_group, "topk_group": cfg.topk_group})
-    idx, w = moe_ops.route_sigmoid_topk(u, lp["wr"], lp["br"], cfg.top_k,
-                                        cfg.routed_scale, **limited)
+    if count_groups:
+        limited["with_groups"] = True
+    idx, w, *kept = moe_ops.route_sigmoid_topk(
+        u, lp["wr"], lp["br"], cfg.top_k, cfg.routed_scale, **limited)
     y, stats = moe_ops.expert_layer(
         u, idx, w, lp["wg"], lp["wu"], lp["wd"], n_expert=cfg.n_expert,
         held=held_experts(cfg), row_valid=row_valid, activation=jax.nn.silu)
     stats = dict(stats, held_pairs=moe_ops.held_pairs(
         idx, cfg.experts_held, cfg.n_expert, row_valid))
+    if count_groups:
+        # the valid rows of which a kept group holds an expert held here:
+        # only those can send this share a pair
+        ours = np.zeros((cfg.n_group,), bool)
+        ours[[e * cfg.n_group // cfg.n_expert for e in cfg.experts_held]] \
+            = True
+        with_held = jnp.any(kept[0] & jnp.asarray(ours), axis=-1)
+        if row_valid is not None:
+            with_held = with_held & row_valid
+        stats["groups_kept_with_held"] = jnp.sum(with_held).astype(jnp.int32)
     with jax.named_scope("moe/shared"):
         shared = swiglu(u, lp["sg"], lp["su"], lp["sd"])
     return x + (y + shared.astype(jnp.float32)).astype(x.dtype), stats
@@ -449,7 +464,8 @@ class ServedLM:
     """THE SERVING CONTRACT: what ``serving.ServingEngine`` may ask of a
     model and of its config. ``SmallThinkerLM``, ``KimiK2LM``, ``LagunaLM``,
     ``Ling3FlashLM``, ``Motif3LM``, ``Glm5FlashLM``, ``FalconH1LM``,
-    ``OuroLM`` and ``EvaByteLM`` are this class over their module's
+    ``OuroLM``, ``EvaByteLM`` and ``DeepSeekV32LM`` are this class over
+    their module's
     ``init_params``, ``prefill_forward`` and ``decode_forward`` (and, where
     the head is not the plain one, ``head``);
     ``decoder_lm.DecoderLM`` meets it with methods of its own.
@@ -491,6 +507,7 @@ class ServedLM:
       ``moe_max_expert_rows``, ``moe_held_pairs`` [expert layers],
       ``state_slots_stepped``, ``attn_rows_read.<group>``,
       ``attn_rows_context.<group>``, ``index_blocks_scored``,
+      ``index_rows_scored``, ``moe_groups_kept_with_held``,
       ``ut_expected_exit_step``, ``eva_chunks_closed``,
       ``eva_windows_closed``; a name
       without a histogram (a probe) rides to ``engine.last_decode_stats``
@@ -544,7 +561,8 @@ class ServedLM:
       query reads)`` of a latent cache whose layers choose the rows a
       query reads: the cache then keeps a pooled index key a block beside
       the rows, through the same page table, and the open block's raw
-      keys a slot (``LatentPagedCache(index=)``). Absent: no index;
+      keys a slot, or, with blocks of ONE row, a key a row and nothing a
+      slot (``LatentPagedCache(index=)``). Absent: no index;
     * ``experts_held`` (with ``n_expert``, ``top_k``): the global ids of
       the routed experts held here, from which the engine tells the form
       of an executable's grouped product. Absent: no expert layer.

@@ -632,9 +632,30 @@ def mla_decode_attention(q, ctx_rows, ctx_len, rank: int, sm_scale=1.0,
     return o.astype(q.dtype)
 
 
+def mla_rows_attention(q, rows, held, rank: int, sm_scale=1.0):
+    """:func:`mla_decode_attention` over a TABLE of gathered rows: ``q``
+    [B, H, W] absorbed, ``rows`` [B, K, W] the rows a slot chose, ``held``
+    [B, K] bool which of them count (a slot that holds none comes back
+    finite and is nobody's to read). [B, H, rank]. The softmax by hand,
+    its maximum behind a barrier: left to the compiler inside a decode
+    step, the maximum and its broadcast over the K rows became ONE float
+    ``reduce-window`` of K taps a score (``f32[32,128,2048]`` twice a
+    layer, 5 ms of a step on a v5e; PERF.md section 6, PR 62), as
+    :func:`_rows_masked_attention`'s did in a prefill."""
+    sc = jnp.einsum("bhw,bkw->bhk", q, rows,
+                    preferred_element_type=jnp.float32) * sm_scale
+    sc = jnp.where(held[:, None, :], sc, neg_inf(jnp.float32))
+    top = jax.lax.optimization_barrier(jnp.max(sc, axis=-1, keepdims=True))
+    p = jnp.exp(sc - top)
+    total = jnp.sum(p, axis=-1, keepdims=True)
+    o = jnp.einsum("bhk,bkr->bhr", p.astype(rows.dtype), rows[..., :rank],
+                   preferred_element_type=jnp.float32)
+    return (o / total).astype(q.dtype)
+
+
 # -- learned sparse attention (DeepSeek's lightning indexer over pooled keys) --
 
-def dsa_index_scores(q_idx, w_idx, keys, closed):
+def dsa_index_scores(q_idx, w_idx, keys, closed, score_dtype=jnp.float32):
     """The index scores of one position a slot: ``q_idx`` [B, Hi, L] the
     index queries, ``w_idx`` [B, Hi] float32 their weights, ``keys`` one
     pooled index key of L lanes a block: [N, L] (the same for every
@@ -643,15 +664,32 @@ def dsa_index_scores(q_idx, w_idx, keys, closed):
     lane slice at a time, so that the gathered rows are never re-laid),
     ``closed`` [B] the blocks that may be scored (those before it are
     closed). ``I(b) = sum_j w_j ReLU(q_j . K_b)`` [B, N] float32, the
-    masking constant at and past ``closed``."""
+    masking constant at and past ``closed``. A key a ROW is a block of
+    one. ``score_dtype``: the precision the heads' products are rounded
+    to and their weighted sum is ACCUMULATED in (float32 as every
+    configuration states; a lower one is a control's)."""
     with jax.named_scope("attn/dsa_index"):
         lanes = q_idx.shape[-1]
         w = w_idx.astype(jnp.float32)
 
+        def rounded(x):
+            if jnp.dtype(score_dtype) == jnp.float32:
+                return x
+            info = jnp.finfo(score_dtype)
+            return jax.lax.reduce_precision(x, info.nexp, info.nmant)
+
         def scored(k):
             sc = jnp.einsum("bhl,nl->bhn" if k.ndim == 2 else "bhl,bnl->bhn",
                             q_idx, k, preferred_element_type=jnp.float32)
-            return jnp.einsum("bh,bhn->bn", w, jax.nn.relu(sc))
+            if jnp.dtype(score_dtype) == jnp.float32:
+                return jnp.einsum("bh,bhn->bn", w, jax.nn.relu(sc))
+            # a lower precision ACCUMULATES in it: the running sum over
+            # the heads rounded after every head's term
+            terms = rounded(w[:, :, None] * jax.nn.relu(rounded(sc)))
+            return jax.lax.fori_loop(
+                0, terms.shape[1],
+                lambda h, acc: rounded(acc + terms[:, h]),
+                jnp.zeros(terms.shape[:1] + terms.shape[2:], jnp.float32))
 
         packed = keys.shape[-1] // lanes
         if packed == 1:
@@ -693,6 +731,138 @@ def dsa_select(scores, own_block, top_blocks: int):
         return chosen, jnp.where(picked < n, picked, -1)
 
 
+def dsa_select_rows(scores, topk: int):
+    """The ROWS a query reads where the choice is of single rows and none
+    is forced in: ``scores`` [B, N] float32 (the masking constant where a
+    row may not be chosen). The ``topk`` rows of highest score (every one
+    that may be chosen where there are fewer), a tie going to the lower
+    row: ``lax.top_k``'s set, exactly, WITHOUT a sort, which is what
+    ``top_k`` at k = 2,048 of 10,240 is on the chip. The k-th largest score
+    is found by bisection on the integer image of the float32 scores (32
+    compare-and-count passes over ``[B, N]``), a row is chosen where it
+    scores above it, and of those that score the same the lowest that still
+    fit (a second bisection, on the row index: no cumulative sum, which the
+    chip's compiler makes a reduce-window of N taps). Returns ``chosen``
+    [B, N] bool."""
+    with jax.named_scope("attn/dsa_select"):
+        b, n = scores.shape
+        k = min(int(topk), n)
+        u32 = jnp.uint32
+        bits = jax.lax.bitcast_convert_type(scores, jnp.int32)
+        # a float32's order as an unsigned integer's: negatives reversed,
+        # then the sign bit turned
+        image = jax.lax.bitcast_convert_type(
+            jnp.where(bits < 0, bits ^ jnp.int32(0x7FFFFFFF), bits), u32) \
+            ^ u32(0x80000000)
+
+        def value_bit(i, kth):
+            cand = kth | jnp.left_shift(u32(1), (31 - i).astype(u32))
+            enough = jnp.sum(image >= cand[:, None], axis=-1) >= k
+            return jnp.where(enough, cand, kth)
+
+        kth = jax.lax.fori_loop(0, 32, value_bit, jnp.zeros((b,), u32))
+        above = image > kth[:, None]
+        tie = (image == kth[:, None]) & (scores > neg_inf(jnp.float32))
+        room = k - jnp.sum(above, axis=-1)
+        rows = jnp.arange(n, dtype=jnp.int32)[None, :]
+        width = max(n - 1, 1).bit_length()
+
+        def row_bit(i, first):
+            # the largest row index with fewer than ``room`` ties before it
+            cand = first | jnp.left_shift(jnp.int32(1), width - 1 - i)
+            few = jnp.sum(tie & (rows < cand[:, None]), axis=-1) < room
+            return jnp.where(few, cand, first)
+
+        last = jax.lax.fori_loop(0, width, row_bit,
+                                 jnp.zeros((b,), jnp.int32))
+        return above | (tie & (rows <= last[:, None]))
+
+
+def dsa_chosen_rows(chosen, topk: int):
+    """The TABLE of a choice: ``chosen`` [B, N] bool (:func:`dsa_select_rows`:
+    at most ``topk`` true a row) as ``(rows [B, topk] int32, held [B, topk]
+    bool)``: the chosen rows ascending, 0 where ``held`` is false (fewer
+    than ``topk`` were chosen). Exact and WITHOUT a sort or a scatter, which
+    is what a compaction is on the chip by ``top_k``'s indices or by
+    ``nonzero``: the N rows are 128-lane chunks; the j-th chosen row lies
+    in the chunk at which the chunks' running count first passes j (a
+    compare against 128 counts), and in it at the lane whose running count
+    is what is left of j (the chunk's lanes fetched by a one-hot product
+    and counted by a triangular one: 0/1 values, exact in bfloat16 with
+    float32 sums)."""
+    with jax.named_scope("attn/dsa_select"):
+        b, n = chosen.shape
+        k = min(int(topk), n)
+        lanes = 128
+        m = jnp.pad(chosen, ((0, 0), (0, -n % lanes))).reshape(b, -1, lanes)
+        ends = jnp.cumsum(jnp.sum(m, axis=-1, dtype=jnp.int32), axis=-1)
+        slot = jnp.arange(k, dtype=jnp.int32)
+        before = ends[:, None, :] <= slot[None, :, None]       # [B, k, C]
+        chunk = jnp.sum(before, axis=-1, dtype=jnp.int32)
+        start = jnp.max(jnp.where(before, ends[:, None, :], 0), axis=-1)
+        held = slot[None, :] < ends[:, -1:]
+        bf, f32 = jnp.bfloat16, jnp.float32
+        onehot = jnp.arange(m.shape[1])[None, None, :] == chunk[:, :, None]
+        in_chunk = jnp.einsum("bkc,bcl->bkl", onehot.astype(bf), m.astype(bf),
+                              preferred_element_type=f32)
+        lane = jnp.arange(lanes)
+        upto = (lane[:, None] <= lane[None, :]).astype(bf)
+        count = jnp.einsum("bkl,lm->bkm", in_chunk.astype(bf), upto,
+                           preferred_element_type=f32)
+        want = (slot[None, :] - start + 1).astype(f32)[:, :, None]
+        hit = (in_chunk > 0) & (count == want)
+        rows = chunk * lanes + jnp.sum(jnp.where(hit, lane, 0), axis=-1,
+                                       dtype=jnp.int32)
+        return jnp.where(held, rows, 0), held
+
+
+def _rows_masked_attention(q, k, v, mask_of, per_row, sm_scale, bq: int,
+                           kernel: bool):
+    """Attention of ONE sequence in which row t reads the rows ``mask_of(i,
+    *block of per_row)`` [bq, S] bool says (the caller's causal triangle in
+    it), ``bq`` query rows at a time: by ONE ``dsa_prefill_attention``
+    kernel over the rows' masks as ``int8 [S, S]`` where ``kernel``, else
+    the BLOCKED form (the float32 scores of ``bq`` rows against the whole
+    sequence held at a time)."""
+    from .pallas_kernels import dsa_prefill
+
+    s = q.shape[0]
+
+    def rows_of(args):
+        i, qb, *rest = args
+        mask = mask_of(i, *rest)
+        with jax.named_scope("attn/dsa_sparse"):
+            sc = jnp.einsum("qhd,khd->hqk", qb, k,
+                            preferred_element_type=jnp.float32) * sm_scale
+            sc = jnp.where(mask[None], sc, neg_inf(jnp.float32))
+            # the softmax by hand, its maximum behind a barrier: left to
+            # the compiler, the maximum and its broadcast over the S keys
+            # become ONE reduce-window of 2 S - 1 taps a score (23 ms a
+            # block of 128 rows at S = 8,192 on a v5e, 1.5 s a layer)
+            top = jax.lax.optimization_barrier(
+                jnp.max(sc, axis=-1, keepdims=True))
+            p = jnp.exp(sc - top)
+            total = jnp.sum(p, axis=-1)                         # [H, bq]
+            o = jnp.einsum("hqk,khd->qhd", p.astype(v.dtype), v,
+                           preferred_element_type=jnp.float32)
+            return (o / total.T[:, :, None]).astype(q.dtype)
+
+    def split(x):
+        return x.reshape((s // bq, bq) + x.shape[1:])
+
+    blocks = jnp.arange(s // bq)
+    if kernel:
+        mask = jax.lax.map(
+            lambda a: mask_of(*a).astype(jnp.int8),
+            (blocks,) + tuple(split(x) for x in per_row))
+        with jax.named_scope("attn/dsa_sparse"):
+            return dsa_prefill.dsa_prefill_attention(
+                q, k, v, mask.reshape(s, s), sm_scale=float(sm_scale))
+    out = jax.lax.map(rows_of, (blocks, split(q))
+                      + tuple(split(x) for x in per_row))
+    return out.reshape((s,) + out.shape[2:])
+
+
 def dsa_causal_attention(q, k, v, q_idx, w_idx, k_pool, kpool: int,
                          top_blocks: int, sm_scale=1.0, block_q: int = 256):
     """Causal attention of ONE sequence in which every query row reads the
@@ -731,39 +901,71 @@ def dsa_causal_attention(q, k, v, q_idx, w_idx, k_pool, kpool: int,
         with jax.named_scope("attn/dsa_sparse"):
             return jnp.repeat(chosen, kpool, axis=1) & (cols <= rows[:, None])
 
-    def rows_of(args):
-        i, qb, qib, wib = args
-        mask = mask_of(i, qib, wib)
-        with jax.named_scope("attn/dsa_sparse"):
-            sc = jnp.einsum("qhd,khd->hqk", qb, k,
-                            preferred_element_type=jnp.float32) * sm_scale
-            sc = jnp.where(mask[None], sc, neg_inf(jnp.float32))
-            # the softmax by hand, its maximum behind a barrier: left to
-            # the compiler, the maximum and its broadcast over the S keys
-            # become ONE reduce-window of 2 S - 1 taps a score (23 ms a
-            # block of 128 rows at S = 8,192 on a v5e, 1.5 s a layer)
-            top = jax.lax.optimization_barrier(
-                jnp.max(sc, axis=-1, keepdims=True))
-            p = jnp.exp(sc - top)
-            total = jnp.sum(p, axis=-1)                         # [H, bq]
-            o = jnp.einsum("hqk,khd->qhd", p.astype(v.dtype), v,
-                           preferred_element_type=jnp.float32)
-            return (o / total.T[:, :, None]).astype(q.dtype)
+    return _rows_masked_attention(q, k, v, mask_of, (q_idx, w_idx), sm_scale,
+                                  bq, kernel)
 
-    def split(x):
-        return x.reshape((s // bq, bq) + x.shape[1:])
 
-    blocks = jnp.arange(s // bq)
-    if kernel:
-        mask = jax.lax.map(
-            lambda a: mask_of(*a).astype(jnp.int8),
-            (blocks, split(q_idx), split(w_idx)))
+def dsa_rows_causal_attention(q, k, v, q_idx, w_idx, k_idx, topk: int,
+                              sm_scale=1.0, block_q: int = 256,
+                              select=None, score_dtype=jnp.float32):
+    """:func:`dsa_causal_attention` where the choice is of single ROWS and
+    none is forced in (DeepSeek-V3.2's own form): ``k_idx`` [S, L] one
+    index key a row; row t reads the ``topk`` rows s <= t of highest index
+    score (its whole prefix where t + 1 <= ``topk``: the mask is then the
+    causal triangle), by :func:`dsa_select_rows` (``select``: another rule
+    with its signature, a control's). On a TPU the same ONE
+    ``dsa_prefill_attention`` kernel a layer, for which ``q`` and ``k``
+    [S, H, D] are zero-padded to whole lane tiles where a head's D is not
+    (128 + 64 rotary lanes -> 256: a third more of q and k in HBM for the
+    length of the call, and a quarter of the first product's lanes
+    multiplied for nothing: zero lanes add nothing to a score); elsewhere
+    the blocked form at D as it is. The index scores, too, are ONE kernel
+    a layer there (``dsa_index.dsa_index_scores_prefill``: ``[S, S]``
+    float32 and no ``[block_q, Hi, S]`` products through HBM;
+    ``dsa/prefill_index_calls.kernel|blocked`` count which). Returns [S,
+    H, Dv]."""
+    from .pallas_kernels import dsa_index, dsa_prefill
+
+    s, n_head, d = q.shape
+    bq = _divisor_block(block_q, s, s)
+    cols = jnp.arange(s)[None, :]
+    wide = -(-d // 128) * 128
+    kernel = _on_tpu() and dsa_prefill.dsa_prefill_gate(
+        n_head, wide, v.shape[-1], s, 1, q.dtype.itemsize) is None
+    _count("kernel" if kernel else "blocked", "dsa/prefill_calls",
+           "dsa_rows_causal_attention")
+    select = select or dsa_select_rows
+    # the scores of every row against every row before it by ONE kernel
+    # where the chip takes the shapes and the configuration's float32
+    # scores are asked for; else a query block's at a time in XLA
+    scored = _on_tpu() and jnp.dtype(score_dtype) == jnp.float32 \
+        and dsa_index.dsa_index_prefill_gate(
+            q_idx.shape[1], q_idx.shape[2], s) is None
+    _count("kernel" if scored else "blocked", "dsa/prefill_index_calls",
+           "dsa_rows_causal_attention")
+
+    def chosen_of(i, scores):
+        rows = i * bq + jnp.arange(bq)
+        causal = cols <= rows[:, None]
+        chosen = select(jnp.where(causal, scores, neg_inf(jnp.float32)),
+                        topk)
         with jax.named_scope("attn/dsa_sparse"):
-            return dsa_prefill.dsa_prefill_attention(
-                q, k, v, mask.reshape(s, s), sm_scale=float(sm_scale))
-    out = jax.lax.map(rows_of, (blocks, split(q), split(q_idx),
-                                split(w_idx)))
-    return out.reshape((s,) + out.shape[2:])
+            return chosen & causal
+
+    def mask_of(i, qib, wib):
+        rows = i * bq + jnp.arange(bq)
+        return chosen_of(i, dsa_index_scores(qib, wib, k_idx, rows + 1,
+                                             score_dtype))
+
+    if kernel and wide != d:
+        q, k = (jnp.pad(x, ((0, 0), (0, 0), (0, wide - d))) for x in (q, k))
+    if scored:
+        with jax.named_scope("attn/dsa_index"):
+            scores = dsa_index.dsa_index_scores_prefill(q_idx, w_idx, k_idx)
+        return _rows_masked_attention(q, k, v, chosen_of, (scores,), sm_scale,
+                                      bq, kernel)
+    return _rows_masked_attention(q, k, v, mask_of, (q_idx, w_idx), sm_scale,
+                                  bq, kernel)
 
 
 def differential_combine(o, lam, n_kv: int):

@@ -125,8 +125,9 @@ def route_topk(h, wr, top_k: int) -> Tuple[jnp.ndarray, jnp.ndarray]:
 
 
 def route_sigmoid_topk(h, wr, bias, top_k: int, scale: float = 1.0,
-                       n_group: int = 1, topk_group: int = 1
-                       ) -> Tuple[jnp.ndarray, jnp.ndarray]:
+                       n_group: int = 1, topk_group: int = 1,
+                       with_groups: bool = False
+                       ) -> Tuple[jnp.ndarray, ...]:
     """The DeepSeek-V3 family's router: ``s = sigmoid(h wr)`` [N, E]; the
     ``top_k`` largest of ``s + bias`` are CHOSEN, and weighed by ``s``
     alone (the bias selects, never weighs), normalised over the chosen
@@ -136,7 +137,9 @@ def route_sigmoid_topk(h, wr, bias, top_k: int, scale: float = 1.0,
     ``topk_group`` best groups stay, and the ``top_k`` are chosen among
     their experts. ``n_group`` = ``topk_group`` = 1 is the plain top-k,
     the same operations as before there were groups. Returns ``(idx [N,
-    k] int32, w [N, k] float32)``."""
+    k] int32, w [N, k] float32)`` and, ``with_groups`` (a group-limited
+    router's counter), ``kept`` [N, n_group] bool: the groups that
+    stayed."""
     with jax.named_scope("moe/router"):
         s = jax.nn.sigmoid(jnp.dot(h, wr, preferred_element_type=jnp.float32))
         biased = s + bias.astype(jnp.float32)
@@ -152,6 +155,10 @@ def route_sigmoid_topk(h, wr, bias, top_k: int, scale: float = 1.0,
         _, idx = jax.lax.top_k(biased, top_k)
         chosen = jnp.take_along_axis(s, idx, axis=-1)
         w = chosen / (jnp.sum(chosen, axis=-1, keepdims=True) + 1e-20)
+        if with_groups:
+            if n_group == 1:    # one group, and it stays
+                kept = jnp.ones((biased.shape[0], 1), bool)
+            return idx.astype(jnp.int32), w * scale, kept
         return idx.astype(jnp.int32), w * scale
 
 

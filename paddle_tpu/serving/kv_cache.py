@@ -63,11 +63,41 @@ different kinds side by side:
   RING by the rule above, the row being stored with its rotary key
   already rotated. A latent cache may keep an INDEX beside its rows
   (``index``; a model whose latent layers choose the rows a query reads):
-  one pooled index key a block of ``kpool`` rows, in a second pool ``"ik"``
-  addressed through the group's own page table (a page of 16 rows owns 4
-  of them: no second allocator, no second free list), and, bound to the
-  SLOT as a state group's convolution tail is, the raw keys of the block
-  still open (``"it"``).
+  index keys in a second pool ``"ik"`` addressed through the group's own
+  page table (no second allocator, no second free list), a page's keys
+  together (pooled: side by side in ONE pool row; a key a row: a page a
+  ``[page_size, lanes]`` tile), so that the page table gathers them as
+  it stands. Two shapes of it, ONE code path with a short branch where they
+  part (a block of one row is a case of ``index=``, not a second class:
+  the pool, its addressing, the prompt's write and the scores are the
+  same lines):
+
+  - POOLED BLOCKS (``index = (kpool > 1, lanes, blocks a query reads)``):
+    one key a block of ``kpool`` rows, the MEAN of the block's keys,
+    written when the block's last row is (a page of 16 rows owns 4 of
+    them), and, bound to the SLOT as a state group's convolution tail is,
+    the raw keys of the block still open (``"it"``). A query reads its own
+    block and the best closed ones; the read goes by 8-row tiles through a
+    second, shorter table a step (the least the chip copies).
+  - ROWS (``index = (1, lanes, rows a query reads)``): one key a ROW,
+    written with the row at every step and with a prompt's rows at a
+    prefill; no pooling, no open block, no ``"it"``, no slot entry after
+    the page table. A query scores every row of its context, its own
+    among them (``index_scores``: the ``dsa_index_scores`` kernel over
+    its live pages of keys), and reads the best ``topk`` single rows by
+    the latent kernel's wave over the slot's pages as they stand with the
+    choice as its row mask (``rows_decode_attention``: a one-row copy is
+    not a thing the chip's compiler gives a Pallas kernel out of HBM, of
+    32-bit words or of bfloat16: 8 rows at the least, and 8-row tiles
+    hold a chosen row almost everywhere once a few thousand single rows
+    are chosen; XLA's own gather of the chosen rows costs a descriptor a
+    row and is the off-chip path).
+
+  Side by side, what a paged group's slot holds: every position in pages
+  (``window`` None); the last W positions as a RING (``window`` W); a
+  window's rows and then a summary a chunk of it, COMPACTING (``chunk``,
+  below, ``KV`` groups); and, beside a latent group of pages, an index of
+  pooled blocks or of rows.
 * ``STATE``: the layers of a linear-attention recurrence keep nothing a
   token. What they keep belongs to the SLOT, has a fixed size and is
   rewritten whole at every step: a ``[H, dk, dv]`` float32 state and the
@@ -1181,9 +1211,11 @@ class LatentPagedCache(PagedKVCache):
             if len(paged) != 1 or paged[0].window is not None \
                     or self.page_size % (2 * kpool) or 8 % kpool:
                 raise ValueError(
-                    "an index is kept beside ONE latent group of pages, "
-                    "whole blocks a page and whole blocks an 8-row tile "
-                    "(the least the chip copies), pairs of tiles a page: "
+                    "an index is kept beside ONE latent group of pages: a "
+                    "key a ROW (blocks of 1: whole 8-row tiles a page), or "
+                    "a pooled key a block of rows, whole blocks a page and "
+                    "whole blocks an 8-row tile (the least the chip copies "
+                    "of the rows a block chooses), pairs of tiles a page: "
                     "got groups %s, page_size=%d, blocks of %d rows"
                     % ([(g.name, g.window) for g in paged], self.page_size,
                        kpool))
@@ -1193,11 +1225,18 @@ class LatentPagedCache(PagedKVCache):
     def page_table_len(self) -> int:
         """With an index, a slot's ``dest`` row ends with the slot itself:
         the open block's keys are the slot's, not a page's."""
-        return self._pt_start[-1] + (self.index is not None)
+        return self._pt_start[-1] + self._open_block
+
+    @property
+    def _open_block(self) -> bool:
+        """Whether a slot keeps the raw keys of a block still open: an
+        index of POOLED keys does, an index of a key a row has no open
+        block."""
+        return self.index is not None and self.index[0] > 1
 
     def prompt_dest_groups(self, group_pages, slot: int = 0) -> np.ndarray:
         dest = super().prompt_dest_groups(group_pages, slot)
-        if self.index is None:
+        if not self._open_block:
             return dest
         return np.concatenate([dest, np.full(1, slot, np.int32)])
 
@@ -1209,11 +1248,18 @@ class LatentPagedCache(PagedKVCache):
             # a PAGE's pooled keys side by side in one row (4 x 128 lanes
             # at a page of 16 rows): the page table gathers them as it
             # stands, a row a page
+            # (a key a ROW keeps the page's rows as an axis, a page one
+            # tile of its own: the row is then written where its latent
+            # row is, and a page is a thing a kernel can copy)
             state["ik"] = jnp.zeros(
+                (len(g.layers), g.num_pages, self.page_size, lanes)
+                if kpool == 1 else
                 (len(g.layers), g.num_pages,
                  self.page_size // kpool * lanes), self.dtype)
-            state["it"] = jnp.zeros(
-                (len(g.layers), self.slots, kpool - 1, lanes), self.dtype)
+            if self._open_block:
+                state["it"] = jnp.zeros(
+                    (len(g.layers), self.slots, kpool - 1, lanes),
+                    self.dtype)
         return state
 
     def index_bytes(self, state: Cache) -> int:
@@ -1223,7 +1269,9 @@ class LatentPagedCache(PagedKVCache):
     def write_index(self, state: Cache, layer: int, key_new, pos, active
                     ) -> Cache:
         """One decode step of a layer's index: ``key_new`` [B, lanes], the
-        index key of position ``pos[b]``. A row that is not its block's
+        index key of position ``pos[b]``. With a key a ROW it is written
+        to its row's lanes of its page's row in ``"ik"`` as it is. With
+        pooled blocks: a row that is not its block's
         last joins the slot's open block (``"it"``); the block's LAST row
         closes it: the mean of the block's keys, in float32, is written to
         the block's lanes of its page's row in ``"ik"`` (through the page
@@ -1233,6 +1281,14 @@ class LatentPagedCache(PagedKVCache):
         _, li = self._where[layer]
         pt = state["pt"]
         b_idx = jnp.arange(pt.shape[0])
+        if kpool == 1:
+            # a key a ROW: no block is ever open, the key goes to its
+            # row's place in its page as it is
+            page = jnp.where(active, pt[b_idx, pos // self.page_size],
+                             state["ik"].shape[1])
+            return {**state, "ik": state["ik"].at[
+                li, page, pos % self.page_size].set(
+                    key_new.astype(self.dtype), mode="drop")}
         j = pos % kpool
         tail = state["it"][li]                           # [B, kpool - 1, L]
         pooled = ((jnp.sum(tail.astype(jnp.float32), axis=1)
@@ -1265,36 +1321,78 @@ class LatentPagedCache(PagedKVCache):
         kpool, lanes, _ = self.index
         _, li = self._where[layer]
         per_page = self.page_size // kpool
-        rows = pooled.astype(self.dtype).reshape(-1, per_page * lanes)
+        rows = pooled.astype(self.dtype).reshape(
+            (-1,) + state["ik"].shape[2:])      # a page's keys together
         p = jnp.arange(rows.shape[0])
         flat = jnp.where(p * self.page_size + kpool <= length, dest[p],
                          state["ik"].shape[1])
-        return {**state,
-                "ik": state["ik"].at[li, flat].set(rows, mode="drop"),
-                "it": state["it"].at[li, dest[-1]].set(
-                    tail.astype(self.dtype))}
+        state = {**state,
+                 "ik": state["ik"].at[li, flat].set(rows, mode="drop")}
+        if tail is None:        # a key a row: no block is left open
+            return state
+        return {**state, "it": state["it"].at[li, dest[-1]].set(
+            tail.astype(self.dtype))}
 
     def index_scores(self, state: Cache, layer: int, q_idx, w_idx, ctx_len,
-                     active):
+                     active, score_dtype=jnp.float32):
         """The index scores of one decode step: ``q_idx`` [B, Hi, lanes]
         the index queries, ``w_idx`` [B, Hi] float32 their weights.
         Returns ``(scores [B, blocks a slot] float32, closed [B])``: ``I(t,
         b) = sum_j w_j ReLU(q_j . K_b)`` for each of the slot's CLOSED
-        blocks before the one position ``ctx_len - 1`` lies in, the
-        masking constant elsewhere (and everywhere in a slot that is not
-        ``active``); ``closed`` counts them. The keys are gathered a PAGE's
-        row at a time, by the page table as it stands."""
+        blocks before the one position ``ctx_len - 1`` lies in (with a key
+        a ROW: for every row of the context, that position's among them),
+        the masking constant elsewhere (and everywhere in a slot that is
+        not ``active``); ``closed`` counts them. The keys are gathered a
+        PAGE at a time, by the page table as it stands; a key a row at
+        float32 scores by the ``dsa_index_scores`` kernel where
+        :meth:`index_kernel_mode` arms it (the live pages alone, the
+        heads' products never in HBM). ``score_dtype``:
+        ``dsa_index_scores``'s."""
         from ..ops import attention_ops
 
         kpool = self.index[0]
         _, li = self._where[layer]
-        closed = jnp.where(active, (ctx_len - 1) // kpool, 0)
-        return attention_ops.dsa_index_scores(
-            q_idx, w_idx, state["ik"][li, state["pt"]], closed), closed
+        # a key a ROW scores every row of the context, the position's own
+        # among them: nothing is forced in, so it has to earn its place
+        closed = jnp.where(active, ctx_len if kpool == 1
+                           else (ctx_len - 1) // kpool, 0)
+        if kpool == 1 and jnp.dtype(score_dtype) == jnp.float32:
+            mode, _ = self.index_kernel_mode()
+            if mode is not None:
+                from ..ops.pallas_kernels import dsa_index
+
+                return dsa_index.dsa_index_scores_paged(
+                    q_idx, w_idx, state["ik"], state["pt"], closed, layer=li,
+                    interpret=(mode == "interpret")), closed
+        keys = state["ik"][li, state["pt"]]
+        if kpool == 1:          # [B, pages, rows a page, L]: a key a row
+            keys = keys.reshape(keys.shape[0], -1, keys.shape[-1])
+        return attention_ops.dsa_index_scores(q_idx, w_idx, keys, closed,
+                                              score_dtype), closed
+
+    def index_kernel_mode(self):
+        """:meth:`kernel_mode`'s twin for the index scores of a key a row:
+        the same flag, ``dsa_index_gate`` over the index's geometry (an
+        index of pooled blocks is scored in XLA: a page owns too few of
+        its keys for the chip to copy them a page at a time)."""
+        from ..ops import attention_ops
+        from ..ops.pallas_kernels.dsa_index import dsa_index_gate
+
+        mode = attention_ops.paged_kernel_mode()
+        if mode is None or self.index is None or self.index[0] != 1:
+            return None, "n/a"
+        why_not = dsa_index_gate(self.dtype, self.index[1], self.page_size,
+                                 self.max_ctx,
+                                 interpret=(mode == "interpret"))
+        if why_not is not None:
+            return None, "gate: " + why_not
+        return mode, None
 
     def sparse_kernel_mode(self):
-        """:meth:`kernel_mode`'s twin for the sparse read: the same flag,
-        the latent kernel's gate at the 8-row tile it copies."""
+        """:meth:`kernel_mode`'s twin for the sparse read of pooled
+        blocks: the same flag, the latent kernel's gate at the 8-row tile
+        it copies (a choice of single rows goes by the pages as they
+        stand: :meth:`rows_decode_attention` asks :meth:`kernel_mode`)."""
         from ..ops import attention_ops
         from ..ops.pallas_kernels.mla_attention import (SPARSE_TILE,
                                                         mla_decode_gate)
@@ -1314,7 +1412,8 @@ class LatentPagedCache(PagedKVCache):
         """Decode attention over the CHOSEN blocks only: ``q`` [B, H,
         rank + rope] absorbed, ``chosen`` [B, blocks a slot] bool (the
         selection: closed blocks, and the block position ``ctx_len - 1``
-        lies in). Returns ``(o [B, H, rank], rows_read [B])``. The chip
+        lies in; single rows are read by :meth:`rows_decode_attention`).
+        Returns ``(o [B, H, rank], rows_read [B])``. The chip
         copies 8 rows at the least (a bfloat16 tile's rows in HBM), so the
         read goes by 8-row TILES: the tiles that hold a chosen block, in
         ascending order, through a second, shorter table a step, and a row
@@ -1366,6 +1465,54 @@ class LatentPagedCache(PagedKVCache):
             q, state["c"][li, pool_rows], length, self.rank,
             sm_scale=sm_scale, row_valid=valid), read
 
+    def rows_decode_attention(self, state: Cache, layer: int, q, chosen,
+                              ctx_len, active, sm_scale: float = 1.0):
+        """Decode attention over the CHOSEN ROWS only (an index of a key a
+        row): ``q`` [B, H, rank + rope] absorbed, ``chosen`` [B, rows a
+        slot] bool (``ops.attention_ops.dsa_select_rows``: at most ``topk``
+        a slot, every one below the slot's length). Returns ``(o [B, H,
+        rank], rows_read [B])``; ``rows_read`` counts the rows CHOSEN,
+        whatever was copied. Where :meth:`kernel_mode` arms it: the latent
+        kernel's wave over the slot's WHOLE context through the page table
+        as it stands, the choice as its ``row_valid`` mask, under the name
+        ``dsa_sparse_decode``: every page is copied, a row that was not
+        chosen meets the masking constant. That is form (b) of the two
+        exact ones PERF.md section 6 (PR 62) measured: 873 us a layer at
+        32 slots of 7.4k rows and 128 heads, its products and not its
+        bytes the bound (3.7 ns a row of context). Form (a), a read of the
+        chosen rows alone, has no Pallas kernel: the chip's compiler takes
+        no copy of fewer than 8 rows out of HBM, of 32-bit words as of
+        bfloat16, and 8-row tiles hold a chosen row almost everywhere once
+        a few thousand single rows are chosen; as XLA's gather it costs a
+        descriptor a row (15 ns: 1.0 ms a layer for 65,536 rows before
+        the attention over them) and wins only past some 9k rows a slot,
+        the edge of this envelope. Off the kernel the chosen rows ARE
+        gathered (``dsa_chosen_rows`` makes their table): the CPU tests'
+        path."""
+        from ..ops import attention_ops
+
+        topk = self.index[2]
+        _, li = self._where[layer]
+        live = _live_len(ctx_len, active)
+        chosen = chosen & (jnp.arange(chosen.shape[1])[None, :]
+                           < live[:, None])
+        read = jnp.sum(chosen, axis=-1).astype(jnp.int32)
+        q = jnp.pad(q, ((0, 0), (0, 0), (0, self.row_width - q.shape[-1])))
+        mode, _ = self.kernel_mode()
+        if mode is not None:
+            from ..ops.pallas_kernels import mla_attention as _mla
+
+            return _mla.mla_paged_decode(
+                q, state["c"], state["pt"], live, page_size=self.page_size,
+                rank=self.rank, layer=li, sm_scale=sm_scale,
+                row_valid=chosen, interpret=(mode == "interpret"),
+                name=_mla.SPARSE_KERNEL_NAME), read
+        rows, held = attention_ops.dsa_chosen_rows(chosen, topk)
+        pool_rows = self._pool_rows(state["pt"], rows.T).T
+        return attention_ops.mla_rows_attention(
+            q, state["c"][li, pool_rows], held, self.rank,
+            sm_scale=sm_scale), read
+
     def write_token(self, state: Cache, layer: int, row_new, pos, active
                     ) -> Cache:
         """``row_new`` [B, rank + rope] written at position ``pos[b]`` of
@@ -1377,12 +1524,19 @@ class LatentPagedCache(PagedKVCache):
         """A latent layer: ``(row_new [S, rank + rope], dest, length)`` of
         ONE sequence, positions >= ``length`` dropped; with an index,
         ``(row_new, pooled keys [S / kpool, lanes], open block's keys
-        [kpool - 1, lanes], dest, length)``. A state layer: ``(state,
-        tail, dest, length)``, the paged cache's."""
+        [kpool - 1, lanes], dest, length)``, or, of a key a row,
+        ``(row_new, index keys [S, lanes], dest, length)``. A state layer:
+        ``(state, tail, dest, length)``, the paged cache's."""
         if len(new_dest_length) == 5:    # a latent layer with an index
             row_new, pooled, tail, dest, length = new_dest_length
             state = self._write_index_prompt(state, layer, pooled, tail,
                                              dest, length)
+            new_dest_length = (row_new, dest, length)
+        elif len(new_dest_length) == 4 and layer in self._where \
+                and self.index is not None:    # ... of a key a row
+            row_new, keys, dest, length = new_dest_length
+            state = self._write_index_prompt(state, layer, keys, None, dest,
+                                             length)
             new_dest_length = (row_new, dest, length)
         if len(new_dest_length) == 3:
             row_new, dest, length = new_dest_length

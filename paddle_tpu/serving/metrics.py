@@ -24,6 +24,7 @@ __all__ = [
     "FAULTS", "RETRIES", "TIMEOUTS", "REQUESTS_FAILED",
     "DRAINS", "DRAINED_REQUESTS", "DRAIN_REJECTED",
     "MOE_EXPERTS_TOUCHED", "MOE_MAX_EXPERT_ROWS", "MOE_HELD_PAIRS",
+    "MOE_GROUPS_KEPT_WITH_HELD", "INDEX_ROWS_SCORED",
     "STATE_SLOTS_STEPPED", "STATE_POOL_BYTES", "LATENT_RING_BYTES",
     "EVA_CHUNKS_CLOSED", "EVA_WINDOWS_CLOSED",
     "PREFIX_HITS", "PREFIX_MISSES", "PREFIX_INSERTS", "PREFIX_EVICTIONS",
@@ -209,6 +210,16 @@ INDEX_BLOCKS_SCORED = _mx.histogram(
     help="closed blocks of index keys a decode step scored in one sparse "
          "latent layer, over the live slots, one observation a step (a "
          "model whose indexer chooses the rows a query reads)")
+INDEX_ROWS_SCORED = _mx.histogram(
+    "serving/index_rows_scored",
+    help="context rows whose index key a decode step scored in one sparse "
+         "latent layer, over the live slots, one observation a step (a "
+         "model whose indexer keeps a key a ROW and chooses single rows)")
+MOE_GROUPS_KEPT_WITH_HELD = _mx.histogram(
+    "serving/moe_groups_kept_with_held",
+    help="live rows of which a group that a group-limited router kept "
+         "holds an expert this chip holds, one observation a layer a "
+         "decode step: the rows that CAN send this share a pair")
 INDEX_POOL_BYTES = _mx.gauge(
     "serving/index_pool_bytes",
     help="bytes of the pooled index keys and the open blocks' raw keys a "
@@ -253,6 +264,8 @@ _MODEL_STATS = {"moe_experts_touched": MOE_EXPERTS_TOUCHED,
                 "moe_held_pairs": MOE_HELD_PAIRS,
                 "state_slots_stepped": STATE_SLOTS_STEPPED,
                 "index_blocks_scored": INDEX_BLOCKS_SCORED,
+                "index_rows_scored": INDEX_ROWS_SCORED,
+                "moe_groups_kept_with_held": MOE_GROUPS_KEPT_WITH_HELD,
                 "ut_expected_exit_step": UT_EXPECTED_EXIT_STEP,
                 "eva_chunks_closed": EVA_CHUNKS_CLOSED,
                 "eva_windows_closed": EVA_WINDOWS_CLOSED}

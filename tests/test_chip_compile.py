@@ -1917,3 +1917,128 @@ def test_parallel_hybrid_decoder_prefill_holds_the_scan_kernel(chip,
     assert sum(k.startswith("%ssd_chunk_scan") for k in kernels) == 2
     assert not [rtype for _, rtype, op, _ in _instructions(text)
                 if op == "while" and "f32[32,256,128]" in rtype]
+
+
+def _dsv32_case(chip, n_layer=2):
+    """The row-choosing latent decoder at its published widths, as one
+    chip of sixteen holds it (16 of 256 experts, 16,160 rows of the
+    vocabulary): the dense layer and a sparse one, parameters as shapes."""
+    from paddle_tpu.models import deepseek_v32 as ds
+
+    scaling = {"beta_fast": 32, "beta_slow": 1, "factor": 40, "mscale": 1,
+               "mscale_all_dim": 1, "original_max_position_embeddings": 4096,
+               "type": "yarn"}
+    cfg = ds.DeepSeekV32Config(
+        vocab_size=16160, n_layer=n_layer, d_model=7168, n_head=128,
+        q_rank=1536, kv_rank=512, d_nope=128, d_rope=64, d_v=128,
+        index_heads=64, index_dim=128, index_topk=2048, d_dense=18432,
+        n_dense=1, n_expert=256, top_k=8, d_expert=2048, n_group=8,
+        topk_group=4, routed_scale=2.5, rope_theta=1e4, rope_scaling=scaling,
+        max_seq=16384, dtype="bfloat16", experts_held=tuple(range(16)))
+    model = ds.DeepSeekV32LM(cfg, params={})
+
+    def sds(x):
+        return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=chip)
+
+    params = jax.tree_util.tree_map(
+        sds, jax.eval_shape(lambda: ds.init_params(cfg, 0)))
+    return cfg, model, params, sds
+
+
+def test_row_choosing_decoder_decode_step(chip, monkeypatch):
+    """The decode step of the row-choosing latent decoder at its published
+    widths, the dense layer and a sparse one over the cell's two pools:
+    the index scores and the sparse read once a layer each under their
+    own names (and no dense latent call), the fused expert kernel, no ``top_k`` sort of the scores (the
+    choice is a bisection: the sorts left are the expert layer's and the
+    probe's); neither pool is copied or sliced, both are aliased from
+    input to output, and the step's scratch is a fraction of a GB."""
+    from paddle_tpu.serving.kv_cache import CacheGroup, LatentPagedCache
+
+    monkeypatch.setattr(attention_ops, "paged_kernel_mode",
+                        lambda: "compiled")
+    monkeypatch.setattr(attention_ops, "_on_tpu", lambda: True)
+    cfg, model, params, sds = _dsv32_case(chip)
+    groups = [CacheGroup(name, layers, window, 20480, kind)
+              for name, layers, window, kind in cfg.cache_groups]
+    ops = LatentPagedCache(2, 512, 64, 32, 16384, 16, 20480,
+                           dtype="bfloat16", groups=groups,
+                           index=cfg.index_row)
+    assert ops.index_kernel_mode() == ("compiled", None)
+    cache = jax.tree_util.tree_map(sds, jax.eval_shape(ops.init_state))
+    assert {k: v.shape for k, v in cache.items()} == {
+        "c": (2, 327680, 640), "ik": (2, 20480, 16, 128), "pt": (32, 1024)}
+    ints = jax.ShapeDtypeStruct((32,), jnp.int32, sharding=chip)
+    flags = jax.ShapeDtypeStruct((32,), jnp.bool_, sharding=chip)
+
+    def chunk(params, cache, lengths, tokens, active):
+        logits, cache, stats = model.decode(params, cache, ops, tokens,
+                                            lengths, active)
+        return cache, jnp.argmax(logits, -1), stats
+
+    exe = jax.jit(chunk, donate_argnums=(1,)).lower(
+        params, cache, ints, ints, flags).compile()
+    text = exe.as_text()
+    assert exe.memory_analysis().temp_size_in_bytes < 0.5e9
+    kernels = [ln.strip().split(" = ")[0] for ln in text.split("\n")
+               if "tpu_custom_call" in ln]
+    assert sum(k.startswith("%dsa_sparse_decode") for k in kernels) == 2
+    assert sum(k.startswith("%dsa_index_scores") for k in kernels) == 2
+    assert not any(k.startswith("%mla_latent_decode") for k in kernels)
+    stream, grouped = _expert_products(text, (16, 7168, 2048))
+    assert len(stream) >= 1 and grouped == 0
+    instructions = list(_instructions(text))
+    types = {name: rtype for name, rtype, _, _ in instructions}
+    # no sort over a slot's 16,384 scores, and no float reduce-window
+    assert [rtype for _, rtype, op, _ in instructions
+            if op == "sort" and _has_dim(rtype, 16384)] == []
+    assert [rtype for _, rtype, op, _ in instructions
+            if op == "reduce-window" and not rtype.startswith("s32")] == []
+    for rows, lanes in ((327680, 640), (20480, 128)):
+        moved = [(op, rtype) for _, rtype, op, operands in instructions
+                 if op in ("copy", "copy-start", "slice", "dynamic-slice",
+                           "transpose")
+                 and any(_has_dim(t, rows) and _has_dim(t, lanes)
+                         for t in [rtype] + [types.get(o, "")
+                                             for o in operands])]
+        assert moved == [], moved
+    n_params = len(jax.tree_util.tree_leaves(params))
+    aliased = {int(p) for p in re.findall(
+        r"\((\d+), \{\}, (?:may|must)-alias\)", text.split("\n", 1)[0])}
+    assert {n_params, n_params + 1} <= aliased      # "c" "ik" in key order
+
+
+def test_row_choosing_decoder_prefill_attends_in_one_kernel(chip,
+                                                            monkeypatch):
+    """The cell's one bucket's prefill of the dense layer and a sparse
+    one: the gate takes 128 heads of 192 lanes padded to 256, each layer's
+    attention under its rows' own masks is ONE ``dsa_prefill_attention``
+    call and its index scores ONE ``dsa_index_scores_prefill`` call, no
+    score of 128 heads (or product of 64 index heads) against the bucket's
+    keys is a float32 result of any instruction, no softmax became a
+    ``reduce-window``, and
+    two layers' scratch is under the 3.5 GB reckoned for the bucket."""
+    from paddle_tpu.ops.pallas_kernels import dsa_prefill
+
+    monkeypatch.setattr(attention_ops, "_on_tpu", lambda: True)
+    _, model, params, _ = _dsv32_case(chip)
+    assert dsa_prefill.dsa_prefill_gate(128, 256, 128, 8192, 1) is None
+    assert "whole 128-lane" in dsa_prefill.dsa_prefill_gate(128, 192, 128,
+                                                            8192, 1)
+    toks = jax.ShapeDtypeStruct((1, 8192), jnp.int32, sharding=chip)
+    lens = jax.ShapeDtypeStruct((1,), jnp.int32, sharding=chip)
+    exe = jax.jit(model.prefill_last).lower(params, toks, lens).compile()
+    text = exe.as_text()
+    assert exe.memory_analysis().temp_size_in_bytes < 3.5e9
+    kernels = [ln.strip().split(" = ")[0] for ln in text.split("\n")
+               if "tpu_custom_call" in ln]
+    assert sum(k.startswith("%dsa_prefill_attention") for k in kernels) == 2
+    assert sum(k.startswith("%dsa_index_scores_prefill")
+               for k in kernels) == 2
+    instructions = list(_instructions(text))
+    assert [rtype for _, rtype, op, _ in instructions
+            if op == "reduce-window" and not rtype.startswith("s32")] == []
+    # neither the 128 heads' scores nor the 64 index heads' products
+    scores = re.compile(r"f32\[(\d+,)*(128|64),\d+,8192\]")
+    assert [rtype for _, rtype, _, _ in instructions
+            if scores.search(rtype)] == []

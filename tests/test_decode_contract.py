@@ -32,12 +32,14 @@ from paddle_tpu.reliability import FaultPlan, faults
 from paddle_tpu.serving import metrics as sm
 
 MODELS = ["decoder_lm", "smallthinker", "kimi_k2", "laguna", "ling3_flash",
-          "motif3", "glm5_flash", "falcon_h1", "ouro", "evabyte"]
+          "motif3", "glm5_flash", "falcon_h1", "ouro", "evabyte",
+          "deepseek_v32"]
 EOS = 3
 TOL = dict(rtol=2e-4, atol=2e-4)
 # the one prompt bucket and the context budget: what each model's own tests
 # serve it at, where they differ from (16, 64)
-GEOMETRY = {"glm5_flash": (128, 256), "evabyte": (32, 160)}
+GEOMETRY = {"glm5_flash": (128, 256), "evabyte": (32, 160),
+            "deepseek_v32": (128, 256)}
 
 
 class Served:

@@ -1105,3 +1105,48 @@ def test_what_a_compacting_group_refuses_says_why(what, over):
                                                         [])):
             with pytest.raises(ValueError, match="compacting group"):
                 call()
+
+
+def test_a_row_choosing_latent_model_is_served_end_to_end(rng):
+    """The toy DeepSeek-V3.2 through ``submit``/``run``: admission reserves
+    ONE set of pages for the latent rows and the index keys beside them,
+    they return at retirement, and the counters read what the lengths
+    imply: a decode step at position p scores p + 1 rows of its slot and
+    reads ``min(p + 1, index_topk)`` of them, in the first layer."""
+    import test_deepseek_v32 as toy
+    from paddle_tpu.serving import metrics as sm
+
+    model = toy.toy_model()
+    cfg = serving.ServingConfig(slots=2, page_size=16, max_seq=256,
+                                prompt_buckets=(128,),
+                                group_pages={"latent_sparse": 20})
+    # (prompt, new tokens): decode consumes positions [n, n + m - 1)
+    plan = [(5, 20), (70, 12), (100, 9), (12, 3)]
+    read = sm.attn_rows_read("latent_sparse")
+    context = sm.attn_rows_context("latent_sparse")
+    before = (read.sum, context.sum, sm.INDEX_ROWS_SCORED.sum,
+              sm.MOE_GROUPS_KEPT_WITH_HELD.count, read.count)
+    with serving.ServingEngine(model, cfg) as eng:
+        ops = eng.cache_ops
+        assert eng.pool.name == "latent_sparse" and ops.index == (1, 16, 16)
+        assert set(eng._cache) == {"c", "ik", "pt"}
+        assert eng._cache["ik"].shape == (4, 20, 16, 16)
+        reqs = [eng.submit(rng.randint(0, 96, n).tolist(), m)
+                for n, m in plan]
+        eng.step()
+        assert [len(r.pages) for r in reqs[:2]] == [2, 6]   # 25 and 82 rows
+        assert eng.pool.num_used == sm.pages_used("latent_sparse").value == 8
+        eng.run()
+        assert all(r.state == "finished" for r in reqs)
+        assert [len(r.tokens_out) for r in reqs] == [m for _, m in plan]
+        assert eng.pool.num_used == 0 and eng.page_accounting_ok()
+        _, stats = eng.last_decode_stats
+        assert np.asarray(stats["dsa_probe"]).shape[-1] == 1 + 16
+    consumed = [range(n, n + m - 1) for n, m in plan]
+    assert context.sum - before[1] == sm.INDEX_ROWS_SCORED.sum - before[2] \
+        == sum(p + 1 for r in consumed for p in r)
+    assert read.sum - before[0] == sum(min(p + 1, 16)
+                                       for r in consumed for p in r)
+    # an observation an expert layer (3 of the toy's 4) a decode step
+    assert sm.MOE_GROUPS_KEPT_WITH_HELD.count - before[3] \
+        == 3 * (read.count - before[4])
