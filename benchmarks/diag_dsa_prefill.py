@@ -5,7 +5,10 @@ in both its forms (the blocked XLA at several ``block_q`` and the
 their results, the seconds one copy of the kernel takes to lower and to
 compile, the kernel alone under a mask made before, and with ``--sweep``
 the kernel at other tiles), index scores and
-selection each alone, and one KDA layer's chunk scan at 64
+selection each alone (``--length``: the prompt in the bucket, which the
+kernel's path is told: its query blocks past it are neither chosen nor
+attended, and the gap is read over the rows under it), and one KDA layer's
+chunk scan at 64
 and at 32 heads in both its forms (the XLA loop and the ``kda_chunk_scan``
 kernel: their times, the largest gap between their results, the seconds
 one copy of the kernel takes to lower and to compile, and a KDA layer's
@@ -38,8 +41,9 @@ def best_ms(fn, *args, reps):
     return min(times)
 
 
-def dsa_part(out, s, reps, trace_dir, sweep=()):
-    """The DSA layer's masked attention at GLM's widths, ``s`` rows."""
+def dsa_part(out, s, reps, trace_dir, sweep=(), length=None):
+    """The DSA layer's masked attention at GLM's widths, ``s`` rows of
+    which ``length`` are the prompt's (None: all)."""
     import jax
     import jax.numpy as jnp
 
@@ -56,14 +60,16 @@ def dsa_part(out, s, reps, trace_dir, sweep=()):
     qi = jax.random.normal(ks[3], (s, hi, li), bf)
     wi = jax.random.normal(ks[4], (s, hi), jnp.float32)
     kp = jax.random.normal(ks[5], (s // kpool, li), bf)
+    live = s if length is None else int(length)
+    n = jnp.asarray(live, jnp.int32)    # traced: one executable a bucket
     if trace_dir:
         from grid import reduce
 
-        fn = jax.jit(lambda *a: ao.dsa_causal_attention(
-            *a, kpool, top, 0.0625))
-        jax.block_until_ready(fn(q, k, v, qi, wi, kp))
+        fn = jax.jit(lambda n, *a: ao.dsa_causal_attention(
+            *a, kpool, top, 0.0625, length=n))
+        jax.block_until_ready(fn(n, q, k, v, qi, wi, kp))
         with jax.profiler.trace(trace_dir):
-            jax.block_until_ready(fn(q, k, v, qi, wi, kp))
+            jax.block_until_ready(fn(n, q, k, v, qi, wi, kp))
         trace = reduce.load(reduce.find_xplane(trace_dir))
         out["device_ops_ms"] = [
             [name, round(t * 1e3, 2)] for name, t in
@@ -82,11 +88,12 @@ def dsa_part(out, s, reps, trace_dir, sweep=()):
     finally:
         ao._on_tpu = on_tpu
     out["dsa_prefill_gate"] = dp.dsa_prefill_gate(h, d, d, s, kpool)
-    fn = jax.jit(lambda *a: ao.dsa_causal_attention(*a, kpool, top, 0.0625))
+    fn = jax.jit(lambda n, *a: ao.dsa_causal_attention(
+        *a, kpool, top, 0.0625, length=n))
     out["dsa_causal_attention_ms.kernel"] = best_ms(
-        fn, q, k, v, qi, wi, kp, reps=reps)
-    gap = jnp.abs(fn(q, k, v, qi, wi, kp).astype(jnp.float32)
-                  - blocked.astype(jnp.float32))
+        fn, n, q, k, v, qi, wi, kp, reps=reps)
+    gap = jnp.abs(fn(n, q, k, v, qi, wi, kp).astype(jnp.float32)
+                  - blocked.astype(jnp.float32))[:live]
     out["dsa_causal_attention_gap"] = float(gap.max())
     out["dsa_causal_attention_gap_mean"] = float(gap.mean())
     out["dsa_prefill_calls"] = {
@@ -98,12 +105,12 @@ def dsa_part(out, s, reps, trace_dir, sweep=()):
     mask = jnp.tril(jnp.ones((s, s), jnp.int8))
     t0 = time.perf_counter()
     lowered = jax.jit(lambda *a: dp.dsa_prefill_attention(
-        *a, sm_scale=0.0625)).lower(q, k, v, mask)
+        *a, sm_scale=0.0625)).lower(q, k, v, mask, n)
     t1 = time.perf_counter()
     alone = lowered.compile()
     out["dsa_prefill_attention_lower_s"] = t1 - t0
     out["dsa_prefill_attention_compile_s"] = time.perf_counter() - t1
-    out["dsa_prefill_attention_ms"] = best_ms(alone, q, k, v, mask,
+    out["dsa_prefill_attention_ms"] = best_ms(alone, q, k, v, mask, n,
                                               reps=reps)
     for tiles in sweep:
         bq, bk, g = (int(x) for x in tiles.split("x"))
@@ -111,7 +118,7 @@ def dsa_part(out, s, reps, trace_dir, sweep=()):
             *a, sm_scale=0.0625, block_q=bq, block_k=bk, heads=g))
         try:
             out["dsa_prefill_attention_ms.%s" % tiles] = best_ms(
-                fn, q, k, v, mask, reps=reps)
+                fn, q, k, v, mask, n, reps=reps)
         except Exception as e:      # a tile the chip's compiler refuses
             out["dsa_prefill_attention_ms.%s" % tiles] = repr(e)[:200]
 
@@ -191,6 +198,8 @@ def kda_part(out, s, reps, heads=(64, 32), dk=128, d=2560):
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--rows", type=int, default=8192)
+    ap.add_argument("--length", type=int, default=None,
+                    help="the prompt's rows in the bucket of --rows (all)")
     ap.add_argument("--reps", type=int, default=3)
     ap.add_argument("--parts", default="dsa,kda",
                     help="which layers' parts to time")
@@ -206,11 +215,12 @@ def main(argv=None) -> int:
     if jax.default_backend() != "tpu":
         print("diag_dsa_prefill: no TPU", file=sys.stderr)
         return 3
-    out = {"rows": args.rows, "device": jax.devices()[0].device_kind}
+    out = {"rows": args.rows, "length": args.length or args.rows,
+           "device": jax.devices()[0].device_kind}
     parts = args.parts.split(",")
     if "dsa" in parts:
         dsa_part(out, args.rows, args.reps, args.trace,
-                 [t for t in args.sweep.split(",") if t])
+                 [t for t in args.sweep.split(",") if t], args.length)
     if "kda" in parts:
         kda_part(out, args.rows, args.reps)
     print(json.dumps(out))

@@ -36,7 +36,14 @@ through a table and XLA's attention over them (the off-chip path of
 
 ``--parts prefill``: the prefill's two kernels against their XLA forms at
 4,096 rows and the served widths: the index scores' values and time, and a
-layer's masked attention by the kernels' path against the blocked one.
+layer's masked attention by the kernels' path against the blocked one;
+then the prefill's three sparse-attention passes ALONE in the served
+bucket of 8,192 rows at prompts of 4,608, 6,144 and 8,192 rows (the index
+scores' kernel, the selection's loop over query blocks, the masked
+attention's kernel; every call's operand the chain's carry, so that nothing
+is hoisted out of the chain), each beside the share of its time at 8,192
+rows that the rows a token can read predict: ``L^2 - 2,048^2``, ``L -
+2,048`` and ``L^2``.
 
 ``--cell <grid.run's arguments>``: the cell's own traced run, and after its
 last line the decode and prefill executables' device seconds of the traced
@@ -347,6 +354,82 @@ def part_prefill(rng):
           "out_std": float(jnp.std(blocked.astype(jnp.float32))),
           "rows_off_by_0.05": int(rows_off.sum()),
           "first_rows_off": np.nonzero(np.asarray(rows_off))[0][:8].tolist()})
+    del q, k, v, kernels, blocked, diff
+    part_prefill_lengths(rng)
+
+
+def part_prefill_lengths(rng, s=8192, lengths=(4608, 6144, 8192)):
+    """The three sparse-attention passes of one layer's prefill ALONE in a
+    bucket of ``s`` rows told each of ``lengths``: microseconds a call by
+    the chain's slope, and its share of the same pass's time at ``s`` rows
+    beside what the rows a token can read predict."""
+    import jax.numpy as jnp
+
+    from paddle_tpu.ops import attention_ops
+    from paddle_tpu.ops.pallas_kernels import dsa_index, dsa_prefill
+
+    bf, f32 = jnp.bfloat16, jnp.float32
+    q_idx = jnp.asarray(rng.standard_normal((s, INDEX_HEADS, INDEX_LANES)),
+                        bf)
+    w_idx = jnp.asarray(rng.standard_normal((s, INDEX_HEADS)) * 0.1, f32)
+    k_idx = jnp.asarray(rng.standard_normal((s, INDEX_LANES)), bf)
+    # the attention kernel's own operands: a head's 192 lanes padded to 256
+    q = jnp.asarray(rng.standard_normal((s, HEADS, 256)) * 1.5, bf)
+    k = jnp.asarray(rng.standard_normal((s, HEADS, 256)), bf)
+    v = jnp.asarray(rng.standard_normal((s, HEADS, 128)), bf)
+    tril = jnp.tril(jnp.ones((s, s), jnp.int8))
+    scores = dsa_index.dsa_index_scores_prefill(q_idx, w_idx, k_idx)
+    scores = jnp.where(tril != 0, scores, 0.0)      # no tile of garbage
+    bq = 256                    # dsa_rows_causal_attention's query block
+
+    def score(qi, i, w, ki, n):
+        sc = dsa_index.dsa_index_scores_prefill(
+            qi, w, ki, -(-n // bq) * bq, first=TOPK // bq * bq)
+        # rows every length reads, columns under their causal edge
+        return qi.at[:8, 0, :].add(
+            (sc[TOPK:TOPK + 8, :INDEX_LANES] * 1e-6).astype(bf))
+
+    def choose(sc, i, qq, kk, vv, qi, ki, n):
+        # the selection's loop alone: the two kernels stand aside, the
+        # scores' handing back the carry (passed as the weights, which
+        # nothing else reads) and the attention's the mask it was handed
+        real = (dsa_index.dsa_index_scores_prefill,
+                dsa_prefill.dsa_prefill_attention)
+        dsa_index.dsa_index_scores_prefill = \
+            lambda q_idx, w_idx, k_idx, end=None, **kw: w_idx
+        dsa_prefill.dsa_prefill_attention = \
+            lambda q, k, v, mask, length=None, **kw: mask
+        try:
+            mask = attention_ops.dsa_rows_causal_attention(
+                qq[..., :192], kk[..., :192], vv, qi, sc, ki, TOPK, SCALE,
+                length=n)
+        finally:
+            (dsa_index.dsa_index_scores_prefill,
+             dsa_prefill.dsa_prefill_attention) = real
+        return sc.at[0, :1].add(jnp.sum(mask[::bq].astype(f32)) * 1e-9)
+
+    def attend(qq, i, kk, vv, mask, n):
+        o = dsa_prefill.dsa_prefill_attention(qq, kk, vv, mask, n,
+                                              sm_scale=SCALE)
+        return qq.at[:8, :, :128].add(o[:8] * 1e-3)
+
+    predicted = {
+        "index_scores": lambda n: (n * n - TOPK * TOPK)
+        / (s * s - TOPK * TOPK),
+        "selection": lambda n: (n - TOPK) / (s - TOPK),
+        "masked_attention": lambda n: n * n / (s * s)}
+    passes = (("index_scores", score, q_idx, (w_idx, k_idx)),
+              ("selection", choose, scores, (q, k, v, q_idx, k_idx)),
+              ("masked_attention", attend, q, (k, v, tril)))
+    for name, step, carry, consts in passes:
+        took = {n: slope_us(step, carry, consts + (jnp.int32(n),))
+                for n in lengths}
+        for n in lengths:
+            emit({"part": "prefill", "what": "pass_alone_at_a_length",
+                  "pass": name, "bucket": s, "length": n,
+                  "us_a_layer": took[n],
+                  "share_of_the_bucket's": took[n] / took[s],
+                  "share_predicted": predicted[name](n)})
 
 
 def parts(names) -> int:

@@ -25,8 +25,10 @@ particular to serving it:
   no row is forced in, and a context of at most 2,048 rows is read whole;
 * PREFILL expands K and V and attends under a mask that is each ROW's own
   (``ops.attention_ops.dsa_rows_causal_attention``: row t's 2,048 of its
-  prefix AND the causal triangle), and hands the cache the rows and the
-  index keys;
+  prefix AND the causal triangle; told the prompt's length, so that a
+  query block past it is neither scored, chosen nor attended and one under
+  ``index_topk`` reads its prefix with nothing scored), and hands the cache
+  the rows and the index keys;
 * the indexer's first ``d_rope`` lanes are rotated at the row's position,
   rotate-half, at the attention's own frequencies (the reference says how
   that relates to the family's code);
@@ -232,9 +234,11 @@ def _probed(chosen, topk: int):
     return jnp.where(held[0], rows[0], -1)
 
 
-def _attend_prefill(cfg, lp, h, pos):
+def _attend_prefill(cfg, lp, h, pos, length):
     """One sequence's attention half, K and V EXPANDED from the latent:
-    ``(y [S, d], row [S, rank + rope], index keys [S, L])``."""
+    ``(y [S, d], row [S, rank + rope], index keys [S, L])``; ``length`` of
+    the S rows are the prompt's, and the query blocks past it are not
+    attended (``y`` is zero there)."""
     s = h.shape[0]
     q_n, q_r, row, q_idx, w_idx, k_idx = _inputs(cfg, lp, h, pos)
     kv = (row[:, :cfg.kv_rank] @ lp["wkvb"]).reshape(
@@ -246,7 +250,7 @@ def _attend_prefill(cfg, lp, h, pos):
     o = attention_ops.dsa_rows_causal_attention(
         jnp.concatenate([q_n, q_r], axis=-1), k, kv[..., cfg.d_nope:],
         q_idx, w_idx, k_idx, cfg.index_topk, cfg.sm_scale,
-        score_dtype=cfg.score_dtype)
+        score_dtype=cfg.score_dtype, length=length)
     return o.reshape(s, -1) @ lp["wo"], row, k_idx
 
 
@@ -254,9 +258,12 @@ def prefill_forward(params: Dict, cfg: DeepSeekV32Config, tokens, lengths):
     """Causal forward over bucket-padded prompts ``tokens`` [B, S]. Returns
     ``(x [B, S, d] before the final norm, kept)`` with ``kept`` a layer
     ``(row [B, S, rank + rope], index keys [B, S, L])``: what the cache's
-    ``write_prompt`` takes. A padding position's row and key are garbage
-    that no valid row reads (causality), and the routed experts do not
-    compute it."""
+    ``write_prompt`` takes. A padding position's latent row and index key
+    are still garbage, which ``write_prompt`` places by length and no valid
+    row reads (causality); neither the three sparse-attention passes (index
+    scores, selection, masked attention: a query block past the prompt's
+    end comes out of attention as ZEROS) nor the routed experts compute
+    it."""
     b, s = tokens.shape
     x = params["tok_emb"][tokens]
     pos = jnp.arange(s)
@@ -264,7 +271,7 @@ def prefill_forward(params: Dict, cfg: DeepSeekV32Config, tokens, lengths):
     kept = []
     for lp in params["layers"]:
         h = rms_norm(x, lp["g1"], cfg.rms_eps)
-        ys, *keep = zip(*(_attend_prefill(cfg, lp, h[j], pos)
+        ys, *keep = zip(*(_attend_prefill(cfg, lp, h[j], pos, lengths[j])
                           for j in range(b)))
         kept.append(tuple(jnp.stack(t) for t in keep))
         x = x + jnp.stack(ys)

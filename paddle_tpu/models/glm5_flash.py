@@ -26,8 +26,9 @@ serving it:
   512-lane rows);
 * PREFILL expands K and V and attends under a mask that is each ROW's own
   (``ops.attention_ops.dsa_causal_attention``: a selection depends on its
-  query), by blocks of query rows, and hands the cache the rows, the
-  pooled keys and the open block's keys;
+  query), by blocks of query rows up to the prompt's end (a block past it
+  comes back as zeros), and hands the cache the rows, the pooled keys and
+  the open block's keys;
 * position reaches the latent layer through the KDA layers and the
   indexer only (``qk_rope_head_dim`` 0): the indexer's first 64 lanes are
   rotated, pairs interleaved;
@@ -349,7 +350,7 @@ def _dsa_prefill(cfg, lp, h, pos, length):
     pooled = _pooled_keys(cfg, k_idx)
     o = attention_ops.dsa_causal_attention(
         q, kv[..., :cfg.d_nope], kv[..., cfg.d_nope:], q_idx, w_idx, pooled,
-        kpool, cfg.index_topk // kpool, cfg.sm_scale)
+        kpool, cfg.index_topk // kpool, cfg.sm_scale, length=length)
     tail = jax.lax.dynamic_slice_in_dim(
         jnp.pad(k_idx, ((0, kpool - 1), (0, 0))), length // kpool * kpool,
         kpool - 1, axis=0)

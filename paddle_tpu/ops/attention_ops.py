@@ -817,20 +817,28 @@ def dsa_chosen_rows(chosen, topk: int):
 
 
 def _rows_masked_attention(q, k, v, mask_of, per_row, sm_scale, bq: int,
-                           kernel: bool):
+                           kernel: bool, length, whole: int):
     """Attention of ONE sequence in which row t reads the rows ``mask_of(i,
     *block of per_row)`` [bq, S] bool says (the caller's causal triangle in
     it), ``bq`` query rows at a time: by ONE ``dsa_prefill_attention``
     kernel over the rows' masks as ``int8 [S, S]`` where ``kernel``, else
     the BLOCKED form (the float32 scores of ``bq`` rows against the whole
-    sequence held at a time)."""
+    sequence held at a time). A query block does only what a token can
+    read, under ONE ``lax.switch`` in either form: a block that starts at
+    or past ``length`` (an int32 scalar or None, the rows that are the
+    sequence's) asks ``mask_of`` nothing, reads nothing and comes back as
+    ZEROS; a block whose last row lies under ``whole`` (the rows that read
+    their whole prefix, whatever ``mask_of`` would score) is the causal
+    triangle itself, ``mask_of`` not asked; every other block is
+    ``mask_of``'s. Rows past the length in the last live block are finite
+    and nobody's to read."""
     from .pallas_kernels import dsa_prefill
 
     s = q.shape[0]
+    cols = jnp.arange(s)[None, :]
+    length = s if length is None else length
 
-    def rows_of(args):
-        i, qb, *rest = args
-        mask = mask_of(i, *rest)
+    def attend(qb, mask):
         with jax.named_scope("attn/dsa_sparse"):
             sc = jnp.einsum("qhd,khd->hqk", qb, k,
                             preferred_element_type=jnp.float32) * sm_scale
@@ -847,24 +855,40 @@ def _rows_masked_attention(q, k, v, mask_of, per_row, sm_scale, bq: int,
                            preferred_element_type=jnp.float32)
             return (o / total.T[:, :, None]).astype(q.dtype)
 
+    def block(i, rest, dead, under):
+        """Block ``i``'s part: ``dead`` past the length, else ``under`` its
+        rows' mask."""
+        rows = i * bq + jnp.arange(bq)
+        kind = jnp.where(i * bq >= length, 0,
+                         jnp.where((i + 1) * bq <= whole, 1, 2))
+        return jax.lax.switch(
+            kind, (lambda *_: dead,
+                   lambda *_: under(cols <= rows[:, None]),
+                   lambda *rest: under(mask_of(i, *rest))), *rest)
+
     def split(x):
         return x.reshape((s // bq, bq) + x.shape[1:])
 
     blocks = jnp.arange(s // bq)
     if kernel:
         mask = jax.lax.map(
-            lambda a: mask_of(*a).astype(jnp.int8),
+            lambda a: block(a[0], a[1:], jnp.zeros((bq, s), jnp.int8),
+                            lambda m: m.astype(jnp.int8)),
             (blocks,) + tuple(split(x) for x in per_row))
         with jax.named_scope("attn/dsa_sparse"):
             return dsa_prefill.dsa_prefill_attention(
-                q, k, v, mask.reshape(s, s), sm_scale=float(sm_scale))
-    out = jax.lax.map(rows_of, (blocks, split(q))
-                      + tuple(split(x) for x in per_row))
+                q, k, v, mask.reshape(s, s), length,
+                sm_scale=float(sm_scale))
+    out = jax.lax.map(
+        lambda a: block(a[0], a[2:], jnp.zeros((bq,) + v.shape[1:], q.dtype),
+                        functools.partial(attend, a[1])),
+        (blocks, split(q)) + tuple(split(x) for x in per_row))
     return out.reshape((s,) + out.shape[2:])
 
 
 def dsa_causal_attention(q, k, v, q_idx, w_idx, k_pool, kpool: int,
-                         top_blocks: int, sm_scale=1.0, block_q: int = 256):
+                         top_blocks: int, sm_scale=1.0, block_q: int = 256,
+                         length=None):
     """Causal attention of ONE sequence in which every query row reads the
     rows its indexer chose: ``q``/``k`` [S, H, D], ``v`` [S, H, Dv]
     EXPANDED; ``q_idx`` [S, Hi, L], ``w_idx`` [S, Hi] the rows' index
@@ -882,7 +906,14 @@ def dsa_causal_attention(q, k, v, q_idx, w_idx, k_pool, kpool: int,
     held at a time, never the [S, S] of all (on a v5e at S = 8,192 and 64
     heads of 256: 131, 106 and 93 ms a layer at 128, 256 and 512 rows a
     block; PERF.md, PR 47). ``dsa/prefill_calls.kernel`` and ``.blocked``
-    count which. Returns [S, H, Dv]."""
+    count which. ``length`` (an int32 scalar; None: S): the rows that are
+    the sequence's, the rest its bucket's padding. A query block past it
+    is neither scored, chosen nor attended and comes back as ZEROS
+    (:func:`_rows_masked_attention`; the kernel's query blocks are its own,
+    larger: zeros from the first of THOSE that starts past the length), and
+    a block whose rows all lie under ``top_blocks x kpool`` keeps every
+    closed block, so it is the causal triangle with no score computed.
+    Returns [S, H, Dv]."""
     from .pallas_kernels import dsa_prefill
 
     s, n_head, d = q.shape
@@ -902,12 +933,13 @@ def dsa_causal_attention(q, k, v, q_idx, w_idx, k_pool, kpool: int,
             return jnp.repeat(chosen, kpool, axis=1) & (cols <= rows[:, None])
 
     return _rows_masked_attention(q, k, v, mask_of, (q_idx, w_idx), sm_scale,
-                                  bq, kernel)
+                                  bq, kernel, length, top_blocks * kpool)
 
 
 def dsa_rows_causal_attention(q, k, v, q_idx, w_idx, k_idx, topk: int,
                               sm_scale=1.0, block_q: int = 256,
-                              select=None, score_dtype=jnp.float32):
+                              select=None, score_dtype=jnp.float32,
+                              length=None):
     """:func:`dsa_causal_attention` where the choice is of single ROWS and
     none is forced in (DeepSeek-V3.2's own form): ``k_idx`` [S, L] one
     index key a row; row t reads the ``topk`` rows s <= t of highest index
@@ -922,8 +954,14 @@ def dsa_rows_causal_attention(q, k, v, q_idx, w_idx, k_idx, topk: int,
     the blocked form at D as it is. The index scores, too, are ONE kernel
     a layer there (``dsa_index.dsa_index_scores_prefill``: ``[S, S]``
     float32 and no ``[block_q, Hi, S]`` products through HBM;
-    ``dsa/prefill_index_calls.kernel|blocked`` count which). Returns [S,
-    H, Dv]."""
+    ``dsa/prefill_index_calls.kernel|blocked`` count which). ``length`` (an
+    int32 scalar; None: S): the rows that are the sequence's. A query
+    block past it is neither scored, chosen nor attended and comes back as
+    ZEROS, and a block whose rows all lie under ``topk`` is the causal
+    triangle with no score computed and no selection run, in both forms
+    (:func:`_rows_masked_attention`); the scores' kernel is told both, and
+    computes only the query blocks between. On every row under the length
+    the result is what it is with no length given. Returns [S, H, Dv]."""
     from .pallas_kernels import dsa_index, dsa_prefill
 
     s, n_head, d = q.shape
@@ -960,12 +998,18 @@ def dsa_rows_causal_attention(q, k, v, q_idx, w_idx, k_idx, topk: int,
     if kernel and wide != d:
         q, k = (jnp.pad(x, ((0, 0), (0, 0), (0, wide - d))) for x in (q, k))
     if scored:
+        # the scores' kernel computes the query blocks the switch reads:
+        # from the first that holds a row at or past ``topk`` to the last
+        # that holds a row of the sequence
         with jax.named_scope("attn/dsa_index"):
-            scores = dsa_index.dsa_index_scores_prefill(q_idx, w_idx, k_idx)
+            scores = dsa_index.dsa_index_scores_prefill(
+                q_idx, w_idx, k_idx,
+                None if length is None else -(-length // bq) * bq,
+                first=topk // bq * bq)
         return _rows_masked_attention(q, k, v, chosen_of, (scores,), sm_scale,
-                                      bq, kernel)
+                                      bq, kernel, length, topk)
     return _rows_masked_attention(q, k, v, mask_of, (q_idx, w_idx), sm_scale,
-                                  bq, kernel)
+                                  bq, kernel, length, topk)
 
 
 def differential_combine(o, lam, n_kv: int):

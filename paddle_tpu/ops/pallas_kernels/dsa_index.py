@@ -30,8 +30,13 @@ heads, 34 GB a layer); here a tile of ``_BLOCK_K`` keys by ``_BLOCK_Q``
 queries accumulates the heads' weighted ReLUs in VMEM and ``[S, S]``
 float32 is all that is written, keys down the sublanes and queries along
 the lanes (a head's weight is then a row vector, as it lies), the tiles
-wholly past a query block neither copied nor computed. The call's name is
-``dsa_index_scores_prefill``; :func:`dsa_index_prefill_gate` is its gate.
+wholly past a query block neither copied nor computed; nor are the query
+blocks whose scores nobody reads, which the caller names by two rows
+(``first``: the rows before it read their whole prefix; ``end``, a
+scalar-prefetch operand: the rows from it on lie past the prompt in its
+bucket). What a tile that was not computed holds is whatever the buffer
+held. The call's name is ``dsa_index_scores_prefill``;
+:func:`dsa_index_prefill_gate` is its gate.
 """
 
 from __future__ import annotations
@@ -250,15 +255,17 @@ def dsa_index_prefill_gate(heads: int, lanes: int, s: int,
     return None
 
 
-def _prefill_kernel(q_ref, w_ref, k_ref, o_ref, acc, *, heads, block_q,
-                    block_k):
-    """One tile: ``q_ref`` [Hi, bq, L], ``w_ref`` [Hi, bq] float32,
-    ``k_ref`` [bk, L]; ``o_ref`` [bk, bq] float32, keys by queries."""
+def _prefill_kernel(end_ref, q_ref, w_ref, k_ref, o_ref, acc, *, heads,
+                    block_q, block_k, first):
+    """One tile: ``end_ref`` [1] the first row nobody reads the scores of,
+    ``q_ref`` [Hi, bq, L], ``w_ref`` [Hi, bq] float32, ``k_ref`` [bk, L];
+    ``o_ref`` [bk, bq] float32, keys by queries."""
     i, j = pl.program_id(0), pl.program_id(1)
+    read = ((i + 1) * block_q > first) & (i * block_q < end_ref[0])
 
     # a tile whose first key lies past the block's last row holds nothing
     # a row may choose: the caller's causal mask drops what is left there
-    @pl.when(j * block_k < (i + 1) * block_q)
+    @pl.when(read & (j * block_k < (i + 1) * block_q))
     def _():
         acc[...] = jnp.zeros(acc.shape, jnp.float32)
         k = k_ref[...]
@@ -274,42 +281,60 @@ def _prefill_kernel(q_ref, w_ref, k_ref, o_ref, acc, *, heads, block_q,
         o_ref[...] = acc[...]
 
 
-@functools.partial(jax.jit, static_argnames=("block_q", "block_k",
+@functools.partial(jax.jit, static_argnames=("first", "block_q", "block_k",
                                              "interpret"))
-def dsa_index_scores_prefill(q_idx, w_idx, k_idx, *, block_q: int = _BLOCK_Q,
+def dsa_index_scores_prefill(q_idx, w_idx, k_idx, end=None, *,
+                             first: int = 0, block_q: int = _BLOCK_Q,
                              block_k: int = _BLOCK_K,
                              interpret: bool = False):
     """``q_idx`` [S, Hi, L], ``w_idx`` [S, Hi] float32, ``k_idx`` [S, L]
-    of ONE sequence. Returns ``I`` [S, S] float32, ``I[t, s] = sum_j w[t,
-    j] ReLU(q[t, j] . k[s])`` wherever ``s <= t`` (the tiles wholly past a
-    query block hold whatever the buffer held: the caller masks by
-    causality). Jitted, so that the layers of one executable lower ONE
-    kernel text."""
+    of ONE sequence; ``first`` (static) and ``end`` (an int32 scalar; None:
+    S) the rows whose scores are read, ``first <= t < end``. Returns ``I``
+    [S, S] float32, ``I[t, s] = sum_j w[t, j] ReLU(q[t, j] . k[s])``
+    wherever ``s <= t`` in every query block of ``block_q`` rows that holds
+    such a row. The tiles wholly past a query block, and the query blocks
+    that hold no such row (neither copied nor computed), hold whatever the
+    buffer held: the caller masks by causality and reads no row outside
+    the two. Jitted, so that the layers of one executable lower ONE kernel
+    text."""
     s, heads, lanes = q_idx.shape
     why_not = dsa_index_prefill_gate(heads, lanes, s, interpret=interpret)
     if why_not is not None:
         raise ValueError(why_not)
     unit = 1 if interpret else _LANES
     bq, bk = _tile(block_q, s, unit), _tile(block_k, s, unit)
+    first = min(int(first), s)
 
-    def last(i):                # the last key tile a query block scores
-        return ((i + 1) * bq - 1) // bk
+    def at(i, j, end_ref):
+        """The (query block, key tile) a grid step reads: its own up to
+        the block's causal edge, then the edge's; of a block nobody reads,
+        the first tile of the first block that is read (before it) or the
+        last tile of the last (after it): held in VMEM, nothing copied."""
+        lo = min(first // bq, s // bq - 1)
+        hi = jnp.maximum(jnp.maximum(end_ref[0] - 1, 0) // bq, lo)
+        block = jnp.clip(i, lo, hi)
+        edge = ((block + 1) * bq - 1) // bk
+        return block, jnp.where(i < lo, 0, jnp.where(
+            i > hi, edge, jnp.minimum(j, edge)))
 
-    out = pl.pallas_call(
-        functools.partial(_prefill_kernel, heads=heads, block_q=bq,
-                          block_k=bk),
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
         grid=(s // bq, s // bk),
         in_specs=[
-            pl.BlockSpec((heads, bq, lanes), lambda i, j: (0, i, 0)),
-            pl.BlockSpec((heads, bq), lambda i, j: (0, i)),
-            pl.BlockSpec((bk, lanes),
-                         lambda i, j: (jnp.minimum(j, last(i)), 0))],
-        out_specs=pl.BlockSpec((bk, bq), lambda i, j: (j, i)),
+            pl.BlockSpec((heads, bq, lanes), lambda *st: (0, at(*st)[0], 0)),
+            pl.BlockSpec((heads, bq), lambda *st: (0, at(*st)[0])),
+            pl.BlockSpec((bk, lanes), lambda *st: (at(*st)[1], 0))],
+        out_specs=pl.BlockSpec((bk, bq), lambda i, j, _: (j, i)),
+        scratch_shapes=[pltpu.VMEM((bk, bq), jnp.float32)])
+    out = pl.pallas_call(
+        functools.partial(_prefill_kernel, heads=heads, block_q=bq,
+                          block_k=bk, first=first),
+        grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((s, s), jnp.float32),
-        scratch_shapes=[pltpu.VMEM((bk, bq), jnp.float32)],
         interpret=interpret, name=PREFILL_KERNEL_NAME,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
             vmem_limit_bytes=_VMEM_LIMIT),
-    )(q_idx.transpose(1, 0, 2), w_idx.astype(jnp.float32).T, k_idx)
+    )(jnp.asarray(s if end is None else end, jnp.int32).reshape(1),
+      q_idx.transpose(1, 0, 2), w_idx.astype(jnp.float32).T, k_idx)
     return out.T

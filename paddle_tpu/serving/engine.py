@@ -1106,6 +1106,8 @@ class ServingEngine:
         _trace.on_prefill(req, slot, bucket, t0, t1, cause="local")
         _sm.PREFILL_MS.observe((t1 - t0) * 1e3)
         _sm.PREFILL_COUNT.inc()
+        _sm.PREFILL_ROWS_PROMPT.inc(req.prompt_len)
+        _sm.PREFILL_ROWS_BUCKET.inc(bucket)
         self._prefills += 1
         return self._finish_prefill(req, slot, tok, last_logits)
 

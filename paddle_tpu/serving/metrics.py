@@ -5,6 +5,12 @@ decode driver never race a get-or-create, and tools
 (``tools/dump_metrics --selftest``) can assert the full set exists by
 importing this module alone. Same hot-path contract as the executor
 instruments: module-level handles, a single disabled-branch per call.
+
+``serving/prefill_rows.prompt`` and ``serving/prefill_rows.bucket`` count,
+a cold prefill, the rows that were the prompt's and the rows of the bucket
+it ran in: their ratio is the share of a prefill's rows that a token can
+read, which is what the passes that stop at the prompt's end (the routed
+experts, the sparse-attention prefill) still compute.
 """
 
 from __future__ import annotations
@@ -15,7 +21,8 @@ __all__ = [
     "REQUESTS_SUBMITTED", "REQUESTS_ADMITTED", "REQUESTS_RETIRED",
     "REQUESTS_REJECTED", "QUEUE_DEPTH", "SLOT_OCCUPANCY",
     "PAGES_IN_USE", "PAGE_POOL_UTILIZATION", "ADMISSION_BLOCKED",
-    "PREFILL_COUNT", "DECODE_STEPS", "DECODE_DISPATCHES",
+    "PREFILL_COUNT", "PREFILL_ROWS_PROMPT", "PREFILL_ROWS_BUCKET",
+    "DECODE_STEPS", "DECODE_DISPATCHES",
     "DECODE_LAUNCHED_AHEAD",
     "TOKENS_GENERATED", "CYCLES", "SAMPLER_DISPATCHES",
     "REQUEST_LATENCY_MS", "TTFT_MS", "DECODE_STEP_MS", "PREFILL_MS",
@@ -56,6 +63,17 @@ ADMISSION_BLOCKED = _mx.counter(
          "cover the request's worst-case page need (backpressure, not crash)")
 PREFILL_COUNT = _mx.counter(
     "serving/prefills", help="compiled prefill invocations")
+PREFILL_ROWS_PROMPT = _mx.counter(
+    "serving/prefill_rows.prompt",
+    help="rows of the prompts that the compiled prefills ran "
+         "(Request.prompt_len, a cold prefill)")
+PREFILL_ROWS_BUCKET = _mx.counter(
+    "serving/prefill_rows.bucket",
+    help="rows of the buckets those prefills ran in: "
+         "serving/prefill_rows.prompt over it is the share of a bucket's "
+         "rows that are a prompt's, the most that a pass which stops at the "
+         "prompt's end (the routed experts, the sparse-attention prefill) "
+         "computes of what a pass over the whole bucket does")
 DECODE_STEPS = _mx.counter(
     "serving/decode_steps", help="decode steps executed (all slots at once)")
 DECODE_DISPATCHES = _mx.counter(

@@ -60,6 +60,64 @@ def rng():
 
 
 @pytest.fixture
+def prefill_told_its_length(monkeypatch):
+    """What a sparse-attention prefill holds when it is told its prompt's
+    length (``attention_ops.dsa_rows_causal_attention``'s test and
+    ``dsa_causal_attention``'s): ``check(run, arm, case, want_mask, length,
+    bq)`` with ``run`` the call over ``case`` (q, k, v first) at scale 0.25
+    and mask blocks of ``bq`` rows, taking ``length=``; ``arm()`` what
+    stands the kernels' interpreters in, the attention's through
+    ``dsa_prefill.dsa_prefill_attention``; ``want_mask`` [S, S] bool the
+    rows each row reads, by hand. The blocked form, the kernel interpreted
+    at query blocks of 256 and the softmax by hand under ``want_mask`` agree
+    on every row under the length; the rows from the first query block past
+    it on are exactly zero and the rest finite; the ``int8`` mask the
+    kernel is handed is, on the live rows, the one made with no length (by
+    hand's), the causal triangle in the first mask block and zeros in the
+    mask's blocks past the length."""
+    import jax.numpy as jnp
+    from paddle_tpu.ops.pallas_kernels import dsa_prefill
+
+    def check(run, arm, case, want_mask, length, bq):
+        q, k, v = (np.asarray(x, np.float64) for x in case[:3])
+        s = q.shape[0]
+        sc = np.where(want_mask[None],
+                      np.einsum("qhd,khd->hqk", q, k) * 0.25, -np.inf)
+        p = np.exp(sc - sc.max(axis=-1, keepdims=True))
+        want = np.einsum("hqk,khd->qhd", p / p.sum(axis=-1, keepdims=True),
+                         v)
+        n = jnp.asarray(length, jnp.int32)
+
+        def held(got, block):
+            got = np.asarray(got)
+            np.testing.assert_allclose(got[:length], want[:length],
+                                       atol=2e-6, rtol=0)
+            assert np.isfinite(got).all()
+            assert not got[-(-length // block) * block:].any()
+
+        held(run(length=n), bq)
+        arm()
+        masks, real = [], dsa_prefill.dsa_prefill_attention
+
+        def spy(q, k, v, mask, *a, **kw):
+            masks.append(np.asarray(mask))
+            return real(q, k, v, mask, *a, block_q=256, block_k=128,
+                        heads=2, **kw)
+
+        monkeypatch.setattr(dsa_prefill, "dsa_prefill_attention", spy)
+        held(run(length=n), 256)
+        held(run(), s)
+        told, untold = masks
+        np.testing.assert_array_equal(untold != 0, want_mask)
+        np.testing.assert_array_equal(told[:length], untold[:length])
+        np.testing.assert_array_equal(told[:bq] != 0,
+                                      np.tril(np.ones((bq, s), bool)))
+        assert not told[-(-length // bq) * bq:].any()
+
+    return check
+
+
+@pytest.fixture
 def poisoned_latent_pool():
     """Builds a latent pool for the kernel's tests: ``(q, poisoned, clean,
     page_table)`` for slots of ``lens`` rows. ``poisoned`` [2, rows, width]

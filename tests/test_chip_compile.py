@@ -276,6 +276,57 @@ def _has_dim(type_str, n):
                for dims in re.findall(r"\[([\d,]*)\]", type_str))
 
 
+def _copies_around(instructions, kernel, rows):
+    """``[(call, feeding, after)]`` for every custom call named ``kernel``:
+    the ``copy`` instructions of ``rows``-row results that feed it (through
+    bitcasts) and those that read its result."""
+    by = {name: (rtype, op, operands)
+          for name, rtype, op, operands in instructions}
+
+    def source(name):
+        while name in by and by[name][1] == "bitcast":
+            name = by[name][2][0]
+        return name
+
+    def big_copy(name):
+        return name in by and by[name][1] == "copy" \
+            and _has_dim(by[name][0], rows)
+
+    found = []
+    for name, (rtype, op, operands) in by.items():
+        if op == "custom-call" and name.startswith(kernel):
+            readers = [n for n, (_, _, ops) in by.items() if name in ops]
+            found.append((name,
+                          [source(o) for o in operands if big_copy(source(o))],
+                          [n for n in readers if big_copy(n)]))
+    return found
+
+
+def _choices_inside_loops(text):
+    """``(homes, reached)``: the computations that hold a ``conditional``
+    (each has to be a ``while``'s body), and the text of every computation
+    its branches reach."""
+    comps = {name: "\n".join(lines)
+             for name, lines in _computations(text)[0].items()}
+    bodies = set(re.findall(r"body=%([\w.\-]+)", text))
+    homes = [name for name, body in comps.items() if " conditional(" in body]
+    assert homes and set(homes) <= bodies, (homes, sorted(bodies)[:8])
+    reached, todo = {}, []
+    for home in homes:
+        for line in comps[home].split("\n"):
+            if " conditional(" in line:
+                todo += re.findall(r"%([\w.\-]+)", line.split(
+                    "branch_computations={")[1].split("}")[0])
+    while todo:
+        name = todo.pop()
+        if name in reached or name not in comps:
+            continue
+        reached[name] = comps[name]
+        todo += re.findall(
+            r"(?:calls|to_apply|body|condition)=%([\w.\-]+)", comps[name])
+    return {home: comps[home] for home in homes}, reached
+
+
 @pytest.mark.parametrize("name", sorted(PAGED_SHAPES))
 def test_paged_decode_attention(chip, name):
     """The engine's entry: the whole ``[n_layer, rows, H*D]`` pool and a
@@ -1655,7 +1706,10 @@ def test_sparse_hybrid_decoder_prefill_attends_in_one_kernel(chip,
     ``dsa_prefill_attention`` call, no score of 64 heads against the
     bucket's keys is a float32 result of any instruction, and no softmax
     became a ``reduce-window`` (the selection's and the experts' running
-    counts, int32, are the only ones)."""
+    counts, int32, are the only ones). The call takes the prompt's length
+    as a scalar-prefetch operand with no ``[S, H D]`` copy more around it,
+    and the mask's loop holds the ``conditional`` that skips the blocks
+    past the prompt and those that keep every closed block."""
     from paddle_tpu.ops.pallas_kernels import dsa_prefill
 
     monkeypatch.setattr(attention_ops, "_on_tpu", lambda: True)
@@ -1674,6 +1728,17 @@ def test_sparse_hybrid_decoder_prefill_attends_in_one_kernel(chip,
     scores = re.compile(r"f32\[(\d+,)*64,\d+,%d\]" % bucket)
     assert [rtype for _, rtype, _, _ in instructions
             if scores.search(rtype)] == []
+    # the length's scalar brought no copy of [S, H D] around the call: its
+    # result is read as it lies, and of its operands only k and v come
+    # through one (the compiler's turn of its own products, as before)
+    (_, feeding, after), = _copies_around(instructions,
+                                          "dsa_prefill_attention", bucket)
+    assert len(feeding) <= 2 and after == [], (feeding, after)
+    # the mask's loop chooses a block under a conditional: a block past
+    # the prompt, one that keeps every closed block, and one that selects
+    homes, reached = _choices_inside_loops(text)
+    assert len(homes) == 1
+    assert any("dsa_select" in body for body in reached.values())
 
 
 def _prefill_last_of(build, n_layer):
@@ -2008,28 +2073,38 @@ def test_row_choosing_decoder_decode_step(chip, monkeypatch):
     assert {n_params, n_params + 1} <= aliased      # "c" "ik" in key order
 
 
-def test_row_choosing_decoder_prefill_attends_in_one_kernel(chip,
-                                                            monkeypatch):
+@pytest.fixture(scope="module")
+def row_choosing_prefill(chip):
+    """``(text, scratch bytes)`` of the row-choosing decoder's prefill of
+    its one bucket, the dense layer and a sparse one, compiled ONCE for the
+    tests that read it."""
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setattr(attention_ops, "_on_tpu", lambda: True)
+        _, model, params, _ = _dsv32_case(chip)
+        toks = jax.ShapeDtypeStruct((1, 8192), jnp.int32, sharding=chip)
+        lens = jax.ShapeDtypeStruct((1,), jnp.int32, sharding=chip)
+        exe = jax.jit(model.prefill_last).lower(params, toks, lens).compile()
+    return exe.as_text(), exe.memory_analysis().temp_size_in_bytes
+
+
+def test_row_choosing_decoder_prefill_attends_in_one_kernel(
+        row_choosing_prefill):
     """The cell's one bucket's prefill of the dense layer and a sparse
     one: the gate takes 128 heads of 192 lanes padded to 256, each layer's
     attention under its rows' own masks is ONE ``dsa_prefill_attention``
-    call and its index scores ONE ``dsa_index_scores_prefill`` call, no
-    score of 128 heads (or product of 64 index heads) against the bucket's
-    keys is a float32 result of any instruction, no softmax became a
-    ``reduce-window``, and
+    call and its index scores ONE ``dsa_index_scores_prefill`` call (both
+    with the prompt's length as a scalar-prefetch operand, and no ``[S, H
+    D]`` copy more around either for it), no score of 128 heads (or
+    product of 64 index heads) against the bucket's keys is a float32
+    result of any instruction, no softmax became a ``reduce-window``, and
     two layers' scratch is under the 3.5 GB reckoned for the bucket."""
     from paddle_tpu.ops.pallas_kernels import dsa_prefill
 
-    monkeypatch.setattr(attention_ops, "_on_tpu", lambda: True)
-    _, model, params, _ = _dsv32_case(chip)
     assert dsa_prefill.dsa_prefill_gate(128, 256, 128, 8192, 1) is None
     assert "whole 128-lane" in dsa_prefill.dsa_prefill_gate(128, 192, 128,
                                                             8192, 1)
-    toks = jax.ShapeDtypeStruct((1, 8192), jnp.int32, sharding=chip)
-    lens = jax.ShapeDtypeStruct((1,), jnp.int32, sharding=chip)
-    exe = jax.jit(model.prefill_last).lower(params, toks, lens).compile()
-    text = exe.as_text()
-    assert exe.memory_analysis().temp_size_in_bytes < 3.5e9
+    text, scratch = row_choosing_prefill
+    assert scratch < 3.5e9
     kernels = [ln.strip().split(" = ")[0] for ln in text.split("\n")
                if "tpu_custom_call" in ln]
     assert sum(k.startswith("%dsa_prefill_attention") for k in kernels) == 2
@@ -2042,3 +2117,29 @@ def test_row_choosing_decoder_prefill_attends_in_one_kernel(chip,
     scores = re.compile(r"f32\[(\d+,)*(128|64),\d+,8192\]")
     assert [rtype for _, rtype, _, _ in instructions
             if scores.search(rtype)] == []
+    # the attention's result is read as it lies and of its operands only q
+    # and k come through a copy (the compiler's turn of its own products,
+    # as before the scalar); the scores' call has none on either side
+    for call, feeding, after in _copies_around(
+            instructions, "dsa_prefill_attention", 8192):
+        assert len(feeding) <= 2 and after == [], (call, feeding, after)
+    for call, feeding, after in _copies_around(
+            instructions, "dsa_index_scores_prefill", 8192):
+        assert feeding == [] and after == [], (call, feeding, after)
+
+
+def test_row_choosing_decoder_prefill_chooses_under_a_conditional(
+        row_choosing_prefill):
+    """The mask's loop over query blocks holds a ``conditional`` a layer,
+    inside the loop's body: its branches are a block past the prompt
+    (zeros), a block under ``index_topk`` (the causal triangle) and the
+    selection, whose bisections (``dsa_select``'s two loops) lie in a
+    branch and nowhere else in the loop; and nothing a branch reaches
+    sorts."""
+    text, _ = row_choosing_prefill
+    homes, reached = _choices_inside_loops(text)
+    assert len(homes) == 2                      # one a layer
+    assert not any("dsa_select" in body.replace(
+        "branch_2_fun/attn/dsa_select", "") for body in homes.values())
+    assert sum("dsa_select/while" in body for body in reached.values()) >= 2
+    assert not any(" sort(" in body for body in reached.values())

@@ -158,6 +158,72 @@ def test_prefill_equals_the_reference_on_both_sides_of_index_topk(
         == [((128, 24), (128, 16))] * 4
 
 
+def _rows_case(rng, s, h=4, d=24, dv=16, hi=4, li=16):
+    def arr(*shape):
+        return jnp.asarray(rng.randn(*shape).astype("float32"))
+
+    return (arr(s, h, d), arr(s, h, d), arr(s, h, dv), arr(s, hi, li),
+            arr(s, hi), arr(s, li))
+
+
+def _rows_read_by_hand(case, topk):
+    """[S, S] bool: row t reads the ``topk`` rows s <= t of highest index
+    score, a tie to the lower row, by a sort a row."""
+    q_idx, w_idx, k_idx = (np.asarray(x, np.float64) for x in case[3:])
+    s = q_idx.shape[0]
+    index = np.einsum("th,ths->ts", w_idx, np.maximum(
+        np.einsum("thl,sl->ths", q_idx, k_idx), 0.0)).astype(np.float32)
+    mask = np.zeros((s, s), bool)
+    for t in range(s):
+        order = np.argsort(-index[t, :t + 1], kind="stable")
+        mask[t, order[:topk]] = True
+    return mask
+
+
+@pytest.mark.parametrize("length,why", [
+    (1, "one row"), (159, "index_topk - 1"), (161, "index_topk + 1"),
+    (300, "inside the third query block"), (511, "S - 1"), (512, "S")])
+def test_a_prefill_stops_at_its_prompts_end_and_chooses_nothing_under_topk(
+        rng, monkeypatch, prefill_told_its_length, length, why):
+    """``dsa_rows_causal_attention`` told the prompt's length, at ``topk``
+    160 in a bucket of 512 and mask blocks of 128 (the first reads its
+    whole prefix, the second not), the scores' kernel interpreted at query
+    blocks of 64: ``conftest.prefill_told_its_length`` has what holds."""
+    case = _rows_case(rng, 512)
+
+    def arm():
+        _arm_the_prefill_kernel(monkeypatch)
+        monkeypatch.setattr(
+            dsa_index, "dsa_index_scores_prefill", functools.partial(
+                dsa_index.dsa_index_scores_prefill, interpret=True,
+                block_q=64, block_k=128))
+
+    prefill_told_its_length(
+        functools.partial(attention_ops.dsa_rows_causal_attention, *case,
+                          160, 0.25, block_q=128),
+        arm, case, _rows_read_by_hand(case, 160), length, 128)
+
+
+@pytest.mark.parametrize("first,end", [(0, 512), (128, 300), (256, 256),
+                                       (130, 1), (512, 512)])
+def test_the_scores_kernel_computes_the_query_blocks_that_are_read(
+        rng, first, end):
+    """``dsa_index_scores_prefill`` told the rows whose scores are read:
+    every query block of 64 that holds one equals the kernel told nothing,
+    up to its causal edge (what the others hold is nobody's to read)."""
+    q_idx, w_idx, k_idx = _rows_case(rng, 512)[3:]
+    run = functools.partial(dsa_index.dsa_index_scores_prefill, q_idx, w_idx,
+                            k_idx, block_q=64, block_k=128, interpret=True)
+    whole = np.tril(np.asarray(run()))
+    got = np.tril(np.asarray(run(jnp.asarray(end, jnp.int32), first=first)))
+    lo, hi = first // 64 * 64, -(-end // 64) * 64
+    np.testing.assert_array_equal(got[lo:hi], whole[lo:hi])
+    want = np.tril(np.maximum(np.einsum(
+        "thl,sl->ths", q_idx, k_idx), 0.0).transpose(1, 0, 2)
+        * np.asarray(w_idx).T[:, :, None]).sum(0)
+    np.testing.assert_allclose(whole, np.tril(want), atol=2e-5, rtol=0)
+
+
 # -- (b) decode through the cache ------------------------------------------------
 
 

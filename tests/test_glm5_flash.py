@@ -390,6 +390,40 @@ def test_the_prefill_kernel_equals_the_blocked_form(rng, monkeypatch, rows,
                                rtol=0)
 
 
+def _rows_read_by_hand(case, kpool, top_blocks):
+    """[S, S] bool: row t reads its own block up to itself and the
+    ``top_blocks - 1`` closed blocks of highest index score, a tie to the
+    lower block, by a sort a row."""
+    q_idx, w_idx, k_pool = (np.asarray(x, np.float64) for x in case[3:])
+    s = q_idx.shape[0]
+    index = np.einsum("th,thb->tb", w_idx, np.maximum(
+        np.einsum("thl,bl->thb", q_idx, k_pool), 0.0)).astype(np.float32)
+    mask = np.zeros((s, s), bool)
+    for t in range(s):
+        own = t // kpool
+        order = np.argsort(-index[t, :own], kind="stable")
+        for b in list(order[:top_blocks - 1]) + [own]:
+            mask[t, b * kpool:(b + 1) * kpool] = True
+    return mask & np.tril(np.ones((s, s), bool))
+
+
+@pytest.mark.parametrize("length,why", [
+    (1, "one row"), (159, "index_topk - 1"), (161, "index_topk + 1"),
+    (300, "inside the third query block"), (511, "S - 1"), (512, "S")])
+def test_a_prefill_stops_at_its_prompts_end_and_chooses_nothing_under_topk(
+        rng, monkeypatch, prefill_told_its_length, length, why):
+    """``dsa_causal_attention`` told the prompt's length, at ``kpool`` 4
+    and 40 blocks read (160 rows) in a bucket of 512 and mask blocks of 128
+    (the first keeps every closed block, the second not):
+    ``conftest.prefill_told_its_length`` has what holds."""
+    case = _dsa_case(rng, 512)
+    prefill_told_its_length(
+        functools.partial(attention_ops.dsa_causal_attention, *case, 4, 40,
+                          0.25, block_q=128),
+        lambda: _arm_the_prefill_kernel(monkeypatch), case,
+        _rows_read_by_hand(case, 4, 40), length, 128)
+
+
 def test_the_prefill_kernel_reads_no_key_tile_past_a_query_block(rng):
     """The mask is the caller's, the causal edge the grid's: keys past a
     query block's last row are not read even where the mask names them,

@@ -740,6 +740,27 @@ def test_an_admission_launches_one_program(cache):
                    for r in reqs)
 
 
+@pytest.mark.parametrize("cache", ["paged", "resume"])
+def test_prefill_rows_count_the_prompts_and_their_buckets(cache):
+    """``serving/prefill_rows.prompt`` and ``.bucket`` rise, a COLD
+    prefill, by the prompt's rows and by the rows of the bucket it ran in
+    (5, 19, 11, 27 and 4 rows in buckets of 8, 32, 16, 32 and 8: two
+    thirds of what the prefills computed a row for was a prompt's); a
+    prefix-cache resume counts into neither."""
+    from paddle_tpu.serving import metrics as sm
+
+    eng, stream = _admission_engine(cache)
+    before = sm.PREFILL_ROWS_PROMPT.value, sm.PREFILL_ROWS_BUCKET.value
+    for prompt, m in stream:
+        eng.submit(prompt, m)
+        eng.run()
+    eng.close()
+    rows = (sm.PREFILL_ROWS_PROMPT.value - before[0],
+            sm.PREFILL_ROWS_BUCKET.value - before[1])
+    assert rows == ((19, 32) if cache == "resume" else (66, 96))
+    assert eng._prefills == (1 if cache == "resume" else 5)
+
+
 def test_the_admission_path_holds_no_eager_device_call():
     """Between a ``serving/prefill`` span's open and close the engine calls
     one executable and reads one token: none of the path's functions
