@@ -45,6 +45,16 @@ is hoisted out of the chain), each beside the share of its time at 8,192
 rows that the rows a token can read predict: ``L^2 - 2,048^2``, ``L -
 2,048`` and ``L^2``.
 
+``--copy-pages 1,4,8`` (after ``--parts``; PR 64): ``index`` and ``read``
+time their kernels once a RUN (the pages one copy moves: the tables here
+are made of aligned runs of 8 pages in a scrambled order, as
+``serving/page_pool.py`` hands a latent group its pages, so every run in
+the list reads them rightly and 1 is the kernel as it was), with the us a
+512-row WAVE beside the us a layer. Without it the kernels take the run
+the cache gives them (``mla_attention.RUN_PAGES``). ``--index-wave-rows
+512,1024`` times the index kernel once a WAVE length too (a diagnosis: the
+script sets ``dsa_index._WAVE_ROWS``; the program ships one constant).
+
 ``--cell <grid.run's arguments>``: the cell's own traced run, and after its
 last line the decode and prefill executables' device seconds of the traced
 stretch under each of ``drivers/serve_rowdsa.SCOPES`` and under none, every
@@ -53,6 +63,7 @@ most. About four minutes of chip for the parts, the cell's own for
 ``--cell``.
 
     python benchmarks/diag_dsv32_step.py --parts select,index,read
+    python benchmarks/diag_dsv32_step.py --parts index,read --copy-pages 1,4,8
     python benchmarks/diag_dsv32_step.py --cell --workload \
         dsv32-sparsedoc-sat --seed 7 --seconds 40 --trace 1
 """
@@ -74,6 +85,9 @@ SLOTS, HEADS, RANK, ROPE, WIDTH, PAGE = 32, 128, 512, 64, 640, 16
 TABLE_ROWS, TOPK, LO, HI = 16384, 2048, 4608, 10240
 INDEX_HEADS, INDEX_LANES = 64, 128
 SCALE = 0.1352
+RUN = 8     # pages of a run of the tables made here: every --copy-pages' own
+COPY_PAGES = [None]     # --copy-pages; None: the cache's own run
+INDEX_WAVE_ROWS = [None]    # --index-wave-rows; None: the kernel's own
 POINTS = []
 
 
@@ -114,6 +128,24 @@ def _lengths(rng):
     import numpy as np
 
     return np.sort(rng.integers(LO, HI + 1, SLOTS))[::-1].copy()
+
+
+def _table(rng, pages):
+    """A page table a slot of ``pages`` pages made of aligned runs of
+    ``RUN`` in a scrambled order, in the first ``HI // PAGE`` entries of a
+    table of ``TABLE_ROWS`` rows."""
+    import numpy as np
+
+    table = np.zeros((SLOTS, TABLE_ROWS // PAGE), np.int32)
+    first = rng.permutation(pages // RUN) * RUN
+    table[:, :HI // PAGE] = (first[:, None] + np.arange(RUN)).reshape(
+        SLOTS, -1)
+    return table
+
+
+def _waves(lens, rows=512):
+    """Waves of ``rows`` rows that slots of ``lens`` rows hold."""
+    return int((-(-lens // rows)).sum())
 
 
 def part_select(rng):
@@ -181,8 +213,7 @@ def part_index(rng):
         groups=[CacheGroup("latent_sparse", (0,), None, pages, LATENT)],
         index=(1, INDEX_LANES, TOPK))
     lens = _lengths(rng)
-    table = np.zeros((SLOTS, TABLE_ROWS // PAGE), np.int32)
-    table[:, :HI // PAGE] = rng.permutation(pages).reshape(SLOTS, -1)
+    table = _table(rng, pages)
     state = {"pt": jnp.asarray(table),
              "ik": jnp.asarray(rng.standard_normal(
                  (1, pages, PAGE, INDEX_LANES)) * 0.5, jnp.bfloat16)}
@@ -198,6 +229,20 @@ def part_index(rng):
     def by_the_cache(q, pt, ik, w):     # the kernel, where the flag arms it
         return ops.index_scores({"pt": pt, "ik": ik}, 0, q, w, ctx, active)[0]
 
+    def by_the_kernel(cp, wave):
+        from paddle_tpu.ops.pallas_kernels import dsa_index
+
+        def scores(q, pt, ik, w):
+            kept = dsa_index._WAVE_ROWS
+            dsa_index._WAVE_ROWS = wave or kept     # read at trace time
+            try:
+                return dsa_index.dsa_index_scores_paged(
+                    q, w, ik, pt, ctx, layer=0, copy_pages=cp)
+            finally:
+                dsa_index._WAVE_ROWS = kept
+
+        return scores
+
     def by_xla(q, pt, ik, w):
         # the table made to depend on the query, so that the gather of
         # the keys is not hoisted out of the chain as loop-invariant
@@ -209,19 +254,27 @@ def part_index(rng):
              "num_hidden_layers": 1}
     need = flops_rowdsa.index_score_need_s(float(lens.sum()), model, PEAKS)
     args = (state["pt"], state["ik"], w)
-    gap = float(jnp.max(jnp.abs(by_the_cache(q, *args) - by_xla(q, *args))))
-    for name, form in (("cache_%s" % (ops.index_kernel_mode()[0] or "xla"),
-                        by_the_cache), ("xla_gather_and_scores", by_xla)):
+    forms = [("cache_%s" % (ops.index_kernel_mode()[0] or "xla"),
+              by_the_cache, ops.group_run_pages(0), None)]
+    if COPY_PAGES != [None] or INDEX_WAVE_ROWS != [None]:
+        forms = [("kernel", by_the_kernel(cp or ops.group_run_pages(0), wave),
+                  cp or ops.group_run_pages(0), wave)
+                 for cp in COPY_PAGES for wave in INDEX_WAVE_ROWS]
+    for name, form, cp, wave in forms + [("xla_gather_and_scores", by_xla,
+                                          None, None)]:
         def step(q, i, pt, ik, w, form=form):
             bump = jnp.max(form(q, pt, ik, w), axis=-1)[:, None, None] * 1e-9
             return q + bump.astype(q.dtype)
 
         us = slope_us(step, q, args)
-        emit({"part": "index", "form": name, "slots": SLOTS,
+        emit({"part": "index", "form": name, "copy_pages": cp,
+              "slots": SLOTS,
               "rows_scored": int(lens.sum()), "table_rows": TABLE_ROWS,
-              "us_a_layer": us, "need_us": need * 1e6,
-              "roofline_share": need * 1e6 / us,
-              "max_gap_between_the_forms": gap})
+              "wave_rows": wave or 512,
+              "us_a_layer": us, "us_a_wave": us / _waves(lens, wave or 512),
+              "need_us": need * 1e6, "roofline_share": need * 1e6 / us,
+              "max_gap_to_xla": float(jnp.max(jnp.abs(
+                  form(q, *args) - by_xla(q, *args))))})
 
 
 def part_read(rng):
@@ -234,8 +287,7 @@ def part_read(rng):
 
     lens = _lengths(rng)
     pages = SLOTS * HI // PAGE
-    table = np.zeros((SLOTS, TABLE_ROWS // PAGE), np.int32)
-    table[:, :HI // PAGE] = rng.permutation(pages).reshape(SLOTS, -1)
+    table = _table(rng, pages)
     pool = jnp.asarray(rng.standard_normal((1, pages * PAGE, WIDTH)) * 0.3,
                        jnp.bfloat16).at[..., RANK + ROPE:].set(0)
     q = jnp.asarray(rng.standard_normal((SLOTS, HEADS, WIDTH)) * 0.1,
@@ -252,23 +304,28 @@ def part_read(rng):
              "num_attention_heads": HEADS, "num_hidden_layers": 1}
     need = flops_rowdsa.sparse_read_need_s(SLOTS * TOPK, model, PEAKS) * 1e6
 
-    def wave_form(masked):
+    def wave_form(masked, cp):
         def step(q, i, pool, pt, mask):
             o = mla.mla_paged_decode(
                 q, pool, pt, ctx, page_size=PAGE, rank=RANK, layer=0,
                 sm_scale=SCALE, row_valid=mask if masked else None,
-                name=mla.SPARSE_KERNEL_NAME if masked else mla.KERNEL_NAME)
+                name=mla.SPARSE_KERNEL_NAME if masked else mla.KERNEL_NAME,
+                copy_pages=cp)
             return q.at[..., :RANK].add((o * 1e-3).astype(q.dtype))
 
         return step
 
-    for name, masked in (("b_whole_context_row_mask", True),
-                         ("dense_whole_context", False)):
-        us = slope_us(wave_form(masked), q, (pool, pt, mask))
-        emit({"part": "read", "form": name, "slots": SLOTS, "heads": HEADS,
-              "rows_chosen": SLOTS * TOPK, "rows_copied": int(lens.sum()),
-              "us_a_layer": us, "need_us": need,
-              "roofline_share": need / us})
+    for cp in COPY_PAGES:
+        cp = cp or mla.run_pages(PAGE, TABLE_ROWS // PAGE)
+        for name, masked in (("b_whole_context_row_mask", True),
+                             ("dense_whole_context", False)):
+            us = slope_us(wave_form(masked, cp), q, (pool, pt, mask))
+            emit({"part": "read", "form": name, "copy_pages": cp,
+                  "slots": SLOTS, "heads": HEADS,
+                  "rows_chosen": SLOTS * TOPK,
+                  "rows_copied": int(lens.sum()), "us_a_layer": us,
+                  "us_a_wave": us / _waves(lens), "need_us": need,
+                  "roofline_share": need / us})
     # form (a): the chosen rows alone, gathered by XLA and attended in
     # XLA, the table of rows given
     from paddle_tpu.ops import attention_ops
@@ -524,6 +581,11 @@ def main(argv) -> int:
         names = ["select", "index", "read"]
         if argv[:1] == ["--parts"]:
             names = [n for n in argv[1].split(",") if n]
+        for flag, into in (("--copy-pages", COPY_PAGES),
+                           ("--index-wave-rows", INDEX_WAVE_ROWS)):
+            if flag in argv:
+                into[:] = [int(n) for n in
+                           argv[argv.index(flag) + 1].split(",") if n]
         rc = parts(names)
     os.makedirs(os.path.join(ROOT, "chiprun_out"), exist_ok=True)
     with open(os.path.join(ROOT, "chiprun_out", "diag_dsv32_step.json"),

@@ -6,8 +6,9 @@ call beside it: a RING of ``window`` rows a slot (seven layers) by the
 kernel and by the XLA gather of 128 rows a slot followed by
 ``ops.attention_ops.mla_decode_attention``.
 
-    python benchmarks/diag_latent_ring.py [--heads 32,64,80]
+    python benchmarks/diag_latent_ring.py [--heads 32,64,80,128]
         [--context 512,2688,4000,4800,5500] [--wave-rows 512] [--ragged]
+        [--copy-pages 1,4,8]
 
 One JSON line a point: microseconds a layer (the slope between the
 medians of ``--reps`` timings of N and of 2N calls chained in one
@@ -19,6 +20,12 @@ in it), microseconds a WAVE
 wave), the rows' bytes and the share of the HBM rate that is. Run on the
 chip; it refuses another backend. ``--wave-rows`` hands the kernel its
 ``block_pages`` (a diagnosis; the program ships one constant).
+``--copy-pages`` (a list; PR 64) hands it the RUN it may copy with one
+descriptor, each point once a run: a slot's table here is made of aligned
+runs of 8 pages in a scrambled order (what ``serving/page_pool.py`` gives
+a latent group), so 1, 4 and 8 all read it rightly and ``copy_pages=1`` is
+the kernel as it was. This is where ``mla_attention.RUN_PAGES`` was chosen
+(PERF.md section 6, PR 64, has the table).
 
 What a wave's parts cost, read with this file's ``measure`` over scratch
 copies of the kernel with one part taken out (PR 44, TPU v5 lite, 80
@@ -63,7 +70,8 @@ import numpy as np
 
 HBM_BYTES_PER_S = 819e9     # TPU v5e (grid/peaks.json)
 RANK, ROPE, WIDTH, PAGE = 512, 64, 640, 16
-SERVED_SLOTS = {32: 64, 64: 32, 80: 64}     # heads: slots, as the cells run
+SERVED_SLOTS = {32: 64, 64: 32, 80: 64, 128: 32}    # heads: the cells' slots
+RUN = 8     # pages of a run of the tables made here: every --copy-pages' own
 
 
 def _chained(attend, layers):
@@ -126,12 +134,13 @@ def measure(name, attend, heads, slots, rows_a_slot, layers, reps=9,
     rows = np.full(slots, rows_a_slot)
     if ragged:
         rows = rng.integers(rows_a_slot // 2, rows_a_slot * 3 // 2 + 1, slots)
-    pages_a_slot = -(-int(rows.max()) // PAGE)
+    pages_a_slot = -(-int(rows.max()) // (PAGE * RUN)) * RUN
     q = jnp.asarray(rng.standard_normal((slots, heads, WIDTH)) * 0.1,
                     jnp.bfloat16).at[..., RANK + ROPE:].set(0)
     pool = jnp.asarray(rng.standard_normal(
         (layers, slots * pages_a_slot * PAGE, WIDTH)) * 0.3, jnp.bfloat16)
-    pt = jnp.asarray(rng.permutation(slots * pages_a_slot).reshape(
+    first = rng.permutation(slots * pages_a_slot // RUN) * RUN
+    pt = jnp.asarray((first[:, None] + np.arange(RUN)).reshape(
         slots, pages_a_slot), jnp.int32)
     lens = jnp.asarray(rows, jnp.int32)
     chain = _chained(attend, layers)
@@ -167,21 +176,27 @@ def main(argv=None) -> int:
     ap.add_argument("--ragged", action="store_true",
                     help="slots of half to one and a half times --context")
     ap.add_argument("--reps", type=int, default=9)
+    ap.add_argument("--copy-pages", type=_ints, default=[1],
+                    help="pages a copy moves (the pool's run), each in turn")
     args = ap.parse_args(argv)
     if jax.default_backend() != "tpu":
         print("diag_latent_ring: needs the chip, found %r"
               % jax.default_backend(), file=sys.stderr)
         return 3
-    kernel = kernel_form(block_pages=max(1, args.wave_rows // PAGE))
-    for heads in args.heads:
-        slots = args.slots or SERVED_SLOTS.get(heads, 64)
-        for ctx in args.context:
-            measure("full_kernel", kernel, heads, slots, ctx, 2, args.reps,
-                    args.wave_rows, ragged=args.ragged)
-    slots = args.slots or SERVED_SLOTS[80]
-    measure("ring_kernel", kernel, 80, slots, args.window, args.layers,
-            args.reps, args.wave_rows, calls=64)
-    measure("ring_gather", _gather, 80, slots, args.window, args.layers,
+    slots_80 = args.slots or SERVED_SLOTS[80]
+    for cp in args.copy_pages:
+        kernel = kernel_form(block_pages=max(1, args.wave_rows // PAGE),
+                             copy_pages=cp)
+        for heads in args.heads:
+            slots = args.slots or SERVED_SLOTS.get(heads, 64)
+            for ctx in args.context:
+                measure("full_kernel", kernel, heads, slots, ctx, 2,
+                        args.reps, args.wave_rows, ragged=args.ragged,
+                        copy_pages=cp)
+        measure("ring_kernel", kernel, 80, slots_80, args.window,
+                args.layers, args.reps, args.wave_rows, calls=64,
+                copy_pages=cp)
+    measure("ring_gather", _gather, 80, slots_80, args.window, args.layers,
             args.reps, args.wave_rows, calls=64)
     return 0
 

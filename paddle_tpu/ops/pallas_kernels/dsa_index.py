@@ -18,6 +18,21 @@ rows]`` scores: the masking constant at and past its length, and in every
 wave it holds no row of. The call's name in a device trace is
 ``dsa_index_scores``.
 
+A copy moves a page RUN (``copy_pages``; PR 64): the pool of the latent
+group hands its pages out in aligned runs of R pages side by side
+(``serving/page_pool.py`` guarantees it, the engine checks it,
+``mla_attention.py``'s docstring has the rule), so entry ``R g`` of a
+slot's table starts ``[R, page_size, lanes]`` of consecutive keys, all the
+slot's own, and one descriptor moves them where a page of 16 keys (4 KB)
+paid one each; the wave buffer is ``[pages, page_size, lanes]`` on the
+copy's side and is read as rows on end (a view: a bfloat16 page of 16 rows
+is one tile). Read on the chip (``benchmarks/diag_dsv32_step.py --parts
+index --copy-pages 1,4,8 --index-wave-rows 512,1024,2048``, PR 64; 32 slots
+of 4,608-10,240 rows, us a layer): waves of 512 rows 392 at R = 1, 224 at
+R = 4 or 8 (the kernel WAS its descriptors, and then is its loop a wave);
+waves of 1,024 rows 170 | 153 (R = 4 | 8), of 2,048 rows **142** | 133:
+``_WAVE_ROWS`` is 2,048 (52% of the keys' stream), R the latent kernel's.
+
 :func:`dsa_index_gate` says from the geometry whether the chip's compiler
 takes the call; the cache asks it and keeps the XLA form elsewhere.
 
@@ -58,7 +73,10 @@ __all__ = ["dsa_index_scores_paged", "dsa_index_gate", "KERNEL_NAME",
 KERNEL_NAME = "dsa_index_scores"
 PREFILL_KERNEL_NAME = "dsa_index_scores_prefill"
 _LANES = 128
-_WAVE_ROWS = 512
+# rows a wave of the DECODE kernel scores: read on the chip at 512, 1,024
+# and 2,048 rows (PERF.md, PR 64): once a wave's copies are a few runs what
+# is left of it is the loop's own cost a wave, which 2,048 rows share
+_WAVE_ROWS = 2048
 _BLOCK_Q = 256              # query rows a grid step (the lanes of a tile)
 _BLOCK_K = 512              # keys a grid step (its sublanes)
 _VMEM_LIMIT = 48 << 20
@@ -91,7 +109,7 @@ def dsa_index_gate(dtype, lanes: int, page_size: int, table_rows: int,
 
 def _index_kernel(pt_ref, len_ref, layer_ref, q_ref, w_ref, pool, o_ref, scr,
                   sems, *, wave_pages, page_size, pages_per_slot, num_pages,
-                  low, precision):
+                  low, precision, copy_pages=1):
     b = pl.program_id(0)
     ps = page_size
     rows = wave_pages * ps
@@ -99,30 +117,36 @@ def _index_kernel(pt_ref, len_ref, layer_ref, q_ref, w_ref, pool, o_ref, scr,
     ctx = jnp.minimum(len_ref[b], pages_per_slot * ps)
     live_pages = (ctx + ps - 1) // ps
     live_waves = (ctx + rows - 1) // rows
+    # a copy moves a RUN of this many pages, ``[cp, page_size, lanes]`` of
+    # the pool from the run's FIRST table entry on (``mla_attention.py``
+    # says who guarantees a run); the wave buffer is pages by rows a page
+    cp = copy_pages
+    copies = wave_pages // cp       # of a full wave
     # a full wave's copies start in unrolled runs of this many, with the
     # buffer a constant of the descriptor (``mla_attention.py`` has the
     # readings: a descriptor costs the scalar unit 13 ns so, 20 otherwise)
-    run = max(d for d in range(1, 5) if wave_pages % d == 0)
+    unroll = max(d for d in range(1, 5) if copies % d == 0)
     o_ref[...] = jnp.full(o_ref.shape, low, o_ref.dtype)
 
     def page(w, i, buf):
-        """The copy of wave ``w``'s page ``i`` into ``buf``. A table entry
-        is clamped: a corrupt one reads a wrong page, never out of
+        """The copy of wave ``w``'s run ``i`` into ``buf``. A table entry
+        is clamped: a corrupt one reads a wrong run, never out of
         bounds."""
-        entry = pt_ref[b * pages_per_slot + w * wave_pages + i]
+        entry = pt_ref[b * pages_per_slot + w * wave_pages + i * cp]
         return pltpu.make_async_copy(
-            pool.at[layer, jnp.clip(entry, 0, num_pages - 1)],
-            scr.at[buf, pl.ds(pl.multiple_of(i * ps, ps), ps)], sems.at[buf])
+            pool.at[layer, pl.ds(jnp.clip(entry, 0, num_pages - cp), cp)],
+            scr.at[buf, pl.ds(pl.multiple_of(i * cp, cp), cp)], sems.at[buf])
 
     def each_live_page(w, buf, act):
-        """``act`` on the copy of each page of a PARTIAL wave that holds a
-        row below the length, one at a time."""
+        """``act`` on the copy of each run of a PARTIAL wave that holds a
+        row below the length, one at a time (a run that straddles the
+        length whole: its pages are the slot's own)."""
         def body(i, _):
             act(page(w, i, buf))
             return 0
 
-        jax.lax.fori_loop(
-            0, jnp.clip(live_pages - w * wave_pages, 0, wave_pages), body, 0)
+        pages = jnp.clip(live_pages - w * wave_pages, 0, wave_pages)
+        jax.lax.fori_loop(0, (pages + (cp - 1)) // cp, body, 0)
 
     def full(w):                # every row of the wave lies below the length
         return (w + 1) * rows <= ctx
@@ -132,11 +156,14 @@ def _index_kernel(pt_ref, len_ref, layer_ref, q_ref, w_ref, pool, o_ref, scr,
             @pl.when(full(w) & (buf == const))
             def _(const=const):
                 def some(g, _):
-                    for j in range(run):
-                        page(w, g * run + j, const).start()
+                    for j in range(unroll):
+                        page(w, g * unroll + j, const).start()
                     return 0
 
-                jax.lax.fori_loop(0, wave_pages // run, some, 0)
+                if copies == unroll:    # one run: every offset a constant
+                    some(0, 0)
+                else:
+                    jax.lax.fori_loop(0, copies // unroll, some, 0)
 
         @pl.when(jnp.logical_not(full(w)))
         def _():
@@ -166,8 +193,9 @@ def _index_kernel(pt_ref, len_ref, layer_ref, q_ref, w_ref, pool, o_ref, scr,
             def _():
                 each_live_page(w, buf, lambda c: c.wait())
 
+            keys = scr[buf].reshape(rows, scr.shape[-1])    # pages on end
             sc = jax.lax.dot_general(
-                q, scr[buf], (((1,), (1,)), ((), ())), precision=precision,
+                q, keys, (((1,), (1,)), ((), ())), precision=precision,
                 preferred_element_type=jnp.float32)             # [Hi, R]
             score = jnp.sum(jnp.maximum(sc, 0.0) * weight, axis=0,
                             keepdims=True)                      # [1, R]
@@ -182,12 +210,16 @@ def _index_kernel(pt_ref, len_ref, layer_ref, q_ref, w_ref, pool, o_ref, scr,
 
 
 def dsa_index_scores_paged(q_idx, w_idx, pool, page_table, ctx_len, *, layer,
-                           interpret: bool = False):
+                           interpret: bool = False, copy_pages: int = 1):
     """``q_idx`` [B, Hi, L] the index queries, ``w_idx`` [B, Hi] float32
     their weights, ``pool`` [n_layer, num_pages, page_size, L] of which
     layer ``layer`` is read, ``page_table`` [B, pages_per_slot] int32,
     ``ctx_len`` [B] the rows a slot may score (0: none, and no page is
-    moved). Returns [B, pages_per_slot * page_size] float32:
+    moved), ``copy_pages`` (static) the pool's run: entry ``copy_pages *
+    g`` of a slot's table starts that many pages side by side in the pool,
+    all the slot's own, and one copy moves them
+    (``mla_attention.mla_paged_decode``'s rule). Returns [B,
+    pages_per_slot * page_size] float32:
     ``attention_ops.dsa_index_scores``'s, to the products' round-off (the
     heads' weighted sum is float32 here)."""
     from ..attention_ops import neg_inf_value
@@ -208,6 +240,10 @@ def dsa_index_scores_paged(q_idx, w_idx, pool, page_table, ctx_len, *, layer,
     if pages_per_slot % wave_pages:
         raise ValueError("a slot's %d pages are not whole waves of %d"
                          % (pages_per_slot, wave_pages))
+    cp = int(copy_pages)
+    if cp < 1 or wave_pages % cp or num_pages < cp:
+        raise ValueError("runs of %d pages do not tile waves of %d pages "
+                         "over a pool of %d" % (cp, wave_pages, num_pages))
     hp = -(-heads // 8) * 8     # whole sublanes of heads; the padding is 0
     pad = ((0, 0), (0, hp - heads), (0, 0))
     q = jnp.pad(q_idx.astype(pool.dtype), pad)
@@ -218,7 +254,7 @@ def dsa_index_scores_paged(q_idx, w_idx, pool, page_table, ctx_len, *, layer,
         _index_kernel, wave_pages=wave_pages, page_size=ps,
         pages_per_slot=pages_per_slot, num_pages=num_pages,
         low=neg_inf_value(jnp.float32),
-        precision=jax.lax.Precision.HIGHEST if f32 else None)
+        precision=jax.lax.Precision.HIGHEST if f32 else None, copy_pages=cp)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
         grid=(b,),
@@ -226,7 +262,7 @@ def dsa_index_scores_paged(q_idx, w_idx, pool, page_table, ctx_len, *, layer,
                   pl.BlockSpec((1, hp, _LANES), lambda i, *_: (i, 0, 0)),
                   pl.BlockSpec(memory_space=pl.ANY)],
         out_specs=pl.BlockSpec((1, 1, table_rows), lambda i, *_: (i, 0, 0)),
-        scratch_shapes=[pltpu.VMEM((2, wave_pages * ps, lanes), pool.dtype),
+        scratch_shapes=[pltpu.VMEM((2, wave_pages, ps, lanes), pool.dtype),
                         pltpu.SemaphoreType.DMA((2,))])
     out = pl.pallas_call(
         kernel, grid_spec=grid_spec,

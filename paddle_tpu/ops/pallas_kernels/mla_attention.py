@@ -41,15 +41,32 @@ started). For wave ``w`` of a slot, in buffer ``buf``, ONE loop body:
 2. wait for wave ``w``;
 3. fold it into the online-softmax state ``(m, l, acc)``.
 
+A copy moves a page RUN (``copy_pages``, static; PR 64). A page table is
+one entry a ``page_size``-row page, but the pool of a latent group
+(``serving/page_pool.py``) hands its pages out in aligned runs of R pages
+side by side, and a reservation is whole runs: so entry ``R g`` of a
+slot's table starts ``R * page_size`` consecutive pool rows that are all
+the slot's own. THE POOL guarantees that (the engine checks it on the host
+where it sets a slot's table); the caller that knows it
+(``kv_cache.LatentPagedCache``, from the group's geometry:
+:func:`run_pages`) says so with ``copy_pages=R`` and the kernel RELIES on
+it: it reads the run's FIRST entry alone and moves the run with one
+descriptor, ``block_pages / R`` copies a wave for ``block_pages``. With
+``copy_pages=1`` (the default; the sparse read's 8-row tiles, whose second
+table is made a step and knows no runs) the kernel is, to the letter, the
+one it was. A corrupt entry is still clamped, to the last whole run: it
+reads wrong rows, never out of bounds.
+
 A wave is FULL when every one of its rows is below the slot's length and
-every one of its pages is in the table. Its ``block_pages`` copies start
+every one of its pages is in the table. Its copies start
 with no predicate, in unrolled runs of four, one loop a buffer (so a
 copy's buffer and semaphore are constants of its descriptor); they are
 waited for ONCE (all signal the buffer's one semaphore; the wait is for
 their sum) and the buffer is folded as it lies. Only a slot's last wave
-can be partial: it copies and awaits just the pages that hold a row below
-the length (a loop of that many trips) and ZEROES the rows at or beyond
-the length in the buffer, where the exactly-0.0 probabilities would meet
+can be partial: it copies and awaits just the runs that hold a row below
+the length (a loop of that many trips; the run that straddles the length
+is copied whole, its pages being the slot's own) and ZEROES the rows at or
+beyond the length in the buffer, where the exactly-0.0 probabilities would meet
 them. The scores of such rows are replaced with the package's one masking
 constant; that select runs on every wave (all true in a full one, at a
 cost no chip run could read) so that the kernel holds one fold. So stale
@@ -65,8 +82,16 @@ compiles in 1.0-1.9 s a copy, 22 s more set-up in Kimi's cell; the runs of
 four read 1.5 us (45%) and compile in 0.3 s. On a v5e at 80 heads the fold
 alone is 0.80 us a wave, the copies alone 0.85, and a copy's descriptor
 costs the scalar unit 13 ns (20 with a destination computed at run time)
-that it does NOT overlap with the fold: what bounds a wave now is its 32
-descriptors, not its 655 KB (0.80 us at the HBM rate). The
+that it does NOT overlap with the fold: what bounded a wave of single
+pages was its 32 descriptors, not its 655 KB (0.80 us at the HBM rate).
+With runs of 4 pages (8 descriptors a wave; ``benchmarks/diag_latent_ring
+.py --copy-pages 1,4,8``, PR 64, ragged slots of 2,688 and 4,800 rows, us a
+wave at R = 1 | 4 | 8): 32 heads 1.31 | 1.00 | 1.01, 64 heads 1.44 | 1.05 |
+1.06, 80 heads 1.54 | 1.11 | 1.12, 128 heads 1.83 | 1.34 | 1.32; a ring
+call of 64 slots 55.7 | 46.6 | 46.6 us. No reading tells 8 from 4, and 4
+pads a reservation by three pages at the most: ``RUN_PAGES`` is 4. What is
+left above the stream's 0.80 us is the fold itself (1.28 us of products at
+128 heads) and a wave's own loop. The
 kernel's name in a device trace is ``mla_latent_decode``; a caller whose
 page table is a RING of a few pages (a window layer: one wave a slot, a
 call whose cost is each slot's own chain and not its bytes) names its calls
@@ -99,13 +124,19 @@ from jax.experimental.pallas import tpu as pltpu
 
 __all__ = ["mla_paged_decode", "mla_gather_reference", "mla_decode_gate",
            "KERNEL_NAME", "RING_KERNEL_NAME", "SPARSE_KERNEL_NAME",
-           "SPARSE_TILE"]
+           "SPARSE_TILE", "RUN_PAGES", "run_pages"]
 
 KERNEL_NAME = "mla_latent_decode"
 RING_KERNEL_NAME = "mla_latent_decode_ring"
 SPARSE_KERNEL_NAME = "dsa_sparse_decode"
 SPARSE_TILE = 8     # rows the sparse read copies at a time: an HBM tile's
 _LANES = 128
+# pages of an aligned RUN: what the pool of a latent group hands out side
+# by side (serving/page_pool.py) and what the kernels here and in
+# dsa_index.py copy with one descriptor. Chosen once on the chip from {4,
+# 8} (benchmarks/diag_latent_ring.py --copy-pages; PERF.md, PR 64): no
+# reading tells them apart, and 4 pads a reservation by half as much
+RUN_PAGES = 4
 # context rows a wave folds: [H, 512] f32 scores. Read on the chip at 256,
 # 512, 1,024 and 2,048 rows (PERF.md, PR 44): 1,024 folds 2 to 8% faster at
 # the served lengths and costs each of a decode executable's kernels a
@@ -139,9 +170,20 @@ def mla_decode_gate(dtype, width: int, rank: int, page_size: int,
     return None
 
 
+def run_pages(page_size: int, pages_per_slot: int) -> int:
+    """The run a latent group of this geometry takes: the largest of
+    ``RUN_PAGES``, its half, ... that divides both a slot's table and the
+    wave the kernel folds over it (1 if none does: single pages)."""
+    wave = max(1, min(max(1, _WAVE_ROWS // int(page_size)), pages_per_slot))
+    r = RUN_PAGES
+    while r > 1 and (wave % r or pages_per_slot % r):
+        r //= 2
+    return r
+
+
 def _mla_kernel(pt_ref, len_ref, layer_ref, q_ref, pool, *rest, block_pages,
                 page_size, pages_per_slot, num_pages, rank, sm_scale,
-                mask_value, precision):
+                mask_value, precision, copy_pages=1):
     # the sparse read's row mask rides between the pool and the output
     valid_ref = rest[0] if len(rest) == 5 else None
     o_ref, scr, sems, ahead = rest[-4:]
@@ -152,10 +194,17 @@ def _mla_kernel(pt_ref, len_ref, layer_ref, q_ref, pool, *rest, block_pages,
     layer = layer_ref[0]
     n_waves = -(-pages_per_slot // block_pages)
     whole = pages_per_slot // block_pages   # waves with every page tabled
+    # a copy moves a RUN of this many pages (the pool's: the table's entry
+    # ``cp g`` starts ``cp`` pages side by side); 1: a page a copy
+    cp = copy_pages
+    copies = block_pages // cp      # of a full wave
     # a full wave's copies start in unrolled runs of this many (2 reads 6%
     # slower than 4 or 8 on the chip; a copy more in a run is 8 ms more of
     # compilation for each of an executable's kernels, at every start)
-    run = max(d for d in range(1, 5) if block_pages % d == 0)
+    unroll = max(d for d in range(1, 5) if copies % d == 0)
+
+    def times(x, k):    # no product by 1 in the kernel's text
+        return x if k == 1 else x * k
 
     def length(slot):
         """A slot's rows, as many as its table can hold."""
@@ -164,26 +213,30 @@ def _mla_kernel(pt_ref, len_ref, layer_ref, q_ref, pool, *rest, block_pages,
     ctx = length(b)
 
     def page(slot, w, i, buf):
-        """The copy of ``slot``'s wave ``w``, page ``i``, into ``buf``. A
-        table entry is clamped: a corrupt one reads a wrong page, never
-        out of bounds."""
-        entry = pt_ref[slot * pages_per_slot + w * block_pages + i]
+        """The copy of ``slot``'s wave ``w``, run ``i`` (``cp`` pages from
+        the run's FIRST table entry on), into ``buf``. The entry is
+        clamped: a corrupt one reads a wrong run, never out of bounds."""
+        entry = pt_ref[slot * pages_per_slot + w * block_pages + times(i, cp)]
         return pltpu.make_async_copy(
-            pool.at[layer, pl.ds(jnp.clip(entry, 0, num_pages - 1) * ps, ps)],
-            scr.at[buf, pl.ds(pl.multiple_of(i * ps, ps), ps)], sems.at[buf])
+            pool.at[layer, pl.ds(jnp.clip(entry, 0, num_pages - cp) * ps,
+                                 cp * ps)],
+            scr.at[buf, pl.ds(pl.multiple_of(i * (cp * ps), cp * ps),
+                              cp * ps)], sems.at[buf])
 
     def each_live_page(slot, length, w, buf, act):
-        """``act`` on the copy of each page of a wave that holds a row
-        below the length, one at a time; a page wholly at or past the
-        length is not in the loop."""
+        """``act`` on the copy of each run of a wave that holds a row
+        below the length, one at a time; a run wholly at or past the
+        length is not in the loop, one that straddles it is copied whole
+        (its pages are the slot's own: a reservation is whole runs)."""
         live = jnp.minimum((length + ps - 1) // ps, pages_per_slot)
 
         def body(i, _):
             act(page(slot, w, i, buf))
             return 0
 
+        pages = jnp.clip(live - w * block_pages, 0, block_pages)
         jax.lax.fori_loop(
-            0, jnp.clip(live - w * block_pages, 0, block_pages), body, 0)
+            0, pages if cp == 1 else (pages + (cp - 1)) // cp, body, 0)
 
     def start(slot, length, w, buf):
         """Wave ``w`` of ``slot`` on its way into ``buf``. A FULL wave (no
@@ -196,11 +249,14 @@ def _mla_kernel(pt_ref, len_ref, layer_ref, q_ref, pool, *rest, block_pages,
             @pl.when(full & (buf == const))
             def _(const=const):
                 def some(g, _):
-                    for j in range(run):
-                        page(slot, w, g * run + j, const).start()
+                    for j in range(unroll):
+                        page(slot, w, g * unroll + j, const).start()
                     return 0
 
-                jax.lax.fori_loop(0, block_pages // run, some, 0)
+                if cp > 1 and copies == unroll:
+                    some(0, 0)      # one run: every offset a constant
+                else:
+                    jax.lax.fori_loop(0, copies // unroll, some, 0)
 
         @pl.when(jnp.logical_not(full))
         def _():
@@ -300,7 +356,7 @@ def _mla_kernel(pt_ref, len_ref, layer_ref, q_ref, pool, *rest, block_pages,
 def mla_paged_decode(q, pool, page_table, ctx_len, *, page_size, rank,
                      layer=None, sm_scale=1.0, block_pages=None,
                      interpret: bool = False, name: str = KERNEL_NAME,
-                     row_valid=None):
+                     row_valid=None, copy_pages: int = 1):
     """Absorbed latent decode attention over a paged pool.
 
     ``q`` [B, H, W]: each head's absorbed query over the row's lanes (its
@@ -312,8 +368,13 @@ def mla_paged_decode(q, pool, page_table, ctx_len, *, page_size, rank,
     it moves no page). ``name`` is the call's name in a device trace.
     ``row_valid`` [B, pages_per_slot * page_size] bool (the sparse read):
     of the rows below the length, those that count; a slot with a length
-    has one at least. Returns [B, H, rank] in ``q``'s type, matching
-    :func:`mla_gather_reference` to the products' round-off."""
+    has one at least. ``copy_pages`` (static): the pool's RUN, what the
+    caller KNOWS of the table: entry ``copy_pages * g`` of a slot starts
+    that many pages side by side in the pool, all the slot's own
+    (``serving/page_pool.py`` hands them out so); the kernel then reads
+    that entry alone and copies the run whole. Returns [B, H, rank] in
+    ``q``'s type, matching :func:`mla_gather_reference` to the products'
+    round-off."""
     b, h, width = q.shape
     if pool.ndim == 2 and layer is None:
         pool, layer = pool[None], 0
@@ -334,6 +395,11 @@ def mla_paged_decode(q, pool, page_table, ctx_len, *, page_size, rank,
         raise ValueError("layer %d outside a pool of %d" % (layer, n_layer))
     bp = int(block_pages) if block_pages else max(1, _WAVE_ROWS // ps)
     bp = max(1, min(bp, pages_per_slot))
+    cp = int(copy_pages)
+    if cp < 1 or bp % cp or pages_per_slot % cp or num_rows // ps < cp:
+        raise ValueError("runs of %d pages do not tile waves of %d pages of "
+                         "a table of %d over a pool of %d"
+                         % (cp, bp, pages_per_slot, num_rows // ps))
     from ..attention_ops import neg_inf_value
 
     f32 = pool.dtype == jnp.float32
@@ -344,7 +410,7 @@ def mla_paged_decode(q, pool, page_table, ctx_len, *, page_size, rank,
         pages_per_slot=pages_per_slot, num_pages=num_rows // ps,
         rank=int(rank), sm_scale=float(sm_scale),
         mask_value=neg_inf_value(jnp.float32),
-        precision=jax.lax.Precision.HIGHEST if f32 else None)
+        precision=jax.lax.Precision.HIGHEST if f32 else None, copy_pages=cp)
     masks = ()
     if row_valid is not None:
         table_rows = pages_per_slot * ps

@@ -460,10 +460,12 @@ class ServingEngine:
         # allocated to last, so pages, states and the per-slot tables
         with _cc.phase("startup/pools"):
             if self.cfg.paged:      # one free list a PAGED cache group
-                self.pools = [PagePool(g.num_pages, self.cfg.page_size,
-                                       name=g.name, primary=(gi == 0))
-                              for gi, g in enumerate(self.cache_ops.groups)
-                              if g.kind != STATE]
+                self.pools = [PagePool(
+                    g.num_pages, self.cfg.page_size, name=g.name,
+                    primary=(gi == 0),
+                    run_pages=self.cache_ops.group_run_pages(gi))
+                    for gi, g in enumerate(self.cache_ops.groups)
+                    if g.kind != STATE]
             # the first group's pool, under the name a one-group engine's
             # only pool always had
             self.pool: Optional[PagePool] = \
@@ -666,11 +668,11 @@ class ServingEngine:
                 "prompt+max_new_tokens=%d exceeds max_seq=%d" %
                 (total, self.cfg.max_seq))
         for gi, pool in enumerate(self.pools):
-            need = self.cache_ops.pages_needed(gi, total)
-            if need > pool.num_pages:
+            need = pool.rounded(self.cache_ops.pages_needed(gi, total))
+            if need > pool.capacity:
                 raise ValueError(
                     "request needs %d pages but the %s pool only has %d"
-                    % (need, pool.name, pool.num_pages))
+                    % (need, pool.name, pool.capacity))
         req = self.scheduler.submit(req)
         _trace.on_submitted(req)
         return req
@@ -844,6 +846,11 @@ class ServingEngine:
             out["page_pool_utilization"] = round(self.pool.utilization, 4)
             out["pages_by_group"] = {p.name: [p.num_used, p.num_pages]
                                      for p in self.pools}
+            # serving/page_run_pages.<group> and serving/pages_padding
+            # .<group>: the run a group's pool hands out, what it costs now
+            out["page_run_pages"] = {p.name: p.run_pages for p in self.pools}
+            out["pages_padding"] = {p.name: p.num_padding
+                                    for p in self.pools}
         if self.prefix_cache is not None:
             out["prefix_cache"] = self.prefix_cache.stats()
         return out
@@ -1046,6 +1053,18 @@ class ServingEngine:
                 return None
         return got
 
+    def _check_runs(self, group_pages: List[List[int]]) -> None:
+        """What the kernels that walk a latent page table rely on, checked
+        on the host where a slot's table is set: a group whose pool hands
+        out runs is given whole aligned ascending runs (a table that broke
+        this would read another request's rows, silently)."""
+        for pool, pages in zip(self.pools, group_pages):
+            if not pool.whole_runs(pages):
+                raise ValueError(
+                    "cache group %r is read by whole aligned runs of %d "
+                    "pages, a slot's table was handed %s"
+                    % (pool.name, pool.run_pages, list(pages)))
+
     def _prefill(self, req: Request, slot: int, bucket: int
                  ) -> Optional[Request]:
         """Run the per-bucket compiled prefill; returns the request if it
@@ -1094,6 +1113,8 @@ class ServingEngine:
         with _span("serving/prefill.launch"):
             prompt = np.full((bucket,), cfg.pad_id, np.int32)
             prompt[:req.prompt_len] = req.prompt
+            if cfg.paged:
+                self._check_runs(req.group_pages)
             dest = (self.cache_ops.prompt_dest_groups(req.group_pages, slot)
                     if cfg.paged else self.cache_ops.prompt_dest(slot))
             exe = self._get_prefill_exe(bucket)

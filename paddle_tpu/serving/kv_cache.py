@@ -93,6 +93,22 @@ different kinds side by side:
     are chosen; XLA's own gather of the chosen rows costs a descriptor a
     row and is the off-chip path).
 
+  A latent group's pages come in RUNS (:meth:`LatentPagedCache
+  .group_run_pages`; PR 64): its free list (``page_pool.PagePool``, built
+  by the engine with the run the cache names) hands out R pages side by
+  side in the pool, the first a multiple of R, so entry ``R g`` of a
+  slot's table starts ``R * page_size`` consecutive pool rows, all the
+  slot's own. The POOL guarantees it and the engine checks it where it
+  sets a slot's table; the two kernels that walk the table
+  (``mla_latent_decode`` under its three names, ``dsa_index_scores``) rely
+  on it and are handed R as ``copy_pages``: one copy a run where they paid
+  one a page. R is the latent kernel's ``RUN_PAGES`` where it divides the
+  slot's table and the kernel's wave (a ring takes the largest half that
+  divides its pages, 1 if none: the geometry decides, not a model's
+  name). Nothing else reads the table differently: it is still one entry
+  a page, and the pooled blocks' 8-row tiles go by their own table a step
+  with single copies. ``KV`` groups keep single pages.
+
   Side by side, what a paged group's slot holds: every position in pages
   (``window`` None); the last W positions as a RING (``window`` W); a
   window's rows and then a summary a chunk of it, COMPACTING (``chunk``,
@@ -429,6 +445,13 @@ class PagedKVCache(_KVCacheBase):
         if self.groups[gi].chunk is not None:
             return self.pages_needed(gi, self.max_ctx)
         return self.group_rows(gi) // self.page_size
+
+    def group_run_pages(self, gi: int) -> int:
+        """Pages side by side in the pool that group ``gi``'s free list
+        hands out as ONE aligned run (``page_pool.py``): 1, single pages,
+        for every group of K and V rows (a prefix is shared page by
+        page)."""
+        return 1
 
     @property
     def page_table_len(self) -> int:
@@ -1234,6 +1257,19 @@ class LatentPagedCache(PagedKVCache):
         block."""
         return self.index is not None and self.index[0] > 1
 
+    def group_run_pages(self, gi: int) -> int:
+        """A latent group's pages come in aligned RUNS, as long as its
+        geometry lets the kernels copy them whole: the largest of the
+        latent kernel's ``RUN_PAGES``, its half, ... that divides the
+        slot's table (a window group's ring) and the kernel's wave; 1 if
+        none does. Every kernel call over the group's table is handed it
+        as ``copy_pages``."""
+        from ..ops.pallas_kernels.mla_attention import run_pages
+
+        if self.groups[gi].kind != LATENT:
+            return 1
+        return run_pages(self.page_size, self.group_pages_per_slot(gi))
+
     def prompt_dest_groups(self, group_pages, slot: int = 0) -> np.ndarray:
         dest = super().prompt_dest_groups(group_pages, slot)
         if not self._open_block:
@@ -1363,7 +1399,8 @@ class LatentPagedCache(PagedKVCache):
 
                 return dsa_index.dsa_index_scores_paged(
                     q_idx, w_idx, state["ik"], state["pt"], closed, layer=li,
-                    interpret=(mode == "interpret")), closed
+                    interpret=(mode == "interpret"),
+                    copy_pages=self.group_run_pages(0)), closed
         keys = state["ik"][li, state["pt"]]
         if kpool == 1:          # [B, pages, rows a page, L]: a key a row
             keys = keys.reshape(keys.shape[0], -1, keys.shape[-1])
@@ -1506,7 +1543,8 @@ class LatentPagedCache(PagedKVCache):
                 q, state["c"], state["pt"], live, page_size=self.page_size,
                 rank=self.rank, layer=li, sm_scale=sm_scale,
                 row_valid=chosen, interpret=(mode == "interpret"),
-                name=_mla.SPARSE_KERNEL_NAME), read
+                name=_mla.SPARSE_KERNEL_NAME,
+                copy_pages=self.group_run_pages(0)), read
         rows, held = attention_ops.dsa_chosen_rows(chosen, topk)
         pool_rows = self._pool_rows(state["pt"], rows.T).T
         return attention_ops.mla_rows_attention(
@@ -1596,7 +1634,8 @@ class LatentPagedCache(PagedKVCache):
                 length, page_size=self.page_size, rank=self.rank, layer=li,
                 sm_scale=sm_scale, interpret=(mode == "interpret"),
                 name=(_mla.KERNEL_NAME if self.groups[gi].window is None
-                      else _mla.RING_KERNEL_NAME))
+                      else _mla.RING_KERNEL_NAME),
+                copy_pages=self.group_run_pages(gi))
         return attention_ops.mla_decode_attention(
             q, self.context(state, layer), length, self.rank,
             sm_scale=sm_scale)

@@ -37,7 +37,8 @@ __all__ = [
     "PREFIX_HITS", "PREFIX_MISSES", "PREFIX_INSERTS", "PREFIX_EVICTIONS",
     "PREFIX_ENTRIES", "PREFIX_PAGES", "PREFIX_TOKENS_REUSED",
     "PREFIX_POISONED_SKIPPED",
-    "pages_used", "attn_rows_read", "model_stat",
+    "pages_used", "page_run_pages", "pages_padding", "attn_rows_read",
+    "model_stat",
 ]
 
 REQUESTS_SUBMITTED = _mx.counter(
@@ -294,6 +295,24 @@ def pages_used(group: str):
     (a gauge a group; get-or-create, so engines of one process share it)."""
     return _mx.gauge("serving/pages_used.%s" % group,
                      help="KV-cache pages allocated in cache group %r" % group)
+
+
+def page_run_pages(group: str):
+    """``serving/page_run_pages.<group>``: pages side by side in the pool
+    that the group's free list hands out as one aligned run (1 where the
+    group keeps single pages: ``page_pool.py``)."""
+    return _mx.gauge("serving/page_run_pages.%s" % group,
+                     help="pages an aligned run of cache group %r holds"
+                     % group)
+
+
+def pages_padding(group: str):
+    """``serving/pages_padding.<group>``: of ``serving/pages_used
+    .<group>``, the pages handed out beyond those asked for (reservations
+    rounded up to whole runs: the whole cost of runs)."""
+    return _mx.gauge("serving/pages_padding.%s" % group,
+                     help="pages allocated in cache group %r beyond those "
+                     "asked for" % group)
 
 
 def attn_rows_read(group: str):

@@ -440,7 +440,7 @@ def test_the_table_of_a_choice_is_its_rows_ascending_without_a_sort(rng):
 def test_the_sparse_reads_kernel_equals_the_gather_of_the_chosen_rows(
         rng, dtype):
     """Contexts of 150, 9 and 0 rows, 24 rows read at the most, a page
-    table out of order: the latent kernel's wave over the whole context
+    table of runs out of order: the latent kernel's wave over the whole context
     under the choice as a row mask (interpreted, under the name
     ``dsa_sparse_decode``) equals the XLA gather of the chosen rows alone;
     both count the rows CHOSEN; a slot that holds nothing reads nothing,
@@ -450,7 +450,10 @@ def test_the_sparse_reads_kernel_equals_the_gather_of_the_chosen_rows(
         groups=[CacheGroup("latent_sparse", (0,), None, 48, LATENT)],
         index=(1, 8, 24))
     state = ops.init_state()
-    order = rng.permutation(48)
+    # a latent group's pages come in aligned runs (the pool's: the kernel
+    # copies a run from its first entry), the runs out of order
+    r = ops.group_run_pages(0)
+    order = (rng.permutation(48 // r)[:, None] * r + np.arange(r)).reshape(-1)
     for slot in range(3):
         state = ops.set_page_table(state, slot, jnp.asarray(
             ops.prompt_dest_groups([order[16 * slot:16 * slot + 16]],

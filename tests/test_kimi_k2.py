@@ -172,7 +172,8 @@ def test_decode_through_the_latent_cache_equals_the_reference(toy, kernel,
                 want = reference_rows(toy, seq, np.arange(first, first + m))
                 got = np.stack(eng.captured_logits(req))
                 np.testing.assert_allclose(got, want, atol=TOL, rtol=0)
-            assert peak == 1 + 4 + 6      # ceil(7/8), ceil(31/8), ceil(45/8)
+            # ceil(7/8), ceil(31/8), ceil(45/8) pages, in whole runs of 4
+            assert peak == 4 + 4 + 8
             assert eng.pool.num_used == 0
     finally:
         set_flag("paged_attention_kernel", "auto")
@@ -407,7 +408,8 @@ def test_decode_counts_the_held_experts_load(rng):
     with _engine(model, collect_logits=False) as eng:
         eng.submit([1, 2, 3], 5)
         eng.step()
-        assert sm.pages_used("latent").value == 1
+        assert sm.pages_used("latent").value == 4     # one page: a run
+        assert sm.pages_padding("latent").value == 3
         eng.run()
     steps, expert_layers = 4, 2    # the first token comes from the prefill
     assert sm.MOE_EXPERTS_TOUCHED.count - t0[0] == steps * expert_layers
@@ -453,7 +455,10 @@ def test_the_cache_holds_one_latent_row_a_token_and_no_v_pool(toy):
         assert [p.name for p in eng.pools] == ["latent"]
         req = eng.submit(list(range(1, 12)), 6)
         eng.step()
-        assert eng.pool.num_used == 3 and eng.page_accounting_ok()
+        # 17 positions: 3 pages, handed out as one run of 4
+        assert eng.pool.num_used == 4 and eng.page_accounting_ok()
+        assert eng.stats()["page_run_pages"] == {"latent": 4}
+        assert eng.stats()["pages_padding"] == {"latent": 1}
         rows = np.asarray(eng._cache["c"][1])
         written = np.flatnonzero(np.abs(rows).sum(-1))
         assert len(written) == 11 + 1     # the prompt and one decode step

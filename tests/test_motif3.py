@@ -261,8 +261,9 @@ def test_decode_through_ring_and_pages_equals_the_reference(toy, kernel, rng):
                 want = reference_rows(toy, seq, np.arange(first, first + m))
                 got = np.stack(eng.captured_logits(req))
                 np.testing.assert_allclose(got, want, atol=TOL, rtol=0)
-            # pages: ceil(46/8), ceil(57/8), ceil(7/8); rings: 2, 2, 1
-            assert peak == [6 + 8 + 1, 2 + 2 + 1]
+            # pages: ceil(46/8), ceil(57/8), ceil(7/8) in whole runs of 4;
+            # rings: 2, 2, 1 in whole runs of 2 (a ring is two pages)
+            assert peak == [8 + 8 + 4, 2 + 2 + 2]
             assert [p.num_used for p in eng.pools] == [0, 0]
     finally:
         set_flag("paged_attention_kernel", "auto")
@@ -564,10 +565,15 @@ def test_two_latent_groups_one_of_pages_and_one_of_rings(toy):
     with _engine(toy, collect_logits=False, prompt_buckets=(16,)) as eng:
         req = eng.submit(list(range(1, 12)), 30)
         eng.step()
-        assert [p.num_used for p in eng.pools] == [6, 2]
+        # 41 positions: 6 pages in two runs of 4; the ring is one run of 2
+        assert [p.num_used for p in eng.pools] == [8, 2]
         assert eng.page_accounting_ok()
-        assert eng.stats()["pages_by_group"] == {"latent_full": [6, 40],
+        assert eng.stats()["pages_by_group"] == {"latent_full": [8, 40],
                                                  "latent_ring": [2, 6]}
+        assert eng.stats()["page_run_pages"] == {"latent_full": 4,
+                                                 "latent_ring": 2}
+        assert eng.stats()["pages_padding"] == {"latent_full": 2,
+                                                "latent_ring": 0}
         eng.run()
         assert req.state == "finished"
         assert [p.num_used for p in eng.pools] == [0, 0]

@@ -1155,8 +1155,10 @@ def test_a_row_choosing_latent_model_is_served_end_to_end(rng):
         reqs = [eng.submit(rng.randint(0, 96, n).tolist(), m)
                 for n, m in plan]
         eng.step()
-        assert [len(r.pages) for r in reqs[:2]] == [2, 6]   # 25 and 82 rows
-        assert eng.pool.num_used == sm.pages_used("latent_sparse").value == 8
+        # 25 and 82 rows: 2 and 6 pages, in whole runs of 4
+        assert [len(r.pages) for r in reqs[:2]] == [4, 8]
+        assert eng.pool.num_used == sm.pages_used("latent_sparse").value == 12
+        assert sm.pages_padding("latent_sparse").value == 4
         eng.run()
         assert all(r.state == "finished" for r in reqs)
         assert [len(r.tokens_out) for r in reqs] == [m for _, m in plan]
