@@ -236,7 +236,8 @@ def main():
             "short": moe_ops._tiles(3 * even // 4)}
         base = {"share": name, "n": n, "k": k, "held": e_held,
                 "experts": n_expert, "d": d, "f": f, "even": even,
-                "chosen": moe_ops.combine_form(sizes_of["margin"], n * k)}
+                "chosen": moe_ops.combine_form(sizes_of["margin"], n * k,
+                                               e_held)}
         data = {fill: draw(a.seed, n, k, e_held, n_expert, d, f, fill)
                 for fill in fills}
         for size, rows in sizes_of.items():

@@ -5,7 +5,8 @@ through which ``serving.ServingEngine`` drives any of them
 
 ``smallthinker.py``, ``kimi_k2.py``, ``laguna.py``, ``ling3_flash.py``,
 ``motif3.py``, ``glm5_flash.py``, ``falcon_h1.py``, ``ouro.py``,
-``evabyte.py`` and ``deepseek_v32.py`` take their blocks from here and keep what only they have. A
+``evabyte.py``, ``deepseek_v32.py`` and ``nemotron3.py`` take their blocks
+from here and keep what only they have. A
 block two models need is written HERE under a public name; no model module
 imports another's underscore names. Two forms of a block are one function
 only where the merged one needs no argument that says who calls it and the
@@ -464,7 +465,8 @@ class ServedLM:
     """THE SERVING CONTRACT: what ``serving.ServingEngine`` may ask of a
     model and of its config. ``SmallThinkerLM``, ``KimiK2LM``, ``LagunaLM``,
     ``Ling3FlashLM``, ``Motif3LM``, ``Glm5FlashLM``, ``FalconH1LM``,
-    ``OuroLM``, ``EvaByteLM`` and ``DeepSeekV32LM`` are this class over
+    ``OuroLM``, ``EvaByteLM``, ``DeepSeekV32LM`` and ``Nemotron3LM`` are
+    this class over
     their module's
     ``init_params``, ``prefill_forward`` and ``decode_forward`` (and, where
     the head is not the plain one, ``head``);
@@ -490,6 +492,8 @@ class ServedLM:
       (the cache's ``write_prompt`` unpacks a layer's ``kept`` by the
       layer's group: it puts the closed windows' summaries where
       attention reads them and the open window's where they wait);
+      ``None`` of a layer that stands in NO cache group (a feed-forward
+      that is a layer of its own: nothing is written for it);
     * ``prefill_last`` (optional; the engine asks ``hasattr``): the same
       with ``logits [B, V]`` of each prompt's LAST row only ([B, S, V] at S
       = 8,192 and V = 151,936 would be 5 GB). Absent: the engine calls
@@ -533,8 +537,9 @@ class ServedLM:
       slot are kept as a ring (None: every position, in pages), and the
       first group is the one admission counts pages of. A layer is named
       once a KIND of group: in one paged group (``KV`` or ``LATENT``), in
-      one ``STATE`` group, or in one of each (a block whose two mixers, one
-      over pages and one over a recurrent state, read the same input);
+      one ``STATE`` group, in one of each (a block whose two mixers, one
+      over pages and one over a recurrent state, read the same input), or
+      in none (a layer that keeps nothing);
       the state groups come after the paged ones, and the engine's page
       accounting is the paged groups' alone. Absent: one group
       of every layer that keeps every position. ``kind`` is one of

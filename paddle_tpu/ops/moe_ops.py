@@ -8,15 +8,15 @@ experts that received rows. The same function serves the decode step (16
 rows x 6) and the prefill (8,192 rows x 6), and :func:`_grouped_ffn` gives
 each pass the form of the grouped product that its rows call for:
 
-* a pass of at most ``STREAM_ROWS`` rows on a TPU (every decode pass of
-  the served cells: 96 to 256 rows, a few an expert) is bound by the
-  stream of the touched experts' weights, and takes
-  ``pallas_kernels/expert_stream.py``: gate, up and down in ONE kernel
-  that reads each touched expert's three matrices once and multiplies the
+* a pass of at most ``STREAM_ROWS_AN_EXPERT`` rows a held expert on a TPU
+  (every decode pass of the served cells: 96 to 768 rows, 1 to 21 an
+  expert) is bound by the stream of the touched experts' weights, and
+  takes ``pallas_kernels/expert_stream.py``: the products in ONE kernel
+  that reads each touched expert's matrices once and multiplies the
   expert's own rows;
-* every larger pass (the prefills: from 1,024 rows up, bound by
-  arithmetic) and every other backend takes ``jax.lax.ragged_dot`` three
-  times; on the TPU the compiler lowers it to its own grouped-matmul
+* every larger pass (the prefills: 40 rows an expert and up, bound by
+  arithmetic) and every other backend takes ``jax.lax.ragged_dot`` a
+  matrix; on the TPU the compiler lowers it to its own grouped-matmul
   kernel, which reads an expert's weights only where its group has rows
   but multiplies EVERY row of the pass by every touched expert (PERF.md,
   PR 42): right where the rows are many, half the stream's rate where
@@ -31,8 +31,9 @@ through the grouped matmul in passes of a bound derived from the share
 (:func:`pass_rows`), so a 12-of-384 share of a 4,096-token prefill gathers
 1,280 rows and not the 32,768 of which 31 in 32 belong to absent experts; a
 router that sends it more makes the loop run again, and nothing is dropped.
-A DECODE pass (at most ``STREAM_ROWS`` rows) holds twice an even router's
-load and adds its weighed rows to their tokens by a scatter-add. A PREFILL
+A DECODE pass (at most ``STREAM_ROWS_AN_EXPERT`` rows a held expert) holds
+twice an even router's load and adds its weighed rows to their tokens by a
+scatter-add. A PREFILL
 pass holds the even load and a quarter of it (``PASS_MARGIN``) and gives
 its rows back in the form :func:`combine_form` chooses from the part of
 all pairs it is:
@@ -55,7 +56,9 @@ The routing rule and the experts' activation are the caller's:
 :func:`route_sigmoid_topk` (sigmoid scores, a bias that selects and never
 weighs, group-limited where the model's router is), and
 ``expert_layer(..., activation=)`` (ReLU by default: ReGLU experts;
-``jax.nn.silu`` gives SwiGLU). An activation with numbers of its own for
+``jax.nn.silu`` gives SwiGLU). An UNGATED expert has two matrices and no
+gate (``wg=None``): ``act(u Wu_e) Wd_e``, Nemotron's with :func:`relu2`;
+every path then runs two products, not three. An activation with numbers of its own for
 every expert (PolyNorm's three weights and bias) comes with ``act_params``
 [E_held, P] and is called ``activation(gate, p)`` with ``p`` the P numbers
 of the expert the rows belong to; it sees an expert's WHOLE width, so it
@@ -76,14 +79,18 @@ import numpy as np
 from . import attention_ops
 
 __all__ = ["route_topk", "route_sigmoid_topk", "expert_layer", "held_pairs",
-           "pass_rows", "matmul_form", "combine_form", "STREAM_ROWS"]
+           "pass_rows", "matmul_form", "combine_form", "relu2",
+           "STREAM_ROWS_AN_EXPERT"]
 
-# Rows of a pass up to which the experts' weights, not the arithmetic, bound
-# the grouped product: no served cell has a pass between 257 and 1,024 rows
-# (decode passes 96-256, prefill passes 1,024-81,920: PERF.md, PR 42), and
-# 512 rows over 12 or more experts are still far under the chip's ridge of
-# 240 rows an expert.
-STREAM_ROWS = 512
+# Rows of a pass A HELD EXPERT up to which the experts' weights, not the
+# arithmetic, bound the grouped product: an expert's matrices are read once
+# whatever rows it has, so what bounds a pass is its rows an expert against
+# the chip's ridge of 240, not its rows. One row tile of the stream kernel
+# (``expert_stream._row_tile``: 32). The served decode passes hold 1.2 to
+# 21.3 rows an expert (96 rows over 64 experts to 256 over 12; Nemotron's
+# 768 over 64 are 12), the prefill passes 40 (Ling's 5,120 over 128) to 768:
+# no served pass lies between (tests/test_moe_share.py pins each).
+STREAM_ROWS_AN_EXPERT = 32
 
 # What a prefill's pass holds over an even router's load. The grouped matmul
 # all but skips the tiles past its last group (a Laguna layer's gate and up
@@ -178,18 +185,25 @@ def _tiles(rows: int) -> int:
     return -(-max(rows, 1) // 256) * 256
 
 
+def _stream_bound(rows: int, e_held: int) -> bool:
+    """Whether the experts' weight stream bounds a pass of ``rows`` rows
+    over ``e_held`` held experts: a decode pass."""
+    return rows <= STREAM_ROWS_AN_EXPERT * e_held
+
+
 def _share_rows(n_pairs: int, e_held: int, n_expert: int) -> int:
     """Rows of one pass of a share's grouped matmul, in whole tiles of 256
     and never more than there are pairs. Twice what an even router sends
     ``e_held`` of ``n_expert`` experts where that is a decode pass (at most
-    ``STREAM_ROWS`` rows); a larger one (a prefill's) holds the even load
-    and ``PASS_MARGIN`` of it, and stays over ``STREAM_ROWS``."""
+    ``STREAM_ROWS_AN_EXPERT`` rows an expert); a larger one (a prefill's)
+    holds the even load and ``PASS_MARGIN`` of it, and stays a prefill's
+    pass: over that bound by a tile."""
     even = -(-n_pairs * e_held // n_expert)
     twice = min(n_pairs, _tiles(2 * even))
-    if twice <= STREAM_ROWS:
+    if _stream_bound(twice, e_held):
         return twice
     return min(n_pairs, max(_tiles(even + int(even * PASS_MARGIN)),
-                            STREAM_ROWS + 256))
+                            _tiles(STREAM_ROWS_AN_EXPERT * e_held + 1)))
 
 
 def pass_rows(n_pairs: int, e_held: int, n_expert: int) -> int:
@@ -206,29 +220,47 @@ def _on_tpu() -> bool:
     return attention_ops._on_tpu()
 
 
-def matmul_form(rows: int) -> str:
-    """Which grouped product a pass of ``rows`` rows takes: ``"stream"``
-    (the fused kernel) or ``"grouped"`` (``ragged_dot`` x 3). A function
-    of the pass's static row count and the backend alone."""
-    return "stream" if rows <= STREAM_ROWS and _on_tpu() else "grouped"
+def matmul_form(rows: int, e_held: int) -> str:
+    """Which grouped product a pass of ``rows`` rows over ``e_held`` held
+    experts takes: ``"stream"`` (the fused kernel) or ``"grouped"``
+    (``ragged_dot`` a matrix). A function of the pass's static counts and
+    the backend alone."""
+    return ("stream" if _stream_bound(rows, e_held) and _on_tpu()
+            else "grouped")
 
 
-def combine_form(rows: int, n_pairs: int) -> str:
-    """How a pass of ``rows`` rows of a share gives its results back to the
-    tokens of ``n_pairs`` pairs: ``"gather"`` (every pair fetches its row
-    by the inverse permutation and a token's ``k`` are summed: ``n_pairs``
-    rows gathered a pass, no scatter) where the pass is a prefill's and at
-    least ``GATHER_SHARE`` of the pairs, else ``"scatter"`` (the pass's
-    rows added to their tokens). A function of static counts alone."""
-    return ("gather" if rows > STREAM_ROWS and rows >= GATHER_SHARE * n_pairs
-            else "scatter")
+def combine_form(rows: int, n_pairs: int, e_held: int) -> str:
+    """How a pass of ``rows`` rows of a share of ``e_held`` experts gives
+    its results back to the tokens of ``n_pairs`` pairs: ``"gather"``
+    (every pair fetches its row by the inverse permutation and a token's
+    ``k`` are summed: ``n_pairs`` rows gathered a pass, no scatter) where
+    the pass is a prefill's and at least ``GATHER_SHARE`` of the pairs,
+    else ``"scatter"`` (the pass's rows added to their tokens). A function
+    of static counts alone."""
+    return ("gather" if not _stream_bound(rows, e_held)
+            and rows >= GATHER_SHARE * n_pairs else "scatter")
 
 
-def _ragged_ffn(xs, wg, wu, wd, sizes, activation, act_params=None):
-    """The grouped feed-forward as three of the compiler's grouped matmuls
-    (gate and up are rounded to ``xs``'s type before the activation). With
+def relu2(x):
+    """``relu(x)^2``: Nemotron's ungated experts' activation."""
+    return jnp.square(jax.nn.relu(x))
+
+
+def _ragged_ffn(xs, wg, wu, wd, sizes, activation, act_params=None,
+                transposed_up: bool = False):
+    """The grouped feed-forward as the compiler's grouped matmuls, one a
+    matrix: three of a gated expert (gate and up are rounded to ``xs``'s
+    type before the activation), two of an ungated one (``wg`` None). With
     ``act_params`` [E, P] each row's activation is given the P numbers of
-    the expert whose group the row lies in, as P columns ``[M, 1]``."""
+    the expert whose group the row lies in, as P columns ``[M, 1]``.
+    ``transposed_up``: ``wg``/``wu`` are stored ``[E, f, d]``; turned here
+    (a change of layout the compiler folds into the product's operand)."""
+    if transposed_up:
+        wu = jnp.swapaxes(wu, 1, 2)
+        wg = None if wg is None else jnp.swapaxes(wg, 1, 2)
+    if wg is None:
+        return jax.lax.ragged_dot(
+            activation(jax.lax.ragged_dot(xs, wu, sizes)), wd, sizes)
     gate = jax.lax.ragged_dot(xs, wg, sizes)
     up = jax.lax.ragged_dot(xs, wu, sizes)
     if act_params is None:
@@ -241,33 +273,47 @@ def _ragged_ffn(xs, wg, wu, wd, sizes, activation, act_params=None):
     return jax.lax.ragged_dot((act * up).astype(xs.dtype), wd, sizes)
 
 
-def _grouped_ffn(xs, wg, wu, wd, sizes, activation, act_params=None):
-    """``(act(xs Wg_e) * (xs Wu_e)) Wd_e`` for the rows of each group of
-    ``sizes`` (rows sorted by expert; the rows past the last group are
-    unspecified), in ``xs``'s type, in the form :func:`matmul_form` gives
-    the pass's rows; widths the kernel's gate refuses (no whole lane
-    tiles: no served model's) keep ``ragged_dot``. ``act_params`` [E, P]:
-    the activation's own numbers for each expert."""
+def _grouped_ffn(xs, wg, wu, wd, sizes, activation, act_params=None,
+                 transposed_up: bool = False):
+    """``(act(xs Wg_e) * (xs Wu_e)) Wd_e`` (``act(xs Wu_e) Wd_e`` where
+    ``wg`` is None) for the rows of each group of ``sizes`` (rows sorted by
+    expert; the rows past the last group are unspecified), in ``xs``'s
+    type, in the form :func:`matmul_form` gives the pass; a geometry the
+    kernel's gate refuses keeps ``ragged_dot`` (``moe/pass_form.stream``
+    and ``.grouped`` count, once a traced pass, what was TAKEN: a silent
+    fall to ``ragged_dot`` shows there). ``act_params`` [E, P]: the activation's own numbers
+    for each expert."""
     m, d = xs.shape
-    e, _, f = wg.shape
-    if matmul_form(m) == "stream":
+    e, f, _ = wd.shape
+    if matmul_form(m, e) == "stream":
         from .pallas_kernels import expert_stream   # Pallas only where used
 
-        if expert_stream.expert_stream_gate(m, e, d, f, xs.dtype) is None:
+        if expert_stream.expert_stream_gate(m, e, d, f, xs.dtype,
+                                            gated=wg is not None) is None:
+            attention_ops._count("stream", "moe/pass_form",
+                                 "moe_ops._grouped_ffn")
             return expert_stream.expert_stream_ffn(
-                xs, wg, wu, wd, sizes, activation, act_params=act_params)
-    return _ragged_ffn(xs, wg, wu, wd, sizes, activation, act_params)
+                xs, wg, wu, wd, sizes, activation, act_params=act_params,
+                transposed_up=transposed_up)
+    attention_ops._count("grouped", "moe/pass_form", "moe_ops._grouped_ffn")
+    return _ragged_ffn(xs, wg, wu, wd, sizes, activation, act_params,
+                       transposed_up)
 
 
 def expert_layer(u, idx, w, wg, wu, wd, n_expert: Optional[int] = None,
                  held: Optional[Sequence[int]] = None, row_valid=None,
-                 activation=jax.nn.relu, act_params=None
+                 activation=jax.nn.relu, act_params=None,
+                 transposed_up: bool = False
                  ) -> Tuple[jnp.ndarray, Dict[str, jnp.ndarray]]:
     """``sum_k w[n, k] * (act(u Wg_e) * (u Wu_e)) Wd_e`` with ``e = idx[n,
     k]``, for the experts in ``held``; ``activation`` is ``act``: a
     callable of the gate's rows ``[R, f]`` (ReLU: ReGLU experts;
     ``jax.nn.silu``: SwiGLU), which sees an expert's whole width ``f`` in
-    every path. Where the activation has numbers of its own for every
+    every path. ``wg`` None: UNGATED experts of two matrices, ``act(u
+    Wu_e) Wd_e`` (:func:`relu2`: Nemotron's). ``transposed_up``: ``wg``
+    and ``wu`` are stored ``[E_held, f, d]``, each expert's matrix
+    transposed: how a width ``f`` of no whole lane tiles is stored
+    (``pallas_kernels/expert_stream.py`` says why). Where the activation has numbers of its own for every
     expert, ``act_params`` [E_held, P] holds them in the order of the
     weights and the callable is ``act(gate, p)`` with ``p`` a list of P
     values that broadcast against ``[R, 1]``: the numbers of the expert
@@ -287,7 +333,7 @@ def expert_layer(u, idx, w, wg, wu, wd, n_expert: Optional[int] = None,
     """
     n, _ = u.shape
     k = idx.shape[1]
-    e_held = wg.shape[0]
+    e_held = wd.shape[0]
     n_expert = e_held if n_expert is None else int(n_expert)
     if held is None:
         if n_expert != e_held:
@@ -312,10 +358,10 @@ def expert_layer(u, idx, w, wg, wu, wd, n_expert: Optional[int] = None,
         sizes = jnp.zeros((e_held + 1,), jnp.int32).at[flat].add(1)[:e_held]
         if e_held < n_expert:
             return _share(u, w, wg, wu, wd, order, sizes, activation,
-                          pass_rows(n * k, e_held, n_expert), act_params), \
-                _group_stats(sizes)
+                          pass_rows(n * k, e_held, n_expert), act_params,
+                          transposed_up), _group_stats(sizes)
         out = _grouped_ffn(u[order // k], wg, wu, wd, sizes, activation,
-                           act_params)
+                           act_params, transposed_up)
         # rows past the last group are whatever the grouped matmul left
         out = jnp.where((flat[order] < e_held)[:, None], out, 0)
         back = jnp.zeros((n * k,), jnp.int32).at[order].set(
@@ -332,7 +378,7 @@ def _group_stats(sizes) -> Dict[str, jnp.ndarray]:
 
 
 def _share(u, w, wg, wu, wd, order, sizes, activation, rows: int,
-           act_params=None):
+           act_params=None, transposed_up: bool = False):
     """A share's part of the layer: the sorted pairs of the HELD experts
     (the first ``sum(sizes)`` of ``order``), ``rows`` of them a pass, each
     pass one grouped matmul over its own slice of every group, its rows
@@ -345,7 +391,7 @@ def _share(u, w, wg, wu, wd, order, sizes, activation, rows: int,
     ends = jnp.cumsum(sizes)
     total = ends[-1]
     wf = w.astype(jnp.float32).reshape(n * k)
-    combine = combine_form(rows, n * k)
+    combine = combine_form(rows, n * k, sizes.shape[0])
     attention_ops._count(combine, "moe/share_combine", "moe_ops._share")
     if combine == "gather":
         # where each pair lies among the sorted ones (the held pairs' below
@@ -364,7 +410,7 @@ def _share(u, w, wg, wu, wd, order, sizes, activation, rows: int,
         part = (jnp.clip(ends, lo, lo + rows)
                 - jnp.clip(ends - sizes, lo, lo + rows))
         out = _grouped_ffn(u[pair // k], wg, wu, wd, part, activation,
-                           act_params)
+                           act_params, transposed_up)
         # rows past the last group are whatever the grouped matmul left
         if combine == "gather":
             rel = back - lo

@@ -149,7 +149,7 @@ def _expert_matmul_form(mcfg, n_tokens: int) -> Optional[str]:
     from ..ops import moe_ops
 
     return moe_ops.matmul_form(moe_ops.pass_rows(
-        n_tokens * mcfg.top_k, len(held), mcfg.n_expert))
+        n_tokens * mcfg.top_k, len(held), mcfg.n_expert), len(held))
 
 
 # the per-slot state every decode executable carries from step to step, in
@@ -1620,6 +1620,8 @@ class ServingEngine:
                                             length[None])
                 last = logits[0, length - 1]
             for i, kv in enumerate(kvs):
+                if kv is None:      # a layer in no cache group keeps nothing
+                    continue
                 if isinstance(kv[0], tuple):
                     # a layer in a paged AND a state group: its rows, then
                     # what the prompt leaves in its slot

@@ -382,11 +382,13 @@ class PagedKVCache(_KVCacheBase):
                         "layer %d is in two %s cache groups"
                         % (layer, "state" if g.kind == STATE else "paged"))
                 where[layer] = (gi, li)
+        # a layer may stand in NO group: it keeps nothing (a feed-forward
+        # that is a layer of its own)
         covered = sorted(set(self._where) | set(self._where_state))
-        if covered != list(range(self.n_layer)):
-            raise ValueError("the cache groups must cover layers 0..%d, each "
-                             "once a kind, got %s" % (self.n_layer - 1,
-                                                      covered))
+        if not covered or covered[0] < 0 or covered[-1] >= self.n_layer:
+            raise ValueError("the cache groups name layers of 0..%d, each "
+                             "at most once a kind, got %s"
+                             % (self.n_layer - 1, covered))
         # query heads a KV head, by group name
         if not isinstance(q_per_kv, Mapping):
             q_per_kv = {g.name: q_per_kv for g in self.groups}
@@ -516,9 +518,10 @@ class PagedKVCache(_KVCacheBase):
         state = {}
         for gi, g in enumerate(self.groups):
             if g.kind == STATE:
-                h, dk, dv, taps, width = self.slot_state
+                _, _, _, taps, width = self.slot_state
                 state[self._key(gi, "s")] = jnp.zeros(
-                    (len(g.layers), self.slots, h, dk, dv), jnp.float32)
+                    (len(g.layers), self.slots) + self.state_shape(),
+                    jnp.float32)
                 state[self._key(gi, "tail")] = jnp.zeros(
                     (len(g.layers), self.slots, taps, width), self.dtype)
                 continue
@@ -836,18 +839,46 @@ class PagedKVCache(_KVCacheBase):
             return None, "gate: " + why_not
         return mode, None
 
+    def _ssd_groups(self) -> int:
+        """The groups that share ``B`` and ``C`` in Mamba-2's recurrence,
+        which the tail's width tells (``heads x dv`` channels of ``x`` and
+        ``2 x groups x dk`` of ``B`` and ``C``)."""
+        h, dk, dv, _, width = self.slot_state
+        return (width - h * dv) // (2 * dk)
+
+    def state_shape(self) -> Tuple[int, int, int]:
+        """A slot's state in one layer as the pool keeps it: ``[H, dk,
+        dv]``, or Mamba-2's heads narrower than a lane tile side by side
+        (``ops/pallas_kernels/ssd.state_shape``: 64 x [128, 64] is kept
+        as 32 x [128, 128], 2 MiB and no padding lanes)."""
+        h, dk, dv, _, _ = self.slot_state
+        if self.recurrence == "ssd":
+            from ..ops.pallas_kernels import ssd
+
+            return ssd.state_shape(h, dk, dv, self._ssd_groups())
+        return (h, dk, dv)
+
+    def slot_states(self, state: Cache, gi: int, slot: int):
+        """``[layers, H, dk, dv]`` float32: what ``slot`` keeps in state
+        group ``gi``, in the MODEL's order whatever the pool's."""
+        kept = state[self._key(gi, "s")][:, slot]
+        if self.recurrence == "ssd":
+            from ..ops.pallas_kernels import ssd
+
+            return ssd.unpack_state(
+                kept, self.slot_state[0] // kept.shape[-3])
+        return kept
+
     def _state_step_forms(self):
         """``(gate, XLA form, kernel)`` of the state groups' recurrence.
         The gate takes ``interpret`` alone: Mamba-2's also needs the groups
-        that share ``B`` and ``C``, which the tail's width tells (``heads x
-        dv`` channels of ``x`` and ``2 x groups x dk`` of ``B`` and
-        ``C``)."""
+        that share ``B`` and ``C``."""
         h, dk, dv, _, width = self.slot_state
         if self.recurrence == "ssd":
             from ..ops.pallas_kernels import ssd
 
             return (functools.partial(ssd.ssd_state_step_gate, h, dk, dv,
-                                      (width - h * dv) // (2 * dk)),
+                                      self._ssd_groups()),
                     ssd.ssd_state_step_xla, ssd.ssd_state_step)
         from ..ops.pallas_kernels import kda
 
@@ -883,6 +914,12 @@ class PagedKVCache(_KVCacheBase):
         key = self._key(gi, "s")
         mode, _ = self.state_kernel_mode()
         _, step_xla, step_kernel = self._state_step_forms()
+        from ..ops import attention_ops
+
+        # ``<recurrence>/step_calls.kernel|xla``: the form, once a traced call
+        attention_ops._count("xla" if mode is None else "kernel",
+                             self.recurrence + "/step_calls",
+                             "cache_ops.state_step")
         if mode is None:
             o, s = step_xla(state[key], li, *inputs_active)
         else:
@@ -985,9 +1022,14 @@ class PagedKVCache(_KVCacheBase):
         gi, li = self._where_state[layer]
         off = self._pt_start[gi]
         sk, tk = self._key(gi, "s"), self._key(gi, "tail")
+        s_new = s_new.astype(jnp.float32)
+        if self.recurrence == "ssd":
+            from ..ops.pallas_kernels import ssd
+
+            s_new = ssd.pack_state(
+                s_new, s_new.shape[0] // state[sk].shape[-3])
         return {**state,
-                sk: state[sk].at[li, dest[off]].set(
-                    s_new.astype(jnp.float32)),
+                sk: state[sk].at[li, dest[off]].set(s_new),
                 tk: state[tk].at[li, dest[off]].set(
                     tail_new.astype(state[tk].dtype))}
 

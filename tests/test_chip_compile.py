@@ -2143,3 +2143,181 @@ def test_row_choosing_decoder_prefill_chooses_under_a_conditional(
         "branch_2_fun/attn/dsa_select", "") for body in homes.values())
     assert sum("dsa_select/while" in body for body in reached.values()) >= 2
     assert not any(" sort(" in body for body in reached.values())
+
+
+# -- the hybrid of one-part layers (Nemotron-3-Nano as one chip of EP2) ---------
+
+NEMOTRON_SERVE = dict(slots=128, page_size=16, max_seq=16384, pages=81920)
+
+
+def test_ungated_expert_stream_kernel_at_the_published_width(chip):
+    """768 rows over 64 held experts of ``[2688, 1856]`` and ``[1856,
+    2688]`` bfloat16, no gate: the kernel's gate takes the width that is
+    14.5 lane tiles, and the compiled call names the experts' TWO operands
+    (what the grid's readers tell an expert operation by) and no third."""
+    from paddle_tpu.ops import moe_ops
+    from paddle_tpu.ops.pallas_kernels import expert_stream as es
+
+    m, e, d, f = 768, 64, 2688, 1856
+    assert es.expert_stream_gate(m, e, d, f, jnp.bfloat16,
+                                 gated=False) is None
+    bf16 = jnp.bfloat16
+    text = compiled_text(
+        chip, lambda xs, wu, wd, sizes: es.expert_stream_ffn(
+            xs, None, wu, wd, sizes, moe_ops.relu2, transposed_up=True),
+        ((m, d), bf16), ((e, f, d), bf16), ((e, f, d), bf16),
+        ((e,), jnp.int32))
+    kernel, = [ln for ln in text.split("\n") if "tpu_custom_call" in ln]
+    assert "%ragged_dot_stream" in kernel.split(" = ")[0]
+    # W_up transposed and W_down, both [E, f, d] with the hidden size in
+    # the lanes, read as the parameters lie: no copy of either in front
+    assert kernel.split(" custom-call(")[1].split(")")[0].count("%w") == 2
+    assert not [rtype for _, rtype, op, _ in _instructions(text)
+                if op in ("copy", "transpose") and "64,1856,2688" in rtype]
+
+
+def test_ssd_kernels_at_the_64_lane_geometry(chip, monkeypatch):
+    """64 heads of ``[128, 64]`` in 8 groups: the pool keeps 128 slots x 4
+    layers as ``[4, 128, 32, 128, 128]`` float32 (2 MiB a slot a layer),
+    the step kernel takes a whole slot a grid step with the buffer aliased
+    in and out and untouched outside the kernel, and the chunk scan of a
+    2,048-row bucket is ONE kernel call."""
+    from paddle_tpu.ops.pallas_kernels import ssd
+
+    monkeypatch.setattr(attention_ops, "_on_tpu", lambda: True)
+    assert ssd.ssd_state_step_gate(64, 128, 64, 8) is None
+    assert ssd.ssd_chunk_scan_gate(64, 8, 128, 64) is None
+    assert ssd._step_blocks(64, 8, 128, 64) == (2, 4, 8)
+    f32 = jnp.float32
+    shape = (4, 128) + ssd.state_shape(64, 128, 64, 8)
+    assert shape == (4, 128, 32, 128, 128)
+    args = [jax.ShapeDtypeStruct(s, d, sharding=chip) for s, d in (
+        (shape, f32), ((128, 64, 64), f32), ((128, 8, 128), f32),
+        ((128, 8, 128), f32), ((128, 64), f32), ((128,), jnp.bool_))]
+    text = jax.jit(
+        lambda s, x, b, c, a, live: ssd.ssd_state_step(s, 2, x, b, c, a,
+                                                       live),
+        donate_argnums=(0,)).lower(*args).compile().as_text()
+    kernel, = [ln for ln in text.split("\n") if "tpu_custom_call" in ln]
+    assert kernel.strip().startswith("%ssd_state_step")
+    assert "f32[4,128,32,128,128]" in kernel.split(" custom-call(")[0]
+    assert [op for _, rtype, op, _ in _instructions(text)
+            if _has_dim(rtype, 4) and "32,128,128" in rtype
+            and op not in ("parameter", "custom-call", "get-tuple-element",
+                           "tuple")] == []
+    assert re.search(r"\(0, \{\}, (?:may|must)-alias\)",
+                     text.split("\n", 1)[0])
+    text = compiled_text(
+        chip, lambda x, b, c, a: ssd.ssd_chunk_scan(x, b, c, a),
+        ((2048, 64, 64), f32), ((2048, 8, 128), f32), ((2048, 8, 128), f32),
+        ((2048, 64), f32))
+    kernel, = [ln for ln in text.split("\n") if "tpu_custom_call" in ln]
+    assert kernel.strip().startswith("%ssd_chunk_scan")
+
+
+def _nemotron_case(chip, pattern="ME*"):
+    """Nemotron-3-Nano at its published widths as one chip of the EP2 pair
+    holds it (64 of 128 experts, 65,536 rows of the vocabulary), a layer of
+    each kind, over the cell's pool and 128 slots' states: ``(model,
+    params, ops, cache)`` as shapes on the described chip."""
+    import json
+
+    from grid.drivers import serve_nemotron
+    from paddle_tpu.models import nemotron3 as nm
+    from paddle_tpu.serving.kv_cache import CacheGroup, PagedKVCache
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "grid", "configs",
+                           "nemotron-3-nano-ep2-serve.json")) as f:
+        cfg = serve_nemotron.model_config(dict(
+            json.load(f), hybrid_override_pattern=pattern,
+            num_hidden_layers=len(pattern)))
+    model = nm.Nemotron3LM(cfg, params={})
+
+    def sds(x):
+        return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=chip)
+
+    params = jax.tree_util.tree_map(
+        sds, jax.eval_shape(lambda: nm.init_params(cfg, 0)))
+    g = NEMOTRON_SERVE
+    groups = [CacheGroup(name, layers, window,
+                         g["pages"] if kind == "kv" else 0, kind)
+              for name, layers, window, kind in cfg.cache_groups]
+    ops = PagedKVCache(cfg.n_layer, 2, 128, g["slots"], g["max_seq"],
+                       g["page_size"], g["pages"], dtype="bfloat16",
+                       groups=groups, q_per_kv=16,
+                       slot_state=cfg.slot_state,
+                       recurrence=cfg.state_recurrence)
+    cache = jax.tree_util.tree_map(sds, jax.eval_shape(ops.init_state))
+    return model, params, ops, cache
+
+
+def test_one_part_layers_decode_step(chip, monkeypatch):
+    """A decode step of an ``M``, an ``E`` and a ``*`` layer at 128 slots:
+    ONE state-step kernel over the packed float32 buffer, ONE fused
+    ungated expert kernel over the 768-row pass (no ``ragged-dot`` of the
+    compiler's left), ONE paged kernel at 16 query heads a KV head (its
+    result ``[128, 16, 256]``); the pool and the states are neither
+    copied nor turned, and each is aliased from input to output."""
+    monkeypatch.setattr(attention_ops, "paged_kernel_mode",
+                        lambda: "compiled")
+    monkeypatch.setattr(attention_ops, "_on_tpu", lambda: True)
+    model, params, ops, cache = _nemotron_case(chip)
+    assert ops.kernel_mode() == ("compiled", None)
+    assert ops.state_kernel_mode() == ("compiled", None)
+    assert sorted(cache) == ["k", "pt", "s.ssm", "tail.ssm", "v"]
+    assert cache["s.ssm"].shape == (1, 128, 32, 128, 128)
+    ints = jax.ShapeDtypeStruct((128,), jnp.int32, sharding=chip)
+    flags = jax.ShapeDtypeStruct((128,), jnp.bool_, sharding=chip)
+
+    def chunk(params, cache, lengths, tokens, active):
+        logits, cache, stats = model.decode(params, cache, ops, tokens,
+                                            lengths, active)
+        return cache, jnp.argmax(logits, -1), stats
+
+    text = jax.jit(chunk, donate_argnums=(1,)).lower(
+        params, cache, ints, ints, flags).compile().as_text()
+    kernels = [ln.strip().split(" custom-call(")[0]
+               for ln in text.split("\n") if "tpu_custom_call" in ln]
+    steps = [k for k in kernels if k.startswith("%ssd_state_step")]
+    paged = [k for k in kernels if k.startswith("%paged_attention")]
+    stream = [k for k in kernels if k.startswith("%ragged_dot_stream")]
+    assert len(steps) == len(paged) == len(stream) == 1
+    assert "f32[1,128,32,128,128]" in steps[0]
+    assert "bf16[128,16,256]" in paged[0]
+    assert "bf16[768,2688]" in stream[0]
+    assert not re.findall(r"= \S+ custom-call\([^\n]*ragged-dot", text)
+    instructions = list(_instructions(text))
+    types = {name: rtype for name, rtype, _, _ in instructions}
+    rows = NEMOTRON_SERVE["pages"] * 16
+    moved = [(op, rtype) for _, rtype, op, operands in instructions
+             if op in ("copy", "copy-start", "slice", "dynamic-slice",
+                       "transpose")
+             and any(_has_dim(t, rows) or "32,128,128" in t
+                     for t in [rtype] + [types.get(o, "") for o in operands])]
+    assert moved == [], moved
+    n_params = len(jax.tree_util.tree_leaves(params))
+    aliased = {int(p) for p in re.findall(
+        r"\((\d+), \{\}, (?:may|must)-alias\)", text.split("\n", 1)[0])}
+    # "k" "pt" "s.ssm" "tail.ssm" "v" in key order
+    assert {n_params, n_params + 2, n_params + 3, n_params + 4} <= aliased
+
+
+def test_one_part_layers_prefill(chip, monkeypatch):
+    """The 2,048-row bucket's prefill of a layer of each kind: the chunk
+    scan is ONE ``ssd_chunk_scan`` call, the half share's pass of 7,680
+    rows takes the compiler's grouped product twice a pass (two matrices an
+    expert) and no stream kernel, causal attention the flash kernel."""
+    monkeypatch.setattr(attention_ops, "_on_tpu", lambda: True)
+    model, params, _, _ = _nemotron_case(chip)
+    toks = jax.ShapeDtypeStruct((1, 2048), jnp.int32, sharding=chip)
+    lens = jax.ShapeDtypeStruct((1,), jnp.int32, sharding=chip)
+    text = jax.jit(model.prefill_last).lower(params, toks,
+                                             lens).compile().as_text()
+    kernels = [ln.strip().split(" = ")[0] for ln in text.split("\n")
+               if "tpu_custom_call" in ln]
+    assert sum(k.startswith("%ssd_chunk_scan") for k in kernels) == 1
+    assert not any(k.startswith("%ragged_dot_stream") for k in kernels)
+    assert len(re.findall(r"= \S+ custom-call\([^\n]*ragged-dot", text)) == 2
+    assert not [rtype for _, rtype, op, _ in _instructions(text)
+                if op == "while" and "f32[64,128,64]" in rtype]

@@ -33,7 +33,7 @@ from paddle_tpu.serving import metrics as sm
 
 MODELS = ["decoder_lm", "smallthinker", "kimi_k2", "laguna", "ling3_flash",
           "motif3", "glm5_flash", "falcon_h1", "ouro", "evabyte",
-          "deepseek_v32"]
+          "deepseek_v32", "nemotron3"]
 EOS = 3
 TOL = dict(rtol=2e-4, atol=2e-4)
 # the one prompt bucket and the context budget: what each model's own tests

@@ -182,17 +182,18 @@ def test_a_share_over_the_kernel_runs_two_passes(rng, stream_here,
 
 def test_the_rule_sends_512_rows_to_the_kernel_and_513_to_ragged_dot(
         rng, stream_here, monkeypatch):
-    """The choice is a function of the pass's static rows and the backend:
-    512 rows on a TPU take the kernel, 513 the compiler's grouped matmul,
-    and no row count takes the kernel elsewhere."""
+    """The choice is a function of the pass's static rows an expert and the
+    backend: 512 rows over 16 experts on a TPU (32 an expert) take the
+    kernel, 513 the compiler's grouped matmul, and no row count takes the
+    kernel elsewhere."""
     taken = []
     real = es.expert_stream_ffn
     monkeypatch.setattr(es, "expert_stream_ffn", lambda xs, *a, **kw: (
         taken.append(xs.shape[0]), real(xs, *a, **kw))[1])
-    wg, wu, wd = _weights(rng, 4, 16, 8)
+    wg, wu, wd = _weights(rng, 16, 16, 8)
     for m in (512, 513):
         xs = jnp.asarray(rng.randn(m, 16).astype("float32"))
-        sizes = jnp.asarray([m - 9, 0, 4, 3], jnp.int32)
+        sizes = jnp.asarray([m - 9, 0, 4, 3] + [0] * 12, jnp.int32)
         got = moe_ops._grouped_ffn(xs, wg, wu, wd, sizes, jax.nn.relu)
         live = (np.arange(m) < m - 2)[:, None]
         np.testing.assert_allclose(
@@ -200,18 +201,18 @@ def test_the_rule_sends_512_rows_to_the_kernel_and_513_to_ragged_dot(
                 _ragged3(xs, wg, wu, wd, sizes, jax.nn.relu)), 0),
             atol=2e-5, rtol=0)
     assert taken == [512]
-    assert [moe_ops.matmul_form(m) for m in (1, 512, 513, 81920)] == [
+    assert [moe_ops.matmul_form(m, 16) for m in (1, 512, 513, 81920)] == [
         "stream", "stream", "grouped", "grouped"]
     # the served cells' passes: every decode pass under the bound, every
     # prefill pass over it (pairs, experts held, experts)
     assert [moe_ops.pass_rows(*g) for g in (
         (16 * 6, 64, 64), (64 * 8, 128, 512), (32 * 8, 12, 384),
         (16 * 10, 128, 256))] == [96, 256, 256, 160]
-    assert min(moe_ops.pass_rows(*g) for g in (
-        (1024 * 6, 64, 64), (2048 * 8, 128, 512), (2048 * 8, 12, 384),
-        (4096 * 10, 128, 256))) > moe_ops.STREAM_ROWS
+    assert not any(moe_ops._stream_bound(moe_ops.pass_rows(*g), g[1])
+                   for g in ((1024 * 6, 64, 64), (2048 * 8, 128, 512),
+                             (2048 * 8, 12, 384), (4096 * 10, 128, 256)))
     monkeypatch.setattr(moe_ops, "_on_tpu", lambda: False)
-    assert moe_ops.matmul_form(96) == "grouped"
+    assert moe_ops.matmul_form(96, 64) == "grouped"
 
 
 def _forms():
@@ -228,7 +229,7 @@ def test_dispatch_counters_say_which_form_each_executable_took(
     bound put between them): every decode dispatch counts into ``.stream``,
     every prefill into ``.grouped``, and a model with no expert layer into
     neither."""
-    monkeypatch.setattr(moe_ops, "STREAM_ROWS", 16)
+    monkeypatch.setattr(moe_ops, "STREAM_ROWS_AN_EXPERT", 2)   # x 8 experts
     snap0 = mx.snapshot()
     base = {k: snap0[k]["value"] for k in (
         "serving/decode_dispatches", "serving/prefills")}

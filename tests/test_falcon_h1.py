@@ -355,9 +355,12 @@ def test_a_layer_stands_once_in_a_kind_of_group():
         _two_kinds(slot_state=None)
     with pytest.raises(ValueError, match="recurrence='s4'"):
         _two_kinds(recurrence="s4")
-    with pytest.raises(ValueError, match="cover layers"):
+    with pytest.raises(ValueError, match="name layers of 0..1"):
         _two_kinds(groups=[CacheGroup("global", (0,), None, 8, KV),
-                           CacheGroup("ssm", (0,), None, 0, STATE)])
+                           CacheGroup("ssm", (2,), None, 0, STATE)])
+    # a layer may stand in NO group (it keeps nothing: PR 65)
+    _two_kinds(groups=[CacheGroup("global", (0,), None, 8, KV),
+                       CacheGroup("ssm", (0,), None, 0, STATE)])
     # the state group alone may cover a layer the paged one does not
     ops = _two_kinds(groups=[CacheGroup("global", (0,), None, 8, KV), st])
     assert ops.state_kernel_mode()[0] in (None, "interpret", "compiled")

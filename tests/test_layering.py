@@ -47,7 +47,8 @@ LEFT = [
 ]
 
 SERVED = ["smallthinker", "kimi_k2", "laguna", "ling3_flash", "motif3",
-          "glm5_flash", "falcon_h1", "ouro", "evabyte", "deepseek_v32"]
+          "glm5_flash", "falcon_h1", "ouro", "evabyte", "deepseek_v32",
+          "nemotron3"]
 
 
 def _files(package):

@@ -274,7 +274,8 @@ def test_the_parallel_hybrids_tables_are_the_references(what):
 SERVED = [("smallthinker", "SmallThinkerLM"), ("kimi_k2", "KimiK2LM"),
           ("laguna", "LagunaLM"), ("ling3_flash", "Ling3FlashLM"),
           ("motif3", "Motif3LM"), ("glm5_flash", "Glm5FlashLM"),
-          ("falcon_h1", "FalconH1LM"), ("deepseek_v32", "DeepSeekV32LM")]
+          ("falcon_h1", "FalconH1LM"), ("deepseek_v32", "DeepSeekV32LM"),
+          ("nemotron3", "Nemotron3LM")]
 
 
 @pytest.mark.parametrize("module,cls", SERVED, ids=[m for m, _ in SERVED])

@@ -90,7 +90,7 @@ def test_a_prefill_pass_returns_every_held_pair(rng, monkeypatch, case,
     e_held = E // held_of
     held = sorted(rng.choice(E, e_held, replace=False).tolist())
     rows = moe_ops.pass_rows(N * K, e_held, E)
-    assert rows > moe_ops.STREAM_ROWS
+    assert not moe_ops._stream_bound(rows, e_held)
     dtype = jnp.bfloat16 if case == "bfloat16" else jnp.float32
     u, w, wg, wu, wd, pn = _layer(rng, dtype, case == "act_params")
     away = sorted(set(range(E)) - set(held))
@@ -133,11 +133,12 @@ def test_a_decode_pass_is_what_it_was(share, monkeypatch):
     held before a prefill's pass was cut to its load (twice an even
     router's, in whole tiles of 256), streams on a TPU and scatters."""
     geometry, rows = DECODE_PASSES[share]
+    e_held = geometry[1]
     assert moe_ops.pass_rows(*geometry) == rows
-    assert moe_ops.combine_form(rows, geometry[0]) == "scatter"
-    assert moe_ops.matmul_form(rows) == "grouped"
+    assert moe_ops.combine_form(rows, geometry[0], e_held) == "scatter"
+    assert moe_ops.matmul_form(rows, e_held) == "grouped"
     monkeypatch.setattr(moe_ops, "_on_tpu", lambda: True)
-    assert moe_ops.matmul_form(rows) == "stream"
+    assert moe_ops.matmul_form(rows, e_held) == "stream"
 
 
 @pytest.mark.parametrize("share", sorted(PREFILL_PASSES))
@@ -150,15 +151,103 @@ def test_a_prefill_pass_holds_its_load_and_a_margin(share, monkeypatch):
     even = n_pairs * e_held // n_expert
     assert moe_ops.pass_rows(*geometry) == rows
     assert even < rows <= 2 * even and rows % 256 == 0
-    assert moe_ops.combine_form(rows, n_pairs) == combine
+    assert moe_ops.combine_form(rows, n_pairs, e_held) == combine
     monkeypatch.setattr(moe_ops, "_on_tpu", lambda: True)
-    assert moe_ops.matmul_form(rows) == "grouped"
+    assert moe_ops.matmul_form(rows, e_held) == "grouped"
 
 
-def test_a_pass_is_never_cut_to_a_decode_pass():
-    """A prefill's pass stays over ``STREAM_ROWS`` however small its even
-    load (the form of its product does not change with the margin), and
-    holds every pair where every expert is held."""
-    assert moe_ops._share_rows(8 * 257, 1, 8) == moe_ops.STREAM_ROWS + 256
-    assert moe_ops._share_rows(512, 2, 16) == 256
+def test_a_pass_is_never_cut_to_a_decode_pass(monkeypatch):
+    """A prefill's pass stays over ``STREAM_ROWS_AN_EXPERT`` rows an expert
+    however small its even load (the form of its product does not change
+    with the margin), and holds every pair where every expert is held."""
+    monkeypatch.setattr(moe_ops, "_on_tpu", lambda: True)
+    bound = moe_ops.STREAM_ROWS_AN_EXPERT
+    # twice the even load (2 x 2,064) is over the bound of 32 x 64 by a
+    # little: the pass is a prefill's, the even load and a margin
+    rows = moe_ops._share_rows(8 * 2064, 64, 512)
+    assert rows == 2816 and rows > bound * 64
+    assert moe_ops.matmul_form(rows, 64) == "grouped"
+    # ... and a margin that would fall under the bound stays over it
+    rows = moe_ops._share_rows(8 * 257, 1, 8)
+    assert rows == 512 and moe_ops.matmul_form(rows, 1) == "grouped"
+    assert moe_ops._share_rows(512, 16, 128) == 256
+    assert moe_ops.matmul_form(256, 16) == "stream"
     assert moe_ops.pass_rows(8192 * 6, 64, 64) == 8192 * 6
+
+
+# (configuration, pass, pairs, experts held, experts) -> (rows, product on
+# a TPU, combine): every pass the served expert configurations make at
+# their slots and prompt buckets (grid/configs, grid/traffic), as the rule
+# of rows a pass (STREAM_ROWS = 512, the parent of PR 65) gave them. The
+# rule of rows AN EXPERT gives each the same, and Nemotron-3's decode pass
+# of 768 rows over 64 held experts (12 an expert) the stream.
+SERVED_PASSES = [
+    ("smallthinker", "decode", 96, 64, 64, 96, "stream", "scatter"),
+    ("smallthinker", "1024", 6144, 64, 64, 6144, "grouped", "gather"),
+    ("smallthinker", "2048", 12288, 64, 64, 12288, "grouped", "gather"),
+    ("smallthinker", "4096", 24576, 64, 64, 24576, "grouped", "gather"),
+    ("smallthinker", "8192", 49152, 64, 64, 49152, "grouped", "gather"),
+    ("kimi", "decode", 256, 12, 384, 256, "stream", "scatter"),
+    ("kimi", "2048", 16384, 12, 384, 768, "grouped", "scatter"),
+    ("kimi", "4096", 32768, 12, 384, 1280, "grouped", "scatter"),
+    ("laguna", "decode", 160, 128, 256, 160, "stream", "scatter"),
+    ("laguna", "4096", 40960, 128, 256, 25600, "grouped", "gather"),
+    ("laguna", "8192", 81920, 128, 256, 51200, "grouped", "gather"),
+    ("ling", "decode", 512, 128, 512, 256, "stream", "scatter"),
+    ("ling", "2048", 16384, 128, 512, 5120, "grouped", "gather"),
+    ("ling", "4096", 32768, 128, 512, 10240, "grouped", "gather"),
+    ("ling", "8192", 65536, 128, 512, 20480, "grouped", "gather"),
+    ("motif", "decode", 512, 24, 384, 256, "stream", "scatter"),
+    ("motif", "2048", 16384, 24, 384, 1280, "grouped", "scatter"),
+    ("motif", "4096", 32768, 24, 384, 2560, "grouped", "scatter"),
+    ("motif", "8192", 65536, 24, 384, 5120, "grouped", "scatter"),
+    ("glm", "decode", 512, 36, 288, 256, "stream", "scatter"),
+    ("glm", "4096", 32768, 36, 288, 5120, "grouped", "scatter"),
+    ("glm", "8192", 65536, 36, 288, 10240, "grouped", "scatter"),
+    ("dsv32", "decode", 256, 16, 256, 256, "stream", "scatter"),
+    ("dsv32", "8192", 65536, 16, 256, 5120, "grouped", "scatter"),
+    ("nemotron3", "decode", 768, 64, 128, 768, "stream", "scatter"),
+    ("nemotron3", "2048", 12288, 64, 128, 7680, "grouped", "gather"),
+    ("nemotron3", "4096", 24576, 64, 128, 15360, "grouped", "gather"),
+    ("nemotron3", "8192", 49152, 64, 128, 30720, "grouped", "gather"),
+]
+
+
+@pytest.mark.parametrize(
+    "pairs,e_held,n_expert,rows,matmul,combine",
+    [c[2:] for c in SERVED_PASSES],
+    ids=["%s-%s" % c[:2] for c in SERVED_PASSES])
+def test_a_served_pass_keeps_its_rows_and_forms(
+        monkeypatch, pairs, e_held, n_expert, rows, matmul, combine):
+    monkeypatch.setattr(moe_ops, "_on_tpu", lambda: True)
+    assert moe_ops.pass_rows(pairs, e_held, n_expert) == rows
+    assert moe_ops.matmul_form(rows, e_held) == matmul
+    assert moe_ops.combine_form(rows, pairs, e_held) == combine
+
+
+def test_the_pinned_passes_are_the_configurations_own():
+    """The table's pairs, experts held and experts are what
+    ``grid/configs`` and ``grid/traffic`` say: slots (decode) or a prompt
+    bucket's rows, times the experts a token."""
+    from grid import manifest
+
+    short = {"smallthinker-21b-a3b-serve": "smallthinker",
+             "kimi-k2-ep32-serve": "kimi", "laguna-s-ep2-serve": "laguna",
+             "ling-3-flash-ep4-serve": "ling",
+             "motif-3-beta-ep16-serve": "motif",
+             "glm-5.3-flash-ep8-serve": "glm",
+             "deepseek-v32-ep16-serve": "dsv32",
+             "nemotron-3-nano-ep2-serve": "nemotron3"}
+    found = set()
+    for w in manifest.benchmark()["workloads"]:
+        if w["config"] not in short:
+            continue
+        cell = manifest.Cell(w["name"])
+        mcfg = manifest.driver(cell.kind).model_config(cell.config)
+        geometry = (len(mcfg.experts_held), mcfg.n_expert)
+        found.add((short[w["config"]], "decode",
+                   cell.config["engine"]["slots"] * mcfg.top_k) + geometry)
+        for b in cell.traffic["prompt_buckets"]:
+            found.add((short[w["config"]], str(b), b * mcfg.top_k)
+                      + geometry)
+    assert found == {c[:5] for c in SERVED_PASSES}

@@ -418,7 +418,15 @@ def test_one_group_is_the_same_cache(rng):
     assert [g.name for g in c.groups] == ["global"]
     assert c.pages_needed(0, 13) == 4
     assert np.array_equal(c.prompt_dest([5, 6]), c.prompt_dest_groups([[5, 6]]))
-    with pytest.raises(ValueError, match="cover layers"):
-        from paddle_tpu.serving.kv_cache import CacheGroup
+    from paddle_tpu.serving.kv_cache import CacheGroup
+    # a layer no group names keeps nothing (PR 65: a feed-forward that is a
+    # layer of its own); asking the cache for it is an error, and so is a
+    # group that names a layer the model has not
+    gap = PagedKVCache(3, 2, 8, 2, 32, 4, 9,
+                       groups=[CacheGroup("global", (0, 2), None, 9)])
+    assert gap.init_state()["k"].shape[0] == 2
+    with pytest.raises(KeyError):
+        gap.context(gap.init_state(), 1)
+    with pytest.raises(ValueError, match="name layers of 0..2"):
         PagedKVCache(3, 2, 8, 2, 32, 4, 9,
-                     groups=[CacheGroup("global", (0, 2), None, 9)])
+                     groups=[CacheGroup("global", (0, 3), None, 9)])

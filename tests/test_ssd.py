@@ -127,8 +127,12 @@ def test_the_step_kernel_equals_plain_xla_and_skips_idle_slots(live, rng):
 def test_the_gates_name_what_they_refuse():
     assert ssd.ssd_state_step_gate(32, 256, 128, 2) is None    # as served
     assert ssd.ssd_chunk_scan_gate(32, 2, 256, 128) is None
-    assert "whole (8, 128)" in ssd.ssd_state_step_gate(32, 256, 64, 2)
-    assert "multiple of 8" in ssd.ssd_state_step_gate(8, 256, 128, 2)
+    assert "whole (8, 128)" in ssd.ssd_state_step_gate(32, 256, 96, 2)
+    # heads of half a lane tile ride side by side (Nemotron-3's geometry)
+    assert ssd.ssd_state_step_gate(64, 128, 64, 8) is None
+    assert ssd.ssd_chunk_scan_gate(64, 8, 128, 64) is None
+    assert "whole tiles" in ssd.ssd_chunk_scan_gate(64, 8, 128, 96)
+    assert "multiple of 8" in ssd.ssd_state_step_gate(24, 256, 128, 2)
     assert "KiB of VMEM" in ssd.ssd_state_step_gate(32, 512, 128, 2)
     assert "do not divide" in ssd.ssd_state_step_gate(30, 256, 128, 4)
     assert "128-lane" in ssd.ssd_chunk_scan_gate(32, 2, 64, 128)
